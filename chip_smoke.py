@@ -1,0 +1,482 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that deepspeed_tpu still starts on the chip.
+
+One process that owns every chip JAX finds drives the two main paths
+through the entry points a user calls, at published widths with random
+weights made from a seed:
+
+* trainer — ``deepspeed_tpu.initialize`` → ``engine.train_batch``:
+  GPT-2 Medium, sequence 1024, bf16, ZeRO stage 3 over
+  ``{"fsdp": -1, "data": 1}``, 16 sequences a step (4 × gas 4 on one
+  chip, 4 × gas 1 on four) under the repo's ``774M-zero3`` remat recipe;
+* server — ``deepspeed_tpu.init_inference("gpt2-xl")`` → ``ServingEngine``
+  on the paged pool (8 slots, ``page_len`` 128), a bf16 then an int8 KV
+  pool, eight seeded greedy requests each;
+* every Pallas kernel those paths arm, once, against the repo's own
+  lax/XLA ground truth at the same shapes.
+
+It fails (non-zero exit, no result line) when JAX finds no TPU, when a
+phase raises, or when a check does not hold; no phase is wrapped in
+``try``.  Wall times it prints are smoke observations, not measurements.
+The last line of stdout is
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
+
+Run it from the root of a checkout: ``python3 chip_smoke.py`` (or
+``bin/deepspeed chip_smoke.py``).  The compile cache goes where
+``JAX_COMPILATION_CACHE_DIR`` says, else to ``<checkout>/.jax_cache``.
+"""
+from __future__ import annotations
+
+import collections
+import dataclasses
+import gc
+import json
+import math
+import re
+import sys
+import time
+from typing import Any, Dict, List, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import deepspeed_tpu
+from deepspeed_tpu.models import gpt2
+from deepspeed_tpu.utils.device import setup_compile_cache
+
+
+@dataclasses.dataclass(frozen=True)
+class Smoke:
+    """The sizes of one run.  ``FULL`` is what the chip runs; the CPU test
+    drives the same phases at toy sizes with ``mosaic=False``."""
+
+    train_cfg: gpt2.GPT2Config
+    seq: int
+    micro: int              # sequences per device per micro-batch
+    global_batch: int       # sequences per optimizer step, whatever the device count
+    steps: int
+    serve_model: str        # an init_inference preset name
+    slots: int
+    max_len: int            # positions per slot (prompt + generated)
+    page_len: int
+    prefill_chunk: int
+    prompt_lens: Tuple[int, int]
+    new_tokens: int
+    requests: int
+    # True: the Pallas kernels are compiled by Mosaic and must show in
+    # the optimized HLO.  False (CPU control-flow test): the kernel suite
+    # is not armed and no executable may hold a Mosaic call.
+    mosaic: bool = True
+    seed: int = 0
+
+
+# GPT-2 Large, the repo's own 774M-zero3 rung (bench.py), does not leave
+# room for the fp32 gradient accumulator that gas > 1 adds on one chip:
+# compiled for a v5e it wants 17.86 GB of 15.75 GB (PERF.md, "what stopped
+# the program").  The next preset down keeps every published width.
+_TRAIN_CFG = dataclasses.replace(
+    gpt2.GPT2_MEDIUM, remat=True, xent_chunk_size=512,
+    remat_save_names=("qkv", "ffn_pre", "attn_o", "attn_lse"),
+)
+FULL = Smoke(
+    train_cfg=_TRAIN_CFG, seq=1024, micro=4, global_batch=16, steps=3,
+    # 8 slots x 512 is the repo's own serving shape (tools/bench_serving.py)
+    serve_model="gpt2-xl", slots=8, max_len=512, page_len=128, prefill_chunk=64,
+    prompt_lens=(32, 384), new_tokens=32, requests=8,
+)
+
+# bf16 operands; the kernel rounds the softmax weights to bf16 before
+# the PV dot (the MXU's native rate) where the f32 reference does not —
+# the bound tests/test_flash_attention.py::test_bf16_forward_close uses.
+TOL_BF16 = 3e-2
+# f32 math on both sides, but the dots accumulate in another order and
+# the int8 scales fold in at another point.
+TOL_F32 = 2e-3
+# elementwise f32 optimizer math, reassociated (m + keep·(…) vs b1·m + …)
+# under two compilers whose divide and sqrt are not correctly rounded,
+# measured on what the steps moved the parameters by: the parameters'
+# own ulp (7e-9 at |p| 0.1) is 2e-5 of LAMB's two-step move.  Relative
+# to each tensor's largest entry.
+TOL_UPDATE = 1e-4
+# one chip vs all of them: the same 16 sequences, summed in another
+# order (per-device partial sums, bf16 matmuls split over the mesh).
+# Four v5e chips differed from one by 1e-5 over three steps (PERF.md);
+# a hundred times that.
+TOL_LOSS_ACROSS_MESHES = 1e-3
+
+
+_T0 = time.perf_counter()
+
+
+def say(msg: str) -> None:
+    print(f"[chip_smoke +{time.perf_counter() - _T0:6.1f}s] {msg}", flush=True)
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise AssertionError(f"chip_smoke: {what}")
+
+
+# ---------------------------------------------------------------------------
+# optimized-HLO reading
+# ---------------------------------------------------------------------------
+
+_MOSAIC_RE = re.compile(r'%([A-Za-z_]\w*?)(?:\.\d+)* = (\S+)[^\n]*custom_call_target="tpu_custom_call"')
+_GATHER_RE = re.compile(r"= (.*?) all-gather(?:-start)?\(")
+_SHAPE_RE = re.compile(r"(bf16|f16|f32)\[([\d,]+)\]")
+
+
+def mosaic_kernels(hlo: str) -> Dict[str, int]:
+    """Kernel name (the ``pallas_call``'s ``name=``) → number of Mosaic
+    custom calls in an executable's optimized HLO."""
+    return dict(collections.Counter(m.group(1) for m in _MOSAIC_RE.finditer(hlo)))
+
+
+def first_output_dims(hlo: str, kernel: str) -> Tuple[int, ...]:
+    for m in _MOSAIC_RE.finditer(hlo):
+        if m.group(1) == kernel:
+            return tuple(int(d) for d in _SHAPE_RE.search(m.group(2)).group(2).split(","))
+    raise AssertionError(f"chip_smoke: no Mosaic call named {kernel!r} in the executable")
+
+
+def gathered_float_shapes(hlo: str) -> List[Tuple[int, ...]]:
+    out = []
+    for m in _GATHER_RE.finditer(hlo):
+        out += [tuple(int(d) for d in dims.split(",")) for _, dims in _SHAPE_RE.findall(m.group(1))]
+    return out
+
+
+def expect_kernels(found: Dict[str, int], expected: Sequence[str], where: str) -> None:
+    """A shape-based dispatch to a lax path must be visible, not silent:
+    the executable holds exactly the kernels this path is known to arm."""
+    say(f"{where}: Mosaic kernels {found or '{}'}")
+    check(sorted(found) == sorted(expected),
+          f"{where}: expected Mosaic kernels {sorted(expected)}, executable holds {sorted(found)}")
+
+
+# ---------------------------------------------------------------------------
+# phase 1: each kernel against its ground truth
+# ---------------------------------------------------------------------------
+
+def _max_err(a, b) -> float:
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / max(1.0, float(np.max(np.abs(b)))))
+
+
+def check_flash_attention(s: Smoke) -> Dict[str, float]:
+    """flash fwd + bwd vs ``mha_reference`` on a sample of heads at the
+    trainer's per-device shape."""
+    from deepspeed_tpu.ops.attention.flash_attention import flash_attention, mha_reference
+
+    heads = min(4, s.train_cfg.n_head)
+    shape = (s.micro, heads, s.seq, s.train_cfg.head_dim)
+    keys = jax.random.split(jax.random.PRNGKey(s.seed), 4)
+    q, k, v, g = (jax.random.normal(kk, shape, jnp.float32).astype(jnp.bfloat16) for kk in keys)
+
+    def run(attn):
+        def fwd_bwd(q, k, v, g):
+            out, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, causal=True), q, k, v)
+            return (out, *vjp(g))
+
+        return jax.jit(fwd_bwd)(q, k, v, g)
+
+    got = run(flash_attention)
+    with jax.default_matmul_precision("highest"):
+        want = run(mha_reference)
+    errs = {n: _max_err(a, b) for n, a, b in zip(("out", "dq", "dk", "dv"), got, want)}
+    say(f"flash_attention vs mha_reference {shape}: " + " ".join(f"{n}={e:.2e}" for n, e in errs.items()))
+    check(max(errs.values()) < TOL_BF16, f"flash attention off its reference by {errs} (tolerance {TOL_BF16})")
+    return errs
+
+
+def check_flash_decode_paged(s: Smoke, mcfg, kv_dtype) -> float:
+    """``flash_decode_paged`` vs gather + ``cache_attention(use_kernel=False)``
+    on a pool written through the real paged write, at the server's
+    shapes: scattered pages, a different fill per slot."""
+    from deepspeed_tpu.ops.transformer.inference import (
+        init_kv_cache, paged_cache_attention, paged_cache_write,
+    )
+
+    B, H, d = s.slots, mcfg.n_head, mcfg.head_dim
+    P = s.max_len // s.page_len
+    rng = np.random.default_rng(s.seed)
+    table = (1 + rng.permutation(B * P)).reshape(B, P).astype(np.int32)  # page 0 is the garbage page
+    fill = rng.integers(1, P * s.page_len, (B,)).astype(np.int32)
+    fill[0] = P * s.page_len - 1  # one slot full to the last row
+    k_pool, v_pool = (jax.tree.map(lambda a: a[0], c)
+                      for c in init_kv_cache(1, 1 + B * P, H, s.page_len, d, kv_dtype))
+    kk, kv_, kq = jax.random.split(jax.random.PRNGKey(s.seed + 1), 3)
+    rows = (B, H, P * s.page_len, d)
+    zero = jnp.zeros((B,), jnp.int32)
+    k_pool = paged_cache_write(k_pool, jax.random.normal(kk, rows, jnp.float32).astype(jnp.bfloat16), table, zero)
+    v_pool = paged_cache_write(v_pool, jax.random.normal(kv_, rows, jnp.float32).astype(jnp.bfloat16), table, zero)
+    q = jax.random.normal(kq, (B, H, 1, d), jnp.float32).astype(jnp.bfloat16)
+
+    def attend(use_kernel):
+        return jax.jit(lambda *a: paged_cache_attention(*a, use_kernel=use_kernel))(
+            q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(fill))
+
+    got = attend(True)
+    with jax.default_matmul_precision("highest"):
+        want = attend(False)
+    err = _max_err(got, want)
+    name = "int8" if kv_dtype == "int8" else jnp.dtype(kv_dtype).name
+    say(f"flash_decode_paged[{name}] vs cache_attention (B={B} H={H} pages={P}x{s.page_len} d={d}): {err:.2e}")
+    # the output is cast to bf16 on both paths: one bf16 ulp on top of TOL_F32
+    check(err < TOL_F32 + 2 ** -8, f"flash_decode_paged[{name}] off its reference by {err}")
+    return err
+
+
+def check_fused_update(s: Smoke) -> Dict[str, float]:
+    """The fused Adam and LAMB kernels vs the XLA update the engine runs
+    without them, on leaves of the trainer's shapes: one stacked weight
+    (the Pallas path) and one ragged bias (the XLA leaf path)."""
+    from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
+    from deepspeed_tpu.ops.kernels.fused_update import engine_update
+    from deepspeed_tpu.ops.lamb.fused_lamb import FusedLamb
+
+    c = s.train_cfg
+    shapes = {"qkv_w": (c.n_layer, c.n_embd, 3 * c.n_embd), "qkv_b": (c.n_layer, 3 * c.n_embd - 1)}
+    keys = iter(jax.random.split(jax.random.PRNGKey(s.seed + 2), 2 * len(shapes)))
+    params = {n: 0.02 * jax.random.normal(next(keys), sh, jnp.float32) for n, sh in shapes.items()}
+    grads = {n: 1e-3 * jax.random.normal(next(keys), sh, jnp.float32) for n, sh in shapes.items()}
+    # a learning rate large enough that what the steps move the
+    # parameters by stands clear of the parameters' own ulp (LAMB's trust
+    # ratio is ~0.02 here, so at 1e-4 two steps move |p| ~ 0.1 by 4e-6,
+    # 500 ulp)
+    lr = jnp.float32(1e-2)
+    errs = {}
+    for name, opt in (("adam", FusedAdam(lr=1e-2, weight_decay=0.01)), ("lamb", FusedLamb(lr=1e-2))):
+        state = opt.init(params)
+
+        def fused(g, st, p, opt=opt):
+            return engine_update(opt, g, st, p, lr, None)
+
+        def xla(g, st, p, opt=opt):
+            upd, new = opt.update(g, st, p, lr=lr)
+            return jax.tree.map(lambda a, u: a + u, p, upd), new
+
+        # two steps, so the second reads moments the first one wrote
+        got = want = (params, state)
+        for _ in range(2):
+            got = jax.jit(fused)(grads, got[1], got[0])
+            want = jax.jit(xla)(grads, want[1], want[0])
+        # what the two steps moved the parameters by, and the moments
+        parts = lambda out: jax.tree.leaves((  # noqa: E731
+            jax.tree.map(jnp.subtract, out[0], params), out[1].exp_avg, out[1].exp_avg_sq))
+        errs[name] = max(
+            float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))) for a, b in zip(parts(got), parts(want))
+        )
+        if s.mosaic:
+            hlo = jax.jit(fused).lower(grads, state, params).compile().as_text()
+            expect_kernels(mosaic_kernels(hlo), {"adam": ["fused_adam"], "lamb": ["fused_lamb_dir", "fused_lamb_apply"]}[name],
+                           f"fused_update[{name}]")
+    say("fused_update vs XLA update: " + " ".join(f"{n}={e:.2e}" for n, e in errs.items()))
+    check(max(errs.values()) < TOL_UPDATE, f"fused update off the XLA update by {errs} (tolerance {TOL_UPDATE})")
+    return errs
+
+
+# ---------------------------------------------------------------------------
+# phase 2: the trainer
+# ---------------------------------------------------------------------------
+
+def _batches(s: Smoke):
+    rng = np.random.default_rng(s.seed)
+    for _ in range(s.steps):
+        yield {"input_ids": rng.integers(0, s.train_cfg.vocab_size, (s.global_batch, s.seq), dtype=np.int32)}
+
+
+def train(s: Smoke, devices: Sequence) -> Dict[str, Any]:
+    """``initialize`` → ``train_batch`` × steps over ``devices``; returns
+    the losses and what the compiled step and the state layout show."""
+    from deepspeed_tpu.comm.mesh import make_mesh
+    from deepspeed_tpu.config.config import MeshConfig
+
+    n = len(devices)
+    check(s.global_batch % (s.micro * n) == 0, f"{s.global_batch} sequences do not split over {n} devices x micro {s.micro}")
+    gas = s.global_batch // (s.micro * n)
+    mesh_block = {"fsdp": -1, "data": 1}
+    config = {
+        "train_micro_batch_size_per_gpu": s.micro,
+        "gradient_accumulation_steps": gas,
+        "bf16": {"enabled": True},
+        "zero_optimization": {"stage": 3},
+        "mesh": mesh_block,
+        "optimizer": {"type": "Adam", "params": {"lr": 1e-4}},
+        "steps_per_print": 10_000,
+    }
+    model_fn, _, tp_fn = gpt2.make_model(s.train_cfg)
+    t0 = time.perf_counter()
+    params = gpt2.init_params_device(s.train_cfg, seed=s.seed)
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model_fn, model_parameters=params, config=config, tp_spec_fn=tp_fn,
+        mesh=make_mesh(MeshConfig.from_dict(mesh_block), devices=list(devices)),
+    )
+    del params
+    say(f"train[{n} dev]: engine ready in {time.perf_counter() - t0:.1f}s (micro {s.micro} x gas {gas} x dp {n})")
+
+    losses, walls = [], []
+    for batch in _batches(s):
+        t0 = time.perf_counter()
+        losses.append(float(engine.train_batch(batch)))  # float() waits for the step
+        walls.append(time.perf_counter() - t0)
+    say(f"train[{n} dev]: losses {[round(x, 4) for x in losses]}; smoke wall first step (compile + run) "
+        f"{walls[0]:.1f}s, later steps {[round(w, 2) for w in walls[1:]]}s")
+
+    check(all(math.isfinite(x) for x in losses), f"non-finite loss in {losses}")
+    # untrained, the tied head's logits are Gaussian with variance
+    # n_embd · 0.02² (unit-variance LayerNorm output times the init's
+    # embedding std), so the expected cross-entropy is ln(vocab) plus
+    # half of that: 11.03 at GPT-2 Medium, not the 10.82 of uniform logits
+    want = math.log(s.train_cfg.vocab_size) + s.train_cfg.n_embd * 0.02 ** 2 / 2
+    check(abs(losses[0] - want) < 0.01 * want, f"first loss {losses[0]:.4f} not within 1% of {want:.4f}")
+    check(engine.global_steps == s.steps, f"global_steps {engine.global_steps} after {s.steps} steps")
+    check(engine.compilation_count == 1, f"{engine.compilation_count} train executables for one batch shape")
+
+    # ZeRO-3: every device holds its 1/n of the parameters and Adam
+    # state, leaves under the persistence threshold (biases, LayerNorms)
+    # whole
+    small = engine.config.zero_config.param_persistence_threshold
+    held = collections.Counter()
+    total = promised = 0
+    for leaf in jax.tree.leaves((engine.state["params"], engine.state["opt_state"])):
+        total += leaf.nbytes
+        promised += leaf.nbytes if leaf.size < small else leaf.nbytes / n
+        for shard in leaf.addressable_shards:
+            held[shard.device.id] += shard.data.nbytes
+    share = max(held.values()) / total
+    say(f"train[{n} dev]: params + Adam state {total / 2**30:.2f} GiB, largest per-device share {share:.3f}")
+    check(share <= 1.05 * promised / total,
+          f"a device holds {share:.3f} of params + optimizer state, ZeRO-3 over {n} promises {promised / total:.3f}")
+
+    hlo = engine.train_step_executable().as_text()
+    if s.mosaic:
+        # fused_update runs on one device only (runtime/engine.py: a Mosaic
+        # call cannot be partitioned over the sharded optimizer state)
+        expect_kernels(mosaic_kernels(hlo),
+                       ["flash_attention_fwd", "flash_attention_bwd"] + (["fused_adam"] if n == 1 else []),
+                       f"train[{n} dev]")
+        c = s.train_cfg
+        rows = first_output_dims(hlo, "flash_attention_fwd")[0]
+        check(rows == s.micro * c.n_head,
+              f"attention kernel runs on {rows} batch·head rows a device, expected micro {s.micro} x {c.n_head} heads")
+        whole = {tuple(sorted(t)) for t in (
+            (s.micro * n, c.n_head, s.seq, c.head_dim), (s.micro * n * c.n_head, s.seq, c.head_dim),
+            (s.micro * n, s.seq, 3 * c.n_embd), (s.micro * n, s.seq, c.n_embd),
+        )}
+        gathered = [g for g in gathered_float_shapes(hlo) if tuple(sorted(g)) in whole]
+        check(n == 1 or not gathered, f"the step all-gathers whole-batch activations {gathered} in front of attention")
+    else:
+        expect_kernels(mosaic_kernels(hlo), [], f"train[{n} dev]")
+    del engine
+    gc.collect()
+    return {"losses": losses, "share": share, "first_step_wall": walls[0]}
+
+
+# ---------------------------------------------------------------------------
+# phase 3: the server
+# ---------------------------------------------------------------------------
+
+def serve(s: Smoke, device) -> Dict[str, Any]:
+    """``init_inference`` → one ``ServingEngine`` per KV dtype on the
+    paged pool; seeded greedy requests submitted and drained.  The
+    engine gets an explicit one-device mesh: the default spreads ``data``
+    over every device and replicates the pool, so four chips would
+    compute the same tokens four times."""
+    from deepspeed_tpu.comm.mesh import make_mesh
+    from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.serving import ServingEngine
+
+    t0 = time.perf_counter()
+    inf = deepspeed_tpu.init_inference(
+        model=s.serve_model, max_out_tokens=s.max_len, init_on_device=True, seed=s.seed,
+        mesh=make_mesh(MeshConfig(), devices=[device]),
+    )
+    mcfg = inf.model_config
+    say(f"serve: {s.serve_model} ready in {time.perf_counter() - t0:.1f}s")
+    rng = np.random.default_rng(s.seed)
+    lo, hi = s.prompt_lens
+    prompts = [rng.integers(0, mcfg.vocab_size, (int(n),), dtype=np.int32)
+               for n in rng.integers(lo, hi + 1, (s.requests,))]
+    out = {}
+    for kv in ("model", "int8"):
+        if s.mosaic:
+            check_flash_decode_paged(s, mcfg, "int8" if kv == "int8" else inf.dtype)
+        srv = ServingEngine(inf, config={
+            "num_slots": s.slots, "max_len": s.max_len, "kv_cache_dtype": kv,
+            "prefill_chunk": s.prefill_chunk,
+            # pages for every slot's full length plus the garbage page, and
+            # none of the default 2x prefix-cache headroom nothing here
+            # uses: as compiled today the prefill step holds about four
+            # pool-sized copies (PERF.md), and they have to fit beside it
+            "kvcache": {"enabled": True, "page_len": s.page_len,
+                        "num_pages": 1 + s.slots * (s.max_len // s.page_len)},
+        })
+        t0 = time.perf_counter()
+        ids = [srv.submit(p, max_new_tokens=s.new_tokens) for p in prompts]
+        done = srv.drain()
+        wall = time.perf_counter() - t0
+        check(sorted(done) == sorted(ids), f"serve[{kv}]: submitted {sorted(ids)}, drained {sorted(done)}")
+        for rid in ids:
+            r = done[rid]
+            check(r.status == "done" and r.finish_reason == "length" and len(r.generated) == s.new_tokens,
+                  f"serve[{kv}]: request {rid} ended {r.status}/{r.finish_reason} with {len(r.generated)} tokens")
+            check(all(0 <= t < mcfg.vocab_size for t in r.generated), f"serve[{kv}]: request {rid} token id out of range")
+        check((srv.prefill_compiles, srv.decode_compiles) == (1, 1),
+              f"serve[{kv}]: {srv.prefill_compiles} prefill / {srv.decode_compiles} decode executables for one pool")
+        say(f"serve[{kv}]: {len(ids)} requests x {s.new_tokens} tokens done, smoke wall {wall:.1f}s "
+            f"(compiles included), pool {srv.pool.cache_bytes() / 2**30:.2f} GiB")
+        # the paged prefill attends through gather + lax (T > 1); only
+        # the decode step arms a kernel
+        expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
+                       ["flash_decode_paged"] if s.mosaic else [], f"serve[{kv}] decode")
+        expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()), [], f"serve[{kv}] prefill")
+        out[kv] = [done[rid].generated for rid in ids]
+        del srv, done
+        gc.collect()
+    return out
+
+
+# ---------------------------------------------------------------------------
+
+def run(s: Smoke, devices: Sequence) -> None:
+    """Every phase, in order; raises on the first check that fails."""
+    if s.mosaic:
+        check_flash_attention(s)
+        check_fused_update(s)
+    result = train(s, devices[:1])
+    if len(devices) > 1:
+        # the same 16 sequences on one chip (above) and on all of them
+        many = train(s, devices)
+        for i, (a, b) in enumerate(zip(result["losses"], many["losses"])):
+            check(abs(a - b) <= TOL_LOSS_ACROSS_MESHES * abs(a),
+                  f"step {i + 1} loss {b:.5f} on {len(devices)} devices vs {a:.5f} on one (tolerance {TOL_LOSS_ACROSS_MESHES})")
+        say(f"train: losses on 1 and {len(devices)} devices agree within {TOL_LOSS_ACROSS_MESHES}")
+    serve(s, devices[0])
+
+
+def main() -> int:
+    devices = jax.devices()
+    device = {"platform": devices[0].platform, "kind": devices[0].device_kind, "count": len(devices)}
+    if device["platform"] != "tpu":
+        print(f"chip_smoke: needs a TPU, JAX found platform {device['platform']!r} "
+              f"({device['kind']} x {device['count']})", file=sys.stderr)
+        return 2
+    say(f"device: {device}")
+    cache_hits = collections.Counter()
+    jax.monitoring.register_event_listener(
+        lambda event, **kw: cache_hits.update([event.rsplit("/", 1)[-1]])
+        if event.startswith("/jax/compilation_cache/cache_") else None
+    )
+    say(f"compile cache: {setup_compile_cache()}")
+    run(FULL, devices)
+    say(f"compile cache: {cache_hits['cache_hits']} hits, {cache_hits['cache_misses']} misses")
+    peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices)
+    say(f"peak HBM in use on a device: {peak / 2**30:.2f} GiB")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -16,6 +16,7 @@ from typing import Any, Callable, Optional, Tuple
 from deepspeed_tpu.version import __version__
 from deepspeed_tpu.comm.distributed import init_distributed
 from deepspeed_tpu.config.config import DeepSpeedConfig, DeepSpeedConfigError
+from deepspeed_tpu.utils.device import setup_compile_cache
 from deepspeed_tpu.utils.logging import log_dist, logger
 
 __git_hash__ = None
@@ -73,6 +74,7 @@ def initialize(
 
     if dist_init_required is None or dist_init_required:
         init_distributed(verbose=False)
+    setup_compile_cache()
 
     # Resolve the mesh first (the batch triad needs the dp world size).
     if mesh is None:
@@ -173,6 +175,7 @@ def init_inference(model=None, **kwargs):
     """Reference ``init_inference`` (:227) — builds an InferenceEngine."""
     from deepspeed_tpu.inference.engine import InferenceEngine
 
+    setup_compile_cache()
     return InferenceEngine(model=model, **kwargs)
 
 

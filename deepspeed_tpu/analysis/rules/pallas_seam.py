@@ -1,17 +1,16 @@
 """Rule: raw ``pl.pallas_call`` sites belong in the kernel seam.
 
 Every Pallas kernel is a block-size decision (the autotuner's domain,
-``ops/kernels/autotune.py``), a version-compat surface
-(``CompilerParams`` vs ``TPUCompilerParams`` — the exact drift that held
-11 tier-1 tests red on this container's jaxlib), and an attribution
-contract (docs/kernels.md: every kernel lands with a bucket pin and a
-bench rung).  A bare ``pl.pallas_call`` outside
+``ops/kernels/autotune.py``), an arming and interpret-or-compile
+decision (``utils/device.py``: one probe, no quiet fallback), a stable
+``name=`` the optimized HLO and the device trace find it by, and an
+attribution contract (docs/kernels.md: every kernel lands with a bucket
+pin and a bench rung).  A bare ``pl.pallas_call`` outside
 ``deepspeed_tpu/ops/kernels/`` and ``deepspeed_tpu/ops/attention/``
-gets none of that: hardcoded tiles, per-call compat guards, and cost
+gets none of that: hardcoded tiles, its own platform probe, and cost
 invisible to the roofline table.  New kernels go in ``ops/kernels/``
 (or the attention package, whose flash/splash kernels predate the
-seam) and route compiler params through
-:func:`deepspeed_tpu.ops.kernels.compat.tpu_compiler_params`.
+seam).
 """
 from __future__ import annotations
 
@@ -41,8 +40,8 @@ def _is_pallas_call(node: ast.Call):
     Severity.B,
     "direct pl.pallas_call site outside deepspeed_tpu/ops/kernels/ and "
     "ops/attention/ — new kernels go through the kernel seam (autotuned "
-    "blocks, tpu_compiler_params version shim, attribution pin + bench "
-    "rung per docs/kernels.md)",
+    "blocks, arming rule, stable name, attribution pin + bench rung per "
+    "docs/kernels.md)",
 )
 def check_raw_pallas_call(rule, ctx):
     path = os.path.normpath(ctx.path).replace(os.sep, "/")
@@ -53,7 +52,7 @@ def check_raw_pallas_call(rule, ctx):
             yield make_finding(
                 rule, ctx, node,
                 "raw 'pallas_call' outside the kernel seam — this kernel gets "
-                "no autotuned blocks, no CompilerParams version shim, and no "
+                "no autotuned blocks, no arming rule or stable name, and no "
                 "attribution/bench coverage; put it in ops/kernels/ (see "
                 "docs/kernels.md)",
             )

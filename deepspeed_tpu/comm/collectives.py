@@ -35,8 +35,6 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.comm.compressed import (  # noqa: F401  (re-exports: the 1-bit tier)
-    _shard_map,
-    _sm_flags,
     compress_chunks,
     compressed_allreduce,
     compressed_allreduce_compressed_out,
@@ -48,22 +46,13 @@ AxisName = Union[str, Tuple[str, ...]]
 
 
 def shard_map_manual(fn, mesh, in_specs, out_specs, manual_axes):
-    """Version-compat ``shard_map`` with only ``manual_axes`` mapped
-    manually (every other mesh axis stays automatic/GSPMD) and the
-    replication check off.  Newer jax spells this ``axis_names=...`` +
-    ``check_vma``; older jax spells it ``auto=<complement>`` +
-    ``check_rep`` — the pipeline engine's per-stage bodies need it to
-    run on both."""
-    import inspect
-
-    sm = _shard_map()
-    params = inspect.signature(sm).parameters
-    kw = dict(_sm_flags())
-    if "axis_names" in params:
-        kw["axis_names"] = set(manual_axes)
-    elif "auto" in params:
-        kw["auto"] = frozenset(a for a in mesh.axis_names if a not in manual_axes)
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    """``jax.shard_map`` with only ``manual_axes`` mapped manually (every
+    other mesh axis stays automatic/GSPMD) and the replication check
+    off."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=set(manual_axes), check_vma=False,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -73,15 +62,6 @@ def shard_map_manual(fn, mesh, in_specs, out_specs, manual_axes):
 def axis_size(axis_name: AxisName):
     """Traced size of one (or a tuple of) mapped mesh axes."""
     return jax.lax.psum(1, axis_name)
-
-
-def static_axis_size(axis_name: AxisName) -> int:
-    """STATIC size of a mapped axis, usable to build ppermute perm
-    lists inside a shard_map body.  Newer jax has ``lax.axis_size``;
-    older jax constant-folds ``psum(1, axis)`` to the same value."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    return int(jax.lax.psum(1, axis_name))
 
 
 def flat_axis_index(axis_name: AxisName):
@@ -214,12 +194,12 @@ def quantized_allreduce_replicated(
     def body(x, k):
         return _int8_body(x, k, axis_name=axis_name, stochastic=stoch)
 
-    mapped = _shard_map()(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(dp_rows_spec(axis_name), replicated_pspec()),
         out_specs=replicated_pspec(),
-        **_sm_flags(),
+        check_vma=False,
     )
     return mapped(x_rows, key)
 
@@ -232,8 +212,8 @@ def dense_allreduce_replicated(x_rows, mesh, axis_name: AxisName = "data"):
     def body(x):
         return jax.lax.pmean(x[0], axis_name)
 
-    mapped = _shard_map()(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(dp_rows_spec(axis_name),), out_specs=replicated_pspec(),
-        **_sm_flags(),
+        check_vma=False,
     )
     return mapped(x_rows)

@@ -22,26 +22,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _shard_map():
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:  # older jax fallback
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
-def _sm_flags() -> dict:
-    """Replication-check opt-out kwarg across jax versions: newer
-    shard_map spells it ``check_vma``, older ``check_rep``."""
-    import inspect
-
-    params = inspect.signature(_shard_map()).parameters
-    if "check_vma" in params:
-        return {"check_vma": False}
-    if "check_rep" in params:
-        return {"check_rep": False}
-    return {}
-
-
 def _sign_compress(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Compress to {-1,+1} int8 signs + scalar L1 scale (reference
     nccl.py:76-86: scale = |x|.mean(); sign with 0→+1)."""
@@ -103,12 +83,12 @@ def _exchange(x_per_rank, worker_error, server_error, mesh, axis_name, replicate
         out, new_werr, new_serr = _body(x, werr, serr, axis_name=axis_name)
         return (out[0] if replicated_out else out), new_werr, new_serr
 
-    mapped = _shard_map()(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(rows, rows, rows),
         out_specs=(replicated_pspec() if replicated_out else rows, rows, rows),
-        **_sm_flags(),
+        check_vma=False,
     )
     return mapped(x_per_rank, worker_error, server_error)
 
@@ -180,12 +160,12 @@ def compressed_allreduce_compressed_out(
         all_scales = jax.lax.all_gather(srv_scale, axis_name)  # (n,)
         return all_signs, all_scales, new_werr[None], new_serr[None]
 
-    mapped = _shard_map()(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(rows, rows, rows),
         out_specs=(replicated_pspec(), replicated_pspec(), rows, rows),
-        **_sm_flags(),
+        check_vma=False,
     )
     return mapped(x_per_rank, worker_error, server_error)
 

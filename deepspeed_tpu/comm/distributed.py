@@ -86,19 +86,6 @@ def init_distributed(
     )
     attempts = {"n": 0}
 
-    def _supports_init_timeout() -> bool:
-        # signature probe, NOT try/except TypeError around the call: a
-        # TypeError raised from INSIDE initialize (bad argument types)
-        # must not be misread as "older jax" and retried unbounded
-        import inspect
-
-        try:
-            return "initialization_timeout" in inspect.signature(
-                jax.distributed.initialize
-            ).parameters
-        except (TypeError, ValueError):
-            return False
-
     def _initialize():
         attempts["n"] += 1
         kwargs = dict(
@@ -106,7 +93,7 @@ def init_distributed(
             num_processes=num_processes,
             process_id=process_id,
         )
-        if deadline > 0 and _supports_init_timeout():
+        if deadline > 0:
             # bound the in-call wait too: a wrong coordinator address
             # otherwise blocks INSIDE initialize for jax's own default
             kwargs["initialization_timeout"] = max(1, int(deadline))

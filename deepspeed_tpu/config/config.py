@@ -2015,7 +2015,7 @@ _KNOWN_TOP_LEVEL = {
 class KernelsConfig:
     """``kernels`` block (TPU-native extension; docs/kernels.md): the
     Pallas kernel suite.  ``enabled``: ``"auto"`` arms the suite on
-    TPU-class backends only (the lax/XLA paths stay the CPU ground
+    device platform ``tpu`` only (the lax/XLA paths stay the CPU ground
     truth); ``true``/``false`` force it.  ``flash_decode`` /
     ``fused_update`` subtract individual kernels from an armed suite.
     ``autotune`` is the block-size tuner mode (``off`` = deterministic

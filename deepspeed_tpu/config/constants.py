@@ -415,7 +415,7 @@ DIVERGENCE_ACTIONS = [
 # Pallas kernel suite (ops/kernels; docs/kernels.md)
 #############################################
 KERNELS = "kernels"
-KERNELS_ENABLED_AUTO = "auto"  # armed on TPU-class backends only
+KERNELS_ENABLED_AUTO = "auto"  # armed on device platform "tpu" only
 KERNELS_ENABLED_CHOICES = [KERNELS_ENABLED_AUTO, True, False]
 KERNELS_FLASH_DECODE_DEFAULT = True  # fused int8-KV flash-decode kernel
 KERNELS_FUSED_UPDATE_DEFAULT = True  # one-HBM-pass Adam/LAMB update

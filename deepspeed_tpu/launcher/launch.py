@@ -4,14 +4,15 @@ Reference: ``deepspeed/launcher/launch.py`` (``main`` :67) — decode the
 world info, set ``MASTER_*``/rank env vars, spawn one process per local
 accelerator, kill the pack if any child dies (:129-167).
 
-TPU difference: JAX runs **one process per host** that owns all local
-chips (SURVEY §3.1 TPU note), so the per-rank fan-out collapses to a
-single child per node — but the contract stays: env-var bootstrap
-(MASTER_ADDR/PORT, RANK, WORLD_SIZE consumed by
-``comm/distributed.init_distributed``), signal propagation, non-zero
-exit on child failure.  ``--procs_per_node`` > 1 is supported for
-CPU-cluster/debug runs (each child gets a distinct RANK and a
-``JAX_LOCAL_DEVICE`` hint).
+TPU difference: one JAX process owns every chip of its host, and a chip
+belongs to one process at a time, so on a TPU host the per-rank fan-out
+is a single child — more than one (a hostfile with several slots for the
+node, ``--procs_per_node`` or the runner's ``--num_gpus`` above 1) is
+refused unless ``JAX_PLATFORMS=cpu`` keeps the children off the chips
+(CPU-cluster and debug runs, the multi-process tests).  The contract
+stays: env-var bootstrap (MASTER_ADDR/PORT, RANK, LOCAL_RANK, WORLD_SIZE
+consumed by ``comm/distributed.init_distributed``), signal propagation,
+non-zero exit on child failure.
 
 Supervision (docs/resilience.md): children get ``DS_SUPERVISION_PORT``
 (derived from ``master_port``) so the heartbeat side channel needs no
@@ -81,6 +82,14 @@ def main(args=None):
         procs_per_node = max(1, args.procs_per_node)
         world_size = procs_per_node
         rank_offset = args.node_rank * procs_per_node
+
+    if procs_per_node > 1 and os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        raise SystemExit(
+            f"launch: {procs_per_node} processes asked for on this node, but one JAX "
+            "process owns every local chip and a second one fails or hangs waiting "
+            "for them.  Start one process per host (one hostfile slot, no --num_gpus), "
+            "or set JAX_PLATFORMS=cpu for a multi-process CPU run."
+        )
 
     children: List[subprocess.Popen] = []
 

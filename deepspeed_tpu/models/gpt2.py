@@ -177,9 +177,9 @@ def init_params_device(cfg: GPT2Config, seed: int = 0, dtype=jnp.float32):
     """Random init generated ON DEVICE (same tree structure/shapes as
     ``init_params``, independent random stream).
 
-    For benchmark/serving paths where host generation + upload of an
-    XL-class model costs minutes over PCIe/tunnel while on-chip
-    generation costs seconds.  Not bitwise-equal to ``init_params`` —
+    For benchmark/serving paths: host generation of an XL-class model is
+    minutes of single-threaded numpy plus a multi-GB upload, on-chip
+    generation is seconds.  Not bitwise-equal to ``init_params`` —
     use the host init when pinned numerics matter."""
     if cfg.n_experts > 0:
         raise NotImplementedError("device init does not cover MoE; use init_params")

@@ -28,10 +28,7 @@ from deepspeed_tpu.ops.kernels.autotune import (  # noqa: F401 — public surfac
     get_autotuner,
     reset_autotuner,
 )
-from deepspeed_tpu.ops.kernels.compat import (  # noqa: F401
-    on_tpu_backend,
-    tpu_compiler_params,
-)
+from deepspeed_tpu.utils.device import on_tpu_backend  # noqa: F401
 
 _STATE: Dict[str, Any] = {
     "enabled": "auto",        # "auto" | True | False (config layer)
@@ -114,7 +111,7 @@ def _suite_armed() -> bool:
         if enabled in (True, False):
             return bool(enabled)
     # auto (explicit env "auto" overrides config, per the escape-hatch
-    # contract): TPU-class backends only — the lax/XLA paths stay the
+    # contract): device platform "tpu" only — the lax/XLA paths stay the
     # CPU tier-1 ground truth
     return on_tpu_backend()
 

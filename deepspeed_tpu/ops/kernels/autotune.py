@@ -56,22 +56,14 @@ def autotune_mode() -> str:
 
 
 def default_cache_path() -> str:
-    """The cache file rides next to XLA's persistent compile cache when
-    one is configured (same lifecycle and cleanup story); otherwise
-    ``~/.cache/deepspeed_tpu/``.  ``DS_KERNEL_AUTOTUNE_CACHE`` overrides."""
-    env = os.environ.get("DS_KERNEL_AUTOTUNE_CACHE")
-    if env:
-        return env
-    cache_dir = None
-    try:
-        import jax
+    """The cache file rides in XLA's persistent compile cache directory
+    (same lifecycle and cleanup story; ``utils.device.cache_dir``).
+    ``DS_KERNEL_AUTOTUNE_CACHE`` overrides."""
+    from deepspeed_tpu.utils.device import cache_dir
 
-        cache_dir = getattr(jax.config, "jax_compilation_cache_dir", None)
-    except Exception:  # noqa: BLE001 — jax may not be importable (lint CI)
-        cache_dir = None
-    if not cache_dir:
-        cache_dir = os.path.join(os.path.expanduser("~"), ".cache", "deepspeed_tpu")
-    return os.path.join(cache_dir, "kernel_autotune.json")
+    return os.environ.get("DS_KERNEL_AUTOTUNE_CACHE") or os.path.join(
+        cache_dir(), "kernel_autotune.json"
+    )
 
 
 def _jaxlib_fingerprint() -> str:

@@ -50,9 +50,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.kernels.compat import on_tpu_backend as _on_tpu, tpu_compiler_params
 from deepspeed_tpu.ops.registry import register_op
+from deepspeed_tpu.utils.device import pallas_interpret_default
 
 # Same mask constant as cache_attention: fully-masked rows degrade to
 # the same uniform softmax on both paths (parity over garbage rows the
@@ -180,8 +181,6 @@ def flash_decode(
     """Single-query attention against a slot cache; see module docs.
     Returns (B, H, 1, d) in ``q.dtype``.  Block sizes default to the
     autotuner's table (cached measured winners when present)."""
-    from jax.experimental.pallas import tpu as pltpu
-
     from deepspeed_tpu.ops.kernels.autotune import get_autotuner
 
     quant = isinstance(k_cache, dict)
@@ -199,7 +198,7 @@ def flash_decode(
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = pallas_interpret_default()
 
     blocks = get_autotuner().blocks_for("flash_decode", B=B, H=H, S=S, d=d, int8=quant)
     bk = _pick_block_k(S, block_k or blocks["block_k"])
@@ -258,10 +257,11 @@ def flash_decode(
             pltpu.VMEM((bs, 1), jnp.float32),   # l
             pltpu.VMEM((bs, d), jnp.float32),   # acc
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_decode",
     )(pos_vec, *args)
     return out
 
@@ -364,8 +364,6 @@ def flash_decode_paged(
     is one kv block (``decode_paged_supported`` demands page_len be
     lane-aligned), and the online softmax state lives in VMEM scratch
     exactly like :func:`flash_decode`."""
-    from jax.experimental.pallas import tpu as pltpu
-
     quant = isinstance(k_cache, dict)
     k_op = k_cache["q"] if quant else k_cache
     v_op = v_cache["q"] if quant else v_cache
@@ -383,7 +381,7 @@ def flash_decode_paged(
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = pallas_interpret_default()
 
     table = jnp.asarray(page_table, jnp.int32)
     pos_vec = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
@@ -427,10 +425,11 @@ def flash_decode_paged(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, d), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_decode_paged",
     )(table, pos_vec, *args)
     return out
 

@@ -46,12 +46,14 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.kernels.compat import on_tpu_backend as _on_tpu
 from deepspeed_tpu.ops.registry import register_op
+from deepspeed_tpu.utils.device import pallas_interpret_default
 
 _COLS = 256           # lane-aligned row width for the flattened leaf view
 _MIN_ROWS = 8         # below this the grid overhead beats the fusion win
+_PART_TILE = (8, 128)  # fp32 VMEM tile: the smallest block Mosaic writes
 
 
 # ---------------------------------------------------------------------------
@@ -158,8 +160,6 @@ def _leaf_grid(n: int, block_rows: int) -> Optional[Tuple[int, int]]:
 
 def _adam_pallas_leaf(p, g, m, v, scal, *, b1, b2, eps, weight_decay,
                       adam_w_mode, block_rows, interpret):
-    from jax.experimental.pallas import tpu as pltpu
-
     n = p.size
     rows, br = _leaf_grid(n, block_rows)
     shape2 = (rows, _COLS)
@@ -182,6 +182,7 @@ def _adam_pallas_leaf(p, g, m, v, scal, *, b1, b2, eps, weight_decay,
         # true in-place: p/m/v buffers are consumed by their updates
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name="fused_adam",
     )(scal, p2, g2, m2, v2)
     return po.reshape(p.shape), mo.reshape(p.shape), vo.reshape(p.shape)
 
@@ -223,9 +224,10 @@ def _lamb_dir_kernel(scal_ref, p_ref, g_ref, m_ref, v_ref,
     dir_ref[:] = d
     mo_ref[:] = m_new
     vo_ref[:] = v_new
-    # per-block norm partials for the whole-leaf trust ratio
-    wsq_ref[0, 0] = jnp.sum(p32 * p32)
-    dsq_ref[0, 0] = jnp.sum(d * d)
+    # per-block norm partials for the whole-leaf trust ratio, splat over
+    # one (8, 128) tile each: Mosaic has no smaller VMEM output block
+    wsq_ref[:] = jnp.full(wsq_ref.shape, jnp.sum(p32 * p32))
+    dsq_ref[:] = jnp.full(dsq_ref.shape, jnp.sum(d * d))
 
 
 def _lamb_apply_kernel(scal_ref, p_ref, dir_ref, trust_ref, po_ref):
@@ -238,15 +240,14 @@ def _lamb_apply_kernel(scal_ref, p_ref, dir_ref, trust_ref, po_ref):
 
 def _lamb_pallas_leaf(p, g, m, v, scal, *, b1, b2, eps, weight_decay,
                       min_coeff, max_coeff, block_rows, interpret):
-    from jax.experimental.pallas import tpu as pltpu
-
     n = p.size
     rows, br = _leaf_grid(n, block_rows)
     shape2 = (rows, _COLS)
     p2, g2, m2, v2 = (t.reshape(shape2) for t in (p, g, m, v))
     nblk = rows // br
     blk = pl.BlockSpec((br, _COLS), lambda i: (i, 0))
-    part = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    part = pl.BlockSpec(_PART_TILE, lambda i: (i, 0))
+    part_shape = jax.ShapeDtypeStruct((nblk * _PART_TILE[0], _PART_TILE[1]), jnp.float32)
     d2, mo, vo, wsq, dsq = pl.pallas_call(
         functools.partial(
             _lamb_dir_kernel, b1=b1, b2=b2, eps=eps, weight_decay=weight_decay,
@@ -258,12 +259,15 @@ def _lamb_pallas_leaf(p, g, m, v, scal, *, b1, b2, eps, weight_decay,
             jax.ShapeDtypeStruct(shape2, jnp.float32),
             jax.ShapeDtypeStruct(shape2, jnp.float32),
             jax.ShapeDtypeStruct(shape2, jnp.float32),
-            jax.ShapeDtypeStruct((nblk, 1), jnp.float32),
-            jax.ShapeDtypeStruct((nblk, 1), jnp.float32),
+            part_shape,
+            part_shape,
         ],
         input_output_aliases={3: 1, 4: 2},
         interpret=interpret,
+        name="fused_lamb_dir",
     )(scal, p2, g2, m2, v2)
+    # one representative element per block tile
+    wsq, dsq = (t[:: _PART_TILE[0], 0] for t in (wsq, dsq))
     trust = _lamb_trust(
         jnp.sqrt(jnp.sum(wsq)), jnp.sqrt(jnp.sum(dsq)), min_coeff, max_coeff
     ).reshape(1)
@@ -278,6 +282,7 @@ def _lamb_pallas_leaf(p, g, m, v, scal, *, b1, b2, eps, weight_decay,
         out_shape=jax.ShapeDtypeStruct(shape2, p.dtype),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="fused_lamb_apply",
     )(scal, p2, d2, trust)
     return po.reshape(p.shape), mo.reshape(p.shape), vo.reshape(p.shape)
 
@@ -316,7 +321,7 @@ def engine_update(optimizer, grads, opt_state, params, lr, overflow,
     from deepspeed_tpu.ops.lamb.fused_lamb import FusedLamb, LambState
 
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = pallas_interpret_default()
     is_adam = isinstance(optimizer, FusedAdam) and isinstance(opt_state, AdamState)
     is_lamb = isinstance(optimizer, FusedLamb) and isinstance(opt_state, LambState)
     if is_adam and getattr(optimizer, "state_precision", "fp32") != "fp32":

@@ -6,14 +6,17 @@ compile at trace time), so the only native code left is **host-side** —
 the async disk I/O engine (``csrc/aio``) and the SIMD host optimizer
 (``csrc/adam``) used by ZeRO-Offload/Infinity.  Those are compiled here
 with g++ at first use into a shared library loaded via ctypes, cached by
-source hash (rebuild on source change), mirroring the reference's
-compile-at-first-use contract without torch cpp_extension.
+source hash (rebuild on source change) and, for ``-march=native``
+builds, by the CPU they were compiled for — a tree copied to another
+machine rebuilds instead of loading foreign code — mirroring the
+reference's compile-at-first-use contract without torch cpp_extension.
 """
 from __future__ import annotations
 
 import ctypes
 import hashlib
 import os
+import platform
 import subprocess
 from typing import List, Optional
 
@@ -27,12 +30,29 @@ BASE_FLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC", "-fopenmp", "-Wall"]
 ARCH_FLAGS = ["-march=native", "-funroll-loops"]
 
 
+def _machine_tag() -> str:
+    """What ``-march=native`` resolves against: the CPU architecture and
+    its feature flags."""
+    feats = platform.processor()
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    feats = line.split(":", 1)[1].strip()
+                    break
+    except OSError:
+        pass
+    return f"{platform.machine()} {feats}"
+
+
 def _source_hash(paths: List[str], flags: List[str]) -> str:
     h = hashlib.sha256()
     for p in sorted(paths):
         with open(p, "rb") as f:
             h.update(f.read())
     h.update(" ".join(flags).encode())
+    if "-march=native" in flags:
+        h.update(_machine_tag().encode())
     return h.hexdigest()[:16]
 
 

@@ -52,9 +52,7 @@ def _ring_attention_sharded(q, k, v, *, axis_name: str, causal: bool, sm_scale: 
     ``q, k, v``: local shards ``(B, H, T/P, D)``; sequence is sharded
     contiguously (shard ``r`` holds positions ``[r*T/P, (r+1)*T/P)``).
     """
-    from deepspeed_tpu.comm.collectives import static_axis_size
-
-    ring = static_axis_size(axis_name)  # version-compat lax.axis_size
+    ring = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, h, t_local, d = q.shape
     qf = q.astype(jnp.float32) * sm_scale

@@ -27,6 +27,7 @@ epilogues, and the backward pass).  The profiler therefore:
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -35,24 +36,36 @@ import numpy as np
 
 from deepspeed_tpu.utils.logging import log_dist, logger
 
-# bf16 peak TFLOPS per chip for MFU math; overridable per call.
-PEAK_TFLOPS_BY_PLATFORM = {
-    "tpu": 197.0,   # v5e bf16 (BASELINE hardware)
-    "cpu": 0.5,     # so CPU-mesh tests produce sane (small) MFU numbers
-    "gpu": 312.0,   # A100 bf16, for completeness
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeak:
+    """One chip's published peaks: the MFU and roofline denominators."""
+
+    bf16_tflops: float
+    hbm_gbps: float
+    source: str
+
+
+# The one peaks table, keyed by ``jax.devices()[0].device_kind``.  A row
+# is added with its source; a device that has none has no MFU and no
+# roofline (:func:`device_peak` raises) rather than an invented one.
+DEVICE_PEAKS: Dict[str, DevicePeak] = {
+    "TPU v5 lite": DevicePeak(
+        bf16_tflops=197.0, hbm_gbps=819.0,
+        source='Google Cloud documentation, "TPU v5e" system architecture',
+    ),
 }
 
-# peak HBM GB/s per chip — the roofline denominator that pairs with the
-# table above (machine balance = peak flops / peak bytes; the attribution
-# module's compute- vs memory-bound verdicts key on it).
-PEAK_HBM_GBPS_BY_PLATFORM = {
-    "tpu": 819.0,   # v5e HBM2
-    # 0.5 TFLOPS / 100 GB/s → machine balance 5 flops/byte: far enough
-    # from both the dryrun train matmuls (AI ~10) and the decode
-    # matvecs (AI ~1) that the pinned roofline verdicts are stable
-    "cpu": 100.0,
-    "gpu": 2039.0,  # A100 80GB
-}
+
+def device_peak(device_kind: Optional[str] = None) -> DevicePeak:
+    """Peaks of ``device_kind`` (default: this process's first device)."""
+    kind = device_kind or jax.devices()[0].device_kind
+    if kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no published peak for device kind {kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); add a DEVICE_PEAKS row with its source"
+        )
+    return DEVICE_PEAKS[kind]
 
 
 def _num_params(tree: Any) -> int:
@@ -60,34 +73,29 @@ def _num_params(tree: Any) -> int:
 
 
 def cost_bytes(cost: Optional[Dict[str, float]]) -> float:
-    """HBM bytes from a ``cost_analysis()`` dict — one home for the
-    'bytes accessed' vs 'bytes_accessed' key-spelling difference across
-    jaxlib versions."""
-    cost = cost or {}
-    return float(cost.get("bytes accessed", cost.get("bytes_accessed", 0.0)))
+    """HBM bytes from a ``cost_analysis()`` dict."""
+    return float((cost or {}).get("bytes accessed", 0.0))
 
 
-def peak_flops(backend: Optional[str] = None, n_devices: int = 1) -> float:
+def peak_flops(device_kind: Optional[str] = None, n_devices: int = 1) -> float:
     """bf16 peak FLOP/s for the MFU denominator.  Defaults to ONE
     chip's peak: XLA ``cost_analysis()`` reports the *partitioned*
     (per-device) module, so per-device flops over per-chip peak is the
     correct MFU (verified against the analytic 6N+attention count on
     the 8-device dryrun, within 10%; tests/test_telemetry.py pins it)."""
-    backend = backend or jax.default_backend()
-    return PEAK_TFLOPS_BY_PLATFORM.get(backend, 100.0) * 1e12 * max(1, int(n_devices))
+    return device_peak(device_kind).bf16_tflops * 1e12 * max(1, int(n_devices))
 
 
-def peak_hbm_bytes_per_s(backend: Optional[str] = None) -> float:
+def peak_hbm_bytes_per_s(device_kind: Optional[str] = None) -> float:
     """Peak HBM bytes/s for ONE chip — the roofline bandwidth ceiling
     (per-device, matching :func:`peak_flops`)."""
-    backend = backend or jax.default_backend()
-    return PEAK_HBM_GBPS_BY_PLATFORM.get(backend, 100.0) * 1e9
+    return device_peak(device_kind).hbm_gbps * 1e9
 
 
 def derive_step_stats(
     cost: Optional[Dict[str, float]],
     wall_s: float,
-    backend: Optional[str] = None,
+    device_kind: Optional[str] = None,
 ) -> Dict[str, float]:
     """The one MFU/HBM derivation (shared by the profiler, the engine's
     telemetry gauges, and bench records): compiled-cost FLOPs and bytes
@@ -102,13 +110,13 @@ def derive_step_stats(
     cost = cost or {}
     flops = float(cost.get("flops", 0.0))
     hbm = cost_bytes(cost)
-    peak = peak_flops(backend)
+    peak = peak_flops(device_kind)
     achieved = flops / wall_s if wall_s and wall_s > 0 else float("nan")
     return {
         "flops_per_step": flops,
         "hbm_bytes_per_step": hbm,
         "achieved_flops": achieved,
-        "mfu": achieved / peak if peak else float("nan"),
+        "mfu": achieved / peak,
         "hbm_gbps": hbm / wall_s / 1e9 if wall_s and wall_s > 0 else float("nan"),
     }
 
@@ -128,8 +136,6 @@ def analyze_fn(fn: Callable, *args, static_argnums=()) -> Dict[str, float]:
     lowered = jax.jit(fn, static_argnums=static_argnums, out_shardings=None).lower(*args)
     compiled = lowered.compile()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     out = {
         "flops": float(cost.get("flops", 0.0)),
@@ -228,14 +234,10 @@ class FlopsProfiler:
 def _live_bytes_by_device() -> Dict[int, int]:
     """Per-device live-buffer accounting from ``jax.live_arrays()`` —
     the real number on backends whose PJRT client exposes no
-    ``memory_stats`` (XLA:CPU, some tunnels): sum of addressable shard
-    bytes per device over every live Array."""
+    ``memory_stats`` (XLA:CPU): sum of addressable shard bytes per
+    device over every live Array."""
     out: Dict[int, int] = {}
-    try:
-        arrays = jax.live_arrays()
-    except Exception:  # pragma: no cover - very old jax
-        return out
-    for a in arrays:
+    for a in jax.live_arrays():
         try:
             for s in a.addressable_shards:
                 out[s.device.id] = out.get(s.device.id, 0) + int(s.data.nbytes)

@@ -193,8 +193,8 @@ class DevicePrefetchLoader(ResumableWrapperMixin):
     overlap each other AND the step); this single-worker wrapper stays
     for direct users of the plain ``device_put`` path.
 
-    The engine's compiled step dispatches asynchronously; what serializes
-    a remote/tunneled TPU is the per-step host→device input transfer.
+    The engine's compiled step dispatches asynchronously; what the host
+    still does in line is the per-step host→device input transfer.
     Keeping ``prefetch_depth`` batches in flight overlaps the next
     transfers with the current step — the JAX-native equivalent of the
     reference dataloader's pinned-memory + non-blocking H2D copies.
@@ -232,9 +232,9 @@ class DevicePrefetchLoader(ResumableWrapperMixin):
                 return jax.device_put(batch, self.sharding)
             return jax.device_put(batch)
 
-        # device_put is a synchronous host call on remote/tunneled
-        # backends — run it in a worker thread so transfers overlap the
-        # compiled step instead of serializing with it
+        # device_put stages the batch on the calling thread — run it in
+        # a worker thread so transfers overlap the compiled step instead
+        # of serializing with it
         queue = collections.deque()
         with ThreadPoolExecutor(max_workers=1) as pool:
             try:

@@ -487,13 +487,14 @@ class DeepSpeedEngine:
         self.wall_clock_breakdown = config.wall_clock_breakdown
         self._cached_loss = None
         self._compiled = {}
+        self._train_executable = None
         self._train_step_cost: Dict[str, float] = {}
         self.skipped_steps = 0
         # Host-side mirror of state["global_step"].  Reading the device
-        # scalar costs a full host<->device round trip (on a remote/
-        # tunneled TPU that is ~100ms), so the hot path must never sync
-        # on it; the mirror advances with every non-skipped step and is
-        # reconciled from the device value at checkpoint load.
+        # scalar blocks the host until every step dispatched so far has
+        # finished, so the hot path must never sync on it; the mirror
+        # advances with every non-skipped step and is reconciled from
+        # the device value at checkpoint load.
         self._host_global_step = 0
         self._host_micro_step = 0
 
@@ -1364,6 +1365,12 @@ class DeepSpeedEngine:
         if self.telemetry is not None:
             self.telemetry.set_comm(summ)
 
+    def train_step_executable(self):
+        """The newest compiled ``train_batch`` executable (its
+        ``as_text()`` is the optimized HLO), or None before the first
+        step."""
+        return self._train_executable
+
     def train_step_attribution(self):
         """The compiled train step's per-kernel cost table
         (:class:`~deepspeed_tpu.telemetry.attribution.Attribution`), or
@@ -1662,8 +1669,7 @@ class DeepSpeedEngine:
             if self.loss_scaler.dynamic:
                 # explicit d2h read: the deliberate once-per-step host
                 # sync must not look like an implicit transfer under the
-                # sanitizer's guard (and on remote backends device_get
-                # batches better than __bool__)
+                # sanitizer's guard
                 with self._sup_region("engine.overflow_sync"):
                     overflowed = bool(jax.device_get(info["overflow"]))
                 if overflowed:
@@ -1687,9 +1693,8 @@ class DeepSpeedEngine:
         ``batch`` leaves must have leading dim ``gas * micro_batch`` (one
         full train_batch worth of per-replica samples) or ``micro_batch``
         (gas==1).  Batches already stacked/placed by
-        ``prefetch_loader()`` pass through untouched (no re-put — on
-        remote TPU backends ``device_put`` is a synchronous host RPC and
-        must stay off the hot path).
+        ``prefetch_loader()`` pass through untouched (no re-put: the
+        host-side staging of ``device_put`` stays off the hot path).
         """
         self.tput_timer.start()
         if (
@@ -1736,6 +1741,7 @@ class DeepSpeedEngine:
                     .compile()
                 )
             self._compiled[tb_key] = executable
+            self._train_executable = executable
             self.compilation_count += 1
             # ds_shard Pass 1/2 feed (no-op unless the audit armed it)
             shard_hooks.note_train(self, "train.train_batch", executable,
@@ -1747,13 +1753,8 @@ class DeepSpeedEngine:
                 # names the state/batch leaf whose shape/dtype/sharding
                 # drifted since the last executable was built
                 san.recompile.note("engine.train_batch", (self.state, stacked), owner=id(self))
-            try:
-                cost = executable.cost_analysis() or {}
-                if isinstance(cost, list):
-                    cost = cost[0] if cost else {}
-                self._train_step_cost = {k: float(v) for k, v in cost.items() if np.isscalar(v)}
-            except Exception:
-                self._train_step_cost = {}
+            cost = executable.cost_analysis() or {}
+            self._train_step_cost = {k: float(v) for k, v in cost.items() if np.isscalar(v)}
             if self.telemetry is not None:
                 # the compiled step's cost analysis is the numerator of
                 # the live MFU / HBM-GB/s gauges (docs/telemetry.md)
@@ -1854,10 +1855,10 @@ class DeepSpeedEngine:
         """Run N full train steps in ONE compiled program — a
         ``lax.scan`` of the train step over a stacked run of batches.
 
-        TPU-idiomatic driver loop: per-program dispatch costs (host RPC
-        latency, argument marshalling — ~10-30 ms/step through remote
-        runtimes) amortize over the whole run, the way t5x/pax drive
-        entire loops inside one program.  Semantics are identical to
+        TPU-idiomatic driver loop: per-program dispatch costs (argument
+        marshalling, launch latency) amortize over the whole run, the way
+        t5x/pax drive entire loops inside one program.  What a dispatch
+        costs on an attached chip: not measured (PERF.md).  Semantics are identical to
         calling ``train_batch`` N times: same grads, same updates, same
         overflow skipping; per-step losses return as one (N,) array.
 

@@ -3,8 +3,8 @@
 The engine's compiled step dispatches asynchronously; what serializes a
 training loop is the host work per batch — pulling the next batch out of
 the loader (tokenization, disk reads) and ``jax.device_put`` of it with
-the engine's batch sharding (a synchronous host RPC on remote/tunneled
-TPU backends).  :class:`DevicePrefetcher` runs both ahead of the
+the engine's batch sharding (host-side staging and, for a sharded batch,
+one slice per device).  :class:`DevicePrefetcher` runs both ahead of the
 consumer as a two-stage pipeline:
 
     loader thread:  ``next(loader)``      -> bounded queue (depth N)

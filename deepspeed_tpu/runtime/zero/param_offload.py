@@ -558,8 +558,8 @@ class ZeroInfinityEngine:
 
     @staticmethod
     def _start_host_copy(tree) -> None:
-        """Kick off the D2H transfer of every leaf (best effort — some
-        backends/tunnels don't expose copy_to_host_async)."""
+        """Kick off the D2H transfer of every leaf (best effort — not
+        every backend exposes copy_to_host_async)."""
         for leaf in jax.tree.leaves(tree):
             try:
                 leaf.copy_to_host_async()

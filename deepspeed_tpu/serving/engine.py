@@ -471,70 +471,83 @@ class ServingEngine:
             )
         return self._decode_fn
 
+    def _abstract_tree(self, tree):
+        """ShapeDtypeStructs carrying the tree's own shardings, so an AOT
+        lowering is the executable the live call runs."""
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=a.sharding), tree
+        )
+
+    def _abstract_staged(self, shape, dtype):
+        """A host-fed argument, as the step stages it (replicated)."""
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=self._replicated)
+
     def _decode_abstract_args(self):
         """The decode executable's argument signature as
         ShapeDtypeStructs (pool-derived, nothing executes) — shared by
-        ``attribute_decode`` and the ds_shard collective audit."""
+        ``compiled_step`` and the ds_shard collective audit."""
         S = self.pool.num_slots
-        abstract = lambda tree: jax.tree.map(  # noqa: E731
-            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), tree
-        )
+        vec = lambda dtype: self._abstract_staged((S,), dtype)  # noqa: E731
         args = [
-            abstract(self.engine.params),
-            jax.ShapeDtypeStruct((S,), jnp.int32),   # toks
-            jax.ShapeDtypeStruct((S,), jnp.int32),   # pos
-            jax.ShapeDtypeStruct((S,), jnp.bool_),   # flags
-            jax.ShapeDtypeStruct((S,), jnp.float32),  # temps
-            jax.ShapeDtypeStruct((S,), jnp.int32),   # topks
-            jax.ShapeDtypeStruct((S,), jnp.uint32),  # seeds
+            self._abstract_tree(self.engine.params),
+            vec(jnp.int32),    # toks
+            vec(jnp.int32),    # pos
+            vec(jnp.bool_),    # flags
+            vec(jnp.float32),  # temps
+            vec(jnp.int32),    # topks
+            vec(jnp.uint32),   # seeds
         ]
         if self._paged:
             args += [
-                jax.ShapeDtypeStruct((S, self.pool.pages_per_slot), jnp.int32),
-                jax.ShapeDtypeStruct((S,), jnp.bool_),  # write_mask
+                self._abstract_staged((S, self.pool.pages_per_slot), jnp.int32),
+                vec(jnp.bool_),  # write_mask
             ]
-        args += [abstract(self.pool.k), abstract(self.pool.v)]
+        args += [self._abstract_tree(self.pool.k), self._abstract_tree(self.pool.v)]
         return tuple(args)
 
     def _prefill_abstract_args(self):
         """The prefill executable's argument signature (one chunk, one
         slot) as ShapeDtypeStructs — the ds_shard audit's AOT feed."""
-        abstract = lambda tree: jax.tree.map(  # noqa: E731
-            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), tree
-        )
         chunk = self.config.prefill_chunk
-        i32 = lambda: jax.ShapeDtypeStruct((), jnp.int32)  # noqa: E731
-        args = [abstract(self.engine.params),
-                jax.ShapeDtypeStruct((1, chunk), jnp.int32)]
+        scalar = lambda dtype: self._abstract_staged((), dtype)  # noqa: E731
+        args = [self._abstract_tree(self.engine.params),
+                self._abstract_staged((1, chunk), jnp.int32)]
         if self._paged:
-            args += [
-                jax.ShapeDtypeStruct((self.pool.pages_per_slot,), jnp.int32),
-                i32(), i32(), i32(), i32(),  # pos, take_idx, cow_src, cow_dst
-            ]
+            args.append(self._abstract_staged((self.pool.pages_per_slot,), jnp.int32))
+            args += [scalar(jnp.int32)] * 4  # pos, take_idx, cow_src, cow_dst
         else:
-            args += [i32(), i32(), i32()]   # slot, pos, take_idx
+            args += [scalar(jnp.int32)] * 3  # slot, pos, take_idx
         args += [
-            jax.ShapeDtypeStruct((), jnp.bool_),    # do_sample
-            jax.ShapeDtypeStruct((), jnp.float32),  # temperature
-            jax.ShapeDtypeStruct((), jnp.int32),    # top_k
-            jax.ShapeDtypeStruct((), jnp.uint32),   # seed
-            abstract(self.pool.k), abstract(self.pool.v),
+            scalar(jnp.bool_),    # do_sample
+            scalar(jnp.float32),  # temperature
+            scalar(jnp.int32),    # top_k
+            scalar(jnp.uint32),   # seed
+            self._abstract_tree(self.pool.k), self._abstract_tree(self.pool.v),
         ]
         return tuple(args)
 
+    def compiled_step(self, which: str):
+        """The ``"prefill"`` or ``"decode"`` step AOT-compiled against
+        the pool's own shapes and shardings — abstract args only, so
+        nothing executes, no slot state is touched, and the sanitizer's
+        one-executable recompile proof is unaffected."""
+        if which == "decode":
+            self._get_decode()  # ensure the jit handle exists
+            return self._decode_jit.lower(*self._decode_abstract_args()).compile()
+        if which == "prefill":
+            self._get_prefill()
+            return self._prefill_jit.lower(*self._prefill_abstract_args()).compile()
+        raise ValueError(f"compiled_step: 'prefill' or 'decode', got {which!r}")
+
     def attribute_decode(self):
         """Per-kernel cost attribution of the decode executable
-        (docs/telemetry.md §Attribution): AOT-lower the decode function
-        against the pool's own shapes — abstract args only, so nothing
-        executes, no slot state is touched, and the sanitizer's
-        one-executable recompile proof is unaffected.  Returns an
+        (docs/telemetry.md §Attribution) over :meth:`compiled_step`.
+        Returns an
         :class:`~deepspeed_tpu.telemetry.attribution.Attribution` or
         None when the backend exposes no HLO text."""
         from deepspeed_tpu.telemetry.attribution import attribute_executable
 
-        self._get_decode()  # ensure the jit handle exists
-        compiled = self._decode_jit.lower(*self._decode_abstract_args()).compile()
-        return attribute_executable(compiled, label="serving_decode")
+        return attribute_executable(self.compiled_step("decode"), label="serving_decode")
 
     # ------------------------------------------------------------------
     # measured service rate (the admission controller's feed)
@@ -1176,8 +1189,9 @@ class ServingEngine:
         (per-chip share), and ``hbm_bytes_per_step`` as the decode
         roofline traffic model — params read once per token step plus
         the KV pool touched — an upper bound, not a measured access
-        count; plus the registry digest."""
-        from deepspeed_tpu.profiling.flops_profiler import peak_flops
+        count; plus the registry digest.  ``mfu`` is None on a device
+        with no published peak."""
+        from deepspeed_tpu.profiling.flops_profiler import DEVICE_PEAKS
 
         mcfg = self.engine.model_config
         n_params = mcfg.num_params() if hasattr(mcfg, "num_params") else 0
@@ -1187,8 +1201,10 @@ class ServingEngine:
         # per-chip share of the model work (bench.py's tokens/s/chip
         # convention): a sharded model splits the 2N across devices
         flops_step = 2.0 * n_params * max(live, 0.0) / jax.device_count()
+        peak = DEVICE_PEAKS.get(jax.devices()[0].device_kind)
         mfu = (
-            flops_step / wall_s / peak_flops() if wall_s > 0 and flops_step else None
+            flops_step / wall_s / (peak.bf16_tflops * 1e12)
+            if peak is not None and wall_s > 0 and flops_step else None
         )
         param_bytes = sum(
             int(np.prod(np.shape(p)) * np.dtype(p.dtype).itemsize)

@@ -204,8 +204,9 @@ def build_mesh(cfg=None, devices: Optional[Sequence] = None):
     """Build the framework mesh over the given (default: all) devices
     and derive its :class:`MeshTopology`.
 
-    Returns ``(mesh, topology)``.  Single-granule device sets get the
-    flat canonical arrangement; multi-granule sets get the 2-level
+    Returns ``(mesh, topology)``.  Single-granule device sets get
+    ``mesh_utils.create_device_mesh``'s arrangement (ICI-aware on a TPU,
+    flat elsewhere); multi-granule sets get the 2-level
     hybrid arrangement (real TPU multi-slice/multi-host via
     ``mesh_utils.create_hybrid_device_mesh`` when its metadata is
     usable, else direct granule-block assembly)."""
@@ -255,7 +256,12 @@ def build_mesh(cfg=None, devices: Optional[Sequence] = None):
                 "using flat device order (cross-slice collectives may ride slow links)"
             )
     if dev_array is None:
-        dev_array = np.asarray(devices).reshape(shape)
+        # ICI-aware order within one slice (on a v5e 2x2 host a 4-way
+        # axis becomes the physical ring 0-1-3-2, where the flat device
+        # order would hop the diagonal twice); a plain reshape off-TPU
+        from jax.experimental import mesh_utils
+
+        dev_array = mesh_utils.create_device_mesh(shape, devices)
     mesh = Mesh(dev_array, MESH_AXES)
     logger.info(
         "mesh: " + " × ".join(f"{ax}={sizes[ax]}" for ax in MESH_AXES if sizes[ax] > 1 or ax == "data")

@@ -28,9 +28,9 @@ evidence format EQuARX (arXiv:2506.17615) and cross-replica sharding
 (arXiv:2004.13336) used to prove their wins.
 
 Publishing surfaces: registry gauges (``attribution/<bucket>/*``),
-Perfetto counter tracks, ``ds_report`` rows, bench records, and the
-``perf-sentinel`` CI artifact (``python -m
-deepspeed_tpu.telemetry.attribution``).
+Perfetto counter tracks, ``ds_report`` rows and bench records.  The
+roofline needs the device's published peaks
+(``flops_profiler.DEVICE_PEAKS``); a device that has none gets no table.
 
 This file also owns the ONE ``jax.profiler`` trace cost-walk shared by
 ``tools/profile_train_step.py`` / ``profile_bert_step.py`` /
@@ -182,7 +182,7 @@ class Attribution:
     module_bytes: float
     unattributed_flops: float  # residual folded into layernorm/other
     unattributed_bytes: float
-    backend: Optional[str] = None
+    backend: Optional[str] = None  # device_kind the roofline is priced for
     meta: Dict[str, Any] = field(default_factory=dict)
 
     # -- derived views ------------------------------------------------------
@@ -477,7 +477,7 @@ def attribute_executable(
     if backend is None:
         import jax
 
-        backend = jax.default_backend()
+        backend = jax.devices()[0].device_kind
     return attribute_hlo_text(
         text, module_cost=module_cost or _module_cost(compiled),
         label=label, backend=backend,
@@ -615,85 +615,3 @@ def profile_and_report(engine_step, trace_dir: Optional[str] = None,
                           denom=denom if denom is not None else steps)
     tables["trace_dir"] = trace_dir
     return tables
-
-
-# ---------------------------------------------------------------------------
-# CLI: the perf-sentinel roofline artifact (8-device dryrun)
-# ---------------------------------------------------------------------------
-
-def _dryrun_roofline(out_path: Optional[str]) -> int:
-    """Build the dryrun tiny train engine + serving decode executable,
-    attribute both, print the tables, and (optionally) write the JSON
-    artifact CI uploads."""
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-    ).strip()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import dataclasses
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    import numpy as np
-
-    import deepspeed_tpu
-    from deepspeed_tpu.models import gpt2
-
-    cfg = dataclasses.replace(gpt2.GPT2_TINY, remat=False,
-                              scan_unroll=gpt2.GPT2_TINY.n_layer)
-    model_fn, init_fn, tp_fn = gpt2.make_model(cfg)
-    engine, _, _, _ = deepspeed_tpu.initialize(
-        model=model_fn, model_parameters=init_fn(),
-        config={
-            "train_micro_batch_size_per_gpu": 2,
-            "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
-            "steps_per_print": 10_000,
-        },
-        tp_spec_fn=tp_fn,
-    )
-    rng = np.random.default_rng(0)
-    batch = {"input_ids": rng.integers(0, cfg.vocab_size, (16, 16), dtype=np.int32)}
-    engine.train_batch(batch)
-    records = []
-    attr = engine.train_step_attribution()
-    if attr is not None:
-        print(attr.format_table())
-        records.append(attr.to_record())
-
-    # serving decode executable (plain jit → on-demand AOT attribution)
-    import jax.numpy as jnp
-
-    from deepspeed_tpu.serving import ServingEngine
-
-    inf = deepspeed_tpu.init_inference(
-        model_config=gpt2.GPT2_TINY, params=gpt2.init_params(gpt2.GPT2_TINY),
-        dtype=jnp.float32, max_out_tokens=gpt2.GPT2_TINY.n_positions,
-    )
-    srv = ServingEngine(inf, num_slots=2, prefill_chunk=8, max_len=32)
-    dattr = srv.attribute_decode()
-    if dattr is not None:
-        print()
-        print(dattr.format_table())
-        records.append(dattr.to_record())
-
-    if out_path:
-        with open(out_path, "w") as f:
-            json.dump({"schema": 1, "backend": jax.default_backend(),
-                       "tables": records}, f, indent=1)
-        print(f"\nroofline artifact -> {out_path}")
-    return 0 if records else 1
-
-
-def main(argv: Optional[List[str]] = None) -> int:
-    import argparse
-
-    p = argparse.ArgumentParser(
-        description="Per-kernel cost attribution roofline (8-device dryrun)"
-    )
-    p.add_argument("--out", default="", help="write the roofline JSON artifact here")
-    args = p.parse_args(argv)
-    return _dryrun_roofline(args.out or None)
-
-
-if __name__ == "__main__":
-    raise SystemExit(main())

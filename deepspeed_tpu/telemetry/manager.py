@@ -34,7 +34,7 @@ class TelemetryManager:
         self._cost: Dict[str, float] = {}
         self._attribution = None  # per-kernel cost table (attribution.py)
         self._spikes = 0
-        self._jax_backend: Optional[str] = None
+        self._kind: Optional[str] = None
         self._profiler_fired = False
         self._lock = threading.Lock()
         # per-step publish runs on the hot path: memoize metric handles
@@ -134,13 +134,13 @@ class TelemetryManager:
         cfg = self.config
         if cfg is not None and not getattr(cfg, "attribution", True):
             return
-        if not (self.registry.enabled or self.tracer.enabled):
+        if not (self.registry.enabled or self.tracer.enabled) or not self._has_peak():
             return
         from deepspeed_tpu.telemetry.attribution import attribute_executable
 
         try:
             attr = attribute_executable(
-                compiled, label=label, backend=self._backend(),
+                compiled, label=label, backend=self._device_kind(),
                 max_hlo_mb=float(getattr(cfg, "attribution_max_hlo_mb", 256.0) or 256.0),
             )
         except Exception as e:  # noqa: BLE001
@@ -148,13 +148,20 @@ class TelemetryManager:
             return
         self.set_attribution(attr)
 
-    def _backend(self) -> str:
-        # memoized: jax.default_backend() is not free on a per-step path
-        if self._jax_backend is None:
+    def _device_kind(self) -> str:
+        # memoized: jax.devices() is not free on a per-step path
+        if self._kind is None:
             import jax
 
-            self._jax_backend = jax.default_backend()
-        return self._jax_backend
+            self._kind = jax.devices()[0].device_kind
+        return self._kind
+
+    def _has_peak(self) -> bool:
+        """MFU and roofline exist only where the device's peaks are
+        published (flops_profiler.DEVICE_PEAKS) — no invented row."""
+        from deepspeed_tpu.profiling.flops_profiler import DEVICE_PEAKS
+
+        return self._device_kind() in DEVICE_PEAKS
 
     # -- per-step publish (StepTimeline hook) --------------------------------
     def publish_step(self, prefix: str, rec: Dict[str, float], count: int = 1,
@@ -184,11 +191,11 @@ class TelemetryManager:
             g_wall.set(wall_ms)
             self._g(f"{prefix}/steps_per_s").set(1.0 / wall)
             self._check_spike(prefix, wall_ms, prev_mean, prev_count)
-            if self._cost:
+            if self._cost and self._has_peak():
                 # the ONE shared MFU/HBM derivation (flops_profiler)
                 from deepspeed_tpu.profiling.flops_profiler import derive_step_stats
 
-                stats = derive_step_stats(self._cost, wall, backend=self._backend())
+                stats = derive_step_stats(self._cost, wall, device_kind=self._device_kind())
                 if stats["flops_per_step"]:
                     self._g("mfu").set(stats["mfu"])
                 if stats["hbm_bytes_per_step"]:

@@ -34,7 +34,7 @@ from deepspeed_tpu.telemetry.attribution import (
 )
 from deepspeed_tpu.telemetry import regression as reg
 
-pytestmark = pytest.mark.telemetry
+pytestmark = [pytest.mark.telemetry, pytest.mark.usefixtures("cpu_peak")]
 
 TINY = dataclasses.replace(gpt2.GPT2_TINY, remat=False,
                            scan_unroll=gpt2.GPT2_TINY.n_layer)

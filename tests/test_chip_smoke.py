@@ -1,0 +1,227 @@
+"""What can be known about the chip path without a chip.
+
+* every Pallas kernel the smoke arms lowers for the TPU at the smoke's
+  shapes (``jax.export`` runs the Pallas→Mosaic lowering on the CPU; the
+  Mosaic compiler itself only runs where libtpu compiles, i.e. in
+  ``chip_smoke.py``) — this is the test that would have caught the fused
+  LAMB partial-norm blocks;
+* ``chip_smoke.py``'s phases, driven at toy sizes on the CPU mesh, for
+  control flow only; the script itself refuses to run off a TPU;
+* the compile-cache rule: where ``JAX_COMPILATION_CACHE_DIR`` is set,
+  no code sets another directory;
+* the multi-device wrapper the compiled kernels run under.
+"""
+import dataclasses
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+import chip_smoke
+from deepspeed_tpu.models import gpt2
+from deepspeed_tpu.utils import device
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FULL = chip_smoke.FULL
+
+
+def _lowers_for_tpu(fn, *shapes):
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*shapes)
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# ---------------------------------------------------------------------------
+# (a) lowering at the smoke's shapes
+# ---------------------------------------------------------------------------
+
+def test_flash_attention_lowers_for_tpu_at_smoke_shapes():
+    from deepspeed_tpu.ops.attention.flash_attention import flash_attention
+
+    c = FULL.train_cfg
+    qkv = _sds((FULL.micro, c.n_head, FULL.seq, c.head_dim), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: flash_attention(*a, causal=True, interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    _lowers_for_tpu(fwd_bwd, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_flash_decode_lowers_for_tpu_at_smoke_shapes(kv):
+    from deepspeed_tpu.ops.kernels.flash_decode import flash_decode, flash_decode_paged
+
+    mcfg = gpt2.PRESETS[FULL.serve_model]
+    B, H, d, S, PL = FULL.slots, mcfg.n_head, mcfg.head_dim, FULL.max_len, FULL.page_len
+    P = S // PL
+
+    def cache(rows_shape):
+        if kv == "int8":
+            return {"q": _sds(rows_shape, jnp.int8), "s": _sds(rows_shape[:-1] + (1,), jnp.float32)}
+        return _sds(rows_shape, jnp.bfloat16)
+
+    q, pos = _sds((B, H, 1, d), jnp.bfloat16), _sds((B,), jnp.int32)
+    pages = cache((1 + B * P, H, PL, d))
+    _lowers_for_tpu(
+        lambda q, k, v, t, p: flash_decode_paged(q, k, v, t, p, interpret=False),
+        q, pages, pages, _sds((B, P), jnp.int32), pos,
+    )
+    slots = cache((B, H, S, d))
+    _lowers_for_tpu(lambda q, k, v, p: flash_decode(q, k, v, p, interpret=False), q, slots, slots, pos)
+    _lowers_for_tpu(
+        lambda q, k, v, p, m: flash_decode(q, k, v, p, key_padding_mask=m, interpret=False),
+        q, slots, slots, pos, _sds((B, S), jnp.bool_),
+    )
+
+
+@pytest.mark.parametrize("opt_name", ["adam", "lamb"])
+def test_fused_update_lowers_for_tpu_at_smoke_shapes(opt_name):
+    from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
+    from deepspeed_tpu.ops.kernels.fused_update import engine_update
+    from deepspeed_tpu.ops.lamb.fused_lamb import FusedLamb
+
+    c = FULL.train_cfg
+    opt = FusedAdam(lr=1e-4) if opt_name == "adam" else FusedLamb(lr=1e-4)
+    tree = {"qkv_w": _sds((c.n_layer, c.n_embd, 3 * c.n_embd), jnp.float32)}
+    state = jax.eval_shape(opt.init, tree)
+    _lowers_for_tpu(
+        lambda g, st, p: engine_update(opt, g, st, p, jnp.float32(1e-4), None, interpret=False),
+        tree, state, tree,
+    )
+
+
+# ---------------------------------------------------------------------------
+# (b) the smoke's control flow, and its refusal to run off a TPU
+# ---------------------------------------------------------------------------
+
+TOY = chip_smoke.Smoke(
+    train_cfg=dataclasses.replace(
+        gpt2.GPT2_TINY, remat=True, xent_chunk_size=64,
+        remat_save_names=FULL.train_cfg.remat_save_names,
+    ),
+    seq=128, micro=2, global_batch=8, steps=2,
+    serve_model="tiny", slots=2, max_len=64, page_len=16, prefill_chunk=16,
+    prompt_lens=(4, 40), new_tokens=4, requests=3, mosaic=False,
+)
+
+
+def test_chip_smoke_phases_at_toy_size_on_the_cpu_mesh():
+    chip_smoke.run(TOY, jax.devices()[:4])
+
+
+_HLO = """
+  %flash_attention_fwd.1 = (bf16[64,1024,64]{2,1,0:T(8,128)(2,1)S(1)}, f32[64,8,1024]{2,1,0}) custom-call(%a, %b, %c), custom_call_target="tpu_custom_call", operand_layout_constraints={}, backend_config={"custom_call_config":{"body":"TUzv"}}
+  %fused_adam.17 = (f32[180,256]{1,0}) custom-call(%s, %p), custom_call_target="tpu_custom_call", backend_config={}
+  %fused_adam.18 = (f32[180,256]{1,0}) custom-call(%s, %p), custom_call_target="tpu_custom_call", backend_config={}
+  %custom-call.3 = bf16[4,16]{1,0} custom-call(%x), custom_call_target="ConcatBitcast"
+  %all-gather-start.2 = (bf16[4,1024,1024]{2,1,0}, bf16[16,1024,1024]{2,1,0}) all-gather-start(%y), dimensions={0}
+  %all-gather.5 = s32[16,1024,1]{1,2,0} all-gather(%ids), dimensions={0}
+"""
+
+
+def test_smoke_reads_kernels_and_gathers_from_optimized_hlo():
+    assert chip_smoke.mosaic_kernels(_HLO) == {"flash_attention_fwd": 1, "fused_adam": 2}
+    assert chip_smoke.first_output_dims(_HLO, "flash_attention_fwd") == (64, 1024, 64)
+    assert chip_smoke.gathered_float_shapes(_HLO) == [(4, 1024, 1024), (16, 1024, 1024)]
+    chip_smoke.expect_kernels(chip_smoke.mosaic_kernels(_HLO), ["fused_adam", "flash_attention_fwd"], "t")
+    # a kernel that quietly dispatched to its lax path is a failure, not a pass
+    with pytest.raises(AssertionError, match="flash_attention_bwd"):
+        chip_smoke.expect_kernels(
+            chip_smoke.mosaic_kernels(_HLO), ["fused_adam", "flash_attention_fwd", "flash_attention_bwd"], "t")
+
+
+def test_chip_smoke_refuses_to_run_off_a_tpu():
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO}
+    res = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, env=env, cwd=REPO,
+    )
+    assert res.returncode != 0
+    assert "platform 'cpu'" in res.stderr
+    assert '"ok"' not in res.stdout
+
+
+# ---------------------------------------------------------------------------
+# (c) the compile cache is placed from outside
+# ---------------------------------------------------------------------------
+
+def test_cache_env_set_means_no_code_sets_a_directory(monkeypatch, tmp_path):
+    from deepspeed_tpu.ops.kernels.autotune import default_cache_path
+
+    monkeypatch.delenv("DS_KERNEL_AUTOTUNE_CACHE", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    # even on a TPU the helper leaves jax.config alone when the env names the place
+    monkeypatch.setattr(device, "on_tpu_backend", lambda: True)
+    assert device.setup_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    # the autotune cache rides beside the compile cache, never in ~/.cache
+    assert default_cache_path() == str(tmp_path / "kernel_autotune.json")
+
+
+def test_cache_env_unset_is_checkout_dir_on_tpu_and_none_on_cpu(monkeypatch):
+    from deepspeed_tpu.ops.kernels.autotune import default_cache_path
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("DS_KERNEL_AUTOTUNE_CACHE", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    assert device.setup_compile_cache() is None  # CPU: no persistent cache
+    assert jax.config.jax_compilation_cache_dir == before
+    fixed = os.path.join(REPO, ".jax_cache")
+    assert default_cache_path() == os.path.join(fixed, "kernel_autotune.json")
+    monkeypatch.setattr(device, "on_tpu_backend", lambda: True)
+    try:
+        assert device.setup_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+# ---------------------------------------------------------------------------
+# the wrapper compiled kernels run under on a multi-device mesh
+# ---------------------------------------------------------------------------
+
+def test_flash_kernel_over_mesh_matches_single_device():
+    """Batch over the dp grid, heads over tp, a broadcast bias dim left
+    whole, the indivisible batch left whole — same numbers as one
+    device (interpret mode stands in for the compiled kernel)."""
+    from deepspeed_tpu.ops.attention import flash_attention as fa
+    from deepspeed_tpu.ops.kernels.sharded import dim_spec, free_mesh_axes
+    from deepspeed_tpu.parallel.sequence import ambient_mesh
+    from deepspeed_tpu.sharding.mesh import MESH_AXES
+
+    shape = {"data": 2, "fsdp": 2, "model": 2}
+    mesh = Mesh(np.asarray(jax.devices()).reshape([shape.get(a, 1) for a in MESH_AXES]), MESH_AXES)
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.standard_normal((4, 4, 256, 16)), jnp.float32) for _ in range(3))
+    bias = jnp.asarray(rng.standard_normal((4, 1, 1, 256)), jnp.float32)
+
+    def kernel(q, k, v, bias, drop_seed):
+        return fa._flash_attention(q, k, v, bias, None, drop_seed, True, 0.25, 128, 128, True, 1.0, None, None)
+
+    want = kernel(q, k, v, bias, None)
+    with ambient_mesh(mesh):
+        sizes = free_mesh_axes()
+        assert {a: n for a, n in sizes.items() if n > 1} == shape
+        got = jax.jit(lambda *a: fa._over_mesh(kernel, *a, None, sizes))(q, k, v, bias)
+        grads = jax.jit(jax.grad(lambda q: fa._over_mesh(kernel, q, k, v, bias, None, sizes).sum()))(q)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(grads), np.asarray(jax.grad(lambda q: kernel(q, k, v, bias, None).sum())(q)),
+        atol=2e-4, rtol=2e-4,
+    )
+    # a batch of 3 does not split over data x fsdp = 4: it stays whole
+    assert dim_spec((3, 4, 256, 16), {0: ("data", "fsdp"), 1: "model"}, sizes) == \
+        jax.sharding.PartitionSpec(None, "model", None, None)
+    assert free_mesh_axes() == {}  # no ambient mesh: one device's worth

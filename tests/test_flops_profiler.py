@@ -36,7 +36,7 @@ def test_get_model_profile_gpt2():
     assert flops > rough / 3
 
 
-def test_engine_profile_step(capsys):
+def test_engine_profile_step(capsys, cpu_peak):
     cfg = dataclasses.replace(gpt2.GPT2_TINY, remat=False)
     model_fn, init_fn, tp_fn = gpt2.make_model(cfg)
     config = {

@@ -412,7 +412,7 @@ def test_serving_churn_parity_with_kernel_armed(monkeypatch):
 # attribution pin: the kv-dequant bucket dies with the kernel armed
 # ---------------------------------------------------------------------------
 
-def test_attribution_kv_dequant_bucket_eliminated():
+def test_attribution_kv_dequant_bucket_eliminated(cpu_peak):
     from deepspeed_tpu.telemetry.attribution import attribute_executable
 
     B, H, S, d = 4, 2, 256, 64

@@ -36,7 +36,7 @@ from deepspeed_tpu.telemetry import (
     validate_chrome_trace,
 )
 
-pytestmark = pytest.mark.telemetry
+pytestmark = [pytest.mark.telemetry, pytest.mark.usefixtures("cpu_peak")]
 
 
 @pytest.fixture(autouse=True)
@@ -714,9 +714,9 @@ class TestConfigAndSatellites:
         from deepspeed_tpu.profiling.flops_profiler import derive_step_stats, peak_flops
 
         stats = derive_step_stats(
-            {"flops": 1e12, "bytes accessed": 5e9}, wall_s=0.5, backend="tpu")
+            {"flops": 1e12, "bytes accessed": 5e9}, wall_s=0.5, device_kind="TPU v5 lite")
         assert stats["achieved_flops"] == pytest.approx(2e12)
-        assert stats["mfu"] == pytest.approx(2e12 / peak_flops("tpu"))
+        assert stats["mfu"] == pytest.approx(2e12 / peak_flops("TPU v5 lite")) == pytest.approx(2 / 197)
         assert stats["hbm_gbps"] == pytest.approx(10.0)
 
     def test_status_and_shutdown_roundtrip(self, tmp_path):

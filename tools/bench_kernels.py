@@ -191,7 +191,7 @@ def main():
 
     import jax
 
-    from deepspeed_tpu.ops.kernels.compat import on_tpu_backend
+    from deepspeed_tpu.utils.device import on_tpu_backend
 
     backend = jax.default_backend()
     on_tpu = on_tpu_backend()
