@@ -1,0 +1,2 @@
+"""Device idle share of a train cell's traced window."""
+from benchmark.readers import device_idle_pct as read  # noqa: F401
