@@ -1,0 +1,7 @@
+"""Median device time of one execution of the serving engine's prefill program (one chunk of
+one prompt): ``jit_serve_prefill`` on the ``XLA Modules`` line of the traced window."""
+from benchmark import programs
+
+
+def read(record):
+    return programs.device_ms_p50(programs.of_run(record), "jit_serve_prefill")

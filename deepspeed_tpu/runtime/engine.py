@@ -1695,7 +1695,16 @@ class DeepSpeedEngine:
         (gas==1).  Batches already stacked/placed by
         ``prefetch_loader()`` pass through untouched (no re-put: the
         host-side staging of ``device_put`` stays off the hot path).
+
+        In a ``jax.profiler`` trace the call is one ``ds.train.step``
+        span carrying the step number, with ``ds.train.data_wait``,
+        ``.compile`` and ``.dispatch`` inside it (docs/telemetry.md); no
+        fence is added, so the device may still be running when it ends.
         """
+        with self.timeline.annotation("step", step=self._host_global_step + 1):
+            return self._train_batch(batch)
+
+    def _train_batch(self, batch: Any) -> jnp.ndarray:
         self.tput_timer.start()
         if (
             self._onebit_exchange_ok
@@ -1706,7 +1715,9 @@ class DeepSpeedEngine:
         san = self._sanitizer
         was_placed = isinstance(batch, _PlacedBatch)
         t_place = time.perf_counter()
-        with san.transfer.guard("engine.train_batch.place") if san is not None else nullcontext():
+        with self.timeline.annotation("data_wait"), (
+            san.transfer.guard("engine.train_batch.place") if san is not None else nullcontext()
+        ):
             stacked = self._stack_and_place(batch)
         if not was_placed:
             # prefetched batches had their wait noted by the prefetcher
@@ -1734,9 +1745,13 @@ class DeepSpeedEngine:
                           {"lr": scalar, "grad_norm": scalar, "overflow": scalar})
             else:
                 out_sh = (self._state_shardings, scalar)
+            # the function's name is the program's in the profiler's
+            # trace: jit_train_step on the devices' "XLA Modules" lines
+            train_step = self._scoped(full_step)
+            train_step.__name__ = "train_step"
             with self.timeline.phase("compile"):
                 executable = (
-                    jax.jit(self._scoped(full_step), donate_argnums=(0,), out_shardings=out_sh)
+                    jax.jit(train_step, donate_argnums=(0,), out_shardings=out_sh)
                     .lower(self.state, stacked)
                     .compile()
                 )
@@ -1770,15 +1785,18 @@ class DeepSpeedEngine:
         # supervision: the compiled step is the step-boundary collective
         # (grad psum over the data axis) — the armed deadline plus the
         # peer-death escalation live here (docs/resilience.md)
+        guard = san.transfer.guard("engine.train_batch") if san is not None else nullcontext()
         with self._sup_region("engine.train_batch"):
+            # ds.train.dispatch: the call of the compiled step until it
+            # returns (XLA dispatch is async: not the device's time)
             if self._offload:
-                with san.transfer.guard("engine.train_batch") if san is not None else nullcontext():
+                with self.timeline.annotation("dispatch"), guard:
                     self.state, loss = self._compiled[tb_key](self.state, stacked)
                 # the host optimizer step is a deliberate host-I/O region
                 # (grads device->host, masters host->device) — not guarded
                 info = self._host_apply_step()
             else:
-                with san.transfer.guard("engine.train_batch") if san is not None else nullcontext():
+                with self.timeline.annotation("dispatch"), guard:
                     self.state, loss, info = self._compiled[tb_key](self.state, stacked)
         if san is not None:
             san.donation.note(donated, "engine.train_batch", step=self._host_global_step)

@@ -26,6 +26,15 @@ The timeline itself is pure host bookkeeping (two ``perf_counter``
 reads and a dict update per note): enabled without the fence it does
 not change the hot path and still attributes every host-measurable
 phase; the per-step device fence is the engine's (opt-in) choice.
+
+Every :meth:`StepTimeline.phase` block is also a
+``jax.profiler.TraceAnnotation`` named ``ds.<prefix>.<name>``, so the
+same phases stand in the profiler's trace, on the clock of the device
+planes, whenever a ``jax.profiler`` trace is running (and cost one
+inactive annotation otherwise).  A dotted name (``prefill.stage``) is a
+*sub-phase*: annotated under its full name, recorded under its last
+component and, lying inside another phase, left out of the sum from
+which ``other`` and ``wall`` derive (docs/telemetry.md).
 """
 from __future__ import annotations
 
@@ -33,6 +42,9 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Deque, Dict, List, Optional
+
+import numpy as np
+from jax.profiler import TraceAnnotation
 
 PHASES = ("data_wait", "compute", "ckpt_stall", "compile", "other")
 
@@ -42,16 +54,28 @@ class StepTimeline:
 
     ``phases`` customizes the attributed phase names (the serving engine
     uses ``prefill/decode/sched``); ``other`` is always present as the
-    unattributed remainder.  :meth:`set_gauge` records per-step levels
-    (e.g. queue depth) that are averaged — not ms-scaled — in
-    :meth:`summary`."""
+    unattributed remainder.  ``sub_phases`` names what is timed *inside*
+    a phase (serving: ``stage/dispatch/wait`` inside prefill and decode)
+    and is therefore reported but never added to the step's wall;
+    ``blocked_on`` names the one in which the host is blocked on the
+    device, so that :meth:`summary` can report the rest of each step
+    (``host_ms_p50/p95``: time in which a serial engine has given the
+    device nothing).
+    ``prefix`` (``train`` or ``serve``) names the engine in the
+    profiler's trace: ``ds.<prefix>.<phase>``.  :meth:`set_gauge`
+    records per-step levels (e.g. queue depth) that are averaged — not
+    ms-scaled — in :meth:`summary`."""
 
-    def __init__(self, enabled: bool = True, window: int = 512, phases=None):
+    def __init__(self, enabled: bool = True, window: int = 512, phases=None,
+                 sub_phases=(), blocked_on: Optional[str] = None, prefix: str = "train"):
         self.enabled = bool(enabled)
         self.window = max(1, int(window))
         self.phases = tuple(phases) if phases is not None else PHASES
         if "other" not in self.phases:
             self.phases = self.phases + ("other",)
+        self.sub_phases = tuple(sub_phases)
+        self.blocked_on = blocked_on
+        self.prefix = str(prefix)
         self.records: Deque[Dict[str, float]] = deque(maxlen=self.window)
         self.total_steps = 0
         self._pending: Dict[str, float] = {}
@@ -94,27 +118,38 @@ class StepTimeline:
             return
         self._pending[phase] = self._pending.get(phase, 0.0) + float(seconds)
 
+    def annotation(self, name: str, **args) -> TraceAnnotation:
+        """``ds.<prefix>.<name>`` in the profiler's trace, with ``args``
+        (the step number on a step's span) as the event's arguments.
+        Written only while a ``jax.profiler`` trace is running; not
+        gated on ``enabled``, and nothing is recorded here."""
+        return TraceAnnotation(f"ds.{self.prefix}.{name}", **args)
+
     @contextmanager
     def phase(self, name: str):
-        """Time a host block and note it under ``name`` (and as a trace
-        span when the attached telemetry plane has tracing armed)."""
-        if not self.enabled:
-            yield
-            return
-        tm = self._telemetry
-        tracer = tm.tracer if tm is not None and tm.tracer.enabled else None
-        t0m = tracer.now() if tracer is not None else 0.0
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            self.note(name, dt)
-            if tracer is not None:
-                tracer.add_span(
-                    f"{self._t_prefix}/{name}", self._t_prefix, t0m, t0m + dt,
-                    pid=self._trace_pid,
-                )
+        """Time a host block and note it under ``name`` — a sub-phase
+        ``outer.inner`` under ``inner`` — and annotate it in the
+        profiler's trace (and, a phase only, as a Chrome-trace span when
+        the attached telemetry plane has tracing armed)."""
+        with self.annotation(name):
+            if not self.enabled:
+                yield
+                return
+            key = name.rpartition(".")[2]
+            tm = self._telemetry
+            tracer = tm.tracer if key == name and tm is not None and tm.tracer.enabled else None
+            t0m = tracer.now() if tracer is not None else 0.0
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                self.note(key, dt)
+                if tracer is not None:
+                    tracer.add_span(
+                        f"{self._t_prefix}/{name}", self._t_prefix, t0m, t0m + dt,
+                        pid=self._trace_pid,
+                    )
 
     def set_gauge(self, name: str, value: float) -> None:
         """Record a per-step level (queue depth, live slots, ...): kept
@@ -132,18 +167,21 @@ class StepTimeline:
         if not self.enabled:
             return
         now = time.perf_counter()
+        # sub-phases lie inside a phase: counted again they would zero
+        # ``other`` and inflate ``wall``
+        noted = sum(v for p, v in self._pending.items() if p not in self.sub_phases)
         if self._last_boundary is None:
             # first boundary: no previous anchor, the wall is whatever
             # was explicitly noted (avoids charging engine build time
             # to step 1's "other")
-            wall = sum(self._pending.values())
+            wall = noted
         else:
             wall = now - self._last_boundary
         self._last_boundary = now
-        noted = sum(self._pending.values())
         other = max(0.0, wall - noted)
         count = max(1, int(count))
-        rec = {p: self._pending.get(p, 0.0) / count for p in self.phases if p != "other"}
+        rec = {p: self._pending.get(p, 0.0) / count
+               for p in self.phases + self.sub_phases if p != "other"}
         rec["other"] = (self._pending.get("other", 0.0) + other) / count
         rec["wall"] = max(wall, noted) / count
         rec.update(self._pending_gauges)
@@ -168,11 +206,15 @@ class StepTimeline:
     def summary(self, last_n: Optional[int] = None) -> Dict[str, float]:
         """Mean per-step milliseconds per phase over the last ``last_n``
         recorded steps (default: the whole window), plus ``steps_per_s``
-        derived from the mean step wall."""
+        derived from the mean step wall; and, so that a slow stretch of
+        the window does not vanish in a mean, ``<phase>_ms_p50`` and
+        ``<phase>_ms_p95`` for every phase, sub-phase, ``other`` and
+        ``wall`` (and ``host``: wall minus the ``blocked_on`` phase)."""
         recs: List[Dict[str, float]] = list(self.records)
         if last_n is not None:
             recs = recs[-int(last_n):]
-        out = {f"{p}_ms": 0.0 for p in self.phases}
+        timed = self.phases + self.sub_phases
+        out = {f"{p}_ms": 0.0 for p in timed}
         out["wall_ms"] = 0.0
         out["steps"] = len(recs)
         out["steps_per_s"] = 0.0
@@ -184,8 +226,14 @@ class StepTimeline:
         if not recs:
             return out
         n = len(recs)
-        for p in self.phases:
-            out[f"{p}_ms"] = round(sum(r.get(p, 0.0) for r in recs) * 1000.0 / n, 3)
+        per_step = {p: [r.get(p, 0.0) for r in recs] for p in timed + ("wall",)}
+        for p in timed:
+            out[f"{p}_ms"] = round(sum(per_step[p]) * 1000.0 / n, 3)
+        if self.blocked_on is not None:
+            per_step["host"] = [r.get("wall", 0.0) - r.get(self.blocked_on, 0.0) for r in recs]
+        for p, vals in per_step.items():
+            for q, v in zip((50, 95), np.percentile(vals, (50, 95))):
+                out[f"{p}_ms_p{q}"] = round(float(v) * 1000.0, 3)
         for g in sorted(self._gauge_names):
             out[g] = round(sum(r.get(g, 0.0) for r in recs) / n, 3)
         wall = sum(r.get("wall", 0.0) for r in recs) / n

@@ -239,7 +239,15 @@ class ServingEngine:
 
         from deepspeed_tpu.runtime.overlap.timeline import StepTimeline
 
-        self.timeline = StepTimeline(enabled=True, phases=("sched", "prefill", "decode"))
+        # stage/dispatch/wait are timed inside prefill and decode (each
+        # summed over the step's two programs); ``wait`` is the blocking
+        # read, so wall - wait is the step's host overhead.  The phases
+        # also stand in the profiler's trace as ds.serve.* spans
+        # (docs/telemetry.md)
+        self.timeline = StepTimeline(
+            enabled=True, phases=("sched", "prefill", "decode"),
+            sub_phases=("stage", "dispatch", "wait"), blocked_on="wait", prefix="serve",
+        )
 
         # telemetry (docs/telemetry.md): attach to whatever plane the
         # process armed (the train engine's configure(), or an explicit
@@ -349,8 +357,8 @@ class ServingEngine:
                 )
 
             if self._paged:
-                def fn(params, toks, table, pos, take_idx, cow_src, cow_dst,
-                       flag, temp, topk, seed, k_pool, v_pool):
+                def serve_prefill(params, toks, table, pos, take_idx, cow_src, cow_dst,
+                                  flag, temp, topk, seed, k_pool, v_pool):
                     # the slot's pending copy-on-write lands BEFORE this
                     # chunk's writes: a traced (src, dst) page pair rides
                     # the request's first chunk ((0, 0) — garbage page
@@ -377,7 +385,8 @@ class ServingEngine:
 
                 donate = (11, 12)
             else:
-                def fn(params, toks, slot, pos, take_idx, flag, temp, topk, seed, k_pool, v_pool):
+                def serve_prefill(params, toks, slot, pos, take_idx, flag, temp, topk, seed,
+                                  k_pool, v_pool):
                     ks, vs = _take_slot(k_pool, slot), _take_slot(v_pool, slot)
                     # explicit clipped position ids: the zero-padded chunk
                     # tail must not clamp the wpe slice and shift real rows
@@ -403,7 +412,9 @@ class ServingEngine:
 
                 donate = (9, 10)
 
-            self._prefill_jit = jax.jit(self.engine._scoped(fn), donate_argnums=donate)
+            # the function's name is the program's in the profiler's
+            # trace: jit_serve_prefill on the device's "XLA Modules" line
+            self._prefill_jit = jax.jit(self.engine._scoped(serve_prefill), donate_argnums=donate)
             self._prefill_fn = self._wrap(self._prefill_jit, "serving.prefill")
             self.prefill_compiles += 1
             # ds_shard Pass 2 feed (no-op unless the audit armed it)
@@ -422,8 +433,8 @@ class ServingEngine:
             max_top_k = self.config.max_top_k
 
             if self._paged:
-                def fn(params, toks, pos, flags, temps, topks, seeds,
-                       page_table, write_mask, k_pool, v_pool):
+                def serve_decode(params, toks, pos, flags, temps, topks, seeds,
+                                 page_table, write_mask, k_pool, v_pool):
                     # per-slot page tables are traced values of the one
                     # fixed signature; write_mask redirects non-decoding
                     # slots' writes to the garbage page (pages.py)
@@ -442,7 +453,7 @@ class ServingEngine:
 
                 donate = (9, 10)
             else:
-                def fn(params, toks, pos, flags, temps, topks, seeds, k_pool, v_pool):
+                def serve_decode(params, toks, pos, flags, temps, topks, seeds, k_pool, v_pool):
                     # per-slot pos: slot-indexed cache write + position mask
                     # (ops/transformer/inference.py), auto-clipped position ids
                     logits, k_pool, v_pool = forward_with_cache(
@@ -461,7 +472,7 @@ class ServingEngine:
 
                 donate = (7, 8)
 
-            self._decode_jit = jax.jit(self.engine._scoped(fn), donate_argnums=donate)
+            self._decode_jit = jax.jit(self.engine._scoped(serve_decode), donate_argnums=donate)
             self._decode_fn = self._wrap(self._decode_jit, "serving.decode")
             self.decode_compiles += 1
             # ds_shard Pass 2 feed (no-op unless the audit armed it)
@@ -855,8 +866,14 @@ class ServingEngine:
         return self._step_once(admit=True)
 
     def _step_once(self, admit: bool) -> bool:
-        tl = self.timeline
         self._step_count += 1
+        # one span round the whole step, carrying its number, so that
+        # the phases' spans nest in it in the profiler's trace
+        with self.timeline.annotation("step", step=self._step_count):
+            return self._step_phases(admit)
+
+    def _step_phases(self, admit: bool) -> bool:
+        tl = self.timeline
         compiles0 = self.prefill_compiles + self.decode_compiles
         t0 = time.monotonic()
         if self._paged:
@@ -1221,40 +1238,43 @@ class ServingEngine:
         faults.check("serving.prefill")
         faults.check_latency("serving.prefill")
         san = self._sanitizer
+        tl = self.timeline
         fn = self._get_prefill()
         r = job.req
         # explicit staging of the host-side chunk + scalars onto the
         # serving mesh (transfer-guard clean: device_put is sanctioned,
         # and pre-placing on the mesh means the jit has nothing to move)
-        if self._paged:
-            cow_src, cow_dst = self.pool.consume_cow(r.slot)
-            staged = jax.device_put(
-                (job.tokens[None, :], self.pool.table(r.slot),
-                 np.int32(job.start), np.int32(job.take_idx),
-                 np.int32(cow_src), np.int32(cow_dst),
-                 np.bool_(r.do_sample), np.float32(r.temperature),
-                 np.int32(r.top_k), np.uint32(r.seed & 0xFFFFFFFF)),
-                self._replicated,
-            )
-        else:
-            staged = jax.device_put(
-                (job.tokens[None, :], np.int32(r.slot), np.int32(job.start),
-                 np.int32(job.take_idx), np.bool_(r.do_sample),
-                 np.float32(r.temperature), np.int32(r.top_k),
-                 np.uint32(r.seed & 0xFFFFFFFF)),
-                self._replicated,
-            )
+        with tl.phase("prefill.stage"):
+            if self._paged:
+                cow_src, cow_dst = self.pool.consume_cow(r.slot)
+                staged = jax.device_put(
+                    (job.tokens[None, :], self.pool.table(r.slot),
+                     np.int32(job.start), np.int32(job.take_idx),
+                     np.int32(cow_src), np.int32(cow_dst),
+                     np.bool_(r.do_sample), np.float32(r.temperature),
+                     np.int32(r.top_k), np.uint32(r.seed & 0xFFFFFFFF)),
+                    self._replicated,
+                )
+            else:
+                staged = jax.device_put(
+                    (job.tokens[None, :], np.int32(r.slot), np.int32(job.start),
+                     np.int32(job.take_idx), np.bool_(r.do_sample),
+                     np.float32(r.temperature), np.int32(r.top_k),
+                     np.uint32(r.seed & 0xFFFFFFFF)),
+                    self._replicated,
+                )
         tracer = self.telemetry.tracer if self.telemetry.tracer.enabled else None
         t0 = tracer.now() if tracer is not None else 0.0
         guard = san.transfer.guard("serving.prefill") if san is not None else nullcontext()
-        with guard:
+        with tl.phase("prefill.dispatch"), guard:
             first, k, v = fn(
                 self.engine.params, *staged, self.pool.k, self.pool.v,
             )
         self.pool.swap(k, v)
         # explicit d2h read doubles as the fence that keeps prefill_ms
         # honest; the value is the first generated token on final chunks
-        tok = int(jax.device_get(first))
+        with tl.phase("prefill.wait"):
+            tok = int(jax.device_get(first))
         now = time.monotonic()
         if self._paged and job.final:
             # the whole prompt's KV is paged in: learn it as a shared
@@ -1278,30 +1298,33 @@ class ServingEngine:
         faults.check("serving.decode")
         faults.check_latency("serving.decode")
         san = self._sanitizer
+        tl = self.timeline
         fn = self._get_decode()
-        flags, temps, topks, seeds = self.scheduler.sampling_inputs()
-        if self._paged:
-            # non-decoding slots write to the garbage page; their reads
-            # were already safe behind the position mask
-            wmask = np.zeros((self.pool.num_slots,), np.bool_)
-            for r in decoding:
-                wmask[r.slot] = True
-            staged = jax.device_put(
-                (toks, pos, flags, temps, topks, seeds,
-                 self.pool.tables(), wmask),
-                self._replicated,
-            )
-        else:
-            staged = jax.device_put(
-                (toks, pos, flags, temps, topks, seeds), self._replicated
-            )
+        with tl.phase("decode.stage"):
+            flags, temps, topks, seeds = self.scheduler.sampling_inputs()
+            if self._paged:
+                # non-decoding slots write to the garbage page; their reads
+                # were already safe behind the position mask
+                wmask = np.zeros((self.pool.num_slots,), np.bool_)
+                for r in decoding:
+                    wmask[r.slot] = True
+                staged = jax.device_put(
+                    (toks, pos, flags, temps, topks, seeds,
+                     self.pool.tables(), wmask),
+                    self._replicated,
+                )
+            else:
+                staged = jax.device_put(
+                    (toks, pos, flags, temps, topks, seeds), self._replicated
+                )
         guard = san.transfer.guard("serving.decode") if san is not None else nullcontext()
-        with guard:
+        with tl.phase("decode.dispatch"), guard:
             nxt, k, v = fn(
                 self.engine.params, *staged, self.pool.k, self.pool.v,
             )
         self.pool.swap(k, v)
-        out = np.asarray(jax.device_get(nxt))
+        with tl.phase("decode.wait"):
+            out = np.asarray(jax.device_get(nxt))
         now = time.monotonic()
         self.scheduler.note_decode(
             {r.slot: int(out[r.slot]) for r in decoding}, now, self._step_count
