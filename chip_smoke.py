@@ -64,6 +64,9 @@ class Smoke:
     prompt_lens: Tuple[int, int]
     new_tokens: int
     requests: int
+    # one more leaf for the fused update's check, of the shape class the
+    # benchmark's one-chip train cell holds (GPT-2 Large's fc_w, two layers)
+    update_leaf: Tuple[int, ...] = (2, 1280, 5120)
     # True: the Pallas kernels are compiled by Mosaic and must show in
     # the optimized HLO.  False (CPU control-flow test): the kernel suite
     # is not armed and no executable may hold a Mosaic call.
@@ -145,6 +148,30 @@ def gathered_float_shapes(hlo: str) -> List[Tuple[int, ...]]:
     for m in _GATHER_RE.finditer(hlo):
         out += [tuple(int(d) for d in dims.split(",")) for _, dims in _SHAPE_RE.findall(m.group(1))]
     return out
+
+
+_INSTR_RE = re.compile(r"^\s*(?:ROOT )?%?\S+ = (.*?)\s([a-z][a-z\-]*)\(", re.M)
+# what may hold an array without moving it
+_NO_MOVE = ("parameter", "get-tuple-element", "bitcast", "tuple")
+
+
+def leaf_sized_moves(hlo: str, elems: int) -> List[str]:
+    """Opcodes of the instructions, anywhere in an optimized HLO module,
+    that produce an array of ``elems`` elements and are neither a Mosaic
+    call nor free: each is one more pass over a leaf of that size (a
+    ``copy``, ``reshape``, ``transpose`` or fusion round a kernel that
+    was handed a view its buffers do not have)."""
+    out = []
+    for m in _INSTR_RE.finditer(hlo):
+        sizes = [math.prod(int(d) for d in dims.split(",")) for _, dims in _SHAPE_RE.findall(m.group(1))]
+        line = hlo[m.start():hlo.find("\n", m.end())]
+        if elems in sizes and m.group(2) not in _NO_MOVE and "tpu_custom_call" not in line:
+            out.append(m.group(2))
+    return out
+
+
+# the Mosaic calls an executable of the fused update must hold, by optimizer
+UPDATE_KERNELS = {"adam": ["fused_adam"], "lamb": ["fused_lamb_dir", "fused_lamb_apply"]}
 
 
 def expect_kernels(found: Dict[str, int], expected: Sequence[str], where: str) -> None:
@@ -230,14 +257,18 @@ def check_flash_decode_paged(s: Smoke, mcfg, kv_dtype) -> float:
 
 def check_fused_update(s: Smoke) -> Dict[str, float]:
     """The fused Adam and LAMB kernels vs the XLA update the engine runs
-    without them, on leaves of the trainer's shapes: one stacked weight
-    (the Pallas path) and one ragged bias (the XLA leaf path)."""
+    without them, on leaves of the trainer's shapes — one stacked weight
+    (the Pallas path) and one ragged bias (the XLA leaf path) — and one
+    of the benchmark's train cell.  The executable, compiled as the
+    engine compiles it (state and parameters donated), must hold the
+    kernels and nothing else that touches a weight leaf."""
     from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
     from deepspeed_tpu.ops.kernels.fused_update import engine_update
     from deepspeed_tpu.ops.lamb.fused_lamb import FusedLamb
 
     c = s.train_cfg
-    shapes = {"qkv_w": (c.n_layer, c.n_embd, 3 * c.n_embd), "qkv_b": (c.n_layer, 3 * c.n_embd - 1)}
+    shapes = {"qkv_w": (c.n_layer, c.n_embd, 3 * c.n_embd), "qkv_b": (c.n_layer, 3 * c.n_embd - 1),
+              "cell_fc_w": s.update_leaf}
     keys = iter(jax.random.split(jax.random.PRNGKey(s.seed + 2), 2 * len(shapes)))
     params = {n: 0.02 * jax.random.normal(next(keys), sh, jnp.float32) for n, sh in shapes.items()}
     grads = {n: 1e-3 * jax.random.normal(next(keys), sh, jnp.float32) for n, sh in shapes.items()}
@@ -269,9 +300,14 @@ def check_fused_update(s: Smoke) -> Dict[str, float]:
             float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))) for a, b in zip(parts(got), parts(want))
         )
         if s.mosaic:
-            hlo = jax.jit(fused).lower(grads, state, params).compile().as_text()
-            expect_kernels(mosaic_kernels(hlo), {"adam": ["fused_adam"], "lamb": ["fused_lamb_dir", "fused_lamb_apply"]}[name],
-                           f"fused_update[{name}]")
+            # outputs in the order of the donated inputs, as the engine's
+            # state is: jit pairs donated buffers with results by position
+            hlo = (jax.jit(lambda g, st, p: fused(g, st, p)[::-1], donate_argnums=(1, 2))
+                   .lower(grads, state, params).compile().as_text())
+            expect_kernels(mosaic_kernels(hlo), UPDATE_KERNELS[name], f"fused_update[{name}]")
+            for leaf in ("qkv_w", "cell_fc_w"):
+                moves = leaf_sized_moves(hlo, math.prod(shapes[leaf]))
+                check(not moves, f"fused_update[{name}]: {moves} of {leaf}'s size {shapes[leaf]} beside the kernels")
     say("fused_update vs XLA update: " + " ".join(f"{n}={e:.2e}" for n, e in errs.items()))
     check(max(errs.values()) < TOL_UPDATE, f"fused update off the XLA update by {errs} (tolerance {TOL_UPDATE})")
     return errs

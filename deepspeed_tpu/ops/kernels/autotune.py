@@ -122,8 +122,6 @@ def default_blocks(kind: str, **key: Any) -> Dict[str, int]:
       byte so it takes the larger block a step earlier), ``block_slots``
       groups pool slots per program when the pool is wide and the
       context short (program-count bound).
-    * ``fused_update``: flat-leaf rows per program; memory-bound, so
-      one size class.
     * ``flash_attention``: the measured (512, 512) train-step winner
       (see flash_attention.py block_q/block_k docstring).
     """
@@ -140,8 +138,6 @@ def default_blocks(kind: str, **key: Any) -> Dict[str, int]:
                     block_slots = cand
                     break
         return {"block_k": block_k, "block_slots": block_slots}
-    if kind == "fused_update":
-        return {"block_rows": 256}
     if kind == "flash_attention":
         sq = int(key.get("sq", 512))
         sk = int(key.get("sk", sq))
@@ -165,8 +161,6 @@ def candidate_blocks(kind: str, **key: Any) -> List[Dict[str, int]]:
         for bk in ks:
             for bs in slots:
                 out.append({"block_k": bk, "block_slots": bs})
-    elif kind == "fused_update":
-        out = [{"block_rows": r} for r in (128, 256, 512, 1024)]
     elif kind == "flash_attention":
         sq, sk = int(key.get("sq", 512)), int(key.get("sk", 512))
         qs = sorted({_divisor_floor(sq, p) for p in (256, 512, 1024)})

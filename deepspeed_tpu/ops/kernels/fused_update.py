@@ -15,11 +15,13 @@ happen in-register between the two.
 
 Three executors share one update body:
 
-* **Pallas** (:func:`_adam_pallas_leaf`) — lane-aligned leaves
-  (``size % 256 == 0``, the transformer weight matrices that carry
-  ~all the bytes);
-* **XLA** (:func:`_adam_math`) — ragged/tiny leaves (biases,
-  layernorms) where a padding copy would cost more than it saves;
+* **Pallas** (:func:`_adam_pallas_leaf`) — leaves whose last two
+  dimensions fill whole tiles (:func:`_leaf_view`: the transformer
+  weight matrices that carry ~all the bytes), each read **in the
+  layout its buffers already have**;
+* **XLA** (:func:`_adam_math`) — ragged/tiny leaves (vectors, stacked
+  biases, an odd-row embedding) where a padding copy would cost more
+  than it saves;
 * **host numpy** — ``ops/adam/cpu_adam.py``'s fallback calls
   :func:`adam_update_reference` with ``xp=numpy``, so the
   ZeRO-Offload/Infinity drain steps the exact same formulas (the
@@ -41,7 +43,8 @@ the XLA path's four-plus.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional, Tuple
+import math
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -51,9 +54,12 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.registry import register_op
 from deepspeed_tpu.utils.device import pallas_interpret_default
 
-_COLS = 256           # lane-aligned row width for the flattened leaf view
-_MIN_ROWS = 8         # below this the grid overhead beats the fusion win
-_PART_TILE = (8, 128)  # fp32 VMEM tile: the smallest block Mosaic writes
+_LANES = 128
+_PART_TILE = (8, _LANES)  # fp32 VMEM tile: the smallest block Mosaic writes
+# most elements of one block.  Seven leaf-sized operands, each double-
+# buffered, stand in VMEM at once: 56 B an element, 5.5 MB of the 16 MB
+# a v5e kernel may use.
+_BLOCK_ELEMS = 96 * 1024
 
 
 # ---------------------------------------------------------------------------
@@ -141,31 +147,56 @@ def _adam_kernel(scal_ref, p_ref, g_ref, m_ref, v_ref, po_ref, mo_ref, vo_ref,
     vo_ref[:] = v_new
 
 
-def _leaf_grid(n: int, block_rows: int) -> Optional[Tuple[int, int]]:
-    """(rows, block_rows) for the flattened (rows, _COLS) leaf view, or
-    None when the leaf is ragged/tiny (XLA path; a pad would cost a
-    full extra read+write — exactly the traffic this kernel removes)."""
-    if n % _COLS:
+def _leaf_view(shape, dtypes):
+    """The view the kernels take of a leaf, from its shape and dtypes:
+    ``((lead, rows, cols), (block_rows, block_cols))``, or None when the
+    leaf is ragged/tiny (XLA path; a pad would cost a full extra
+    read+write — exactly the traffic this kernel removes).
+
+    The TPU stores an array in (sublanes, 128) tiles over its last two
+    dimensions — 8 rows of float32, 16 of bf16 — so a view is free only
+    while it leaves those two alone: ``rows`` and ``cols`` are the
+    leaf's own, the dimensions before them fold into ``lead``, which the
+    grid walks one entry at a time.  (A ``(size // 256, 256)`` view of a
+    ``(…, 5120)`` leaf is a relayout: one more read and write of every
+    operand and result, around a kernel whose point is one pass.)
+    A block is whole tiles, as many rows of the whole last dimension as
+    ``_BLOCK_ELEMS`` holds — ``(16, 5120)``, ``(64, 1280)``; a last
+    dimension too wide for even one tile-row of that is split on a
+    128-multiple, hence the grid's third axis."""
+    if len(shape) < 2:
         return None
-    rows = n // _COLS
-    if rows < _MIN_ROWS:
+    rows, cols = shape[-2:]
+    sublanes = max(32 // jnp.dtype(d).itemsize for d in dtypes)
+    if cols % _LANES or rows % sublanes or not rows * cols:
         return None
-    b = min(block_rows, rows)
-    while b > _MIN_ROWS and rows % b:
-        b //= 2
-    if rows % b:
-        return None
-    return rows, b
+
+    def largest_block(n, unit, most):
+        # unit divides n and most >= unit, so there is always one
+        return max(b for b in range(unit, min(n, most) + 1, unit) if n % b == 0)
+
+    bc = largest_block(cols, _LANES, _BLOCK_ELEMS // sublanes)
+    br = largest_block(rows, sublanes, _BLOCK_ELEMS // bc)
+    return (math.prod(shape[:-2]), rows, cols), (br, bc)
 
 
-def _adam_pallas_leaf(p, g, m, v, scal, *, b1, b2, eps, weight_decay,
-                      adam_w_mode, block_rows, interpret):
-    n = p.size
-    rows, br = _leaf_grid(n, block_rows)
-    shape2 = (rows, _COLS)
-    p2, g2, m2, v2 = (t.reshape(shape2) for t in (p, g, m, v))
-    grid = (rows // br,)
-    blk = pl.BlockSpec((br, _COLS), lambda i: (i, 0))
+def _leaf_specs(view):
+    """(grid, leaf block spec, norm-partial block spec) of a
+    :func:`_leaf_view`: the leading dimension is squeezed out of the
+    kernel's refs, so the bodies see ``(block_rows, block_cols)``; each
+    block has one norm-partial tile, in the grid's order."""
+    (lead, rows, cols), (br, bc) = view
+    nr, nc = rows // br, cols // bc
+    blk = pl.BlockSpec((None, br, bc), lambda l, i, j: (l, i, j))
+    part = pl.BlockSpec(_PART_TILE, lambda l, i, j: ((l * nr + i) * nc + j, 0))
+    return (lead, nr, nc), blk, part
+
+
+def _adam_pallas_leaf(p, g, m, v, scal, *, view, b1, b2, eps,
+                      weight_decay, adam_w_mode, interpret):
+    shape3 = view[0]
+    p3, g3, m3, v3 = (t.reshape(shape3) for t in (p, g, m, v))
+    grid, blk, _ = _leaf_specs(view)
     po, mo, vo = pl.pallas_call(
         functools.partial(
             _adam_kernel, b1=b1, b2=b2, eps=eps,
@@ -175,15 +206,15 @@ def _adam_pallas_leaf(p, g, m, v, scal, *, b1, b2, eps, weight_decay,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), blk, blk, blk, blk],
         out_specs=[blk, blk, blk],
         out_shape=[
-            jax.ShapeDtypeStruct(shape2, p.dtype),
-            jax.ShapeDtypeStruct(shape2, jnp.float32),
-            jax.ShapeDtypeStruct(shape2, jnp.float32),
+            jax.ShapeDtypeStruct(shape3, p.dtype),
+            jax.ShapeDtypeStruct(shape3, jnp.float32),
+            jax.ShapeDtypeStruct(shape3, jnp.float32),
         ],
         # true in-place: p/m/v buffers are consumed by their updates
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
         name="fused_adam",
-    )(scal, p2, g2, m2, v2)
+    )(scal, p3, g3, m3, v3)
     return po.reshape(p.shape), mo.reshape(p.shape), vo.reshape(p.shape)
 
 
@@ -238,34 +269,30 @@ def _lamb_apply_kernel(scal_ref, p_ref, dir_ref, trust_ref, po_ref):
     po_ref[:] = (p32 + upd).astype(po_ref.dtype)
 
 
-def _lamb_pallas_leaf(p, g, m, v, scal, *, b1, b2, eps, weight_decay,
-                      min_coeff, max_coeff, block_rows, interpret):
-    n = p.size
-    rows, br = _leaf_grid(n, block_rows)
-    shape2 = (rows, _COLS)
-    p2, g2, m2, v2 = (t.reshape(shape2) for t in (p, g, m, v))
-    nblk = rows // br
-    blk = pl.BlockSpec((br, _COLS), lambda i: (i, 0))
-    part = pl.BlockSpec(_PART_TILE, lambda i: (i, 0))
-    part_shape = jax.ShapeDtypeStruct((nblk * _PART_TILE[0], _PART_TILE[1]), jnp.float32)
-    d2, mo, vo, wsq, dsq = pl.pallas_call(
+def _lamb_pallas_leaf(p, g, m, v, scal, *, view, b1, b2, eps,
+                      weight_decay, min_coeff, max_coeff, interpret):
+    shape3 = view[0]
+    p3, g3, m3, v3 = (t.reshape(shape3) for t in (p, g, m, v))
+    grid, blk, part = _leaf_specs(view)
+    part_shape = jax.ShapeDtypeStruct((math.prod(grid) * _PART_TILE[0], _PART_TILE[1]), jnp.float32)
+    d3, mo, vo, wsq, dsq = pl.pallas_call(
         functools.partial(
             _lamb_dir_kernel, b1=b1, b2=b2, eps=eps, weight_decay=weight_decay,
         ),
-        grid=(nblk,),
+        grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), blk, blk, blk, blk],
         out_specs=[blk, blk, blk, part, part],
         out_shape=[
-            jax.ShapeDtypeStruct(shape2, jnp.float32),
-            jax.ShapeDtypeStruct(shape2, jnp.float32),
-            jax.ShapeDtypeStruct(shape2, jnp.float32),
+            jax.ShapeDtypeStruct(shape3, jnp.float32),
+            jax.ShapeDtypeStruct(shape3, jnp.float32),
+            jax.ShapeDtypeStruct(shape3, jnp.float32),
             part_shape,
             part_shape,
         ],
         input_output_aliases={3: 1, 4: 2},
         interpret=interpret,
         name="fused_lamb_dir",
-    )(scal, p2, g2, m2, v2)
+    )(scal, p3, g3, m3, v3)
     # one representative element per block tile
     wsq, dsq = (t[:: _PART_TILE[0], 0] for t in (wsq, dsq))
     trust = _lamb_trust(
@@ -273,17 +300,17 @@ def _lamb_pallas_leaf(p, g, m, v, scal, *, b1, b2, eps, weight_decay,
     ).reshape(1)
     po = pl.pallas_call(
         _lamb_apply_kernel,
-        grid=(nblk,),
+        grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM), blk, blk,
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=blk,
-        out_shape=jax.ShapeDtypeStruct(shape2, p.dtype),
+        out_shape=jax.ShapeDtypeStruct(shape3, p.dtype),
         input_output_aliases={1: 0},
         interpret=interpret,
         name="fused_lamb_apply",
-    )(scal, p2, d2, trust)
+    )(scal, p3, d3, trust)
     return po.reshape(p.shape), mo.reshape(p.shape), vo.reshape(p.shape)
 
 
@@ -307,7 +334,8 @@ def _lamb_math(p, g, m, v, lr, keep, c1, c2, *, b1, b2, eps, weight_decay,
 # ---------------------------------------------------------------------------
 
 def engine_update(optimizer, grads, opt_state, params, lr, overflow,
-                  interpret: Optional[bool] = None):
+                  interpret: Optional[bool] = None,
+                  split: Optional[Dict[str, int]] = None):
     """The ``_apply_update_unscaled`` seam: returns
     ``(new_params, new_opt_state)`` with the fused-kernel treatment, or
     None when this optimizer/state isn't kernel-eligible (the caller
@@ -315,9 +343,10 @@ def engine_update(optimizer, grads, opt_state, params, lr, overflow,
     FusedAdamW with fp32 state (8-bit/bf16 states keep their SR
     machinery on XLA), and FusedLamb.  Overflow folds in-producer:
     skipped steps write back old state + unchanged params in the same
-    single pass."""
+    single pass.  ``split``, when given, is handed back the elements
+    that went each way (``pallas_elems`` / ``xla_elems``, counted while
+    tracing): whether a model's state takes the one-pass path."""
     from deepspeed_tpu.ops.adam.fused_adam import AdamState, FusedAdam
-    from deepspeed_tpu.ops.kernels.autotune import get_autotuner
     from deepspeed_tpu.ops.lamb.fused_lamb import FusedLamb, LambState
 
     if interpret is None:
@@ -348,35 +377,26 @@ def engine_update(optimizer, grads, opt_state, params, lr, overflow,
         jnp.asarray(c1, jnp.float32), jnp.asarray(c2, jnp.float32),
     ])
 
-    block_rows = get_autotuner().blocks_for("fused_update")["block_rows"]
-    n_pallas = 0
-    n_xla = 0
+    if split is None:
+        split = {}
+    split.update(pallas_elems=0, xla_elems=0)
 
     def one(g, m, v, p):
-        nonlocal n_pallas, n_xla
         common = dict(b1=b1, b2=b2, eps=optimizer.eps,
                       weight_decay=optimizer.weight_decay)
-        eligible = _leaf_grid(p.size, block_rows) is not None
         if is_adam:
             common["adam_w_mode"] = optimizer.adam_w_mode
-            if eligible:
-                n_pallas += 1
-                return _adam_pallas_leaf(
-                    p, g, m, v, scal, block_rows=block_rows,
-                    interpret=interpret, **common,
-                )
-            n_xla += 1
-            return _adam_math(p, g, m, v, lr, keep, c1, c2, **common)
-        common["min_coeff"] = optimizer.min_coeff
-        common["max_coeff"] = optimizer.max_coeff
-        if eligible:
-            n_pallas += 1
-            return _lamb_pallas_leaf(
-                p, g, m, v, scal, block_rows=block_rows,
-                interpret=interpret, **common,
-            )
-        n_xla += 1
-        return _lamb_math(p, g, m, v, lr, keep, c1, c2, **common)
+            pallas_leaf, xla_leaf = _adam_pallas_leaf, _adam_math
+        else:
+            common["min_coeff"] = optimizer.min_coeff
+            common["max_coeff"] = optimizer.max_coeff
+            pallas_leaf, xla_leaf = _lamb_pallas_leaf, _lamb_math
+        view = _leaf_view(p.shape, (p.dtype, g.dtype))
+        if view is None:
+            split["xla_elems"] += p.size
+            return xla_leaf(p, g, m, v, lr, keep, c1, c2, **common)
+        split["pallas_elems"] += p.size
+        return pallas_leaf(p, g, m, v, scal, view=view, interpret=interpret, **common)
 
     from deepspeed_tpu.ops.adam.fused_adam import _map_multi
 

@@ -489,6 +489,10 @@ class DeepSpeedEngine:
         self._compiled = {}
         self._train_executable = None
         self._train_step_cost: Dict[str, float] = {}
+        # elements the fused update gave the Pallas kernels and the XLA
+        # leaf path in the step traced last (empty: the update is XLA's);
+        # engine_update fills it while the step is traced
+        self._fused_update_split: Dict[str, int] = {}
         self.skipped_steps = 0
         # Host-side mirror of state["global_step"].  Reading the device
         # scalar blocks the host until every step dispatched so far has
@@ -829,6 +833,20 @@ class DeepSpeedEngine:
         state["grad_acc"] = jax.tree.map(jnp.zeros_like, state["grad_acc"])
         return state, info
 
+    def _note_update_split(self, span) -> None:
+        """Inside a ``ds.train.compile`` span, after the step has been
+        traced: how many of the optimizer's elements take the one-pass
+        kernels (docs/kernels.md), as the span's arguments and one line
+        of the log."""
+        split = self._fused_update_split
+        if not split:
+            return
+        span.set_metadata(**{f"fused_update_{k}": v for k, v in split.items()})
+        log_dist(
+            f"kernels: fused_update takes {split['pallas_elems']:,} elements in one "
+            f"pass a leaf, {split['xla_elems']:,} stay on the XLA leaf path"
+        )
+
     def _apply_update(self, state, grads):
         """Unscale/clip/update given already-averaged grads (shared by the
         grad-accumulation path and the pipeline engine's fused batch)."""
@@ -888,8 +906,11 @@ class DeepSpeedEngine:
             else:
                 from deepspeed_tpu.ops.kernels.fused_update import engine_update
 
+                # split: counted in trace-time Python, read by
+                # _note_update_split once the step being traced has compiled
                 fused = engine_update(
-                    self.optimizer, grads, state["opt_state"], state["params"], lr, overflow
+                    self.optimizer, grads, state["opt_state"], state["params"], lr, overflow,
+                    split=self._fused_update_split,
                 )
         if fused is not None:
             new_params, new_opt = fused
@@ -1749,12 +1770,13 @@ class DeepSpeedEngine:
             # trace: jit_train_step on the devices' "XLA Modules" lines
             train_step = self._scoped(full_step)
             train_step.__name__ = "train_step"
-            with self.timeline.phase("compile"):
+            with self.timeline.phase("compile") as span:
                 executable = (
                     jax.jit(train_step, donate_argnums=(0,), out_shardings=out_sh)
                     .lower(self.state, stacked)
                     .compile()
                 )
+                self._note_update_split(span)
             self._compiled[tb_key] = executable
             self._train_executable = executable
             self.compilation_count += 1
@@ -1929,7 +1951,7 @@ class DeepSpeedEngine:
                 return state, losses, jnp.sum(ovf.astype(jnp.int32)), lrs[-1], gns[-1]
 
             scalar = self._sh(P())
-            with self.timeline.phase("compile"):
+            with self.timeline.phase("compile") as span:
                 self._compiled[key] = (
                     jax.jit(
                         self._scoped(full_run), donate_argnums=(0,),
@@ -1938,6 +1960,7 @@ class DeepSpeedEngine:
                     .lower(self.state, run)
                     .compile()
                 )
+                self._note_update_split(span)
             self.compilation_count += 1
             if san is not None:
                 san.recompile.note("engine.train_batches", (self.state, run), owner=id(self))

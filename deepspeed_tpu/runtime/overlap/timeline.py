@@ -130,10 +130,12 @@ class StepTimeline:
         """Time a host block and note it under ``name`` — a sub-phase
         ``outer.inner`` under ``inner`` — and annotate it in the
         profiler's trace (and, a phase only, as a Chrome-trace span when
-        the attached telemetry plane has tracing armed)."""
-        with self.annotation(name):
+        the attached telemetry plane has tracing armed).  Yields the
+        annotation: ``set_metadata(**args)`` on it adds arguments that
+        are known only once the block has run."""
+        with self.annotation(name) as span:
             if not self.enabled:
-                yield
+                yield span
                 return
             key = name.rpartition(".")[2]
             tm = self._telemetry
@@ -141,7 +143,7 @@ class StepTimeline:
             t0m = tracer.now() if tracer is not None else 0.0
             t0 = time.perf_counter()
             try:
-                yield
+                yield span
             finally:
                 dt = time.perf_counter() - t0
                 self.note(key, dt)

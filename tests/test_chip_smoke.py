@@ -101,6 +101,45 @@ def test_fused_update_lowers_for_tpu_at_smoke_shapes(opt_name):
     )
 
 
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a described, not attached, v5e: libtpu compiles for
+    it here.  Described inside a fixture and in this file only — a
+    process keeps libtpu once it has loaded it."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e topology can be described here: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("opt_name", ["adam", "lamb"])
+def test_fused_update_compiles_for_v5e_with_no_pass_beside_the_kernels(opt_name, v5e_chip):
+    """The check chip_smoke makes on the chip, made by the chip's
+    compiler without the chip: Mosaic takes the blocks the leaf's shape
+    gives, and XLA puts no copy, reshape or transpose of a weight leaf
+    round the calls (the (rows, 256) view cost seven: PERF.md, PR 27)."""
+    from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
+    from deepspeed_tpu.ops.kernels.fused_update import engine_update
+    from deepspeed_tpu.ops.lamb.fused_lamb import FusedLamb
+
+    opt = FusedAdam(lr=1e-4) if opt_name == "adam" else FusedLamb(lr=1e-4)
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=v5e_chip), t)
+    tree = on_chip({"cell_fc_w": _sds(FULL.update_leaf, jnp.float32)})
+    state = on_chip(jax.eval_shape(opt.init, tree))
+    hlo = (
+        jax.jit(
+            lambda g, st, p: engine_update(opt, g, st, p, jnp.float32(1e-4), None, interpret=False)[::-1],
+            donate_argnums=(1, 2),
+        ).lower(tree, state, tree).compile().as_text()
+    )
+    chip_smoke.expect_kernels(chip_smoke.mosaic_kernels(hlo), chip_smoke.UPDATE_KERNELS[opt_name], opt_name)
+    assert chip_smoke.leaf_sized_moves(hlo, int(np.prod(FULL.update_leaf))) == []
+
+
 # ---------------------------------------------------------------------------
 # (b) the smoke's control flow, and its refusal to run off a TPU
 # ---------------------------------------------------------------------------
@@ -112,7 +151,7 @@ TOY = chip_smoke.Smoke(
     ),
     seq=128, micro=2, global_batch=8, steps=2,
     serve_model="tiny", slots=2, max_len=64, page_len=16, prefill_chunk=16,
-    prompt_lens=(4, 40), new_tokens=4, requests=3, mosaic=False,
+    prompt_lens=(4, 40), new_tokens=4, requests=3, update_leaf=(2, 128, 512), mosaic=False,
 )
 
 
@@ -127,11 +166,20 @@ _HLO = """
   %custom-call.3 = bf16[4,16]{1,0} custom-call(%x), custom_call_target="ConcatBitcast"
   %all-gather-start.2 = (bf16[4,1024,1024]{2,1,0}, bf16[16,1024,1024]{2,1,0}) all-gather-start(%y), dimensions={0}
   %all-gather.5 = s32[16,1024,1]{1,2,0} all-gather(%ids), dimensions={0}
+  %p.1 = f32[36,1280,5120]{2,1,0:T(8,128)} parameter(3), metadata={op_name="p"}
+  %reshape.98.remat2 = f32[921600,256]{1,0:T(8,128)} reshape(%p.1), metadata={op_name="jit(f)/reshape"}
+  %fused_adam.14 = (f32[921600,256]{1,0:T(8,128)}, f32[921600,256]{1,0:T(8,128)}) custom-call(%s, %reshape.98.remat2), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[4]{0}, f32[921600,256]{1,0}}
+  %pallas_call.58 = f32[921600,256]{1,0:T(8,128)} get-tuple-element(%fused_adam.14), index=1
+  %bitcast.9 = f32[1,46080,5120]{2,1,0:T(8,128)} bitcast(%pallas_call.58)
+  ROOT %copy.115 = f32[36,1280,5120]{2,1,0:T(8,128)S(1)} copy(%bitcast.9), backend_config={"flag_configs":[]}
 """
 
 
 def test_smoke_reads_kernels_and_gathers_from_optimized_hlo():
-    assert chip_smoke.mosaic_kernels(_HLO) == {"flash_attention_fwd": 1, "fused_adam": 2}
+    assert chip_smoke.mosaic_kernels(_HLO) == {"flash_attention_fwd": 1, "fused_adam": 3}
+    # a pass over a leaf beside the kernel that was meant to be the only one
+    assert chip_smoke.leaf_sized_moves(_HLO, 36 * 1280 * 5120) == ["reshape", "copy"]
+    assert chip_smoke.leaf_sized_moves(_HLO, 180 * 256) == []
     assert chip_smoke.first_output_dims(_HLO, "flash_attention_fwd") == (64, 1024, 64)
     assert chip_smoke.gathered_float_shapes(_HLO) == [(4, 1024, 1024), (16, 1024, 1024)]
     chip_smoke.expect_kernels(chip_smoke.mosaic_kernels(_HLO), ["fused_adam", "flash_attention_fwd"], "t")

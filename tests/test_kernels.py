@@ -16,6 +16,7 @@ body, so the parity statements carry to hardware modulo MXU rounding.
 """
 import dataclasses
 import json
+import math
 import os
 
 import jax
@@ -153,15 +154,19 @@ def _grads_like(params, seed=1):
     )
 
 
-@pytest.mark.parametrize("opt_kind", ["adamw", "adam_l2", "lamb"])
-def test_fused_update_trajectory_parity(opt_kind):
+def _update_opt(opt_kind):
     from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
     from deepspeed_tpu.ops.lamb.fused_lamb import FusedLamb
 
     if opt_kind == "lamb":
-        opt = FusedLamb(lr=1e-2, weight_decay=0.01)
-    else:
-        opt = FusedAdam(lr=1e-2, weight_decay=0.01, adam_w_mode=(opt_kind == "adamw"))
+        return FusedLamb(lr=1e-2, weight_decay=0.01)
+    # "adamw" decouples the decay; any other name ("adam", "adam_l2") adds it to the gradient
+    return FusedAdam(lr=1e-2, weight_decay=0.01, adam_w_mode=(opt_kind == "adamw"))
+
+
+@pytest.mark.parametrize("opt_kind", ["adamw", "adam_l2", "lamb"])
+def test_fused_update_trajectory_parity(opt_kind):
+    opt = _update_opt(opt_kind)
     params = _tree()
     grads = _grads_like(params)
     st_ref, p_ref = opt.init(params), params
@@ -207,6 +212,113 @@ def test_fused_update_ineligible_optimizers_return_none():
     assert fu.engine_update(sgd, grads, sgd.init(params), params, 1e-2, None) is None
     a8 = FusedAdam(lr=1e-2, state_precision="8bit")
     assert fu.engine_update(a8, grads, a8.init(params), params, 1e-2, None) is None
+
+
+# the leaf shapes the two train configurations hold (GPT-2 Large and XL:
+# fc_w, fc_proj_w, qkv_w, proj_w, a stacked bias, the odd-row
+# embedding), scaled down with the ratios kept; True where the rule of
+# docs/kernels.md gives the leaf to the Pallas kernels
+_L, _D, _V = 2, 128, 257
+_VIEW_LEAVES = {
+    "L_D_4D": ((_L, _D, 4 * _D), True),
+    "L_4D_D": ((_L, 4 * _D, _D), True),
+    "L_D_3D": ((_L, _D, 3 * _D), True),
+    "L_D_D": ((_L, _D, _D), True),
+    "L_3D_bias": ((_L, 3 * _D), False),
+    "V_D_odd_rows": ((_V, _D), False),
+}
+
+
+@pytest.mark.parametrize("overflow", [False, True], ids=["step", "overflow"])
+@pytest.mark.parametrize("opt_kind", ["adam", "adamw", "lamb"])
+@pytest.mark.parametrize("leaf", list(_VIEW_LEAVES))
+def test_fused_update_leaf_view_parity(leaf, opt_kind, overflow, monkeypatch):
+    """engine_update against the XLA update, leaf shape by leaf shape,
+    with a block budget small enough that the grid has all three axes
+    (lead, row blocks, split columns)."""
+    monkeypatch.setattr(fu, "_BLOCK_ELEMS", 8 * 256)
+    shape, on_kernel = _VIEW_LEAVES[leaf]
+    opt = _update_opt(opt_kind)
+    params = {"x": _rand(shape, seed=4)}
+    grads = {"x": _rand(shape, seed=5)}
+    # moments of a step already taken, so the kernels read non-zero state
+    _, st = opt.update(grads, opt.init(params), params, lr=jnp.float32(1e-2))
+    split = {}
+    if overflow:
+        bad = {"x": grads["x"].at[(0,) * len(shape)].set(jnp.inf)}
+        p_k, st_k = fu.engine_update(
+            opt, bad, st, params, jnp.float32(1e-2), jnp.bool_(True), interpret=True, split=split
+        )
+        assert bool(jnp.all(p_k["x"] == params["x"]))
+        assert bool(jnp.all(st_k.exp_avg["x"] == st.exp_avg["x"]))
+        assert bool(jnp.all(st_k.exp_avg_sq["x"] == st.exp_avg_sq["x"]))
+        assert int(st_k.step) == int(st.step)
+    else:
+        upd, st_ref = opt.update(grads, st, params, lr=jnp.float32(1e-2))
+        p_k, st_k = fu.engine_update(
+            opt, grads, st, params, jnp.float32(1e-2), None, interpret=True, split=split
+        )
+        assert _max_err(p_k["x"], params["x"] + upd["x"]) < 1e-5
+        assert _max_err(st_k.exp_avg["x"], st_ref.exp_avg["x"]) < 1e-6
+        assert _max_err(st_k.exp_avg_sq["x"], st_ref.exp_avg_sq["x"]) < 1e-6
+        assert int(st_k.step) == int(st_ref.step)
+    n = math.prod(shape)
+    assert split == ({"pallas_elems": n, "xla_elems": 0} if on_kernel else {"pallas_elems": 0, "xla_elems": n})
+
+
+@pytest.mark.parametrize("opt_kind", ["adam", "lamb"])
+@pytest.mark.parametrize("shape", [(_L, _D, 4 * _D), (4 * _D, _D), (2, _L, _D, 3 * _D)], ids=str)
+def test_fused_update_takes_the_leaf_in_its_own_layout(shape, opt_kind):
+    """No relayout round the kernels: every leaf-sized operand and result
+    of each pallas_call keeps the leaf's last two dimensions, and no
+    reshape of the leaf touches them (on the TPU they are the tiled ones;
+    a (rows, 256) view cost a pass over every operand — PERF.md, PR 27)."""
+    opt = _update_opt(opt_kind)
+    params = {"x": _rand(shape)}
+    st = opt.init(params)
+    jaxpr = jax.make_jaxpr(
+        lambda g, st, p: fu.engine_update(opt, g, st, p, jnp.float32(1e-2), jnp.bool_(False), interpret=False)
+    )(params, st, params)
+    n, minor = math.prod(shape), shape[-2:]
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == (1 if opt_kind == "adam" else 2)
+    for e in calls:
+        leaf_sized = [v.aval for v in (*e.invars, *e.outvars) if v.aval.size == n]
+        assert len(leaf_sized) >= 3  # fused_lamb_apply: p, the direction, the new p
+        assert all(a.shape[-2:] == minor for a in leaf_sized), [a.shape for a in leaf_sized]
+    for e in jaxpr.eqns:
+        if e.primitive.name in ("reshape", "transpose", "copy") and e.invars[0].aval.size == n:
+            assert e.primitive.name == "reshape"
+            assert e.invars[0].aval.shape[-2:] == e.outvars[0].aval.shape[-2:] == minor
+
+
+@pytest.mark.parametrize("shape,dtype,want", [
+    # GPT-2 Large (benchmark/configs/gpt2-large-train-1chip.json)
+    ((36, 1280, 5120), "float32", ((36, 1280, 5120), (16, 5120))),
+    ((36, 5120, 1280), "float32", ((36, 5120, 1280), (64, 1280))),
+    ((36, 1280, 3840), "float32", ((36, 1280, 3840), (16, 3840))),
+    ((36, 1280, 1280), "float32", ((36, 1280, 1280), (64, 1280))),
+    ((1024, 1280), "float32", ((1, 1024, 1280), (64, 1280))),
+    ((50257, 1280), "float32", None),    # wte: odd rows
+    ((36, 5120), "float32", None),       # stacked bias: 36 rows are not whole tiles
+    ((1280,), "float32", None),
+    # GPT-2 XL
+    ((48, 1600, 6400), "float32", ((48, 1600, 6400), (8, 6400))),
+    ((48, 6400, 1600), "float32", None),  # 1600 is 12.5 lanes: XL keeps only fc_w on the kernel
+    # bf16 tiles are 16 rows deep
+    ((4, 64, 256), "bfloat16", ((4, 64, 256), (64, 256))),
+    ((4, 24, 256), "bfloat16", None),
+    # a last dimension wider than a tile-row of the budget is split
+    ((16, 8 * 12288), "float32", ((1, 16, 8 * 12288), (8, 12288))),
+    ((2, 3, 8, 128), "float32", ((6, 8, 128), (8, 128))),
+    ((8, 100), "float32", None),
+    ((0, 128), "float32", None),
+], ids=str)
+def test_fused_update_leaf_view_rule(shape, dtype, want):
+    assert fu._leaf_view(shape, (jnp.dtype(dtype), jnp.float32)) == want
+    if want is not None:
+        (_, rows, cols), (br, bc) = want
+        assert rows % br == 0 and cols % bc == 0 and br * bc <= fu._BLOCK_ELEMS
 
 
 def test_shared_update_body_numpy_matches_jax():
@@ -270,9 +382,11 @@ def test_autotune_defaults_are_deterministic():
     assert a == b
     assert a["block_k"] >= 512  # long context takes the big block
     assert at.default_blocks("flash_decode", S=128, B=1)["block_k"] == 128
-    assert at.default_blocks("fused_update")["block_rows"] > 0
-    with pytest.raises(KeyError):
-        at.default_blocks("nope")
+    assert at.default_blocks("flash_attention", sq=1024)["block_q"] == 512
+    # the fused update derives its block from each leaf's shape: no table
+    for kind in ("nope", "fused_update"):
+        with pytest.raises(KeyError):
+            at.default_blocks(kind)
 
 
 def test_autotune_cache_roundtrip(tmp_path):
@@ -317,17 +431,17 @@ def test_autotune_corrupt_cache_falls_back_to_defaults(tmp_path):
     with open(path2, "w") as f:
         json.dump({"entries": {"fp": {"no_blocks": 1}}}, f)
     t2 = at.Autotuner(path=path2, mode="cache")
-    assert t2.blocks_for("fused_update") == at.default_blocks("fused_update")
+    assert t2.blocks_for("flash_attention") == at.default_blocks("flash_attention")
     assert t2.stats()["cache_ok"] is False
 
 
 def test_autotune_off_mode_ignores_cache(tmp_path):
     path = str(tmp_path / "kernel_autotune.json")
     force = at.Autotuner(path=path, mode="force")
-    force.record(at.fingerprint("fused_update"), {"block_rows": 1024}, 1.0)
+    force.record(at.fingerprint("flash_attention"), {"block_q": 1024, "block_k": 1024}, 1.0)
     off = at.Autotuner(path=path, mode="off")
-    assert off.blocks_for("fused_update") == at.default_blocks("fused_update")
-    assert off.tune("fused_update", lambda b: 0.0) == at.default_blocks("fused_update")
+    assert off.blocks_for("flash_attention") == at.default_blocks("flash_attention")
+    assert off.tune("flash_attention", lambda b: 0.0) == at.default_blocks("flash_attention")
 
 
 def test_autotune_failed_candidates_degrade(tmp_path):
@@ -336,7 +450,7 @@ def test_autotune_failed_candidates_degrade(tmp_path):
     def bad_timer(blocks):
         raise RuntimeError("grid refused")
 
-    assert tuner.tune("fused_update", bad_timer) == at.default_blocks("fused_update")
+    assert tuner.tune("flash_attention", bad_timer) == at.default_blocks("flash_attention")
 
 
 def test_autotune_env_mode_escape_hatch(monkeypatch):

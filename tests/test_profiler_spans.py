@@ -132,7 +132,7 @@ class _Trace:
     ``ds.*`` events as ``(name, start_ns, end_ns, step or None)``."""
 
     def __init__(self, path):
-        self.path, self.spans = str(path), []
+        self.path, self.spans, self.args = str(path), [], {}
 
     def __enter__(self):
         opts = jax.profiler.ProfileOptions()
@@ -148,7 +148,9 @@ class _Trace:
                 for line in plane.lines:
                     for e in line.events:
                         if e.name.startswith("ds."):
-                            step = dict(e.stats).get("step")
+                            stats = dict(e.stats)
+                            self.args.setdefault(e.name, stats)  # the first event's arguments
+                            step = stats.get("step")
                             self.spans.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
                                                None if step is None else int(step)))
         self.spans.sort(key=lambda s: s[1])
@@ -230,6 +232,25 @@ class TestSpansInTheProfilersTrace:
         assert [(s[0], s[3]) for s in tr.spans] == [("ds.train.step", 3), ("ds.train.data_wait", None),
                                                     ("ds.train.dispatch", None)]
         assert engine.timeline.summary()["steps"] == 0
+
+    @pytest.mark.parametrize("kernels", ["1", "0"])
+    def test_compile_span_carries_the_fused_update_split(self, kernels, tmp_path, monkeypatch):
+        """Armed, the optimizer's elements on the one-pass kernels and on
+        the XLA leaf path are arguments of ``ds.train.compile``, counted
+        while the step was traced; not armed, the span has none."""
+        monkeypatch.setenv("DS_KERNELS", kernels)
+        engine, batch = _train_engine()
+        with _Trace(tmp_path) as tr:
+            engine.train_batch(batch)
+        split = {k: int(v) for k, v in tr.args["ds.train.compile"].items() if k.startswith("fused_update_")}
+        if kernels == "0":
+            assert split == {} and engine._fused_update_split == {}
+            return
+        assert split == {f"fused_update_{k}": v for k, v in engine._fused_update_split.items()}
+        n = sum(x.size for x in jax.tree.leaves(engine.state["params"]))
+        assert split["fused_update_pallas_elems"] + split["fused_update_xla_elems"] == n
+        # some of GPT2_TINY's weight matrices fill whole tiles; vectors and ragged leaves do not
+        assert min(split.values()) > 0
 
 
 # ---------------------------------------------------------------------------
