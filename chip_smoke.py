@@ -9,6 +9,9 @@ weights made from a seed:
   GPT-2 Medium, sequence 1024, bf16, ZeRO stage 3 over
   ``{"fsdp": -1, "data": 1}``, 16 sequences a step (4 × gas 4 on one
   chip, 4 × gas 1 on four) under the repo's ``774M-zero3`` remat recipe;
+* a second family on the same server — DeepSeek-V2 at a tiny size
+  (latent attention on the paged latent pool, dropless routing over the
+  experts held here) against ``benchmark/reference_deepseek_v2.py``;
 * server — ``deepspeed_tpu.init_inference("gpt2-xl")`` → ``ServingEngine``
   on the paged pool (8 slots, ``page_len`` 128), a bf16 then an int8 KV
   pool, eight seeded greedy requests each;
@@ -475,6 +478,69 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
+# phase 4: a second family on the same server — DeepSeek-V2 at a tiny size
+# ---------------------------------------------------------------------------
+
+# every mechanism present (latent attention on the paged latent pool, YaRN,
+# one dense layer then expert layers, group-limited routing over all experts
+# with half of them held here, shared experts, an untied head over half the
+# vocabulary), at sizes the compiled ``mla_decode_paged`` accepts: pages of
+# 128 positions, a latent of 128 + 64
+DSV2_SMOKE = {
+    "vocab_size": 512, "hidden_size": 256, "intermediate_size": 512, "moe_intermediate_size": 64,
+    "num_hidden_layers": 3, "num_attention_heads": 8, "q_lora_rank": 96, "kv_lora_rank": 128,
+    "qk_nope_head_dim": 32, "qk_rope_head_dim": 64, "v_head_dim": 32, "n_routed_experts": 16, "n_shared_experts": 2,
+    "num_experts_per_tok": 4, "n_group": 4, "topk_group": 2, "routed_scaling_factor": 2.0, "norm_topk_prob": False,
+    "first_k_dense_replace": 1, "rms_norm_eps": 1e-6, "rope_theta": 10000, "max_position_embeddings": 4096,
+    "rope_scaling": {"type": "yarn", "factor": 40, "original_max_position_embeddings": 64, "beta_fast": 32,
+                     "beta_slow": 1, "mscale": 0.707, "mscale_all_dim": 0.707},
+    "experts_held": [8, 8], "vocab_held": 256,
+}
+TOL_DSV2_TOKEN_GAP = 0.05  # logits of +-0.5 here; a bf16 program strays ~1e-2 from the float32 reference
+
+
+def serve_deepseek_v2(s: Smoke, device) -> Dict[str, float]:
+    """``init_inference(model_config=DeepseekV2Config)`` → the same
+    ``ServingEngine``: compiles both programs, serves two requests
+    (chunked prefill, then decode through the latent pool) and holds
+    every emitted token against the plain reference's logits
+    (``benchmark/reference_deepseek_v2.py``) — so that a bring-up fault
+    shows here, in seconds, before the 10 GB cell runs."""
+    from benchmark import weights_deepseek_v2 as weights
+    from benchmark.reference_deepseek_v2 import Reference
+    from benchmark.runners.serve_dsv2 import served_gaps
+    from deepspeed_tpu.comm.mesh import make_mesh
+    from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.models import deepseek_v2
+    from deepspeed_tpu.serving import ServingEngine
+
+    dims = DSV2_SMOKE
+    mcfg = deepseek_v2.DeepseekV2Config.from_hf(dims, experts_held=dims["experts_held"], vocab_held=dims["vocab_held"])
+    inf = deepspeed_tpu.init_inference(
+        model_config=mcfg, params=weights.program_params(s.seed, dims, jnp.bfloat16), dtype=jnp.bfloat16,
+        max_out_tokens=512, mesh=make_mesh(MeshConfig(), devices=[device]), donate_params=True,
+    )
+    srv = ServingEngine(inf, config={"num_slots": 2, "max_len": 512, "prefill_chunk": 128, "max_new_tokens": 16,
+                                     "kvcache": {"enabled": True, "page_len": 128, "num_pages": 9}})
+    rng = np.random.default_rng(s.seed)
+    prompts = [rng.integers(1, dims["vocab_held"], n, dtype=np.int32) for n in (200, 131)]
+    ids = [srv.submit(p, max_new_tokens=8) for p in prompts]
+    done = srv.drain()
+    check((srv.prefill_compiles, srv.decode_compiles) == (1, 1),
+          f"serve[dsv2]: {srv.prefill_compiles} prefill / {srv.decode_compiles} decode executables")
+    moe = srv.stats()["moe"]
+    check(moe["dropped_assignments"] == 0 and moe["assignments_computed"] > 0, f"serve[dsv2]: expert counters {moe}")
+    gaps = served_gaps(Reference(dims, s.seed), [{"prompt": p, "generated": list(done[i].generated)}
+                                                 for p, i in zip(prompts, ids)], 256)
+    check(gaps["token_gap_max"] <= TOL_DSV2_TOKEN_GAP,
+          f"serve[dsv2]: an emitted token lies {gaps['token_gap_max']:.4f} under the reference's best logit "
+          f"(tolerance {TOL_DSV2_TOKEN_GAP})")
+    expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
+                   ["mla_decode_paged"] if s.mosaic else [], "serve[dsv2] decode")
+    say(f"serve[dsv2]: 2 requests x 8 tokens through the latent pool, token gap mean {gaps['token_gap_mean']:.5f} "
+        f"max {gaps['token_gap_max']:.5f} over {gaps['tokens']} tokens")
+    return gaps
+
 
 def run(s: Smoke, devices: Sequence) -> None:
     """Every phase, in order; raises on the first check that fails."""
@@ -490,6 +556,7 @@ def run(s: Smoke, devices: Sequence) -> None:
                   f"step {i + 1} loss {b:.5f} on {len(devices)} devices vs {a:.5f} on one (tolerance {TOL_LOSS_ACROSS_MESHES})")
         say(f"train: losses on 1 and {len(devices)} devices agree within {TOL_LOSS_ACROSS_MESHES}")
     serve(s, devices[0])
+    serve_deepseek_v2(s, devices[0])
 
 
 def main() -> int:

@@ -132,7 +132,7 @@ def family_corpora() -> Dict[str, Dict[str, List[str]]]:
 
     import jax
 
-    from deepspeed_tpu.models import bert, gpt2
+    from deepspeed_tpu.models import bert, deepseek_v2, gpt2
 
     tiny = dataclasses.replace(gpt2.GPT2_TINY)
     tiny_moe = dataclasses.replace(gpt2.GPT2_TINY, n_experts=4)
@@ -149,6 +149,9 @@ def family_corpora() -> Dict[str, Dict[str, List[str]]]:
         "neo": {"gpt-neo (gpt2 dense schema)": gpt2_dense},
         "moe": {"gpt2-tiny-moe": gpt2_moe},
         "bert": {"bert-tiny": bert_tree},
+        "deepseek_v2": {"deepseek-v2-tiny": _leaf_paths(jax.tree.map(
+            lambda shape: 0, deepseek_v2.param_shapes(deepseek_v2.DEEPSEEK_V2_TINY),
+            is_leaf=lambda s: isinstance(s, tuple)))},
     }
 
 

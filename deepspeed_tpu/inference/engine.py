@@ -122,6 +122,7 @@ class InferenceEngine:
         seed: int = 0,
         init_on_device: bool = False,
         kernels: Any = None,
+        donate_params: bool = False,
         **kwargs,
     ):
         """``model`` may be:
@@ -130,6 +131,12 @@ class InferenceEngine:
           injection policy (``replace_with_kernel_inject`` path);
         * a preset name (``"gpt2"``, ``"bert-base"``, ...);
         * ``None`` with explicit ``model_config`` + ``params``.
+
+        ``donate_params``: the caller hands a device-resident ``params``
+        tree over — it is donated to the cast/reshard, so a tree already
+        in the engine's dtype and placement is taken as it is instead of
+        being copied (a model that fills most of the chip cannot be held
+        twice).  The caller's arrays are invalid afterwards.
         """
         self.mp_world_size = int(mp_size)
         self.dtype = dtype if dtype is not None else jnp.bfloat16
@@ -183,8 +190,16 @@ class InferenceEngine:
         else:
             raise ValueError("init_inference needs `model` (module/state_dict/preset) or model_config=")
 
-        self._is_gpt = isinstance(self.model_config, gpt2_mod.GPT2Config)
-        self._family = gpt2_mod if self._is_gpt else bert_mod
+        # the family seam (docs/serving.md §Model families): the config's
+        # class names its model module, which supplies the parameter
+        # tree, the partition rules and — for a family with a cache kind
+        # of its own — the serving forward.  Duck-typed configs outside
+        # the built-in MROs keep falling back to BERT's encoder layout.
+        from deepspeed_tpu.models import family_of
+
+        self._family = family_of(self.model_config) or bert_mod
+        self._is_gpt = self._family is gpt2_mod  # the GPT-2 parameter layout (generate(), the slot cache)
+        self._causal = bool(getattr(self._family, "CAUSAL_LM", False))
         # partition-rule engine: the family table every param layout
         # resolves through (sharding/rules.py; packed-int8 aware)
         from deepspeed_tpu.sharding.rules import rules_for_config, rules_for_family
@@ -192,9 +207,6 @@ class InferenceEngine:
         try:
             self._rules = rules_for_config(self.model_config)
         except ValueError:
-            # duck-typed configs outside the built-in MROs keep working
-            # (the same fallback as self._family above); the table is
-            # only consulted when a layout actually needs resolving
             self._rules = rules_for_family("gpt2" if self._is_gpt else "bert")
         # disable remat for inference (no backward to save memory for)
         if getattr(self.model_config, "remat", False):
@@ -216,17 +228,15 @@ class InferenceEngine:
             # a random init would only serve as a shape template here, so
             # skip it — the restore target comes from checkpoint metadata
             params = self._load_checkpoint_params(checkpoint, checkpoint_tag, params)
-        owns_params = False  # only engine-created trees may be donated
+        owns_params = bool(donate_params) and params is not None  # only engine-created or handed-over trees may be donated
         if params is None:
             if init_on_device and getattr(self.model_config, "n_experts", 0) == 0:
                 # generate the random init ON the chip: host generation of
                 # an XL-class model is minutes of numpy plus a multi-GB
                 # upload, on-chip generation is seconds
-                init_dev = gpt2_mod.init_params_device if self._is_gpt else bert_mod.init_params_device
-                params = init_dev(self.model_config, seed=seed, dtype=self.dtype)
+                params = self._family.init_params_device(self.model_config, seed=seed, dtype=self.dtype)
             else:
-                init = gpt2_mod.init_params if self._is_gpt else bert_mod.init_params
-                params = init(self.model_config, seed=seed)
+                params = self._family.init_params(self.model_config, seed=seed)
             owns_params = True
         self._packed_int8 = False
         if quantize_bits:
@@ -261,7 +271,7 @@ class InferenceEngine:
         clamped by the model's positional table — the number every
         length check (generate, init_cache, serving admission) derives
         from."""
-        if self._is_gpt:
+        if self._causal:
             return min(self.max_out_tokens, self.model_config.n_positions)
         return self.max_out_tokens
 
@@ -410,6 +420,11 @@ class InferenceEngine:
         """Full-sequence forward: GPT → logits (B,T,V); BERT → encoder
         hidden states (BERT accepts token_type_ids/attention_mask
         kwargs)."""
+        if self._causal and not self._is_gpt:
+            raise NotImplementedError(
+                f"{type(self.model_config).__name__} runs on a cache kind of its own: serve it "
+                "through ServingEngine (docs/serving.md §Model families)"
+            )
         if self._is_gpt and kw:
             raise TypeError(
                 f"forward() got unexpected kwargs {sorted(kw)} for a GPT-family "

@@ -22,6 +22,9 @@ from deepspeed_tpu.ops.attention.flash_attention import flash_attention, mha_ref
 from deepspeed_tpu.models.gpt2 import _dropout, _layer_norm
 
 
+CAUSAL_LM = False  # models/__init__.py: what the engines ask of a family
+
+
 @dataclasses.dataclass(frozen=True)
 class BertConfig:
     vocab_size: int = 30522

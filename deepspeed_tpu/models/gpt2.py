@@ -35,6 +35,9 @@ from deepspeed_tpu.ops.attention.flash_attention import flash_attention, mha_ref
 from deepspeed_tpu.ops.normalize import dropout as _dropout, layer_norm as _layer_norm, token_nll
 
 
+CAUSAL_LM = True  # models/__init__.py: what the engines ask of a family
+
+
 @dataclasses.dataclass(frozen=True)
 class GPT2Config:
     vocab_size: int = 50257

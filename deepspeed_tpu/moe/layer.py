@@ -223,3 +223,76 @@ def moe_ffn_from_block(lp: Dict[str, Any], h: jnp.ndarray, *, top_k: int = 2,
     )
     params = {k: lp[k] for k in MOE_PARAM_KEYS}
     return moe_ffn(params, h, cfg, rng=rng, training=training, token_mask=token_mask)
+
+
+# ---------------------------------------------------------------------------
+# dropless routing over the experts held here (DeepSeek-V2 style)
+# ---------------------------------------------------------------------------
+
+def group_limited_topk(probs: jnp.ndarray, n_group: int, topk_group: int, top_k: int,
+                       scale: float = 1.0, renormalize: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Group-limited greedy routing: ``probs (N, E)`` are the router's
+    softmax scores over **all** experts.  A group's score is its largest
+    ``p``; the ``topk_group`` best groups are kept, the rest zeroed, and
+    the ``top_k`` best experts of what is left are chosen.  Returns
+    ``(idx (N, top_k) int32, weight (N, top_k) float32)`` with
+    ``weight = scale * p`` (``renormalize`` divides by the chosen sum
+    first)."""
+    N, E = probs.shape
+    group_score = probs.reshape(N, n_group, E // n_group).max(axis=-1)
+    _, best = jax.lax.top_k(group_score, topk_group)
+    keep = jnp.sum(jax.nn.one_hot(best, n_group, dtype=probs.dtype), axis=1) > 0  # (N, n_group)
+    masked = jnp.where(jnp.repeat(keep, E // n_group, axis=1), probs, 0.0)
+    w, idx = jax.lax.top_k(masked, top_k)
+    if renormalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), (w * scale).astype(jnp.float32)
+
+
+def dropless_held_experts(x: jnp.ndarray, idx: jnp.ndarray, weight: jnp.ndarray, w_gu: jnp.ndarray,
+                          w_down: jnp.ndarray, held: Tuple[int, int],
+                          valid: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The part of a routed-expert layer that the experts **held here**
+    give: ``sum_{e in chosen, first <= e < first + count} w_e E_e(x)``
+    with ``E_e(x) = (silu(x W_gate,e) * x W_up,e) W_down,e``.
+
+    No capacity and no dropped assignment: every (token, expert) pair
+    whose expert is held is computed.  Static shapes: the ``N * top_k``
+    assignments are sorted by expert (those of absent experts last), the
+    held ones go through two grouped matmuls (``jax.lax.ragged_dot``,
+    group sizes traced), and each token sums its own rows back.  On one
+    chip there is no exchange; over an ``expert`` mesh axis this is what
+    each rank computes between the two all-to-alls.
+
+    ``x (N, D)``; ``idx``/``weight (N, K)`` from the router over all
+    experts; ``w_gu (count, D, 2F)`` (gate columns first), ``w_down
+    (count, F, D)``.  ``valid (N,)`` marks the real tokens for the
+    counters only.  Returns ``(out (N, D), counts (count + 1,) int32)``:
+    per held expert the real tokens computed for it, and last the real
+    assignments the router sent to held experts — equal to their sum
+    unless something drops.
+    """
+    first, count = held
+    N, K = idx.shape
+    local = idx - first
+    is_held = (local >= 0) & (local < count)
+    key = jnp.where(is_held, local, count).reshape(N * K)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+    xs = jnp.take(x, order // K, axis=0)
+    gu = jax.lax.ragged_dot(xs, w_gu.astype(x.dtype), sizes)
+    g, u = jnp.split(gu, 2, axis=-1)
+    ys = jax.lax.ragged_dot(jax.nn.silu(g) * u, w_down.astype(x.dtype), sizes)
+    # rows past the held groups belong to absent experts: nothing was computed for them
+    computed = jnp.arange(N * K) < jnp.sum(sizes)
+    ws = jnp.take(jnp.where(is_held, weight, 0.0).reshape(N * K), order)
+    ys = jnp.where(computed[:, None], ys.astype(jnp.float32) * ws[:, None], 0.0)
+    out = jnp.take(ys, jnp.argsort(order), axis=0).reshape(N, K, -1).sum(axis=1)
+    # counters, over the real tokens: the rows the grouped matmuls computed per expert (read off the
+    # group keys), and what the router sent to held experts (read off its own choice) — equal unless a
+    # later change drops assignments between the two
+    real = jnp.ones((N,), bool) if valid is None else valid.astype(bool)
+    per_expert = jnp.bincount(key, weights=jnp.repeat(real, K).astype(jnp.int32), length=count + 1)[:count].astype(jnp.int32)
+    routed = jnp.sum((is_held & real[:, None]).astype(jnp.int32))
+    counts = jnp.concatenate([per_expert, routed[None]])
+    return out.astype(x.dtype), counts

@@ -73,7 +73,7 @@ class ServingEngine:
         # ServingConfig (or replace()d fields) never went through
         # from_dict's chunk-multiple / dtype checks
         config = ServingConfig.from_dict(dataclasses.asdict(config))
-        if not engine._is_gpt:
+        if not engine._causal:
             raise ValueError("ServingEngine requires a causal-LM (GPT-family) InferenceEngine")
         self.engine = engine
         self.config = config
@@ -141,6 +141,21 @@ class ServingEngine:
         self._replicated = replicated_sharding(engine.mesh)
         kvc = config.kvcache
         self._paged = bool(kvc.enabled)
+        # the family seam (docs/serving.md §Model families): a family
+        # whose cache is not the per-head K/V pair supplies the kind of
+        # its pool and its own step on it; the engine keeps scheduler,
+        # staging, sampling, program names and timeline
+        family = engine._family
+        cache_kind = getattr(family, "cache_kind", None)
+        make_forward = getattr(family, "serving_forward", None)
+        self._family_forward = make_forward(mcfg) if make_forward is not None else None
+        if self._family_forward is not None and kv_dtype == "int8":
+            raise ValueError(f"{type(mcfg).__name__}: no int8 form of its cache kind (kv_cache_dtype must be 'model')")
+        # per-step counters a family's step returns beside the tokens
+        # (DeepSeek-V2: tokens per held expert; stats()["moe"])
+        self._aux_total = None
+        self._aux_decode_touched = 0
+        self._aux_decode_steps = 0
         if self._paged:
             import math
 
@@ -173,13 +188,19 @@ class ServingEngine:
                     )
                     max_len = aligned
             self.pool = PagedKVPool(
-                mcfg.n_layer, config.num_slots, mcfg.n_head, max_len,
-                mcfg.head_dim, kv_dtype, page_len=kvc.page_len,
+                mcfg.n_layer, config.num_slots, getattr(mcfg, "n_head", 0), max_len,
+                getattr(mcfg, "head_dim", 0), kv_dtype, page_len=kvc.page_len,
                 num_pages=(kvc.num_pages or None), sharding=self._replicated,
                 prefill_chunk=config.prefill_chunk,
                 pinned_prefixes=kvc.pinned_prefixes,
                 session_ttl_seconds=kvc.session_ttl_seconds,
                 spill_dir=(kvc.spill_dir or None),
+                kind=cache_kind(mcfg, kv_dtype) if cache_kind is not None else None,
+            )
+        elif self._family_forward is not None:
+            raise ValueError(
+                f"{type(mcfg).__name__} is served on its own cache kind, which lives in the "
+                "paged pool only: set serving.kvcache.enabled"
             )
         else:
             self.pool = SlotKVPool(
@@ -337,7 +358,7 @@ class ServingEngine:
             from deepspeed_tpu.inference.engine import sample_logits_pooled
             from deepspeed_tpu.ops.transformer.inference import forward_with_cache
 
-            icfg = self.engine.inference_config(self.pool.max_len)
+            icfg = self.engine.inference_config(self.pool.max_len) if self._family_forward is None else None
             n_pos = self.engine.model_config.n_positions
             chunk = self.config.prefill_chunk
             max_top_k = self.config.max_top_k
@@ -356,7 +377,31 @@ class ServingEngine:
                     c, cs,
                 )
 
-            if self._paged:
+            if self._family_forward is not None:
+                fwd = self._family_forward
+
+                def serve_prefill(params, toks, table, pos, take_idx, cow_src, cow_dst,
+                                  flag, temp, topk, seed, k_pool, v_pool):
+                    # the paged step below with the family's own forward
+                    # on its own cache kind; the chunk's padded tail is
+                    # computed and left out of the family's counters
+                    cow = lambda b: b.at[:, cow_dst].set(b[:, cow_src])  # noqa: E731
+                    k_pool = jax.tree.map(cow, k_pool)
+                    v_pool = jax.tree.map(cow, v_pool)
+                    logits, k_pool, v_pool, aux = fwd(
+                        params, toks, k_pool, v_pool, pos[None], page_table=table[None, :],
+                        row_valid=(jnp.arange(chunk, dtype=jnp.int32) <= take_idx)[None, :],
+                        take=take_idx[None],
+                    )
+                    key = jax.random.fold_in(jax.random.PRNGKey(seed), pos + take_idx)
+                    first = sample_logits_pooled(
+                        logits.astype(jnp.float32), key[None], flag[None], temp[None],
+                        topk[None], max_top_k,
+                    )[0]
+                    return (first, aux), k_pool, v_pool
+
+                donate = (11, 12)
+            elif self._paged:
                 def serve_prefill(params, toks, table, pos, take_idx, cow_src, cow_dst,
                                   flag, temp, topk, seed, k_pool, v_pool):
                     # the slot's pending copy-on-write lands BEFORE this
@@ -429,10 +474,28 @@ class ServingEngine:
             from deepspeed_tpu.inference.engine import sample_logits_pooled
             from deepspeed_tpu.ops.transformer.inference import forward_with_cache
 
-            icfg = self.engine.inference_config(self.pool.max_len)
+            icfg = self.engine.inference_config(self.pool.max_len) if self._family_forward is None else None
             max_top_k = self.config.max_top_k
 
-            if self._paged:
+            if self._family_forward is not None:
+                fwd = self._family_forward
+
+                def serve_decode(params, toks, pos, flags, temps, topks, seeds,
+                                 page_table, write_mask, k_pool, v_pool):
+                    logits, k_pool, v_pool, aux = fwd(
+                        params, toks[:, None], k_pool, v_pool, pos, page_table=page_table,
+                        write_mask=write_mask, row_valid=write_mask[:, None],
+                    )
+                    keys = jax.vmap(
+                        lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
+                    )(seeds, pos)
+                    nxt = sample_logits_pooled(
+                        logits.astype(jnp.float32), keys, flags, temps, topks, max_top_k,
+                    )
+                    return (nxt, aux), k_pool, v_pool
+
+                donate = (9, 10)
+            elif self._paged:
                 def serve_decode(params, toks, pos, flags, temps, topks, seeds,
                                  page_table, write_mask, k_pool, v_pool):
                     # per-slot page tables are traced values of the one
@@ -1274,7 +1337,8 @@ class ServingEngine:
         # explicit d2h read doubles as the fence that keeps prefill_ms
         # honest; the value is the first generated token on final chunks
         with tl.phase("prefill.wait"):
-            tok = int(jax.device_get(first))
+            tok = jax.device_get(first)
+        tok = int(tok if self._family_forward is None else self._note_aux(tok, decode=False))
         now = time.monotonic()
         if self._paged and job.final:
             # the whole prompt's KV is paged in: learn it as a shared
@@ -1324,11 +1388,47 @@ class ServingEngine:
             )
         self.pool.swap(k, v)
         with tl.phase("decode.wait"):
-            out = np.asarray(jax.device_get(nxt))
+            out = jax.device_get(nxt)
+        out = np.asarray(out if self._family_forward is None else self._note_aux(out, decode=True))
         now = time.monotonic()
         self.scheduler.note_decode(
             {r.slot: int(out[r.slot]) for r in decoding}, now, self._step_count
         )
+
+    def _note_aux(self, got, decode: bool):
+        """A family's step returns ``(tokens, aux)``: add the step's
+        counters (host side, a few hundred integers) and hand the tokens
+        on.  DeepSeek-V2's ``aux (moe layers, held + 1)``: tokens
+        computed per held expert, and last the assignments routed to
+        held experts."""
+        tokens, aux = got
+        aux = np.asarray(aux, np.int64)
+        self._aux_total = aux if self._aux_total is None else self._aux_total + aux
+        if decode:
+            self._aux_decode_steps += 1
+            self._aux_decode_touched += int(np.count_nonzero(aux[:, :-1]))
+        return tokens
+
+    def reset_moe_counters(self) -> None:
+        """Start the expert counters of :meth:`stats` afresh (a
+        benchmark window opens)."""
+        self._aux_total, self._aux_decode_touched, self._aux_decode_steps = None, 0, 0
+
+    def _moe_stats(self) -> Dict[str, Any]:
+        per_expert = self._aux_total[:, :-1]
+        computed, routed = int(per_expert.sum()), int(self._aux_total[:, -1].sum())
+        mean = per_expert.mean(axis=1)
+        return {
+            "tokens_per_expert": per_expert.tolist(),  # [moe layer][held expert]
+            "assignments_computed": computed,
+            "assignments_routed_held": routed,
+            "dropped_assignments": routed - computed,
+            # the fullest held expert's load over the mean held expert's, the worst layer
+            "load_max_over_mean": float(np.max(per_expert.max(axis=1) / np.maximum(mean, 1e-9))) if computed else None,
+            "decode_steps": self._aux_decode_steps,
+            # held experts (summed over the moe layers) with at least one token, a decode step
+            "decode_experts_touched_mean": self._aux_decode_touched / max(1, self._aux_decode_steps),
+        }
 
     # ------------------------------------------------------------------
     def stats(self) -> Dict[str, Any]:
@@ -1389,6 +1489,8 @@ class ServingEngine:
             self._publish_kvcache()
         if self.tenants is not None:
             out["tenants"] = self.tenants.snapshot()
+        if self._aux_total is not None:
+            out["moe"] = self._moe_stats()
         out.update(self.timeline.summary())
         return out
 

@@ -74,6 +74,52 @@ def _locked(fn):
     return wrapper
 
 
+class PerHeadKV:
+    """The default cache kind: a K and a V buffer of ``(layers, pages,
+    heads, page_len, head_dim)`` (or the int8 code+scale pair)."""
+
+    def __init__(self, heads: int, head_dim: int, dtype: Any):
+        self.heads, self.head_dim, self.dtype = int(heads), int(head_dim), dtype
+
+    def buffers(self, n_layer: int, num_pages: int, page_len: int):
+        from deepspeed_tpu.ops.transformer.inference import init_kv_cache
+
+        return init_kv_cache(n_layer, num_pages, self.heads, page_len, self.head_dim, self.dtype)
+
+    def describe(self, n_layer: int, num_pages: int, page_len: int) -> str:
+        return (f"2 x ({n_layer} layers x {num_pages} pages x {self.heads} heads x "
+                f"{page_len} page_len x {self.head_dim} head_dim)")
+
+
+class LatentKV:
+    """Latent-attention cache kind: ONE buffer ``(layers, pages, width,
+    page_len)`` — per position one row of ``width`` numbers shared by
+    all heads, positions along the lanes
+    (``ops/transformer/latent_attention.py``) — and no second buffer
+    (``pool.v`` is None, an empty pytree to ``jit``)."""
+
+    def __init__(self, width: int, dtype: Any):
+        self.width, self.dtype = int(width), dtype
+
+    def buffers(self, n_layer: int, num_pages: int, page_len: int):
+        return jnp.zeros((n_layer, num_pages, self.width, page_len), self.dtype), None
+
+    def describe(self, n_layer: int, num_pages: int, page_len: int) -> str:
+        return f"1 x ({n_layer} layers x {num_pages} pages x {self.width} latent x {page_len} page_len)"
+
+
+def _named_leaves(k, v) -> Dict[str, Any]:
+    """The pool's device buffers by spill name (``k``, ``v``, or
+    ``k.q`` / ``k.s`` for the int8 pair); an absent buffer has none."""
+    out: Dict[str, Any] = {}
+    for prefix, tree in (("k", k), ("v", v)):
+        if tree is None:
+            continue
+        for name, buf in (tree.items() if isinstance(tree, dict) else ((None, tree),)):
+            out[prefix if name is None else f"{prefix}.{name}"] = buf
+    return out
+
+
 class PagedKVPool:
     """Fixed-shape device page pool + host-side allocator with
     shared-prefix dedup, copy-on-write, and durable sessions.
@@ -92,9 +138,12 @@ class PagedKVPool:
                  prefill_chunk: int = 1,
                  pinned_prefixes: Sequence[Sequence[int]] = (),
                  session_ttl_seconds: float = 0.0,
-                 spill_dir: Optional[str] = None):
-        from deepspeed_tpu.ops.transformer.inference import init_kv_cache
-
+                 spill_dir: Optional[str] = None,
+                 kind: Optional[Any] = None):
+        """``kind`` is the cache kind (docs/serving.md §Cache kinds):
+        it makes the device buffers, every leaf with the page axis at
+        dim 1; the allocator below never looks inside them.  Default:
+        :class:`PerHeadKV` from ``heads`` / ``head_dim`` / ``kv_dtype``."""
         if num_slots < 1:
             raise SlotPoolError(f"num_slots must be >= 1, got {num_slots}")
         if page_len < 1:
@@ -128,9 +177,8 @@ class PagedKVPool:
                 f"{self.num_slots} slots x {self.pages_per_slot} pages — "
                 f"a full pool of cache misses will wait on page churn"
             )
-        self.k, self.v = init_kv_cache(
-            n_layer, self.num_pages, heads, self.page_len, head_dim, kv_dtype
-        )
+        self.kind = kind if kind is not None else PerHeadKV(heads, head_dim, kv_dtype)
+        self.k, self.v = self.kind.buffers(n_layer, self.num_pages, self.page_len)
         if sharding is not None:
             self.k, self.v = jax.device_put((self.k, self.v), sharding)
         # host-side allocator state (every public touch goes through
@@ -285,9 +333,7 @@ class PagedKVPool:
         kind = "int8+f32 scales" if isinstance(self.k, dict) else str(np.dtype(
             jax.tree.leaves(self.k)[0].dtype))
         return (
-            f"2 x ({self.n_layer} layers x {self.num_pages} pages x "
-            f"{self.heads} heads x {self.page_len} page_len x "
-            f"{self.head_dim} head_dim) [{kind}] = "
+            f"{self.kind.describe(self.n_layer, self.num_pages, self.page_len)} [{kind}] = "
             f"{self.cache_bytes() / 1e6:.1f} MB "
             f"({self.num_slots} slots x {self.pages_per_slot} pages/slot)"
         )
@@ -638,19 +684,16 @@ class PagedKVPool:
 
     def _gather_host(self, pages: Sequence[int]) -> Dict[str, np.ndarray]:
         ids = jnp.asarray(np.asarray(pages, np.int32))
-        out: Dict[str, np.ndarray] = {}
-        for prefix, tree in (("k", self.k), ("v", self.v)):
-            leaves = tree if isinstance(tree, dict) else {None: tree}
-            for name, buf in leaves.items():
-                key = prefix if name is None else f"{prefix}.{name}"
-                out[key] = jax.device_get(jnp.take(buf, ids, axis=1))
-        return out
+        return {key: jax.device_get(jnp.take(buf, ids, axis=1))
+                for key, buf in _named_leaves(self.k, self.v).items()}
 
     def _scatter_device(self, pages: Sequence[int],
                         leaves: Dict[str, np.ndarray]) -> None:
         ids = jnp.asarray(np.asarray(pages, np.int32))
 
         def put(tree, prefix):
+            if tree is None:
+                return None
             if isinstance(tree, dict):
                 return {
                     name: buf.at[:, ids].set(jnp.asarray(leaves[f"{prefix}.{name}"]))

@@ -294,7 +294,25 @@ def _moe_family_rules(layout: SpecLayout) -> Tuple[Rule, ...]:
     )
 
 
+def _deepseek_v2_rules(layout: SpecLayout) -> Tuple[Rule, ...]:
+    """DeepSeek-V2 (models/deepseek_v2.py) as it is deployed: the routed
+    experts a rank holds are the ``expert`` axis of the stacked expert
+    matrices ``(held, ...)`` of a layer, embedding and head are
+    vocabulary-parallel, and latent attention, shared experts, router
+    and norms are replicated (data-parallel attention: a latent cache
+    that all heads share is not split by head).  The tree has no
+    stacked-layer dim (``layers/<i>/<name>``)."""
+    ep = layout.expert_axis
+    return (
+        (r"(^|/)experts_gu$", PartitionSpec(ep, None, None)),
+        (r"(^|/)experts_down$", PartitionSpec(ep, None, None)),
+        (r"(^|/)embed$", layout.vocab_embedding()),
+        (r"(^|/)head$", layout.vocab_embedding()),
+    )
+
+
 register_family("gpt2", _gpt2_rules)
+register_family("deepseek_v2", _deepseek_v2_rules)
 register_family("bert", _bert_rules)
 register_family("neo", _neo_rules)
 register_family("moe", _moe_family_rules)
@@ -321,6 +339,8 @@ def rules_for_config(model_config: Any, layout: SpecLayout = DEFAULT_LAYOUT) -> 
             return rules_for_family("gpt2", layout)
         if klass.__name__ == "BertConfig":
             return rules_for_family("bert", layout)
+        if klass.__name__ == "DeepseekV2Config":
+            return rules_for_family("deepseek_v2", layout)
     raise ValueError(
         f"no built-in partition rules for model config {type(model_config).__name__}"
     )
