@@ -484,8 +484,8 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
 # every mechanism present (latent attention on the paged latent pool, YaRN,
 # one dense layer then expert layers, group-limited routing over all experts
 # with half of them held here, shared experts, an untied head over half the
-# vocabulary), at sizes the compiled ``mla_decode_paged`` accepts: pages of
-# 128 positions, a latent of 128 + 64
+# vocabulary), at sizes the compiled ``mla_decode_paged`` and ``mla_prefill``
+# accept: pages of 128 positions, a latent of 128 + 64, chunks of 128
 DSV2_SMOKE = {
     "vocab_size": 512, "hidden_size": 256, "intermediate_size": 512, "moe_intermediate_size": 64,
     "num_hidden_layers": 3, "num_attention_heads": 8, "q_lora_rank": 96, "kv_lora_rank": 128,
@@ -537,6 +537,12 @@ def serve_deepseek_v2(s: Smoke, device) -> Dict[str, float]:
           f"(tolerance {TOL_DSV2_TOKEN_GAP})")
     expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
                    ["mla_decode_paged"] if s.mosaic else [], "serve[dsv2] decode")
+    expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()),
+                   ["mla_prefill"] if s.mosaic else [], "serve[dsv2] prefill")
+    stats = srv.stats()
+    check(stats["mla_prefill_kernel"] is bool(s.mosaic),
+          f"serve[dsv2]: stats() say of the prefill program: mla_prefill_kernel {stats['mla_prefill_kernel']}, "
+          f"fallback {stats['mla_prefill_fallback']!r}")
     say(f"serve[dsv2]: 2 requests x 8 tokens through the latent pool, token gap mean {gaps['token_gap_mean']:.5f} "
         f"max {gaps['token_gap_max']:.5f} over {gaps['tokens']} tokens")
     return gaps

@@ -281,11 +281,12 @@ def _swiglu(x, w_gu, w_down):
 
 
 def mla_block(cfg: DeepseekV2Config, lp: Dict[str, Any], x, pool, layer: int, pos, page_table,
-              write_mask=None, use_kernel: Optional[bool] = None):
+              write_mask=None, use_kernel: Optional[bool] = None, trace_notes: Optional[dict] = None):
     """``x + MLA(RMS(x))`` for ``x (B, T, D)`` at per-row write offsets
     ``pos (B,)``: writes the rows' latents into ``pool[layer]`` through
     ``page_table`` and attends over the cache — absorbed for ``T == 1``
-    (decode), expanded otherwise (a prefill chunk)."""
+    (decode), expanded otherwise (a prefill chunk, which tells
+    ``trace_notes`` the form it took)."""
     from deepspeed_tpu.ops.transformer import latent_attention as la
 
     B, T, _ = x.shape
@@ -305,13 +306,14 @@ def mla_block(cfg: DeepseekV2Config, lp: Dict[str, Any], x, pool, layer: int, po
         attn = la.absorbed_attention(q[..., :dn], q_pe, pool, layer, page_table, pos, w_kvb, dn,
                                      cfg.softmax_scale, use_kernel=use_kernel)
     else:
-        attn = la.expanded_attention(q[..., :dn], q_pe, pool, layer, page_table, pos, w_kvb, dn, cfg.softmax_scale)
+        attn = la.expanded_attention(q[..., :dn], q_pe, pool, layer, page_table, pos, w_kvb, dn, cfg.softmax_scale,
+                                     use_kernel=use_kernel, trace_notes=trace_notes)
     return x + attn.reshape(B, T, H * dv) @ lp["o"], pool
 
 
 def forward_with_cache(params: Dict[str, Any], tokens, pool, pos, cfg: DeepseekV2Config, page_table,
                        write_mask=None, row_valid=None, take=None, use_kernel: Optional[bool] = None,
-                       routing_sink: Optional[list] = None):
+                       routing_sink: Optional[list] = None, trace_notes: Optional[dict] = None):
     """One network step on the paged latent cache.
 
     ``tokens (B, T)``; ``pool`` the ``(layers, pages, width, page_len)``
@@ -326,7 +328,9 @@ def forward_with_cache(params: Dict[str, Any], tokens, pool, pos, cfg: DeepseekV
     router sent to held experts (``moe/layer.py::dropless_held_experts``).
     ``routing_sink``, a list, is given each expert layer's chosen experts
     ``(B * T, top_k)`` (benchmark/control_deepseek_v2.py compares them
-    with the reference's).
+    with the reference's); ``trace_notes``, a dict, is told while
+    tracing which form a chunk's attention took
+    (``latent_attention.expanded_attention``).
     """
     from deepspeed_tpu.moe.layer import dropless_held_experts, group_limited_topk
 
@@ -335,7 +339,7 @@ def forward_with_cache(params: Dict[str, Any], tokens, pool, pos, cfg: DeepseekV
     valid = None if row_valid is None else row_valid.reshape(B * T)
     aux = []
     for layer, lp in enumerate(params["layers"]):
-        x, pool = mla_block(cfg, lp, x, pool, layer, pos, page_table, write_mask, use_kernel)
+        x, pool = mla_block(cfg, lp, x, pool, layer, pos, page_table, write_mask, use_kernel, trace_notes)
         h = rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps)
         if "mlp_gu" in lp:  # a leading dense layer
             x = x + _swiglu(h, lp["mlp_gu"], lp["mlp_down"])
@@ -362,11 +366,16 @@ def serving_forward(cfg: DeepseekV2Config):
     """The family seam of ``ServingEngine`` (docs/serving.md): the
     model's own step on its own cache kind.  ``fwd(params, tokens, k, v,
     pos, page_table=, write_mask=, row_valid=, take=) -> (logits, k, v,
-    aux)`` — ``k`` is the latent pool, ``v`` is None."""
+    aux)`` — ``k`` is the latent pool, ``v`` is None.  ``fwd.trace_notes``
+    holds what the programs traced through it said of themselves (which
+    form a prefill chunk's attention compiled to): ``stats()`` shows it."""
+    notes: Dict[str, Any] = {}
 
     def fwd(params, tokens, k, v, pos, page_table, write_mask=None, row_valid=None, take=None):
         logits, k, aux = forward_with_cache(params, tokens, k, pos, cfg, page_table, write_mask=write_mask,
-                                            row_valid=row_valid, take=take)
+                                            row_valid=row_valid, take=take, trace_notes=notes)
         return logits, k, v, aux
+
+    fwd.trace_notes = notes
 
     return fwd

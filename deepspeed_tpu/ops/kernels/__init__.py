@@ -39,14 +39,15 @@ _STATE: Dict[str, Any] = {
 _WARNED: set = set()
 
 
-def warn_once(key: str, msg: str) -> None:
+def warn_once(key, msg: str, level: str = "warning") -> None:
     """Trace-time-safe single-shot warning (dispatch sites run while
-    tracing, where per-instance flags would be a traced side effect)."""
+    tracing, where per-instance flags would be a traced side effect);
+    ``level="info"`` for a site that says which form it took."""
     if key not in _WARNED:
         _WARNED.add(key)
         from deepspeed_tpu.utils.logging import logger
 
-        logger.warning(msg)
+        getattr(logger, level)(msg)
 
 
 def configure(

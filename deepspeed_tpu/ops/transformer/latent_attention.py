@@ -16,7 +16,9 @@ function:
   are rebuilt per head from the cached latents of the chunk's context,
   ``block_pages`` pages at a time under an online softmax, for as many
   blocks as the context has — nothing of the slot's or the pool's size
-  is materialised;
+  is materialised (one block's scores, statistics and value product in
+  :func:`deepspeed_tpu.ops.kernels.mla_prefill.mla_prefill` when the
+  kernel suite is armed, the ``jnp`` lines otherwise);
 * **absorbed** (:func:`absorbed_attention`, decode): the key half of
   ``w_kvb`` is folded into the query, the value half into the output,
   so every head's query meets the shared row directly
@@ -117,13 +119,42 @@ def absorbed_attention(q_nope, q_pe, pool, layer: int, page_table, pos, w_kvb, n
     return jnp.einsum("bhc,chv->bhv", out.astype(dt), w_kvb[..., nope:].astype(dt))[:, None]
 
 
+def prefill_form(use_kernel: Optional[bool], H: int, T: int, S: int, nope: int, rope: int, dv: int):
+    """Which form a prefill chunk of these shapes takes: ``(kernel,
+    why_not)`` — the Mosaic kernel when the suite is armed (or
+    ``use_kernel`` says so) and it serves the shapes, else the ``jnp``
+    body and the reason; one line of the log for each distinct answer."""
+    from deepspeed_tpu.ops import kernels as _kernels
+    from deepspeed_tpu.ops.kernels.mla_prefill import mla_prefill_supported
+
+    if use_kernel is None:
+        use_kernel = _kernels.flash_decode_armed()
+    if not use_kernel:
+        why_not = "kernel suite not armed"
+    elif not mla_prefill_supported(H, T, S, nope, rope, dv):
+        why_not = f"unsupported shape (H {H}, T {T}, S {S}, head dims {nope} / {rope} / {dv})"
+    else:
+        why_not = ""
+    _kernels.warn_once(("mla_prefill", H, T, S, why_not),
+                       f"kernels: a prefill chunk (H {H}, T {T}, context blocks of {S}) takes "
+                       + (f"the jnp form of expanded_attention: {why_not}" if why_not else "mla_prefill"), level="info")
+    return not why_not, why_not
+
+
 def expanded_attention(q_nope, q_pe, pool, layer: int, page_table, pos, w_kvb, nope: int, sm_scale: float,
-                       block_pages: int = 8):
+                       block_pages: int = 8, use_kernel: Optional[bool] = None, trace_notes: Optional[dict] = None):
     """A chunk: ``q_nope (B, T, H, nope)``, ``q_pe (B, T, H, r)`` at
     positions ``pos[b] + t`` against the slot's cache (the chunk's own
     rows are already written).  Walks the context ``block_pages`` pages
     at a time, as far as the furthest query reaches; returns
-    ``(B, T, H, v)``."""
+    ``(B, T, H, v)``.
+
+    One block's scores, statistics and value product are
+    :func:`deepspeed_tpu.ops.kernels.mla_prefill.mla_prefill` where
+    :func:`prefill_form` says so, and the ``jnp`` lines below otherwise
+    (the CPU, small shapes; the kernel's reference).  ``trace_notes``, a
+    dict, is told which (``mla_prefill_kernel``, ``mla_prefill_fallback``:
+    ``ServingEngine.stats()``), while tracing."""
     B, T, H, _ = q_nope.shape
     P, page_len = page_table.shape[1], pool.shape[3]
     c = w_kvb.shape[0]
@@ -132,28 +163,50 @@ def expanded_attention(q_nope, q_pe, pool, layer: int, page_table, pos, w_kvb, n
         block_pages -= 1
     S = block_pages * page_len
     dt = q_nope.dtype
-    q_pos = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (B, T)
     n_blocks = jnp.minimum((jnp.max(pos) + T + S - 1) // S, P // block_pages)
+    kernel, why_not = prefill_form(use_kernel, H, T, S, nope, q_pe.shape[-1], dv)
+    if trace_notes is not None:
+        trace_notes.update(mla_prefill_kernel=kernel, mla_prefill_fallback=why_not)
 
-    def body(j, carry):
-        m, l, acc = carry
+    def block_rows(j):
         pages = jax.lax.dynamic_slice_in_dim(page_table, j * block_pages, block_pages, axis=1)
-        rows = _gather_slot(pool, layer, pages).astype(dt)  # (B, S, W)
-        kv = jnp.einsum("bsc,chx->bshx", rows[..., :c], w_kvb.astype(dt))  # per-head keys and values
-        s = jnp.einsum("bthn,bshn->bhts", q_nope, kv[..., :nope], preferred_element_type=jnp.float32)
-        s = s + jnp.einsum("bthr,bsr->bhts", q_pe, rows[..., c:], preferred_element_type=jnp.float32)
-        k_pos = j * S + jnp.arange(S, dtype=jnp.int32)
-        ok = k_pos[None, None, None, :] <= q_pos[:, None, :, None]
-        s = jnp.where(ok, s * sm_scale, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m - m_new)
-        l = alpha * l + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum("bhts,bshv->bhtv", p.astype(dt), kv[..., nope:],
-                                                  preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        return _gather_slot(pool, layer, pages).astype(dt)  # (B, S, W)
 
-    init = (jnp.full((B, H, T), NEG_INF, jnp.float32), jnp.zeros((B, H, T), jnp.float32),
-            jnp.zeros((B, H, T, dv), jnp.float32))
+    if kernel:
+        from deepspeed_tpu.ops.kernels.mla_prefill import STAT_LANES, mla_prefill
+
+        # head-major, as the kernel tiles them; the K/V expansion stays XLA's
+        qn, qp = q_nope.transpose(0, 2, 1, 3), q_pe.transpose(0, 2, 1, 3)
+        w_k, w_v = w_kvb[..., :nope].astype(dt), w_kvb[..., nope:].astype(dt)
+
+        def body(j, carry):
+            rows = block_rows(j)
+            return mla_prefill(qn, qp, jnp.einsum("bsc,chn->bhsn", rows[..., :c], w_k), rows[..., c:],
+                               jnp.einsum("bsc,chv->bhsv", rows[..., :c], w_v), pos, j * S, carry, sm_scale)
+
+        stat = (B, H, T, STAT_LANES)  # m and l as the kernel carries them: every lane alike
+    else:
+        stat = (B, H, T)
+        q_pos = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (B, T)
+
+        def body(j, carry):
+            m, l, acc = carry
+            rows = block_rows(j)
+            kv = jnp.einsum("bsc,chx->bshx", rows[..., :c], w_kvb.astype(dt))  # per-head keys and values
+            s = jnp.einsum("bthn,bshn->bhts", q_nope, kv[..., :nope], preferred_element_type=jnp.float32)
+            s = s + jnp.einsum("bthr,bsr->bhts", q_pe, rows[..., c:], preferred_element_type=jnp.float32)
+            k_pos = j * S + jnp.arange(S, dtype=jnp.int32)
+            ok = k_pos[None, None, None, :] <= q_pos[:, None, :, None]
+            s = jnp.where(ok, s * sm_scale, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            alpha = jnp.exp(m - m_new)
+            l = alpha * l + jnp.sum(p, axis=-1)
+            acc = acc * alpha[..., None] + jnp.einsum("bhts,bshv->bhtv", p.astype(dt), kv[..., nope:],
+                                                      preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+    init = (jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32), jnp.zeros((B, H, T, dv), jnp.float32))
     _, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
+    l = l.reshape(B, H, T, -1)[..., 0]
     return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).transpose(0, 2, 1, 3).astype(dt)

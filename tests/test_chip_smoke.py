@@ -140,6 +140,31 @@ def test_fused_update_compiles_for_v5e_with_no_pass_beside_the_kernels(opt_name,
     assert chip_smoke.leaf_sized_moves(hlo, int(np.prod(FULL.update_leaf))) == []
 
 
+@pytest.mark.parametrize("H,T,pages,dn,dr,dv,c", [
+    (128, 512, 64, 128, 64, 128, 512),  # the DeepSeek-V2 cell's chunk: context blocks of 8 pages
+    (8, 128, 4, 32, 64, 32, 128),       # chip_smoke's tiny DeepSeek-V2
+], ids=["published_dims", "smoke_dims"])
+def test_expanded_attention_compiles_for_v5e_with_the_scores_in_the_kernel(H, T, pages, dn, dr, dv, c, v5e_chip, monkeypatch):
+    """A prefill chunk's attention, by the chip's compiler without the
+    chip: Mosaic takes ``mla_prefill`` at these tiles, and no float32
+    ``(H, T, S)`` score block is left in the program round it."""
+    from deepspeed_tpu.ops.kernels import mla_prefill
+    from deepspeed_tpu.ops.transformer import latent_attention as la
+
+    monkeypatch.setattr(mla_prefill, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
+    on_chip = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    hlo = jax.jit(
+        lambda qn, qp, pool, table, pos, w: la.expanded_attention(qn, qp, pool, 1, table, pos, w, dn, 0.11, use_kernel=True)
+    ).lower(
+        on_chip((1, T, H, dn), jnp.bfloat16), on_chip((1, T, H, dr), jnp.bfloat16),
+        on_chip((2, 1 + pages, c + dr, 128), jnp.bfloat16), on_chip((1, pages), jnp.int32), on_chip((1,), jnp.int32),
+        on_chip((c, H, dn + dv), jnp.bfloat16),
+    ).compile().as_text()
+    assert chip_smoke.mosaic_kernels(hlo) == {"mla_prefill": 1}
+    S = min(pages, 8) * 128
+    assert f"f32[1,{H},{T},{S}]" not in hlo and f"f32[{H},{T},{S}]" not in hlo
+
+
 # ---------------------------------------------------------------------------
 # (b) the smoke's control flow, and its refusal to run off a TPU
 # ---------------------------------------------------------------------------
