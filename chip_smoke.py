@@ -77,7 +77,7 @@ class Smoke:
     seed: int = 0
 
 
-# GPT-2 Large, the repo's own 774M-zero3 rung (bench.py), does not leave
+# GPT-2 Large with the repo's 774M-zero3 recipe does not leave
 # room for the fp32 gradient accumulator that gas > 1 adds on one chip:
 # compiled for a v5e it wants 17.86 GB of 15.75 GB (PERF.md, "what stopped
 # the program").  The next preset down keeps every published width.
@@ -87,7 +87,6 @@ _TRAIN_CFG = dataclasses.replace(
 )
 FULL = Smoke(
     train_cfg=_TRAIN_CFG, seq=1024, micro=4, global_batch=16, steps=3,
-    # 8 slots x 512 is the repo's own serving shape (tools/bench_serving.py)
     serve_model="gpt2-xl", slots=8, max_len=512, page_len=128, prefill_chunk=64,
     prompt_lens=(32, 384), new_tokens=32, requests=8,
 )

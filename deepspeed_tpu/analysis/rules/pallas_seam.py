@@ -3,9 +3,9 @@
 Every Pallas kernel is a block-size decision (the autotuner's domain,
 ``ops/kernels/autotune.py``), an arming and interpret-or-compile
 decision (``utils/device.py``: one probe, no quiet fallback), a stable
-``name=`` the optimized HLO and the device trace find it by, and an
-attribution contract (docs/kernels.md: every kernel lands with a bucket
-pin and a bench rung).  A bare ``pl.pallas_call`` outside
+``name=`` the optimized HLO and the device trace find it by, and a
+``benchmark/kernels/<name>.py`` that prices its work for the roofline
+share (docs/kernels.md).  A bare ``pl.pallas_call`` outside
 ``deepspeed_tpu/ops/kernels/`` and ``deepspeed_tpu/ops/attention/``
 gets none of that: hardcoded tiles, its own platform probe, and cost
 invisible to the roofline table.  New kernels go in ``ops/kernels/``
@@ -20,7 +20,7 @@ import os
 from deepspeed_tpu.analysis.core import Severity, make_finding, register
 
 # the two sanctioned kernel homes (attention/ predates the seam and
-# already carries autotune defaults + attribution pins)
+# already carries autotune defaults + stable names)
 _EXEMPT = ("deepspeed_tpu/ops/kernels/", "deepspeed_tpu/ops/attention/")
 
 
@@ -40,8 +40,8 @@ def _is_pallas_call(node: ast.Call):
     Severity.B,
     "direct pl.pallas_call site outside deepspeed_tpu/ops/kernels/ and "
     "ops/attention/ — new kernels go through the kernel seam (autotuned "
-    "blocks, arming rule, stable name, attribution pin + bench rung per "
-    "docs/kernels.md)",
+    "blocks, arming rule, a stable name= and a benchmark/kernels/<name>.py "
+    "per docs/kernels.md)",
 )
 def check_raw_pallas_call(rule, ctx):
     path = os.path.normpath(ctx.path).replace(os.sep, "/")
@@ -52,7 +52,7 @@ def check_raw_pallas_call(rule, ctx):
             yield make_finding(
                 rule, ctx, node,
                 "raw 'pallas_call' outside the kernel seam — this kernel gets "
-                "no autotuned blocks, no arming rule or stable name, and no "
-                "attribution/bench coverage; put it in ops/kernels/ (see "
-                "docs/kernels.md)",
+                "no autotuned blocks, no arming rule and no stable name the "
+                "benchmark's trace reducer keys on; put it in ops/kernels/ "
+                "(see docs/kernels.md)",
             )

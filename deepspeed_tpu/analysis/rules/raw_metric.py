@@ -4,8 +4,8 @@ Every metric is an aggregation/export/cross-rank decision
 (docs/telemetry.md): a direct ``.add_scalar(...)`` /
 ``.write_events(...)`` call — or a hand-built ``SummaryWriter`` —
 outside ``deepspeed_tpu/telemetry/`` bypasses the registry, so the
-value never reaches the JSONL/Prometheus exporters, the cross-rank
-aggregate stream, or the bench-record digest, and its cadence/flush
+value never reaches the JSONL/Prometheus exporters or the cross-rank
+aggregate stream, and its cadence/flush
 behaviour is ad hoc.  Publish through the engine's
 :class:`~deepspeed_tpu.telemetry.TelemetryManager` (or
 ``telemetry.get_registry()`` for out-of-engine events); the
@@ -32,7 +32,7 @@ _EXEMPT = ("deepspeed_tpu/telemetry/", "deepspeed_tpu/utils/monitor.py")
     Severity.C,
     "direct add_scalar/write_events call or hand-built SummaryWriter "
     "outside deepspeed_tpu/telemetry/ — publish through the metrics "
-    "registry so exporters, cross-rank aggregation, and bench digests "
+    "registry so exporters and cross-rank aggregation "
     "see the value",
 )
 def check_raw_metric(rule, ctx):

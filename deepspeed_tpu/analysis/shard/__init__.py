@@ -16,8 +16,8 @@ suppression machinery (docs/ds_shard.md):
   source line.
 
 * **Pass 2 — collective audit (post-compile, ``hloaudit``):** walk
-  each AOT-compiled executable's optimized HLO (the PR 11 attribution
-  parser) and classify every all-gather / all-reduce / reduce-scatter
+  each AOT-compiled executable's optimized HLO (utils/hlo.py's
+  regexes) and classify every all-gather / all-reduce / reduce-scatter
   / all-to-all / collective-permute as *budgeted* (a CommLayer
   decision record or the PR 8 byte model covers it within tolerance)
   or *unbudgeted* (tier A: GSPMD inserted a reshard nobody priced —

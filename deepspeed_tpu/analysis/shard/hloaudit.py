@@ -1,7 +1,7 @@
 """ds_shard Pass 2 — compiled-collective audit (post-compile).
 
-Walks an AOT-compiled executable's optimized HLO (the PR 11
-attribution parser's regexes) and classifies every collective as
+Walks an AOT-compiled executable's optimized HLO (utils/hlo.py's
+instruction regex) and classifies every collective as
 *budgeted* or *unbudgeted* against the PR 6/PR 8 comm model:
 
 * each instruction's replica groups are mapped back to mesh axes (both
@@ -38,12 +38,10 @@ from deepspeed_tpu.analysis.shard.rules import (
     SiteContext,
     make_shard_finding,
 )
-from deepspeed_tpu.telemetry.attribution import (
-    _COLLECTIVES,
-    _INSTR_RE,
-    _META_RE,
-    _shape_elems_bytes,
-)
+from deepspeed_tpu.utils.hlo import COLLECTIVE_WEIGHTS, DTYPE_BYTES, INSTR_RE, SHAPE_RE, shape_bytes
+
+_COLLECTIVES = (*COLLECTIVE_WEIGHTS, "collective-broadcast")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 
 # budget-matching tolerance: actual <= budget * (1 + REL) + ABS.
 # REL covers GSPMD's extra partial-sum reductions riding the same link
@@ -117,7 +115,7 @@ def _parse_groups(raw: str) -> List[List[int]]:
 
 def _result_shapes(type_str: str) -> List[Tuple[int, ...]]:
     shapes = []
-    for _dt, dims in re.findall(r"(\w+)\[([\d,]*)\]", type_str):
+    for _dt, dims in SHAPE_RE.findall(type_str):
         shapes.append(tuple(int(d) for d in dims.split(",") if d))
     return shapes
 
@@ -125,20 +123,18 @@ def _result_shapes(type_str: str) -> List[Tuple[int, ...]]:
 def parse_collectives(hlo_text: str) -> List[CollectiveInstr]:
     out: List[CollectiveInstr] = []
     for line in hlo_text.splitlines():
-        m = _INSTR_RE.match(line)
+        m = INSTR_RE.match(line)
         if not m or m.group("opcode") not in _COLLECTIVES:
             continue
         if "-start" in m.group("opcode") or "-done" in m.group("opcode"):
             continue
-        _elems, nbytes = _shape_elems_bytes(m.group("type"))
+        nbytes = shape_bytes(m.group("type"))
         dtype_bytes = 4
         dt = re.match(r"\(?\s*(\w+)\[", m.group("type"))
         if dt:
-            from deepspeed_tpu.telemetry.attribution import _DTYPE_BYTES
-
-            dtype_bytes = _DTYPE_BYTES.get(dt.group(1), 4)
+            dtype_bytes = DTYPE_BYTES.get(dt.group(1), 4)
         gm = _GROUPS_RE.search(line)
-        meta = _META_RE.search(line)
+        meta = _OP_NAME_RE.search(line)
         fm = _SRC_FILE_RE.search(line)
         lm = _SRC_LINE_RE.search(line)
         rest = m.group("rest")
@@ -150,7 +146,7 @@ def parse_collectives(hlo_text: str) -> List[CollectiveInstr]:
             payload_bytes=nbytes,
             dtype_bytes=dtype_bytes,
             groups=_parse_groups(gm.group(1)) if gm else [],
-            op_name=meta.group("op") if meta else "",
+            op_name=meta.group(1) if meta else "",
             source_file=fm.group(1) if fm else None,
             source_line=int(lm.group(1)) if lm else 1,
             operand_shapes=operand_shapes,

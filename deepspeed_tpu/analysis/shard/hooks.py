@@ -123,7 +123,7 @@ def _jit_hlo_thunk(jit_fn: Any, args: Tuple[Any, ...],
                    collector: ShardCollector, site: str) -> Callable[[], Optional[str]]:
     """Deferred AOT lower+compile of a plain-jit site against the
     abstract shapes of its first real invocation (the
-    serving.attribute_decode pattern).  Compile failures are recorded
+    ServingEngine.compiled_step pattern).  Compile failures are recorded
     as skips, not findings — pipe SPMD doesn't compile on every
     backend (tests/capabilities.py)."""
     abstract = _abstract(args)

@@ -1705,9 +1705,7 @@ class TelemetryConfig:
     profiler_capture_ms: int = C.TELEMETRY_PROFILER_CAPTURE_MS_DEFAULT
     slo_ttft_breach_ms: float = C.TELEMETRY_SLO_TTFT_BREACH_MS_DEFAULT
     aggregate: bool = C.TELEMETRY_AGGREGATE_DEFAULT
-    # per-kernel cost attribution + runtime anomaly watch (ISSUE 11)
-    attribution: bool = C.TELEMETRY_ATTRIBUTION_DEFAULT
-    attribution_max_hlo_mb: float = C.TELEMETRY_ATTRIBUTION_MAX_HLO_MB_DEFAULT
+    # runtime anomaly watch (telemetry/anomaly.py)
     spike_factor: float = C.TELEMETRY_SPIKE_FACTOR_DEFAULT
     spike_min_window: int = C.TELEMETRY_SPIKE_MIN_WINDOW_DEFAULT
     straggler_factor: float = C.TELEMETRY_STRAGGLER_FACTOR_DEFAULT
@@ -1741,10 +1739,6 @@ class TelemetryConfig:
                 _pop(d, "slo_ttft_breach_ms", C.TELEMETRY_SLO_TTFT_BREACH_MS_DEFAULT)
             ),
             aggregate=bool(_pop(d, "aggregate", C.TELEMETRY_AGGREGATE_DEFAULT)),
-            attribution=bool(_pop(d, "attribution", C.TELEMETRY_ATTRIBUTION_DEFAULT)),
-            attribution_max_hlo_mb=float(
-                _pop(d, "attribution_max_hlo_mb", C.TELEMETRY_ATTRIBUTION_MAX_HLO_MB_DEFAULT)
-            ),
             spike_factor=float(_pop(d, "spike_factor", C.TELEMETRY_SPIKE_FACTOR_DEFAULT)),
             spike_min_window=int(
                 _pop(d, "spike_min_window", C.TELEMETRY_SPIKE_MIN_WINDOW_DEFAULT)
@@ -1792,11 +1786,6 @@ class TelemetryConfig:
             raise DeepSpeedConfigError(
                 f"'{C.TELEMETRY}.straggler_factor' must be > 1, "
                 f"got {out.straggler_factor}"
-            )
-        if out.attribution_max_hlo_mb <= 0:
-            raise DeepSpeedConfigError(
-                f"'{C.TELEMETRY}.attribution_max_hlo_mb' must be > 0, "
-                f"got {out.attribution_max_hlo_mb}"
             )
         return out
 

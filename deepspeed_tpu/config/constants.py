@@ -384,8 +384,6 @@ TELEMETRY_TRACE_BUFFER_DEFAULT = 100_000  # span ring-buffer events
 TELEMETRY_PROFILER_CAPTURE_MS_DEFAULT = 2000  # jax.profiler window length
 TELEMETRY_SLO_TTFT_BREACH_MS_DEFAULT = 0.0  # 0 = no on-breach capture
 TELEMETRY_AGGREGATE_DEFAULT = True  # piggyback snapshots on supervision beats
-TELEMETRY_ATTRIBUTION_DEFAULT = True  # per-kernel cost attribution at compile time
-TELEMETRY_ATTRIBUTION_MAX_HLO_MB_DEFAULT = 256.0  # skip the walk past this text size
 TELEMETRY_SPIKE_FACTOR_DEFAULT = 2.5  # step wall > factor x window mean -> anomaly
 TELEMETRY_SPIKE_MIN_WINDOW_DEFAULT = 8  # samples before the spike watch arms
 TELEMETRY_STRAGGLER_FACTOR_DEFAULT = 1.5  # rank wall > factor x cluster median

@@ -713,7 +713,6 @@ def telemetry_report(config=None) -> None:
             if t.enabled else "off (telemetry disabled)",
         ),
     ]
-    rows += _attribution_rows(t)
     for name, value in rows:
         print(f"{name} " + "." * (30 - len(name)) + f" {value}")
 
@@ -764,28 +763,6 @@ def _kv_tier_rows() -> list:
     ]
 
 
-def _attribution_rows(t) -> list:
-    """Per-kernel attribution summary (docs/telemetry.md §Attribution):
-    the top-3 buckets by roofline time share from the LIVE registry's
-    ``attribution/*`` gauges, when a compiled step has published them."""
-    if not t.attribution:
-        return [("attribution", "off (telemetry.attribution=false)")]
-    from deepspeed_tpu import telemetry as tel
-
-    reg = tel.get_registry()
-    shares = []
-    for m in reg.metrics():
-        if m.name == "attribution/time_share_pct" and m.kind == "gauge" \
-                and m.value is not None:
-            shares.append((m.labels.get("bucket", "?"),
-                           m.labels.get("engine", "?"), m.value))
-    if not shares:
-        return [("attribution", "armed (no compiled step has published yet)")]
-    shares.sort(key=lambda s: -s[2])
-    top = ", ".join(f"{b} {v:.0f}% [{e}]" for b, e, v in shares[:3])
-    return [("attribution top-3", top)]
-
-
 def kernels_report(config=None) -> None:
     """Pallas kernel-suite rows (docs/kernels.md): which kernels are
     armed for this process/backend and the block autotuner cache state
@@ -808,61 +785,6 @@ def kernels_report(config=None) -> None:
         ("autotune entries", f"{at['entries']} on disk, {at['lru']} in LRU"),
         ("autotune hits/misses", f"{at['hits']}/{at['misses']} ({at['tunes']} tuned this process)"),
     ]
-    for name, value in rows:
-        print(f"{name} " + "." * (30 - len(name)) + f" {value}")
-
-
-def bench_history_report() -> None:
-    """Bench trajectory rows: last run's sha + rung count from
-    BENCH.json, history depth and the current regression-gate status
-    from ``bench_history.jsonl`` (docs/performance.md §Regression
-    workflow)."""
-    import json
-
-    from deepspeed_tpu.telemetry import regression as reg
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    # honors the DS_BENCH_HISTORY_PATH override, like every writer
-    hist_path = reg.default_history_path(root)
-    bench_path = os.path.join(root, "BENCH.json")
-    print()
-    print("bench history / perf sentinel:")
-    rows = []
-    if os.path.exists(bench_path):
-        try:
-            with open(bench_path) as f:
-                doc = json.load(f)
-            rungs = doc.get("rungs", {})
-            measured = sum(1 for r in rungs.values() if not r.get("skipped"))
-            rows.append((
-                "last bench run",
-                f"sha {doc.get('git_sha', '?')}, {measured}/{len(rungs)} rung(s) "
-                f"measured{'' if doc.get('complete') else ' (INCOMPLETE)'}",
-            ))
-        except (OSError, ValueError) as e:
-            rows.append(("last bench run", f"BENCH.json unreadable ({e})"))
-    else:
-        rows.append(("last bench run", "no BENCH.json yet (run bench.py)"))
-    history = reg.history_load(hist_path)
-    bench_lines = [h for h in history if h.get("kind") == "bench"]
-    if not bench_lines:
-        rows.append(("bench history", "empty (bench runs append bench_history.jsonl)"))
-    else:
-        runs = len({h.get("run_id") for h in bench_lines})
-        rows.append((
-            "bench history",
-            f"{len(bench_lines)} record(s) over {runs} run(s), "
-            f"{len({h.get('metric') for h in bench_lines})} metric(s)",
-        ))
-        ok, bad = reg.gate(reg.bench_diff(history))
-        # the band is named so a divergence from a CI gate run with
-        # per-metric overrides reads as a settings difference, not a bug
-        rows.append((
-            "regression gate",
-            f"{GREEN}GREEN{END} (default 5% band)" if ok
-            else f"{RED}RED{END} at the default 5% band ({len(bad)} regressing: "
-                 + ", ".join(v["metric"] for v in bad[:3]) + ")",
-        ))
     for name, value in rows:
         print(f"{name} " + "." * (30 - len(name)) + f" {value}")
 
@@ -962,7 +884,6 @@ def cli_main() -> int:
     telemetry_report()
     kernels_report()
     analysis_report()
-    bench_history_report()
     return 0 if ok else 1
 
 

@@ -3,8 +3,8 @@
 The decode hot path (one query token per live slot against an S-position
 cache) was a chain of XLA fusions: for the int8 pool it **dequantized
 the codes, materialized fp32-sized score/operand tensors, then
-attended** — the ``kv-dequant`` attribution bucket that caps GPT-Neo
-2.7B long-context int8 decode (~1,152 tok/s, BENCH_EXTRA).  This kernel
+attended** — the ``kv_dequant`` scope of the lax path, which capped
+long-context int8 decode (not measured on this code).  This kernel
 collapses the round-trip: int8 codes + scales stream HBM→VMEM once,
 dequantization happens **in-register inside the flash inner loop**
 (codes are the dot operands; the per-row scales fold into the score row

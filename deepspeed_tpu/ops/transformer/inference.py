@@ -252,9 +252,9 @@ def cache_attention(q, k_cache, v_cache, pos, sm_scale: Optional[float] = None,
         # (T,S) score matrix and folded into p before the value dot.
         # Dequantizing first (codes*scale as the operand) defeats
         # operand fusion and materializes an f32-sized cache per step.
-        # The kv_dequant scope pins this round-trip to the `kv-dequant`
-        # attribution bucket (docs/telemetry.md) — the cost the fused
-        # decode kernel deletes, so the pin is visible exactly when
+        # The kv_dequant scope names this round-trip in a profiler
+        # trace's op names — the cost the fused
+        # decode kernel deletes, so the name is visible exactly when
         # this lax path runs.
         with jax.named_scope("kv_dequant"):
             k_scale = k_cache["s"][..., 0][:, :, None, :]  # (B,H,1,S)

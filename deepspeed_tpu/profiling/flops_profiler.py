@@ -17,7 +17,7 @@ epilogues, and the backward pass).  The profiler therefore:
   CAVEAT: XLA cost analysis counts a ``lax.scan`` body ONCE, not per
   trip — models that scan over layers (models/gpt2.py) or engines that
   scan over micro-batches under-report flops by that factor.  For MFU
-  use an analytic count (bench.py does: flops/token ≈ 6N + attention),
+  use an analytic count (flops/token ≈ 6N + attention, as benchmark/stats.py does),
   or unroll the scan for profiling;
 * measures wall clock around real calls for achieved FLOPS / MFU against
   a configurable peak;
@@ -97,16 +97,15 @@ def derive_step_stats(
     wall_s: float,
     device_kind: Optional[str] = None,
 ) -> Dict[str, float]:
-    """The one MFU/HBM derivation (shared by the profiler, the engine's
-    telemetry gauges, and bench records): compiled-cost FLOPs and bytes
+    """The one MFU/HBM derivation (shared by the profiler and the engine's
+    telemetry gauges): compiled-cost FLOPs and bytes
     over a measured step wall against the PER-CHIP peak.
 
     ``cost`` is the executable's ``cost_analysis()`` dict — the
     **per-device** flops/bytes of the GSPMD-partitioned module, which is
     why the denominator is one chip's peak.  NB the module-level scan
     caveat applies: a ``lax.scan`` body is counted ONCE — profile with
-    the scan unrolled (bench.py's headline config does) for truthful
-    absolute numbers."""
+    the scan unrolled for truthful absolute numbers."""
     cost = cost or {}
     flops = float(cost.get("flops", 0.0))
     hbm = cost_bytes(cost)

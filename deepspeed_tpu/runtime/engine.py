@@ -1392,17 +1392,9 @@ class DeepSpeedEngine:
         step."""
         return self._train_executable
 
-    def train_step_attribution(self):
-        """The compiled train step's per-kernel cost table
-        (:class:`~deepspeed_tpu.telemetry.attribution.Attribution`), or
-        None before the first compile / when the plane is disabled —
-        surfaced by ds_report, bench records, and the perf-sentinel
-        roofline artifact."""
-        return self.telemetry.attribution() if self.telemetry is not None else None
-
     def comm_summary(self) -> Dict[str, Any]:
         """Active comm-strategy table + the per-step comm-bytes model
-        (docs/comm.md) — surfaced by ds_report and bench.py records."""
+        (docs/comm.md) — surfaced by ds_report."""
         from deepspeed_tpu.comm.strategy import step_comm_bytes
 
         model = step_comm_bytes(
@@ -1531,8 +1523,7 @@ class DeepSpeedEngine:
                 # micro-batch (shape[0] // gas wins below) and every
                 # per-chip throughput normalization drifts with it —
                 # surface it once; callers that need the hard guarantee
-                # pin train_batch_size to the fed shape (see
-                # tools/bench_long_context.py)
+                # pin train_batch_size to the fed shape
                 self._batch_mismatch_warned = True
                 logger.warning(
                     f"train_batch fed {fed} samples but the config triad says "
@@ -1796,10 +1787,6 @@ class DeepSpeedEngine:
                 # the compiled step's cost analysis is the numerator of
                 # the live MFU / HBM-GB/s gauges (docs/telemetry.md)
                 self.telemetry.set_step_cost(self._train_step_cost)
-                # per-kernel cost attribution (docs/telemetry.md
-                # §Attribution): one HLO walk per new executable —
-                # compile-time only, nothing added to the hot path
-                self.telemetry.attribute_compiled(executable, "train_step")
         profile_step = self._host_global_step + 1
         self.flops_profiler.start_step(profile_step)
         donated = jax.tree.leaves(self.state) if san is not None else None
@@ -1910,9 +1897,7 @@ class DeepSpeedEngine:
         carry double-buffered per iteration); True = fully unrolled
         (no loop, n× graph); an int k >= 2 = partial unroll (k step
         bodies per while iteration — carry copies amortize 1/k at k×
-        graph size); k == 1 is the plain scan, identical to False
-        (bench.py's ``DS_TB_UNROLL`` uses the same convention, with
-        ``full`` as the full-unroll sentinel).
+        graph size); k == 1 is the plain scan, identical to False.
         """
         batches = list(batches)
         n = len(batches)

@@ -16,7 +16,7 @@ loop plus the instrumentation to prove it:
 * :mod:`~deepspeed_tpu.runtime.overlap.timeline` —
   :class:`StepTimeline`, honest (fenced) per-step attribution of wall
   time to ``data_wait`` / ``compute`` / ``ckpt_stall`` / ``compile`` /
-  ``other``, exported through ``bench.py`` and ``ds_report``;
+  ``other``, exported through the telemetry plane and ``ds_report``;
 * :mod:`~deepspeed_tpu.runtime.overlap.worker` —
   :class:`BoundedWorker`, the shared bounded-queue background thread
   (serving KV tier migration rides on it; see

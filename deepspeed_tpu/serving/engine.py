@@ -272,7 +272,7 @@ class ServingEngine:
 
         # telemetry (docs/telemetry.md): attach to whatever plane the
         # process armed (the train engine's configure(), or an explicit
-        # telemetry.configure() from bench_serving / the smoke tool) —
+        # telemetry.configure() from a tool) —
         # a no-config process gets no-op publishes.  The scheduler's
         # lifecycle events become per-request spans + TTFT/TPOT
         # histograms; step phases ride the timeline attachment.
@@ -294,7 +294,7 @@ class ServingEngine:
         self._prefill_fn = None
         self._prefill_jit = None  # unwrapped jit handle (ds_shard audit)
         self._decode_fn = None
-        self._decode_jit = None  # unwrapped jit handle (attribute_decode)
+        self._decode_jit = None  # unwrapped jit handle (compiled_step)
         self.prefill_compiles = 0
         self.decode_compiles = 0
         self._step_count = 0
@@ -347,7 +347,7 @@ class ServingEngine:
         signature is checked — a second signature at either site is a
         recorded recompile (the compile-stability tests gate on this).
         Owner-scoped so several serving engines in one armed process
-        (the bench sweeps builds 8) each keep their first-compile grace."""
+        (a fleet builds one a replica) each keep their first-compile grace."""
         san = self._sanitizer
         if san is not None:
             return san.recompile.wrap(fn, site=site, owner=id(self))
@@ -612,16 +612,6 @@ class ServingEngine:
             self._get_prefill()
             return self._prefill_jit.lower(*self._prefill_abstract_args()).compile()
         raise ValueError(f"compiled_step: 'prefill' or 'decode', got {which!r}")
-
-    def attribute_decode(self):
-        """Per-kernel cost attribution of the decode executable
-        (docs/telemetry.md §Attribution) over :meth:`compiled_step`.
-        Returns an
-        :class:`~deepspeed_tpu.telemetry.attribution.Attribution` or
-        None when the backend exposes no HLO text."""
-        from deepspeed_tpu.telemetry.attribution import attribute_executable
-
-        return attribute_executable(self.compiled_step("decode"), label="serving_decode")
 
     # ------------------------------------------------------------------
     # measured service rate (the admission controller's feed)
@@ -1125,7 +1115,7 @@ class ServingEngine:
     def _on_request_event(self, kind: str, r, now: float, step: int) -> None:
         """Scheduler lifecycle hook → spans on the request's own trace
         lane (tid = request id): queue → prefill → decode → retire, plus
-        the TTFT / per-output-token histograms the SLO bench reads.
+        the TTFT / per-output-token histograms.
         Host dict ops only; spans cost nothing when tracing is off."""
         tm = self.telemetry
         tracer = tm.tracer if tm.tracer.enabled else None
@@ -1260,41 +1250,6 @@ class ServingEngine:
                           "pages_live": st["pages_live"]},
                 )
             self._kv_evt_seen[key] = int(st[key])
-
-    def telemetry_summary(self) -> Dict[str, Any]:
-        """Compact roll-up for bench records — MODEL-derived, unlike the
-        train engine's compiled-cost gauges (the serving executables are
-        plain jit; docs/telemetry.md): ``mfu`` from 2·N FLOPs per
-        generated token over the live slots at the measured step wall
-        (per-chip share), and ``hbm_bytes_per_step`` as the decode
-        roofline traffic model — params read once per token step plus
-        the KV pool touched — an upper bound, not a measured access
-        count; plus the registry digest.  ``mfu`` is None on a device
-        with no published peak."""
-        from deepspeed_tpu.profiling.flops_profiler import DEVICE_PEAKS
-
-        mcfg = self.engine.model_config
-        n_params = mcfg.num_params() if hasattr(mcfg, "num_params") else 0
-        s = self.timeline.summary()
-        wall_s = s.get("wall_ms", 0.0) / 1e3
-        live = s.get("live_slots", 0.0)
-        # per-chip share of the model work (bench.py's tokens/s/chip
-        # convention): a sharded model splits the 2N across devices
-        flops_step = 2.0 * n_params * max(live, 0.0) / jax.device_count()
-        peak = DEVICE_PEAKS.get(jax.devices()[0].device_kind)
-        mfu = (
-            flops_step / wall_s / (peak.bf16_tflops * 1e12)
-            if peak is not None and wall_s > 0 and flops_step else None
-        )
-        param_bytes = sum(
-            int(np.prod(np.shape(p)) * np.dtype(p.dtype).itemsize)
-            for p in jax.tree.leaves(self.engine.params)
-        )
-        return {
-            "mfu": None if mfu is None else round(mfu, 6),
-            "hbm_bytes_per_step": param_bytes + self.pool.cache_bytes(),
-            "telemetry": self.telemetry.digest(),
-        }
 
     # ------------------------------------------------------------------
     def _run_prefill(self, job: PrefillJob) -> None:

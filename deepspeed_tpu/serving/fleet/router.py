@@ -803,7 +803,7 @@ class FleetRouter:
             self._refire(hd, dead, now)
 
     # ------------------------------------------------------------------
-    # introspection (ds_report fleet rows, bench records)
+    # introspection (ds_report fleet rows)
     # ------------------------------------------------------------------
     def replicas_by_state(self) -> Dict[str, int]:
         out: Dict[str, int] = {}

@@ -30,22 +30,8 @@ from deepspeed_tpu.telemetry.exporters import (
     PrometheusTextfileExporter,
     TensorBoardSink,
 )
-from deepspeed_tpu.telemetry.attribution import (
-    BUCKETS,
-    Attribution,
-    attribute_executable,
-    attribute_hlo_text,
-    attribute_jit,
-)
 from deepspeed_tpu.telemetry.manager import TelemetryManager
-from deepspeed_tpu.telemetry.regression import (
-    bench_diff,
-    check_step_spike,
-    find_stragglers,
-    history_append,
-    history_bless,
-    history_load,
-)
+from deepspeed_tpu.telemetry.anomaly import check_step_spike, find_stragglers
 from deepspeed_tpu.telemetry.registry import (
     Counter,
     Gauge,
@@ -145,7 +131,7 @@ def manager_for(label: str, monitor=None) -> TelemetryManager:
 
 
 def flush() -> None:
-    """Force an immediate export (bench records read files right after)."""
+    """Force an immediate export (a reader of the files comes right after)."""
     if _EXPORT_LOOP is not None:
         _EXPORT_LOOP.flush()
 
@@ -213,9 +199,6 @@ __all__ = [
     "JsonlExporter", "PrometheusTextfileExporter", "TensorBoardSink", "ExportLoop",
     "CrossRankAggregator", "encode_metrics", "decode_metrics",
     "TelemetryManager",
-    "Attribution", "BUCKETS",
-    "attribute_executable", "attribute_hlo_text", "attribute_jit",
-    "bench_diff", "history_append", "history_bless", "history_load",
     "check_step_spike", "find_stragglers",
     "configure", "manager_for", "get_registry", "get_tracer",
     "flush", "export_trace", "shutdown", "status", "reset_for_tests",

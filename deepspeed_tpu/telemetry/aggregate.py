@@ -114,9 +114,9 @@ class CrossRankAggregator:
             }
             for name, vs in sorted(names.items())
         }
-        # runtime anomaly watch (regression.py): rank step wall vs the
+        # runtime anomaly watch (anomaly.py): rank step wall vs the
         # cluster median, flagged in the SAME stream that detects death
-        from deepspeed_tpu.telemetry.regression import find_stragglers
+        from deepspeed_tpu.telemetry.anomaly import find_stragglers
 
         stragglers = find_stragglers(
             latest, alive, factor=self.straggler_factor

@@ -148,8 +148,8 @@ class TensorBoardSink:
 
 class ExportLoop:
     """One daemon thread flushing the registry to every exporter on a
-    cadence; ``flush()`` forces an immediate export (bench records, the
-    atexit hook).  Exporter failures are logged, never raised — losing a
+    cadence; ``flush()`` forces an immediate export (tools that read
+    the files next, the atexit hook).  Exporter failures are logged, never raised — losing a
     scrape must not take down the run."""
 
     def __init__(self, registry, exporters, interval_seconds: float = 10.0):

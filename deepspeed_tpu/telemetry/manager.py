@@ -32,7 +32,6 @@ class TelemetryManager:
         self.monitor = monitor
         self.config = config
         self._cost: Dict[str, float] = {}
-        self._attribution = None  # per-kernel cost table (attribution.py)
         self._spikes = 0
         self._kind: Optional[str] = None
         self._profiler_fired = False
@@ -111,43 +110,6 @@ class TelemetryManager:
     def step_cost(self) -> Dict[str, float]:
         return dict(self._cost)
 
-    # -- per-kernel attribution (compile-time one-shot; attribution.py) ------
-    def set_attribution(self, attribution) -> None:
-        """Carry the compiled step's per-kernel cost table: registry
-        gauges + Perfetto counter tracks now, ds_report/bench rows on
-        demand.  Never raises — attribution is evidence, not control."""
-        if attribution is None:
-            return
-        self._attribution = attribution
-        try:
-            attribution.publish(self)
-        except Exception as e:  # noqa: BLE001
-            logger.warning(f"telemetry: attribution publish failed: {e!r}")
-
-    def attribution(self):
-        return self._attribution
-
-    def attribute_compiled(self, compiled, label: str) -> None:
-        """Walk one compiled executable into the bucket table (gated on
-        ``telemetry.attribution``; skipped while the plane is disabled —
-        the walk is one-shot at compile time but still not free)."""
-        cfg = self.config
-        if cfg is not None and not getattr(cfg, "attribution", True):
-            return
-        if not (self.registry.enabled or self.tracer.enabled) or not self._has_peak():
-            return
-        from deepspeed_tpu.telemetry.attribution import attribute_executable
-
-        try:
-            attr = attribute_executable(
-                compiled, label=label, backend=self._device_kind(),
-                max_hlo_mb=float(getattr(cfg, "attribution_max_hlo_mb", 256.0) or 256.0),
-            )
-        except Exception as e:  # noqa: BLE001
-            logger.warning(f"telemetry: attribution walk failed: {e!r}")
-            return
-        self.set_attribution(attr)
-
     def _device_kind(self) -> str:
         # memoized: jax.devices() is not free on a per-step path
         if self._kind is None:
@@ -204,10 +166,10 @@ class TelemetryManager:
 
     def _check_spike(self, prefix: str, wall_ms: float,
                      prev_mean: Optional[float], prev_count: int) -> None:
-        """Runtime anomaly watch (regression.py): a step wall far above
+        """Runtime anomaly watch (anomaly.py): a step wall far above
         its own recent window becomes a structured event — counter,
         Perfetto instant, and a (rate-limited) log line."""
-        from deepspeed_tpu.telemetry.regression import check_step_spike
+        from deepspeed_tpu.telemetry.anomaly import check_step_spike
 
         cfg = self.config
         event = check_step_spike(
@@ -263,11 +225,10 @@ class TelemetryManager:
             summary.get("grad_exchange_bytes", 0)
         )
 
-    # -- summaries for bench records / ds_report ------------------------------
+    # -- summaries ----------------------------------------------------------
     def summary(self) -> Dict[str, Any]:
-        """Compact per-engine roll-up for bench records: the live MFU
-        gauge, the compiled step's FLOPs/HBM bytes, and the snapshot
-        digest."""
+        """Compact per-engine roll-up: the live MFU gauge, the compiled
+        step's FLOPs/HBM bytes, and the snapshot digest."""
         from deepspeed_tpu.profiling.flops_profiler import cost_bytes
 
         mfu = self.registry.gauge("mfu", engine=self.label)
@@ -277,19 +238,12 @@ class TelemetryManager:
             "hbm_bytes_per_step": cost_bytes(self._cost) or None,
             "telemetry": self.digest(),
         }
-        if self._attribution is not None:
-            # top buckets by roofline time share — the bench record's
-            # one-line answer to "which kernel family owns this step"
-            out["attribution_top"] = [
-                {"bucket": b, "time_share_pct": s}
-                for b, s in self._attribution.top_buckets(3)
-            ]
         return out
 
     def digest(self) -> Dict[str, Any]:
-        """Content digest of the current compact snapshot — a bench
-        record carries it so two runs' telemetry states are comparable
-        at a glance without embedding the whole snapshot."""
+        """Content digest of the current compact snapshot, so two runs'
+        telemetry states are comparable at a glance without embedding
+        the whole snapshot."""
         compact = self.registry.snapshot_compact()
         payload = json.dumps(compact, sort_keys=True).encode()
         return {

@@ -132,8 +132,7 @@ class TraceBuffer:
                     ts: Optional[float] = None, pid: int = PID_ENGINE,
                     tid: int = 0) -> None:
         """One "C" counter sample: Perfetto renders each ``series`` key
-        as a stacked counter track under ``name`` (the attribution
-        module emits per-bucket time-share tracks this way)."""
+        as a stacked counter track under ``name``."""
         if not self.enabled:
             return
         self._ensure_meta(pid, tid)

@@ -3,8 +3,8 @@
 The reference tracks its comm volume implicitly (bucket sizes,
 allgather_bucket_size knobs, stage2.py:1489 allgather tail); under XLA
 the compiled HLO is the ground truth, so the framework ships a parser
-that attributes wire bytes to each collective op.  Used by the ZeRO
-comm bench rung (bench.py), the 1-bit wire-byte regression tests
+that attributes wire bytes to each collective op.  Used by ds_shard's
+collective audit (analysis/shard/), the 1-bit wire-byte regression tests
 (tests/test_onebit.py), and the ZeRO collective-byte regression test.
 """
 from __future__ import annotations
@@ -25,9 +25,29 @@ COLLECTIVE_WEIGHTS = {
 DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8, "u64": 8,
     "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1, "pred": 1,
+    "c64": 8, "c128": 16, "s4": 1, "u4": 1, "f8e4m3fn": 1, "f8e5m2": 1,
+    "f8e4m3b11fnuz": 1, "f8e4m3fnuz": 1, "f8e5m2fnuz": 1,
 }
 
-_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+INSTR_RE = re.compile(
+    r"^\s+(?:ROOT\s+)?%(?P<name>[^\s=]+)\s+=\s+(?P<type>\([^)]*\)|\S+)\s+"
+    r"(?P<opcode>[\w-]+)\((?P<rest>.*)$"
+)
+
+
+def shape_bytes(type_str: str, dtype_filter: Optional[str] = None) -> int:
+    """Total bytes of an HLO type string (a tuple's members summed), of one dtype tag if given."""
+    nbytes = 0
+    for dt, dims in SHAPE_RE.findall(type_str):
+        if dt not in DTYPE_BYTES or (dtype_filter and dt != dtype_filter):
+            continue
+        n = 1
+        for d in dims.split(","):
+            if d:
+                n *= int(d)
+        nbytes += n * DTYPE_BYTES[dt]
+    return nbytes
 
 
 def collective_bytes_by_op(hlo_text: str, dtype_filter: Optional[str] = None) -> Dict[str, int]:
@@ -52,16 +72,7 @@ def collective_bytes_by_op(hlo_text: str, dtype_filter: Optional[str] = None) ->
                     cut, weight, kind = i, w, c
         if cut < 0:
             continue
-        n_bytes = 0
-        for dt, dims in _SHAPE_RE.findall(rhs[:cut]):
-            if dt not in DTYPE_BYTES or (dtype_filter and dt != dtype_filter):
-                continue
-            n = 1
-            for d in dims.split(","):
-                if d:
-                    n *= int(d)
-            n_bytes += n * DTYPE_BYTES[dt] * weight
-        totals[kind] = totals.get(kind, 0) + n_bytes
+        totals[kind] = totals.get(kind, 0) + shape_bytes(rhs[:cut], dtype_filter) * weight
     return totals
 
 

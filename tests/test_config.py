@@ -242,6 +242,8 @@ class TestUnknownKeyNesting:
                 r"'flops_profiler\.profile_steps' \(did you mean 'profile_step'\?\)",
             ),
             ("tensorboard", {"output_pth": "x"}, r"'tensorboard\.output_pth' \(did you mean 'output_path'\?\)"),
+            # the HLO-text attribution went with its fields (PR 30): an old config says so by name
+            ("telemetry", {"attribution": False}, r"'telemetry\.attribution'"),
         ],
     )
     def test_every_block_reports_full_path(self, block, payload, expect):

@@ -8,8 +8,7 @@ the ragged-leaf XLA fallback; the engine-level fused-update seam
 (trajectory parity against the stock XLA path); autotuner cache
 round-trip, corrupt-cache fallback-to-defaults, mode semantics, and the
 LRU; serving churn parity with the kernel armed (decode_compiles still
-== 1 under armed ds_san); and the attribution pin that the
-``kv-dequant`` bucket goes to ~0 with the fused decode kernel armed.
+== 1 under armed ds_san).
 
 Off-TPU every kernel runs under ``interpret=True`` — the same kernel
 body, so the parity statements carry to hardware modulo MXU rounding.
@@ -520,39 +519,6 @@ def test_serving_churn_parity_with_kernel_armed(monkeypatch):
         n_new = 4 if rid == rids[0] else 3
         solo = np.asarray(eng.generate(prompt[None, :], max_new_tokens=n_new))[0]
         np.testing.assert_array_equal(res[rid].tokens(), solo)
-
-
-# ---------------------------------------------------------------------------
-# attribution pin: the kv-dequant bucket dies with the kernel armed
-# ---------------------------------------------------------------------------
-
-def test_attribution_kv_dequant_bucket_eliminated(cpu_peak):
-    from deepspeed_tpu.telemetry.attribution import attribute_executable
-
-    B, H, S, d = 4, 2, 256, 64
-    q = _rand((B, H, 1, d), jnp.bfloat16, seed=1)
-    kc, vc = _int8_cache(_rand((B, H, S, d), seed=2), _rand((B, H, S, d), seed=3))
-    pos = jnp.asarray([5, 100, 255, 0], jnp.int32)
-
-    def attribute(use_kernel):
-        f = jax.jit(lambda q, kc, vc, p: cache_attention(
-            q, kc, vc, p, use_kernel=use_kernel
-        ))
-        return attribute_executable(
-            f.lower(q, kc, vc, pos).compile(), label=f"decode_k{use_kernel}"
-        )
-
-    off = attribute(False)
-    on = attribute(True)
-    assert off is not None and on is not None
-    # lax int8 decode pays the dequant round-trip...
-    assert off.buckets["kv-dequant"].flops > 0
-    assert off.buckets["kv-dequant"].bytes > 0
-    # ...the fused kernel eliminates the bucket (scales fold in-register
-    # into attention work)
-    assert on.buckets["kv-dequant"].flops == 0
-    assert on.buckets["kv-dequant"].bytes == 0
-    assert on.buckets["attention"].flops > 0
 
 
 def test_kernels_report_shape(monkeypatch):

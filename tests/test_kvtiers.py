@@ -130,6 +130,50 @@ def test_tiered_multiturn_bit_identical_vs_all_hbm(eng, tmp_path):
     srv._tiers.close()
 
 
+def test_sessions_at_4x_device_kv_capacity_no_queue_full(eng, tmp_path):
+    """The capacity gate: eight 3-turn sessions whose parked KV is four
+    times the device pool's usable pages are all served — no submit
+    raises ``ServingQueueFull``, the tiers swap, and every output is
+    bit-identical to an all-HBM pool that holds the whole working set."""
+    page_len, tail_len, budget, n_sess, n_turns = 16, 8, 4, 8, 3
+    pages_for = lambda tokens: -(-tokens // page_len)
+    working_set = n_sess * pages_for(n_turns * (tail_len + budget) - 1)
+    per_request = pages_for(n_turns * (tail_len + budget)) + 1  # +1 copy-on-write page
+    t0_pages = max(-(-working_set // 4), per_request + 1)
+    assert working_set >= 4 * t0_pages
+    rng = np.random.default_rng(0)
+    tails = [[rng.integers(1, TINY.vocab_size, tail_len, dtype=np.int32)
+              for _ in range(n_turns)] for _ in range(n_sess)]
+
+    def run(srv):
+        outputs, hist = [], [np.array([], np.int32)] * n_sess
+        for turn in range(n_turns):
+            prompts = [np.concatenate([hist[s], tails[s][turn]]).astype(np.int32)
+                       for s in range(n_sess)]
+            rids = [srv.submit(prompts[s], max_new_tokens=budget, session_id=f"sess-{s}")
+                    for s in range(n_sess)]  # ServingQueueFull here fails the test
+            res = srv.drain(max_steps=5000)
+            for s, rid in enumerate(rids):
+                gen = np.asarray(res[rid].generated, np.int32)
+                outputs.append(gen)
+                hist[s] = np.concatenate([prompts[s], gen])
+        return outputs
+
+    ref = run(_tsrv(eng, tmp_path, kvcache={"num_pages": 2 * working_set}))
+    srv = _tsrv(eng, tmp_path, kvcache={"num_pages": t0_pages + 1},
+                tiers={"host_pages": t0_pages, "residency_window": page_len,
+                       "demote_watermark": 0.5, "demote_batch": 8, "prefetch_ahead": 2})
+    got = run(srv)
+    st = srv.stats()
+    srv._tiers.close()
+    assert len(got) == len(ref) == n_sess * n_turns
+    for a, b in zip(got, ref):
+        np.testing.assert_array_equal(a, b)
+    assert st.get("rejected", 0) == 0
+    tiers = st["kvcache"]["tiers"]
+    assert tiers["demote_t0_t1"] + tiers["promote_t1_t0"] + tiers["promote_t2_t0"] > 0, tiers
+
+
 @pytest.mark.slow  # tier-1 wall budget; the kvcache-tiers CI job runs it
 def test_tail_residency_window_demote_and_rebind(eng, tmp_path):
     """A parked session keeps only its residency window in T0; the tier

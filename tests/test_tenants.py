@@ -379,3 +379,83 @@ def test_replay_bypasses_bucket_no_double_charge(eng, tmp_path):
     assert snap["bucket_tokens"] == pytest.approx(12.0)
     _run_all(srv2, [rid])
     assert srv2.scheduler.request(rid).finish_time is not None
+
+
+# ---------------------------------------------------------------------------
+# isolation under a noisy neighbour, on the scheduler's own clock
+# ---------------------------------------------------------------------------
+
+def _drive_in_steps(srv, clock, schedule):
+    """Open loop in virtual time: ``schedule`` is ``[(arrival_step,
+    tenant, prompt)]``, the engine's clock reads one second a scheduler
+    step.  Returns per-tenant TTFTs in steps and rejected submits."""
+    from deepspeed_tpu.serving import ServingQueueFull
+
+    pending = sorted(schedule, key=lambda a: a[0])
+    rids, rejected, steps = {}, {}, 0
+    while pending or srv.scheduler.has_work():
+        while pending and pending[0][0] <= steps:
+            _, tenant, prompt = pending.pop(0)
+            try:
+                rids[srv.submit(prompt, max_new_tokens=4, tenant=tenant)] = tenant
+            except ServingQueueFull:
+                rejected[tenant] = rejected.get(tenant, 0) + 1
+        srv.step()
+        steps += 1
+        clock.now = float(steps)
+        assert steps < 5000, "schedule did not drain"
+    ttft = {}
+    for rid, tenant in rids.items():
+        r = srv.scheduler.request(rid)
+        ttft.setdefault(tenant, []).append(r.first_token_step - r.submit_step)
+    return ttft, rejected
+
+
+def test_quiet_tenant_ttft_steps_hold_under_10x_noisy_neighbour(eng, monkeypatch):
+    """The isolation gate: a quiet tenant's median submit-to-first-token
+    in scheduler steps next to a neighbour offered 10x its token-bucket
+    quota stays within 2x its solo value; the bucket throttles the
+    neighbour and every quiet request completes.  Arrivals, the bucket
+    and the TTFT all read scheduler steps, so host load cannot move it."""
+    from deepspeed_tpu.serving import engine as serving_engine
+
+    clock = SimpleNamespace(now=0.0, monotonic=lambda: clock.now)
+    monkeypatch.setattr(serving_engine, "time", clock)
+    rng = np.random.default_rng(0)
+    prompts = [_prompt(seed=100 + i, n=int(rng.integers(4, 25))) for i in range(12)]
+    cost = float(np.mean([len(p) + 4 for p in prompts]))
+
+    # capacity in requests a step, from a closed loop of the same prompts
+    srv = ServingEngine(eng, num_slots=2, prefill_chunk=8, max_len=64)
+    for p in prompts:
+        srv.submit(p, max_new_tokens=4)
+    steps = 0
+    while srv.scheduler.has_work():
+        srv.step()
+        steps += 1
+    capacity = len(prompts) / steps
+    noisy_quota = 0.3 * capacity  # the bucket's sustained rate, in requests a step
+
+    def make():
+        clock.now = 0.0
+        noisy = {"refill_tokens_per_second": noisy_quota * cost,
+                 "burst_tokens": 2.0 * cost, "slo_class": "bronze"}
+        return ServingEngine(
+            eng, num_slots=2, prefill_chunk=8, max_len=64,
+            tenants={"enabled": True,
+                     "overrides": {"quiet": {"slo_class": "gold"}, "noisy": noisy}},
+        )
+
+    quiet_at = np.cumsum(rng.exponential(1.0 / (0.4 * capacity), size=len(prompts)))
+    quiet = [(a, "quiet", p) for a, p in zip(quiet_at, prompts)]
+    n_noisy = int(10.0 * noisy_quota * quiet_at[-1]) + 1
+    noisy_at = np.cumsum(rng.exponential(1.0 / (10.0 * noisy_quota), size=n_noisy))
+    noisy = [(a, "noisy", prompts[i % len(prompts)]) for i, a in enumerate(noisy_at)]
+
+    solo, _ = _drive_in_steps(make(), clock, quiet)
+    mixed, rejected = _drive_in_steps(make(), clock, quiet + noisy)
+    assert len(mixed["quiet"]) == len(prompts) and "quiet" not in rejected
+    assert rejected.get("noisy", 0) > 0
+    assert len(mixed.get("noisy", [])) >= 1
+    ratio = np.median(mixed["quiet"]) / np.median(solo["quiet"])
+    assert ratio <= 2.0, (solo, mixed)

@@ -1,9 +1,9 @@
 """ZeRO collective-byte regression tests (VERDICT r2 #2: the BASELINE
 'ZeRO allgather BW' metric needs HLO-grounded byte accounting).
 
-The analytic model (zero_step_comm_model) feeds the bench rung's
-GB/s-demand line; these tests pin it against compiled-HLO byte counts
-so the bench number can't drift from reality.  Caveats encoded here:
+The analytic model (zero_step_comm_model) feeds the comm summary's
+per-step byte model; these tests pin it against compiled-HLO byte counts
+so the model can't drift from reality.  Caveats encoded here:
 
 * XLA:CPU decomposes all-gather/reduce-scatter into all-reduce for some
   shapes, so per-op taxonomy is asserted loosely and TOTALS tightly;

@@ -1,6 +1,6 @@
 """Comm-strategy sweep: dense vs int8 vs 1-bit gradient exchange.
 
-Drives the `comm-strategies` bench rung (bench.py) and runs standalone:
+Runs standalone:
 
     python tools/bench_comm.py --dryrun          # 8 virtual CPU devices
     python tools/bench_comm.py --steps 16        # real devices
@@ -50,12 +50,6 @@ def log(msg):
 
 def emit(rec):
     print(json.dumps(rec), flush=True)
-    from deepspeed_tpu.telemetry.regression import tool_history_emit
-
-    # standalone runs feed the persistent bench history too (no-op when
-    # the bench.py driver parent is the history writer)
-    tool_history_emit(rec, rung="comm-strategies",
-                      base_dir=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def _tb_collective_bytes(engine):

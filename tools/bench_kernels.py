@@ -1,6 +1,6 @@
 """Kernel-suite microbench: lax reference vs Pallas (docs/kernels.md).
 
-Drives the `kernels` bench rung (bench.py) and runs standalone:
+Runs standalone:
 
     python tools/bench_kernels.py --dryrun     # CPU: tiny shapes, interpret kernels
     python tools/bench_kernels.py              # real devices: 2k/16k contexts
@@ -16,10 +16,6 @@ Measures, per (kv dtype, context) cell:
   param tree, stock XLA ``FusedAdam``/``FusedLamb`` vs the one-pass
   kernel; step wall plus the compiled-cost HBM bytes of each (the
   bytes column is the claim: same math, fewer passes).
-
-Every record goes through ``tool_history_emit`` so ``bench_diff
---gate`` covers the kernels from the first run; the bench.py parent
-appends for driver runs (DS_BENCH_CHILD=1 suppresses the local write).
 """
 from __future__ import annotations
 
@@ -43,10 +39,6 @@ def log(msg):
 
 def emit(rec):
     print(json.dumps(rec), flush=True)
-    from deepspeed_tpu.telemetry.regression import tool_history_emit
-
-    tool_history_emit(rec, rung="kernels",
-                      base_dir=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def _time(fn, iters, *args):

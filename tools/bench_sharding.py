@@ -1,6 +1,6 @@
 """Weight-update-sharding sweep: replicated vs cross-replica ZeRO-1.
 
-Drives the `sharding` bench rung (bench.py) and runs standalone:
+Runs standalone:
 
     python tools/bench_sharding.py --dryrun      # 8 virtual CPU devices
     python tools/bench_sharding.py --steps 16    # real devices
@@ -57,12 +57,6 @@ def log(msg):
 
 def emit(rec):
     print(json.dumps(rec), flush=True)
-    from deepspeed_tpu.telemetry.regression import tool_history_emit
-
-    # standalone runs feed the persistent bench history too (no-op when
-    # the bench.py driver parent is the history writer)
-    tool_history_emit(rec, rung="sharding",
-                      base_dir=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def _opt_state_bytes(engine):
