@@ -484,9 +484,11 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
 # one dense layer then expert layers, group-limited routing over all experts
 # with half of them held here, shared experts, an untied head over half the
 # vocabulary), at sizes the compiled ``mla_decode_paged`` and ``mla_prefill``
-# accept: pages of 128 positions, a latent of 128 + 64, chunks of 128
+# and ``moe_grouped_matmul`` accept: pages of 128 positions, a latent of
+# 128 + 64, chunks of 128 (x top-4 = 512 assignment rows), expert widths of
+# whole lane tiles; a decode step's 2 x 4 = 8 rows stay on ``ragged_dot``
 DSV2_SMOKE = {
-    "vocab_size": 512, "hidden_size": 256, "intermediate_size": 512, "moe_intermediate_size": 64,
+    "vocab_size": 512, "hidden_size": 256, "intermediate_size": 512, "moe_intermediate_size": 128,
     "num_hidden_layers": 3, "num_attention_heads": 8, "q_lora_rank": 96, "kv_lora_rank": 128,
     "qk_nope_head_dim": 32, "qk_rope_head_dim": 64, "v_head_dim": 32, "n_routed_experts": 16, "n_shared_experts": 2,
     "num_experts_per_tok": 4, "n_group": 4, "topk_group": 2, "routed_scaling_factor": 2.0, "norm_topk_prob": False,
@@ -537,11 +539,14 @@ def serve_deepseek_v2(s: Smoke, device) -> Dict[str, float]:
     expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
                    ["mla_decode_paged"] if s.mosaic else [], "serve[dsv2] decode")
     expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()),
-                   ["mla_prefill"] if s.mosaic else [], "serve[dsv2] prefill")
+                   ["mla_prefill", "moe_grouped_matmul"] if s.mosaic else [], "serve[dsv2] prefill")
     stats = srv.stats()
     check(stats["mla_prefill_kernel"] is bool(s.mosaic),
           f"serve[dsv2]: stats() say of the prefill program: mla_prefill_kernel {stats['mla_prefill_kernel']}, "
           f"fallback {stats['mla_prefill_fallback']!r}")
+    check(stats["moe_grouped_kernel"] == ("512" if s.mosaic else ""),
+          f"serve[dsv2]: stats() say of the held experts: moe_grouped_kernel {stats['moe_grouped_kernel']!r}, "
+          f"fallback {stats['moe_grouped_fallback']!r}")
     say(f"serve[dsv2]: 2 requests x 8 tokens through the latent pool, token gap mean {gaps['token_gap_mean']:.5f} "
         f"max {gaps['token_gap_max']:.5f} over {gaps['tokens']} tokens")
     return gaps

@@ -330,7 +330,8 @@ def forward_with_cache(params: Dict[str, Any], tokens, pool, pos, cfg: DeepseekV
     ``(B * T, top_k)`` (benchmark/control_deepseek_v2.py compares them
     with the reference's); ``trace_notes``, a dict, is told while
     tracing which form a chunk's attention took
-    (``latent_attention.expanded_attention``).
+    (``latent_attention.expanded_attention``) and which the held
+    experts' grouped matmuls (``dropless_held_experts``).
     """
     from deepspeed_tpu.moe.layer import dropless_held_experts, group_limited_topk
 
@@ -351,7 +352,8 @@ def forward_with_cache(params: Dict[str, Any], tokens, pool, pos, cfg: DeepseekV
                                     cfg.routed_scaling_factor, cfg.norm_topk_prob)
         if routing_sink is not None:
             routing_sink.append(idx)
-        routed, counts = dropless_held_experts(flat, idx, w, lp["experts_gu"], lp["experts_down"], cfg.held, valid)
+        routed, counts = dropless_held_experts(flat, idx, w, lp["experts_gu"], lp["experts_down"], cfg.held, valid,
+                                               trace_notes=trace_notes)
         x = x + (routed + _swiglu(flat, lp["shared_gu"], lp["shared_down"])).reshape(x.shape)
         aux.append(counts)
     take = jnp.full((B,), T - 1, jnp.int32) if take is None else take
@@ -368,7 +370,8 @@ def serving_forward(cfg: DeepseekV2Config):
     pos, page_table=, write_mask=, row_valid=, take=) -> (logits, k, v,
     aux)`` — ``k`` is the latent pool, ``v`` is None.  ``fwd.trace_notes``
     holds what the programs traced through it said of themselves (which
-    form a prefill chunk's attention compiled to): ``stats()`` shows it."""
+    form a prefill chunk's attention compiled to, and each program's
+    grouped expert matmuls): ``stats()`` shows it."""
     notes: Dict[str, Any] = {}
 
     def fwd(params, tokens, k, v, pos, page_table, write_mask=None, row_valid=None, take=None):

@@ -249,9 +249,56 @@ def group_limited_topk(probs: jnp.ndarray, n_group: int, topk_group: int, top_k:
     return idx.astype(jnp.int32), (w * scale).astype(jnp.float32)
 
 
+def grouped_form(rows: int, D: int, F: int, dtype) -> Tuple[bool, str]:
+    """Which form the two grouped matmuls of a held-expert call of
+    ``rows`` assignment rows take: ``(kernel, why_not)`` — the Mosaic
+    kernel ``moe_grouped_matmul`` when the suite is armed, the trace
+    targets one device and the kernel serves both shapes
+    (``(rows, D) x (D, 2F)`` and ``(rows, F) x (F, D)``), else
+    ``jax.lax.ragged_dot`` and the reason; one line of the log for each
+    distinct answer.  No row threshold: on the chip the kernel is ahead
+    at a decode step's 192 rows as at a chunk's 3,072 (docs/kernels.md)."""
+    from deepspeed_tpu.ops import kernels as _kernels
+    from deepspeed_tpu.ops.kernels.grouped_matmul import grouped_matmul_supported
+    from deepspeed_tpu.ops.kernels.sharded import free_mesh_axes
+    from deepspeed_tpu.utils.device import pallas_interpret_default
+
+    if not _kernels.grouped_matmul_armed():
+        why_not = "kernel suite not armed"
+    elif not pallas_interpret_default() and any(n > 1 for n in free_mesh_axes().values()):
+        why_not = "traced for a multi-device mesh"
+    elif not (grouped_matmul_supported(rows, D, 2 * F, dtype) and grouped_matmul_supported(rows, F, D, dtype)):
+        why_not = f"unsupported shape ({rows} rows, widths {D} / {F}, {jnp.dtype(dtype).name})"
+    else:
+        why_not = ""
+    _kernels.warn_once(("moe_grouped_matmul", rows, D, F, why_not),
+                       f"kernels: a held-expert call of {rows} assignment rows (widths {D} / {F}) takes "
+                       + (f"jax.lax.ragged_dot: {why_not}" if why_not else "moe_grouped_matmul"), level="info")
+    return not why_not, why_not
+
+
+def note_grouped_form(trace_notes: dict, rows: int, why_not: str) -> None:
+    """Leave in a family's ``trace_notes`` which form a held-expert call
+    of ``rows`` assignment rows took.  Both keys are strings (a serve
+    record keeps scalars and strings of ``stats()``):
+    ``moe_grouped_kernel`` the row counts on the kernel, ascending,
+    comma-separated; ``moe_grouped_fallback`` ``"<rows>: <reason>"`` for
+    the others, ``"; "`` between."""
+    on = {int(r) for r in trace_notes.get("moe_grouped_kernel", "").split(",") if r}
+    off = dict(e.split(": ", 1) for e in trace_notes.get("moe_grouped_fallback", "").split("; ") if e)
+    on.discard(rows)
+    off.pop(str(rows), None)
+    if why_not:
+        off[str(rows)] = why_not
+    else:
+        on.add(rows)
+    trace_notes["moe_grouped_kernel"] = ",".join(str(r) for r in sorted(on))
+    trace_notes["moe_grouped_fallback"] = "; ".join(f"{r}: {off[r]}" for r in sorted(off, key=int))
+
+
 def dropless_held_experts(x: jnp.ndarray, idx: jnp.ndarray, weight: jnp.ndarray, w_gu: jnp.ndarray,
-                          w_down: jnp.ndarray, held: Tuple[int, int],
-                          valid: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                          w_down: jnp.ndarray, held: Tuple[int, int], valid: Optional[jnp.ndarray] = None,
+                          trace_notes: Optional[dict] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The part of a routed-expert layer that the experts **held here**
     give: ``sum_{e in chosen, first <= e < first + count} w_e E_e(x)``
     with ``E_e(x) = (silu(x W_gate,e) * x W_up,e) W_down,e``.
@@ -259,10 +306,20 @@ def dropless_held_experts(x: jnp.ndarray, idx: jnp.ndarray, weight: jnp.ndarray,
     No capacity and no dropped assignment: every (token, expert) pair
     whose expert is held is computed.  Static shapes: the ``N * top_k``
     assignments are sorted by expert (those of absent experts last), the
-    held ones go through two grouped matmuls (``jax.lax.ragged_dot``,
-    group sizes traced), and each token sums its own rows back.  On one
-    chip there is no exchange; over an ``expert`` mesh axis this is what
-    each rank computes between the two all-to-alls.
+    held ones go through two grouped matmuls (group sizes traced), and
+    each token sums its own rows back.  On one chip there is no
+    exchange; over an ``expert`` mesh axis this is what each rank
+    computes between the two all-to-alls.
+
+    The grouped matmuls have two forms, chosen by :func:`grouped_form`
+    from what the trace can see: the Mosaic kernel
+    ``ops/kernels/grouped_matmul.py`` (each touched expert's weights
+    streamed once; the chip) and ``jax.lax.ragged_dot`` (the CPU,
+    unsupported shapes, a multi-device trace; the kernel's reference).
+    Same rows, same arithmetic: operands in their dtype, float32
+    products.  ``trace_notes``, a dict, is told while tracing which row
+    counts took which (``moe_grouped_kernel``, ``moe_grouped_fallback``:
+    ``ServingEngine.stats()``, docs/telemetry.md).
 
     ``x (N, D)``; ``idx``/``weight (N, K)`` from the router over all
     experts; ``w_gu (count, D, 2F)`` (gate columns first), ``w_down
@@ -280,9 +337,16 @@ def dropless_held_experts(x: jnp.ndarray, idx: jnp.ndarray, weight: jnp.ndarray,
     order = jnp.argsort(key, stable=True)
     sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
     xs = jnp.take(x, order // K, axis=0)
-    gu = jax.lax.ragged_dot(xs, w_gu.astype(x.dtype), sizes)
+    kernel, why_not = grouped_form(N * K, x.shape[1], w_down.shape[1], x.dtype)
+    if trace_notes is not None:
+        note_grouped_form(trace_notes, N * K, why_not)
+    if kernel:
+        from deepspeed_tpu.ops.kernels.grouped_matmul import grouped_matmul as grouped
+    else:
+        grouped = jax.lax.ragged_dot
+    gu = grouped(xs, w_gu.astype(x.dtype), sizes)
     g, u = jnp.split(gu, 2, axis=-1)
-    ys = jax.lax.ragged_dot(jax.nn.silu(g) * u, w_down.astype(x.dtype), sizes)
+    ys = grouped(jax.nn.silu(g) * u, w_down.astype(x.dtype), sizes)
     # rows past the held groups belong to absent experts: nothing was computed for them
     computed = jnp.arange(N * K) < jnp.sum(sizes)
     ws = jnp.take(jnp.where(is_held, weight, 0.0).reshape(N * K), order)

@@ -125,6 +125,12 @@ def fused_update_armed() -> bool:
     return _suite_armed() and _STATE["fused_update"]
 
 
+def grouped_matmul_armed() -> bool:
+    """The held experts' grouped matmul (``grouped_matmul.py``) has no
+    knob of its own: it follows the suite."""
+    return _suite_armed()
+
+
 def kernels_report() -> Dict[str, Any]:
     """ds_report rows: which kernels are armed and the autotuner cache
     state (path / entries / hits)."""

@@ -1447,7 +1447,7 @@ class ServingEngine:
         if self._aux_total is not None:
             out["moe"] = self._moe_stats()
         # what the family's programs said of themselves while tracing
-        # (DeepSeek-V2: mla_prefill_kernel / mla_prefill_fallback)
+        # (DeepSeek-V2: mla_prefill_kernel / mla_prefill_fallback, moe_grouped_kernel / moe_grouped_fallback)
         out.update(getattr(self._family_forward, "trace_notes", {}))
         out.update(self.timeline.summary())
         return out

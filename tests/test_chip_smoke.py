@@ -165,6 +165,29 @@ def test_expanded_attention_compiles_for_v5e_with_the_scores_in_the_kernel(H, T,
     assert f"f32[1,{H},{T},{S}]" not in hlo and f"f32[{H},{T},{S}]" not in hlo
 
 
+@pytest.mark.parametrize("tokens", [512, 32], ids=["a_chunk_of_512", "a_decode_step_of_32_rows"])
+def test_held_experts_compile_for_v5e_with_the_weights_read_where_they_are(tokens, v5e_chip, monkeypatch):
+    """The DeepSeek-V2 cell's routed layer (40 experts held, top-6,
+    5120 / 1536), by the chip's compiler without the chip: Mosaic takes
+    ``moe_grouped_matmul`` for both grouped matmuls, and XLA puts no
+    copy, transpose or fusion of a layer's expert weights round them
+    (1.26 GB a call if it did: PERF.md, PR 27 and PR 28)."""
+    from deepspeed_tpu.moe.layer import dropless_held_experts
+    from deepspeed_tpu.ops.kernels import grouped_matmul
+
+    monkeypatch.setenv("DS_KERNELS", "1")
+    monkeypatch.setattr(grouped_matmul, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
+    on_chip = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    E, D, F, top_k = 40, 5120, 1536, 6
+    hlo = jax.jit(lambda x, idx, w, gu, down: dropless_held_experts(x, idx, w, gu, down, (0, E))).lower(
+        on_chip((tokens, D), jnp.bfloat16), on_chip((tokens, top_k), jnp.int32), on_chip((tokens, top_k), jnp.float32),
+        on_chip((E, D, 2 * F), jnp.bfloat16), on_chip((E, F, D), jnp.bfloat16),
+    ).compile().as_text()
+    assert chip_smoke.mosaic_kernels(hlo) == {"moe_grouped_matmul": 2}
+    assert "ragged-dot" not in hlo
+    assert chip_smoke.leaf_sized_moves(hlo, E * D * 2 * F) == [] and chip_smoke.leaf_sized_moves(hlo, E * F * D) == []
+
+
 # ---------------------------------------------------------------------------
 # (b) the smoke's control flow, and its refusal to run off a TPU
 # ---------------------------------------------------------------------------
