@@ -12,6 +12,10 @@ weights made from a seed:
 * a second family on the same server — DeepSeek-V2 at a tiny size
   (latent attention on the paged latent pool, dropless routing over the
   experts held here) against ``benchmark/reference_deepseek_v2.py``;
+* a third family — Solar-Open2 at a small size on the hybrid cache (K/V
+  pages + per-slot recurrent state; ``kda_decode`` and the grouped
+  ``flash_decode_paged`` in its decode program)
+  against ``benchmark/reference_solar_open2.py``;
 * server — ``deepspeed_tpu.init_inference("gpt2-xl")`` → ``ServingEngine``
   on the paged pool (8 slots, ``page_len`` 128), a bf16 then an int8 KV
   pool, eight seeded greedy requests each;
@@ -552,6 +556,70 @@ def serve_deepseek_v2(s: Smoke, device) -> Dict[str, float]:
     return gaps
 
 
+# Solar-Open2 at a small size: the published kinds of layer (one period twice: GQA, KDA, KDA, KDA), head_dim 128 and
+# 16 KDA heads so that the kernels' tiles are the real ones, everything else narrow.  Experts 8-15 of 16 are held.
+SOLAR2_SMOKE = {
+    "hidden_size": 256, "num_hidden_layers": 8, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 128,
+    "vocab_size": 512, "intermediate_size": 512, "moe_intermediate_size": 128, "rms_norm_eps": 1e-5, "gqa_layers": [0, 4],
+    "use_gqa_gate": True, "use_rope": False, "first_k_dense_replace": 0, "kda_use_full_proj": False,
+    "kda_allow_neg_eigval": True, "tie_word_embeddings": False, "n_routed_experts": 16, "n_shared_experts": 1,
+    "norm_topk_prob": True, "routed_scaling_factor": 1, "num_experts_per_tok": 4, "max_position_embeddings": 4096,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 128, "num_heads": 16, "num_kv_heads": None},
+    "experts_held": [8, 8], "vocab_held": 256,
+}
+TOL_SOLAR2_TOKEN_GAP = 0.05  # as TOL_DSV2_TOKEN_GAP: a bf16 program against the float32 reference
+
+
+def serve_solar_open2(s: Smoke, device) -> Dict[str, float]:
+    """``init_inference(model_config=SolarOpen2Config)`` → the same
+    ``ServingEngine`` on the hybrid cache: compiles both programs, serves
+    three requests over two slots (chunked prefill; decode through the
+    per-slot recurrent state and the grouped ``flash_decode_paged``; a
+    slot reused) and holds every emitted token against the plain
+    reference's logits (``benchmark/reference_solar_open2.py``)."""
+    from benchmark import weights_solar_open2 as weights
+    from benchmark.reference_solar_open2 import Reference
+    from benchmark.runners.serve_solar2 import served_gaps
+    from deepspeed_tpu.comm.mesh import make_mesh
+    from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.models import solar_open2
+    from deepspeed_tpu.serving import ServingEngine
+
+    dims = SOLAR2_SMOKE
+    mcfg = solar_open2.SolarOpen2Config.from_hf(dims, experts_held=dims["experts_held"], vocab_held=dims["vocab_held"])
+    inf = deepspeed_tpu.init_inference(
+        model_config=mcfg, params=weights.program_params(s.seed, dims, jnp.bfloat16), dtype=jnp.bfloat16,
+        max_out_tokens=512, mesh=make_mesh(MeshConfig(), devices=[device]), donate_params=True,
+    )
+    srv = ServingEngine(inf, config={"num_slots": 2, "max_len": 512, "prefill_chunk": 128, "max_new_tokens": 16,
+                                     "kvcache": {"enabled": True, "page_len": 128, "num_pages": 9}})
+    rng = np.random.default_rng(s.seed)
+    prompts = [rng.integers(1, dims["vocab_held"], n, dtype=np.int32) for n in (200, 131, 70)]
+    ids = [srv.submit(p, max_new_tokens=8) for p in prompts]
+    done = srv.drain()
+    check((srv.prefill_compiles, srv.decode_compiles) == (1, 1),
+          f"serve[solar2]: {srv.prefill_compiles} prefill / {srv.decode_compiles} decode executables")
+    stats = srv.stats()
+    moe, hybrid = stats["moe"], stats["hybrid"]
+    check(moe["dropped_assignments"] == 0 and moe["assignments_computed"] > 0, f"serve[solar2]: expert counters {moe}")
+    check(hybrid["state_resets_in_program"] == 3 and hybrid["state_bytes"] > 0, f"serve[solar2]: hybrid cache {hybrid}")
+    gaps = served_gaps(Reference(dims, s.seed), [{"prompt": p, "generated": list(done[i].generated)}
+                                                 for p, i in zip(prompts, ids)], 256)
+    check(gaps["token_gap_max"] <= TOL_SOLAR2_TOKEN_GAP,
+          f"serve[solar2]: an emitted token lies {gaps['token_gap_max']:.4f} under the reference's best logit "
+          f"(tolerance {TOL_SOLAR2_TOKEN_GAP})")
+    expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
+                   ["kda_decode", "flash_decode_paged"] if s.mosaic else [], "serve[solar2] decode")
+    expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()),
+                   ["moe_grouped_matmul"] if s.mosaic else [], "serve[solar2] prefill")
+    check(stats["kda_decode_kernel"] is bool(s.mosaic) and stats["gqa_decode_kernel"] is bool(s.mosaic),
+          f"serve[solar2]: stats() say of the decode program: kda_decode_kernel {stats['kda_decode_kernel']} "
+          f"({stats['kda_decode_fallback']!r}), gqa_decode_kernel {stats['gqa_decode_kernel']} ({stats['gqa_decode_fallback']!r})")
+    say(f"serve[solar2]: 3 requests x 8 tokens through the hybrid cache, token gap mean {gaps['token_gap_mean']:.5f} "
+        f"max {gaps['token_gap_max']:.5f} over {gaps['tokens']} tokens")
+    return gaps
+
+
 def run(s: Smoke, devices: Sequence) -> None:
     """Every phase, in order; raises on the first check that fails."""
     if s.mosaic:
@@ -567,6 +635,7 @@ def run(s: Smoke, devices: Sequence) -> None:
         say(f"train: losses on 1 and {len(devices)} devices agree within {TOL_LOSS_ACROSS_MESHES}")
     serve(s, devices[0])
     serve_deepseek_v2(s, devices[0])
+    serve_solar_open2(s, devices[0])
 
 
 def main() -> int:

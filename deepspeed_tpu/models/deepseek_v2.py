@@ -238,22 +238,33 @@ def param_shapes(cfg: DeepseekV2Config) -> Dict[str, Any]:
             "layers": [dict(dense) for _ in range(cfg.n_dense_layers)] + [dict(moe) for _ in range(cfg.n_moe_layers)]}
 
 
-def init_params_device(cfg: DeepseekV2Config, seed: int = 0, dtype=jnp.bfloat16, std: float = 0.02):
-    """Random parameters made on the default device, one leaf at a time
-    (no float32 copy of the whole tree ever exists): normal(``std``),
-    residual projections scaled by ``1 / sqrt(2 L)``, gains 1."""
-    proj = std / math.sqrt(2 * cfg.num_hidden_layers)
+def seeded_tree(shapes: Dict[str, Any], n_layers: int, seed: int, dtype, std: float = 0.02,
+                residual: Tuple[str, ...] = ("o", "mlp_down", "shared_down", "experts_down"), special=None):
+    """A tree of shapes (leaves are tuples) as random parameters made on
+    the default device, one leaf at a time (no float32 copy of the whole
+    tree ever exists): normal(``std``), the ``residual`` projections
+    scaled by ``1 / sqrt(2 n_layers)``, every ``*norm`` / ``norm_f`` gain
+    1.  ``special(name, key, shape)`` may return a leaf's float32 values
+    itself (None: the rule above).  Shared by the decoder families."""
+    proj = std / math.sqrt(2 * n_layers)
     key = jax.random.PRNGKey(seed)
-    flat, treedef = jax.tree_util.tree_flatten_with_path(param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+    flat, treedef = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=lambda s: isinstance(s, tuple))
     leaves = []
     for i, (path, shape) in enumerate(flat):
         name = str(path[-1].key)
         if name.endswith("norm") or name == "norm_f":
             leaves.append(jnp.ones(shape, dtype))
             continue
-        s = proj if name in ("o", "mlp_down", "shared_down", "experts_down") else std
-        leaves.append((jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32) * s).astype(dtype))
+        own = special(name, jax.random.fold_in(key, i), shape) if special is not None else None
+        if own is None:
+            own = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32) * (proj if name in residual else std)
+        leaves.append(own.astype(dtype))
     return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def init_params_device(cfg: DeepseekV2Config, seed: int = 0, dtype=jnp.bfloat16, std: float = 0.02):
+    """Random parameters made on the default device (:func:`seeded_tree`)."""
+    return seeded_tree(param_shapes(cfg), cfg.num_hidden_layers, seed, dtype, std)
 
 
 def init_params(cfg: DeepseekV2Config, seed: int = 0):
@@ -367,17 +378,19 @@ def forward_with_cache(params: Dict[str, Any], tokens, pool, pos, cfg: DeepseekV
 def serving_forward(cfg: DeepseekV2Config):
     """The family seam of ``ServingEngine`` (docs/serving.md): the
     model's own step on its own cache kind.  ``fwd(params, tokens, k, v,
-    pos, page_table=, write_mask=, row_valid=, take=) -> (logits, k, v,
-    aux)`` — ``k`` is the latent pool, ``v`` is None.  ``fwd.trace_notes``
+    pos, page_table=, write_mask=, row_valid=, take=, state=, slot=) ->
+    (logits, k, v, state, aux)`` — ``k`` is the latent pool, ``v`` and
+    ``state`` are None (this kind has no slot-axis group, and no use for
+    the ``slot``).  ``fwd.trace_notes``
     holds what the programs traced through it said of themselves (which
     form a prefill chunk's attention compiled to, and each program's
     grouped expert matmuls): ``stats()`` shows it."""
     notes: Dict[str, Any] = {}
 
-    def fwd(params, tokens, k, v, pos, page_table, write_mask=None, row_valid=None, take=None):
+    def fwd(params, tokens, k, v, pos, page_table, write_mask=None, row_valid=None, take=None, state=None, slot=None):
         logits, k, aux = forward_with_cache(params, tokens, k, pos, cfg, page_table, write_mask=write_mask,
                                             row_valid=row_valid, take=take, trace_notes=notes)
-        return logits, k, v, aux
+        return logits, k, v, state, aux
 
     fwd.trace_notes = notes
 

@@ -249,6 +249,23 @@ def group_limited_topk(probs: jnp.ndarray, n_group: int, topk_group: int, top_k:
     return idx.astype(jnp.int32), (w * scale).astype(jnp.float32)
 
 
+def sigmoid_topk(logits: jnp.ndarray, bias: Optional[jnp.ndarray], top_k: int, scale: float = 1.0,
+                 renormalize: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sigmoid-scored routing (the DeepSeek-V3 / GLM-4-MoE convention,
+    one group): ``logits (N, E)`` float32 over **all** experts; the score
+    is ``s = sigmoid(logits)``, the ``top_k`` experts are chosen by ``s +
+    bias`` (``bias (E,)``, a load-balancing correction that selects and
+    never weighs; None = 0), and the weights are ``s`` at the chosen,
+    divided by their sum (``renormalize``), times ``scale``.  Returns
+    ``(idx (N, top_k) int32, weight (N, top_k) float32)``."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(s if bias is None else s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if renormalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), (w * scale).astype(jnp.float32)
+
+
 def grouped_form(rows: int, D: int, F: int, dtype) -> Tuple[bool, str]:
     """Which form the two grouped matmuls of a held-expert call of
     ``rows`` assignment rows take: ``(kernel, why_not)`` — the Mosaic

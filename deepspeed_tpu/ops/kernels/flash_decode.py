@@ -277,13 +277,15 @@ def decode_paged_supported(B: int, H: int, P: int, page_len: int, d: int) -> boo
 def _flash_decode_paged_kernel(
     pt_ref,           # SMEM (B, P) int32 — per-slot page table (scalar prefetch)
     pos_ref,          # SMEM (B,) int32 — per-slot query position (scalar prefetch)
-    q_ref,            # (1, 1, 1, d)
-    k_ref,            # (1, 1, page_len, d)  — THE page pt[b, p], codes or bf16/f32
-    v_ref,            # (1, 1, page_len, d)
-    *rest,            # [ks_ref, vs_ref (1,1,1,page_len)]; o_ref; scratch m, l, acc
+    q_ref,            # (1, block_heads, group, d): the query heads of block_heads KV heads
+    k_ref,            # (1, block_heads, page_len, d)  — THE page pt[b, p], codes or bf16/f32
+    v_ref,            # (1, block_heads, page_len, d)
+    *rest,            # [ks_ref, vs_ref (1,block_heads,1,page_len)]; o_ref; scratch m, l, acc
     sm_scale: float,
     page_len: int,
     quant: bool,
+    block_heads: int,
+    group: int,
 ):
     refs = list(rest)
     ks_ref = refs.pop(0) if quant else None
@@ -308,38 +310,41 @@ def _flash_decode_paged_kernel(
     key_idx = p_idx * page_len + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_len), 1
     )
-
-    q = q_ref[0, 0].astype(jnp.float32)                          # (1, d)
-    k = k_ref[0, 0].astype(jnp.float32)                          # (page_len, d)
-    scores = jax.lax.dot_general(
-        q, k,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * sm_scale                                                 # (1, page_len)
-    if quant:
-        scores = scores * ks_ref[0, 0]                           # in-register dequant
     allowed = key_idx <= pos_ref[b]
-    scores = jnp.where(allowed, scores, NEG_INF)
+    # static unroll over the KV heads of this program; a KV head's
+    # ``group`` query heads share its page: one fetch, ``group`` rows
+    for h in range(block_heads):
+        rows = pl.dslice(h * group, group)
+        q = q_ref[0, h].astype(jnp.float32)                      # (group, d)
+        k = k_ref[0, h].astype(jnp.float32)                      # (page_len, d)
+        scores = jax.lax.dot_general(
+            q, k,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * sm_scale                                             # (group, page_len)
+        if quant:
+            scores = scores * ks_ref[0, h]                       # in-register dequant
+        scores = jnp.where(allowed, scores, NEG_INF)
 
-    m_prev = m_ref[:]                                            # (1, 1)
-    l_prev = l_ref[:]
-    m_cur = jnp.max(scores, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(scores - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[:] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    m_ref[:] = m_new
-    if quant:
-        p = p * vs_ref[0, 0]
-    v = v_ref[0, 0].astype(jnp.float32)
-    acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
+        m_prev = m_ref[rows]                                     # (group, 1)
+        l_prev = l_ref[rows]
+        m_cur = jnp.max(scores, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(scores - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[rows] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[rows] = m_new
+        if quant:
+            p = p * vs_ref[0, h]
+        v = v_ref[0, h].astype(jnp.float32)
+        acc_ref[rows] = acc_ref[rows] * alpha + jnp.dot(
+            p, v, preferred_element_type=jnp.float32
+        )
 
     @pl.when(p_idx == num_p - 1)
     def _emit():
         l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        o_ref[:] = (acc_ref[:] / l)[:, None, None, :].astype(o_ref.dtype)
+        o_ref[:] = (acc_ref[:] / l).reshape(o_ref.shape).astype(o_ref.dtype)
 
 
 def flash_decode_paged(
@@ -352,26 +357,40 @@ def flash_decode_paged(
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Single-query attention against a PAGED pool (docs/serving.md
-    §Paged KV & prefix caching): caches are ``(num_pages, H, page_len,
+    §Paged KV & prefix caching): caches are ``(num_pages, Hkv, page_len,
     d)`` (or the int8 code+scale pair), ``page_table`` (B,
     pages_per_slot) maps each slot's logical positions onto pages.
+
+    **Grouped queries**: ``q (B, H, 1, d)`` with ``H`` a multiple of the
+    cache's ``Hkv``; query head ``i`` attends KV head ``i // (H / Hkv)``.
+    The ``H / Hkv`` query heads of a KV head sit in one tile against its
+    page, so a page is fetched once a KV head, not once a query head.
+    Multi-head attention is the group of 1 — one KV head a program,
+    grid ``(B, H, pages_per_slot)``, as it always ran; a grouped call
+    takes every KV head's page in one program, grid ``(B, 1,
+    pages_per_slot)``: with groups there are few KV heads and many
+    slots, and a program a (slot, KV head, page) is mostly grid steps.
+    A page past a slot's position is computed fully masked, for every
+    group size (its table entry is the garbage page, the same block as
+    the step before: not fetched again).
 
     The page table rides the grid as a **prefetched scalar**
     (``PrefetchScalarGridSpec``): the k/v BlockSpec index_map reads
     ``pt[b, p]``, so each program's K/V page streams HBM→VMEM directly
-    — the gather the lax path materializes never exists.  Grid
-    ``(B, H, pages_per_slot)`` with the page axis sequential; one page
-    is one kv block (``decode_paged_supported`` demands page_len be
-    lane-aligned), and the online softmax state lives in VMEM scratch
-    exactly like :func:`flash_decode`."""
+    — the gather the lax path materializes never exists.  The page axis
+    is sequential; one page is one kv block (``decode_paged_supported``
+    demands page_len be lane-aligned), and the online softmax state
+    lives in VMEM scratch exactly like :func:`flash_decode`."""
     quant = isinstance(k_cache, dict)
     k_op = k_cache["q"] if quant else k_cache
     v_op = v_cache["q"] if quant else v_cache
     B, H, T, d = q.shape
-    NP, _, page_len, _ = k_op.shape
+    NP, Hkv, page_len, _ = k_op.shape
     P = page_table.shape[1]
     if T != 1:
         raise ValueError(f"flash_decode_paged serves exactly one query per slot, got T={T}")
+    if H % Hkv:
+        raise ValueError(f"flash_decode_paged: {H} query heads are not whole groups over {Hkv} KV heads")
     if not decode_paged_supported(B, H, P, page_len, d):
         raise ValueError(
             f"flash_decode_paged grid cannot serve (B={B}, H={H}, P={P}, "
@@ -382,24 +401,26 @@ def flash_decode_paged(
         sm_scale = 1.0 / (d ** 0.5)
     if interpret is None:
         interpret = pallas_interpret_default()
+    group = H // Hkv
+    bh = 1 if group == 1 else Hkv  # KV heads a program holds
 
     table = jnp.asarray(page_table, jnp.int32)
     pos_vec = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
 
     # index maps receive (*grid_ids, *scalar_prefetch_refs)
     in_specs = [
-        pl.BlockSpec((1, 1, 1, d), lambda b, h, p, pt, pv: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, page_len, d), lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)),
-        pl.BlockSpec((1, 1, page_len, d), lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)),
+        pl.BlockSpec((1, bh, group, d), lambda b, h, p, pt, pv: (b, h, 0, 0)),
+        pl.BlockSpec((1, bh, page_len, d), lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)),
+        pl.BlockSpec((1, bh, page_len, d), lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)),
     ]
-    args = [q, k_op, v_op]
+    args = [q.reshape(B, Hkv, group, d), k_op, v_op]
     if quant:
         # (NP, H, page_len, 1) scales -> (NP, H, 1, page_len) row
         # vectors (contiguous reshape) sharing the score-row layout
-        ks = k_cache["s"].reshape(NP, H, 1, page_len)
-        vs = v_cache["s"].reshape(NP, H, 1, page_len)
+        ks = k_cache["s"].reshape(NP, Hkv, 1, page_len)
+        vs = v_cache["s"].reshape(NP, Hkv, 1, page_len)
         spec = pl.BlockSpec(
-            (1, 1, 1, page_len), lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)
+            (1, bh, 1, page_len), lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)
         )
         in_specs += [spec, spec]
         args += [ks, vs]
@@ -409,29 +430,31 @@ def flash_decode_paged(
         sm_scale=sm_scale,
         page_len=page_len,
         quant=quant,
+        block_heads=bh,
+        group=group,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, P),
+        grid=(B, Hkv // bh, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, d), lambda b, h, p, pt, pv: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, bh, group, d), lambda b, h, p, pt, pv: (b, h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),   # m
-            pltpu.VMEM((1, 1), jnp.float32),   # l
-            pltpu.VMEM((1, d), jnp.float32),   # acc
+            pltpu.VMEM((bh * group, 1), jnp.float32),   # m
+            pltpu.VMEM((bh * group, 1), jnp.float32),   # l
+            pltpu.VMEM((bh * group, d), jnp.float32),   # acc
         ],
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="flash_decode_paged",
     )(table, pos_vec, *args)
-    return out
+    return out.reshape(B, H, 1, d)
 
 
 def flash_decode_reference(q, k_cache, v_cache, pos, sm_scale=None, key_padding_mask=None):

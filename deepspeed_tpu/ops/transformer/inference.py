@@ -176,6 +176,61 @@ def paged_cache_write(cache, t, page_table, pos, write_mask=None):
     return scat(cache, t)
 
 
+def page_target(page_table, b: int, at, page_len: int, span: int = 1, write_mask=None):
+    """``(page id, offset)`` of logical position ``at`` of row ``b``,
+    clipped so that ``span`` positions from it fit the slot; a row whose
+    ``write_mask`` is False goes to the garbage page (page 0, offset 0).
+    The index arithmetic of every slice-wise cache write (here and
+    ``latent_attention.latent_cache_write``)."""
+    at = jnp.clip(at, 0, page_table.shape[1] * page_len - span)
+    pid, off = page_table[b, at // page_len], at % page_len
+    if write_mask is not None:
+        pid, off = jnp.where(write_mask[b], pid, 0), jnp.where(write_mask[b], off, 0)
+    return pid, off
+
+
+def paged_cache_write_slices(pool, layer: int, t, page_table, pos, write_mask=None):
+    """:func:`paged_cache_write` into layer ``layer`` of a stacked
+    bf16/f32 pool ``(layers, num_pages, H, page_len, d)``, written as
+    ``dynamic_update_slice``s, because those update a donated pool in
+    place in the layout it has.  An XLA scatter over the (page,
+    position) dims wants ``page_len`` ahead of ``H`` in memory: on the
+    TPU it copied both pools (2 x 1.3 GB at 5,121 pages of 8 x 128 x
+    128) into that layout and back, every step.
+
+    One position a row (decode) is one update.  A chunk (``T > 1``) is
+    written **page by page, wherever it starts**: each of the
+    ``ceil(T / page_len) + 1`` pages it can touch is read, the positions
+    the chunk covers replaced, and written back — a start inside a page
+    (a prefix hit, any future caller) splits at the page edges instead of
+    being clamped onto a boundary, and positions past the slot's last
+    page are dropped."""
+    page_len, P = pool.shape[3], page_table.shape[1]
+    B, H, T, d = t.shape
+    t = t.astype(pool.dtype)
+    zero = jnp.int32(0)
+    if T == 1:
+        for b in range(B):
+            pid, off = page_target(page_table, b, pos[b], page_len, 1, write_mask)
+            pool = jax.lax.dynamic_update_slice(pool, t[b][None, None], (jnp.int32(layer), pid, zero, off, zero))
+        return pool
+    windows = -(-T // page_len) + 1
+    r = jnp.arange(page_len, dtype=jnp.int32)
+    padded = jnp.pad(t, ((0, 0), (0, 0), (page_len, (windows + 1) * page_len - T - page_len), (0, 0)))
+    for b in range(B):
+        first, shift = pos[b] // page_len, pos[b] % page_len
+        for i in range(windows):
+            # position r of logical page first + i is the chunk's index c
+            c = i * page_len + r - shift
+            covered = (c >= 0) & (c < T) & (first + i < P)
+            pid, _ = page_target(page_table, b, (first + i) * page_len, page_len, page_len, write_mask)
+            at = (jnp.int32(layer), pid, zero, zero, zero)
+            old = jax.lax.dynamic_slice(pool, at, (1, 1, H, page_len, d))
+            new = jax.lax.dynamic_slice_in_dim(padded[b], (i + 1) * page_len - shift, page_len, axis=1)
+            pool = jax.lax.dynamic_update_slice(pool, jnp.where(covered[None, None, None, :, None], new[None, None], old), at)
+    return pool
+
+
 def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
                           sm_scale: Optional[float] = None,
                           use_kernel: Optional[bool] = None):
@@ -204,7 +259,58 @@ def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
             )
     gk = paged_gather(k_cache, page_table)
     gv = paged_gather(v_cache, page_table)
+    group = q.shape[1] // jax.tree.leaves(gk)[0].shape[1]
+    if group > 1:  # grouped queries: query head i attends KV head i // group
+        gk, gv = jax.tree.map(lambda a: jnp.repeat(a, group, axis=1), (gk, gv))
     return cache_attention(q, gk, gv, pos, sm_scale=sm_scale, use_kernel=False)
+
+
+def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Optional[float] = None,
+                          block_pages: int = 4):
+    """A prefill chunk against a paged bf16/f32 cache, **block by block
+    over its context** under an online softmax: ``q (B, H, T, d)`` at
+    positions ``pos[b] + t`` (the chunk's own keys already written),
+    caches ``(num_pages, Hkv, page_len, d)`` with ``H`` a multiple of
+    ``Hkv`` (query head ``i`` attends KV head ``i // (H / Hkv)``).  Walks
+    ``block_pages`` pages at a time as far as the furthest query reaches:
+    one block's ``(B, H, T, block_pages * page_len)`` float32 scores are
+    the most that exists, never a slot's or the pool's length.  Returns
+    ``(B, H, T, d)`` in ``q``'s dtype."""
+    B, H, T, d = q.shape
+    _, Hkv, page_len, _ = k_cache.shape
+    P, G = page_table.shape[1], H // Hkv
+    while P % block_pages:
+        block_pages -= 1
+    S = block_pages * page_len
+    if sm_scale is None:
+        sm_scale = 1.0 / (d ** 0.5)
+    n_blocks = jnp.minimum((jnp.max(pos) + T + S - 1) // S, P // block_pages)
+    qg = q.reshape(B, Hkv, G, T, d)
+    q_pos = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (B, T)
+
+    def rows(cache, j):  # (B, Hkv, S, d): block j of every row's context
+        pages = jax.lax.dynamic_slice_in_dim(page_table, j * block_pages, block_pages, axis=1)
+        t = jnp.take(cache, pages.reshape(-1), axis=0).reshape(B, block_pages, Hkv, page_len, d)
+        return t.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, S, d).astype(q.dtype)
+
+    def body(j, carry):
+        m, l, acc = carry
+        s = jnp.einsum("bhgtd,bhsd->bhgts", qg, rows(k_cache, j), preferred_element_type=jnp.float32) * sm_scale
+        k_pos = j * S + jnp.arange(S, dtype=jnp.int32)
+        ok = k_pos[None, None, :] <= q_pos[:, :, None]  # (B, T, S)
+        s = jnp.where(ok[:, None, None], s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum("bhgts,bhsd->bhgtd", p.astype(q.dtype), rows(v_cache, j),
+                                                  preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    stat = (B, Hkv, G, T)
+    init = (jnp.full(stat, -1e30, jnp.float32), jnp.zeros(stat, jnp.float32), jnp.zeros(stat + (d,), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
+    return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).reshape(B, H, T, d).astype(q.dtype)
 
 
 def cache_attention(q, k_cache, v_cache, pos, sm_scale: Optional[float] = None,

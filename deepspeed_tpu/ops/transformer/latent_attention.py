@@ -35,6 +35,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops.transformer.inference import page_target
+
 NEG_INF = -1e30
 
 
@@ -59,10 +61,7 @@ def latent_cache_write(pool, layer: int, rows, page_table, pos, write_mask=None)
         span = min(T, page_len)  # positions one update covers: a page, or the chunk inside its page
         for b in range(B):
             for j in range(T // span):
-                at = jnp.clip(pos[b] + j * span, 0, page_table.shape[1] * page_len - span)
-                pid, off = page_table[b, at // page_len], at % page_len
-                if write_mask is not None:
-                    pid, off = jnp.where(write_mask[b], pid, 0), jnp.where(write_mask[b], off, 0)
+                pid, off = page_target(page_table, b, pos[b] + j * span, page_len, span, write_mask)
                 block = rows[b, j * span:(j + 1) * span].T[None, None]  # (1, 1, W, span)
                 pool = jax.lax.dynamic_update_slice(pool, block, (jnp.int32(layer), pid, zero, off))
         return pool

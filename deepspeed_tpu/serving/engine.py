@@ -156,6 +156,10 @@ class ServingEngine:
         self._aux_total = None
         self._aux_decode_touched = 0
         self._aux_decode_steps = 0
+        # what a cache kind with per-slot state is asked about (stats()["hybrid"])
+        self._state_resets = 0
+        self._decode_rows = 0
+        self._decode_steps = 0
         if self._paged:
             import math
 
@@ -380,27 +384,33 @@ class ServingEngine:
             if self._family_forward is not None:
                 fwd = self._family_forward
 
-                def serve_prefill(params, toks, table, pos, take_idx, cow_src, cow_dst,
-                                  flag, temp, topk, seed, k_pool, v_pool):
+                def serve_prefill(params, toks, table, slot, pos, take_idx, cow_src, cow_dst,
+                                  flag, temp, topk, seed, k_pool, v_pool, state_pool):
                     # the paged step below with the family's own forward
                     # on its own cache kind; the chunk's padded tail is
-                    # computed and left out of the family's counters
+                    # computed and left out of the family's counters.
+                    # ``state_pool`` is the kind's slot-axis group: the
+                    # chunk's ``slot`` says which rows of it are this
+                    # request's; copy-on-write is a matter of pages and
+                    # never touches it.  A kind that is pages and nothing
+                    # else hands None for both (empty pytrees: nothing
+                    # is staged or donated for them)
                     cow = lambda b: b.at[:, cow_dst].set(b[:, cow_src])  # noqa: E731
                     k_pool = jax.tree.map(cow, k_pool)
                     v_pool = jax.tree.map(cow, v_pool)
-                    logits, k_pool, v_pool, aux = fwd(
+                    logits, k_pool, v_pool, state_pool, aux = fwd(
                         params, toks, k_pool, v_pool, pos[None], page_table=table[None, :],
                         row_valid=(jnp.arange(chunk, dtype=jnp.int32) <= take_idx)[None, :],
-                        take=take_idx[None],
+                        take=take_idx[None], state=state_pool, slot=None if slot is None else slot[None],
                     )
                     key = jax.random.fold_in(jax.random.PRNGKey(seed), pos + take_idx)
                     first = sample_logits_pooled(
                         logits.astype(jnp.float32), key[None], flag[None], temp[None],
                         topk[None], max_top_k,
                     )[0]
-                    return (first, aux), k_pool, v_pool
+                    return (first, aux), k_pool, v_pool, state_pool
 
-                donate = (11, 12)
+                donate = (12, 13, 14)
             elif self._paged:
                 def serve_prefill(params, toks, table, pos, take_idx, cow_src, cow_dst,
                                   flag, temp, topk, seed, k_pool, v_pool):
@@ -481,10 +491,11 @@ class ServingEngine:
                 fwd = self._family_forward
 
                 def serve_decode(params, toks, pos, flags, temps, topks, seeds,
-                                 page_table, write_mask, k_pool, v_pool):
-                    logits, k_pool, v_pool, aux = fwd(
+                                 page_table, write_mask, k_pool, v_pool, state_pool):
+                    # row b is slot b: the rows of ``state_pool`` are the batch's
+                    logits, k_pool, v_pool, state_pool, aux = fwd(
                         params, toks[:, None], k_pool, v_pool, pos, page_table=page_table,
-                        write_mask=write_mask, row_valid=write_mask[:, None],
+                        write_mask=write_mask, row_valid=write_mask[:, None], state=state_pool,
                     )
                     keys = jax.vmap(
                         lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
@@ -492,9 +503,9 @@ class ServingEngine:
                     nxt = sample_logits_pooled(
                         logits.astype(jnp.float32), keys, flags, temps, topks, max_top_k,
                     )
-                    return (nxt, aux), k_pool, v_pool
+                    return (nxt, aux), k_pool, v_pool, state_pool
 
-                donate = (9, 10)
+                donate = (9, 10, 11)
             elif self._paged:
                 def serve_decode(params, toks, pos, flags, temps, topks, seeds,
                                  page_table, write_mask, k_pool, v_pool):
@@ -576,7 +587,7 @@ class ServingEngine:
                 self._abstract_staged((S, self.pool.pages_per_slot), jnp.int32),
                 vec(jnp.bool_),  # write_mask
             ]
-        args += [self._abstract_tree(self.pool.k), self._abstract_tree(self.pool.v)]
+        args += [self._abstract_tree(a) for a in self._pool_args()]
         return tuple(args)
 
     def _prefill_abstract_args(self):
@@ -588,6 +599,8 @@ class ServingEngine:
                 self._abstract_staged((1, chunk), jnp.int32)]
         if self._paged:
             args.append(self._abstract_staged((self.pool.pages_per_slot,), jnp.int32))
+            if self._family_forward is not None:
+                args.append(scalar(jnp.int32) if self.pool.state is not None else None)  # slot
             args += [scalar(jnp.int32)] * 4  # pos, take_idx, cow_src, cow_dst
         else:
             args += [scalar(jnp.int32)] * 3  # slot, pos, take_idx
@@ -596,8 +609,8 @@ class ServingEngine:
             scalar(jnp.float32),  # temperature
             scalar(jnp.int32),    # top_k
             scalar(jnp.uint32),   # seed
-            self._abstract_tree(self.pool.k), self._abstract_tree(self.pool.v),
         ]
+        args += [self._abstract_tree(a) for a in self._pool_args()]
         return tuple(args)
 
     def compiled_step(self, which: str):
@@ -1265,8 +1278,10 @@ class ServingEngine:
         with tl.phase("prefill.stage"):
             if self._paged:
                 cow_src, cow_dst = self.pool.consume_cow(r.slot)
+                # a family's step is told the slot where its cache kind keeps per-slot state (else None: nothing staged)
+                slot_arg = () if self._family_forward is None else (np.int32(r.slot) if self.pool.state is not None else None,)
                 staged = jax.device_put(
-                    (job.tokens[None, :], self.pool.table(r.slot),
+                    (job.tokens[None, :], self.pool.table(r.slot), *slot_arg,
                      np.int32(job.start), np.int32(job.take_idx),
                      np.int32(cow_src), np.int32(cow_dst),
                      np.bool_(r.do_sample), np.float32(r.temperature),
@@ -1285,10 +1300,10 @@ class ServingEngine:
         t0 = tracer.now() if tracer is not None else 0.0
         guard = san.transfer.guard("serving.prefill") if san is not None else nullcontext()
         with tl.phase("prefill.dispatch"), guard:
-            first, k, v = fn(
-                self.engine.params, *staged, self.pool.k, self.pool.v,
-            )
-        self.pool.swap(k, v)
+            first, *pools = fn(self.engine.params, *staged, *self._pool_args())
+        self.pool.swap(*pools)
+        if self._family_forward is not None and job.start == 0:
+            self._state_resets += 1  # taken inside the program: the chunk at position 0 starts from zero
         # explicit d2h read doubles as the fence that keeps prefill_ms
         # honest; the value is the first generated token on final chunks
         with tl.phase("prefill.wait"):
@@ -1338,10 +1353,10 @@ class ServingEngine:
                 )
         guard = san.transfer.guard("serving.decode") if san is not None else nullcontext()
         with tl.phase("decode.dispatch"), guard:
-            nxt, k, v = fn(
-                self.engine.params, *staged, self.pool.k, self.pool.v,
-            )
-        self.pool.swap(k, v)
+            nxt, *pools = fn(self.engine.params, *staged, *self._pool_args())
+        self.pool.swap(*pools)
+        self._decode_rows += len(decoding)
+        self._decode_steps += 1
         with tl.phase("decode.wait"):
             out = jax.device_get(nxt)
         out = np.asarray(out if self._family_forward is None else self._note_aux(out, decode=True))
@@ -1349,6 +1364,14 @@ class ServingEngine:
         self.scheduler.note_decode(
             {r.slot: int(out[r.slot]) for r in decoding}, now, self._step_count
         )
+
+    def _pool_args(self) -> tuple:
+        """The donated cache arguments of a serving step: K and V, and —
+        for a family's step — the kind's slot-axis group (None for a kind
+        that is pages and nothing else)."""
+        if self._family_forward is not None:
+            return self.pool.k, self.pool.v, self.pool.state
+        return self.pool.k, self.pool.v
 
     def _note_aux(self, got, decode: bool):
         """A family's step returns ``(tokens, aux)``: add the step's
@@ -1446,8 +1469,16 @@ class ServingEngine:
             out["tenants"] = self.tenants.snapshot()
         if self._aux_total is not None:
             out["moe"] = self._moe_stats()
+        if getattr(self.pool, "state", None) is not None:
+            out["hybrid"] = {
+                "state_bytes": self.pool.state_bytes(),
+                # fresh requests whose slot state started from zero inside the prefill program (no reset program exists)
+                "state_resets_in_program": self._state_resets,
+                "decode_rows_updated_mean": self._decode_rows / max(1, self._decode_steps),
+            }
         # what the family's programs said of themselves while tracing
-        # (DeepSeek-V2: mla_prefill_kernel / mla_prefill_fallback, moe_grouped_kernel / moe_grouped_fallback)
+        # (DeepSeek-V2: mla_prefill_kernel / mla_prefill_fallback, moe_grouped_kernel / moe_grouped_fallback;
+        # Solar-Open2: kda_decode_kernel / _fallback, kda_prefill_form, gqa_decode_kernel / _fallback, gqa_prefill_form)
         out.update(getattr(self._family_forward, "trace_notes", {}))
         out.update(self.timeline.summary())
         return out

@@ -98,6 +98,8 @@ class LatentKV:
     (``ops/transformer/latent_attention.py``) — and no second buffer
     (``pool.v`` is None, an empty pytree to ``jit``)."""
 
+    pages_hold_all = True
+
     def __init__(self, width: int, dtype: Any):
         self.width, self.dtype = int(width), dtype
 
@@ -106,6 +108,61 @@ class LatentKV:
 
     def describe(self, n_layer: int, num_pages: int, page_len: int) -> str:
         return f"1 x ({n_layer} layers x {num_pages} pages x {self.width} latent x {page_len} page_len)"
+
+
+class HybridKV:
+    """Cache kind of a model that mixes softmax-attention layers with
+    linear-attention layers (docs/serving.md §Cache kinds).  Two
+    geometries in one pool:
+
+    * **pages** — K and V of the ``paged_layers`` softmax-attention
+      layers only, ``(paged_layers, pages, kv_heads, page_len,
+      head_dim)``: the :class:`PerHeadKV` layout, not ``n_layer`` deep;
+    * **state** — a third group with a **slot** axis where the others
+      have pages (``pool.state``): per linear-attention layer and slot
+      the recurrent state ``s (state_layers, slots, heads, dk, dv)``
+      float32 and the convolution's last inputs ``conv (state_layers,
+      slots, conv_taps, conv_width)``.  Fixed size whatever the
+      sequence's length; the slot that owns a request owns its state.
+
+    A page here does **not** hold everything its positions left behind
+    — the linear layers' memory of them is in the slot's state — so a
+    page cannot stand for a prefix: ``pages_hold_all`` is False and the
+    pool turns prefix hits, prefix learning, session rebinds, spill and
+    tiers off, explicitly (``stats()["reuse"]``).  Copy-on-write, which
+    only ever follows a shared page, never happens; the state is never
+    copied.  A fresh request needs no reset of its slot's state: the
+    model's prefill starts from zero where the chunk starts at position 0."""
+
+    pages_hold_all = False
+
+    def __init__(self, paged_layers: int, kv_heads: int, head_dim: int, dtype: Any, state_layers: int,
+                 state_heads: int, state_dk: int, state_dv: int, conv_taps: int, conv_width: int):
+        self.paged_layers, self.heads, self.head_dim, self.dtype = int(paged_layers), int(kv_heads), int(head_dim), dtype
+        self.state_layers, self.state_heads = int(state_layers), int(state_heads)
+        self.state_dk, self.state_dv = int(state_dk), int(state_dv)
+        self.conv_taps, self.conv_width = int(conv_taps), int(conv_width)
+
+    def buffers(self, n_layer: int, num_pages: int, page_len: int):
+        from deepspeed_tpu.ops.transformer.inference import init_kv_cache
+
+        return init_kv_cache(self.paged_layers, num_pages, self.heads, page_len, self.head_dim, self.dtype)
+
+    def state_buffers(self, num_slots: int) -> Dict[str, Any]:
+        return {
+            "s": jnp.zeros((self.state_layers, num_slots, self.state_heads, self.state_dk, self.state_dv), jnp.float32),
+            "conv": jnp.zeros((self.state_layers, num_slots, self.conv_taps, self.conv_width), self.dtype),
+        }
+
+    def describe(self, n_layer: int, num_pages: int, page_len: int) -> str:
+        return (f"pages 2 x ({self.paged_layers} of {n_layer} layers x {num_pages} pages x {self.heads} heads x "
+                f"{page_len} page_len x {self.head_dim} head_dim) + state per slot ({self.state_layers} layers x "
+                f"[{self.state_heads} heads x {self.state_dk} x {self.state_dv} float32 + "
+                f"{self.conv_taps} x {self.conv_width} conv])")
+
+
+REUSE_OFF = ("off: this cache kind keeps part of a position's trace in per-slot state, so a page cannot stand for a "
+             "prefix (no prefix hits, prefix learning, session rebinds, spill or tiers)")
 
 
 def _named_leaves(k, v) -> Dict[str, Any]:
@@ -141,9 +198,11 @@ class PagedKVPool:
                  spill_dir: Optional[str] = None,
                  kind: Optional[Any] = None):
         """``kind`` is the cache kind (docs/serving.md §Cache kinds):
-        it makes the device buffers, every leaf with the page axis at
-        dim 1; the allocator below never looks inside them.  Default:
-        :class:`PerHeadKV` from ``heads`` / ``head_dim`` / ``kv_dtype``."""
+        it makes the paged device buffers (``k``, ``v``), every leaf
+        with the page axis at dim 1, and — :class:`HybridKV` — a third
+        group ``state`` with a slot axis; the allocator below never
+        looks inside them.  Default: :class:`PerHeadKV` from ``heads`` /
+        ``head_dim`` / ``kv_dtype``."""
         if num_slots < 1:
             raise SlotPoolError(f"num_slots must be >= 1, got {num_slots}")
         if page_len < 1:
@@ -179,8 +238,16 @@ class PagedKVPool:
             )
         self.kind = kind if kind is not None else PerHeadKV(heads, head_dim, kv_dtype)
         self.k, self.v = self.kind.buffers(n_layer, self.num_pages, self.page_len)
+        # the slot-axis group (HybridKV): None for the kinds that are pages and nothing else
+        make_state = getattr(self.kind, "state_buffers", None)
+        self.state = make_state(self.num_slots) if make_state is not None else None
+        # whether a page may stand for a prefix (shared, learned, parked, spilled, tiered)
+        self.reuse = bool(getattr(self.kind, "pages_hold_all", True))
+        if not self.reuse and (spill_dir or len(list(pinned_prefixes))):
+            raise SlotPoolError(f"{type(self.kind).__name__}: prefix reuse is {REUSE_OFF}; "
+                                "spill_dir and pinned_prefixes cannot be set")
         if sharding is not None:
-            self.k, self.v = jax.device_put((self.k, self.v), sharding)
+            self.k, self.v, self.state = jax.device_put((self.k, self.v, self.state), sharding)
         # host-side allocator state (every public touch goes through
         # @_locked — see the decorator's docstring)
         self._lock = threading.RLock()
@@ -212,6 +279,7 @@ class PagedKVPool:
         self.evictions = 0
         self.session_rebinds = 0
         self.alloc_waits = 0  # alloc_request returned None for lack of pages
+        self.sessions_unbound = 0  # requests with a session_id served without a rebind (reuse off)
         # per-tenant quota enforcement (docs/serving.md §Front-door):
         # armed via attach_tenants().  Charges follow the live slot —
         # fresh pages claimed for a tenant's request count against its
@@ -321,12 +389,19 @@ class PagedKVPool:
     def free(self, slot: int) -> None:
         self.retire(slot, None)
 
-    def swap(self, k, v) -> None:
+    def swap(self, k, v, state=None) -> None:
+        """The buffers a serving step returned take the place of the
+        donated ones (``state``: the slot-axis group of a kind that has one)."""
         self.k, self.v = k, v
+        if state is not None:
+            self.state = state
+
+    def state_bytes(self) -> int:
+        return int(sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(self.state)))
 
     def cache_bytes(self) -> int:
         return int(
-            sum(l.size * l.dtype.itemsize for l in jax.tree.leaves((self.k, self.v)))
+            sum(l.size * l.dtype.itemsize for l in jax.tree.leaves((self.k, self.v, self.state)))
         )
 
     def shape_math(self) -> str:
@@ -387,12 +462,16 @@ class PagedKVPool:
         self.lookups += 1
         sid = getattr(req, "session_id", None)
         source, sess, entry, hit = None, None, None, 0
+        if not self.reuse:
+            # no page of this kind can stand for a prefix: every request prefills from position 0
+            self.sessions_unbound += sid is not None
+            sid = None
         if sid is not None:
             sess = self._match_session(sid, prompt, now)
             if sess is not None:
                 hit = self._aligned_hit(sess.cached_len, plen)
                 source = "session" if hit > 0 else None
-        if source is None:
+        if source is None and self.reuse:
             entry = self.index.lookup(prompt, now=now)
             if self.tiers is not None:
                 best = entry.length if entry is not None else 0
@@ -520,7 +599,7 @@ class PagedKVPool:
         to the shared tail page — safe, because it only ever writes
         positions >= the entry length, and readers COW first."""
         pages = self._slot_pages.get(req.slot)
-        if pages is None:
+        if pages is None or not self.reuse:
             return
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
         # the run this prompt shares with previously-learned traffic
@@ -657,7 +736,7 @@ class PagedKVPool:
         self._pending_cow.pop(slot, None)
         self._tables[slot] = GARBAGE_PAGE
         self._free_slots.append(slot)
-        sid = getattr(req, "session_id", None) if req is not None else None
+        sid = getattr(req, "session_id", None) if req is not None and self.reuse else None
         parked = False
         if sid is not None and getattr(req, "finish_reason", None) in ("eos", "length"):
             gen = list(getattr(req, "generated", []) or [])
@@ -764,6 +843,8 @@ class PagedKVPool:
         """Arm hierarchical tiering: ``mgr`` (a
         :class:`~deepspeed_tpu.serving.kvcache.tiers.PageTierManager`)
         takes over session spill/drop and cold prefix eviction."""
+        if not self.reuse:
+            raise SlotPoolError(f"{type(self.kind).__name__}: prefix reuse is {REUSE_OFF}")
         self.tiers = mgr
 
     @_locked
@@ -853,6 +934,8 @@ class PagedKVPool:
         is out of pages a migrated session lands in this pool's own
         spill_dir instead (or is dropped without one)."""
         counts = {"sessions": 0, "pinned": 0, "respilled": 0, "skipped": 0}
+        if not self.reuse:
+            raise SlotPoolError(f"{type(self.kind).__name__}: prefix reuse is {REUSE_OFF}")
         for meta, leaves in read_entries(src_dir):
             kind = meta.get("kind", "session")
             if kind == "pinned_prefix":
@@ -945,6 +1028,12 @@ class PagedKVPool:
             "session_restores": sess["restores"],
             "session_drops": sess["drops"],
         }
+        if self.state is not None:
+            out["kind"] = self.kind.describe(self.n_layer, self.num_pages, self.page_len)
+            out["state_bytes"] = self.state_bytes()
+        if not self.reuse:
+            out["reuse"] = REUSE_OFF
+            out["sessions_unbound"] = self.sessions_unbound
         if self.tiers is not None:
             out["tiers"] = self.tiers.stats()
         if self.tenants is not None:

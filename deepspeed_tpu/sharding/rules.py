@@ -339,7 +339,9 @@ def rules_for_config(model_config: Any, layout: SpecLayout = DEFAULT_LAYOUT) -> 
             return rules_for_family("gpt2", layout)
         if klass.__name__ == "BertConfig":
             return rules_for_family("bert", layout)
-        if klass.__name__ == "DeepseekV2Config":
+        if klass.__name__ in ("DeepseekV2Config", "SolarOpen2Config"):
+            # one deployment, one table: held experts over ``expert``, embedding and head over the
+            # vocabulary, every kind of attention, shared experts, router and norms replicated
             return rules_for_family("deepseek_v2", layout)
     raise ValueError(
         f"no built-in partition rules for model config {type(model_config).__name__}"

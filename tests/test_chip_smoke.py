@@ -188,6 +188,50 @@ def test_held_experts_compile_for_v5e_with_the_weights_read_where_they_are(token
     assert chip_smoke.leaf_sized_moves(hlo, E * D * 2 * F) == [] and chip_smoke.leaf_sized_moves(hlo, E * F * D) == []
 
 
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_solar_open2_steps_compile_for_v5e_with_both_caches_updated_in_place(which, v5e_chip, monkeypatch):
+    """One period of Solar-Open2 at the published widths on the hybrid
+    cache (16 slots, 2,049 pages), by the chip's compiler
+    without the chip: the decode step holds ``kda_decode`` once a KDA
+    layer, the grouped ``flash_decode_paged`` and the experts' kernel;
+    and neither step leaves a copy of the K/V pools or of the recurrent
+    state in the program (an XLA scatter for the K/V write copied both
+    pools every step: PERF.md, PR 32)."""
+    from deepspeed_tpu.models import solar_open2 as so
+    from deepspeed_tpu.ops.kernels import flash_decode, grouped_matmul, kda_decode
+
+    monkeypatch.setenv("DS_KERNELS", "1")
+    for mod in (flash_decode, grouped_matmul, kda_decode):
+        monkeypatch.setattr(mod, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
+    slots, pages, chunk = 16, 2049, 512
+    cfg = so.SolarOpen2Config(num_hidden_layers=4, gqa_layers=(0,), experts_held=(0, 40), vocab_held=24576)
+    on_chip = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    params = jax.tree.map(lambda sh: on_chip(sh, jnp.bfloat16), so.param_shapes(cfg), is_leaf=lambda sh: isinstance(sh, tuple))
+    kind = so.cache_kind(cfg, jnp.bfloat16)
+    k, v = (on_chip(a.shape, a.dtype) for a in jax.eval_shape(lambda: kind.buffers(4, pages, 128)))
+    state = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(lambda: kind.state_buffers(slots)))
+    if which == "decode":
+        def step(p, t, pos, table, wm, k, v, st):
+            return so.forward_with_cache(p, t[:, None], k, v, st, pos, cfg, table, write_mask=wm, row_valid=wm[:, None])
+        args = (params, on_chip((slots,), jnp.int32), on_chip((slots,), jnp.int32), on_chip((slots, 64), jnp.int32),
+                on_chip((slots,), jnp.bool_), k, v, state)
+    else:
+        def step(p, t, table, slot, pos, k, v, st):
+            return so.forward_with_cache(p, t, k, v, st, pos[None], cfg, table[None], slot=slot[None])
+        args = (params, on_chip((1, chunk), jnp.int32), on_chip((64,), jnp.int32), on_chip((), jnp.int32), on_chip((), jnp.int32),
+                k, v, state)
+    compiled = jax.jit(step, donate_argnums=(len(args) - 3, len(args) - 2, len(args) - 1)).lower(*args).compile()
+    found = chip_smoke.mosaic_kernels(compiled.as_text())
+    if which == "decode":
+        assert found == {"kda_decode": 3, "flash_decode_paged": 1, "moe_grouped_matmul": 8}
+    else:
+        assert found == {"moe_grouped_matmul": 8}
+    m = compiled.memory_analysis()
+    cache_bytes = 2 * int(np.prod(k.shape)) * 2 + sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert m.alias_size_in_bytes >= cache_bytes               # all three groups come back in place
+    assert m.temp_size_in_bytes < int(np.prod(k.shape)) * 2   # and no temporary is as large as one pool
+
+
 # ---------------------------------------------------------------------------
 # (b) the smoke's control flow, and its refusal to run off a TPU
 # ---------------------------------------------------------------------------

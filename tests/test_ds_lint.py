@@ -35,13 +35,16 @@ def rule_ids(result):
 @functools.lru_cache(maxsize=1)
 def _repo_self_run():
     """One full-package lint shared by every test that needs the repo's
-    current findings (each full pass costs ~6s of tier-1 time)."""
-    start = time.monotonic()
+    current findings (each full pass costs ~8s of tier-1 time).  The
+    time returned is the pass's own CPU time (it is single-threaded): the
+    wall clock of a tier-1 run also counts what five other xdist workers
+    take from it, and read 16s for the same 8s of work."""
+    start = time.process_time()
     res = lint_paths(
         [os.path.join(REPO_ROOT, "deepspeed_tpu")],
         baseline_path=os.path.join(REPO_ROOT, ".ds_lint_baseline.json"),
     )
-    return res, time.monotonic() - start
+    return res, time.process_time() - start
 
 
 # ---------------------------------------------------------------------------
@@ -1469,7 +1472,7 @@ class TestSelfRun:
         res, elapsed = _repo_self_run()
         new = [f.format() for f in res.findings + res.parse_errors]
         assert new == [], "new ds_lint findings:\n" + "\n".join(new)
-        assert elapsed < 15.0, f"ds_lint self-run took {elapsed:.1f}s (budget 15s)"
+        assert elapsed < 15.0, f"ds_lint self-run took {elapsed:.1f}s of CPU time (budget 15s)"
 
     def test_seeded_violation_is_caught(self, tmp_path):
         # the acceptance check: introducing a violation next to the real

@@ -19,6 +19,7 @@ import dataclasses
 import os
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -702,3 +703,184 @@ def test_fleet_session_stickiness_three_turns(eng, tmp_path):
     assert router.affinity_routes >= 2
     home = router._replicas[homes[0]].engine
     assert home.stats()["kvcache"]["session_rebinds"] == 2
+
+
+# ---------------------------------------------------------------------------
+# hybrid cache kind: K/V pages of some layers + a per-slot state group
+# ---------------------------------------------------------------------------
+
+def _hybrid_pool(**kw):
+    from deepspeed_tpu.serving.kvcache.pages import HybridKV
+
+    kind = HybridKV(paged_layers=2, kv_heads=2, head_dim=8, dtype=jnp.float32, state_layers=6, state_heads=4,
+                    state_dk=8, state_dv=8, conv_taps=3, conv_width=96)
+    return PagedKVPool(8, 3, 0, 64, 0, jnp.float32, page_len=16, num_pages=13, prefill_chunk=16, kind=kind, **kw)
+
+
+def test_hybrid_kind_has_pages_for_its_paged_layers_only_and_a_slot_axis_group():
+    pool = _hybrid_pool()
+    assert pool.k.shape == pool.v.shape == (2, 13, 2, 16, 8)  # 2 of the 8 layers, not n_layer
+    assert pool.state["s"].shape == (6, 3, 4, 8, 8) and pool.state["s"].dtype == jnp.float32
+    assert pool.state["conv"].shape == (6, 3, 3, 96)
+    state_bytes = 6 * 3 * (4 * 8 * 8 + 3 * 96) * 4
+    assert pool.state_bytes() == state_bytes and pool.cache_bytes() == 2 * 2 * 13 * 2 * 16 * 8 * 4 + state_bytes
+    assert "2 of 8 layers" in pool.shape_math() and "state per slot (6 layers" in pool.shape_math()
+    st = pool.stats()
+    assert st["kind"] == pool.kind.describe(8, 13, 16) and st["state_bytes"] == state_bytes
+    # swap keeps the state unless handed a new one
+    new = {k: v + 1 for k, v in pool.state.items()}
+    pool.swap(pool.k, pool.v)
+    assert float(pool.state["s"].max()) == 0.0
+    pool.swap(pool.k, pool.v, new)
+    assert float(pool.state["s"].min()) == 1.0
+
+
+def test_hybrid_kind_refuses_prefix_hits_session_rebinds_and_spill_and_says_so(tmp_path):
+    from deepspeed_tpu.serving.kvcache.pages import REUSE_OFF
+
+    pool = _hybrid_pool()
+    prompt = np.arange(1, 41, dtype=np.int32)
+    r1 = _KReq(1, prompt, max_new=4, sid="chat", generated=[5, 6, 7, 8], finish_reason="length")
+    r1.slot = pool.alloc_request(r1)
+    assert r1.prefill_pos == 0
+    pool.learn_prefix(r1)                      # nothing is learned
+    assert len(pool.index) == 0
+    pool.retire(r1.slot, r1)                   # nothing is parked
+    assert pool.sessions.peek("chat") is None and pool.pages_live == 0
+    r2 = _KReq(2, prompt, max_new=4, sid="chat")   # same prompt, same session: a miss, prefilled from position 0
+    r2.slot = pool.alloc_request(r2)
+    assert (r2.prefill_pos, r2.prefix_hint) == (0, 0) and pool.consume_cow(r2.slot) == (GARBAGE_PAGE, GARBAGE_PAGE)
+    assert pool.prefix_hint_tokens(prompt, "chat") == 0
+    st = pool.stats()
+    assert st["reuse"] == REUSE_OFF and st["sessions_unbound"] == 2
+    assert (st["prefix_hits"], st["session_rebinds"], st["cow_copies"], st["session_parks"]) == (0, 0, 0, 0)
+    pool.retire(r2.slot, r2)
+    _assert_no_leaks(pool)
+    with pytest.raises(SlotPoolError, match="prefix reuse is off"):
+        _hybrid_pool(spill_dir=str(tmp_path))
+    with pytest.raises(SlotPoolError, match="prefix reuse is off"):
+        _hybrid_pool(pinned_prefixes=[[1, 2, 3]])
+    with pytest.raises(SlotPoolError, match="prefix reuse is off"):
+        pool.attach_tiers(object())
+    with pytest.raises(SlotPoolError, match="prefix reuse is off"):
+        pool.import_sessions(str(tmp_path))
+
+
+@pytest.mark.parametrize("kind_name", ["per_head", "latent"])
+def test_page_only_kinds_are_unchanged_by_the_state_group(kind_name):
+    from deepspeed_tpu.serving.kvcache.pages import LatentKV, PerHeadKV
+
+    if kind_name == "per_head":
+        pool = PagedKVPool(3, 2, 2, 64, 8, jnp.float32, page_len=16, num_pages=9, prefill_chunk=16)
+        assert isinstance(pool.kind, PerHeadKV) and pool.k.shape == pool.v.shape == (3, 9, 2, 16, 8)
+        want = 2 * 3 * 9 * 2 * 16 * 8 * 4
+    else:
+        pool = PagedKVPool(3, 2, 0, 64, 0, jnp.float32, page_len=16, num_pages=9, prefill_chunk=16, kind=LatentKV(24, jnp.float32))
+        assert pool.k.shape == (3, 9, 24, 16) and pool.v is None
+        want = 3 * 9 * 24 * 16 * 4
+    assert pool.state is None and pool.reuse and pool.state_bytes() == 0 and pool.cache_bytes() == want
+    st = pool.stats()
+    assert not {"kind", "state_bytes", "reuse", "sessions_unbound"} & set(st)
+    # prefix reuse still works: the second request hits what the first taught
+    prompt = np.arange(1, 41, dtype=np.int32)
+    r1 = _KReq(1, prompt)
+    r1.slot = pool.alloc_request(r1)
+    pool.learn_prefix(r1)
+    r2 = _KReq(2, prompt)
+    r2.slot = pool.alloc_request(r2)
+    assert r2.prefill_pos == 32 and pool.stats()["prefix_hits"] == 1
+
+
+@pytest.mark.parametrize("group", [1, 2, 8])
+def test_grouped_flash_decode_paged_matches_the_gather_and_lax_path(group):
+    from deepspeed_tpu.ops.kernels import flash_decode as fd
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    B, Hkv, P, page_len, d = 3, 2, 3, 128, 16
+    H, num_pages = Hkv * group, 1 + B * P
+    rng = np.random.default_rng(group)
+    q = jnp.asarray(rng.standard_normal((B, H, 1, d)), jnp.float32)
+    kc = jnp.asarray(rng.standard_normal((num_pages, Hkv, page_len, d)), jnp.float32)
+    vc = jnp.asarray(rng.standard_normal((num_pages, Hkv, page_len, d)), jnp.float32)
+    table = np.arange(1, num_pages, dtype=np.int32).reshape(B, P)
+    table[0, 1:] = 0                              # a short row: its unmapped entries are the garbage page
+    table = jnp.asarray(table)
+    pos = jnp.asarray(np.array([37, 2 * page_len + 5, page_len - 1], np.int32))
+    out = fd.flash_decode_paged(q, kc, vc, table, pos)
+    ref = inf.paged_cache_attention(q, kc, vc, table, pos, use_kernel=False)  # gather, KV heads repeated, cache_attention
+    assert out.shape == (B, H, 1, d)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+    # by hand for one (row, query head): head i attends KV head i // group
+    b, i = 1, H - 1
+    n = int(pos[b]) + 1
+    keys = np.concatenate([np.asarray(kc[p, i // group]) for p in np.asarray(table[b])])[:n]
+    vals = np.concatenate([np.asarray(vc[p, i // group]) for p in np.asarray(table[b])])[:n]
+    s = keys @ np.asarray(q[b, i, 0]) / np.sqrt(d)
+    p_ = np.exp(s - s.max())
+    np.testing.assert_allclose(np.asarray(out[b, i, 0]), (p_ / p_.sum()) @ vals, rtol=2e-5, atol=2e-5)
+    with pytest.raises(ValueError, match="whole groups"):
+        fd.flash_decode_paged(q[:, : H - 1] if H > 1 else jnp.zeros((B, 3, 1, d)), kc, vc, table, pos)
+
+
+def test_paged_chunk_attention_and_slice_writes_are_the_whole_sequence_attention():
+    """A chunk written through ``paged_cache_write_slices`` and attended
+    block by block equals plain causal attention over the sequence."""
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    Hkv, G, page_len, d, T = 2, 2, 8, 8, 16
+    rng = np.random.default_rng(0)
+    seq = 40
+    q = jnp.asarray(rng.standard_normal((1, Hkv * G, seq, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, Hkv, seq, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, Hkv, seq, d)), jnp.float32)
+    kp, vp = jnp.zeros((2, 9, Hkv, page_len, d)), jnp.zeros((2, 9, Hkv, page_len, d))
+    table = jnp.asarray([[5, 2, 7, 1, 3, 8, 0, 0]], jnp.int32)
+    outs = []
+    for start in range(0, 48, T):
+        n = min(T, seq - start)
+        pad = lambda t: jnp.pad(t[:, :, start:start + n], ((0, 0), (0, 0), (0, T - n), (0, 0)))  # noqa: E731
+        pos = jnp.asarray([start], jnp.int32)
+        kp = inf.paged_cache_write_slices(kp, 1, pad(k), table, pos)
+        vp = inf.paged_cache_write_slices(vp, 1, pad(v), table, pos)
+        outs.append(inf.paged_chunk_attention(pad(q), kp[1], vp[1], table, pos, block_pages=3)[:, :, :n])
+    got = jnp.concatenate(outs, axis=2)
+    kr, vr = jnp.repeat(k, G, axis=1), jnp.repeat(v, G, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, kr) / np.sqrt(d)
+    s = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), s, -jnp.inf)
+    want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), vr)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert not np.asarray(kp[0]).any()  # the other layer untouched
+    # a decode row with write_mask False goes to the garbage page
+    one = jnp.ones((2, Hkv, 1, d))
+    t2 = jnp.asarray([[5, 2, 7, 1, 3, 8, 0, 0], [4, 6, 0, 0, 0, 0, 0, 0]], jnp.int32)
+    out = inf.paged_cache_write_slices(jnp.zeros_like(kp), 0, one, t2, jnp.asarray([9, 3], jnp.int32), jnp.asarray([True, False]))
+    assert np.asarray(out[0, 2, :, 1]).all() and not np.asarray(out[0, 4]).any() and np.asarray(out[0, 0, :, 0]).all()
+
+
+@pytest.mark.parametrize("start,T", [(3, 16), (5, 8), (13, 4), (8, 16), (0, 12), (30, 24), (60, 16)])
+def test_slice_writes_split_a_chunk_at_the_page_edges_wherever_it_starts(start, T):
+    """A chunk that starts inside a page (a prefix hit, any later caller)
+    lands where the scatter puts it: no block is clamped onto a page
+    boundary; what would pass the slot's last page is dropped, and a
+    masked row goes to the garbage page."""
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    H, page_len, d = 2, 8, 4
+    rng = np.random.default_rng(start * 31 + T)
+    pool = jnp.asarray(rng.standard_normal((2, 12, H, page_len, d)), jnp.float32)
+    t = jnp.asarray(rng.standard_normal((2, H, T, d)), jnp.float32)
+    table = jnp.asarray([[5, 2, 7, 1, 3, 8, 9, 10], [4, 6, 11, 0, 0, 0, 0, 0]], jnp.int32)
+    pos = jnp.asarray([start, 2], jnp.int32)
+    mask = jnp.asarray([True, False])
+    got = np.asarray(inf.paged_cache_write_slices(pool, 1, t, table, pos, mask))
+    want = np.array(pool)
+    for j in range(T):  # row 0, position by position; row 1 is masked: only the garbage page may change
+        at = start + j
+        if at < table.shape[1] * page_len:
+            want[1, int(table[0, at // page_len]), :, at % page_len] = np.asarray(t[0, :, j])
+    live = [p for p in range(12) if p != 0]
+    np.testing.assert_array_equal(got[1, live], want[1, live])
+    np.testing.assert_array_equal(got[0], np.asarray(pool[0]))  # the other layer untouched
+    if start + T <= table.shape[1] * page_len:  # inside the slot: the scatter agrees
+        ref = np.asarray(inf.paged_cache_write(pool[1], t, table, pos, mask))
+        np.testing.assert_array_equal(got[1, live], ref[live])
