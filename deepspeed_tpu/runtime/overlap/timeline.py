@@ -64,7 +64,8 @@ class StepTimeline:
     ``prefix`` (``train`` or ``serve``) names the engine in the
     profiler's trace: ``ds.<prefix>.<phase>``.  :meth:`set_gauge`
     records per-step levels (e.g. queue depth) that are averaged — not
-    ms-scaled — in :meth:`summary`."""
+    ms-scaled — in :meth:`summary`; :meth:`count` keeps running totals
+    of events since the last :meth:`reset_window`."""
 
     def __init__(self, enabled: bool = True, window: int = 512, phases=None,
                  sub_phases=(), blocked_on: Optional[str] = None, prefix: str = "train"):
@@ -81,6 +82,7 @@ class StepTimeline:
         self._pending: Dict[str, float] = {}
         self._pending_gauges: Dict[str, float] = {}
         self._gauge_names: set = set()
+        self.counts: Dict[str, int] = {}
         self._last_boundary: Optional[float] = None
         # comm metadata (docs/comm.md): the active gradient-exchange
         # strategy and its modeled bytes/step — static per engine, set
@@ -162,6 +164,15 @@ class StepTimeline:
         self._pending_gauges[name] = float(value)
         self._gauge_names.add(name)
 
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to the event counter ``name`` (serving:
+        ``stage_puts``, ``programs``): a running total, not a per-step
+        level — :meth:`summary` reports it as it stands, and
+        :meth:`reset_window` starts it afresh."""
+        if not self.enabled:
+            return
+        self.counts[name] = self.counts.get(name, 0) + int(n)
+
     def end_step(self, count: int = 1) -> None:
         """Close the pending record against the wall clock.  ``count > 1``
         spreads the window evenly over ``count`` steps (one compiled
@@ -200,9 +211,11 @@ class StepTimeline:
         self._pending_gauges = {}
 
     def reset_window(self) -> None:
-        """Drop recorded steps (keep the wall anchor); the next
-        ``summary()`` covers only steps recorded after this call."""
+        """Drop recorded steps and zero the event counters (keep the
+        wall anchor); the next ``summary()`` covers only what was
+        recorded after this call."""
         self.records.clear()
+        self.counts = dict.fromkeys(self.counts, 0)
 
     # -- reporting --------------------------------------------------------
     def summary(self, last_n: Optional[int] = None) -> Dict[str, float]:
@@ -222,6 +235,7 @@ class StepTimeline:
         out["steps_per_s"] = 0.0
         for g in sorted(self._gauge_names):
             out[g] = 0.0
+        out.update(self.counts)
         if self.comm_strategy is not None:
             out["comm_strategy"] = self.comm_strategy
             out["comm_bytes_per_step"] = self.comm_bytes

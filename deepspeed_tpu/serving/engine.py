@@ -54,6 +54,7 @@ from deepspeed_tpu.serving.scheduler import (
     ServingQueueFull,
     advance_request_ids,
 )
+from deepspeed_tpu.serving.staging import PackedLayout
 from deepspeed_tpu.serving.watchdog import ServingWatchdog
 from deepspeed_tpu.utils.logging import log_dist, logger
 
@@ -229,6 +230,16 @@ class ServingEngine:
         # the engine's local EWMA otherwise (scheduler stays jax-free)
         self.scheduler.step_seconds_fn = self._measured_step_seconds
         self._step_wall_ewma: Optional[float] = None
+        # each program's host-made inputs travel as ONE packed int32
+        # array (serving/staging.py), laid out from the program's field
+        # list.  Each is filled through the views of a buffer kept
+        # across steps and handed over as a copy
+        self._prefill_layout = PackedLayout(self._prefill_fields())
+        self._prefill_buffer = self._prefill_layout.buffer()
+        self._prefill_views = self._prefill_layout.views(self._prefill_buffer)
+        self._decode_layout = PackedLayout(self._decode_fields())
+        self._decode_buffer = self._decode_layout.buffer()
+        self._decode_views = self._decode_layout.views(self._decode_buffer)
 
         # client_key -> request id (the fleet router's at-most-once
         # admission map; seeded from the journal when one is armed)
@@ -366,6 +377,7 @@ class ServingEngine:
             n_pos = self.engine.model_config.n_positions
             chunk = self.config.prefill_chunk
             max_top_k = self.config.max_top_k
+            unpack = self._prefill_layout.unpack
 
             def _take_slot(c, slot):
                 return jax.tree.map(
@@ -384,8 +396,10 @@ class ServingEngine:
             if self._family_forward is not None:
                 fwd = self._family_forward
 
-                def serve_prefill(params, toks, table, slot, pos, take_idx, cow_src, cow_dst,
-                                  flag, temp, topk, seed, k_pool, v_pool, state_pool):
+                def serve_prefill(params, packed, k_pool, v_pool, state_pool):
+                    f = unpack(packed)
+                    toks, table, pos, take_idx = f["tokens"], f["table"], f["pos"], f["take_idx"]
+                    cow_src, cow_dst, slot = f["cow_src"], f["cow_dst"], f.get("slot")
                     # the paged step below with the family's own forward
                     # on its own cache kind; the chunk's padded tail is
                     # computed and left out of the family's counters.
@@ -393,8 +407,8 @@ class ServingEngine:
                     # chunk's ``slot`` says which rows of it are this
                     # request's; copy-on-write is a matter of pages and
                     # never touches it.  A kind that is pages and nothing
-                    # else hands None for both (empty pytrees: nothing
-                    # is staged or donated for them)
+                    # else has no ``slot`` among its fields and hands None
+                    # for the group (an empty pytree: nothing is donated)
                     cow = lambda b: b.at[:, cow_dst].set(b[:, cow_src])  # noqa: E731
                     k_pool = jax.tree.map(cow, k_pool)
                     v_pool = jax.tree.map(cow, v_pool)
@@ -403,17 +417,19 @@ class ServingEngine:
                         row_valid=(jnp.arange(chunk, dtype=jnp.int32) <= take_idx)[None, :],
                         take=take_idx[None], state=state_pool, slot=None if slot is None else slot[None],
                     )
-                    key = jax.random.fold_in(jax.random.PRNGKey(seed), pos + take_idx)
+                    key = jax.random.fold_in(jax.random.PRNGKey(f["seed"]), pos + take_idx)
                     first = sample_logits_pooled(
-                        logits.astype(jnp.float32), key[None], flag[None], temp[None],
-                        topk[None], max_top_k,
+                        logits.astype(jnp.float32), key[None], f["do_sample"][None], f["temperature"][None],
+                        f["top_k"][None], max_top_k,
                     )[0]
                     return (first, aux), k_pool, v_pool, state_pool
 
-                donate = (12, 13, 14)
+                donate = (2, 3, 4)
             elif self._paged:
-                def serve_prefill(params, toks, table, pos, take_idx, cow_src, cow_dst,
-                                  flag, temp, topk, seed, k_pool, v_pool):
+                def serve_prefill(params, packed, k_pool, v_pool):
+                    f = unpack(packed)
+                    toks, table, pos, take_idx = f["tokens"], f["table"], f["pos"], f["take_idx"]
+                    cow_src, cow_dst = f["cow_src"], f["cow_dst"]
                     # the slot's pending copy-on-write lands BEFORE this
                     # chunk's writes: a traced (src, dst) page pair rides
                     # the request's first chunk ((0, 0) — garbage page
@@ -429,19 +445,20 @@ class ServingEngine:
                         position_ids=position_ids, page_table=table[None, :],
                     )
                     key = jax.random.fold_in(
-                        jax.random.PRNGKey(seed), pos + take_idx
+                        jax.random.PRNGKey(f["seed"]), pos + take_idx
                     )
                     first = sample_logits_pooled(
                         logits[0, take_idx].astype(jnp.float32)[None, :],
-                        key[None], flag[None], temp[None], topk[None],
+                        key[None], f["do_sample"][None], f["temperature"][None], f["top_k"][None],
                         max_top_k,
                     )[0]
                     return first, k_pool, v_pool
 
-                donate = (11, 12)
+                donate = (2, 3)
             else:
-                def serve_prefill(params, toks, slot, pos, take_idx, flag, temp, topk, seed,
-                                  k_pool, v_pool):
+                def serve_prefill(params, packed, k_pool, v_pool):
+                    f = unpack(packed)
+                    toks, slot, pos, take_idx = f["tokens"], f["slot"], f["pos"], f["take_idx"]
                     ks, vs = _take_slot(k_pool, slot), _take_slot(v_pool, slot)
                     # explicit clipped position ids: the zero-padded chunk
                     # tail must not clamp the wpe slice and shift real rows
@@ -454,18 +471,18 @@ class ServingEngine:
                     # the first generated token samples with the request's
                     # params (the same key schedule as decode: key = seed
                     # folded with the fed token's cache position)
-                    key = jax.random.fold_in(jax.random.PRNGKey(seed), pos + take_idx)
+                    key = jax.random.fold_in(jax.random.PRNGKey(f["seed"]), pos + take_idx)
                     first = sample_logits_pooled(
                         logits[0, take_idx].astype(jnp.float32)[None, :],
                         key[None],
-                        flag[None],
-                        temp[None],
-                        topk[None],
+                        f["do_sample"][None],
+                        f["temperature"][None],
+                        f["top_k"][None],
                         max_top_k,
                     )[0]
                     return first, _put_slot(k_pool, ks, slot), _put_slot(v_pool, vs, slot)
 
-                donate = (9, 10)
+                donate = (2, 3)
 
             # the function's name is the program's in the profiler's
             # trace: jit_serve_prefill on the device's "XLA Modules" line
@@ -486,12 +503,14 @@ class ServingEngine:
 
             icfg = self.engine.inference_config(self.pool.max_len) if self._family_forward is None else None
             max_top_k = self.config.max_top_k
+            unpack = self._decode_layout.unpack
 
             if self._family_forward is not None:
                 fwd = self._family_forward
 
-                def serve_decode(params, toks, pos, flags, temps, topks, seeds,
-                                 page_table, write_mask, k_pool, v_pool, state_pool):
+                def serve_decode(params, packed, k_pool, v_pool, state_pool):
+                    f = unpack(packed)
+                    toks, pos, page_table, write_mask = f["toks"], f["pos"], f["tables"], f["write_mask"]
                     # row b is slot b: the rows of ``state_pool`` are the batch's
                     logits, k_pool, v_pool, state_pool, aux = fwd(
                         params, toks[:, None], k_pool, v_pool, pos, page_table=page_table,
@@ -499,16 +518,17 @@ class ServingEngine:
                     )
                     keys = jax.vmap(
                         lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
-                    )(seeds, pos)
+                    )(f["seeds"], pos)
                     nxt = sample_logits_pooled(
-                        logits.astype(jnp.float32), keys, flags, temps, topks, max_top_k,
+                        logits.astype(jnp.float32), keys, f["flags"], f["temps"], f["topks"], max_top_k,
                     )
                     return (nxt, aux), k_pool, v_pool, state_pool
 
-                donate = (9, 10, 11)
+                donate = (2, 3, 4)
             elif self._paged:
-                def serve_decode(params, toks, pos, flags, temps, topks, seeds,
-                                 page_table, write_mask, k_pool, v_pool):
+                def serve_decode(params, packed, k_pool, v_pool):
+                    f = unpack(packed)
+                    toks, pos, page_table, write_mask = f["toks"], f["pos"], f["tables"], f["write_mask"]
                     # per-slot page tables are traced values of the one
                     # fixed signature; write_mask redirects non-decoding
                     # slots' writes to the garbage page (pages.py)
@@ -518,16 +538,18 @@ class ServingEngine:
                     )
                     keys = jax.vmap(
                         lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
-                    )(seeds, pos)
+                    )(f["seeds"], pos)
                     nxt = sample_logits_pooled(
-                        logits[:, -1].astype(jnp.float32), keys, flags, temps,
-                        topks, max_top_k,
+                        logits[:, -1].astype(jnp.float32), keys, f["flags"], f["temps"],
+                        f["topks"], max_top_k,
                     )
                     return nxt, k_pool, v_pool
 
-                donate = (9, 10)
+                donate = (2, 3)
             else:
-                def serve_decode(params, toks, pos, flags, temps, topks, seeds, k_pool, v_pool):
+                def serve_decode(params, packed, k_pool, v_pool):
+                    f = unpack(packed)
+                    toks, pos = f["toks"], f["pos"]
                     # per-slot pos: slot-indexed cache write + position mask
                     # (ops/transformer/inference.py), auto-clipped position ids
                     logits, k_pool, v_pool = forward_with_cache(
@@ -537,14 +559,14 @@ class ServingEngine:
                     # request regardless of slot assignment or pool churn
                     keys = jax.vmap(
                         lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
-                    )(seeds, pos)
+                    )(f["seeds"], pos)
                     nxt = sample_logits_pooled(
-                        logits[:, -1].astype(jnp.float32), keys, flags, temps, topks,
+                        logits[:, -1].astype(jnp.float32), keys, f["flags"], f["temps"], f["topks"],
                         max_top_k,
                     )
                     return nxt, k_pool, v_pool
 
-                donate = (7, 8)
+                donate = (2, 3)
 
             self._decode_jit = jax.jit(self.engine._scoped(serve_decode), donate_argnums=donate)
             self._decode_fn = self._wrap(self._decode_jit, "serving.decode")
@@ -563,55 +585,68 @@ class ServingEngine:
             lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=a.sharding), tree
         )
 
-    def _abstract_staged(self, shape, dtype):
-        """A host-fed argument, as the step stages it (replicated)."""
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=self._replicated)
+    def _abstract_staged(self, layout: PackedLayout):
+        """A program's packed host-made inputs, as the step stages them
+        (one int32 vector, replicated)."""
+        return jax.ShapeDtypeStruct((layout.size,), jnp.int32, sharding=self._replicated)
+
+    def _decode_fields(self):
+        """The decode program's host-made inputs, one entry a slot:
+        ``(name, shape, dtype)`` in the order the program reads them —
+        the one parameter of its packed layout (serving/staging.py)."""
+        S = self.pool.num_slots
+        fields = [
+            ("toks", (S,), np.int32),
+            ("pos", (S,), np.int32),
+            ("flags", (S,), np.bool_),
+            ("temps", (S,), np.float32),
+            ("topks", (S,), np.int32),
+            ("seeds", (S,), np.uint32),
+        ]
+        if self._paged:
+            fields += [
+                ("write_mask", (S,), np.bool_),
+                ("tables", (S, self.pool.pages_per_slot), np.int32),
+            ]
+        return fields
+
+    def _prefill_fields(self):
+        """The prefill program's host-made inputs (one chunk, one slot).
+        ``slot`` is a field where the cache keeps something by slot: the
+        slot-contiguous pool, and a family's kind with per-slot state."""
+        scalar = lambda name, dtype=np.int32: (name, (), dtype)  # noqa: E731
+        fields = [("tokens", (1, self.config.prefill_chunk), np.int32)]
+        if self._paged:
+            fields.append(("table", (self.pool.pages_per_slot,), np.int32))
+        if not self._paged or self.pool.state is not None:
+            fields.append(scalar("slot"))
+        fields += [scalar("pos"), scalar("take_idx")]
+        if self._paged:
+            fields += [scalar("cow_src"), scalar("cow_dst")]
+        fields += [
+            scalar("do_sample", np.bool_),
+            scalar("temperature", np.float32),
+            scalar("top_k"),
+            scalar("seed", np.uint32),
+        ]
+        return fields
+
+    def _abstract_args(self, layout: PackedLayout):
+        """A serve executable's argument signature as ShapeDtypeStructs
+        (pool-derived, nothing executes): the params, the program's one
+        staged array and the donated cache — shared by ``compiled_step``
+        and the ds_shard collective audit's AOT feed."""
+        return (
+            self._abstract_tree(self.engine.params),
+            self._abstract_staged(layout),
+            *(self._abstract_tree(a) for a in self._pool_args()),
+        )
 
     def _decode_abstract_args(self):
-        """The decode executable's argument signature as
-        ShapeDtypeStructs (pool-derived, nothing executes) — shared by
-        ``compiled_step`` and the ds_shard collective audit."""
-        S = self.pool.num_slots
-        vec = lambda dtype: self._abstract_staged((S,), dtype)  # noqa: E731
-        args = [
-            self._abstract_tree(self.engine.params),
-            vec(jnp.int32),    # toks
-            vec(jnp.int32),    # pos
-            vec(jnp.bool_),    # flags
-            vec(jnp.float32),  # temps
-            vec(jnp.int32),    # topks
-            vec(jnp.uint32),   # seeds
-        ]
-        if self._paged:
-            args += [
-                self._abstract_staged((S, self.pool.pages_per_slot), jnp.int32),
-                vec(jnp.bool_),  # write_mask
-            ]
-        args += [self._abstract_tree(a) for a in self._pool_args()]
-        return tuple(args)
+        return self._abstract_args(self._decode_layout)
 
     def _prefill_abstract_args(self):
-        """The prefill executable's argument signature (one chunk, one
-        slot) as ShapeDtypeStructs — the ds_shard audit's AOT feed."""
-        chunk = self.config.prefill_chunk
-        scalar = lambda dtype: self._abstract_staged((), dtype)  # noqa: E731
-        args = [self._abstract_tree(self.engine.params),
-                self._abstract_staged((1, chunk), jnp.int32)]
-        if self._paged:
-            args.append(self._abstract_staged((self.pool.pages_per_slot,), jnp.int32))
-            if self._family_forward is not None:
-                args.append(scalar(jnp.int32) if self.pool.state is not None else None)  # slot
-            args += [scalar(jnp.int32)] * 4  # pos, take_idx, cow_src, cow_dst
-        else:
-            args += [scalar(jnp.int32)] * 3  # slot, pos, take_idx
-        args += [
-            scalar(jnp.bool_),    # do_sample
-            scalar(jnp.float32),  # temperature
-            scalar(jnp.int32),    # top_k
-            scalar(jnp.uint32),   # seed
-        ]
-        args += [self._abstract_tree(a) for a in self._pool_args()]
-        return tuple(args)
+        return self._abstract_args(self._prefill_layout)
 
     def compiled_step(self, which: str):
         """The ``"prefill"`` or ``"decode"`` step AOT-compiled against
@@ -960,9 +995,9 @@ class ServingEngine:
             for job in plan.prefill_jobs:
                 self._run_prefill(job)
         with tl.phase("decode"):
-            toks, pos, decoding = self.scheduler.decode_inputs()
+            decoding = self.scheduler.decoding()
             if decoding:
-                self._run_decode(toks, pos, decoding)
+                self._run_decode(decoding)
         tl.set_gauge("queue_depth", self.scheduler.queue_depth)
         tl.set_gauge("live_slots", self.pool.live_slots)
         tl.end_step()
@@ -1272,35 +1307,17 @@ class ServingEngine:
         tl = self.timeline
         fn = self._get_prefill()
         r = job.req
-        # explicit staging of the host-side chunk + scalars onto the
-        # serving mesh (transfer-guard clean: device_put is sanctioned,
-        # and pre-placing on the mesh means the jit has nothing to move)
+        # explicit staging of the chunk's packed inputs onto the serving
+        # mesh (transfer-guard clean: device_put is sanctioned, and
+        # pre-placing on the mesh means the jit has nothing to move)
         with tl.phase("prefill.stage"):
-            if self._paged:
-                cow_src, cow_dst = self.pool.consume_cow(r.slot)
-                # a family's step is told the slot where its cache kind keeps per-slot state (else None: nothing staged)
-                slot_arg = () if self._family_forward is None else (np.int32(r.slot) if self.pool.state is not None else None,)
-                staged = jax.device_put(
-                    (job.tokens[None, :], self.pool.table(r.slot), *slot_arg,
-                     np.int32(job.start), np.int32(job.take_idx),
-                     np.int32(cow_src), np.int32(cow_dst),
-                     np.bool_(r.do_sample), np.float32(r.temperature),
-                     np.int32(r.top_k), np.uint32(r.seed & 0xFFFFFFFF)),
-                    self._replicated,
-                )
-            else:
-                staged = jax.device_put(
-                    (job.tokens[None, :], np.int32(r.slot), np.int32(job.start),
-                     np.int32(job.take_idx), np.bool_(r.do_sample),
-                     np.float32(r.temperature), np.int32(r.top_k),
-                     np.uint32(r.seed & 0xFFFFFFFF)),
-                    self._replicated,
-                )
+            staged = self._stage(self._prefill_inputs(job))
         tracer = self.telemetry.tracer if self.telemetry.tracer.enabled else None
         t0 = tracer.now() if tracer is not None else 0.0
         guard = san.transfer.guard("serving.prefill") if san is not None else nullcontext()
         with tl.phase("prefill.dispatch"), guard:
-            first, *pools = fn(self.engine.params, *staged, *self._pool_args())
+            tl.count("programs")
+            first, *pools = fn(self.engine.params, staged, *self._pool_args())
         self.pool.swap(*pools)
         if self._family_forward is not None and job.start == 0:
             self._state_resets += 1  # taken inside the program: the chunk at position 0 starts from zero
@@ -1328,32 +1345,18 @@ class ServingEngine:
             )
         self.scheduler.note_prefill(job, tok, now=now, step=self._step_count)
 
-    def _run_decode(self, toks: np.ndarray, pos: np.ndarray, decoding) -> None:
+    def _run_decode(self, decoding) -> None:
         faults.check("serving.decode")
         faults.check_latency("serving.decode")
         san = self._sanitizer
         tl = self.timeline
         fn = self._get_decode()
         with tl.phase("decode.stage"):
-            flags, temps, topks, seeds = self.scheduler.sampling_inputs()
-            if self._paged:
-                # non-decoding slots write to the garbage page; their reads
-                # were already safe behind the position mask
-                wmask = np.zeros((self.pool.num_slots,), np.bool_)
-                for r in decoding:
-                    wmask[r.slot] = True
-                staged = jax.device_put(
-                    (toks, pos, flags, temps, topks, seeds,
-                     self.pool.tables(), wmask),
-                    self._replicated,
-                )
-            else:
-                staged = jax.device_put(
-                    (toks, pos, flags, temps, topks, seeds), self._replicated
-                )
+            staged = self._stage(self._decode_inputs())
         guard = san.transfer.guard("serving.decode") if san is not None else nullcontext()
         with tl.phase("decode.dispatch"), guard:
-            nxt, *pools = fn(self.engine.params, *staged, *self._pool_args())
+            tl.count("programs")
+            nxt, *pools = fn(self.engine.params, staged, *self._pool_args())
         self.pool.swap(*pools)
         self._decode_rows += len(decoding)
         self._decode_steps += 1
@@ -1364,6 +1367,46 @@ class ServingEngine:
         self.scheduler.note_decode(
             {r.slot: int(out[r.slot]) for r in decoding}, now, self._step_count
         )
+
+    def _prefill_inputs(self, job: PrefillJob) -> np.ndarray:
+        """One chunk's packed inputs.  The slot's pending copy-on-write
+        pair is consumed into its first chunk."""
+        r, v = job.req, self._prefill_views
+        v["tokens"][...] = job.tokens
+        v["pos"][...] = job.start
+        v["take_idx"][...] = job.take_idx
+        v["do_sample"][...] = r.do_sample
+        v["temperature"][...] = r.temperature
+        v["top_k"][...] = r.top_k
+        v["seed"][...] = r.seed & 0xFFFFFFFF
+        if "slot" in v:
+            v["slot"][...] = r.slot
+        if self._paged:
+            v["table"][...] = self.pool.table(r.slot)
+            v["cow_src"][...], v["cow_dst"][...] = self.pool.consume_cow(r.slot)
+        return self._prefill_buffer.copy()
+
+    def _decode_inputs(self) -> np.ndarray:
+        """The decode step's packed inputs: the scheduler writes its
+        per-slot rows and the pool its page tables into the buffer kept
+        across steps.  What is handed on — here and for a chunk — is a
+        copy, the hand-over's own: ``device_put`` on the CPU backend may
+        alias a NumPy array's memory, and the next step writes the buffer
+        while the program may still read what was staged."""
+        views = self._decode_views
+        self.scheduler.write_decode_inputs(views)
+        if self._paged:
+            # non-decoding slots write to the garbage page (write_mask);
+            # their reads were already safe behind the position mask
+            self.pool.tables(out=views["tables"])
+        return self._decode_buffer.copy()
+
+    def _stage(self, host):
+        """The step's one sanctioned host→device transfer a program:
+        ``host`` onto the serving mesh, replicated; every array handed
+        over is counted (``stage_puts``)."""
+        self.timeline.count("stage_puts", 1 if isinstance(host, np.ndarray) else len(jax.tree.leaves(host)))
+        return jax.device_put(host, self._replicated)
 
     def _pool_args(self) -> tuple:
         """The donated cache arguments of a serving step: K and V, and —
@@ -1481,6 +1524,10 @@ class ServingEngine:
         # Solar-Open2: kda_decode_kernel / _fallback, kda_prefill_form, gqa_decode_kernel / _fallback, gqa_prefill_form)
         out.update(getattr(self._family_forward, "trace_notes", {}))
         out.update(self.timeline.summary())
+        if out.get("programs"):
+            # host→device transfers a program since the timeline's last
+            # reset: 1.0, each program's inputs being one packed array
+            out["staged_puts_per_program"] = out["stage_puts"] / out["programs"]
         return out
 
 

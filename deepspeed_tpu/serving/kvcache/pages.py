@@ -585,8 +585,13 @@ class PagedKVPool:
         return self._tables[slot].copy()
 
     @_locked
-    def tables(self) -> np.ndarray:
-        return self._tables.copy()
+    def tables(self, out: Optional[np.ndarray] = None) -> np.ndarray:
+        """Every slot's page ids: a copy, or written into ``out`` (the
+        table section of the engine's staging buffer) in one block."""
+        if out is None:
+            return self._tables.copy()
+        out[...] = self._tables
+        return out
 
     # -- prefix learning --------------------------------------------------
     @_locked

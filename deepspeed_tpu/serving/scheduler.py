@@ -844,6 +844,43 @@ class ContinuousScheduler:
             seeds[slot] = np.uint32(r.seed & 0xFFFFFFFF)
         return flags, temps, topks, seeds
 
+    def decoding(self) -> List[Request]:
+        """The requests a decode step advances now."""
+        return [r for r in self._active.values() if r.status == DECODE]
+
+    def write_decode_inputs(self, out: Dict[str, np.ndarray]) -> None:
+        """What :meth:`decode_inputs`, :meth:`sampling_inputs` and the
+        paged pool's write mask say, written into the caller's per-slot
+        arrays — ``out["toks" | "pos" | "flags" | "temps" | "topks" |
+        "seeds"]`` and, where present, ``out["write_mask"]`` (true on the
+        decoding slots: every other slot's write goes to the garbage
+        page) — in one walk of the live set and one indexed write a row.
+        The arrays are the engine's packed staging buffer's views
+        (serving/staging.py: a boolean row holds 0 / 1), so each row is
+        reset to a free slot's values first."""
+        for name, free in (("toks", 0), ("pos", 0), ("flags", 0), ("temps", 1.0),
+                           ("topks", 0), ("seeds", 0), ("write_mask", 0)):
+            if name in out:
+                out[name][:] = free
+        if not self._active:
+            return
+        reqs = list(self._active.values())
+        slots = np.fromiter(self._active, np.intp, len(reqs))
+        out["flags"][slots] = [r.do_sample for r in reqs]
+        out["temps"][slots] = [r.temperature for r in reqs]
+        out["topks"][slots] = [r.top_k for r in reqs]
+        out["seeds"][slots] = [r.seed & 0xFFFFFFFF for r in reqs]
+        out["pos"][slots] = [
+            len(r.prompt) + len(r.generated) - 1 if r.status == DECODE else r.prefill_pos
+            for r in reqs
+        ]
+        decoding = self.decoding()
+        if decoding:
+            dslots = np.fromiter((r.slot for r in decoding), np.intp, len(decoding))
+            out["toks"][dslots] = [r.generated[-1] for r in decoding]
+            if "write_mask" in out:
+                out["write_mask"][dslots] = 1
+
     def note_decode(self, tokens_by_slot: Dict[int, int], now: float, step: int) -> None:
         """Append this step's token per decoding slot; retire at EOS or
         budget — the slot frees *this* token, not at batch end."""
