@@ -149,6 +149,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, t_start: flo
         record = runner.run(ctx)
     finally:
         ctx.stop_trace()
+    tl = record["counters"].get("timeline") or {}
+    if "wall_ms_p50" in tl:  # beside a run that reads far off: was every call into PJRT longer, or a few steps stalled?
+        ctx.say("engine's step timeline over the window, p50 / mean ms: " + ", ".join(
+            f"{p} {tl[p + '_ms_p50']} / {tl[p + '_ms']}" for p in ("wall", "stage", "dispatch", "wait") if p + "_ms_p50" in tl))
     # the engines' own counters see their step executables; this sees every program, however small
     record["counters"]["compiles_in_window"] += ctx.programs_built_in_window()
     ctx.say(f"programs built in the window: {record['counters']['compiles_in_window']} "
@@ -181,9 +185,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, t_start: flo
         if value is not None:
             metrics[entry["name"]] = {"value": float(value), "unit": entry["unit"]}
 
-    for c in record["checks"]:
-        print(f"check {c['name']}: {c['value']!r} (limit {c['op']} {c['limit']!r}) -> "
-              f"{'ok' if c['ok'] else 'NOT OK'}", flush=True)
     result: Dict[str, Any] = {
         "correct": bool(record["checks"]) and all(c["ok"] for c in record["checks"]),
         "attempted": int(record["attempted"]), "failed": int(record["failed"]),
@@ -193,6 +194,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, t_start: flo
         result["device"]["busy_s"] = reduced["busy_s"]
         result["device"]["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"]}
+    # every number compared beside its limit: the last key of the line, and the last lines on standard error
+    result["checks"] = {c["name"]: {"value": c["value"] if c["value"] == c["value"] else None, "limit": c["limit"],
+                                    "op": c["op"], "ok": c["ok"]} for c in record["checks"]}
+    for c in record["checks"]:
+        print(f"check {c['name']}: {c['value']!r} (limit {c['op']} {c['limit']!r}) -> "
+              f"{'ok' if c['ok'] else 'NOT OK'}", file=sys.stderr, flush=True)
     return {"result": result, "record": record}
 
 

@@ -118,12 +118,13 @@ def run(ctx) -> Dict[str, Any]:
         raise RuntimeError("the window never opened: the pre-roll outlasted the run")
     ctx.window_closes()
 
-    w = stamps.window_metrics(stamper.requests, t_open, t_close, float(mix.get("ttft_sample_share", 0.9)))
+    w = stamps.window_metrics(stamper.requests, t_open, t_close, float(mix.get("ttft_sample_share", 0.9)),
+                              open_loop=open_loop)
     tl = srv.timeline.summary()
     kv = pool_stats()
     in_window = [s for s in steps if t_open <= s["t1"] < t_close]
     traced = [s for s in in_window if ctx.trace_t0 is not None and s["t0"] >= ctx.trace_t0]
-    ctx.say(f"window: {w['tokens']} tokens / {w['window_s']:.1f}s, {len(in_window)} steps, "
+    ctx.say(f"window: {w['tokens']} tokens counted of {w['tokens_emitted']} emitted / {w['window_s']:.1f}s, {len(in_window)} steps, "
             f"{w['attempted']} attempted, {w['failed']} failed, {len(served)} finished")
 
     # ---- correctness, outside the window ---------------------------------

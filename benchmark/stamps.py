@@ -63,11 +63,19 @@ class TokenStamper:
 
 
 def window_metrics(requests: List[Dict[str, Any]], t_open: float, t_close: float,
-                   ttft_share: float = 0.9) -> Dict[str, Any]:
+                   ttft_share: float = 0.9, open_loop: bool = False) -> Dict[str, Any]:
     """What the stamps say about ``[t_open, t_close)``.
 
-    * ``tokens``: stamps inside the window, whichever request they
-      belong to and whether or not it finished;
+    * ``tokens_emitted``: stamps inside the window, whichever request
+      they belong to and whether or not it finished;
+    * ``tokens``: what the rate counts.  In a closed loop the backlog is
+      the load and the reading is capacity: every emitted token.  In an
+      open loop (``open_loop``) only the stamps of requests **due inside
+      the window** (the set ``attempted`` counts): the offered traffic's
+      tokens served in time.  The backlog carried in from before the
+      window leaves the number, so it is at most the offered rate and
+      falls only as the engine falls behind — counting every stamp read
+      *lower* the faster the engine drained what it carried in (PR 26);
     * ``ttft_ms``: due time → first stamp, over requests due in the
       first ``ttft_share`` of the window (later ones may fairly still be
       waiting at the close); one of those with no first token by the
@@ -76,18 +84,20 @@ def window_metrics(requests: List[Dict[str, Any]], t_open: float, t_close: float
       whose later stamp lies in the window;
     * ``attempted``: requests due inside the window.
     """
-    tokens = 0
+    tokens = emitted = 0
     ttft, gaps, tpot = [], [], []
     attempted = failed = 0
     oldest_wait = 0.0
     sample_end = t_open + ttft_share * (t_close - t_open)
     for r in requests:
         stamps = [s for s in r["stamps"] if s < t_close]
-        tokens += sum(1 for s in stamps if s >= t_open)
+        inside = [s for s in stamps if s >= t_open]
+        emitted += len(inside)
+        if not open_loop or t_open <= r["due"] < t_close:
+            tokens += len(inside)
         for a, b in zip(stamps, stamps[1:]):
             if b >= t_open:
                 gaps.append((b - a) * 1e3)
-        inside = [s for s in stamps if s >= t_open]
         if len(inside) >= 2:
             tpot.append((inside[-1] - inside[0]) * 1e3 / (len(inside) - 1))
         if r["due"] < t_close and not r["refused"] and (not stamps or stamps[0] >= t_open):
@@ -102,7 +112,7 @@ def window_metrics(requests: List[Dict[str, Any]], t_open: float, t_close: float
                     ttft.append((stamps[0] - r["due"]) * 1e3)
                 else:
                     failed += 1
-    return {"tokens": tokens, "window_s": t_close - t_open, "ttft_ms": ttft, "gaps_ms": gaps,
+    return {"tokens": tokens, "tokens_emitted": emitted, "window_s": t_close - t_open, "ttft_ms": ttft, "gaps_ms": gaps,
             "tpot_ms": tpot, "attempted": attempted, "failed": failed, "oldest_waiting_s": oldest_wait}
 
 

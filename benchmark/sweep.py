@@ -59,7 +59,7 @@ def main():
                                manifest_path=os.path.join(scratch, "BENCHMARK.json"), scratch=scratch)
         w, c = out["record"]["window"], out["record"]["counters"]
         row = {"rate_rps": rate, "offered_tokens_per_s": rate * sum(answers) / len(answers),
-               "serve_tokens_per_s": w["tokens"] / w["window_s"], "ttft_p50_ms": pct(w["ttft_ms"], 50),
+               "serve_tokens_per_s": w["tokens_emitted"] / w["window_s"], "ttft_p50_ms": pct(w["ttft_ms"], 50),
                "ttft_p95_ms": pct(w["ttft_ms"], 95), "itl_p95_ms": pct(w["gaps_ms"], 95),
                "oldest_waiting_s": w["oldest_waiting_s"], "attempted": w["attempted"], "failed": w["failed"],
                "queue_depth_at_close": c["engine_stats"]["queue_depth_now"], "live_slots_mean": c["timeline"]["live_slots"],

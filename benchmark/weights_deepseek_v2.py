@@ -129,34 +129,34 @@ def program_params(seed: int, dims: Dict[str, Any], dtype=jnp.bfloat16) -> Dict[
     """The tree ``deepspeed_tpu.models.deepseek_v2`` takes, for the share
     ``dims`` states, made on the default device block by block (a layer's
     attention, one expert), each cast to ``dtype`` as it is made."""
-    key = seed_key(seed)
+    key = seed_key(seed)  # an argument of each maker: closed over, it is a constant of the program and every seed compiles its own
     first, count = held(dims)
     n_dense, n_layers = dims["first_k_dense_replace"], dims["num_hidden_layers"]
     cast = lambda t: jax.tree.map(lambda a: a.astype(dtype), t)  # noqa: E731
-    attn = jax.jit(lambda l: cast(attn_params(key, l, dims)))
+    attn = jax.jit(lambda key, l: cast(attn_params(key, l, dims)))
 
     @jax.jit
-    def dense(l):
+    def dense(key, l):
         m = cast(dense_mlp_params(key, l, dims))
         return {"mlp_gu": m["gu"], "mlp_down": m["down"]}
 
     @jax.jit
-    def shared(l):
+    def shared(key, l):
         s = cast(shared_params(key, l, dims))
         return {"router": s["router"], "shared_gu": s["gu"], "shared_down": s["down"]}
 
     @jax.jit
-    def expert(l, e):
+    def expert(key, l, e):
         x = cast(expert_params(key, l, e, dims))
         return {"experts_gu": x["gu"], "experts_down": x["down"]}
 
     rows = vocab_rows(dims)
     tree: Dict[str, Any] = {
-        "embed": jax.jit(lambda: table_rows(key, "embed", rows, dims).astype(dtype))(),
-        "head": jax.jit(lambda: table_rows(key, "head", rows, dims).astype(dtype))(),
+        "embed": jax.jit(lambda key: table_rows(key, "embed", rows, dims).astype(dtype))(key),
+        "head": jax.jit(lambda key: table_rows(key, "head", rows, dims).astype(dtype))(key),
         "norm_f": jnp.ones((dims["hidden_size"],), dtype),
     }
-    tree["layers"] = [{**attn(l), **dense(l)} for l in range(n_dense)] + [
-        {**attn(l), **shared(l), **_stacked(lambda e, l=l: expert(l, first + e), (count,))}
+    tree["layers"] = [{**attn(key, l), **dense(key, l)} for l in range(n_dense)] + [
+        {**attn(key, l), **shared(key, l), **_stacked(lambda e, l=l: expert(key, l, first + e), (count,))}
         for l in range(n_dense, n_layers)]
     return tree

@@ -99,32 +99,32 @@ def program_params(seed: int, dims: Dict[str, Any], dtype=jnp.bfloat16) -> Dict[
     ``dims`` states, made on the default device block by block, each
     cast to ``dtype`` as it is made (``A_log``, ``dt_bias`` and the
     router's bias too: the program reads them back into float32)."""
-    key = seed_key(seed)
+    key = seed_key(seed)  # an argument of each maker: closed over, it is a constant of the program and every seed compiles its own
     first, count = held(dims)
     D = dims["hidden_size"]
     cast = lambda t: jax.tree.map(lambda a: a.astype(dtype), t)  # noqa: E731
-    gqa = jax.jit(lambda l: cast(gqa_params(key, l, dims)))
-    kda = jax.jit(lambda l: cast(kda_params(key, l, dims)))
+    gqa = jax.jit(lambda key, l: cast(gqa_params(key, l, dims)))
+    kda = jax.jit(lambda key, l: cast(kda_params(key, l, dims)))
 
     @jax.jit
-    def shared(l):
+    def shared(key, l):
         s = cast(shared_params(key, l, dims))
         return {"router": s["router"], "router_bias": s["router_bias"], "shared_gu": s["gu"], "shared_down": s["down"]}
 
     @jax.jit
-    def expert(l, e):
+    def expert(key, l, e):
         x = cast(expert_params(key, l, e, dims))
         return {"experts_gu": x["gu"], "experts_down": x["down"]}
 
     rows = vocab_rows(dims)
     norms = lambda: {"attn_norm": jnp.ones((D,), dtype), "ffn_norm": jnp.ones((D,), dtype)}  # noqa: E731  (a buffer each: the tree is donated)
     tree: Dict[str, Any] = {
-        "embed": jax.jit(lambda: table_rows(key, "embed", rows, dims).astype(dtype))(),
-        "head": jax.jit(lambda: table_rows(key, "head", rows, dims).astype(dtype))(),
+        "embed": jax.jit(lambda key: table_rows(key, "embed", rows, dims).astype(dtype))(key),
+        "head": jax.jit(lambda key: table_rows(key, "head", rows, dims).astype(dtype))(key),
         "norm_f": jnp.ones((D,), dtype),
     }
     tree["layers"] = [
-        {**norms(), **(gqa(l) if is_gqa(dims, l) else kda(l)), **shared(l),
-         **_stacked(lambda e, l=l: expert(l, first + e), (count,))}
+        {**norms(), **(gqa(key, l) if is_gqa(dims, l) else kda(key, l)), **shared(key, l),
+         **_stacked(lambda e, l=l: expert(key, l, first + e), (count,))}
         for l in range(dims["num_hidden_layers"])]
     return tree

@@ -94,6 +94,9 @@ def test_new_files_only_cell_runs_and_reports_counts_only(toy_root, cell):
     res, rec = out["result"], out["record"]
     assert res["device"]["platform"] == "cpu"
     assert res["correct"] is True and res["failed"] == 0 and res["attempted"] >= 1
+    # each number compared beside its limit, under the line's last key
+    assert list(res)[-1] == "checks" and res["checks"] and json.loads(json.dumps(res)) == res
+    assert all(c["ok"] and c["value"] is not None and c["limit"] is not None for c in res["checks"].values())
     # the new metric was found by name and read; counts only — no time,
     # rate or device share from a CPU run
     assert res["metrics"]["toy_steps_counted"]["value"] == rec["window"]["steps"] >= 1
@@ -104,7 +107,9 @@ def test_new_files_only_cell_runs_and_reports_counts_only(toy_root, cell):
         assert res["device"]["count"] == 4  # ZeRO-3 over four (virtual) devices, checked against the one-device reference
     if not cell.startswith("toy-train"):
         assert "kv_alloc_waits" in res["metrics"]
-        assert rec["window"]["tokens"] > 0
+        # a closed loop counts every emitted token; an open one the tokens of the requests due in the window
+        assert 0 < rec["window"]["tokens"] <= rec["window"]["tokens_emitted"]
+        assert (rec["window"]["tokens"] == rec["window"]["tokens_emitted"]) or cell == "toy-open"
 
 
 def test_end_to_end_line_on_cpu_has_no_device_number(toy_root):

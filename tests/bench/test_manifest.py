@@ -54,7 +54,14 @@ def test_cell_resolves_its_files_by_name(cell):
     cfg = M.config(w["config"])
     entry = next(c for c in M.data["configs"] if c["name"] == w["config"])
     assert entry["file"].startswith(tuple(p + "/" for p in M.data["paths"]))
-    assert cfg["source"] == entry["source"] and cfg["reduced"] == entry["reduced"] == []
+    assert cfg["source"] == entry["source"] and cfg["reduced"] == entry["reduced"]
+    if entry["reduced"]:
+        # a configuration lists its cuts: each is a key of the file as it is run, and ``share`` states
+        # the deployment this is one chip of, with the published value of every key that was cut
+        assert set(entry["reduced"]) <= set(cfg) and len(entry["reduced"]) <= 16
+        share = cfg["share"]
+        assert set(share["published"]) == set(entry["reduced"]) and share["chips_per_layer"] > 1 and share["deployment"]
+        assert all(share["published"][k] != cfg[k] for k in entry["reduced"])
     assert hasattr(M.module("runners", cfg["runner"]), "run")
     assert M.traffic(w["traffic"])["kind"] in ("open", "closed", "tokens")
     e2e = {m["name"] for m in M.end_to_end(cell)}

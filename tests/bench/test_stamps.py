@@ -105,3 +105,139 @@ def test_request_the_engine_retires_short_is_failed():
 @pytest.mark.parametrize("q,want", [(0, 1.0), (50, 2.5), (95, 3.85), (100, 4.0)])
 def test_percentile_interpolates_like_numpy(q, want):
     assert percentile([4.0, 1.0, 3.0, 2.0], q) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("kind", ["closed", "open"])
+def test_rate_counts_every_stamp_in_a_closed_loop_and_only_requests_due_in_the_window_in_an_open_one(kind):
+    eng = FakeEngine()
+    st = stamps.TokenStamper(lambda rid: eng.generated.get(rid, 0))
+    eng.submit(0, 6)
+    st.offer(0, due=0.5, prompt_len=8, max_new=6)    # due in the pre-roll, still decoding when the window opens at 2.0
+    st.offer(None, due=2.5, prompt_len=8, max_new=4, refused=True)
+    for i in range(1, 9):                            # steps end at 0.75, 1.5, ... 6.0
+        if i == 4:
+            eng.submit(1, 3)
+            st.offer(1, due=2.5, prompt_len=8, max_new=3)  # due inside the window
+        eng.step()
+        st.after_step(0.75 * i)
+    w = stamps.window_metrics(st.requests, 2.0, 6.0, open_loop=(kind == "open"))
+    carried, offered = 4, 3                           # request 0's stamps at 2.25 .. 4.5; request 1's at 3.0 .. 4.5
+    assert w["tokens_emitted"] == carried + offered
+    assert w["tokens"] == (offered if kind == "open" else carried + offered)
+    # nothing else knows the loop's kind: gaps are every request's, attempted the requests due in the window
+    assert (w["attempted"], w["failed"]) == (2, 1) and w["ttft_ms"] == [500.0]
+    assert w["gaps_ms"] == [750.0] * 6 and w["oldest_waiting_s"] == 0.5
+
+
+class QueueModel:
+    """A single-server model of the paged engine under ``chat-open``:
+    ``slots`` rows, every step takes ``step_s`` whatever it holds, one
+    64-token prefill chunk a step (the oldest admitted prompt's), a
+    request's first token out of its last chunk, then one token a step."""
+
+    def __init__(self, slots, chunk):
+        self.slots, self.chunk = slots, chunk
+        self.waiting, self.rows, self.generated = [], {}, {}
+
+    def submit(self, rid, prompt_len, max_new):
+        self.waiting.append(rid)
+        self.generated[rid] = 0
+        self.rows[rid] = {"chunks": -(-prompt_len // self.chunk), "max_new": max_new, "live": False}
+
+    def step(self):
+        live = [rid for rid, r in self.rows.items() if r["live"]]
+        while self.waiting and len(live) < self.slots:
+            rid = self.waiting.pop(0)
+            self.rows[rid]["live"] = True
+            live.append(rid)
+        prefilled = False
+        for rid in live:
+            r = self.rows[rid]
+            if r["chunks"] > 0:
+                if not prefilled:
+                    prefilled = True
+                    r["chunks"] -= 1
+                    if r["chunks"] == 0:
+                        self.generated[rid] = 1
+            else:
+                self.generated[rid] += 1
+            if self.generated[rid] >= r["max_new"]:
+                r["live"] = False
+                del self.rows[rid]
+
+
+def _chat_open_window(step_s):
+    """The real ``chat-open`` schedule (lengths, order, gaps, pre-roll) through
+    the model at a fixed step; returns the window's metrics and the offered rate."""
+    from benchmark import traffic
+    from benchmark.manifest import Manifest
+
+    mix = Manifest().traffic("chat-open")
+    assert mix["kind"] == "open"
+    stream, gaps = traffic.request_stream(mix, 0, 50257), traffic.arrival_gaps(mix)
+    eng = QueueModel(slots=16, chunk=64)
+    st = stamps.TokenStamper(lambda rid: eng.generated[rid])
+    t_open = float(mix["preroll_s"])
+    t_close = t_open + 51.0
+    now, next_due, rid = 0.0, next(gaps), 0
+    while now < t_close:
+        while next_due <= now:
+            req = next(stream)
+            eng.submit(rid, len(req["prompt"]), req["max_new"])
+            st.offer(rid, next_due, len(req["prompt"]), req["max_new"])
+            rid += 1
+            next_due += next(gaps)
+        eng.step()
+        now += step_s
+        st.after_step(now)
+    answers = [a for _, a in traffic.length_pool(mix)]
+    return stamps.window_metrics(st.requests, t_open, t_close, open_loop=True), mix["rate_rps"] * sum(answers) / len(answers)
+
+
+def test_a_faster_engine_never_reads_lower_in_the_open_loop():
+    """PR 26: the GPT-2 serve step went 236 → 75 ms and the chat cell's
+    rate, counting every stamp, read *lower* (59.461 → 57.725 tokens/s on
+    the chip): both engines emit what is offered plus the backlog the
+    pre-roll left them, and the slower carries more in."""
+    slow, offered = _chat_open_window(0.236)
+    fast, _ = _chat_open_window(0.075)
+    # the three cycles of the window, less the request due a rounding error before it opens
+    assert slow["attempted"] == fast["attempted"] >= 47 and slow["failed"] == fast["failed"] == 0
+    assert offered == pytest.approx(56.47, abs=0.01)
+    rate = lambda w, key: w[key] / w["window_s"]  # noqa: E731
+    assert rate(fast, "tokens_emitted") < rate(slow, "tokens_emitted"), "the count this PR replaces: the faster engine reads lower"
+    assert rate(slow, "tokens_emitted") > offered, "... and above what the window offers"
+    assert rate(slow, "tokens") < rate(fast, "tokens") <= offered, "the offered traffic's tokens served in time"
+    assert max(fast["gaps_ms"]) < min(slow["gaps_ms"]), "the gaps are every request's, as before"
+
+
+def test_spread_is_between_the_quartiles_over_the_median_and_may_leave_out_one_far_off_run():
+    import statistics
+
+    from benchmark.stats import spread
+
+    assert spread([4200.0] * 6) == 0.0 and spread([4200.0] * 6, leave_out_farthest=True) == 0.0
+    steady = [100.0, 100.2, 100.4, 100.6, 100.8]
+    assert spread(steady) == pytest.approx(0.6 / 100.4)       # quartiles at 100.1 and 100.7 (statistics.quantiles)
+    assert statistics.quantiles(steady, n=4) == pytest.approx([100.1, 100.4, 100.7])
+    one_off = steady + [93.0]                                  # a slow run, -7 %
+    assert spread(one_off) > 0.02
+    assert spread(one_off, leave_out_farthest=True) == pytest.approx(spread(steady))
+    two_off = steady[:4] + [93.0, 94.0]                        # two of six: no median of six hides them
+    assert spread(two_off, leave_out_farthest=True) > 0.02
+    # the far-off run is left out only where that narrows the spread
+    assert spread([1.0, 2.0, 3.0, 4.0, 5.0, 6.0], leave_out_farthest=True) <= spread([1.0, 2.0, 3.0, 4.0, 5.0, 6.0])
+    assert spread([99.0, 101.0], leave_out_farthest=True) == spread([99.0, 101.0])  # too few runs to leave one out
+
+
+def test_sets_summary_reads_the_output_of_sets_sh():
+    from benchmark.stats import summarize_sets
+
+    line = lambda v: '{"correct": true, "metrics": {"serve_tokens_per_s": {"value": %r, "unit": "tokens/s"}}}' % v  # noqa: E731
+    text = []
+    for s, values in (("A", [10.0, 10.1, 10.2, 10.3, 9.0, 10.4]), ("B", [10.0] * 6)):
+        for i, v in enumerate(values):
+            text += [f"RUN set={s} seed={7 + i}", line(v), "RC=0"]
+    out = summarize_sets(text)
+    assert out["B"]["serve_tokens_per_s"]["spread"] == 0.0 and out["A"]["serve_tokens_per_s"]["n"] == 6
+    assert out["A"]["serve_tokens_per_s"]["spread_less_farthest"] < out["A"]["serve_tokens_per_s"]["spread"]
