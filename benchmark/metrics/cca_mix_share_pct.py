@@ -1,0 +1,10 @@
+"""The share of the traced decode steps' device time spent **mixing**: self time of the device
+operations under the named scope ``cca.mix`` (projections, the tail's read and write, both
+convolutions, the q-k mean, norms, rotary) inside ``jit_serve_decode`` executions over their summed
+device time.  None where the trace holds no such scope or program."""
+from benchmark import scopes
+
+
+def read(record):
+    raw = scopes.of_run(record)
+    return scopes.scope_share_pct(raw, "cca.mix", "jit_serve_decode") if raw else None

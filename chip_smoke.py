@@ -541,14 +541,14 @@ def serve_deepseek_v2(s: Smoke, device) -> Dict[str, float]:
           f"serve[dsv2]: an emitted token lies {gaps['token_gap_max']:.4f} under the reference's best logit "
           f"(tolerance {TOL_DSV2_TOKEN_GAP})")
     expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
-                   ["mla_decode_paged"] if s.mosaic else [], "serve[dsv2] decode")
+                   ["mla_decode_paged", "moe_grouped_matmul"] if s.mosaic else [], "serve[dsv2] decode")  # a step's few rows: one padded window
     expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()),
                    ["mla_prefill", "moe_grouped_matmul"] if s.mosaic else [], "serve[dsv2] prefill")
     stats = srv.stats()
     check(stats["mla_prefill_kernel"] is bool(s.mosaic),
           f"serve[dsv2]: stats() say of the prefill program: mla_prefill_kernel {stats['mla_prefill_kernel']}, "
           f"fallback {stats['mla_prefill_fallback']!r}")
-    check(stats["moe_grouped_kernel"] == ("512" if s.mosaic else ""),
+    check((stats["moe_grouped_fallback"] == "" and stats["moe_grouped_kernel"].endswith("512")) if s.mosaic else stats["moe_grouped_kernel"] == "",
           f"serve[dsv2]: stats() say of the held experts: moe_grouped_kernel {stats['moe_grouped_kernel']!r}, "
           f"fallback {stats['moe_grouped_fallback']!r}")
     say(f"serve[dsv2]: 2 requests x 8 tokens through the latent pool, token gap mean {gaps['token_gap_mean']:.5f} "
@@ -609,7 +609,7 @@ def serve_solar_open2(s: Smoke, device) -> Dict[str, float]:
           f"serve[solar2]: an emitted token lies {gaps['token_gap_max']:.4f} under the reference's best logit "
           f"(tolerance {TOL_SOLAR2_TOKEN_GAP})")
     expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
-                   ["kda_decode", "flash_decode_paged"] if s.mosaic else [], "serve[solar2] decode")
+                   ["kda_decode", "flash_decode_paged", "moe_grouped_matmul"] if s.mosaic else [], "serve[solar2] decode")
     expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()),
                    ["moe_grouped_matmul"] if s.mosaic else [], "serve[solar2] prefill")
     check(stats["kda_decode_kernel"] is bool(s.mosaic) and stats["gqa_decode_kernel"] is bool(s.mosaic),

@@ -223,8 +223,10 @@ def cache_kind(cfg: SolarOpen2Config, dtype):
     GQA layers, a per-slot state of the KDA layers."""
     from deepspeed_tpu.serving.kvcache.pages import HybridKV
 
-    return HybridKV(len(cfg.gqa_layers), cfg.num_key_value_heads, cfg.head_dim, dtype, len(cfg.kda_layers),
-                    cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_head_dim, cfg.kda_conv_size - 1, 3 * cfg.kda_width)
+    n = len(cfg.kda_layers)
+    return HybridKV(len(cfg.gqa_layers), cfg.num_key_value_heads, cfg.head_dim, dtype, {
+        "s": (n, (cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_head_dim), jnp.float32),
+        "conv": (n, (cfg.kda_conv_size - 1, 3 * cfg.kda_width), dtype)})
 
 
 # ---------------------------------------------------------------------------
@@ -266,21 +268,6 @@ def gqa_block(cfg: SolarOpen2Config, lp: Dict[str, Any], x, k_pool, v_pool, page
     return x + (jax.nn.sigmoid(h @ lp["gate"]) * attn) @ lp["o"], k_pool, v_pool
 
 
-def _rows_of(buf, layer: int, slot):
-    """Rows ``slot (B,)`` of one layer of a ``(layers, slots, ...)`` state buffer."""
-    tail = buf.shape[2:]
-    return jnp.concatenate([jax.lax.dynamic_slice(buf, (layer, slot[b]) + (0,) * len(tail), (1, 1) + tail)[0]
-                            for b in range(slot.shape[0])], axis=0)
-
-
-def _put_rows(buf, layer: int, slot, rows):
-    """The inverse, as ``dynamic_update_slice``s: a donated buffer is updated in place."""
-    for b in range(slot.shape[0]):
-        buf = jax.lax.dynamic_update_slice(buf, rows[b][None, None].astype(buf.dtype),
-                                           (jnp.int32(layer), slot[b]) + (jnp.int32(0),) * (buf.ndim - 2))
-    return buf
-
-
 def kda_block(cfg: SolarOpen2Config, lp: Dict[str, Any], x, state: Dict[str, Any], state_layer: int, pos, slot=None,
               write_mask=None, row_valid=None, use_kernel: Optional[bool] = None, trace_notes: Optional[dict] = None):
     """``x + KDA(RMS(x))`` for ``x (B, T, D)`` on layer ``state_layer``
@@ -293,6 +280,7 @@ def kda_block(cfg: SolarOpen2Config, lp: Dict[str, Any], x, state: Dict[str, Any
     inputs.  ``slot`` None: a **decode step**, row ``b`` is slot ``b``
     and rows with ``write_mask`` False keep their state."""
     from deepspeed_tpu.ops.transformer import linear_attention as la
+    from deepspeed_tpu.ops.transformer.inference import state_rows, state_rows_write
 
     B, T, _ = x.shape
     Hl, dl, W = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_width
@@ -305,7 +293,7 @@ def kda_block(cfg: SolarOpen2Config, lp: Dict[str, Any], x, state: Dict[str, Any
         n_valid = None
     else:
         fresh = (pos == 0)
-        conv0 = jnp.where(fresh[:, None, None], 0, _rows_of(state["conv"], state_layer, slot))
+        conv0 = jnp.where(fresh[:, None, None], 0, state_rows(state["conv"], state_layer, slot))
         n_valid = None if row_valid is None else jnp.sum(row_valid.astype(jnp.int32), axis=1)
     y, conv1 = la.short_conv(qkv, lp["conv"], conv0, n_valid)
     heads = lambda t: t.reshape(B, T, Hl, dl)  # noqa: E731
@@ -326,12 +314,12 @@ def kda_block(cfg: SolarOpen2Config, lp: Dict[str, Any], x, state: Dict[str, Any
         o = o[:, None]
         conv = state["conv"].at[state_layer].set(jnp.where(mask[:, None, None], conv1, conv0))
     else:
-        s0 = jnp.where(fresh[:, None, None, None], 0.0, _rows_of(state["s"], state_layer, slot))
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, state_rows(state["s"], state_layer, slot))
         o, s1 = la.chunked(s0, q, k, v, g, beta)
         if trace_notes is not None:
             trace_notes["kda_prefill_form"] = f"chunked jnp (chunks of {min(la.CHUNK, T)})"
-        s = _put_rows(state["s"], state_layer, slot, s1)
-        conv = _put_rows(state["conv"], state_layer, slot, conv1)
+        s = state_rows_write(state["s"], state_layer, slot, s1)
+        conv = state_rows_write(state["conv"], state_layer, slot, conv1)
     gate = jax.nn.sigmoid(((h @ lp["g_down"]) @ lp["g_up"]).astype(f32))
     o = rms_norm(o, lp["o_norm"], cfg.rms_norm_eps) * heads(gate)
     return x + o.reshape(B, T, W).astype(x.dtype) @ lp["o"], {"s": s, "conv": conv}

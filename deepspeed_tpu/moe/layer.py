@@ -266,6 +266,35 @@ def sigmoid_topk(logits: jnp.ndarray, bias: Optional[jnp.ndarray], top_k: int, s
     return idx.astype(jnp.int32), (w * scale).astype(jnp.float32)
 
 
+def mlp_top1(h: jnp.ndarray, r_prev: jnp.ndarray, lp: Dict[str, Any], eps: float) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Top-1 routing by a small MLP on a **down-projection that is
+    carried across layers** (the ZAYA router): ``h (N, D)`` the expert
+    layer's normed input, ``r_prev (N, R)`` float32 the previous layer's
+    ``r`` (zeros at the first)::
+
+        r = h W_d + gamma * r_prev                        W_d: D -> R, gamma one learned scalar a layer
+        s = softmax(gelu(gelu(RMS(r) W_1) W_2) W_3)       over **all** experts, float32
+        e = argmax(s + bias),  weight = s_e               the bias selects and never weighs
+
+    ``lp`` holds ``router_down (D, R)``, ``router_gamma ()``,
+    ``router_norm (R,)``, ``router_w1``, ``router_w2 (R, R)``,
+    ``router_w3 (R, E)`` and ``router_bias (E,)``.  Everything after
+    ``h`` is float32 at ``highest`` precision: next to the experts these
+    products are small, and a top-1 choice has no second expert to soften
+    a flipped one.  Returns ``(idx (N, 1) int32, weight (N, 1) float32,
+    r (N, R) float32)``; ``r`` goes to the next layer."""
+    f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+    w = lambda name: lp[name].astype(f32)  # noqa: E731
+    dot = lambda a, b: jnp.dot(a, b, precision=hi)  # noqa: E731
+    r = dot(h.astype(f32), w("router_down")) + w("router_gamma") * r_prev.astype(f32)
+    x = r * jax.lax.rsqrt(jnp.mean(jnp.square(r), -1, keepdims=True) + eps) * w("router_norm")
+    x = jax.nn.gelu(dot(x, w("router_w1")), approximate=False)
+    x = jax.nn.gelu(dot(x, w("router_w2")), approximate=False)
+    s = jax.nn.softmax(dot(x, w("router_w3")), axis=-1)
+    idx = jnp.argmax(s + w("router_bias"), axis=-1)[:, None]
+    return idx.astype(jnp.int32), jnp.take_along_axis(s, idx, axis=-1), r
+
+
 def grouped_form(rows: int, D: int, F: int, dtype) -> Tuple[bool, str]:
     """Which form the two grouped matmuls of a held-expert call of
     ``rows`` assignment rows take: ``(kernel, why_not)`` — the Mosaic
@@ -354,16 +383,21 @@ def dropless_held_experts(x: jnp.ndarray, idx: jnp.ndarray, weight: jnp.ndarray,
     order = jnp.argsort(key, stable=True)
     sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
     xs = jnp.take(x, order // K, axis=0)
-    kernel, why_not = grouped_form(N * K, x.shape[1], w_down.shape[1], x.dtype)
+    from deepspeed_tpu.ops.kernels.grouped_matmul import WINDOW, grouped_matmul
+
+    # fewer assignment rows than one MXU window (top-1 over a small batch): the kernel is asked for a whole
+    # window, the rows past the real ones belonging to no group, as those of absent experts do
+    rows = max(N * K, WINDOW)
+    kernel, why_not = grouped_form(rows, x.shape[1], w_down.shape[1], x.dtype)
     if trace_notes is not None:
         note_grouped_form(trace_notes, N * K, why_not)
     if kernel:
-        from deepspeed_tpu.ops.kernels.grouped_matmul import grouped_matmul as grouped
+        grouped, xs = grouped_matmul, jnp.pad(xs, ((0, rows - N * K), (0, 0)))
     else:
         grouped = jax.lax.ragged_dot
     gu = grouped(xs, w_gu.astype(x.dtype), sizes)
     g, u = jnp.split(gu, 2, axis=-1)
-    ys = grouped(jax.nn.silu(g) * u, w_down.astype(x.dtype), sizes)
+    ys = grouped(jax.nn.silu(g) * u, w_down.astype(x.dtype), sizes)[: N * K]
     # rows past the held groups belong to absent experts: nothing was computed for them
     computed = jnp.arange(N * K) < jnp.sum(sizes)
     ws = jnp.take(jnp.where(is_held, weight, 0.0).reshape(N * K), order)

@@ -231,6 +231,22 @@ def paged_cache_write_slices(pool, layer: int, t, page_table, pos, write_mask=No
     return pool
 
 
+def state_rows(buf, layer: int, slot):
+    """Rows ``slot (B,)`` of one layer of a ``(layers, slots, ...)``
+    per-slot state buffer (``HybridKV.state_buffers``)."""
+    tail = buf.shape[2:]
+    return jnp.concatenate([jax.lax.dynamic_slice(buf, (layer, slot[b]) + (0,) * len(tail), (1, 1) + tail)[0]
+                            for b in range(slot.shape[0])], axis=0)
+
+
+def state_rows_write(buf, layer: int, slot, rows):
+    """The inverse, as ``dynamic_update_slice``s: a donated buffer is updated in place."""
+    for b in range(slot.shape[0]):
+        buf = jax.lax.dynamic_update_slice(buf, rows[b][None, None].astype(buf.dtype),
+                                           (jnp.int32(layer), slot[b]) + (jnp.int32(0),) * (buf.ndim - 2))
+    return buf
+
+
 def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
                           sm_scale: Optional[float] = None,
                           use_kernel: Optional[bool] = None):

@@ -1521,7 +1521,8 @@ class ServingEngine:
             }
         # what the family's programs said of themselves while tracing
         # (DeepSeek-V2: mla_prefill_kernel / mla_prefill_fallback, moe_grouped_kernel / moe_grouped_fallback;
-        # Solar-Open2: kda_decode_kernel / _fallback, kda_prefill_form, gqa_decode_kernel / _fallback, gqa_prefill_form)
+        # Solar-Open2: kda_decode_kernel / _fallback, kda_prefill_form, gqa_decode_kernel / _fallback, gqa_prefill_form;
+        # ZAYA: cca_decode_kernel / _fallback, cca_prefill_form, moe_router_form)
         out.update(getattr(self._family_forward, "trace_notes", {}))
         out.update(self.timeline.summary())
         if out.get("programs"):

@@ -111,37 +111,40 @@ class LatentKV:
 
 
 class HybridKV:
-    """Cache kind of a model that mixes softmax-attention layers with
-    linear-attention layers (docs/serving.md §Cache kinds).  Two
+    """Cache kind of a model whose layers leave more behind than keys
+    and values per position (docs/serving.md §Cache kinds).  Two
     geometries in one pool:
 
     * **pages** — K and V of the ``paged_layers`` softmax-attention
-      layers only, ``(paged_layers, pages, kv_heads, page_len,
-      head_dim)``: the :class:`PerHeadKV` layout, not ``n_layer`` deep;
+      layers, ``(paged_layers, pages, kv_heads, page_len, head_dim)``:
+      the :class:`PerHeadKV` layout, as deep as the family says (some of
+      the layers, or all of them);
     * **state** — a third group with a **slot** axis where the others
-      have pages (``pool.state``): per linear-attention layer and slot
-      the recurrent state ``s (state_layers, slots, heads, dk, dv)``
-      float32 and the convolution's last inputs ``conv (state_layers,
-      slots, conv_taps, conv_width)``.  Fixed size whatever the
-      sequence's length; the slot that owns a request owns its state.
+      have pages (``pool.state``), its leaves **declared by the family**:
+      ``state = {name: (layers, shape a slot, dtype)}`` makes a buffer
+      ``(layers, slots) + shape`` a name.  A linear-attention layer keeps
+      its recurrent state and its convolution's last inputs there
+      (``s`` float32, ``conv``); a layer that mixes along the sequence
+      in front of its softmax attention keeps the mixing's tail (``conv``,
+      ``vshift``) — **such a layer has pages and slot state both**.
+      Fixed size whatever the sequence's length; the slot that owns a
+      request owns its state.
 
     A page here does **not** hold everything its positions left behind
-    — the linear layers' memory of them is in the slot's state — so a
-    page cannot stand for a prefix: ``pages_hold_all`` is False and the
-    pool turns prefix hits, prefix learning, session rebinds, spill and
-    tiers off, explicitly (``stats()["reuse"]``).  Copy-on-write, which
-    only ever follows a shared page, never happens; the state is never
-    copied.  A fresh request needs no reset of its slot's state: the
-    model's prefill starts from zero where the chunk starts at position 0."""
+    — part of their trace is in the slot's state — so a page cannot
+    stand for a prefix: ``pages_hold_all`` is False and the pool turns
+    prefix hits, prefix learning, session rebinds, spill and tiers off,
+    explicitly (``stats()["reuse"]``).  Copy-on-write, which only ever
+    follows a shared page, never happens; the state is never copied.  A
+    fresh request needs no reset of its slot's state: the model's
+    prefill starts from zero where the chunk starts at position 0."""
 
     pages_hold_all = False
 
-    def __init__(self, paged_layers: int, kv_heads: int, head_dim: int, dtype: Any, state_layers: int,
-                 state_heads: int, state_dk: int, state_dv: int, conv_taps: int, conv_width: int):
+    def __init__(self, paged_layers: int, kv_heads: int, head_dim: int, dtype: Any,
+                 state: Dict[str, Tuple[int, Tuple[int, ...], Any]]):
         self.paged_layers, self.heads, self.head_dim, self.dtype = int(paged_layers), int(kv_heads), int(head_dim), dtype
-        self.state_layers, self.state_heads = int(state_layers), int(state_heads)
-        self.state_dk, self.state_dv = int(state_dk), int(state_dv)
-        self.conv_taps, self.conv_width = int(conv_taps), int(conv_width)
+        self.state = {name: (int(layers), tuple(int(n) for n in shape), dt) for name, (layers, shape, dt) in state.items()}
 
     def buffers(self, n_layer: int, num_pages: int, page_len: int):
         from deepspeed_tpu.ops.transformer.inference import init_kv_cache
@@ -149,16 +152,13 @@ class HybridKV:
         return init_kv_cache(self.paged_layers, num_pages, self.heads, page_len, self.head_dim, self.dtype)
 
     def state_buffers(self, num_slots: int) -> Dict[str, Any]:
-        return {
-            "s": jnp.zeros((self.state_layers, num_slots, self.state_heads, self.state_dk, self.state_dv), jnp.float32),
-            "conv": jnp.zeros((self.state_layers, num_slots, self.conv_taps, self.conv_width), self.dtype),
-        }
+        return {name: jnp.zeros((layers, num_slots) + shape, dt) for name, (layers, shape, dt) in self.state.items()}
 
     def describe(self, n_layer: int, num_pages: int, page_len: int) -> str:
+        leaves = " + ".join(f"{name}: {layers} layers x {' x '.join(str(n) for n in shape)} {np.dtype(dt).name}"
+                            for name, (layers, shape, dt) in self.state.items())
         return (f"pages 2 x ({self.paged_layers} of {n_layer} layers x {num_pages} pages x {self.heads} heads x "
-                f"{page_len} page_len x {self.head_dim} head_dim) + state per slot ({self.state_layers} layers x "
-                f"[{self.state_heads} heads x {self.state_dk} x {self.state_dv} float32 + "
-                f"{self.conv_taps} x {self.conv_width} conv])")
+                f"{page_len} page_len x {self.head_dim} head_dim) + state per slot ({leaves})")
 
 
 REUSE_OFF = ("off: this cache kind keeps part of a position's trace in per-slot state, so a page cannot stand for a "
@@ -1036,6 +1036,8 @@ class PagedKVPool:
         if self.state is not None:
             out["kind"] = self.kind.describe(self.n_layer, self.num_pages, self.page_len)
             out["state_bytes"] = self.state_bytes()
+            # the leaves the family declared, by name: bytes over all slots
+            out["state_leaves"] = {name: int(buf.size * buf.dtype.itemsize) for name, buf in self.state.items()}
         if not self.reuse:
             out["reuse"] = REUSE_OFF
             out["sessions_unbound"] = self.sessions_unbound

@@ -311,8 +311,16 @@ def _deepseek_v2_rules(layout: SpecLayout) -> Tuple[Rule, ...]:
     )
 
 
+def _zaya_rules(layout: SpecLayout) -> Tuple[Rule, ...]:
+    """ZAYA (models/zaya.py): the DeepSeek-V2 deployment without an
+    untied head — held experts over ``expert``, the tied embedding
+    vocabulary-parallel, attention, router and norms replicated."""
+    return tuple(r for r in _deepseek_v2_rules(layout) if "head" not in r[0])
+
+
 register_family("gpt2", _gpt2_rules)
 register_family("deepseek_v2", _deepseek_v2_rules)
+register_family("zaya", _zaya_rules)
 register_family("bert", _bert_rules)
 register_family("neo", _neo_rules)
 register_family("moe", _moe_family_rules)
@@ -343,6 +351,8 @@ def rules_for_config(model_config: Any, layout: SpecLayout = DEFAULT_LAYOUT) -> 
             # one deployment, one table: held experts over ``expert``, embedding and head over the
             # vocabulary, every kind of attention, shared experts, router and norms replicated
             return rules_for_family("deepseek_v2", layout)
+        if klass.__name__ == "ZayaConfig":
+            return rules_for_family("zaya", layout)
     raise ValueError(
         f"no built-in partition rules for model config {type(model_config).__name__}"
     )

@@ -232,6 +232,56 @@ def test_solar_open2_steps_compile_for_v5e_with_both_caches_updated_in_place(whi
     assert m.temp_size_in_bytes < int(np.prod(k.shape)) * 2   # and no temporary is as large as one pool
 
 
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_zaya_steps_compile_for_v5e_with_pages_and_tail_updated_in_place(which, v5e_chip, monkeypatch):
+    """Two layers of ZAYA1-8B at the published widths on the cell's
+    hybrid cache (64 slots, 2,433 pages, every layer with pages *and* a
+    slot tail), by the chip's compiler without the chip: a decode step
+    holds the grouped ``flash_decode_paged`` once a layer (2 KV heads x
+    group 4 x 128, reading the stacked pool through a merged leading dim
+    and an offset page table) and the experts' kernel at 2048 / 2048 for
+    its 64 top-1 rows (padded to one MXU window); a chunk of 1,024 holds
+    the experts' kernel; neither leaves a copy of the pools or the tail."""
+    from deepspeed_tpu.models import zaya
+    from deepspeed_tpu.ops.kernels import flash_decode, grouped_matmul
+
+    monkeypatch.setenv("DS_KERNELS", "1")
+    for mod in (flash_decode, grouped_matmul):
+        monkeypatch.setattr(mod, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
+    layers, slots, pages, chunk = 2, 64, 2433, 1024
+    cfg = zaya.ZayaConfig(num_hidden_layers=layers, experts_held=(0, 8), vocab_held=131136)
+    on_chip = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    params = jax.tree.map(lambda sh: on_chip(sh, jnp.bfloat16), zaya.param_shapes(cfg), is_leaf=lambda sh: isinstance(sh, tuple))
+    kind = zaya.cache_kind(cfg, jnp.bfloat16)
+    k, v = (on_chip(a.shape, a.dtype) for a in jax.eval_shape(lambda: kind.buffers(layers, pages, 128)))
+    state = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(lambda: kind.state_buffers(slots)))
+    notes = {}
+    if which == "decode":
+        def step(p, t, pos, table, wm, k, v, st):
+            return zaya.forward_with_cache(p, t[:, None], k, v, st, pos, cfg, table, write_mask=wm, row_valid=wm[:, None],
+                                           trace_notes=notes)
+        args = (params, on_chip((slots,), jnp.int32), on_chip((slots,), jnp.int32), on_chip((slots, 64), jnp.int32),
+                on_chip((slots,), jnp.bool_), k, v, state)
+    else:
+        def step(p, t, table, slot, pos, rv, k, v, st):
+            return zaya.forward_with_cache(p, t, k, v, st, pos[None], cfg, table[None], slot=slot[None], row_valid=rv,
+                                           trace_notes=notes)
+        args = (params, on_chip((1, chunk), jnp.int32), on_chip((64,), jnp.int32), on_chip((), jnp.int32), on_chip((), jnp.int32),
+                on_chip((1, chunk), jnp.bool_), k, v, state)
+    compiled = jax.jit(step, donate_argnums=(len(args) - 3, len(args) - 2, len(args) - 1)).lower(*args).compile()
+    found = chip_smoke.mosaic_kernels(compiled.as_text())
+    if which == "decode":
+        assert found == {"flash_decode_paged": layers, "moe_grouped_matmul": 2 * layers}
+        assert notes["cca_decode_kernel"] is True and notes["moe_grouped_kernel"] == "64"
+    else:
+        assert found == {"moe_grouped_matmul": 2 * layers} and notes["moe_grouped_kernel"] == "1024"
+    assert notes["moe_grouped_fallback"] == ""
+    m = compiled.memory_analysis()
+    cache_bytes = 2 * int(np.prod(k.shape)) * 2 + sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert m.alias_size_in_bytes >= cache_bytes                    # pages and tail come back in place
+    assert m.temp_size_in_bytes < int(np.prod(k.shape)) * 2 // 2   # and no temporary is half of one pool
+
+
 # ---------------------------------------------------------------------------
 # (b) the smoke's control flow, and its refusal to run off a TPU
 # ---------------------------------------------------------------------------

@@ -137,13 +137,13 @@ def test_kernel_form_is_the_ragged_dot_form_counts_alike_and_nothing_dropped(hot
 
 
 def test_a_row_count_no_tile_divides_takes_ragged_dot_bit_for_bit(armed):
-    """25 tokens x top-4 = 100 assignment rows: the armed suite still
+    """33 tokens x top-4 = 132 assignment rows: the armed suite still
     answers with ``ragged_dot``, and says why."""
     held = (0, 8)
-    x, idx, weight, w_gu, w_down, valid = _routed_layer(7, tokens=25, D=128, F=128, experts=16, held=held, top_k=4)
-    assert not grouped_matmul_supported(100, 128, 256, x.dtype)
+    x, idx, weight, w_gu, w_down, valid = _routed_layer(7, tokens=33, D=128, F=128, experts=16, held=held, top_k=4)
+    assert not grouped_matmul_supported(132, 128, 256, x.dtype)
     got, counts = moe.dropless_held_experts(x, idx, weight, w_gu, w_down, held, valid, trace_notes=(notes := {}))
-    assert notes["moe_grouped_kernel"] == "" and notes["moe_grouped_fallback"].startswith("100: unsupported shape")
+    assert notes["moe_grouped_kernel"] == "" and notes["moe_grouped_fallback"].startswith("132: unsupported shape")
     # the parent's lines, to the letter
     local = idx - held[0]
     key = jnp.where((local >= 0) & (local < held[1]), local, held[1]).reshape(-1)
@@ -153,9 +153,27 @@ def test_a_row_count_no_tile_divides_takes_ragged_dot_bit_for_bit(armed):
     g, u = jnp.split(gu, 2, axis=-1)
     ys = jax.lax.ragged_dot(jax.nn.silu(g) * u, w_down, sizes)
     ws = jnp.take(jnp.where((local >= 0) & (local < held[1]), weight, 0.0).reshape(-1), order)
-    ys = jnp.where((jnp.arange(100) < jnp.sum(sizes))[:, None], ys * ws[:, None], 0.0)
-    want = jnp.take(ys, jnp.argsort(order), axis=0).reshape(25, 4, -1).sum(axis=1)
+    ys = jnp.where((jnp.arange(132) < jnp.sum(sizes))[:, None], ys * ws[:, None], 0.0)
+    want = jnp.take(ys, jnp.argsort(order), axis=0).reshape(33, 4, -1).sum(axis=1)
     assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("tokens,top_k", [(64, 1), (25, 4), (3, 1)])
+def test_fewer_rows_than_one_window_are_handed_to_the_kernel_as_a_whole_window(armed, monkeypatch, tokens, top_k):
+    """A top-1 decode step of 64 rows (and 100 rows, and 3): under the
+    kernel's 128-row window the call is padded to one window with rows
+    that belong to no group, takes the kernel, and gives what
+    ``ragged_dot`` gives on the rows alone; the counters see the real rows."""
+    held = (0, 8)
+    x, idx, weight, w_gu, w_down, valid = _routed_layer(11, tokens=tokens, D=128, F=128, experts=16, held=held, top_k=top_k)
+    got, counts = moe.dropless_held_experts(x, idx, weight, w_gu, w_down, held, valid, trace_notes=(notes := {}))
+    assert notes == {"moe_grouped_kernel": str(tokens * top_k), "moe_grouped_fallback": ""}
+    monkeypatch.setenv("DS_KERNELS", "0")  # the reference: the same call on ragged_dot
+    want, want_counts = moe.dropless_held_experts(x, idx, weight, w_gu, w_down, held, valid, trace_notes=(off := {}))
+    assert off["moe_grouped_kernel"] == ""
+    assert got.shape == (tokens, 128) and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
+    assert np.array_equal(np.asarray(counts), np.asarray(want_counts)) and int(counts[-1]) == int(counts[:-1].sum())
 
 
 def test_notes_keep_one_answer_a_row_count():
@@ -191,9 +209,9 @@ def test_stats_say_on_the_cpu_that_both_programs_took_ragged_dot():
 
 def test_stats_say_which_program_took_the_kernel_when_the_suite_is_armed(armed):
     """Widths of whole lane tiles: the chunk's 32 x top-4 = 128 rows
-    take the kernel, the decode step's 8 rows cannot."""
+    take the kernel, and so do the decode step's 8 (handed over as one
+    window of 128, the rest nobody's)."""
     cfg = dataclasses.replace(ds.DEEPSEEK_V2_TINY, hidden_size=128, moe_intermediate_size=128)
     stats = _serve(cfg, prefill_chunk=32)
-    assert stats["moe_grouped_kernel"] == "128"
-    assert stats["moe_grouped_fallback"].startswith("8: unsupported shape (8 rows, widths 128 / 128, float32)")
+    assert stats["moe_grouped_kernel"] == "8,128" and stats["moe_grouped_fallback"] == ""
     assert stats["moe"]["dropped_assignments"] == 0 and stats["moe"]["assignments_computed"] > 0

@@ -712,8 +712,8 @@ def test_fleet_session_stickiness_three_turns(eng, tmp_path):
 def _hybrid_pool(**kw):
     from deepspeed_tpu.serving.kvcache.pages import HybridKV
 
-    kind = HybridKV(paged_layers=2, kv_heads=2, head_dim=8, dtype=jnp.float32, state_layers=6, state_heads=4,
-                    state_dk=8, state_dv=8, conv_taps=3, conv_width=96)
+    kind = HybridKV(paged_layers=2, kv_heads=2, head_dim=8, dtype=jnp.float32,
+                    state={"s": (6, (4, 8, 8), jnp.float32), "conv": (6, (3, 96), jnp.float32)})
     return PagedKVPool(8, 3, 0, 64, 0, jnp.float32, page_len=16, num_pages=13, prefill_chunk=16, kind=kind, **kw)
 
 
@@ -724,9 +724,10 @@ def test_hybrid_kind_has_pages_for_its_paged_layers_only_and_a_slot_axis_group()
     assert pool.state["conv"].shape == (6, 3, 3, 96)
     state_bytes = 6 * 3 * (4 * 8 * 8 + 3 * 96) * 4
     assert pool.state_bytes() == state_bytes and pool.cache_bytes() == 2 * 2 * 13 * 2 * 16 * 8 * 4 + state_bytes
-    assert "2 of 8 layers" in pool.shape_math() and "state per slot (6 layers" in pool.shape_math()
+    assert "2 of 8 layers" in pool.shape_math() and "state per slot (s: 6 layers x 4 x 8 x 8 float32 + conv: 6 layers x 3 x 96 float32)" in pool.shape_math()
     st = pool.stats()
     assert st["kind"] == pool.kind.describe(8, 13, 16) and st["state_bytes"] == state_bytes
+    assert st["state_leaves"] == {"s": 6 * 3 * 4 * 8 * 8 * 4, "conv": 6 * 3 * 3 * 96 * 4}  # the leaves the family declared
     # swap keeps the state unless handed a new one
     new = {k: v + 1 for k, v in pool.state.items()}
     pool.swap(pool.k, pool.v)
