@@ -449,9 +449,7 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
             "num_slots": s.slots, "max_len": s.max_len, "kv_cache_dtype": kv,
             "prefill_chunk": s.prefill_chunk,
             # pages for every slot's full length plus the garbage page, and
-            # none of the default 2x prefix-cache headroom nothing here
-            # uses: as compiled today the prefill step holds about four
-            # pool-sized copies (PERF.md), and they have to fit beside it
+            # none of the default 2x prefix-cache headroom nothing here uses
             "kvcache": {"enabled": True, "page_len": s.page_len,
                         "num_pages": 1 + s.slots * (s.max_len // s.page_len)},
         })
@@ -469,11 +467,24 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
               f"serve[{kv}]: {srv.prefill_compiles} prefill / {srv.decode_compiles} decode executables for one pool")
         say(f"serve[{kv}]: {len(ids)} requests x {s.new_tokens} tokens done, smoke wall {wall:.1f}s "
             f"(compiles included), pool {srv.pool.cache_bytes() / 2**30:.2f} GiB")
-        # the paged prefill attends through gather + lax (T > 1); only
+        # the paged prefill attends block by block in jnp (T > 1); only
         # the decode step arms a kernel
-        expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
-                       ["flash_decode_paged"] if s.mosaic else [], f"serve[{kv}] decode")
+        decode = srv.compiled_step("decode")
+        expect_kernels(mosaic_kernels(decode.as_text()), ["flash_decode_paged"] if s.mosaic else [], f"serve[{kv}] decode")
         expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()), [], f"serve[{kv}] prefill")
+        # the pool is written in place in the one layout the kernel
+        # reads: the decode program hands all of it back aliased and
+        # keeps less than one layer's K+V (and the tied head's weights,
+        # which XLA transposes) beside it — a relayout in front of the
+        # kernel or a scan over the pool is a pool-sized temporary
+        m = decode.memory_analysis()
+        pool, layer_kv = srv.pool.cache_bytes(), srv.pool.cache_bytes() // mcfg.n_layer
+        head = mcfg.vocab_size * mcfg.n_embd * jnp.dtype(inf.dtype).itemsize
+        say(f"serve[{kv}] decode: {m.alias_size_in_bytes / 2**20:.0f} MiB aliased of a pool of {pool / 2**20:.0f}, "
+            f"temporaries {m.temp_size_in_bytes / 2**20:.0f} MiB (one layer's K+V {layer_kv / 2**20:.0f}, the head {head / 2**20:.0f})")
+        check(m.alias_size_in_bytes >= pool, f"serve[{kv}] decode: {m.alias_size_in_bytes} B aliased, the pool holds {pool}")
+        check(m.temp_size_in_bytes < layer_kv + head + (32 << 20),
+              f"serve[{kv}] decode: {m.temp_size_in_bytes} B of temporaries beside a layer slice of {layer_kv} B")
         out[kv] = [done[rid].generated for rid in ids]
         del srv, done
         gc.collect()

@@ -279,13 +279,14 @@ def _flash_decode_paged_kernel(
     pos_ref,          # SMEM (B,) int32 — per-slot query position (scalar prefetch)
     q_ref,            # (1, block_heads, group, d): the query heads of block_heads KV heads
     k_ref,            # (1, block_heads, page_len, d)  — THE page pt[b, p], codes or bf16/f32
-    v_ref,            # (1, block_heads, page_len, d)
+    v_ref,            # (1, block_heads, page_len, d); both (1, block_heads, d, page_len) when ``lanes_hold_rows``
     *rest,            # [ks_ref, vs_ref (1,block_heads,1,page_len)]; o_ref; scratch m, l, acc
     sm_scale: float,
     page_len: int,
     quant: bool,
     block_heads: int,
     group: int,
+    lanes_hold_rows: bool,
 ):
     refs = list(rest)
     ks_ref = refs.pop(0) if quant else None
@@ -316,10 +317,12 @@ def _flash_decode_paged_kernel(
     for h in range(block_heads):
         rows = pl.dslice(h * group, group)
         q = q_ref[0, h].astype(jnp.float32)                      # (group, d)
-        k = k_ref[0, h].astype(jnp.float32)                      # (page_len, d)
+        # the page's positions are the rows of its tile, or — a head
+        # narrower than the lanes — its columns: the contraction moves
+        k = k_ref[0, h].astype(jnp.float32)                      # (page_len, d) | (d, page_len)
         scores = jax.lax.dot_general(
             q, k,
-            dimension_numbers=(((1,), (1,)), ((), ())),
+            dimension_numbers=(((1,), (0 if lanes_hold_rows else 1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * sm_scale                                             # (group, page_len)
         if quant:
@@ -337,8 +340,10 @@ def _flash_decode_paged_kernel(
         if quant:
             p = p * vs_ref[0, h]
         v = v_ref[0, h].astype(jnp.float32)
-        acc_ref[rows] = acc_ref[rows] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
+        acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+            p, v,
+            dimension_numbers=(((1,), (1 if lanes_hold_rows else 0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
 
     @pl.when(p_idx == num_p - 1)
@@ -403,6 +408,16 @@ def flash_decode_paged(
         interpret = pallas_interpret_default()
     group = H // Hkv
     bh = 1 if group == 1 else Hkv  # KV heads a program holds
+    # A head narrower than the 128 lanes: the TPU stores ``(..., page_len,
+    # d)`` with ``page_len`` in the lanes (``d`` there would pad every
+    # row to 128), so the tile Mosaic is handed is ``(d, page_len)`` —
+    # the pool's own bytes under another name, where a ``(page_len, d)``
+    # tile is a relayout of the whole pool in front of every call
+    # (docs/kernels.md).  A rule on the shape, for every caller.
+    lanes_hold_rows = d % 128 != 0
+    if lanes_hold_rows:
+        k_op, v_op = jnp.swapaxes(k_op, 2, 3), jnp.swapaxes(v_op, 2, 3)
+    page_block = (1, bh, d, page_len) if lanes_hold_rows else (1, bh, page_len, d)
 
     table = jnp.asarray(page_table, jnp.int32)
     pos_vec = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
@@ -410,8 +425,8 @@ def flash_decode_paged(
     # index maps receive (*grid_ids, *scalar_prefetch_refs)
     in_specs = [
         pl.BlockSpec((1, bh, group, d), lambda b, h, p, pt, pv: (b, h, 0, 0)),
-        pl.BlockSpec((1, bh, page_len, d), lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)),
-        pl.BlockSpec((1, bh, page_len, d), lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)),
+        pl.BlockSpec(page_block, lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)),
+        pl.BlockSpec(page_block, lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)),
     ]
     args = [q.reshape(B, Hkv, group, d), k_op, v_op]
     if quant:
@@ -432,6 +447,7 @@ def flash_decode_paged(
         quant=quant,
         block_heads=bh,
         group=group,
+        lanes_hold_rows=lanes_hold_rows,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -197,10 +197,8 @@ def attention(sz: Sizes, w_qkv, w_conv0, w_conv1, tau, h, k_pool, v_pool, state:
     with jax.named_scope("cca.attend"):
         k_pool = inf.paged_cache_write_slices(k_pool, layer, k, page_table, pos, write_mask)
         v_pool = inf.paged_cache_write_slices(v_pool, layer, v, page_table, pos, write_mask)
-        # the layer's pages, in place: the two leading dims merged (a bitcast) and the table offset to match
-        pages, page_len = k_pool.shape[1], k_pool.shape[3]
-        kc, vc = (p.reshape((-1,) + p.shape[2:]) for p in (k_pool, v_pool))
-        table = page_table + jnp.int32(layer * pages)
+        kc, vc, table = inf.layer_pages(k_pool, v_pool, page_table, layer)  # the layer's pages, where they lie
+        page_len = k_pool.shape[3]
         if T == 1:
             armed = _kernels.flash_decode_armed() if use_kernel is None else use_kernel
             fits = decode_paged_supported(B, H, page_table.shape[1], page_len, d)

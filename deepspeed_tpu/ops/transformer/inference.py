@@ -33,6 +33,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import with_layout_constraint
 
 from deepspeed_tpu.ops.attention.flash_attention import flash_attention, mha_reference
 from deepspeed_tpu.ops.normalize import layer_norm as _ln
@@ -152,7 +153,12 @@ def paged_cache_write(cache, t, page_table, pos, write_mask=None):
     writes to (garbage page, row 0) — how a fixed-shape decode step
     keeps non-decoding slots from touching real pages (the paged
     analogue of the safe-position invariant).  int8 caches quantize
-    rows exactly like :func:`slot_cache_write`."""
+    rows exactly like :func:`slot_cache_write`.
+
+    **The reference form**, for tests and ``chip_smoke.py``: no serving
+    program calls it.  An XLA scatter over the (page, position) dims
+    relays a pool out and back on the TPU (PERF.md, PRs 32 and 40); the
+    programs write through :func:`paged_cache_write_slices`."""
     quant = isinstance(cache, dict)
     page_len = (cache["q"] if quant else cache).shape[2]
     B, H, T, _ = t.shape
@@ -189,9 +195,11 @@ def page_target(page_table, b: int, at, page_len: int, span: int = 1, write_mask
     return pid, off
 
 
-def paged_cache_write_slices(pool, layer: int, t, page_table, pos, write_mask=None):
-    """:func:`paged_cache_write` into layer ``layer`` of a stacked
-    bf16/f32 pool ``(layers, num_pages, H, page_len, d)``, written as
+def paged_cache_write_slices(pool, layer, t, page_table, pos, write_mask=None):
+    """:func:`paged_cache_write` into layer ``layer`` (a Python int or a
+    traced scalar) of a stacked pool ``(layers, num_pages, H, page_len,
+    d)`` — or of the int8 code+scale pair of such pools, the rows
+    quantized as :func:`paged_cache_write` does — written as
     ``dynamic_update_slice``s, because those update a donated pool in
     place in the layout it has.  An XLA scatter over the (page,
     position) dims wants ``page_len`` ahead of ``H`` in memory: on the
@@ -205,14 +213,18 @@ def paged_cache_write_slices(pool, layer: int, t, page_table, pos, write_mask=No
     (a prefix hit, any future caller) splits at the page edges instead of
     being clamped onto a boundary, and positions past the slot's last
     page are dropped."""
+    if isinstance(pool, dict):
+        cq, cs = _kv_quant(t)
+        return {"q": paged_cache_write_slices(pool["q"], layer, cq, page_table, pos, write_mask),
+                "s": paged_cache_write_slices(pool["s"], layer, cs, page_table, pos, write_mask)}
     page_len, P = pool.shape[3], page_table.shape[1]
     B, H, T, d = t.shape
     t = t.astype(pool.dtype)
-    zero = jnp.int32(0)
+    zero, layer = jnp.int32(0), jnp.asarray(layer, jnp.int32)
     if T == 1:
         for b in range(B):
             pid, off = page_target(page_table, b, pos[b], page_len, 1, write_mask)
-            pool = jax.lax.dynamic_update_slice(pool, t[b][None, None], (jnp.int32(layer), pid, zero, off, zero))
+            pool = jax.lax.dynamic_update_slice(pool, t[b][None, None], (layer, pid, zero, off, zero))
         return pool
     windows = -(-T // page_len) + 1
     r = jnp.arange(page_len, dtype=jnp.int32)
@@ -224,11 +236,36 @@ def paged_cache_write_slices(pool, layer: int, t, page_table, pos, write_mask=No
             c = i * page_len + r - shift
             covered = (c >= 0) & (c < T) & (first + i < P)
             pid, _ = page_target(page_table, b, (first + i) * page_len, page_len, page_len, write_mask)
-            at = (jnp.int32(layer), pid, zero, zero, zero)
+            at = (layer, pid, zero, zero, zero)
             old = jax.lax.dynamic_slice(pool, at, (1, 1, H, page_len, d))
             new = jax.lax.dynamic_slice_in_dim(padded[b], (i + 1) * page_len - shift, page_len, axis=1)
             pool = jax.lax.dynamic_update_slice(pool, jnp.where(covered[None, None, None, :, None], new[None, None], old), at)
     return pool
+
+
+def page_copy(pool, src, dst):
+    """Page ``src`` of every layer onto page ``dst`` of a stacked pool
+    ``(layers, num_pages, ...)`` (any pytree of them): one slice read and
+    one slice written in place — the copy-on-write of a prefill program.
+    ``src == dst`` (the garbage page onto itself, when nothing pends) is
+    the identity."""
+    def one(buf):
+        at = lambda p: (jnp.int32(0), jnp.asarray(p, jnp.int32)) + (jnp.int32(0),) * (buf.ndim - 2)  # noqa: E731
+        page = jax.lax.dynamic_slice(buf, at(src), (buf.shape[0], 1) + buf.shape[2:])
+        return jax.lax.dynamic_update_slice(buf, page, at(dst))
+
+    return jax.tree.map(one, pool)
+
+
+def layer_pages(k_pool, v_pool, page_table, layer):
+    """Layer ``layer`` (an int or a traced scalar) of the stacked pools
+    as the ``(pages, H, page_len, d)`` caches the paged attentions take,
+    **where it lies**: the two leading dims merged (a bitcast) and the
+    page table offset to match.  A ``pool[layer]`` slice in front of a
+    kernel is a copy of the layer.  Returns ``(k, v, table)``."""
+    pages = jax.tree.leaves(k_pool)[0].shape[1]
+    merged = lambda c: jax.tree.map(lambda p: p.reshape((-1,) + p.shape[2:]), c)  # noqa: E731
+    return merged(k_pool), merged(v_pool), page_table + jnp.asarray(layer * pages, jnp.int32)
 
 
 def state_rows(buf, layer: int, slot):
@@ -283,17 +320,22 @@ def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
 
 def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Optional[float] = None,
                           block_pages: int = 4):
-    """A prefill chunk against a paged bf16/f32 cache, **block by block
-    over its context** under an online softmax: ``q (B, H, T, d)`` at
-    positions ``pos[b] + t`` (the chunk's own keys already written),
-    caches ``(num_pages, Hkv, page_len, d)`` with ``H`` a multiple of
-    ``Hkv`` (query head ``i`` attends KV head ``i // (H / Hkv)``).  Walks
+    """A prefill chunk against a paged cache, **block by block over its
+    context** under an online softmax: ``q (B, H, T, d)`` at positions
+    ``pos[b] + t`` (the chunk's own keys already written), caches
+    ``(num_pages, Hkv, page_len, d)`` — bf16/f32, or the int8 code+scale
+    pair, whose scales fold in on the scores and the probabilities as in
+    :func:`cache_attention` — with ``H`` a multiple of ``Hkv`` (query
+    head ``i`` attends KV head ``i // (H / Hkv)``).  Walks
     ``block_pages`` pages at a time as far as the furthest query reaches:
     one block's ``(B, H, T, block_pages * page_len)`` float32 scores are
-    the most that exists, never a slot's or the pool's length.  Returns
-    ``(B, H, T, d)`` in ``q``'s dtype."""
+    the most that exists, never a slot's or the pool's length.  The
+    pool is read where it lies — gathered a block at a time, or, a head
+    narrower than the lanes, the rows' own pages sliced out once.
+    Returns ``(B, H, T, d)`` in ``q``'s dtype."""
+    quant = isinstance(k_cache, dict)
     B, H, T, d = q.shape
-    _, Hkv, page_len, _ = k_cache.shape
+    _, Hkv, page_len, _ = (k_cache["q"] if quant else k_cache).shape
     P, G = page_table.shape[1], H // Hkv
     while P % block_pages:
         block_pages -= 1
@@ -304,14 +346,41 @@ def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Option
     qg = q.reshape(B, Hkv, G, T, d)
     q_pos = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (B, T)
 
-    def rows(cache, j):  # (B, Hkv, S, d): block j of every row's context
-        pages = jax.lax.dynamic_slice_in_dim(page_table, j * block_pages, block_pages, axis=1)
-        t = jnp.take(cache, pages.reshape(-1), axis=0).reshape(B, block_pages, Hkv, page_len, d)
-        return t.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, S, d).astype(q.dtype)
+    if d % 128:
+        # A head narrower than the 128 lanes lies with its positions in
+        # the lanes (flash_decode_paged).  A gather wants its operand
+        # row-major, and a loop body that slices the pool picks a layout
+        # of its own for it: either relays the whole pool out in front of
+        # the loop.  So the rows' own pages are sliced out of the pool
+        # here, in the layout it was handed in, and the loop walks that
+        # copy of the rows' contexts (one layer of ``B`` slots)
+        def context(cache):  # (B, Hkv, P * page_len, x)
+            at = lambda page: (page,) + (jnp.int32(0),) * 3  # noqa: E731
+            t = jnp.concatenate([jax.lax.dynamic_slice(cache, at(page_table[b, i]), (1,) + cache.shape[1:])
+                                 for b in range(B) for i in range(P)], axis=0)
+            return t.reshape(B, P, Hkv, page_len, cache.shape[-1]).transpose(0, 2, 1, 3, 4).reshape(B, Hkv, P * page_len, -1)
+
+        k_cache, v_cache = jax.tree.map(context, (k_cache, v_cache))
+
+        def rows(ctx, j):  # (B, Hkv, S, x): block j of every row's context
+            return jax.lax.dynamic_slice_in_dim(ctx, j * S, S, axis=2)
+    else:
+        def rows(cache, j):
+            pages = jax.lax.dynamic_slice_in_dim(page_table, j * block_pages, block_pages, axis=1)
+            t = jnp.take(cache, pages.reshape(-1), axis=0).reshape(B, block_pages, Hkv, page_len, cache.shape[-1])
+            return t.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, S, cache.shape[-1])
+
+    def operand(cache, j):  # the block's rows as a dot operand, and their per-row scales (B, Hkv, 1, 1, S) if int8
+        if quant:
+            return rows(cache["q"], j).astype(q.dtype), rows(cache["s"], j)[..., 0][:, :, None, None, :]
+        return rows(cache, j).astype(q.dtype), None
 
     def body(j, carry):
         m, l, acc = carry
-        s = jnp.einsum("bhgtd,bhsd->bhgts", qg, rows(k_cache, j), preferred_element_type=jnp.float32) * sm_scale
+        k, k_scale = operand(k_cache, j)
+        s = jnp.einsum("bhgtd,bhsd->bhgts", qg, k, preferred_element_type=jnp.float32) * sm_scale
+        if quant:
+            s = s * k_scale
         k_pos = j * S + jnp.arange(S, dtype=jnp.int32)
         ok = k_pos[None, None, :] <= q_pos[:, :, None]  # (B, T, S)
         s = jnp.where(ok[:, None, None], s, -1e30)
@@ -319,8 +388,14 @@ def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Option
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m - m_new)
         l = alpha * l + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum("bhgts,bhsd->bhgtd", p.astype(q.dtype), rows(v_cache, j),
-                                                  preferred_element_type=jnp.float32)
+        acc = acc * alpha[..., None]
+        if quant:
+            v, v_scale = operand(v_cache, j)
+            p = (p * v_scale).astype(q.dtype)
+        else:  # traced in the order the wide-head programs always were: their lowered text is the parent's
+            p = p.astype(q.dtype)
+            v, _ = operand(v_cache, j)
+        acc = acc + jnp.einsum("bhgts,bhsd->bhgtd", p, v, preferred_element_type=jnp.float32)
         return m_new, l, acc
 
     stat = (B, Hkv, G, T)
@@ -418,6 +493,8 @@ def inference_block(
     key_padding_mask=None,
     page_table=None,
     write_mask=None,
+    layer=None,
+    trace_notes: Optional[dict] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One transformer layer with cache update.
 
@@ -428,10 +505,14 @@ def inference_block(
     whole cache with the position mask.  A per-example (B,) ``pos``
     vector selects the slot-pool form: each row reads/writes its own
     position (continuous batching, serving/).  ``page_table`` (B,
-    pages_per_slot) selects the PAGED form instead: the caches are
-    page pools (num_pages, H, page_len, d), writes scatter through the
-    table (``write_mask`` redirecting masked rows to the garbage page)
-    and attention reads the gathered logical view — requires a
+    pages_per_slot) selects the PAGED form instead: the caches are the
+    **stacked** page pools (layers, num_pages, H, page_len, d) (or the
+    int8 code+scale pair) and ``layer`` — an int or a traced scalar —
+    says which layer of them this block is.  The rows are written into
+    the pool as slices (``write_mask`` redirecting masked rows to the
+    garbage page) and attention reads the layer's pages where they lie:
+    one query through the paged decode kernel (or the gather + lax
+    form), a chunk block by block over the slot's pages — requires a
     per-slot ``pos`` and no ``key_padding_mask``.  Returns
     (y, new_k_cache, new_v_cache).  Mirrors the reference's fused
     attention+MLP inference module (``transformer_inference.py``
@@ -451,9 +532,17 @@ def inference_block(
     if page_table is not None:
         if key_padding_mask is not None:
             raise ValueError("paged caches do not support key_padding_mask")
-        k_cache = paged_cache_write(k_cache, k, page_table, pos, write_mask)
-        v_cache = paged_cache_write(v_cache, v, page_table, pos, write_mask)
-        attn = paged_cache_attention(q, k_cache, v_cache, page_table, pos)
+        k_cache = paged_cache_write_slices(k_cache, layer, k, page_table, pos, write_mask)
+        v_cache = paged_cache_write_slices(v_cache, layer, v, page_table, pos, write_mask)
+        kc, vc, table = layer_pages(k_cache, v_cache, page_table, layer)
+        if trace_notes is not None:
+            trace_notes["kv_write_form"] = "slices, in place"
+        if T == 1:
+            attn = paged_cache_attention(q, kc, vc, table, pos)
+        else:
+            if trace_notes is not None:
+                trace_notes["prefill_attend_form"] = "blockwise (paged_chunk_attention)"
+            attn = paged_chunk_attention(q, kc, vc, table, pos)
         attn = attn.transpose(0, 2, 1, 3).reshape(B, T, D)
         attn = _wmm(attn, lp["proj_w"]) + lp["proj_b"].astype(attn.dtype)
         return _block_mlp(cfg, lp, x + attn), k_cache, v_cache
@@ -540,6 +629,8 @@ def forward_with_cache(
     position_ids=None,
     page_table=None,
     write_mask=None,
+    trace_notes: Optional[dict] = None,
+    pool_layout=None,
 ):
     """Full GPT-2-layout network step with cache: embeddings → scanned
     cached blocks → final LN → tied-embedding logits.
@@ -552,7 +643,15 @@ def forward_with_cache(
     default ``pos + arange(T)`` positions (per-example real positions
     under left padding).  ``page_table`` (B, pages_per_slot) +
     ``write_mask`` (B,) select the paged-cache form (see
-    :func:`inference_block`).  Returns (logits (B,T,V), new_k, new_v).
+    :func:`inference_block`): the stacked pools are the **carry** of
+    the layer loop — every layer updates its own rows of them in place
+    and reads its own pages — never its ``xs``/``ys``, which rebuilds
+    the whole pool a step.  ``pool_layout``, a pytree of
+    ``jax.experimental.layout.Layout`` like ``k_cache``, is the
+    on-device layout the pools were allocated with (``array.format``):
+    the carry is pinned to it.  ``trace_notes``, a dict, is told the
+    forms the paged program took.  Returns (logits (B,T,V), new_k,
+    new_v).
     """
     B, T = tokens.shape
     d = params["wte"].shape[1]
@@ -572,7 +671,30 @@ def forward_with_cache(
     x = jnp.take(params["wte"], tokens, axis=0) + pos_emb
     x = x.astype(cfg.dtype)
 
-    if isinstance(k_cache, (tuple, list)):
+    if page_table is not None:
+        n_layer = jax.tree.leaves(k_cache)[0].shape[0]
+
+        def pin(cache):
+            if pool_layout is None:
+                return cache
+            return jax.tree.map(with_layout_constraint, cache, pool_layout)
+
+        # The pools ride the layer loop's carry and the layer's index its
+        # xs.  A loop body is free to pick the layout of its parameters,
+        # and picks one to suit its own slices of the pool — another than
+        # the pool was handed in with, so it is copied in and out, every
+        # step — unless the carry is pinned to the layout the pool has.
+        def layer(carry, xs):
+            lp, i = xs
+            x, k, v = carry
+            x, k, v = inference_block(cfg, lp, x, pin(k), pin(v), pos, page_table=page_table, write_mask=write_mask,
+                                      layer=i, trace_notes=trace_notes)
+            return (x, pin(k), pin(v)), None
+
+        (x, new_k, new_v), _ = jax.lax.scan(
+            layer, (x, k_cache, v_cache), (params["blocks"], jnp.arange(n_layer, dtype=jnp.int32))
+        )
+    elif isinstance(k_cache, (tuple, list)):
         # PER-LAYER cache buffers (decode fast path): each of the L
         # python-unrolled layers reads/writes ITS OWN (B,H,S,d) array —
         # no slicing/reassembly of a stacked (L,...) buffer, which the
@@ -586,7 +708,6 @@ def forward_with_cache(
             x, ck, cv = inference_block(
                 cfg, lp, x, k_cache[i], v_cache[i], pos,
                 key_padding_mask=key_padding_mask,
-                page_table=page_table, write_mask=write_mask,
             )
             new_k.append(ck)
             new_v.append(cv)
@@ -598,7 +719,6 @@ def forward_with_cache(
             y, ck, cv = inference_block(
                 cfg, lp, carry, ck, cv, pos,
                 key_padding_mask=key_padding_mask,
-                page_table=page_table, write_mask=write_mask,
             )
             return y, (ck, cv)
 

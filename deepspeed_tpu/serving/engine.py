@@ -150,6 +150,9 @@ class ServingEngine:
         cache_kind = getattr(family, "cache_kind", None)
         make_forward = getattr(family, "serving_forward", None)
         self._family_forward = make_forward(mcfg) if make_forward is not None else None
+        # the forms the two programs took, said while they were traced
+        # (stats()): the family's own dict, or the paged per-head pool's
+        self._trace_notes: Dict[str, Any] = getattr(self._family_forward, "trace_notes", {})
         if self._family_forward is not None and kv_dtype == "int8":
             raise ValueError(f"{type(mcfg).__name__}: no int8 form of its cache kind (kv_cache_dtype must be 'model')")
         # per-step counters a family's step returns beside the tokens
@@ -368,12 +371,24 @@ class ServingEngine:
             return san.recompile.wrap(fn, site=site, owner=id(self))
         return fn
 
+    def _pool_layout(self):
+        """The on-device layout the paged pool's K buffers were allocated
+        with (V's is the same), leaf by leaf: what both programs pin the
+        pool to while they carry it through the layers, so that it is
+        one layout from allocation to the kernel and back (on the TPU a
+        head narrower than the lanes lies with ``page_len`` in them;
+        docs/serving.md §Paged KV)."""
+        from jax.experimental.layout import Layout
+
+        return jax.tree.map(lambda a: Layout(major_to_minor=a.format.layout.major_to_minor), self.pool.k)
+
     def _get_prefill(self):
         if self._prefill_fn is None:
             from deepspeed_tpu.inference.engine import sample_logits_pooled
-            from deepspeed_tpu.ops.transformer.inference import forward_with_cache
+            from deepspeed_tpu.ops.transformer.inference import forward_with_cache, page_copy
 
             icfg = self.engine.inference_config(self.pool.max_len) if self._family_forward is None else None
+            notes = self._trace_notes
             n_pos = self.engine.model_config.n_positions
             chunk = self.config.prefill_chunk
             max_top_k = self.config.max_top_k
@@ -426,23 +441,26 @@ class ServingEngine:
 
                 donate = (2, 3, 4)
             elif self._paged:
+                pool_layout = self._pool_layout()
+
                 def serve_prefill(params, packed, k_pool, v_pool):
                     f = unpack(packed)
                     toks, table, pos, take_idx = f["tokens"], f["table"], f["pos"], f["take_idx"]
-                    cow_src, cow_dst = f["cow_src"], f["cow_dst"]
                     # the slot's pending copy-on-write lands BEFORE this
                     # chunk's writes: a traced (src, dst) page pair rides
                     # the request's first chunk ((0, 0) — garbage page
-                    # onto itself — is the identity when nothing pends)
-                    cow = lambda b: b.at[:, cow_dst].set(b[:, cow_src])  # noqa: E731
-                    k_pool = jax.tree.map(cow, k_pool)
-                    v_pool = jax.tree.map(cow, v_pool)
+                    # onto itself — is the identity when nothing pends).
+                    # One page read and written as slices: a scatter over
+                    # the page axis relays the whole pool out
+                    k_pool = page_copy(k_pool, f["cow_src"], f["cow_dst"])
+                    v_pool = page_copy(v_pool, f["cow_src"], f["cow_dst"])
                     position_ids = jnp.clip(
                         pos + jnp.arange(chunk, dtype=jnp.int32), 0, n_pos - 1
                     )[None, :]
                     logits, k_pool, v_pool = forward_with_cache(
                         params, toks, k_pool, v_pool, pos[None], icfg,
                         position_ids=position_ids, page_table=table[None, :],
+                        trace_notes=notes, pool_layout=pool_layout,
                     )
                     key = jax.random.fold_in(
                         jax.random.PRNGKey(f["seed"]), pos + take_idx
@@ -502,6 +520,7 @@ class ServingEngine:
             from deepspeed_tpu.ops.transformer.inference import forward_with_cache
 
             icfg = self.engine.inference_config(self.pool.max_len) if self._family_forward is None else None
+            notes = self._trace_notes
             max_top_k = self.config.max_top_k
             unpack = self._decode_layout.unpack
 
@@ -526,6 +545,8 @@ class ServingEngine:
 
                 donate = (2, 3, 4)
             elif self._paged:
+                pool_layout = self._pool_layout()
+
                 def serve_decode(params, packed, k_pool, v_pool):
                     f = unpack(packed)
                     toks, pos, page_table, write_mask = f["toks"], f["pos"], f["tables"], f["write_mask"]
@@ -534,7 +555,8 @@ class ServingEngine:
                     # slots' writes to the garbage page (pages.py)
                     logits, k_pool, v_pool = forward_with_cache(
                         params, toks[:, None], k_pool, v_pool, pos, icfg,
-                        page_table=page_table, write_mask=write_mask,
+                        page_table=page_table, write_mask=write_mask, trace_notes=notes,
+                        pool_layout=pool_layout,
                     )
                     keys = jax.vmap(
                         lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
@@ -1522,8 +1544,9 @@ class ServingEngine:
         # what the family's programs said of themselves while tracing
         # (DeepSeek-V2: mla_prefill_kernel / mla_prefill_fallback, moe_grouped_kernel / moe_grouped_fallback;
         # Solar-Open2: kda_decode_kernel / _fallback, kda_prefill_form, gqa_decode_kernel / _fallback, gqa_prefill_form;
-        # ZAYA: cca_decode_kernel / _fallback, cca_prefill_form, moe_router_form)
-        out.update(getattr(self._family_forward, "trace_notes", {}))
+        # ZAYA: cca_decode_kernel / _fallback, cca_prefill_form, moe_router_form;
+        # the paged per-head pool: kv_write_form, prefill_attend_form)
+        out.update(self._trace_notes)
         out.update(self.timeline.summary())
         if out.get("programs"):
             # host→device transfers a program since the timeline's last

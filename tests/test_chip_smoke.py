@@ -282,6 +282,66 @@ def test_zaya_steps_compile_for_v5e_with_pages_and_tail_updated_in_place(which, 
     assert m.temp_size_in_bytes < int(np.prod(k.shape)) * 2 // 2   # and no temporary is half of one pool
 
 
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_gpt2_xl_paged_steps_compile_for_v5e_with_the_pool_read_and_written_where_it_lies(which, v5e_chip, monkeypatch):
+    """Four layers of GPT-2 XL at the published widths on the GPT-2
+    serve cells' paged pool (16 slots, 80 pages of 128 x 64: a head of
+    half a lane row, which the TPU stores with ``page_len`` in the
+    lanes), by the chip's compiler without the chip: a decode step holds
+    ``flash_decode_paged`` in its layer loop, handed ``(d, page_len)``
+    tiles of the pool's own bytes; both steps hand the pool back aliased;
+    and neither holds an array the size of the pool or of a layer of it
+    other than the pool itself and its in-place slice updates (the
+    scatter, the scan over the pool and the relayout in front of the
+    kernel made nine such operations, 3 s of a traced 5: PERF.md, PR 40).
+    The carry is pinned to the layout the pool has on the chip, as the
+    engine pins it from ``pool.k.format``."""
+    from jax.experimental.layout import Layout
+
+    from deepspeed_tpu.ops.kernels import flash_decode
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    monkeypatch.setenv("DS_KERNELS", "1")
+    monkeypatch.setattr(flash_decode, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
+    lanes_hold_page_len = Layout(major_to_minor=(0, 1, 2, 4, 3))  # what a v5e gives bf16[.., 128, 64]: the entry layout below
+    mcfg = gpt2.PRESETS["gpt2-xl"]
+    L, slots, pages, page_len, chunk = 4, 16, 80, 128, 64
+    H, d, E, V = mcfg.n_head, mcfg.head_dim, mcfg.n_embd, mcfg.vocab_size
+    on_chip = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    block = {"ln1_g": (E,), "ln1_b": (E,), "qkv_w": (E, 3 * E), "qkv_b": (3 * E,), "proj_w": (E, E), "proj_b": (E,),
+             "ln2_g": (E,), "ln2_b": (E,), "fc_w": (E, 4 * E), "fc_b": (4 * E,), "fc_proj_w": (4 * E, E), "fc_proj_b": (E,)}
+    params = {"wte": on_chip((V, E)), "wpe": on_chip((mcfg.n_positions, E)), "lnf_g": on_chip((E,)), "lnf_b": on_chip((E,)),
+              "blocks": {name: on_chip((L,) + shape) for name, shape in block.items()}}
+    icfg = inf.DeepSpeedInferenceConfig(hidden_size=E, heads=H, dtype=jnp.bfloat16, max_out_tokens=mcfg.n_positions)
+    pool = on_chip((L, pages, H, page_len, d))
+    P = mcfg.n_positions // page_len
+    if which == "decode":
+        def step(p, t, pos, table, wm, k, v):
+            return inf.forward_with_cache(p, t[:, None], k, v, pos, icfg, page_table=table, write_mask=wm,
+                                          pool_layout=lanes_hold_page_len)
+        args = (params, on_chip((slots,), jnp.int32), on_chip((slots,), jnp.int32), on_chip((slots, P), jnp.int32),
+                on_chip((slots,), jnp.bool_), pool, pool)
+    else:
+        def step(p, t, table, pos, src, dst, k, v):
+            k, v = inf.page_copy(k, src, dst), inf.page_copy(v, src, dst)  # the pending copy-on-write, as the engine has it
+            return inf.forward_with_cache(p, t, k, v, pos[None], icfg, page_table=table[None], pool_layout=lanes_hold_page_len)
+        args = (params, on_chip((1, chunk), jnp.int32), on_chip((P,), jnp.int32), on_chip((), jnp.int32),
+                on_chip((), jnp.int32), on_chip((), jnp.int32), pool, pool)
+    compiled = jax.jit(step, donate_argnums=(len(args) - 2, len(args) - 1)).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert chip_smoke.mosaic_kernels(hlo) == ({"flash_decode_paged": 1} if which == "decode" else {})
+    assert "bf16[%d,%d,%d,%d,%d]{3,4,2,1,0" % (L, pages, H, page_len, d) in hlo.split("\n", 1)[0]  # the entry layout is the pinned one
+    if which == "decode":
+        assert chip_smoke.first_output_dims(hlo, "flash_decode_paged") == (slots, H, 1, d)
+    pool_elems = L * pages * H * page_len * d
+    assert set(chip_smoke.leaf_sized_moves(hlo, pool_elems)) <= {"dynamic-update-slice", "fusion", "while"}  # slices, in place
+    assert chip_smoke.leaf_sized_moves(hlo, pool_elems // L) == []                                 # nothing a layer's size
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * pool_elems * 2
+    # beside the pool: the tied head's weights transposed (XLA's choice, 161 MB) and a step's activations
+    assert m.temp_size_in_bytes < 2 * (pool_elems // L) * 2 + V * E * 2 + (32 << 20)
+
+
 # ---------------------------------------------------------------------------
 # (b) the smoke's control flow, and its refusal to run off a TPU
 # ---------------------------------------------------------------------------

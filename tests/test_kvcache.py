@@ -17,6 +17,7 @@ session stickiness; hedge legs ignore affinity).
 """
 import dataclasses
 import os
+import re
 import time
 
 import jax
@@ -885,3 +886,187 @@ def test_slice_writes_split_a_chunk_at_the_page_edges_wherever_it_starts(start, 
     if start + T <= table.shape[1] * page_len:  # inside the slot: the scatter agrees
         ref = np.asarray(inf.paged_cache_write(pool[1], t, table, pos, mask))
         np.testing.assert_array_equal(got[1, live], ref[live])
+
+
+# ---------------------------------------------------------------------------
+# the per-head pool written in place (ISSUE 40): what the old scatter,
+# gather and layer scan guaranteed, asked of the slices that replaced them
+# ---------------------------------------------------------------------------
+
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%?\S+ = (\S+?)\s([a-z][a-z\-]*)\(", re.M)
+_HLO_DIMS = re.compile(r"\[([\d,]+)\]")
+
+
+def _pool_shaped(hlo, pool_shape):
+    """``(opcode, dims)`` of every instruction of an optimized HLO text
+    whose result is shaped like the stacked pool, one layer of it, or
+    its merged-leading-dims view (any trailing width: codes or scales)."""
+    L, NP, H, PL = pool_shape[:4]
+    out = []
+    for m in _HLO_INSTR.finditer(hlo):
+        for dims in _HLO_DIMS.findall(m.group(1)):
+            t = tuple(int(x) for x in dims.split(","))
+            if t[:-1] in ((L, NP, H, PL), (1, NP, H, PL), (NP, H, PL), (L * NP, H, PL)):
+                out.append((m.group(2), t))
+    return out
+
+
+@pytest.mark.parametrize("which", ["prefill", "decode"])
+@pytest.mark.parametrize("kv", ["model", "int8"])
+def test_paged_gpt2_programs_update_the_pool_in_place(eng, kv, which):
+    """Both programs of the paged per-head pool (bf16/f32 and the int8
+    code+scale pair) hand the whole pool back aliased, keep less beside
+    it than one layer's K+V, and hold no scatter, transpose, copy,
+    gather, slice or concatenate shaped like the pool or a layer of it:
+    only in-place slice updates (the parent's programs held all six)."""
+    srv = ServingEngine(eng, num_slots=4, prefill_chunk=8, max_len=128, kv_cache_dtype=kv,
+                        kvcache={"enabled": True, "page_len": 16})
+    shape = jax.tree.leaves(srv.pool.k)[0].shape
+    assert shape == (TINY.n_layer, 65, TINY.n_head, 16, TINY.head_dim)
+    compiled = srv.compiled_step(which)
+    m = compiled.memory_analysis()
+    layer_kv = srv.pool.cache_bytes() // TINY.n_layer
+    assert m.alias_size_in_bytes >= srv.pool.cache_bytes()
+    # the allowance: a chunk's activations, logits and sampling scratch at this size (~0.45 MB measured)
+    assert m.temp_size_in_bytes < layer_kv + 768 * 1024, (m.temp_size_in_bytes, layer_kv)
+    hlo = compiled.as_text()
+    assert " scatter(" not in hlo
+    moved = {op for op, _ in _pool_shaped(hlo, shape)} - {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
+                                                          "dynamic-update-slice", "fusion"}
+    assert moved == set(), moved
+    st = srv.stats()
+    assert st["kv_write_form"] == "slices, in place"
+    if which == "prefill":
+        assert st["prefill_attend_form"].startswith("blockwise")
+
+
+def _serve_all(srv, waves, max_new):
+    """Submit ``waves`` (lists of prompts) one after the other — a wave
+    is drained before the next is admitted, so the later ones can hit
+    what the earlier ones taught the pool — and return the tokens."""
+    out = []
+    for wave in waves:
+        rids = [srv.submit(p, max_new_tokens=max_new) for p in wave]
+        res = srv.drain(max_steps=800)
+        out += [res[r].tokens() for r in rids]
+    return out
+
+
+def _parity_traffic(case):
+    """``(engine kwargs, waves of prompts, max_new)`` of one parity case."""
+    if case == "a_chunk_and_a_decode_step_across_a_page_edge":
+        # chunks of 12 over pages of 16: the chunk [12, 24) straddles the edge
+        # at 16, the 13-token prompt's decode steps walk over it
+        return dict(prefill_chunk=12, max_len=96), [_prompts(1, 13, 13, seed=21) + _prompts(1, 30, 30, seed=22)], 8
+    if case == "a_prefix_hit_that_starts_inside_a_page_with_a_copy_on_write":
+        # 24 shared tokens = a page and a half: the hit's last page is shared
+        # and partial (copy-on-write), the first chunk starts at 24, inside it
+        # (the index learns the split from the second prompt: the third and fourth hit)
+        shared = _prompts(1, 24, 24, seed=23)[0]
+        tails = _prompts(4, 5, 11, seed=24)
+        return dict(prefill_chunk=8, max_len=64), [[np.concatenate([shared, t])] for t in tails], 5
+    if case == "a_masked_row_on_the_garbage_page":
+        # three slots, two requests: the short one decodes through the long
+        # one's five prefill chunks (its row masked) beside a slot that is empty
+        return dict(prefill_chunk=8, max_len=64, num_slots=3), [_prompts(1, 4, 4, seed=25) + _prompts(1, 40, 40, seed=26)], 10
+    raise AssertionError(case)
+
+
+@pytest.mark.parametrize("kv", ["model", "int8"])
+@pytest.mark.parametrize("case", [
+    "a_chunk_and_a_decode_step_across_a_page_edge",
+    "a_prefix_hit_that_starts_inside_a_page_with_a_copy_on_write",
+    "a_masked_row_on_the_garbage_page",
+])
+def test_paged_tokens_are_the_slot_pools_and_generates(eng, case, kv):
+    """The paged engine's greedy tokens equal the slot-contiguous pool's
+    on the same schedule — and solo ``generate()``'s, for the pool in the
+    model's dtype — where the slice writes and the blockwise chunk
+    attention differ most from the scatter and the gathered logical
+    cache they replaced."""
+    kw, waves, max_new = _parity_traffic(case)
+    kw.setdefault("num_slots", 2)
+    paged = ServingEngine(eng, kv_cache_dtype=kv, kvcache={"enabled": True, "page_len": 16}, **kw)
+    slot = ServingEngine(eng, kv_cache_dtype=kv, **kw)
+    got, want = _serve_all(paged, waves, max_new), _serve_all(slot, waves, max_new)
+    for p, g, w in zip([p for wave in waves for p in wave], got, want):
+        np.testing.assert_array_equal(g, w)
+        if kv == "model":
+            np.testing.assert_array_equal(g, _solo(eng, p, max_new))
+    kvs = paged.stats()["kvcache"]
+    if "copy_on_write" in case:
+        assert kvs["prefix_hits"] >= 2 and kvs["cow_copies"] >= 1 and kvs["tokens_saved"] >= 2 * 24
+    assert paged.prefill_compiles == 1 and paged.decode_compiles == 1
+    assert paged.pool.live_slots == 0
+    _assert_no_leaks(paged.pool)
+
+
+@pytest.mark.parametrize("d", [8, 128], ids=["head_narrower_than_the_lanes", "head_of_whole_lanes"])
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+def test_slice_writes_and_chunk_attention_are_the_scatter_and_the_gathered_cache(d, quant):
+    """Three chunks written into layer 1 of a stacked pool by slices and
+    attended block by block equal the scatter (``paged_cache_write``) and
+    the gathered logical cache (``paged_cache_attention``'s lax form) —
+    for both ways a chunk reads the pool (pages gathered a block at a
+    time; a narrow head's pages sliced out once) and for the int8 pair."""
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    H, page_len, T, P = 2, 8, 12, 6
+    rng = np.random.default_rng(d + quant)
+    kp, vp = inf.init_kv_cache(2, 9, H, page_len, d, "int8" if quant else jnp.float32)
+    kr, vr = (jax.tree.map(lambda a: a[1], c) for c in (kp, vp))  # the scatter's copy of layer 1
+    table = jnp.asarray([[5, 2, 7, 1, 3, 8]], jnp.int32)
+    for start in (0, 12, 24):
+        k, v, q = (jnp.asarray(rng.standard_normal((1, H, T, d)), jnp.float32) for _ in range(3))
+        pos = jnp.asarray([start], jnp.int32)
+        kp, vp = (inf.paged_cache_write_slices(c, 1, t, table, pos) for c, t in ((kp, k), (vp, v)))
+        kr, vr = (inf.paged_cache_write(c, t, table, pos) for c, t in ((kr, k), (vr, v)))
+        for got, ref in ((kp, kr), (vp, vr)):
+            jax.tree.map(lambda a, b: np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b)), got, ref)
+        kc, vc, tab = inf.layer_pages(kp, vp, table, 1)
+        got = inf.paged_chunk_attention(q, kc, vc, tab, pos, block_pages=2)
+        want = inf.paged_cache_attention(q, kr, vr, table, pos, use_kernel=False)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5, rtol=3e-5)
+    assert not any(np.asarray(a[0]).any() for a in jax.tree.leaves((kp, vp)))  # the other layer untouched
+
+
+def test_page_copy_is_one_page_of_every_layer_and_the_identity_on_itself():
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    rng = np.random.default_rng(3)
+    pool = {"q": jnp.asarray(rng.integers(-127, 127, (3, 6, 2, 4, 8)), jnp.int8),
+            "s": jnp.asarray(rng.standard_normal((3, 6, 2, 4, 1)), jnp.float32)}
+    out = jax.jit(inf.page_copy)(pool, jnp.int32(2), jnp.int32(5))
+    for name, buf in pool.items():
+        want = np.array(buf)
+        want[:, 5] = want[:, 2]
+        np.testing.assert_array_equal(np.asarray(out[name]), want)
+    same = inf.page_copy(pool, jnp.int32(GARBAGE_PAGE), jnp.int32(GARBAGE_PAGE))
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)), same, pool)
+
+
+@pytest.mark.parametrize("d", [64, 128], ids=["pages_tiled_d_by_page_len", "pages_tiled_page_len_by_d"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_flash_decode_paged_reads_both_tile_forms(d, quant):
+    """The paged decode kernel against the gather + lax form at a head
+    of half a lane row (handed ``(d, page_len)`` tiles) and of a whole
+    one (``(page_len, d)``), a bf16 pool and the int8 pair, through the
+    merged view of a stacked pool at a layer other than the first."""
+    from deepspeed_tpu.ops.kernels import flash_decode as fd
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    B, H, P, page_len, L = 2, 2, 3, 128, 2
+    num_pages = 1 + B * P
+    rng = np.random.default_rng(d + quant)
+    kp, vp = inf.init_kv_cache(L, num_pages, H, page_len, d, "int8" if quant else jnp.bfloat16)
+    table = jnp.asarray(np.arange(1, num_pages, dtype=np.int32).reshape(B, P))
+    rows = lambda: jnp.asarray(rng.standard_normal((B, H, P * page_len, d)), jnp.bfloat16)  # noqa: E731
+    zero = jnp.zeros((B,), jnp.int32)
+    kp, vp = inf.paged_cache_write_slices(kp, 1, rows(), table, zero), inf.paged_cache_write_slices(vp, 1, rows(), table, zero)
+    q = jnp.asarray(rng.standard_normal((B, H, 1, d)), jnp.bfloat16)
+    pos = jnp.asarray(np.array([37, 2 * page_len + 5], np.int32))
+    kc, vc, tab = inf.layer_pages(kp, vp, table, 1)
+    assert fd.decode_paged_supported(B, H, P, page_len, d)
+    got = fd.flash_decode_paged(q, kc, vc, tab, pos)
+    want = inf.paged_cache_attention(q, kc, vc, tab, pos, use_kernel=False)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), atol=2e-2, rtol=2e-2)
