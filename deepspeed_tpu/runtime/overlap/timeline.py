@@ -35,37 +35,54 @@ inactive annotation otherwise).  A dotted name (``prefill.stage``) is a
 *sub-phase*: annotated under its full name, recorded under its last
 component and, lying inside another phase, left out of the sum from
 which ``other`` and ``wall`` derive (docs/telemetry.md).
+
+:meth:`StepTimeline.summary` covers **every step since**
+:meth:`StepTimeline.reset_window` — up to ``window`` of them, one row of
+floats each in a ring; what fell out of the ring is counted
+(``steps_dropped``) — and counts the window's *stalls*: steps whose
+wall is more than ``STALL_FACTOR`` times the window's median.
 """
 from __future__ import annotations
 
 import time
-from collections import deque
 from contextlib import contextmanager
-from typing import Deque, Dict, List, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 from jax.profiler import TraceAnnotation
 
 PHASES = ("data_wait", "compute", "ckpt_stall", "compile", "other")
+# a step is a stall when its wall exceeds this many medians of its window:
+# the longest ordinary serving step (one with a prefill chunk) is 1.8 x its
+# window's median, the stalls seen on the chip's host 4-6 x and up
+STALL_FACTOR = 3.0
+STALLS_KEPT = 16  # stalls() names at most this many, the longest
 
 
 class StepTimeline:
-    """Rolling per-step phase attribution over the last ``window`` steps.
+    """Per-step phase attribution over every step since the last
+    :meth:`reset_window`, up to ``window`` of them.
 
     ``phases`` customizes the attributed phase names (the serving engine
-    uses ``prefill/decode/sched``); ``other`` is always present as the
-    unattributed remainder.  ``sub_phases`` names what is timed *inside*
-    a phase (serving: ``stage/dispatch/wait`` inside prefill and decode)
-    and is therefore reported but never added to the step's wall;
-    ``blocked_on`` names the one in which the host is blocked on the
-    device, so that :meth:`summary` can report the rest of each step
-    (``host_ms_p50/p95``: time in which a serial engine has given the
-    device nothing).
+    uses ``sched/sweep/prefill/decode/commit``); ``other`` is always
+    present as the unattributed remainder.  ``sub_phases`` names what is
+    timed *inside* a phase (serving: ``stage/dispatch/wait/note`` inside
+    prefill and decode) and is therefore reported but never added to the
+    step's wall; ``blocked_on`` names the one in which the host is
+    blocked on the device, so that :meth:`summary` can report the rest
+    of each step (``host_ms_p50/p95``: time in which a serial engine has
+    given the device nothing).
     ``prefix`` (``train`` or ``serve``) names the engine in the
     profiler's trace: ``ds.<prefix>.<phase>``.  :meth:`set_gauge`
     records per-step levels (e.g. queue depth) that are averaged — not
     ms-scaled — in :meth:`summary`; :meth:`count` keeps running totals
-    of events since the last :meth:`reset_window`."""
+    of events since the last :meth:`reset_window`.
+
+    A step is one row of floats (its phases, ``other``, ``wall``, when
+    it began, its gauges) in a ring of ``window`` rows: 8 bytes a number,
+    so a window of tens of thousands of steps is a few MB, touched as it
+    fills.  Steps past ``window`` overwrite the oldest and are counted
+    (``steps_dropped``)."""
 
     def __init__(self, enabled: bool = True, window: int = 512, phases=None,
                  sub_phases=(), blocked_on: Optional[str] = None, prefix: str = "train"):
@@ -77,7 +94,15 @@ class StepTimeline:
         self.sub_phases = tuple(sub_phases)
         self.blocked_on = blocked_on
         self.prefix = str(prefix)
-        self.records: Deque[Dict[str, float]] = deque(maxlen=self.window)
+        # the ring: step n since reset_window() is row n % window.  "at"
+        # (column 0) is the step's start, seconds since reset_window();
+        # gauges take a column each, after the timed ones, as they first
+        # appear.  Made at the first end_step
+        self._cols: List[str] = ["at"] + [p for p in self.phases + self.sub_phases if p != "other"] + ["other", "wall"]
+        self._timed = len(self._cols)
+        self._rows: Optional[np.ndarray] = None
+        self._n = 0
+        self._t_reset = time.perf_counter()
         self.total_steps = 0
         self._pending: Dict[str, float] = {}
         self._pending_gauges: Dict[str, float] = {}
@@ -95,16 +120,22 @@ class StepTimeline:
         self._telemetry = None
         self._t_prefix = "train"
         self._trace_pid = 0
+        self._t_phases: Optional[frozenset] = None
 
-    def attach_telemetry(self, manager, prefix: str = "train", trace_pid: int = 0) -> None:
+    def attach_telemetry(self, manager, prefix: str = "train", trace_pid: int = 0, phases=None) -> None:
         """Route this timeline into a
         :class:`~deepspeed_tpu.telemetry.TelemetryManager`: every
         ``phase()`` block also lands as a span (when tracing is armed)
         and every ``end_step`` publishes the closed record as
-        histograms/gauges.  Detach with ``manager=None``."""
+        histograms/gauges.  ``phases`` keeps the plane to the named
+        phases and sub-phases (with ``other``, ``wall`` and the gauges):
+        what a timeline times beside them stands on the profiler's clock
+        and in :meth:`summary` only, and is the plane's ``other``.
+        Detach with ``manager=None``."""
         self._telemetry = manager
         self._t_prefix = prefix
         self._trace_pid = int(trace_pid)
+        self._t_phases = None if phases is None else frozenset(phases) | {"other", "wall"}
 
     def set_comm(self, strategy: str, bytes_per_step: int) -> None:
         """Record the engine's active comm strategy + per-step
@@ -128,20 +159,22 @@ class StepTimeline:
         return TraceAnnotation(f"ds.{self.prefix}.{name}", **args)
 
     @contextmanager
-    def phase(self, name: str):
+    def phase(self, name: str, **args):
         """Time a host block and note it under ``name`` — a sub-phase
         ``outer.inner`` under ``inner`` — and annotate it in the
-        profiler's trace (and, a phase only, as a Chrome-trace span when
-        the attached telemetry plane has tracing armed).  Yields the
-        annotation: ``set_metadata(**args)`` on it adds arguments that
-        are known only once the block has run."""
-        with self.annotation(name) as span:
+        profiler's trace with ``args`` (and, a phase only, as a
+        Chrome-trace span when the attached telemetry plane has tracing
+        armed).  Yields the annotation: ``set_metadata(**args)`` on it
+        adds arguments that are known only once the block has run."""
+        with self.annotation(name, **args) as span:
             if not self.enabled:
                 yield span
                 return
             key = name.rpartition(".")[2]
             tm = self._telemetry
-            tracer = tm.tracer if key == name and tm is not None and tm.tracer.enabled else None
+            tracer = None
+            if key == name and tm is not None and tm.tracer.enabled and (self._t_phases is None or key in self._t_phases):
+                tracer = tm.tracer
             t0m = tracer.now() if tracer is not None else 0.0
             t0 = time.perf_counter()
             try:
@@ -198,64 +231,147 @@ class StepTimeline:
         rec["other"] = (self._pending.get("other", 0.0) + other) / count
         rec["wall"] = max(wall, noted) / count
         rec.update(self._pending_gauges)
-        for _ in range(count):
-            self.records.append(dict(rec))
+        # a step in progress at reset_window() began where the window does
+        began = max(0.0, now - rec["wall"] * count - self._t_reset)
+        for k in range(count):
+            self._append(rec, began + k * rec["wall"])
         self.total_steps += count
-        if self._telemetry is not None:
+        tm = self._telemetry
+        if tm is not None:
             # registry publish of the closed record (host dict ops; the
             # manager also derives the live MFU gauge from the wall)
-            self._telemetry.publish_step(
-                self._t_prefix, rec, count=count, gauge_names=self._gauge_names
-            )
+            keep = self._t_phases
+            if keep is not None:
+                # a phase the plane is not told of is its `other`: what it has still adds up to the wall
+                hidden = sum(v for k, v in rec.items() if k in self.phases and k not in keep)
+                rec = {k: v for k, v in rec.items() if k in keep or k in self._gauge_names}
+                rec["other"] += hidden
+            tm.publish_step(self._t_prefix, rec, count=count, gauge_names=self._gauge_names)
         self._pending = {}
         self._pending_gauges = {}
+
+    def _append(self, rec: Dict[str, float], at: float) -> None:
+        """One closed step into the ring."""
+        if self._rows is None or len(self._cols) < len(self._gauge_names) + self._timed:
+            new = [g for g in sorted(self._gauge_names) if g not in self._cols]
+            self._cols += new
+            if self._rows is None:
+                self._rows = np.zeros((self.window, len(self._cols)))
+            else:  # a gauge first set after steps were recorded: it read nothing in them
+                self._rows = np.concatenate([self._rows, np.zeros((self.window, len(new)))], axis=1)
+        get = rec.get
+        row = [get(c, 0.0) for c in self._cols]
+        row[0] = at
+        self._rows[self._n % self.window] = row
+        self._n += 1
 
     def reset_window(self) -> None:
         """Drop recorded steps and zero the event counters (keep the
         wall anchor); the next ``summary()`` covers only what was
         recorded after this call."""
-        self.records.clear()
+        self._n = 0
+        self._t_reset = time.perf_counter()
         self.counts = dict.fromkeys(self.counts, 0)
 
     # -- reporting --------------------------------------------------------
-    def summary(self, last_n: Optional[int] = None) -> Dict[str, float]:
-        """Mean per-step milliseconds per phase over the last ``last_n``
-        recorded steps (default: the whole window), plus ``steps_per_s``
-        derived from the mean step wall; and, so that a slow stretch of
-        the window does not vanish in a mean, ``<phase>_ms_p50`` and
-        ``<phase>_ms_p95`` for every phase, sub-phase, ``other`` and
-        ``wall`` (and ``host``: wall minus the ``blocked_on`` phase)."""
-        recs: List[Dict[str, float]] = list(self.records)
+    def _held(self, last_n: Optional[int] = None) -> Dict[str, np.ndarray]:
+        """The steps held, oldest first, a column a name (the last
+        ``last_n`` of them)."""
+        if self._rows is None:
+            rows = np.zeros((0, len(self._cols)))
+        elif self._n <= self.window:
+            rows = self._rows[: self._n]
+        else:
+            i = self._n % self.window
+            rows = np.concatenate([self._rows[i:], self._rows[:i]])
         if last_n is not None:
-            recs = recs[-int(last_n):]
+            rows = rows[-int(last_n):] if int(last_n) > 0 else rows[:0]
+        return {c: rows[:, i] for i, c in enumerate(self._cols)}
+
+    @property
+    def records(self) -> List[Dict[str, float]]:
+        """The steps held, oldest first, a dict a step (phases,
+        sub-phases, ``other``, ``wall`` in seconds; gauges as set)."""
+        held = self._held()
+        names = [c for c in self._cols if c != "at"]
+        return [{c: float(held[c][k]) for c in names} for k in range(len(held["wall"]))]
+
+    @staticmethod
+    def _stalled(wall: np.ndarray):
+        """(median wall, mask of the steps that are stalls)."""
+        p50 = float(np.percentile(wall, 50)) if len(wall) else 0.0
+        return p50, (wall > STALL_FACTOR * p50) if p50 > 0 else np.zeros(len(wall), bool)
+
+    def summary(self, last_n: Optional[int] = None) -> Dict[str, float]:
+        """Mean per-step milliseconds per phase over every step since
+        :meth:`reset_window` (the last ``window`` of them, ``steps_dropped``
+        saying how many fell out; or the last ``last_n``), plus
+        ``steps_per_s`` derived from the mean step wall; and, so that a
+        slow stretch of the window does not vanish in a mean,
+        ``<phase>_ms_p50`` and ``<phase>_ms_p95`` for every phase,
+        sub-phase, ``other`` and ``wall`` (and ``host``: wall minus the
+        ``blocked_on`` phase), ``wall_ms_max`` (and the ``blocked_on``
+        phase's), and the stalls: ``stall_steps`` whose wall is over
+        ``STALL_FACTOR`` medians, ``stall_ms`` their summed excess over
+        the median, ``stall_first_at_s`` seconds after
+        :meth:`reset_window` (-1: none).  Scalars only; :meth:`stalls`
+        names the steps."""
+        held = self._held(last_n)
+        n = len(held["wall"])
         timed = self.phases + self.sub_phases
         out = {f"{p}_ms": 0.0 for p in timed}
         out["wall_ms"] = 0.0
-        out["steps"] = len(recs)
+        out["steps"] = n
+        out["steps_dropped"] = max(0, self._n - self.window)
         out["steps_per_s"] = 0.0
+        out.update(stall_steps=0, stall_ms=0.0, stall_first_at_s=-1.0)
         for g in sorted(self._gauge_names):
             out[g] = 0.0
         out.update(self.counts)
         if self.comm_strategy is not None:
             out["comm_strategy"] = self.comm_strategy
             out["comm_bytes_per_step"] = self.comm_bytes
-        if not recs:
+        if not n:
             return out
-        n = len(recs)
-        per_step = {p: [r.get(p, 0.0) for r in recs] for p in timed + ("wall",)}
         for p in timed:
-            out[f"{p}_ms"] = round(sum(per_step[p]) * 1000.0 / n, 3)
+            out[f"{p}_ms"] = round(float(held[p].sum()) * 1000.0 / n, 3)
+        per_step = {p: held[p] for p in timed + ("wall",)}
         if self.blocked_on is not None:
-            per_step["host"] = [r.get("wall", 0.0) - r.get(self.blocked_on, 0.0) for r in recs]
+            per_step["host"] = held["wall"] - held[self.blocked_on]
         for p, vals in per_step.items():
             for q, v in zip((50, 95), np.percentile(vals, (50, 95))):
                 out[f"{p}_ms_p{q}"] = round(float(v) * 1000.0, 3)
+        for p in ("wall", self.blocked_on):
+            if p is not None:
+                out[f"{p}_ms_max"] = round(float(held[p].max()) * 1000.0, 3)
+        p50, over = self._stalled(held["wall"])
+        if over.any():
+            out["stall_steps"] = int(over.sum())
+            out["stall_ms"] = round(float((held["wall"][over] - p50).sum()) * 1000.0, 3)
+            out["stall_first_at_s"] = round(float(held["at"][over][0]), 3)
         for g in sorted(self._gauge_names):
-            out[g] = round(sum(r.get(g, 0.0) for r in recs) / n, 3)
-        wall = sum(r.get("wall", 0.0) for r in recs) / n
+            out[g] = round(float(held[g].sum()) / n, 3) if g in held else 0.0
+        wall = float(held["wall"].sum()) / n
         out["wall_ms"] = round(wall * 1000.0, 3)
         out["steps_per_s"] = round(1.0 / wall, 3) if wall > 0 else 0.0
         return out
+
+    def stalls(self) -> List[Dict[str, float]]:
+        """The window's stalls by name, the ``STALLS_KEPT`` longest in
+        step order: ``step`` (the ordinal since the timeline began: the
+        serving engine's step number), ``at_s`` since
+        :meth:`reset_window`, ``wall_ms`` and every phase's and
+        sub-phase's ``<name>_ms`` of that step."""
+        held = self._held()
+        wall = held["wall"]
+        _, over = self._stalled(wall)
+        idx = np.flatnonzero(over)
+        idx = np.sort(idx[np.argsort(-wall[idx], kind="stable")[:STALLS_KEPT]])
+        first = self.total_steps - len(wall) + 1
+        return [{"step": first + int(k), "at_s": round(float(held["at"][k]), 3),
+                 "wall_ms": round(float(wall[k]) * 1e3, 3),
+                 **{f"{p}_ms": round(float(held[p][k]) * 1e3, 3) for p in self.phases + self.sub_phases}}
+                for k in idx]
 
     def format_summary(self, last_n: Optional[int] = None) -> str:
         """One log line: phase means and their share of the step wall."""

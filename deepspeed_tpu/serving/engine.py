@@ -58,6 +58,11 @@ from deepspeed_tpu.serving.staging import PackedLayout
 from deepspeed_tpu.serving.watchdog import ServingWatchdog
 from deepspeed_tpu.utils.logging import log_dist, logger
 
+# steps the engine's StepTimeline holds: ``stats()`` and the timeline's
+# summary cover every step since ``timeline.reset_window()`` up to this
+# many (``steps_dropped`` counts what fell out); 8 bytes a number a step
+TIMELINE_STEPS = 32768
+
 
 class ServingEngine:
     def __init__(self, engine, config: Any = None, **overrides):
@@ -278,15 +283,18 @@ class ServingEngine:
 
         from deepspeed_tpu.runtime.overlap.timeline import StepTimeline
 
-        # stage/dispatch/wait are timed inside prefill and decode (each
-        # summed over the step's two programs); ``wait`` is the blocking
-        # read, so wall - wait is the step's host overhead.  The phases
-        # also stand in the profiler's trace as ds.serve.* spans
-        # (docs/telemetry.md)
+        # stage/dispatch/wait/note are timed inside prefill and decode
+        # (each summed over the step's two programs); ``wait`` is the
+        # blocking read, so wall - wait is the step's host overhead.  The
+        # phases also stand in the profiler's trace as ds.serve.* spans,
+        # every instant of a step under exactly one leaf of them
+        # (docs/telemetry.md).  The summary holds the whole of a window
+        # of TIMELINE_STEPS steps: 17 minutes of 31 ms steps
         self.timeline = StepTimeline(
-            enabled=True, phases=("sched", "prefill", "decode"),
-            sub_phases=("stage", "dispatch", "wait"), blocked_on="wait", prefix="serve",
+            enabled=True, window=TIMELINE_STEPS, phases=("sweep", "sched", "prefill", "decode", "commit"),
+            sub_phases=("stage", "dispatch", "wait", "note"), blocked_on="wait", prefix="serve",
         )
+        self._stall_logged = 0  # the last step stats() has logged as a stall
 
         # telemetry (docs/telemetry.md): attach to whatever plane the
         # process armed (the train engine's configure(), or an explicit
@@ -303,7 +311,11 @@ class ServingEngine:
         self._tel_tpot = self.telemetry.histogram("serving/tpot_ms")
         self._tel_queue_wait = self.telemetry.histogram("serving/queue_wait_ms")
         if self.telemetry.collect or self.telemetry.tracer.enabled:
-            self.timeline.attach_telemetry(self.telemetry, prefix="serving")
+            # the plane keeps the phases it has: sweep, commit and note
+            # are on the profiler's clock and in the summary only
+            self.timeline.attach_telemetry(
+                self.telemetry, prefix="serving",
+                phases=("sched", "prefill", "decode", "stage", "dispatch", "wait"))
         self.scheduler.on_event = self._on_request_event
 
         from deepspeed_tpu.analysis.sanitizer import maybe_from_config
@@ -999,18 +1011,19 @@ class ServingEngine:
         tl = self.timeline
         compiles0 = self.prefill_compiles + self.decode_compiles
         t0 = time.monotonic()
-        if self._paged:
-            # TTL sweep BEFORE admission: pages a cold session releases
-            # this tick are available to the requests admitted in it
-            self.pool.sweep(t0)
-        if self._tiers is not None:
-            # migration tick BEFORE admission: hinted prefetch pages
-            # upcoming admits/rebinds back to T0 so their prefill chunk
-            # runs against warm pages; watermark demotion batches the
-            # device_get traffic at the step boundary
-            self._tiers.tick(
-                t0, hints=self.scheduler.upcoming_hints(
-                    self._tiers.prefetch_ahead))
+        with tl.phase("sweep"):
+            if self._paged:
+                # TTL sweep BEFORE admission: pages a cold session releases
+                # this tick are available to the requests admitted in it
+                self.pool.sweep(t0)
+            if self._tiers is not None:
+                # migration tick BEFORE admission: hinted prefetch pages
+                # upcoming admits/rebinds back to T0 so their prefill chunk
+                # runs against warm pages; watermark demotion batches the
+                # device_get traffic at the step boundary
+                self._tiers.tick(
+                    t0, hints=self.scheduler.upcoming_hints(
+                        self._tiers.prefetch_ahead))
         with tl.phase("sched"):
             plan = self.scheduler.tick(t0, self._step_count, admit=admit)
         with tl.phase("prefill"):
@@ -1020,27 +1033,30 @@ class ServingEngine:
             decoding = self.scheduler.decoding()
             if decoding:
                 self._run_decode(decoding)
-        tl.set_gauge("queue_depth", self.scheduler.queue_depth)
-        tl.set_gauge("live_slots", self.pool.live_slots)
-        tl.end_step()
-        # measured service rate for the admission controller (EWMA over
-        # non-empty, non-compile steps — a jit trace in the wall would
-        # poison the TTFT estimate into shedding everything for minutes;
-        # the registry window supersedes the EWMA when armed)
         wall = time.monotonic() - t0
-        if (plan.prefill_jobs or decoding) and (
-            self.prefill_compiles + self.decode_compiles == compiles0
-        ):
-            self._step_wall_ewma = (
-                wall if self._step_wall_ewma is None
-                else 0.2 * wall + 0.8 * self._step_wall_ewma
-            )
-        # retirements this step become durable at the boundary
-        self._journal_commit()
-        if self._tiers is not None:
-            # the step's wall window feeds the swap-hide overlap ratio
-            self._tiers.note_step(t0, time.monotonic())
-        self._publish_kvcache()
+        # the step's books, under one span: gauges, service rate, journal
+        with tl.phase("commit"):
+            tl.set_gauge("queue_depth", self.scheduler.queue_depth)
+            tl.set_gauge("live_slots", self.pool.live_slots)
+            # measured service rate for the admission controller (EWMA over
+            # non-empty, non-compile steps — a jit trace in the wall would
+            # poison the TTFT estimate into shedding everything for minutes;
+            # the registry window supersedes the EWMA when armed)
+            if (plan.prefill_jobs or decoding) and (
+                self.prefill_compiles + self.decode_compiles == compiles0
+            ):
+                self._step_wall_ewma = (
+                    wall if self._step_wall_ewma is None
+                    else 0.2 * wall + 0.8 * self._step_wall_ewma
+                )
+            # retirements this step become durable at the boundary
+            self._journal_commit()
+            if self._tiers is not None:
+                # the step's wall window feeds the swap-hide overlap ratio
+                self._tiers.note_step(t0, time.monotonic())
+            self._publish_kvcache()
+        # the step's record holds its own commit
+        tl.end_step()
         return self.scheduler.has_work()
 
     def drain(self, max_steps: Optional[int] = None) -> Dict[int, Request]:
@@ -1297,8 +1313,16 @@ class ServingEngine:
         publish (step-boundary granularity; host dict reads only)."""
         if not self._paged:
             return
-        st = self.pool.stats()
         tm = self.telemetry
+        tracer = tm.tracer if tm.tracer.enabled else None
+        if not tm.collect and tracer is None:
+            # nobody reads it: no pool.stats() a step, and the two
+            # watermarks move on so that a tracer armed later shows
+            # what happened since, not since the engine began
+            self._kv_evt_seen = {"evictions": self.pool.evictions,
+                                 "session_spills": self.pool.sessions.spills}
+            return
+        st = self.pool.stats()
         if tm.collect:
             for key in ("pages_live", "pages_free", "hit_rate", "tokens_saved",
                         "cow_copies", "evictions", "session_rebinds",
@@ -1309,7 +1333,6 @@ class ServingEngine:
             for key, val in st["tiers"].items():
                 if isinstance(val, (int, float)):
                     tm.gauge(f"kvcache/tier/{key}").set(float(val))
-        tracer = tm.tracer if tm.tracer.enabled else None
         for key, name in (("evictions", "kvcache_evict"),
                           ("session_spills", "kvcache_spill")):
             delta = int(st[key]) - self._kv_evt_seen[key]
@@ -1337,7 +1360,7 @@ class ServingEngine:
         tracer = self.telemetry.tracer if self.telemetry.tracer.enabled else None
         t0 = tracer.now() if tracer is not None else 0.0
         guard = san.transfer.guard("serving.prefill") if san is not None else nullcontext()
-        with tl.phase("prefill.dispatch"), guard:
+        with tl.phase("prefill.dispatch", request=r.request_id, start=job.start, len=job.length), guard:
             tl.count("programs")
             first, *pools = fn(self.engine.params, staged, *self._pool_args())
         self.pool.swap(*pools)
@@ -1347,25 +1370,27 @@ class ServingEngine:
         # honest; the value is the first generated token on final chunks
         with tl.phase("prefill.wait"):
             tok = jax.device_get(first)
-        tok = int(tok if self._family_forward is None else self._note_aux(tok, decode=False))
-        now = time.monotonic()
-        if self._paged and job.final:
-            # the whole prompt's KV is paged in: learn it as a shared
-            # prefix (before note_prefill — a 1-token budget can retire
-            # the request, releasing the slot, inside that call)
-            self.pool.learn_prefix(r, now=now)
-        if tracer is not None:
-            # chunk-level detail on the request's own lane, between its
-            # queue and prefill spans (the fenced read above makes the
-            # span a real device-work window, not dispatch overhead)
-            tracer.add_span(
-                "prefill_chunk", "serving.request", t0, now,
-                pid=_telemetry.PID_REQUESTS, tid=r.request_id,
-                args={"request": r.request_id, "start": job.start,
-                      "len": job.length, "final": job.final},
-                tid_name=f"request {r.request_id}",
-            )
-        self.scheduler.note_prefill(job, tok, now=now, step=self._step_count)
+        # the hand-back: from the read's return to the scheduler's note
+        with tl.phase("prefill.note"):
+            tok = int(tok if self._family_forward is None else self._note_aux(tok, decode=False))
+            now = time.monotonic()
+            if self._paged and job.final:
+                # the whole prompt's KV is paged in: learn it as a shared
+                # prefix (before note_prefill — a 1-token budget can retire
+                # the request, releasing the slot, inside that call)
+                self.pool.learn_prefix(r, now=now)
+            if tracer is not None:
+                # chunk-level detail on the request's own lane, between its
+                # queue and prefill spans (the fenced read above makes the
+                # span a real device-work window, not dispatch overhead)
+                tracer.add_span(
+                    "prefill_chunk", "serving.request", t0, now,
+                    pid=_telemetry.PID_REQUESTS, tid=r.request_id,
+                    args={"request": r.request_id, "start": job.start,
+                          "len": job.length, "final": job.final},
+                    tid_name=f"request {r.request_id}",
+                )
+            self.scheduler.note_prefill(job, tok, now=now, step=self._step_count)
 
     def _run_decode(self, decoding) -> None:
         faults.check("serving.decode")
@@ -1384,11 +1409,12 @@ class ServingEngine:
         self._decode_steps += 1
         with tl.phase("decode.wait"):
             out = jax.device_get(nxt)
-        out = np.asarray(out if self._family_forward is None else self._note_aux(out, decode=True))
-        now = time.monotonic()
-        self.scheduler.note_decode(
-            {r.slot: int(out[r.slot]) for r in decoding}, now, self._step_count
-        )
+        with tl.phase("decode.note"):
+            out = np.asarray(out if self._family_forward is None else self._note_aux(out, decode=True))
+            now = time.monotonic()
+            self.scheduler.note_decode(
+                {r.slot: int(out[r.slot]) for r in decoding}, now, self._step_count
+            )
 
     def _prefill_inputs(self, job: PrefillJob) -> np.ndarray:
         """One chunk's packed inputs.  The slot's pending copy-on-write
@@ -1548,6 +1574,10 @@ class ServingEngine:
         # the paged per-head pool: kv_write_form, prefill_attend_form)
         out.update(self._trace_notes)
         out.update(self.timeline.summary())
+        for stall in self.timeline.stalls() if out["stall_steps"] else ():
+            if stall["step"] > self._stall_logged:
+                self._stall_logged = stall["step"]
+                logger.info(f"serving: stalled step {stall}")
         if out.get("programs"):
             # host→device transfers a program since the timeline's last
             # reset: 1.0, each program's inputs being one packed array

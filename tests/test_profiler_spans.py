@@ -28,12 +28,17 @@ from deepspeed_tpu.serving import ServingEngine
 pytestmark = pytest.mark.telemetry
 
 SERVE_VOCABULARY = {
-    "ds.serve.step", "ds.serve.sched",
-    "ds.serve.prefill", "ds.serve.prefill.stage", "ds.serve.prefill.dispatch", "ds.serve.prefill.wait",
-    "ds.serve.decode", "ds.serve.decode.stage", "ds.serve.decode.dispatch", "ds.serve.decode.wait",
+    "ds.serve.step", "ds.serve.sweep", "ds.serve.sched", "ds.serve.commit",
+    "ds.serve.prefill", "ds.serve.prefill.stage", "ds.serve.prefill.dispatch", "ds.serve.prefill.wait", "ds.serve.prefill.note",
+    "ds.serve.decode", "ds.serve.decode.stage", "ds.serve.decode.dispatch", "ds.serve.decode.wait", "ds.serve.decode.note",
 }
+# the spans under which every instant of a step lies exactly once
+SERVE_LEAVES = SERVE_VOCABULARY - {"ds.serve.step", "ds.serve.prefill", "ds.serve.decode"}
 SERVE_PHASES = dict(phases=("sched", "prefill", "decode"), sub_phases=("stage", "dispatch", "wait"),
                     blocked_on="wait", prefix="serve")
+# the serving engine's own since the hand-back, the sweep and the commit are timed
+ENGINE_PHASES = dict(phases=("sweep", "sched", "prefill", "decode", "commit"), sub_phases=("stage", "dispatch", "wait", "note"),
+                     blocked_on="wait", prefix="serve")
 
 
 # ---------------------------------------------------------------------------
@@ -122,6 +127,118 @@ class TestSummaryPercentiles:
 
 
 # ---------------------------------------------------------------------------
+# summary(): the whole window, and its stalls
+# ---------------------------------------------------------------------------
+
+
+def _engine_step(tl, wait=0.030, sweep=0.0002, sched=0.0001, note=0.0003, commit=0.0004, other=0.0):
+    """One decode-only step as the engine's phases nest, its wall what
+    was noted (the timeline reads no clock for it)."""
+    stage, dispatch = 0.0006, 0.0005
+    tl.note("sweep", sweep), tl.note("sched", sched), tl.note("commit", commit), tl.note("other", other)
+    tl.note("decode", stage + dispatch + wait + note)
+    tl.note("stage", stage), tl.note("dispatch", dispatch), tl.note("wait", wait), tl.note("note", note)
+    tl._last_boundary = None
+    tl.end_step()
+
+
+class TestTheWholeWindow:
+    @pytest.mark.parametrize("steps,window,held", [(700, 32768, 700), (1610, 32768, 1610), (1610, 1024, 1024), (513, 512, 512)])
+    def test_every_step_since_the_reset_is_reported_or_counted_as_dropped(self, steps, window, held):
+        tl = StepTimeline(window=window, **ENGINE_PHASES)
+        for _ in range(37):  # before the window opens
+            _engine_step(tl, wait=0.5)
+        tl.reset_window()
+        for k in range(steps):
+            _engine_step(tl, wait=0.030 + 1e-6 * k)
+        s = tl.summary()
+        assert (s["steps"], s["steps_dropped"]) == (held, steps - held) and tl.total_steps == 37 + steps
+        # the percentiles are the held steps', none of the 500 ms ones from before the reset
+        first = steps - held
+        assert s["wait_ms_p50"] == pytest.approx(30.0 + 1e-3 * (first + (held - 1) / 2), abs=2e-3)
+        assert s["wait_ms_max"] == pytest.approx(30.0 + 1e-3 * (steps - 1), abs=2e-3) and s["wall_ms_max"] < 40
+        recs = tl.records
+        assert len(recs) == held and recs[0]["wait"] == pytest.approx(0.030 + 1e-6 * first)
+        assert recs[-1]["wait"] == pytest.approx(0.030 + 1e-6 * (steps - 1))
+        assert tl.summary(last_n=10)["steps"] == 10 and tl.summary(last_n=10)["wait_ms_p50"] > s["wait_ms_p50"]
+
+    def test_the_new_phases_add_up_with_the_old_ones_to_the_wall(self):
+        tl = StepTimeline(**ENGINE_PHASES)
+        for _ in range(5):
+            _engine_step(tl, other=0.0002)
+        s = tl.summary()
+        for q in ("", "_p50", "_p95"):
+            assert sum(s[f"{p}_ms{q}"] for p in ("sweep", "sched", "prefill", "decode", "commit", "other")) == pytest.approx(s[f"wall_ms{q}"], abs=2e-3)
+            # inside decode: the three of before and the hand-back
+            assert sum(s[f"{p}_ms{q}"] for p in ("stage", "dispatch", "wait", "note")) == pytest.approx(s[f"decode_ms{q}"], abs=2e-3)
+        assert (s["sweep_ms_p50"], s["commit_ms_p50"], s["note_ms_p50"], s["other_ms_p50"]) == (0.2, 0.4, 0.3, 0.2)
+        assert s["host_ms_p50"] == pytest.approx(s["wall_ms_p50"] - 30.0, abs=2e-3)
+        assert set(tl.records[0]) == {"sweep", "sched", "prefill", "decode", "commit", "stage", "dispatch", "wait", "note", "other", "wall"}
+
+    def test_a_planted_step_of_five_medians_is_one_stall(self):
+        tl = StepTimeline(**ENGINE_PHASES)
+        assert tl.summary()["stall_first_at_s"] == -1.0 and tl.stalls() == []
+        for k in range(700):
+            # one step in four carries a chunk: 1.8 x the median, an ordinary step
+            _engine_step(tl, wait=0.160 if k == 400 else 0.055 if k % 4 == 0 else 0.030)
+        s = tl.summary()
+        p50 = s["wall_ms_p50"]
+        assert p50 == pytest.approx(32.1, abs=1e-2) and s["wall_ms_max"] == pytest.approx(162.1, abs=1e-2)
+        assert s["stall_steps"] == 1 and s["stall_ms"] == pytest.approx(162.1 - p50, abs=1e-2) and s["stall_first_at_s"] != -1.0
+        assert all(isinstance(v, (int, float, str)) for v in s.values())  # the summary stays scalars
+        (stall,) = tl.stalls()
+        assert stall["step"] == 401 and stall["wall_ms"] == pytest.approx(162.1) and stall["at_s"] == s["stall_first_at_s"]
+        # where the step's time went: all of it inside the blocking read
+        assert stall["wait_ms"] == pytest.approx(160.0) and stall["note_ms"] == pytest.approx(0.3) and stall["commit_ms"] == pytest.approx(0.4)
+        assert {f"{p}_ms" for p in ("sweep", "sched", "prefill", "decode", "commit", "other", "stage", "dispatch", "wait", "note")} <= set(stall)
+        # a window without it has none; the counters start afresh with the window
+        assert tl.summary(last_n=100)["stall_steps"] == 0
+        tl.reset_window()
+        assert tl.summary()["stall_steps"] == 0 and tl.stalls() == []
+
+    def test_stalls_names_the_longest_sixteen_in_step_order(self):
+        tl = StepTimeline(**ENGINE_PHASES)
+        for k in range(400):
+            _engine_step(tl, wait=0.200 + 0.001 * k if k % 20 == 0 else 0.030)
+        s = tl.summary()
+        assert s["stall_steps"] == 20
+        stalls = tl.stalls()
+        assert [x["step"] for x in stalls] == [1 + 20 * j for j in range(4, 20)]
+
+    def test_the_plane_is_handed_the_phases_it_is_told_and_no_others(self):
+        class Plane:
+            class tracer:
+                enabled, spans = True, []
+                now = staticmethod(lambda: 0.0)
+                add_span = classmethod(lambda cls, name, *a, **kw: cls.spans.append(name))
+
+            published = []
+
+            def publish_step(self, prefix, rec, count=1, gauge_names=()):
+                self.published.append(set(rec))
+                self.last = rec
+
+        tl = StepTimeline(**ENGINE_PHASES)
+        plane = Plane()
+        tl.attach_telemetry(plane, prefix="serving", phases=("sched", "prefill", "decode", "stage", "dispatch", "wait"))
+        for name in ("sweep", "sched", "decode", "commit"):
+            with tl.phase(name):
+                if name == "decode":
+                    with tl.phase("decode.wait"), tl.phase("decode.note"):
+                        pass
+        tl.set_gauge("queue_depth", 3)
+        tl.end_step()
+        assert Plane.tracer.spans == ["serving/sched", "serving/decode"]
+        assert Plane.published == [{"sched", "prefill", "decode", "stage", "dispatch", "wait", "other", "wall", "queue_depth"}]
+        # what the plane is not told of is its `other`: the phases it has still add up to the wall
+        rec, got = tl.records[0], plane.last
+        assert got["other"] == pytest.approx(rec["other"] + rec["sweep"] + rec["commit"]) and rec["sweep"] > 0 and rec["commit"] > 0
+        assert sum(got[p] for p in ("sched", "prefill", "decode", "other")) == pytest.approx(got["wall"])
+        # the summary has them all the same
+        assert tl.summary()["commit_ms_p50"] >= 0 and "sweep_ms_p50" in tl.summary() and "note_ms_p50" in tl.summary()
+
+
+# ---------------------------------------------------------------------------
 # the ds.* spans in a jax.profiler trace
 # ---------------------------------------------------------------------------
 
@@ -132,7 +249,7 @@ class _Trace:
     ``ds.*`` events as ``(name, start_ns, end_ns, step or None)``."""
 
     def __init__(self, path):
-        self.path, self.spans, self.args = str(path), [], {}
+        self.path, self.spans, self.args, self.every = str(path), [], {}, {}
 
     def __enter__(self):
         opts = jax.profiler.ProfileOptions()
@@ -150,6 +267,7 @@ class _Trace:
                         if e.name.startswith("ds."):
                             stats = dict(e.stats)
                             self.args.setdefault(e.name, stats)  # the first event's arguments
+                            self.every.setdefault(e.name, []).append((e.start_ns, stats))  # and every event's
                             step = stats.get("step")
                             self.spans.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
                                                None if step is None else int(step)))
@@ -190,7 +308,7 @@ class TestSpansInTheProfilersTrace:
         srv = serving
         before = srv._step_count
         with _Trace(tmp_path) as tr:
-            srv.submit(np.arange(1, 12, dtype=np.int32), max_new_tokens=3)
+            rid = srv.submit(np.arange(1, 12, dtype=np.int32), max_new_tokens=3)
             srv.drain()
         assert {s[0] for s in tr.spans} == SERVE_VOCABULARY
         steps = [s for s in tr.spans if s[0] == "ds.serve.step"]
@@ -199,13 +317,26 @@ class TestSpansInTheProfilersTrace:
         assert all(s[3] is None for s in tr.spans if s[0] != "ds.serve.step")
         # every other span lies inside one step's span ...
         assert sum(len(tr.inside(s)) for s in steps) == len(tr.spans) - len(steps)
-        # ... and a program's three sub-phases inside its phase, in order
+        # ... and a program's four sub-phases inside its phase, in order (prefill: a chunk after a chunk)
         for which in ("prefill", "decode"):
             for outer in (s for s in tr.spans if s[0] == f"ds.serve.{which}"):
                 inner = [s[0] for s in tr.inside(outer)]
-                assert inner in ([], [f"ds.serve.{which}.{p}" for p in ("stage", "dispatch", "wait")])
-        first = tr.inside(steps[0])
-        assert [s[0] for s in first][:2] == ["ds.serve.sched", "ds.serve.prefill"]
+                one = [f"ds.serve.{which}.{p}" for p in ("stage", "dispatch", "wait", "note")]
+                assert inner == one * (len(inner) // 4)
+        for step in steps:
+            inside = tr.inside(step)
+            assert [s[0] for s in inside][:3] == ["ds.serve.sweep", "ds.serve.sched", "ds.serve.prefill"]
+            assert inside[-1][0] == "ds.serve.commit"
+            # the leaves follow one another and none overlaps the next: every instant under at most one
+            leaves = [s for s in inside if s[0] in SERVE_LEAVES]
+            assert all(a[2] <= b[1] for a, b in zip(leaves, leaves[1:]))
+        assert {s[0] for s in tr.spans if s[0] in SERVE_LEAVES} == SERVE_LEAVES
+        # one request's chunks share an identifier: the chunk's span names its request and where it starts
+        chunks = [{k: int(a[k]) for k in ("request", "start", "len")} for _, a in sorted(tr.every["ds.serve.prefill.dispatch"])]
+        assert {c["request"] for c in chunks} == {rid} and all(0 < c["len"] <= 8 for c in chunks)
+        # they tile the prompt from where the prefix cache let it begin to its end
+        assert all(a["start"] + a["len"] == b["start"] for a, b in zip(chunks, chunks[1:])) and chunks[-1]["start"] + chunks[-1]["len"] == 11
+        assert "request" not in tr.args["ds.serve.decode.dispatch"]
 
     def test_nothing_is_written_of_a_step_taken_while_no_trace_runs(self, serving, tmp_path):
         srv = serving
@@ -217,6 +348,57 @@ class TestSpansInTheProfilersTrace:
         # the timeline's own bookkeeping does not need the profiler
         s = srv.timeline.summary()
         assert s["wait_ms_p50"] > 0 and s["stage_ms_p50"] > 0 and s["host_ms_p95"] >= s["host_ms_p50"] > 0
+
+    def test_the_engine_reports_every_step_of_a_window_and_its_stalls_once(self, serving, monkeypatch):
+        from deepspeed_tpu.serving import engine as engine_mod
+
+        srv = serving
+        srv.timeline.reset_window()
+        srv.submit(np.arange(1, 12, dtype=np.int32), max_new_tokens=3)
+        for k in range(600):  # past the 512 the summary used to hold; most of them idle
+            if k == 300:
+                srv.timeline.note("other", 5.0)  # a step that took five seconds, planted
+            srv.step()
+        s = srv.stats()
+        assert (s["steps"], s["steps_dropped"]) == (600, 0) and srv.timeline.window == engine_mod.TIMELINE_STEPS
+        for key in ("note_ms_p50", "commit_ms_p50", "sweep_ms_p50", "dispatch_ms_p50", "wall_ms_max", "wait_ms_max", "stall_ms"):
+            assert isinstance(s[key], float), key
+        assert s["stall_steps"] >= 1 and s["stall_ms"] > 4000 and s["stall_first_at_s"] >= 0 and s["wall_ms_max"] >= 5000
+        # stats() names each stall once, at info; the summary stays scalars
+        lines = []
+        monkeypatch.setattr(engine_mod.logger, "info", lines.append)
+        srv._stall_logged = 0
+        srv.stats(), srv.stats()
+        assert srv.timeline.total_steps == srv._step_count  # a stall's `step` is the engine's step number
+        planted = [line for line in lines if "stalled step" in line and f"'step': {srv._step_count - 299}," in line]
+        assert len(planted) == 1 and "'other_ms': 5" in planted[0]
+        assert len(lines) == len(srv.timeline.stalls())
+
+    def test_no_pool_stats_a_step_while_nobody_collects(self, serving, monkeypatch):
+        srv = serving
+
+        class Quiet:  # a plane nobody armed (the process's own may have been, by another test file of this worker)
+            collect = False
+            tracer = type("T", (), {"enabled": False})
+
+        monkeypatch.setattr(srv, "telemetry", Quiet)
+        calls = []
+        stats = srv.pool.stats
+        monkeypatch.setattr(srv.pool, "stats", lambda: calls.append(1) or stats())
+        srv.pool.evictions += 2  # as if two pages had been evicted since the last publish
+        for _ in range(3):
+            srv.step()  # idle steps: sweep, sched, commit
+        assert calls == []
+        # the watermarks of the eviction / spill instants moved on all the same
+        assert srv._kv_evt_seen == {"evictions": srv.pool.evictions, "session_spills": srv.pool.sessions.spills}
+        class Collecting:  # a plane that collects: the gauges are read off the pool's stats again
+            collect, gauges = True, {}
+            tracer = type("T", (), {"enabled": False})
+            gauge = classmethod(lambda cls, name: type("G", (), {"set": lambda self, v: cls.gauges.__setitem__(name, v)})())
+
+        monkeypatch.setattr(srv, "telemetry", Collecting)
+        srv._publish_kvcache()
+        assert calls == [1] and Collecting.gauges["kvcache/pages_free"] == srv.pool.pages_free
 
     def test_train_batch_spans(self, tmp_path):
         engine, batch = _train_engine()
