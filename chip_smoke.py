@@ -226,7 +226,10 @@ def check_flash_attention(s: Smoke) -> Dict[str, float]:
 def check_flash_decode_paged(s: Smoke, mcfg, kv_dtype) -> float:
     """``flash_decode_paged`` vs gather + ``cache_attention(use_kernel=False)``
     on a pool written through the real paged write, at the server's
-    shapes: scattered pages, a different fill per slot."""
+    shapes: scattered pages, a different fill per slot, and one slot
+    that does not decode — the kernel walks the work list of the others'
+    filled pages, and that slot reads 0."""
+    from deepspeed_tpu.ops.kernels.flash_decode import decode_paged_supported, paged_work_list
     from deepspeed_tpu.ops.transformer.inference import (
         init_kv_cache, paged_cache_attention, paged_cache_write,
     )
@@ -237,6 +240,8 @@ def check_flash_decode_paged(s: Smoke, mcfg, kv_dtype) -> float:
     table = (1 + rng.permutation(B * P)).reshape(B, P).astype(np.int32)  # page 0 is the garbage page
     fill = rng.integers(1, P * s.page_len, (B,)).astype(np.int32)
     fill[0] = P * s.page_len - 1  # one slot full to the last row
+    live = np.ones((B,), bool)
+    live[-1] = B == 1             # and one, where there are several, that does not decode
     k_pool, v_pool = (jax.tree.map(lambda a: a[0], c)
                       for c in init_kv_cache(1, 1 + B * P, H, s.page_len, d, kv_dtype))
     kk, kv_, kq = jax.random.split(jax.random.PRNGKey(s.seed + 1), 3)
@@ -247,13 +252,16 @@ def check_flash_decode_paged(s: Smoke, mcfg, kv_dtype) -> float:
     q = jax.random.normal(kq, (B, H, 1, d), jnp.float32).astype(jnp.bfloat16)
 
     def attend(use_kernel):
-        return jax.jit(lambda *a: paged_cache_attention(*a, use_kernel=use_kernel))(
-            q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(fill))
+        def f(q, k, v, t, p, m):
+            return paged_cache_attention(q, k, v, t, p, use_kernel=use_kernel, work=paged_work_list(p, m, s.page_len, P))
+        return jax.jit(f)(q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(fill), jnp.asarray(live))
 
     got = attend(True)
     with jax.default_matmul_precision("highest"):
         want = attend(False)
-    err = _max_err(got, want)
+    err = _max_err(got[live], want[live])
+    if decode_paged_supported(B, H, P, s.page_len, d):  # the gather + lax form attends every row
+        check(not np.asarray(got[~live], np.float32).any(), "flash_decode_paged: a row its work list does not visit reads other than 0")
     name = "int8" if kv_dtype == "int8" else jnp.dtype(kv_dtype).name
     say(f"flash_decode_paged[{name}] vs cache_attention (B={B} H={H} pages={P}x{s.page_len} d={d}): {err:.2e}")
     # the output is cast to bf16 on both paths: one bf16 ulp on top of TOL_F32

@@ -234,12 +234,13 @@ def cache_kind(cfg: SolarOpen2Config, dtype):
 # ---------------------------------------------------------------------------
 
 def gqa_block(cfg: SolarOpen2Config, lp: Dict[str, Any], x, k_pool, v_pool, paged_layer: int, pos, page_table,
-              write_mask=None, use_kernel: Optional[bool] = None, trace_notes: Optional[dict] = None):
+              write_mask=None, use_kernel: Optional[bool] = None, trace_notes: Optional[dict] = None, work=None):
     """``x + GatedGQA(RMS(x))`` for ``x (B, T, D)`` at per-row write
     offsets ``pos (B,)``: writes the rows' K and V into layer
     ``paged_layer`` of the pools through ``page_table`` and attends over
-    the cache — the grouped ``flash_decode_paged`` (or the gather + lax
-    form) for ``T == 1``, block by block over the context otherwise."""
+    the cache — the grouped ``flash_decode_paged`` over the step's
+    ``work`` list (or the gather + lax form) for ``T == 1``, block by
+    block over the context otherwise."""
     from deepspeed_tpu.ops import kernels as _kernels
     from deepspeed_tpu.ops.kernels.flash_decode import decode_paged_supported
     from deepspeed_tpu.ops.transformer import inference as inf
@@ -259,7 +260,7 @@ def gqa_block(cfg: SolarOpen2Config, lp: Dict[str, Any], x, k_pool, v_pool, page
         if trace_notes is not None:
             why_not = "" if armed and fits else ("kernel suite not armed" if not armed else f"unsupported page geometry (page_len {kc.shape[2]})")
             trace_notes.update(gqa_decode_kernel=not why_not, gqa_decode_fallback=why_not)
-        attn = inf.paged_cache_attention(q, kc, vc, page_table, pos, use_kernel=armed)
+        attn = inf.paged_cache_attention(q, kc, vc, page_table, pos, use_kernel=armed, work=work, trace_notes=trace_notes)
     else:
         if trace_notes is not None:
             trace_notes["gqa_prefill_form"] = "blockwise jnp (paged_chunk_attention)"
@@ -345,16 +346,18 @@ def forward_with_cache(params: Dict[str, Any], tokens, k_pool, v_pool, state, po
     ``deepseek_v2.forward_with_cache`` returns it.  ``routing_sink`` is
     given each layer's chosen experts ``(B * T, top_k)``."""
     from deepspeed_tpu.moe.layer import dropless_held_experts, sigmoid_topk
+    from deepspeed_tpu.ops.kernels.flash_decode import paged_work_list
 
     B, T = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)
     valid = None if row_valid is None else row_valid.reshape(B * T)
     aux = []
     paged_layer = state_layer = 0
+    work = paged_work_list(pos, write_mask, k_pool.shape[3], page_table.shape[1]) if T == 1 else None  # once, for every GQA layer
     for layer, lp in enumerate(params["layers"]):
         if layer in cfg.gqa_layers:
             x, k_pool, v_pool = gqa_block(cfg, lp, x, k_pool, v_pool, paged_layer, pos, page_table, write_mask,
-                                          use_kernel, trace_notes)
+                                          use_kernel, trace_notes, work)
             paged_layer += 1
         else:
             x, state = kda_block(cfg, lp, x, state, state_layer, pos, slot, write_mask, row_valid, use_kernel, trace_notes)
@@ -384,7 +387,8 @@ def serving_forward(cfg: SolarOpen2Config):
     rows are the slots).  ``fwd.trace_notes`` holds the forms the two
     programs compiled: ``kda_decode_kernel`` / ``_fallback``,
     ``kda_prefill_form``, ``gqa_decode_kernel`` / ``_fallback``,
-    ``gqa_prefill_form``, ``moe_grouped_kernel`` / ``_fallback``."""
+    ``paged_decode_walk``, ``gqa_prefill_form``, ``moe_grouped_kernel``
+    / ``_fallback``."""
     notes: Dict[str, Any] = {}
 
     def fwd(params, tokens, k, v, pos, page_table, write_mask=None, row_valid=None, take=None, state=None, slot=None):

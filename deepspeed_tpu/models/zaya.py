@@ -246,9 +246,11 @@ def forward_with_cache(params: Dict[str, Any], tokens, k_pool, v_pool, state, po
     given each layer's chosen expert ``(B * T, 1)``."""
     from deepspeed_tpu.moe.layer import dropless_held_experts, mlp_top1
     from deepspeed_tpu.ops.transformer import compressed_attention as cca
+    from deepspeed_tpu.ops.kernels.flash_decode import paged_work_list
 
     B, T = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)
+    work = paged_work_list(pos, write_mask, k_pool.shape[3], page_table.shape[1]) if T == 1 else None  # once, for every layer
     r = jnp.zeros((B * T, cfg.router_hidden_size), jnp.float32)  # the router's carry: nothing before the first layer
     valid = None if row_valid is None else row_valid.reshape(B * T)
     aux = []
@@ -256,7 +258,7 @@ def forward_with_cache(params: Dict[str, Any], tokens, k_pool, v_pool, state, po
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         o, k_pool, v_pool, state = cca.attention(cfg.cca, lp["qkv"], lp["conv0"], lp["conv1"], lp["tau"], h, k_pool, v_pool,
                                                  state, layer, pos, page_table, slot, write_mask, row_valid, use_kernel,
-                                                 trace_notes)
+                                                 trace_notes, work)
         x = res(x, o @ lp["o"], lp["res_attn"])
         flat = rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps).reshape(B * T, -1)
         with jax.named_scope("moe.router"):
@@ -283,8 +285,8 @@ def serving_forward(cfg: ZayaConfig):
     ``slot`` is the prefill chunk's slot (a decode step passes None: its
     rows are the slots).  ``fwd.trace_notes`` holds the forms the two
     programs compiled: ``cca_decode_kernel`` / ``_fallback``,
-    ``cca_prefill_form``, ``moe_router_form``, ``moe_grouped_kernel`` /
-    ``_fallback``."""
+    ``paged_decode_walk``, ``cca_prefill_form``, ``moe_router_form``,
+    ``moe_grouped_kernel`` / ``_fallback``."""
     notes: Dict[str, Any] = {}
 
     def fwd(params, tokens, k, v, pos, page_table, write_mask=None, row_valid=None, take=None, state=None, slot=None):

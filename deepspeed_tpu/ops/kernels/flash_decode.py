@@ -274,11 +274,35 @@ def decode_paged_supported(B: int, H: int, P: int, page_len: int, d: int) -> boo
     return page_len >= 128 and page_len % 128 == 0 and d >= 8 and B >= 1 and H >= 1 and P >= 1
 
 
+def paged_work_list(pos, live, page_len: int, pages_per_slot: int):
+    """The work of one paged decode step as a list of (slot, logical
+    page) items: for every row with ``live[b]`` (``None``: every row)
+    the pages ``0 ... pos[b] // page_len``, in slot order, then page
+    order.  Returns ``(slot, page, n, live)``: two int32 arrays of the
+    static capacity ``B * pages_per_slot``, the count ``n (1,)`` — the
+    items past ``n`` repeat the last one and are not walked
+    (``compact_rows``' convention; with ``n == 0`` they name slot 0,
+    page 0) — and the rows the list visits, ``(B,)`` bool.  It depends
+    on nothing a layer has, so a decode program builds it once."""
+    from deepspeed_tpu.ops.kernels.kda_decode import compact_rows
+
+    P = pages_per_slot
+    pos = jnp.asarray(pos, jnp.int32)
+    live = jnp.ones(pos.shape, bool) if live is None else live.astype(bool)
+    pages = jnp.where(live, jnp.clip(pos // page_len, 0, P - 1) + 1, 0)  # (B,) filled pages a row
+    filled = jnp.arange(P, dtype=jnp.int32)[None, :] < pages[:, None]  # (B, P), row-major: slot order, then page order
+    items, n = compact_rows(filled.reshape(-1))
+    return items // P, items % P, n, live
+
+
 def _flash_decode_paged_kernel(
     pt_ref,           # SMEM (B, P) int32 — per-slot page table (scalar prefetch)
     pos_ref,          # SMEM (B,) int32 — per-slot query position (scalar prefetch)
+    slot_ref,         # SMEM (B * P,) int32 — slot of each work item (scalar prefetch)
+    page_ref,         # SMEM (B * P,) int32 — logical page of each work item (scalar prefetch)
+    n_ref,            # SMEM (1,) int32 — items to walk: the traced bound of the grid's last axis
     q_ref,            # (1, block_heads, group, d): the query heads of block_heads KV heads
-    k_ref,            # (1, block_heads, page_len, d)  — THE page pt[b, p], codes or bf16/f32
+    k_ref,            # (1, block_heads, page_len, d)  — THE page pt[slot, page], codes or bf16/f32
     v_ref,            # (1, block_heads, page_len, d); both (1, block_heads, d, page_len) when ``lanes_hold_rows``
     *rest,            # [ks_ref, vs_ref (1,block_heads,1,page_len)]; o_ref; scratch m, l, acc
     sm_scale: float,
@@ -293,63 +317,68 @@ def _flash_decode_paged_kernel(
     vs_ref = refs.pop(0) if quant else None
     o_ref, m_ref, l_ref, acc_ref = refs
 
-    b = pl.program_id(0)
-    p_idx = pl.program_id(2)
-    num_p = pl.num_programs(2)
+    i = pl.program_id(1)
+    b, p_idx = slot_ref[i], page_ref[i]
 
-    @pl.when(p_idx == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    @pl.when(n_ref[0] == 0)
+    def _nothing_decodes():
+        # the one step a grid of no items still takes
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    # logical position of this page's rows within the slot: the page
-    # table indirection happened in the BlockSpec index_map (the k/v
-    # blocks ARE page pt[b, p]), so the mask math is position-space —
-    # unmapped table entries point at the garbage page, whose logical
-    # positions always exceed pos[b]
-    key_idx = p_idx * page_len + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_len), 1
-    )
-    allowed = key_idx <= pos_ref[b]
-    # static unroll over the KV heads of this program; a KV head's
-    # ``group`` query heads share its page: one fetch, ``group`` rows
-    for h in range(block_heads):
-        rows = pl.dslice(h * group, group)
-        q = q_ref[0, h].astype(jnp.float32)                      # (group, d)
-        # the page's positions are the rows of its tile, or — a head
-        # narrower than the lanes — its columns: the contraction moves
-        k = k_ref[0, h].astype(jnp.float32)                      # (page_len, d) | (d, page_len)
-        scores = jax.lax.dot_general(
-            q, k,
-            dimension_numbers=(((1,), (0 if lanes_hold_rows else 1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale                                             # (group, page_len)
-        if quant:
-            scores = scores * ks_ref[0, h]                       # in-register dequant
-        scores = jnp.where(allowed, scores, NEG_INF)
+    @pl.when(n_ref[0] > 0)
+    def _item():
+        @pl.when(p_idx == 0)
+        def _init():
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        m_prev = m_ref[rows]                                     # (group, 1)
-        l_prev = l_ref[rows]
-        m_cur = jnp.max(scores, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(scores - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[rows] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[rows] = m_new
-        if quant:
-            p = p * vs_ref[0, h]
-        v = v_ref[0, h].astype(jnp.float32)
-        acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
-            p, v,
-            dimension_numbers=(((1,), (1 if lanes_hold_rows else 0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        # logical position of this page's rows within the slot: the page
+        # table indirection happened in the BlockSpec index_map (the k/v
+        # blocks ARE page pt[b, p]), so the mask math is position-space
+        key_idx = p_idx * page_len + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_len), 1
         )
+        allowed = key_idx <= pos_ref[b]
+        # static unroll over the KV heads of this program; a KV head's
+        # ``group`` query heads share its page: one fetch, ``group`` rows
+        for h in range(block_heads):
+            rows = pl.dslice(h * group, group)
+            q = q_ref[0, h].astype(jnp.float32)                      # (group, d)
+            # the page's positions are the rows of its tile, or — a head
+            # narrower than the lanes — its columns: the contraction moves
+            k = k_ref[0, h].astype(jnp.float32)                      # (page_len, d) | (d, page_len)
+            scores = jax.lax.dot_general(
+                q, k,
+                dimension_numbers=(((1,), (0 if lanes_hold_rows else 1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sm_scale                                             # (group, page_len)
+            if quant:
+                scores = scores * ks_ref[0, h]                       # in-register dequant
+            scores = jnp.where(allowed, scores, NEG_INF)
 
-    @pl.when(p_idx == num_p - 1)
-    def _emit():
-        l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        o_ref[:] = (acc_ref[:] / l).reshape(o_ref.shape).astype(o_ref.dtype)
+            m_prev = m_ref[rows]                                     # (group, 1)
+            l_prev = l_ref[rows]
+            m_cur = jnp.max(scores, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(scores - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[rows] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[rows] = m_new
+            if quant:
+                p = p * vs_ref[0, h]
+            v = v_ref[0, h].astype(jnp.float32)
+            acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+                p, v,
+                dimension_numbers=(((1,), (1 if lanes_hold_rows else 0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+        # the slot's last item: the page its position lies in
+        @pl.when(p_idx == pos_ref[b] // page_len)
+        def _emit():
+            l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
+            o_ref[:] = (acc_ref[:] / l).reshape(o_ref.shape).astype(o_ref.dtype)
 
 
 def flash_decode_paged(
@@ -359,6 +388,7 @@ def flash_decode_paged(
     page_table: jnp.ndarray,
     pos,
     sm_scale: Optional[float] = None,
+    work=None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Single-query attention against a PAGED pool (docs/serving.md
@@ -370,22 +400,33 @@ def flash_decode_paged(
     cache's ``Hkv``; query head ``i`` attends KV head ``i // (H / Hkv)``.
     The ``H / Hkv`` query heads of a KV head sit in one tile against its
     page, so a page is fetched once a KV head, not once a query head.
-    Multi-head attention is the group of 1 — one KV head a program,
-    grid ``(B, H, pages_per_slot)``, as it always ran; a grouped call
-    takes every KV head's page in one program, grid ``(B, 1,
-    pages_per_slot)``: with groups there are few KV heads and many
-    slots, and a program a (slot, KV head, page) is mostly grid steps.
-    A page past a slot's position is computed fully masked, for every
-    group size (its table entry is the garbage page, the same block as
-    the step before: not fetched again).
+    Multi-head attention is the group of 1 — one KV head a program; a
+    grouped call takes every KV head's page in one program: with groups
+    there are few KV heads and many slots, and a program a (slot, KV
+    head, page) is mostly grid steps.
 
-    The page table rides the grid as a **prefetched scalar**
+    **The grid walks a work list**, ``(Hkv / heads a program, items)``:
+    ``work = (slot, page, n, live)`` as :func:`paged_work_list` builds it —
+    the filled pages ``0 ... pos[b] // page_len`` of the rows that
+    decode, slot by slot — rides as prefetched scalars, and ``n``, a
+    traced value, is the bound of the grid's last axis: a page past a
+    slot's position and a row that does not decode cost no grid step,
+    no DMA and no arithmetic.  A slot's items are consecutive, so its
+    output block stays in VMEM over them and goes back when the slot
+    changes.  ``work=None`` builds the list from ``pos`` with every row
+    live; a decode program builds it once and hands it to every layer's
+    call (the layers differ in the *table*, not in the items).  Rows no
+    item visits read 0.
+
+    The page table rides the grid as a **prefetched scalar** too
     (``PrefetchScalarGridSpec``): the k/v BlockSpec index_map reads
-    ``pt[b, p]``, so each program's K/V page streams HBM→VMEM directly
-    — the gather the lax path materializes never exists.  The page axis
-    is sequential; one page is one kv block (``decode_paged_supported``
-    demands page_len be lane-aligned), and the online softmax state
-    lives in VMEM scratch exactly like :func:`flash_decode`."""
+    ``pt[slot[i], page[i]]``, so each program's K/V page streams
+    HBM→VMEM directly — the gather the lax path materializes never
+    exists.  The item axis is sequential; one page is one kv block
+    (``decode_paged_supported`` demands page_len be lane-aligned), and
+    the online softmax state lives in VMEM scratch exactly like
+    :func:`flash_decode`, started at a slot's page 0 and emitted at the
+    page its position lies in."""
     quant = isinstance(k_cache, dict)
     k_op = k_cache["q"] if quant else k_cache
     v_op = v_cache["q"] if quant else v_cache
@@ -420,13 +461,19 @@ def flash_decode_paged(
     page_block = (1, bh, d, page_len) if lanes_hold_rows else (1, bh, page_len, d)
 
     table = jnp.asarray(page_table, jnp.int32)
-    pos_vec = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+    # a position is inside the slot: the page it lies in is an item of the list
+    pos_vec = jnp.clip(jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,)), 0, P * page_len - 1)
+    if work is None:
+        work = paged_work_list(pos_vec, None, page_len, P)
+    slot, page, n, live = work
 
     # index maps receive (*grid_ids, *scalar_prefetch_refs)
+    row = lambda h, i, pt, pv, sl, pg, n: (sl[i], h, 0, 0)  # noqa: E731
+    kv_page = lambda h, i, pt, pv, sl, pg, n: (pt[sl[i], pg[i]], h, 0, 0)  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, bh, group, d), lambda b, h, p, pt, pv: (b, h, 0, 0)),
-        pl.BlockSpec(page_block, lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)),
-        pl.BlockSpec(page_block, lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)),
+        pl.BlockSpec((1, bh, group, d), row),
+        pl.BlockSpec(page_block, kv_page),
+        pl.BlockSpec(page_block, kv_page),
     ]
     args = [q.reshape(B, Hkv, group, d), k_op, v_op]
     if quant:
@@ -434,9 +481,7 @@ def flash_decode_paged(
         # vectors (contiguous reshape) sharing the score-row layout
         ks = k_cache["s"].reshape(NP, Hkv, 1, page_len)
         vs = v_cache["s"].reshape(NP, Hkv, 1, page_len)
-        spec = pl.BlockSpec(
-            (1, bh, 1, page_len), lambda b, h, p, pt, pv: (pt[b, p], h, 0, 0)
-        )
+        spec = pl.BlockSpec((1, bh, 1, page_len), kv_page)
         in_specs += [spec, spec]
         args += [ks, vs]
 
@@ -450,10 +495,11 @@ def flash_decode_paged(
         lanes_hold_rows=lanes_hold_rows,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv // bh, P),
+        num_scalar_prefetch=5,
+        # one step at least: a grid bound of zero is nothing a compiled program needs to meet
+        grid=(Hkv // bh, jnp.maximum(n[0], 1)),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bh, group, d), lambda b, h, p, pt, pv: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, bh, group, d), row),
         scratch_shapes=[
             pltpu.VMEM((bh * group, 1), jnp.float32),   # m
             pltpu.VMEM((bh * group, 1), jnp.float32),   # l
@@ -465,12 +511,13 @@ def flash_decode_paged(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
         name="flash_decode_paged",
-    )(table, pos_vec, *args)
-    return out.reshape(B, H, 1, d)
+    )(table, pos_vec, slot, page, n, *args)
+    # rows no item visited hold whatever the output buffer held
+    return jnp.where(live[:, None, None, None], out, 0).reshape(B, H, 1, d)
 
 
 def flash_decode_reference(q, k_cache, v_cache, pos, sm_scale=None, key_padding_mask=None):

@@ -153,7 +153,7 @@ def shifted_values(v_now, v_back, vshift, sz: Sizes, n_valid=None):
 
 def attention(sz: Sizes, w_qkv, w_conv0, w_conv1, tau, h, k_pool, v_pool, state: Dict[str, Any], layer: int, pos,
               page_table, slot=None, write_mask=None, row_valid=None, use_kernel: Optional[bool] = None,
-              trace_notes: Optional[dict] = None) -> Tuple[Any, Any, Any, Dict[str, Any]]:
+              trace_notes: Optional[dict] = None, work=None) -> Tuple[Any, Any, Any, Dict[str, Any]]:
     """CCA of ``h (B, T, D)``, the layer's normed input, on layer
     ``layer`` of the stacked pools and of the per-slot ``state``
     (``{"conv", "vshift"}``), at per-row write offsets ``pos (B,)``.
@@ -163,7 +163,9 @@ def attention(sz: Sizes, w_qkv, w_conv0, w_conv1, tau, h, k_pool, v_pool, state:
     read as zero where ``pos == 0``, left at the last token whose
     ``row_valid`` is True).  ``slot`` None: a **decode step**, row ``b``
     is slot ``b`` and rows with ``write_mask`` False keep their tail and
-    write their K/V to the garbage page.  Returns ``(o (B, T, H * d) in
+    write their K/V to the garbage page; ``work`` is the step's work
+    list for the paged decode kernel (``flash_decode.paged_work_list``, built
+    once for all layers).  Returns ``(o (B, T, H * d) in
     h's dtype, k_pool, v_pool, state)``."""
     from deepspeed_tpu.ops import kernels as _kernels
     from deepspeed_tpu.ops.kernels.flash_decode import decode_paged_supported
@@ -206,7 +208,7 @@ def attention(sz: Sizes, w_qkv, w_conv0, w_conv1, tau, h, k_pool, v_pool, state:
                 why_not = "" if armed and fits else ("kernel suite not armed" if not armed
                                                     else f"unsupported page geometry (page_len {page_len})")
                 trace_notes.update(cca_decode_kernel=not why_not, cca_decode_fallback=why_not)
-            o = inf.paged_cache_attention(q, kc, vc, table, pos, use_kernel=armed)
+            o = inf.paged_cache_attention(q, kc, vc, table, pos, use_kernel=armed, work=work, trace_notes=trace_notes)
         else:
             if trace_notes is not None:
                 trace_notes["cca_prefill_form"] = "blockwise jnp (paged_chunk_attention)"

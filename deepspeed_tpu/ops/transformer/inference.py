@@ -286,14 +286,21 @@ def state_rows_write(buf, layer: int, slot, rows):
 
 def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
                           sm_scale: Optional[float] = None,
-                          use_kernel: Optional[bool] = None):
+                          use_kernel: Optional[bool] = None,
+                          work=None, trace_notes: Optional[dict] = None):
     """Attend (B,H,T,d) queries against a paged cache.  Single-query
     steps dispatch to the fused paged flash-decode kernel when the
     kernel suite is armed and the page geometry qualifies (the page
     table rides the grid as a prefetched scalar, so k/v pages stream
     straight from HBM without materializing the gather); otherwise the
     gather + :func:`cache_attention` lax path below is the numerics
-    ground truth, bit-matching the slot-contiguous cache."""
+    ground truth, bit-matching the slot-contiguous cache.
+
+    ``work`` is the kernel's work list (``flash_decode.paged_work_list``; ``None``:
+    the filled pages of every row): the kernel walks its items and
+    nothing else, and the rows it does not visit read 0.  The lax path
+    attends every row and does not read it.  ``trace_notes`` is told
+    when the kernel took the step (``paged_decode_walk``)."""
     quant = isinstance(k_cache, dict)
     if use_kernel is None:
         from deepspeed_tpu.ops import kernels as _kernels
@@ -307,8 +314,10 @@ def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
         B, H, _, d = q.shape
         page_len = (k_cache["q"] if quant else k_cache).shape[2]
         if decode_paged_supported(B, H, page_table.shape[1], page_len, d):
+            if trace_notes is not None:
+                trace_notes["paged_decode_walk"] = "work list"
             return flash_decode_paged(
-                q, k_cache, v_cache, page_table, pos, sm_scale=sm_scale
+                q, k_cache, v_cache, page_table, pos, sm_scale=sm_scale, work=work
             )
     gk = paged_gather(k_cache, page_table)
     gv = paged_gather(v_cache, page_table)
@@ -495,6 +504,7 @@ def inference_block(
     write_mask=None,
     layer=None,
     trace_notes: Optional[dict] = None,
+    work=None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One transformer layer with cache update.
 
@@ -512,8 +522,9 @@ def inference_block(
     the pool as slices (``write_mask`` redirecting masked rows to the
     garbage page) and attention reads the layer's pages where they lie:
     one query through the paged decode kernel (or the gather + lax
-    form), a chunk block by block over the slot's pages — requires a
-    per-slot ``pos`` and no ``key_padding_mask``.  Returns
+    form) — ``work`` its work list, built once for all layers
+    (``flash_decode.paged_work_list``) — a chunk block by block over the slot's pages
+    — requires a per-slot ``pos`` and no ``key_padding_mask``.  Returns
     (y, new_k_cache, new_v_cache).  Mirrors the reference's fused
     attention+MLP inference module (``transformer_inference.py``
     DeepSpeedTransformerInference.forward).
@@ -538,7 +549,7 @@ def inference_block(
         if trace_notes is not None:
             trace_notes["kv_write_form"] = "slices, in place"
         if T == 1:
-            attn = paged_cache_attention(q, kc, vc, table, pos)
+            attn = paged_cache_attention(q, kc, vc, table, pos, work=work, trace_notes=trace_notes)
         else:
             if trace_notes is not None:
                 trace_notes["prefill_attend_form"] = "blockwise (paged_chunk_attention)"
@@ -673,6 +684,11 @@ def forward_with_cache(
 
     if page_table is not None:
         n_layer = jax.tree.leaves(k_cache)[0].shape[0]
+        # a decode step's work list is the same for every layer (they
+        # differ in the table's offset): the loop body closes over it
+        from deepspeed_tpu.ops.kernels.flash_decode import paged_work_list
+
+        work = paged_work_list(pos, write_mask, jax.tree.leaves(k_cache)[0].shape[3], page_table.shape[1]) if T == 1 else None
 
         def pin(cache):
             if pool_layout is None:
@@ -688,7 +704,7 @@ def forward_with_cache(
             lp, i = xs
             x, k, v = carry
             x, k, v = inference_block(cfg, lp, x, pin(k), pin(v), pos, page_table=page_table, write_mask=write_mask,
-                                      layer=i, trace_notes=trace_notes)
+                                      layer=i, trace_notes=trace_notes, work=work)
             return (x, pin(k), pin(v)), None
 
         (x, new_k, new_v), _ = jax.lax.scan(

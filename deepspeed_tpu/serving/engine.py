@@ -169,6 +169,9 @@ class ServingEngine:
         self._state_resets = 0
         self._decode_rows = 0
         self._decode_steps = 0
+        # how far the paged decode kernel's work list engages (stats()):
+        # the pages its grid walks, summed over decode steps
+        self._decode_pages_walked = 0
         if self._paged:
             import math
 
@@ -1407,6 +1410,10 @@ class ServingEngine:
         self.pool.swap(*pools)
         self._decode_rows += len(decoding)
         self._decode_steps += 1
+        if self._paged:
+            # a decoding row attends positions 0 ... prompt + generated - 1
+            self._decode_pages_walked += sum(
+                (len(r.prompt) + len(r.generated) - 1) // self.pool.page_len + 1 for r in decoding)
         with tl.phase("decode.wait"):
             out = jax.device_get(nxt)
         with tl.phase("decode.note"):
@@ -1556,6 +1563,9 @@ class ServingEngine:
         if self._paged:
             out["kvcache"] = self.pool.stats()
             self._publish_kvcache()
+            out["decode_pages_walked"] = self._decode_pages_walked
+            # what a grid of every page of every slot walks
+            out["decode_pages_grid"] = self._decode_steps * self.pool.num_slots * self.pool.pages_per_slot
         if self.tenants is not None:
             out["tenants"] = self.tenants.snapshot()
         if self._aux_total is not None:
@@ -1571,7 +1581,8 @@ class ServingEngine:
         # (DeepSeek-V2: mla_prefill_kernel / mla_prefill_fallback, moe_grouped_kernel / moe_grouped_fallback;
         # Solar-Open2: kda_decode_kernel / _fallback, kda_prefill_form, gqa_decode_kernel / _fallback, gqa_prefill_form;
         # ZAYA: cca_decode_kernel / _fallback, cca_prefill_form, moe_router_form;
-        # the paged per-head pool: kv_write_form, prefill_attend_form)
+        # the paged per-head pool: kv_write_form, prefill_attend_form;
+        # all three that decode through flash_decode_paged: paged_decode_walk)
         out.update(self._trace_notes)
         out.update(self.timeline.summary())
         for stall in self.timeline.stalls() if out["stall_steps"] else ():

@@ -60,7 +60,7 @@ def test_flash_attention_lowers_for_tpu_at_smoke_shapes():
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_flash_decode_lowers_for_tpu_at_smoke_shapes(kv):
-    from deepspeed_tpu.ops.kernels.flash_decode import flash_decode, flash_decode_paged
+    from deepspeed_tpu.ops.kernels.flash_decode import flash_decode, flash_decode_paged, paged_work_list
 
     mcfg = gpt2.PRESETS[FULL.serve_model]
     B, H, d, S, PL = FULL.slots, mcfg.n_head, mcfg.head_dim, FULL.max_len, FULL.page_len
@@ -76,6 +76,11 @@ def test_flash_decode_lowers_for_tpu_at_smoke_shapes(kv):
     _lowers_for_tpu(
         lambda q, k, v, t, p: flash_decode_paged(q, k, v, t, p, interpret=False),
         q, pages, pages, _sds((B, P), jnp.int32), pos,
+    )
+    # as a decode program calls it: the work list of the rows that decode, its length the grid's traced bound
+    _lowers_for_tpu(
+        lambda q, k, v, t, p, m: flash_decode_paged(q, k, v, t, p, work=paged_work_list(p, m, PL, P), interpret=False),
+        q, pages, pages, _sds((B, P), jnp.int32), pos, _sds((B,), jnp.bool_),
     )
     slots = cache((B, H, S, d))
     _lowers_for_tpu(lambda q, k, v, p: flash_decode(q, k, v, p, interpret=False), q, slots, slots, pos)

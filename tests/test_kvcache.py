@@ -1045,6 +1045,131 @@ def test_page_copy_is_one_page_of_every_layer_and_the_identity_on_itself():
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)), same, pool)
 
 
+def _work_case(name, P, page_len):
+    """``(pos, live)`` of three rows: what a decode step's work list is built from."""
+    full = P * page_len - 1
+    return {
+        "ragged": ([37, 2 * page_len + 5, page_len - 1], None),
+        "some_rows_not_live": ([37, 2 * page_len + 5, page_len + 1], [True, False, True]),
+        "no_row_live": ([37, 2 * page_len + 5, 3], [False, False, False]),
+        "every_page_filled": ([full, full, full], None),
+        "pos_on_a_page_boundary": ([page_len, 2 * page_len, 0], [True, True, True]),
+    }[name]
+
+
+WORK_CASES = ["ragged", "some_rows_not_live", "no_row_live", "every_page_filled", "pos_on_a_page_boundary"]
+
+
+@pytest.mark.parametrize("case", WORK_CASES)
+@pytest.mark.parametrize("form", ["multi_head_d64", "grouped_d128", "int8_pool"])
+def test_flash_decode_paged_walks_its_work_list(form, case):
+    """The paged decode kernel over a work list against the gather + lax
+    form, for the three shapes the serve programs hand it — multi-head
+    with a head of half a lane row (``(d, page_len)`` tiles), grouped
+    queries at a whole one, the int8 code + scale pair — and the lists a
+    step can hold.  A row the list does not visit reads 0."""
+    from deepspeed_tpu.ops.kernels import flash_decode as fd
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    Hkv, group, d, quant = {"multi_head_d64": (2, 1, 64, False), "grouped_d128": (2, 4, 128, False),
+                            "int8_pool": (2, 1, 64, True)}[form]
+    B, P, page_len, L = 3, 3, 128, 2
+    H, num_pages = Hkv * group, 1 + B * P
+    rng = np.random.default_rng(WORK_CASES.index(case) + 7 * group + quant)
+    kp, vp = inf.init_kv_cache(L, num_pages, Hkv, page_len, d, "int8" if quant else jnp.bfloat16)
+    table = jnp.asarray(np.arange(1, num_pages, dtype=np.int32).reshape(B, P))
+    rows = lambda: jnp.asarray(rng.standard_normal((B, Hkv, P * page_len, d)), jnp.bfloat16)  # noqa: E731
+    zero = jnp.zeros((B,), jnp.int32)
+    kp, vp = inf.paged_cache_write_slices(kp, 1, rows(), table, zero), inf.paged_cache_write_slices(vp, 1, rows(), table, zero)
+    q = jnp.asarray(rng.standard_normal((B, H, 1, d)), jnp.bfloat16)
+    pos, live = _work_case(case, P, page_len)
+    pos = jnp.asarray(np.array(pos, np.int32))
+    live = None if live is None else jnp.asarray(live)
+    kc, vc, tab = inf.layer_pages(kp, vp, table, 1)
+    work = fd.paged_work_list(pos, live, page_len, P)
+    got = fd.flash_decode_paged(q, kc, vc, tab, pos, work=work)
+    want = np.asarray(inf.paged_cache_attention(q, kc, vc, tab, pos, use_kernel=False), np.float32)
+    if live is not None:
+        want = np.where(np.asarray(live)[:, None, None, None], want, 0.0)
+    assert got.shape == (B, H, 1, d)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=2e-2, rtol=2e-2)
+    # the seam hands the list on, and builds it from ``pos`` where it is given none
+    seam = inf.paged_cache_attention(q, kc, vc, tab, pos, use_kernel=True, work=work)
+    np.testing.assert_array_equal(np.asarray(seam, np.float32), np.asarray(got, np.float32))
+    if live is None:
+        five = fd.flash_decode_paged(q, kc, vc, tab, pos)
+        np.testing.assert_array_equal(np.asarray(five, np.float32), np.asarray(got, np.float32))
+
+
+def test_engine_counts_the_pages_a_decode_step_walks_against_the_grid_of_every_page(eng):
+    """``stats()["decode_pages_walked"]`` is the work list's items summed
+    over the decode steps — each decoding row's ``pos // page_len + 1`` —
+    and ``["decode_pages_grid"]`` what a grid over every page of every
+    slot walks; host-side sums, on every paged engine."""
+    srv = _srv(eng, num_slots=2, max_len=64, kvcache={"page_len": 16})
+    lens, new = (5, 30), 6
+    rids = [srv.submit(np.arange(1, n + 1, dtype=np.int32), max_new_tokens=new) for n in lens]
+    res = srv.drain(max_steps=300)
+    assert [len(res[rid].tokens()) for rid in rids] == [n + new for n in lens]
+    st = srv.stats()
+    # the first token is the prefill's; the g-th decode step of a request attends positions 0 ... prompt + g - 1
+    assert st["decode_pages_walked"] == sum((n + g - 1) // 16 + 1 for n in lens for g in range(1, new))
+    assert st["decode_pages_grid"] % (2 * 4) == 0 and new - 1 <= st["decode_pages_grid"] // (2 * 4) <= 2 * (new - 1)
+    assert 0 < st["decode_pages_walked"] < st["decode_pages_grid"]
+    assert "paged_decode_walk" not in st  # pages of 16 rows: the gather + lax form ran, and nothing walked a list
+    off = ServingEngine(eng, num_slots=2, prefill_chunk=8, max_len=64)
+    assert "decode_pages_walked" not in off.stats()
+
+
+def test_paged_engine_decodes_through_the_work_list_kernel_as_through_the_gather_form(monkeypatch):
+    """A paged engine whose pages qualify for the kernel (128 rows),
+    armed: its decode program walks the work list inside the layer
+    scan — slots that are empty or still prefilling are rows the list
+    does not visit — and emits what the gather + lax form emits."""
+    cfg = dataclasses.replace(TINY, n_positions=256)
+    params = gpt2.init_params(cfg, seed=7)
+    params["wpe"] = params["wpe"] * 40.0
+    inf_eng = deepspeed_tpu.init_inference(model_config=cfg, params=params, dtype=jnp.float32, max_out_tokens=256)
+    prompts = [np.arange(1, n + 1, dtype=np.int32) % 500 + 1 for n in (5, 140, 70)]  # one, two and one page: three requests over two slots
+
+    def served(armed):
+        monkeypatch.setenv("DS_KERNELS", armed)
+        srv = ServingEngine(inf_eng, num_slots=2, prefill_chunk=64, max_len=256, kvcache={"enabled": True, "page_len": 128})
+        rids = [srv.submit(p, max_new_tokens=5) for p in prompts]
+        res = srv.drain(max_steps=400)
+        return [np.asarray(res[r].tokens()) for r in rids], srv.stats()
+
+    got, st = served("1")
+    want, st_off = served("0")
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    assert st["paged_decode_walk"] == "work list" and "paged_decode_walk" not in st_off
+    assert st["decode_pages_walked"] == st_off["decode_pages_walked"] > 0
+
+
+def test_paged_work_list_is_the_live_rows_filled_pages_in_slot_then_page_order():
+    from deepspeed_tpu.ops.kernels.flash_decode import paged_work_list
+
+    page_len, P = 128, 4
+    pos = jnp.asarray(np.array([130, 5, 4 * 128 - 1, 128, 9000], np.int32))
+    live = jnp.asarray([True, False, True, True, True])
+    slot, page, n, visited = (np.asarray(a) for a in paged_work_list(pos, live, page_len, P))
+    # rows 0, 2, 3, 4: 2, 4, 2 and (a position past the slot is held to its last page) 4 pages
+    want = [(0, 0), (0, 1), (2, 0), (2, 1), (2, 2), (2, 3), (3, 0), (3, 1), (4, 0), (4, 1), (4, 2), (4, 3)]
+    assert slot.shape == page.shape == (5 * P,) and slot.dtype == page.dtype == np.int32
+    assert n.shape == (1,) and int(n[0]) == len(want)
+    assert list(zip(slot[: len(want)].tolist(), page[: len(want)].tolist())) == want
+    # past the count the last item repeats: a grid step that is not walked fetches nothing new
+    assert set(zip(slot[len(want):].tolist(), page[len(want):].tolist())) == {want[-1]}
+    np.testing.assert_array_equal(visited, np.asarray(live))
+    # every row live where nobody says otherwise
+    slot, page, n, visited = (np.asarray(a) for a in paged_work_list(pos[:2], None, page_len, P))
+    assert int(n[0]) == 3 and list(zip(slot[:3].tolist(), page[:3].tolist())) == [(0, 0), (0, 1), (1, 0)] and visited.all()
+    # no row live: no item, and the padding names a block that exists
+    slot, page, n, visited = (np.asarray(a) for a in paged_work_list(pos, jnp.zeros((5,), bool), page_len, P))
+    assert int(n[0]) == 0 and not slot.any() and not page.any() and not visited.any()
+
+
 @pytest.mark.parametrize("d", [64, 128], ids=["pages_tiled_d_by_page_len", "pages_tiled_page_len_by_d"])
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 def test_flash_decode_paged_reads_both_tile_forms(d, quant):
