@@ -90,6 +90,18 @@ def test_widths_are_the_published_ones():
         assert (c["vocab_size"], c["n_positions"]) == (50257, 1024)
 
 
+def test_the_gpt2_serve_pool_holds_every_slot_at_its_full_length():
+    """``1 + num_slots x max_len / page_len`` pages (the garbage page first): what the engine asks for by default,
+    and what both serve programs have held since the pool is written in place (PR 40; 80 until PR 44)."""
+    cfg = M.config("gpt2-xl-serve-paged")
+    s, kv = cfg["serving"], cfg["serving"]["kvcache"]
+    assert kv["num_pages"] == 1 + s["num_slots"] * s["max_len"] // kv["page_len"] == 129
+    m = cfg["model"]
+    pool_bytes = kv["num_pages"] * kv["page_len"] * m["n_layer"] * m["n_embd"] * 2 * 2  # K and V, bf16
+    assert round(pool_bytes / 1e9, 2) == 5.07 and "5.07 GB" in cfg["pool_note"] and "129" in cfg["pool_note"]
+    assert "22.10G" not in cfg["pool_note"], "the note tells of the programs that run now, not PR 23's"
+
+
 def test_wall_clock_rules_that_drop_work_are_off():
     s = M.config("gpt2-xl-serve-paged")["serving"]
     assert s["slo_ttft_ms"] == 0 and s["deadline_seconds"] == 0 and s["degrade_max_new_tokens"] == 0

@@ -13,6 +13,7 @@ from benchmark.manifest import Manifest
 
 M = Manifest()
 CELL, CONFIG = "serve-solar2-reasoning-backlog", "solar-open2-serve-ep8share"
+NEW = ("kda_decode_roofline", "gqa_decode_paged_roofline", "linear_state_share_pct")  # the three metrics PR 32 brought
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 HF = {"hidden_size": 64, "num_hidden_layers": 8, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
       "vocab_size": 256, "moe_intermediate_size": 32, "rms_norm_eps": 1e-5, "gqa_layers": [0, 4], "n_routed_experts": 8,
@@ -68,18 +69,41 @@ def test_configuration_has_the_published_widths_and_states_its_cut():
     assert 0 < cfg["checks"]["state_rel_err_max"] < 1
 
 
-def test_the_cell_is_the_issues_and_nothing_else_of_the_manifest_moved():
+@pytest.fixture(params=["as_committed", "with_a_cell_appended"])
+def manifest(request, tmp_path):
+    """The manifest as it stands, and as a later PR leaves it: a configuration, a cell and a metric appended
+    after everything that is there, the new cell added to the list of a metric this cell reports."""
+    if request.param == "as_committed":
+        return M
+    data = json.loads(json.dumps(M.data))
+    for c in data["configs"]:
+        c["file"] = os.path.relpath(os.path.join(M.root, c["file"]), tmp_path)
+    data["configs"].append({**data["configs"][-1], "name": "later-config"})
+    data["workloads"].append({"name": "later-cell", "config": "later-config", "traffic": "longctx-turns-backlog", "chips": 1, "why": "a later PR's"})
+    for m in data["end_to_end"] + data["per_layer"]:
+        if m["name"] in ("serve_tokens_per_s", "gqa_decode_paged_roofline"):
+            m["workloads"].append("later-cell")
+    data["per_layer"].append({**M.metric_entry("kda_decode_roofline"), "name": "later_kernel_roofline", "workloads": ["later-cell"]})
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(data, f)
+    return Manifest(str(tmp_path / "BENCHMARK.json"))
+
+
+def test_the_cell_is_the_issues_and_nothing_else_of_the_manifest_moved(manifest):
+    M = manifest  # noqa: N806 - the body reads as it did when it asked the committed manifest alone
     cell = M.cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "reasoning-decode-backlog", 1) and len(cell["why"]) <= 200
     assert "4 tokens an expert" in cell["why"] and "8x its share" in cell["why"]
-    assert M.data["workloads"][-1] is cell and M.data["configs"][-1]["name"] == CONFIG
-    assert [m["name"] for m in M.data["per_layer"][-3:]] == ["kda_decode_roofline", "gqa_decode_paged_roofline", "linear_state_share_pct"]
-    for m in M.data["per_layer"][-3:]:
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s" and m["unit"] == "%" and m["source"] == "device_trace"
+    # by name, not by place, as tests/bench/test_zaya1.py asks: a later PR appends cells, configurations and metrics after
+    # these, and may add its cell to a metric's list (gqa_decode_paged_roofline lists the ZAYA1 cell too)
+    assert [w["name"] for w in M.data["workloads"]].count(CELL) == 1 and [c["name"] for c in M.data["configs"]].count(CONFIG) == 1
+    for m in map(M.metric_entry, NEW):
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s" and m["unit"] == "%" and m["source"] == "device_trace"
+        assert m in M.data["per_layer"] and m["layer"] == "kernels"
     e2e = {m["name"] for m in M.end_to_end(CELL)}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     names = {m["name"] for m in M.per_layer(CELL)}
-    assert {"kda_decode_roofline", "gqa_decode_paged_roofline", "linear_state_share_pct", "serve_step_ms_p50", "kv_alloc_waits",
+    assert {*NEW, "serve_step_ms_p50", "kv_alloc_waits",
             "kv_pages_in_use_pct", "batch_occupancy_pct", "serve_hbm_peak_gb", "serve_device_idle_pct",
             "moe_dropped_assignments", "moe_expert_load_max_over_mean"} <= names  # the MoE layer is a fifth of its device time
     assert not {"flash_decode_paged_roofline", "mla_decode_paged_roofline"} & names

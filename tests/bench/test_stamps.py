@@ -166,13 +166,12 @@ class QueueModel:
                 del self.rows[rid]
 
 
-def _chat_open_window(step_s):
-    """The real ``chat-open`` schedule (lengths, order, gaps, pre-roll) through
-    the model at a fixed step; returns the window's metrics and the offered rate."""
+def _chat_open_window(step_s, mix):
+    """The ``chat-open`` schedule (lengths, order, gaps, pre-roll) through the
+    model at a fixed step; returns the window's metrics, the mix's offered
+    rate, and the tokens a second that the requests due in the window ask for."""
     from benchmark import traffic
-    from benchmark.manifest import Manifest
 
-    mix = Manifest().traffic("chat-open")
     assert mix["kind"] == "open"
     stream, gaps = traffic.request_stream(mix, 0, 50257), traffic.arrival_gaps(mix)
     eng = QueueModel(slots=16, chunk=64)
@@ -191,24 +190,47 @@ def _chat_open_window(step_s):
         now += step_s
         st.after_step(now)
     answers = [a for _, a in traffic.length_pool(mix)]
-    return stamps.window_metrics(st.requests, t_open, t_close, open_loop=True), mix["rate_rps"] * sum(answers) / len(answers)
+    due_in_window = sum(r["max_new"] for r in st.requests if t_open <= r["due"] < t_close) / 51.0
+    return (stamps.window_metrics(st.requests, t_open, t_close, open_loop=True), mix["rate_rps"] * sum(answers) / len(answers),
+            due_in_window)
 
 
-def test_a_faster_engine_never_reads_lower_in_the_open_loop():
+# the engine's mean step under ``chat-open`` as it is offered now: timeline wall_ms, the mean over a window (my chip run, PR 44)
+MEASURED_STEP_S = 0.0312
+# (slower, faster, what else the mix states): PR 26's pair on the schedule it ran under (3 cycles a window at 0.82 of PR 23's
+# knee, a 34 s pre-roll), and two steps on either side of the measured one on the schedule PR 44 re-drew
+SCHEDULES = {"pr26": (0.236, 0.075, {"rate_rps": 0.9411764706, "preroll_s": 34.0}),
+             "as_offered_now": (1.4 * MEASURED_STEP_S, 0.6 * MEASURED_STEP_S, {})}
+
+
+@pytest.mark.parametrize("schedule", sorted(SCHEDULES))
+def test_a_faster_engine_never_reads_lower_in_the_open_loop(schedule):
     """PR 26: the GPT-2 serve step went 236 → 75 ms and the chat cell's
     rate, counting every stamp, read *lower* (59.461 → 57.725 tokens/s on
     the chip): both engines emit what is offered plus the backlog the
-    pre-roll left them, and the slower carries more in."""
-    slow, offered = _chat_open_window(0.236)
-    fast, _ = _chat_open_window(0.075)
-    # the three cycles of the window, less the request due a rounding error before it opens
-    assert slow["attempted"] == fast["attempted"] >= 47 and slow["failed"] == fast["failed"] == 0
-    assert offered == pytest.approx(56.47, abs=0.01)
+    pre-roll left them, and the slower carries more in.  The count of
+    PR 36 orders them on any schedule, and never reads above the offer."""
+    from benchmark.manifest import Manifest
+
+    slow_s, fast_s, override = SCHEDULES[schedule]
+    mix = {**Manifest().traffic("chat-open"), **override}
+    slow, offered, due_slow = _chat_open_window(slow_s, mix)
+    fast, _, due_fast = _chat_open_window(fast_s, mix)
+    cycles = round(mix["rate_rps"] * 51.0 / mix["pool"])
+    # the whole cycles of the window, less at most the request due a rounding error before it opens
+    assert cycles * 16 - 1 <= slow["attempted"] == fast["attempted"] <= cycles * 16 and slow["failed"] == fast["failed"] == 0
+    assert 0.93 * offered < due_slow == due_fast < 1.01 * offered
     rate = lambda w, key: w[key] / w["window_s"]  # noqa: E731
-    assert rate(fast, "tokens_emitted") < rate(slow, "tokens_emitted"), "the count this PR replaces: the faster engine reads lower"
-    assert rate(slow, "tokens_emitted") > offered, "... and above what the window offers"
-    assert rate(slow, "tokens") < rate(fast, "tokens") <= offered, "the offered traffic's tokens served in time"
+    assert rate(slow, "tokens") < rate(fast, "tokens") <= due_fast, "the offered traffic's tokens served in time"
     assert max(fast["gaps_ms"]) < min(slow["gaps_ms"]), "the gaps are every request's, as before"
+    if schedule == "pr26":
+        assert offered == pytest.approx(56.47, abs=0.01)
+        assert rate(fast, "tokens_emitted") < rate(slow, "tokens_emitted"), "the count PR 36 replaced: the faster engine reads lower"
+        assert rate(slow, "tokens_emitted") > offered, "... and above what the window offers"
+    else:
+        at, _, _ = _chat_open_window(MEASURED_STEP_S, mix)
+        assert rate(slow, "tokens") < rate(at, "tokens") < rate(fast, "tokens")
+        assert rate(fast, "tokens") > 0.97 * due_fast, "an engine well under its knee serves nearly all that is due"
 
 
 def test_spread_is_between_the_quartiles_over_the_median_and_may_leave_out_one_far_off_run():

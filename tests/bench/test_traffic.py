@@ -1,6 +1,9 @@
 """Every seed of a traffic mix carries the same work."""
 import collections
 import itertools
+import json
+import os
+
 import pytest
 
 from benchmark import traffic
@@ -8,6 +11,7 @@ from benchmark.manifest import Manifest
 
 M = Manifest()
 SERVE_MIXES = sorted({w["traffic"] for w in M.data["workloads"] if M.traffic(w["traffic"])["kind"] != "tokens"})
+OPEN_CELLS = [w["name"] for w in M.data["workloads"] if M.traffic(w["traffic"])["kind"] == "open"]
 SEEDS = [0, 1, 12345, 2 ** 31 + 7, 4_000_000_011]
 
 
@@ -63,6 +67,51 @@ def test_open_loop_offers_each_cycle_over_the_same_time():
     assert totals == {8.0}  # 16 arrivals at 2 a second in every cycle
     assert gaps == list(itertools.islice(traffic.arrival_gaps(mix), 48))
     assert max(gaps) > 3 * min(gaps)  # still bursts and lulls, not a metronome
+
+
+@pytest.mark.parametrize("cell", OPEN_CELLS)
+def test_an_open_loops_window_and_preroll_hold_whole_cycles_of_the_pool(cell):
+    """``rate_rps`` is ``pool x k / run_seconds`` and the pre-roll a whole number of cycles, so the window opens on a
+    cycle boundary and holds the same k x pool requests for every seed."""
+    mix = M.traffic(M.cell(cell)["traffic"])
+    cycle_s = mix["pool"] / mix["rate_rps"]
+    k, pre = M.data["run_seconds"] / cycle_s, mix["preroll_s"] / cycle_s
+    assert k == pytest.approx(round(k), abs=1e-6) and round(k) >= 1
+    assert pre == pytest.approx(round(pre), abs=1e-6) and round(pre) >= 1 and mix["preroll_s"] >= 10
+    gaps, due, t = traffic.arrival_gaps(mix), [], 0.0
+    while t < mix["preroll_s"] + M.data["run_seconds"] + cycle_s:
+        t += next(gaps)
+        due.append(t)
+    t_open = mix["preroll_s"]
+    inside = [d for d in due if t_open + 1e-6 < d <= t_open + M.data["run_seconds"] + 1e-6]
+    assert len(inside) == round(k) * mix["pool"]  # an arrival is the end of its gap: each cycle's last falls on the boundary
+    assert f"{round(k)} cycles" in mix["rate_note"] and f"{round(pre)} cycles" in mix["preroll_note"]
+    # the window opens at the first step boundary after the cycle boundary (tens of ms late) and closes as much later:
+    # no arrival is due so soon after either edge that it falls now on one side of it and now on the other
+    t_close = t_open + M.data["run_seconds"]
+    assert min(d - t_open for d in due if d > t_open + 1e-6) > 0.1 and min(d - t_close for d in due if d > t_close + 1e-6) > 0.1
+
+
+@pytest.mark.parametrize("cell", OPEN_CELLS)
+def test_an_open_loops_rate_is_four_fifths_of_a_knee_swept_on_a_tpu(cell):
+    """The sweep that found the knee is kept beside the mix (``benchmark/sweep.py`` wrote its rows on the chip): it shows
+    a rate that holds no queue and one that does, and the cell's rate lies at 0.75-0.85 of the knee they give."""
+    mix = M.traffic(M.cell(cell)["traffic"])
+    with open(os.path.join(M.root, "benchmark", "sweeps", cell + ".json")) as f:
+        sweep = json.load(f)
+    assert sweep["workload"] == cell and sweep["device"].startswith("TPU") and "chip run" in sweep["origin"]
+    rows = sorted(sweep["rows"], key=lambda r: r["rate_rps"])
+    assert 6 <= len(rows) <= 12 and all(r["correct"] and r["seconds"] >= 30 and r["seed"] > 2 ** 31 for r in rows)
+    assert rows[0]["rate_rps"] < mix["rate_rps"] / 0.8 < rows[-1]["rate_rps"], "the rows bracket the knee the rate implies"
+    holds = [r["rate_rps"] for r in rows if r["queue_depth_at_close"] == 0 and r["failed"] == 0]
+    grows = [r["rate_rps"] for r in rows if r["queue_depth_at_close"] >= mix["pool"]]
+    assert holds and grows and max(holds) < min(grows)
+    # the knee by the file's own rule: what the saturated rows emit, over the pool's mean answer
+    answers = [a for _, a in traffic.length_pool(mix)]
+    saturated = [r["serve_tokens_per_s"] for r in rows if r["rate_rps"] >= min(grows)]
+    knee = sum(saturated) / len(saturated) / (sum(answers) / len(answers))
+    assert knee == pytest.approx(sweep["knee_rps"], rel=1e-3) and max(holds) <= knee <= min(grows)
+    assert 0.75 <= mix["rate_rps"] / knee <= 0.85 and mix["rate_rps"] < max(holds), "four fifths of the knee, under a rate seen to hold no queue"
 
 
 def test_quantile_lengths_follow_the_distribution():
