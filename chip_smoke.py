@@ -16,6 +16,11 @@ weights made from a seed:
   pages + per-slot recurrent state; ``kda_decode`` and the grouped
   ``flash_decode_paged`` in its decode program)
   against ``benchmark/reference_solar_open2.py``;
+* a fifth family — Keye's language model at a small size on the cache
+  kind with a third leaf (K, V and an indexer key a position;
+  ``dsa_index_scores_paged`` and ``dsa_sparse_decode`` in its decode
+  program: attention over the positions the indexer selects)
+  against ``benchmark/reference_keye.py``;
 * server — ``deepspeed_tpu.init_inference("gpt2-xl")`` → ``ServingEngine``
   on the paged pool (8 slots, ``page_len`` 128), a bf16 then an int8 KV
   pool, eight seeded greedy requests each;
@@ -639,6 +644,72 @@ def serve_solar_open2(s: Smoke, device) -> Dict[str, float]:
     return gaps
 
 
+KEYE_SMOKE = {
+    "hidden_size": 256, "num_hidden_layers": 2, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 128,
+    "vocab_size": 512, "moe_intermediate_size": 128, "rms_norm_eps": 1e-6, "num_experts": 16, "num_experts_per_tok": 4,
+    "norm_topk_prob": True, "rope_theta": 10000000, "rope_scaling": {"mrope_section": [16, 24, 24]},
+    "sa_config": {"indexer_head_dim": 64, "indexer_num_heads": 4, "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+                  "q_chunk_size": 512, "topk": 128},
+    "decoder_sparse_step": 1, "mlp_only_layers": [], "tie_word_embeddings": False, "max_position_embeddings": 4096,
+    "experts_held": [8, 8], "vocab_held": 256,
+}
+TOL_KEYE_TOKEN_GAP = 0.05  # as TOL_DSV2_TOKEN_GAP: a bf16 program against the float32 reference
+
+
+def serve_keye(s: Smoke, device) -> Dict[str, float]:
+    """``init_inference(model_config=KeyeConfig)`` → the same
+    ``ServingEngine`` on the cache kind whose pages carry a third leaf:
+    compiles both programs, serves three requests over eight slots and nine
+    pages (chunked prefill under the selection mask; decode through
+    ``dsa_index_scores_paged`` and ``dsa_sparse_decode`` over the 128
+    positions each row's indexer selects of up to 308; pages reused) and
+    holds every emitted token against the plain reference's logits
+    (``benchmark/reference_keye.py``)."""
+    from benchmark import weights_keye as weights
+    from benchmark.reference_keye import Reference
+    from benchmark.runners.serve_keye import served_gaps
+    from deepspeed_tpu.comm.mesh import make_mesh
+    from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.models import keye
+    from deepspeed_tpu.serving import ServingEngine
+
+    dims = {**KEYE_SMOKE, "vocab_size": KEYE_SMOKE["vocab_held"]}  # a sliced vocabulary is a smaller one
+    mcfg = keye.KeyeConfig.from_hf(dims, experts_held=dims["experts_held"])
+    inf = deepspeed_tpu.init_inference(
+        model_config=mcfg, params=weights.program_params(s.seed, dims, jnp.bfloat16), dtype=jnp.bfloat16,
+        max_out_tokens=512, mesh=make_mesh(MeshConfig(), devices=[device]), donate_params=True,
+    )
+    srv = ServingEngine(inf, config={"num_slots": 8, "max_len": 512, "prefill_chunk": 128, "max_new_tokens": 16,
+                                     "kvcache": {"enabled": True, "page_len": 128, "num_pages": 9}})  # 8 slots: a block of rows of the selection's kernel
+    rng = np.random.default_rng(s.seed)
+    prompts = [rng.integers(1, dims["vocab_size"], n, dtype=np.int32) for n in (200, 300, 70)]
+    ids = [srv.submit(p, max_new_tokens=8) for p in prompts]
+    done = srv.drain()
+    check((srv.prefill_compiles, srv.decode_compiles) == (1, 1),
+          f"serve[keye]: {srv.prefill_compiles} prefill / {srv.decode_compiles} decode executables")
+    stats = srv.stats()
+    moe, leaves = stats["moe"], stats["kvcache"]["page_leaves"]
+    check(moe["dropped_assignments"] == 0 and moe["assignments_computed"] > 0, f"serve[keye]: expert counters {moe}")
+    check(set(leaves) == {"k", "v", "idx"} and leaves["idx"] == 2 * 9 * 128 * 64 * 2, f"serve[keye]: the pool's leaves {leaves}")
+    check(0 < stats["dsa_positions_selected"] < stats["dsa_positions_attendable"],
+          f"serve[keye]: selected {stats['dsa_positions_selected']} of {stats['dsa_positions_attendable']} attendable positions")
+    gaps = served_gaps(Reference(dims, s.seed), [{"prompt": p, "generated": list(done[i].generated)}
+                                                 for p, i in zip(prompts, ids)], 128)
+    check(gaps["token_gap_max"] <= TOL_KEYE_TOKEN_GAP,
+          f"serve[keye]: an emitted token lies {gaps['token_gap_max']:.4f} under the reference's best logit "
+          f"(tolerance {TOL_KEYE_TOKEN_GAP})")
+    expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
+                   ["dsa_index_scores_paged", "dsa_select_threshold", "dsa_sparse_decode", "moe_grouped_matmul"] if s.mosaic else [], "serve[keye] decode")
+    expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()),
+                   ["dsa_select_threshold", "moe_grouped_matmul"] if s.mosaic else [], "serve[keye] prefill")
+    check(stats["dsa_decode_kernel"].startswith("dsa_sparse_decode" if s.mosaic else "lax"),
+          f"serve[keye]: stats() say of the decode program: dsa_decode_kernel {stats['dsa_decode_kernel']!r}, "
+          f"dsa_index_form {stats['dsa_index_form']!r}")
+    say(f"serve[keye]: 3 requests x 8 tokens through the three-leaf cache, token gap mean {gaps['token_gap_mean']:.5f} "
+        f"max {gaps['token_gap_max']:.5f} over {gaps['tokens']} tokens")
+    return gaps
+
+
 def run(s: Smoke, devices: Sequence) -> None:
     """Every phase, in order; raises on the first check that fails."""
     if s.mosaic:
@@ -655,6 +726,7 @@ def run(s: Smoke, devices: Sequence) -> None:
     serve(s, devices[0])
     serve_deepseek_v2(s, devices[0])
     serve_solar_open2(s, devices[0])
+    serve_keye(s, devices[0])
 
 
 def main() -> int:

@@ -1481,10 +1481,13 @@ class ServingConfig:
                 f"'{C.SERVING}.max_len' must be >= 0 (0 derives it from the "
                 f"engine's capacity), got {out.max_len}"
             )
-        if out.max_len and out.max_len % out.prefill_chunk:
-            # chunk writes land via dynamic_update_slice, whose start
-            # clamps near the cache end — a chunk-multiple capacity is
-            # what guarantees the last chunk never clamps (docs/serving.md)
+        if out.max_len and out.max_len % out.prefill_chunk and not out.kvcache.enabled:
+            # the slot-contiguous pool's chunk writes land via one
+            # dynamic_update_slice, whose start clamps near the cache end —
+            # a chunk-multiple capacity is what guarantees the last chunk
+            # never clamps.  Under the paged pool the rule is the cache
+            # kind's: ServingEngine keeps it for every kind that does not
+            # declare ``chunk_writes_drop_past_slot`` (docs/serving.md)
             raise DeepSpeedConfigError(
                 f"'{C.SERVING}.max_len' ({out.max_len}) must be a multiple of "
                 f"prefill_chunk ({out.prefill_chunk})"

@@ -249,6 +249,20 @@ def group_limited_topk(probs: jnp.ndarray, n_group: int, topk_group: int, top_k:
     return idx.astype(jnp.int32), (w * scale).astype(jnp.float32)
 
 
+def softmax_topk(logits: jnp.ndarray, top_k: int, renormalize: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Softmax routing, one group (the Qwen3-MoE convention): ``logits (N,
+    E)`` over **all** experts; ``p = softmax(logits)`` in float32, the
+    ``top_k`` largest chosen, their weights divided by their sum
+    (``renormalize``: ``norm_topk_prob``).  The same function as
+    :func:`group_limited_topk` at one group with ``renormalize`` (the
+    tests hold them equal), without the group bookkeeping.  Returns
+    ``(idx (N, top_k) int32, weight (N, top_k) float32)``."""
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits.astype(jnp.float32), axis=-1), top_k)
+    if renormalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w.astype(jnp.float32)
+
+
 def sigmoid_topk(logits: jnp.ndarray, bias: Optional[jnp.ndarray], top_k: int, scale: float = 1.0,
                  renormalize: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sigmoid-scored routing (the DeepSeek-V3 / GLM-4-MoE convention,

@@ -328,7 +328,7 @@ def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
 
 
 def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Optional[float] = None,
-                          block_pages: int = 4):
+                          block_pages: int = 4, extra_mask=None):
     """A prefill chunk against a paged cache, **block by block over its
     context** under an online softmax: ``q (B, H, T, d)`` at positions
     ``pos[b] + t`` (the chunk's own keys already written), caches
@@ -341,6 +341,9 @@ def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Option
     the most that exists, never a slot's or the pool's length.  The
     pool is read where it lies — gathered a block at a time, or, a head
     narrower than the lanes, the rows' own pages sliced out once.
+    ``extra_mask (B, T, P * page_len)`` bool, where given, is a per-query
+    selection of the context applied beside the causal mask (learned
+    sparse attention: the same dense walk, fewer keys let through).
     Returns ``(B, H, T, d)`` in ``q``'s dtype."""
     quant = isinstance(k_cache, dict)
     B, H, T, d = q.shape
@@ -392,9 +395,13 @@ def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Option
             s = s * k_scale
         k_pos = j * S + jnp.arange(S, dtype=jnp.int32)
         ok = k_pos[None, None, :] <= q_pos[:, :, None]  # (B, T, S)
+        if extra_mask is not None:
+            ok = ok & jax.lax.dynamic_slice_in_dim(extra_mask, j * S, S, axis=2)
         s = jnp.where(ok[:, None, None], s, -1e30)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
+        if extra_mask is not None:  # a block in which a query selected nothing adds nothing (exp(0) of its all-masked row is 1)
+            p = jnp.where(ok[:, None, None], p, 0.0)
         alpha = jnp.exp(m - m_new)
         l = alpha * l + jnp.sum(p, axis=-1)
         acc = acc * alpha[..., None]

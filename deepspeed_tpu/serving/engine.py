@@ -39,7 +39,7 @@ import numpy as np
 
 from deepspeed_tpu import telemetry as _telemetry
 from deepspeed_tpu.analysis.shard import hooks as shard_hooks
-from deepspeed_tpu.config.config import ServingConfig
+from deepspeed_tpu.config.config import DeepSpeedConfigError, ServingConfig
 from deepspeed_tpu.resilience import faults
 from deepspeed_tpu.serving.journal import JournalError, RequestJournal
 from deepspeed_tpu.serving.kvcache import PagedKVPool
@@ -172,9 +172,22 @@ class ServingEngine:
         # how far the paged decode kernel's work list engages (stats()):
         # the pages its grid walks, summed over decode steps
         self._decode_pages_walked = 0
+        # learned sparse attention (a family whose config names ``select_topk``): the positions a decoding
+        # row could attend and those its selection keeps, summed over decode steps (stats())
+        self._select_topk = int(getattr(mcfg, "select_topk", 0) or 0)
+        self._dsa_attendable = 0
+        self._dsa_selected = 0
+        # the same of the prefill chunks' real queries: the chunks, and the positions their queries could attend
+        self._dsa_chunks = 0
+        self._dsa_chunk_attendable = 0
+        self._dsa_steps0 = 0  # the decode steps before the counters were last started afresh
+        # what the newest decode step of a family that says ``decode_keeps`` left on the device (docs/serving.md §Model families)
+        self.decode_keeps = bool(getattr(self._family_forward, "decode_keeps", False))
+        self.decode_kept: Optional[Dict[str, Any]] = None
         if self._paged:
             import math
 
+            kind = cache_kind(mcfg, kv_dtype) if cache_kind is not None else None  # None: the pool's PerHeadKV
             if config.max_len:
                 if max_len % kvc.page_len:
                     raise ValueError(
@@ -182,6 +195,13 @@ class ServingEngine:
                         f"serving.kvcache.page_len={kvc.page_len} — the paged "
                         "pool maps slots as whole pages (docs/serving.md "
                         "§Paged KV & prefix caching)"
+                    )
+                if max_len % config.prefill_chunk and not getattr(kind, "chunk_writes_drop_past_slot", False):
+                    # the last chunk of a long prompt runs past the slot's end; only a kind whose writes
+                    # drop those positions may have it (a clipped write lands on the slot's last valid page)
+                    raise DeepSpeedConfigError(
+                        f"'serving.max_len' ({max_len}) must be a multiple of prefill_chunk "
+                        f"({config.prefill_chunk}) for the cache kind {type(kind).__name__ if kind is not None else 'PerHeadKV'}"
                     )
             else:
                 # re-floor the derived capacity to a (chunk, page_len)
@@ -211,7 +231,7 @@ class ServingEngine:
                 pinned_prefixes=kvc.pinned_prefixes,
                 session_ttl_seconds=kvc.session_ttl_seconds,
                 spill_dir=(kvc.spill_dir or None),
-                kind=cache_kind(mcfg, kv_dtype) if cache_kind is not None else None,
+                kind=kind,
             )
         elif self._family_forward is not None:
             raise ValueError(
@@ -541,14 +561,19 @@ class ServingEngine:
 
             if self._family_forward is not None:
                 fwd = self._family_forward
+                # a family may ask that arrays of its decode step stay on the device until the next one
+                # (``decode_kept``: never fetched here; Keye's selection, which a check of the served program reads)
+                keeps = self.decode_keeps
 
                 def serve_decode(params, packed, k_pool, v_pool, state_pool):
                     f = unpack(packed)
                     toks, pos, page_table, write_mask = f["toks"], f["pos"], f["tables"], f["write_mask"]
+                    kept = {} if keeps else None
                     # row b is slot b: the rows of ``state_pool`` are the batch's
                     logits, k_pool, v_pool, state_pool, aux = fwd(
                         params, toks[:, None], k_pool, v_pool, pos, page_table=page_table,
                         write_mask=write_mask, row_valid=write_mask[:, None], state=state_pool,
+                        **({"kept": kept} if keeps else {}),
                     )
                     keys = jax.vmap(
                         lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
@@ -556,7 +581,7 @@ class ServingEngine:
                     nxt = sample_logits_pooled(
                         logits.astype(jnp.float32), keys, f["flags"], f["temps"], f["topks"], max_top_k,
                     )
-                    return (nxt, aux), k_pool, v_pool, state_pool
+                    return ((nxt, aux, kept) if keeps else (nxt, aux)), k_pool, v_pool, state_pool
 
                 donate = (2, 3, 4)
             elif self._paged:
@@ -1369,6 +1394,9 @@ class ServingEngine:
         self.pool.swap(*pools)
         if self._family_forward is not None and job.start == 0:
             self._state_resets += 1  # taken inside the program: the chunk at position 0 starts from zero
+        if self._select_topk:
+            self._dsa_chunks += 1
+            self._dsa_chunk_attendable += job.length * job.start + job.length * (job.length + 1) // 2  # query i of the chunk: start + i + 1
         # explicit d2h read doubles as the fence that keeps prefill_ms
         # honest; the value is the first generated token on final chunks
         with tl.phase("prefill.wait"):
@@ -1408,12 +1436,18 @@ class ServingEngine:
             tl.count("programs")
             nxt, *pools = fn(self.engine.params, staged, *self._pool_args())
         self.pool.swap(*pools)
+        if self.decode_keeps:
+            *nxt, self.decode_kept = nxt
         self._decode_rows += len(decoding)
         self._decode_steps += 1
         if self._paged:
             # a decoding row attends positions 0 ... prompt + generated - 1
             self._decode_pages_walked += sum(
                 (len(r.prompt) + len(r.generated) - 1) // self.pool.page_len + 1 for r in decoding)
+        if self._select_topk:
+            fills = [len(r.prompt) + len(r.generated) for r in decoding]  # positions 0 ... fill - 1, the query's own among them
+            self._dsa_attendable += sum(fills)
+            self._dsa_selected += sum(min(f, self._select_topk) for f in fills)
         with tl.phase("decode.wait"):
             out = jax.device_get(nxt)
         with tl.phase("decode.note"):
@@ -1489,6 +1523,8 @@ class ServingEngine:
         """Start the expert counters of :meth:`stats` afresh (a
         benchmark window opens)."""
         self._aux_total, self._aux_decode_touched, self._aux_decode_steps = None, 0, 0
+        self._dsa_attendable = self._dsa_selected = self._dsa_chunks = self._dsa_chunk_attendable = 0
+        self._dsa_steps0 = self._decode_steps
 
     def _moe_stats(self) -> Dict[str, Any]:
         per_expert = self._aux_total[:, :-1]
@@ -1556,7 +1592,7 @@ class ServingEngine:
             "prefill_compiles": self.prefill_compiles,
             "decode_compiles": self.decode_compiles,
             "pool_bytes": self.pool.cache_bytes(),
-            "kv_dtype": "int8" if isinstance(self.pool.k, dict) else str(
+            "kv_dtype": "int8" if isinstance(self.pool.k, dict) and "q" in self.pool.k else str(
                 np.dtype(jax.tree.leaves(self.pool.k)[0].dtype)
             ),
         }
@@ -1566,6 +1602,12 @@ class ServingEngine:
             out["decode_pages_walked"] = self._decode_pages_walked
             # what a grid of every page of every slot walks
             out["decode_pages_grid"] = self._decode_steps * self.pool.num_slots * self.pool.pages_per_slot
+        if self._select_topk:
+            out["dsa_positions_attendable"] = self._dsa_attendable
+            out["dsa_positions_selected"] = self._dsa_selected
+            # what the selection's kernel had to read: its work function (benchmark/kernels/dsa_select_threshold.py)
+            out["dsa_decode_steps"], out["dsa_chunks"] = self._decode_steps - self._dsa_steps0, self._dsa_chunks
+            out["dsa_chunk_positions_attendable"] = self._dsa_chunk_attendable
         if self.tenants is not None:
             out["tenants"] = self.tenants.snapshot()
         if self._aux_total is not None:
@@ -1581,6 +1623,7 @@ class ServingEngine:
         # (DeepSeek-V2: mla_prefill_kernel / mla_prefill_fallback, moe_grouped_kernel / moe_grouped_fallback;
         # Solar-Open2: kda_decode_kernel / _fallback, kda_prefill_form, gqa_decode_kernel / _fallback, gqa_prefill_form;
         # ZAYA: cca_decode_kernel / _fallback, cca_prefill_form, moe_router_form;
+        # Keye: dsa_index_form, dsa_select_form, dsa_decode_kernel, dsa_prefill_form, moe_router_form;
         # the paged per-head pool: kv_write_form, prefill_attend_form;
         # all three that decode through flash_decode_paged: paged_decode_walk)
         out.update(self._trace_notes)

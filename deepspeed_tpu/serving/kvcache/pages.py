@@ -110,6 +110,44 @@ class LatentKV:
         return f"1 x ({n_layer} layers x {num_pages} pages x {self.width} latent x {page_len} page_len)"
 
 
+class IndexedKV:
+    """Cache kind of learned sparse attention (docs/serving.md §Cache
+    kinds): a page carries **three leaves** a layer — K and V of the
+    grouped-query attention in the :class:`PerHeadKV` layout, and one
+    **indexer key** a position, ``(layers, pages, index_dim, page_len)``
+    (positions along the lanes, as :class:`LatentKV`: 64 numbers are half
+    a lane tile, and for a ``(page_len, 64)`` page the TPU compiler copied
+    the whole leaf in front of every kernel call) — under the one page
+    table.  ``pool.k`` is ``{"k": K pages, "idx": indexer keys}`` and
+    ``pool.v`` the V pages: every leaf has the page axis at dim 1, so the
+    allocator, copy-on-write (``page_copy`` maps over the leaves), spill
+    and tiers treat the third leaf as they treat K and V, and
+    ``pool.stats()["page_leaves"]`` gives each leaf's bytes.  A page holds
+    everything its positions left behind: ``pages_hold_all`` is True and
+    prefix reuse stays on — a shared page shares its indexer keys too."""
+
+    pages_hold_all = True
+    names_page_leaves = True  # pool.stats() lists the leaves' bytes by name
+    # every leaf's chunk write goes page by page and drops what lies past the slot's last page
+    # (``paged_cache_write_slices``, ``sparse_attention.index_cache_write``), so a slot need not hold whole chunks;
+    # a kind that does not say so keeps the chunk-multiple rule (``latent_cache_write`` clips onto the last page)
+    chunk_writes_drop_past_slot = True
+
+    def __init__(self, kv_heads: int, head_dim: int, index_dim: int, dtype: Any):
+        self.heads, self.head_dim, self.index_dim, self.dtype = int(kv_heads), int(head_dim), int(index_dim), dtype
+
+    def buffers(self, n_layer: int, num_pages: int, page_len: int):
+        from deepspeed_tpu.ops.transformer.inference import init_kv_cache
+
+        k, v = init_kv_cache(n_layer, num_pages, self.heads, page_len, self.head_dim, self.dtype)
+        return {"k": k, "idx": jnp.zeros((n_layer, num_pages, self.index_dim, page_len), self.dtype)}, v
+
+    def describe(self, n_layer: int, num_pages: int, page_len: int) -> str:
+        return (f"2 x ({n_layer} layers x {num_pages} pages x {self.heads} heads x {page_len} page_len x "
+                f"{self.head_dim} head_dim) + indexer keys ({n_layer} layers x {num_pages} pages x {self.index_dim} x "
+                f"{page_len} page_len)")
+
+
 class HybridKV:
     """Cache kind of a model whose layers leave more behind than keys
     and values per position (docs/serving.md §Cache kinds).  Two
@@ -405,7 +443,7 @@ class PagedKVPool:
         )
 
     def shape_math(self) -> str:
-        kind = "int8+f32 scales" if isinstance(self.k, dict) else str(np.dtype(
+        kind = "int8+f32 scales" if isinstance(self.k, dict) and "q" in self.k else str(np.dtype(
             jax.tree.leaves(self.k)[0].dtype))
         return (
             f"{self.kind.describe(self.n_layer, self.num_pages, self.page_len)} [{kind}] = "
@@ -1038,6 +1076,11 @@ class PagedKVPool:
             out["state_bytes"] = self.state_bytes()
             # the leaves the family declared, by name: bytes over all slots
             out["state_leaves"] = {name: int(buf.size * buf.dtype.itemsize) for name, buf in self.state.items()}
+        if getattr(self.kind, "names_page_leaves", False):
+            # a kind whose pages carry more than K and V: each leaf's bytes over the pool, by its last name
+            out["kind"] = self.kind.describe(self.n_layer, self.num_pages, self.page_len)
+            out["page_leaves"] = {name.rsplit(".", 1)[-1]: int(buf.size * buf.dtype.itemsize)
+                                  for name, buf in _named_leaves(self.k, self.v).items()}
         if not self.reuse:
             out["reuse"] = REUSE_OFF
             out["sessions_unbound"] = self.sessions_unbound

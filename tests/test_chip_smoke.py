@@ -288,6 +288,59 @@ def test_zaya_steps_compile_for_v5e_with_pages_and_tail_updated_in_place(which, 
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_keye_steps_compile_for_v5e_with_all_three_leaves_updated_in_place(which, v5e_chip, monkeypatch):
+    """Two layers of Keye-VL-2.0's language model at the published widths
+    on the cell's three-leaf cache (16 slots of 264 pages, 3,329 pages: K,
+    V and an indexer key a position), by the chip's compiler without the
+    chip: a decode step holds ``dsa_index_scores_paged`` and
+    ``dsa_sparse_decode`` once a layer (reading the stacked leaves through
+    a merged leading dim and an offset page table) and the experts' kernel
+    at 2048 / 768 for its 16 x 8 assignment rows; a chunk of 2,048 holds
+    the experts' kernel; neither leaves a copy of a leaf."""
+    from deepspeed_tpu.models import keye
+    from deepspeed_tpu.ops.kernels import grouped_matmul, sparse_decode
+
+    monkeypatch.setenv("DS_KERNELS", "1")
+    for mod in (sparse_decode, grouped_matmul):
+        monkeypatch.setattr(mod, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
+    layers, slots, pages, per_slot, chunk = 2, 16, 3329, 264, 2048
+    cfg = keye.KeyeConfig(num_hidden_layers=layers, experts_held=(0, 16), vocab_held=18992)
+    on_chip = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    params = jax.tree.map(lambda sh: on_chip(sh, jnp.bfloat16), keye.param_shapes(cfg), is_leaf=lambda sh: isinstance(sh, tuple))
+    kind = keye.cache_kind(cfg, jnp.bfloat16)
+    k, v = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(lambda: kind.buffers(layers, pages, 128)))
+    notes = {}
+    if which == "decode":
+        def step(p, t, pos, table, wm, k, v):
+            return keye.forward_with_cache(p, t[:, None], k, v, pos, cfg, table, write_mask=wm, row_valid=wm[:, None], trace_notes=notes)
+        args = (params, on_chip((slots,), jnp.int32), on_chip((slots,), jnp.int32), on_chip((slots, per_slot), jnp.int32),
+                on_chip((slots,), jnp.bool_), k, v)
+    else:
+        def step(p, t, table, pos, rv, k, v):
+            return keye.forward_with_cache(p, t, k, v, pos[None], cfg, table[None], row_valid=rv, trace_notes=notes)
+        args = (params, on_chip((1, chunk), jnp.int32), on_chip((per_slot,), jnp.int32), on_chip((), jnp.int32),
+                on_chip((1, chunk), jnp.bool_), k, v)
+    compiled = jax.jit(step, donate_argnums=(len(args) - 2, len(args) - 1)).lower(*args).compile()
+    found = chip_smoke.mosaic_kernels(compiled.as_text())
+    buckets = -(-per_slot * 128 // 4096)  # the selection's threshold kernel stands once a bucket of context a layer (one is taken)
+    assert found.pop("dsa_select_threshold") == layers * buckets
+    if which == "decode":
+        assert found == {"dsa_index_scores_paged": layers, "dsa_sparse_decode": layers, "moe_grouped_matmul": 2 * layers}
+        assert notes["dsa_decode_kernel"].startswith("dsa_sparse_decode") and notes["dsa_index_form"].startswith("dsa_index_scores_paged")
+        assert notes["moe_grouped_kernel"] == "128" and "dsa_select_threshold" in notes["dsa_select_form"]
+    else:
+        assert found == {"moe_grouped_matmul": 2 * layers} and notes["moe_grouped_kernel"] == "16384"
+        assert notes["dsa_prefill_form"].startswith("paged_chunk_attention") and "dsa_select_threshold" in notes["dsa_prefill_select_form"]
+    assert notes["moe_grouped_fallback"] == ""
+    m = compiled.memory_analysis()
+    cache_bytes = sum(int(np.prod(a.shape)) * 2 for a in jax.tree.leaves((k, v)))
+    assert m.alias_size_in_bytes >= cache_bytes                         # all three leaves come back in place
+    # no temporary is a leaf of pages: a decode step's stay under half of one; a chunk's are its (2,048 x 33,792) scores,
+    # their ordered bits and the masks (~0.9 GB whatever the number of layers; a leaf of the cell's 8 layers is 3.5 GB)
+    assert m.temp_size_in_bytes < (int(np.prod(v.shape)) * 2 // 2 if which == "decode" else 1.2e9)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_gpt2_xl_paged_steps_compile_for_v5e_with_the_pool_read_and_written_where_it_lies(which, v5e_chip, monkeypatch):
     """Four layers of GPT-2 XL at the published widths on the GPT-2
     serve cells' paged pool (16 slots, 80 pages of 128 x 64: a head of

@@ -1195,3 +1195,71 @@ def test_flash_decode_paged_reads_both_tile_forms(d, quant):
     got = fd.flash_decode_paged(q, kc, vc, tab, pos)
     want = inf.paged_cache_attention(q, kc, vc, tab, pos, use_kernel=False)
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), atol=2e-2, rtol=2e-2)
+
+
+def test_indexed_kind_third_leaf_is_written_shared_copied_on_write_freed_and_reused(tmp_path):
+    """A cache kind whose pages carry a third leaf (an indexer key a
+    position): the leaf is written by slice writes under the same page
+    table as K and V, shared with them on a prefix hit, copied with them
+    on write, spilled and restored under its own name, and its pages go
+    back to the free list and out again with theirs."""
+    from deepspeed_tpu.ops.transformer import inference as inf
+    from deepspeed_tpu.ops.transformer import sparse_attention as dsa
+    from deepspeed_tpu.serving.kvcache.pages import IndexedKV, _named_leaves
+
+    L, Hkv, d, di, page_len = 2, 2, 8, 4, 8
+    pool = PagedKVPool(L, 2, Hkv, 64, d, jnp.float32, page_len=page_len, num_pages=12, prefill_chunk=4,
+                       kind=IndexedKV(Hkv, d, di, jnp.float32), spill_dir=str(tmp_path))
+    assert set(pool.k) == {"k", "idx"} and pool.k["k"].shape == pool.v.shape == (L, 12, Hkv, page_len, d)
+    assert pool.k["idx"].shape == (L, 12, di, page_len) and pool.state is None and pool.reuse  # positions along the lanes
+    st = pool.stats()
+    assert st["page_leaves"] == {"k": L * 12 * Hkv * page_len * d * 4, "v": L * 12 * Hkv * page_len * d * 4, "idx": L * 12 * page_len * di * 4}
+    assert sum(st["page_leaves"].values()) == pool.cache_bytes() and "indexer keys" in st["kind"] and "reuse" not in st
+    assert set(_named_leaves(pool.k, pool.v)) == {"k.k", "k.idx", "v"} and "float32" in pool.shape_math()
+
+    r0 = _KReq("r0", list(range(1, 15)), max_new=2)  # 14 tokens: a full page and a partly filled one
+    r0.slot = pool.alloc_request(r0)
+    table = jnp.asarray(pool.table(r0.slot))[None]
+    rows = jnp.arange(14 * di, dtype=jnp.float32).reshape(1, 14, di) + 1.0
+    kv = jnp.arange(14 * Hkv * d, dtype=jnp.float32).reshape(1, 14, Hkv, d).transpose(0, 2, 1, 3) + 1.0
+    for layer in range(L):  # a chunk that starts inside a page (position 3), after a first one of three
+        for at, n in ((0, 3), (3, 11)):
+            pos = jnp.asarray([at], jnp.int32)
+            pool.k = {"k": inf.paged_cache_write_slices(pool.k["k"], layer, kv[:, :, at:at + n] * (layer + 1), table, pos),
+                      "idx": dsa.index_cache_write(pool.k["idx"], layer, rows[:, at:at + n] * (layer + 1), table, pos)}
+            pool.v = inf.paged_cache_write_slices(pool.v, layer, -kv[:, :, at:at + n] * (layer + 1), table, pos)
+    ctx = np.asarray(dsa.index_context(pool.k["idx"], 1, table))[0]
+    np.testing.assert_array_equal(ctx[:14], 2 * np.asarray(rows[0]))
+    assert not ctx[14:].any() and not np.asarray(pool.k["idx"][:, GARBAGE_PAGE]).any()
+    one = dsa.index_cache_write(pool.k["idx"], 0, jnp.full((1, 1, di), 7.0), table, jnp.asarray([14], jnp.int32), jnp.asarray([False]))
+    assert (np.asarray(one[0, GARBAGE_PAGE, :, 0]) == 7.0).all() and np.array_equal(np.asarray(one[:, 1:]), np.asarray(pool.k["idx"][:, 1:]))
+
+    pool.learn_prefix(r0)
+    shared = list(pool._slot_pages[r0.slot])
+    r1 = _KReq("r1", list(range(1, 15)) + [99, 98], max_new=2)  # starts with r0's prompt: a hit of 12, the tail page copied on write
+    r1.slot = pool.alloc_request(r1)
+    assert r1.prefill_pos == 12 and pool._slot_pages[r1.slot][0] == shared[0] and pool.refcount(shared[0]) == 3
+    src, dst = pool.consume_cow(r1.slot)
+    assert (src, dst) == (shared[1], pool._slot_pages[r1.slot][1]) and dst != src
+    pool.k, pool.v = inf.page_copy(pool.k, src, dst), inf.page_copy(pool.v, src, dst)  # what a prefill program does with the pair
+    for name, buf in _named_leaves(pool.k, pool.v).items():
+        np.testing.assert_array_equal(np.asarray(buf[:, dst]), np.asarray(buf[:, src]), err_msg=name)
+    assert np.asarray(pool.k["idx"][:, dst]).any()
+
+    # spilled and restored under its own name, with K and V
+    host = pool._gather_host([shared[0], dst])
+    assert set(host) == {"k.k", "k.idx", "v"} and host["k.idx"].shape == (L, 2, di, page_len)
+    before = np.asarray(pool.k["idx"][:, shared[0]])
+    pool._scatter_device([dst, shared[0]], host)  # swapped
+    np.testing.assert_array_equal(np.asarray(pool.k["idx"][:, dst]), before)
+
+    free0 = pool.pages_free
+    pool.retire(r0.slot, r0)
+    pool.retire(r1.slot, r1)
+    assert pool.refcount(dst) == 0 and pool.pages_free > free0  # r1's private pages are free again; the entry keeps the shared ones
+    _assert_no_leaks(pool)
+    r2 = _KReq("r2", list(range(50, 80)), max_new=2)
+    r2.slot = pool.alloc_request(r2)
+    assert dst in pool._slot_pages[r2.slot] or pool.pages_free < free0 + 2  # freed pages go out again
+    pool.retire(r2.slot, r2)
+    _assert_no_leaks(pool)
