@@ -228,19 +228,23 @@ def check_flash_attention(s: Smoke) -> Dict[str, float]:
     return errs
 
 
-def check_flash_decode_paged(s: Smoke, mcfg, kv_dtype) -> float:
+def check_flash_decode_paged(s: Smoke, mcfg, kv_dtype, kv_heads=None, head_dim=None, pages=None) -> float:
     """``flash_decode_paged`` vs gather + ``cache_attention(use_kernel=False)``
     on a pool written through the real paged write, at the server's
     shapes: scattered pages, a different fill per slot, and one slot
     that does not decode — the kernel walks the work list of the others'
-    filled pages, and that slot reads 0."""
-    from deepspeed_tpu.ops.kernels.flash_decode import decode_paged_supported, paged_work_list
+    filled spans, and that slot reads 0.  The server's own shapes are
+    multi-head attention with every head of a page in one program (the
+    int8 pool: two pages an item); ``kv_heads``, ``head_dim`` and
+    ``pages`` a slot ask for a grouped call under another tile."""
+    from deepspeed_tpu.ops.kernels.flash_decode import decode_paged_supported, paged_tile, paged_work_list
     from deepspeed_tpu.ops.transformer.inference import (
         init_kv_cache, paged_cache_attention, paged_cache_write,
     )
 
-    B, H, d = s.slots, mcfg.n_head, mcfg.head_dim
-    P = s.max_len // s.page_len
+    B, H, d = s.slots, mcfg.n_head, head_dim or mcfg.head_dim
+    Hkv = kv_heads or H
+    P = pages or s.max_len // s.page_len
     rng = np.random.default_rng(s.seed)
     table = (1 + rng.permutation(B * P)).reshape(B, P).astype(np.int32)  # page 0 is the garbage page
     fill = rng.integers(1, P * s.page_len, (B,)).astype(np.int32)
@@ -248,17 +252,18 @@ def check_flash_decode_paged(s: Smoke, mcfg, kv_dtype) -> float:
     live = np.ones((B,), bool)
     live[-1] = B == 1             # and one, where there are several, that does not decode
     k_pool, v_pool = (jax.tree.map(lambda a: a[0], c)
-                      for c in init_kv_cache(1, 1 + B * P, H, s.page_len, d, kv_dtype))
+                      for c in init_kv_cache(1, 1 + B * P, Hkv, s.page_len, d, kv_dtype))
     kk, kv_, kq = jax.random.split(jax.random.PRNGKey(s.seed + 1), 3)
-    rows = (B, H, P * s.page_len, d)
+    rows = (B, Hkv, P * s.page_len, d)
     zero = jnp.zeros((B,), jnp.int32)
     k_pool = paged_cache_write(k_pool, jax.random.normal(kk, rows, jnp.float32).astype(jnp.bfloat16), table, zero)
     v_pool = paged_cache_write(v_pool, jax.random.normal(kv_, rows, jnp.float32).astype(jnp.bfloat16), table, zero)
     q = jax.random.normal(kq, (B, H, 1, d), jnp.float32).astype(jnp.bfloat16)
+    heads, span = paged_tile(k_pool, P)
 
     def attend(use_kernel):
         def f(q, k, v, t, p, m):
-            return paged_cache_attention(q, k, v, t, p, use_kernel=use_kernel, work=paged_work_list(p, m, s.page_len, P))
+            return paged_cache_attention(q, k, v, t, p, use_kernel=use_kernel, work=paged_work_list(p, m, s.page_len, P, span))
         return jax.jit(f)(q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(fill), jnp.asarray(live))
 
     got = attend(True)
@@ -268,7 +273,8 @@ def check_flash_decode_paged(s: Smoke, mcfg, kv_dtype) -> float:
     if decode_paged_supported(B, H, P, s.page_len, d):  # the gather + lax form attends every row
         check(not np.asarray(got[~live], np.float32).any(), "flash_decode_paged: a row its work list does not visit reads other than 0")
     name = "int8" if kv_dtype == "int8" else jnp.dtype(kv_dtype).name
-    say(f"flash_decode_paged[{name}] vs cache_attention (B={B} H={H} pages={P}x{s.page_len} d={d}): {err:.2e}")
+    say(f"flash_decode_paged[{name}] vs cache_attention (B={B} H={H} over {Hkv} KV heads, pages={P}x{s.page_len} d={d}; "
+        f"{heads} heads x {span} pages a grid step): {err:.2e}")
     # the output is cast to bf16 on both paths: one bf16 ulp on top of TOL_F32
     check(err < TOL_F32 + 2 ** -8, f"flash_decode_paged[{name}] off its reference by {err}")
     return err
@@ -458,6 +464,8 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
     for kv in ("model", "int8"):
         if s.mosaic:
             check_flash_decode_paged(s, mcfg, "int8" if kv == "int8" else inf.dtype)
+            if kv == "model":  # grouped queries over one KV head of whole lane rows, eight pages a slot: a span of eight
+                check_flash_decode_paged(s, mcfg, inf.dtype, kv_heads=1, head_dim=128, pages=8)
         srv = ServingEngine(inf, config={
             "num_slots": s.slots, "max_len": s.max_len, "kv_cache_dtype": kv,
             "prefill_chunk": s.prefill_chunk,

@@ -346,14 +346,15 @@ def forward_with_cache(params: Dict[str, Any], tokens, k_pool, v_pool, state, po
     ``deepseek_v2.forward_with_cache`` returns it.  ``routing_sink`` is
     given each layer's chosen experts ``(B * T, top_k)``."""
     from deepspeed_tpu.moe.layer import dropless_held_experts, sigmoid_topk
-    from deepspeed_tpu.ops.kernels.flash_decode import paged_work_list
+    from deepspeed_tpu.ops.kernels.flash_decode import paged_tile, paged_work_list
 
     B, T = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)
     valid = None if row_valid is None else row_valid.reshape(B * T)
     aux = []
     paged_layer = state_layer = 0
-    work = paged_work_list(pos, write_mask, k_pool.shape[3], page_table.shape[1]) if T == 1 else None  # once, for every GQA layer
+    P = page_table.shape[1]
+    work = paged_work_list(pos, write_mask, k_pool.shape[3], P, paged_tile(k_pool, P)[1]) if T == 1 else None  # once, for every GQA layer
     for layer, lp in enumerate(params["layers"]):
         if layer in cfg.gqa_layers:
             x, k_pool, v_pool = gqa_block(cfg, lp, x, k_pool, v_pool, paged_layer, pos, page_table, write_mask,

@@ -246,11 +246,12 @@ def forward_with_cache(params: Dict[str, Any], tokens, k_pool, v_pool, state, po
     given each layer's chosen expert ``(B * T, 1)``."""
     from deepspeed_tpu.moe.layer import dropless_held_experts, mlp_top1
     from deepspeed_tpu.ops.transformer import compressed_attention as cca
-    from deepspeed_tpu.ops.kernels.flash_decode import paged_work_list
+    from deepspeed_tpu.ops.kernels.flash_decode import paged_tile, paged_work_list
 
     B, T = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)
-    work = paged_work_list(pos, write_mask, k_pool.shape[3], page_table.shape[1]) if T == 1 else None  # once, for every layer
+    P = page_table.shape[1]
+    work = paged_work_list(pos, write_mask, k_pool.shape[3], P, paged_tile(k_pool, P)[1]) if T == 1 else None  # once, for every layer
     r = jnp.zeros((B * T, cfg.router_hidden_size), jnp.float32)  # the router's carry: nothing before the first layer
     valid = None if row_valid is None else row_valid.reshape(B * T)
     aux = []

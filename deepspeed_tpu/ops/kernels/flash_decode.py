@@ -274,51 +274,84 @@ def decode_paged_supported(B: int, H: int, P: int, page_len: int, d: int) -> boo
     return page_len >= 128 and page_len % 128 == 0 and d >= 8 and B >= 1 and H >= 1 and P >= 1
 
 
-def paged_work_list(pos, live, page_len: int, pages_per_slot: int):
-    """The work of one paged decode step as a list of (slot, logical
-    page) items: for every row with ``live[b]`` (``None``: every row)
-    the pages ``0 ... pos[b] // page_len``, in slot order, then page
-    order.  Returns ``(slot, page, n, live)``: two int32 arrays of the
-    static capacity ``B * pages_per_slot``, the count ``n (1,)`` — the
+# K + V bytes a grid step of the paged kernel may hold: what sets its tile
+# (:func:`paged_tile`).  A Mosaic grid step costs ~0.4 us whatever it
+# moves and a mebibyte is ~1.3 us of the v5e's HBM, so at the budget the
+# step's own cost is under a quarter of its bytes' time; twice over for
+# the pipeline's second buffer it is 2 MiB of the 16 MiB of VMEM a kernel
+# may take.  Fixed by what the chip showed: ZAYA1's page reads 0.49 / 0.32 /
+# 0.26 / 0.21 us at 128 KB ... 1 MiB a step, Solar-Open2's 0.86 / 0.75 /
+# 0.74 at 0.5 / 1 / 2 MiB (docs/kernels.md; PERF.md, PR 46).
+TILE_BYTES = 1 << 20
+
+
+def paged_tile(cache, pages_per_slot: int):
+    """``(KV heads a program, pages an item)`` of a paged decode call:
+    the tile a grid step holds, read off the pool's shape and nothing
+    else.  Every KV head of a page whose K + V fit :data:`TILE_BYTES`
+    (the largest divisor of ``Hkv`` that does), then a **span** of the
+    largest of 8, 4, 2, 1 consecutive pages of a row that divides
+    ``pages_per_slot`` and still fits.  ``cache`` is the K pool, its
+    int8 pair, or a ``ShapeDtypeStruct``: ``(..., Hkv, page_len, d)``."""
+    leaf = cache["q"] if isinstance(cache, dict) else cache
+    Hkv, page_len, d = leaf.shape[-3:]
+    pair = 2 * page_len * d * jnp.dtype(leaf.dtype).itemsize  # K + V bytes of one KV head of one page
+    heads = max(h for h in range(1, Hkv + 1) if Hkv % h == 0 and (h == 1 or h * pair <= TILE_BYTES))
+    span = next(c for c in (8, 4, 2, 1) if pages_per_slot % c == 0 and (c == 1 or c * heads * pair <= TILE_BYTES))
+    return heads, span
+
+
+def paged_work_list(pos, live, page_len: int, pages_per_slot: int, span: int = 1):
+    """The work of one paged decode step as a list of (slot, span) items,
+    a span being ``span`` consecutive logical pages of a row
+    (:func:`paged_tile`; 1: an item is a page): for every row with
+    ``live[b]`` (``None``: every row) the spans ``0 ... pos[b] //
+    (page_len * span)``, in slot order, then span order.  Returns
+    ``(slot, span_index, n, live)``: two int32 arrays of the static
+    capacity ``B * pages_per_slot // span``, the count ``n (1,)`` — the
     items past ``n`` repeat the last one and are not walked
     (``compact_rows``' convention; with ``n == 0`` they name slot 0,
-    page 0) — and the rows the list visits, ``(B,)`` bool.  It depends
+    span 0) — and the rows the list visits, ``(B,)`` bool.  It depends
     on nothing a layer has, so a decode program builds it once."""
     from deepspeed_tpu.ops.kernels.kda_decode import compact_rows
 
-    P = pages_per_slot
+    if pages_per_slot % span:
+        raise ValueError(f"paged_work_list: spans of {span} pages do not tile a slot of {pages_per_slot}")
+    S = pages_per_slot // span
     pos = jnp.asarray(pos, jnp.int32)
     live = jnp.ones(pos.shape, bool) if live is None else live.astype(bool)
-    pages = jnp.where(live, jnp.clip(pos // page_len, 0, P - 1) + 1, 0)  # (B,) filled pages a row
-    filled = jnp.arange(P, dtype=jnp.int32)[None, :] < pages[:, None]  # (B, P), row-major: slot order, then page order
+    spans = jnp.where(live, jnp.clip(pos // (page_len * span), 0, S - 1) + 1, 0)  # (B,) filled spans a row
+    filled = jnp.arange(S, dtype=jnp.int32)[None, :] < spans[:, None]  # (B, S), row-major: slot order, then span order
     items, n = compact_rows(filled.reshape(-1))
-    return items // P, items % P, n, live
+    return items // S, items % S, n, live
 
 
 def _flash_decode_paged_kernel(
     pt_ref,           # SMEM (B, P) int32 — per-slot page table (scalar prefetch)
     pos_ref,          # SMEM (B,) int32 — per-slot query position (scalar prefetch)
-    slot_ref,         # SMEM (B * P,) int32 — slot of each work item (scalar prefetch)
-    page_ref,         # SMEM (B * P,) int32 — logical page of each work item (scalar prefetch)
+    slot_ref,         # SMEM (B * P / span,) int32 — slot of each work item (scalar prefetch)
+    span_ref,         # SMEM (B * P / span,) int32 — span of each work item (scalar prefetch)
     n_ref,            # SMEM (1,) int32 — items to walk: the traced bound of the grid's last axis
     q_ref,            # (1, block_heads, group, d): the query heads of block_heads KV heads
-    k_ref,            # (1, block_heads, page_len, d)  — THE page pt[slot, page], codes or bf16/f32
-    v_ref,            # (1, block_heads, page_len, d); both (1, block_heads, d, page_len) when ``lanes_hold_rows``
-    *rest,            # [ks_ref, vs_ref (1,block_heads,1,page_len)]; o_ref; scratch m, l, acc
+    *rest,            # ``span`` K pages, ``span`` V pages, each (1, block_heads, page_len, d) — THE page pt[slot, span * s + j],
+                      # codes or bf16/f32, (1, block_heads, d, page_len) when ``lanes_hold_rows``;
+                      # [``span`` K scales, ``span`` V scales (1, block_heads, 1, page_len)]; o_ref; scratch m, l, acc, scores
     sm_scale: float,
     page_len: int,
     quant: bool,
     block_heads: int,
     group: int,
+    span: int,
     lanes_hold_rows: bool,
 ):
-    refs = list(rest)
-    ks_ref = refs.pop(0) if quant else None
-    vs_ref = refs.pop(0) if quant else None
-    o_ref, m_ref, l_ref, acc_ref = refs
+    k_refs, v_refs, *scales = (rest[g * span: (g + 1) * span] for g in range(4 if quant else 2))
+    ks_refs, vs_refs = scales or (None, None)
+    o_ref, m_ref, l_ref, acc_ref, s_ref = rest[(4 if quant else 2) * span:]
 
     i = pl.program_id(1)
-    b, p_idx = slot_ref[i], page_ref[i]
+    b, s_idx = slot_ref[i], span_ref[i]
+    heads = [(h, pl.dslice(h * group, group)) for h in range(block_heads)]   # a KV head and the rows of its query heads
+    cols = [pl.dslice(j * page_len, page_len) for j in range(span)]          # a page's positions in the item's score rows
 
     @pl.when(n_ref[0] == 0)
     def _nothing_decodes():
@@ -327,55 +360,58 @@ def _flash_decode_paged_kernel(
 
     @pl.when(n_ref[0] > 0)
     def _item():
-        @pl.when(p_idx == 0)
+        @pl.when(s_idx == 0)
         def _init():
             m_ref[:] = jnp.full_like(m_ref, NEG_INF)
             l_ref[:] = jnp.zeros_like(l_ref)
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        # logical position of this page's rows within the slot: the page
-        # table indirection happened in the BlockSpec index_map (the k/v
-        # blocks ARE page pt[b, p]), so the mask math is position-space
-        key_idx = p_idx * page_len + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_len), 1
-        )
-        allowed = key_idx <= pos_ref[b]
-        # static unroll over the KV heads of this program; a KV head's
-        # ``group`` query heads share its page: one fetch, ``group`` rows
-        for h in range(block_heads):
-            rows = pl.dslice(h * group, group)
-            q = q_ref[0, h].astype(jnp.float32)                      # (group, d)
-            # the page's positions are the rows of its tile, or — a head
-            # narrower than the lanes — its columns: the contraction moves
-            k = k_ref[0, h].astype(jnp.float32)                      # (page_len, d) | (d, page_len)
-            scores = jax.lax.dot_general(
-                q, k,
-                dimension_numbers=(((1,), (0 if lanes_hold_rows else 1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * sm_scale                                             # (group, page_len)
-            if quant:
-                scores = scores * ks_ref[0, h]                       # in-register dequant
-            scores = jnp.where(allowed, scores, NEG_INF)
+        # Three passes over the item, so that no product waits for another
+        # head's softmax: (1) every (page, KV head) pair's scores — a KV
+        # head's ``group`` query heads share its page: one fetch, ``group``
+        # rows — into one (rows, span * page_len) buffer; the page's
+        # positions are the rows of its tile, or — a head narrower than the
+        # lanes — its columns: the contraction moves
+        for j in range(span):
+            for h, rows in heads:
+                scores = jax.lax.dot_general(
+                    q_ref[0, h].astype(jnp.float32),                     # (group, d)
+                    k_refs[j][0, h].astype(jnp.float32),                 # (page_len, d) | (d, page_len)
+                    dimension_numbers=(((1,), (0 if lanes_hold_rows else 1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )                                                    # (group, page_len)
+                if quant:
+                    scores = scores * ks_refs[j][0, h]               # in-register dequant
+                s_ref[rows, cols[j]] = scores
 
-            m_prev = m_ref[rows]                                     # (group, 1)
-            l_prev = l_ref[rows]
-            m_cur = jnp.max(scores, axis=1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            p = jnp.exp(scores - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[rows] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            m_ref[rows] = m_new
-            if quant:
-                p = p * vs_ref[0, h]
-            v = v_ref[0, h].astype(jnp.float32)
-            acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
-                p, v,
-                dimension_numbers=(((1,), (1 if lanes_hold_rows else 0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+        # (2) one online-softmax update for every row of the item.  The
+        # page table indirection happened in the BlockSpec index_map (the
+        # k/v blocks ARE pages pt[b, span * s + j]), so the mask math is
+        # position-space; a row's last span may reach past its position:
+        # those pages were fetched, and every position of theirs is masked
+        key_idx = s_idx * (span * page_len) + jax.lax.broadcasted_iota(jnp.int32, (1, span * page_len), 1)
+        scores = jnp.where(key_idx <= pos_ref[b], s_ref[:] * sm_scale, NEG_INF)
+        m_prev = m_ref[:]                                            # (rows, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        p = jnp.exp(scores - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[:] = m_new
+        s_ref[:] = p
+        acc_ref[:] = acc_ref[:] * alpha
 
-        # the slot's last item: the page its position lies in
-        @pl.when(p_idx == pos_ref[b] // page_len)
+        # (3) every pair's probabilities against its V page
+        for h, rows in heads:
+            acc_ref[rows] += sum(
+                jax.lax.dot_general(
+                    s_ref[rows, cols[j]] * vs_refs[j][0, h] if quant else s_ref[rows, cols[j]],
+                    v_refs[j][0, h].astype(jnp.float32),
+                    dimension_numbers=(((1,), (1 if lanes_hold_rows else 0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) for j in range(span))
+
+        # the slot's last item: the span its position lies in
+        @pl.when(s_idx == pos_ref[b] // (page_len * span))
         def _emit():
             l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
             o_ref[:] = (acc_ref[:] / l).reshape(o_ref.shape).astype(o_ref.dtype)
@@ -400,33 +436,44 @@ def flash_decode_paged(
     cache's ``Hkv``; query head ``i`` attends KV head ``i // (H / Hkv)``.
     The ``H / Hkv`` query heads of a KV head sit in one tile against its
     page, so a page is fetched once a KV head, not once a query head.
-    Multi-head attention is the group of 1 — one KV head a program; a
-    grouped call takes every KV head's page in one program: with groups
-    there are few KV heads and many slots, and a program a (slot, KV
-    head, page) is mostly grid steps.
+
+    **A grid step is sized by its bytes** (:func:`paged_tile`, from the
+    pool's shape alone): every KV head of a page in one program —
+    multi-head attention, the group of 1, included — up to
+    :data:`TILE_BYTES` of K + V, and where a page is small a **span** of
+    8, 4 or 2 consecutive pages of a row, each page an operand of its
+    own under the index map ``pt[slot, span * s + j]``.  A step's fixed
+    cost (~0.4 us) is then paid once for about a mebibyte.  A row's last
+    span may reach past its position: those pages are read (the table's
+    entries there name pages that exist) and add nothing.
 
     **The grid walks a work list**, ``(Hkv / heads a program, items)``:
-    ``work = (slot, page, n, live)`` as :func:`paged_work_list` builds it —
-    the filled pages ``0 ... pos[b] // page_len`` of the rows that
-    decode, slot by slot — rides as prefetched scalars, and ``n``, a
-    traced value, is the bound of the grid's last axis: a page past a
-    slot's position and a row that does not decode cost no grid step,
-    no DMA and no arithmetic.  A slot's items are consecutive, so its
-    output block stays in VMEM over them and goes back when the slot
-    changes.  ``work=None`` builds the list from ``pos`` with every row
-    live; a decode program builds it once and hands it to every layer's
-    call (the layers differ in the *table*, not in the items).  Rows no
-    item visits read 0.
+    ``work = (slot, span, n, live)`` as :func:`paged_work_list` builds it
+    under this call's span — the filled spans of the rows that decode,
+    slot by slot — rides as prefetched scalars, and ``n``, a traced
+    value, is the bound of the grid's last axis: a span past a slot's
+    position and a row that does not decode cost no grid step, no DMA
+    and no arithmetic.  A slot's items are consecutive, so its output
+    block stays in VMEM over them and goes back when the slot changes.
+    ``work=None`` builds the list from ``pos`` with every row live; a
+    decode program builds it once and hands it to every layer's call
+    (the layers differ in the *table*, not in the items).  Rows no item
+    visits read 0.
 
     The page table rides the grid as a **prefetched scalar** too
-    (``PrefetchScalarGridSpec``): the k/v BlockSpec index_map reads
-    ``pt[slot[i], page[i]]``, so each program's K/V page streams
+    (``PrefetchScalarGridSpec``), so each program's K/V pages stream
     HBM→VMEM directly — the gather the lax path materializes never
     exists.  The item axis is sequential; one page is one kv block
     (``decode_paged_supported`` demands page_len be lane-aligned), and
     the online softmax state lives in VMEM scratch exactly like
-    :func:`flash_decode`, started at a slot's page 0 and emitted at the
-    page its position lies in."""
+    :func:`flash_decode`, started at a slot's span 0 and emitted at the
+    span its position lies in.  Scores are float32 and the int8 pool's
+    scales fold in in-register whatever the tile.  An item's products
+    run in three passes — every (page, KV head) pair's scores, one
+    softmax update for all its rows, every pair's probabilities x V — so
+    that no pair's products wait for another's softmax: taken pair by
+    pair that chain, not the bytes, was the step's time
+    (docs/kernels.md)."""
     quant = isinstance(k_cache, dict)
     k_op = k_cache["q"] if quant else k_cache
     v_op = v_cache["q"] if quant else v_cache
@@ -448,7 +495,7 @@ def flash_decode_paged(
     if interpret is None:
         interpret = pallas_interpret_default()
     group = H // Hkv
-    bh = 1 if group == 1 else Hkv  # KV heads a program holds
+    bh, span = paged_tile(k_op, P)
     # A head narrower than the 128 lanes: the TPU stores ``(..., page_len,
     # d)`` with ``page_len`` in the lanes (``d`` there would pad every
     # row to 128), so the tile Mosaic is handed is ``(d, page_len)`` —
@@ -464,26 +511,25 @@ def flash_decode_paged(
     # a position is inside the slot: the page it lies in is an item of the list
     pos_vec = jnp.clip(jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,)), 0, P * page_len - 1)
     if work is None:
-        work = paged_work_list(pos_vec, None, page_len, P)
-    slot, page, n, live = work
+        work = paged_work_list(pos_vec, None, page_len, P, span)
+    slot, span_idx, n, live = work
+    if slot.shape[0] != B * (P // span):
+        raise ValueError(f"flash_decode_paged: a work list of {slot.shape[0]} items is not one over spans of {span} pages "
+                         f"({B} slots x {P} pages): build it with paged_tile()'s span")
 
     # index maps receive (*grid_ids, *scalar_prefetch_refs)
-    row = lambda h, i, pt, pv, sl, pg, n: (sl[i], h, 0, 0)  # noqa: E731
-    kv_page = lambda h, i, pt, pv, sl, pg, n: (pt[sl[i], pg[i]], h, 0, 0)  # noqa: E731
-    in_specs = [
-        pl.BlockSpec((1, bh, group, d), row),
-        pl.BlockSpec(page_block, kv_page),
-        pl.BlockSpec(page_block, kv_page),
-    ]
-    args = [q.reshape(B, Hkv, group, d), k_op, v_op]
+    row = lambda h, i, pt, pv, sl, sp, n: (sl[i], h, 0, 0)  # noqa: E731
+    kv_page = lambda j: (lambda h, i, pt, pv, sl, sp, n: (pt[sl[i], sp[i] * span + j], h, 0, 0))  # noqa: E731
+    pages = [pl.BlockSpec(page_block, kv_page(j)) for j in range(span)]
+    in_specs = [pl.BlockSpec((1, bh, group, d), row)] + pages + pages
+    args = [q.reshape(B, Hkv, group, d)] + [k_op] * span + [v_op] * span
     if quant:
         # (NP, H, page_len, 1) scales -> (NP, H, 1, page_len) row
         # vectors (contiguous reshape) sharing the score-row layout
         ks = k_cache["s"].reshape(NP, Hkv, 1, page_len)
         vs = v_cache["s"].reshape(NP, Hkv, 1, page_len)
-        spec = pl.BlockSpec((1, bh, 1, page_len), kv_page)
-        in_specs += [spec, spec]
-        args += [ks, vs]
+        in_specs += [pl.BlockSpec((1, bh, 1, page_len), kv_page(j)) for j in range(span)] * 2
+        args += [ks] * span + [vs] * span
 
     kern = functools.partial(
         _flash_decode_paged_kernel,
@@ -492,6 +538,7 @@ def flash_decode_paged(
         quant=quant,
         block_heads=bh,
         group=group,
+        span=span,
         lanes_hold_rows=lanes_hold_rows,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -504,6 +551,7 @@ def flash_decode_paged(
             pltpu.VMEM((bh * group, 1), jnp.float32),   # m
             pltpu.VMEM((bh * group, 1), jnp.float32),   # l
             pltpu.VMEM((bh * group, d), jnp.float32),   # acc
+            pltpu.VMEM((bh * group, span * page_len), jnp.float32),   # an item's scores, then its probabilities
         ],
     )
     out = pl.pallas_call(
@@ -515,7 +563,7 @@ def flash_decode_paged(
         ),
         interpret=interpret,
         name="flash_decode_paged",
-    )(table, pos_vec, slot, page, n, *args)
+    )(table, pos_vec, slot, span_idx, n, *args)
     # rows no item visited hold whatever the output buffer held
     return jnp.where(live[:, None, None, None], out, 0).reshape(B, H, 1, d)
 

@@ -7,12 +7,13 @@ exact top-k's threshold (:func:`dsa_select_threshold`).
 The two of the decode step walk the step's **work list** exactly as
 ``flash_decode_paged`` does — the list and the page table ride as
 prefetched scalars, the list's length is the traced bound of the grid,
-and a row that does not decode costs no grid step — with one difference:
-an item is a **span** of :func:`span_of` = 4 consecutive pages of a row
-(``work_list``: ``flash_decode.paged_work_list`` over spans), each page an
-operand of its own found through the page table, so a grid step's fixed
-cost is paid once for four pages (the last span of a row may reach past
-its position: those pages are read and masked).
+and a row that does not decode costs no grid step.  An item is a **span**
+of :func:`span_of` = 4 consecutive pages of a row (``work_list``:
+``flash_decode.paged_work_list`` over spans), each page an operand of its
+own found through the page table, so a grid step's fixed cost is paid once
+for four pages (the last span of a row may reach past its position: those
+pages are read and masked) — the form ``flash_decode_paged`` has too since
+PR 46, where ``flash_decode.paged_tile`` sizes the span.
 
 * :func:`dsa_index_scores_paged` — an item is one page of one row's
   indexer keys, ``(index_dim, page_len)``: the row's ``index_heads``

@@ -296,11 +296,12 @@ def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
     gather + :func:`cache_attention` lax path below is the numerics
     ground truth, bit-matching the slot-contiguous cache.
 
-    ``work`` is the kernel's work list (``flash_decode.paged_work_list``; ``None``:
-    the filled pages of every row): the kernel walks its items and
-    nothing else, and the rows it does not visit read 0.  The lax path
-    attends every row and does not read it.  ``trace_notes`` is told
-    when the kernel took the step (``paged_decode_walk``)."""
+    ``work`` is the kernel's work list (``flash_decode.paged_work_list``
+    under ``paged_tile``'s span; ``None``: the filled pages of every
+    row): the kernel walks its items and nothing else, and the rows it
+    does not visit read 0.  The lax path attends every row and does not
+    read it.  ``trace_notes`` is told when the kernel took the step, and
+    the tile of a grid step (``paged_decode_walk``)."""
     quant = isinstance(k_cache, dict)
     if use_kernel is None:
         from deepspeed_tpu.ops import kernels as _kernels
@@ -308,14 +309,15 @@ def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
         use_kernel = _kernels.flash_decode_armed()
     if use_kernel and q.shape[2] == 1:
         from deepspeed_tpu.ops.kernels.flash_decode import (
-            decode_paged_supported, flash_decode_paged,
+            decode_paged_supported, flash_decode_paged, paged_tile,
         )
 
         B, H, _, d = q.shape
         page_len = (k_cache["q"] if quant else k_cache).shape[2]
         if decode_paged_supported(B, H, page_table.shape[1], page_len, d):
             if trace_notes is not None:
-                trace_notes["paged_decode_walk"] = "work list"
+                heads, span = paged_tile(k_cache, page_table.shape[1])
+                trace_notes["paged_decode_walk"] = f"work list, {heads} heads x {span} page{'s' if span > 1 else ''}"
             return flash_decode_paged(
                 q, k_cache, v_cache, page_table, pos, sm_scale=sm_scale, work=work
             )
@@ -693,9 +695,10 @@ def forward_with_cache(
         n_layer = jax.tree.leaves(k_cache)[0].shape[0]
         # a decode step's work list is the same for every layer (they
         # differ in the table's offset): the loop body closes over it
-        from deepspeed_tpu.ops.kernels.flash_decode import paged_work_list
+        from deepspeed_tpu.ops.kernels.flash_decode import paged_tile, paged_work_list
 
-        work = paged_work_list(pos, write_mask, jax.tree.leaves(k_cache)[0].shape[3], page_table.shape[1]) if T == 1 else None
+        P = page_table.shape[1]
+        work = paged_work_list(pos, write_mask, jax.tree.leaves(k_cache)[0].shape[3], P, paged_tile(k_cache, P)[1]) if T == 1 else None
 
         def pin(cache):
             if pool_layout is None:

@@ -31,7 +31,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from contextlib import nullcontext
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -172,6 +172,12 @@ class ServingEngine:
         # how far the paged decode kernel's work list engages (stats()):
         # the pages its grid walks, summed over decode steps
         self._decode_pages_walked = 0
+        # ... the steps its grid takes for them and the pages those fetch,
+        # under the tile the kernel reads off the pool (None: a pool whose
+        # pages another kernel reads)
+        self._decode_grid_steps = 0
+        self._decode_pages_read = 0
+        self._decode_tile: Optional[Tuple[int, int]] = None  # (blocks of KV heads a page, pages an item)
         # learned sparse attention (a family whose config names ``select_topk``): the positions a decoding
         # row could attend and those its selection keeps, summed over decode steps (stats())
         self._select_topk = int(getattr(mcfg, "select_topk", 0) or 0)
@@ -233,6 +239,12 @@ class ServingEngine:
                 spill_dir=(kvc.spill_dir or None),
                 kind=kind,
             )
+            k = self.pool.k
+            if self.pool.v is not None and (not isinstance(k, dict) or "q" in k):  # K/V pages a KV head: flash_decode_paged's
+                from deepspeed_tpu.ops.kernels.flash_decode import paged_tile
+
+                heads, span = paged_tile(k, self.pool.pages_per_slot)
+                self._decode_tile = (jax.tree.leaves(k)[0].shape[2] // heads, span)
         elif self._family_forward is not None:
             raise ValueError(
                 f"{type(mcfg).__name__} is served on its own cache kind, which lives in the "
@@ -1442,8 +1454,13 @@ class ServingEngine:
         self._decode_steps += 1
         if self._paged:
             # a decoding row attends positions 0 ... prompt + generated - 1
-            self._decode_pages_walked += sum(
-                (len(r.prompt) + len(r.generated) - 1) // self.pool.page_len + 1 for r in decoding)
+            last = [len(r.prompt) + len(r.generated) - 1 for r in decoding]
+            self._decode_pages_walked += sum(p // self.pool.page_len + 1 for p in last)
+            if self._decode_tile is not None:
+                blocks, span = self._decode_tile
+                items = sum(p // (self.pool.page_len * span) + 1 for p in last)
+                self._decode_grid_steps += items * blocks
+                self._decode_pages_read += items * span  # the masked tail of a row's last span included
         if self._select_topk:
             fills = [len(r.prompt) + len(r.generated) for r in decoding]  # positions 0 ... fill - 1, the query's own among them
             self._dsa_attendable += sum(fills)
@@ -1602,6 +1619,9 @@ class ServingEngine:
             out["decode_pages_walked"] = self._decode_pages_walked
             # what a grid of every page of every slot walks
             out["decode_pages_grid"] = self._decode_steps * self.pool.num_slots * self.pool.pages_per_slot
+            if self._decode_tile is not None:
+                # walked / grid_steps: the pages a grid step carried; read / walked: the price of the spans' tails
+                out["decode_grid_steps"], out["decode_pages_read"] = self._decode_grid_steps, self._decode_pages_read
         if self._select_topk:
             out["dsa_positions_attendable"] = self._dsa_attendable
             out["dsa_positions_selected"] = self._dsa_selected

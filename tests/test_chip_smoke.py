@@ -60,7 +60,7 @@ def test_flash_attention_lowers_for_tpu_at_smoke_shapes():
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_flash_decode_lowers_for_tpu_at_smoke_shapes(kv):
-    from deepspeed_tpu.ops.kernels.flash_decode import flash_decode, flash_decode_paged, paged_work_list
+    from deepspeed_tpu.ops.kernels.flash_decode import flash_decode, flash_decode_paged, paged_tile, paged_work_list
 
     mcfg = gpt2.PRESETS[FULL.serve_model]
     B, H, d, S, PL = FULL.slots, mcfg.n_head, mcfg.head_dim, FULL.max_len, FULL.page_len
@@ -77,10 +77,21 @@ def test_flash_decode_lowers_for_tpu_at_smoke_shapes(kv):
         lambda q, k, v, t, p: flash_decode_paged(q, k, v, t, p, interpret=False),
         q, pages, pages, _sds((B, P), jnp.int32), pos,
     )
-    # as a decode program calls it: the work list of the rows that decode, its length the grid's traced bound
+    # as a decode program calls it: the work list of the rows that decode, its length the grid's traced bound.
+    # Multi-head attention with all 25 heads of a page in one program, ``(d, page_len)`` tiles (int8: two pages an item)
+    span = paged_tile(pages, P)[1]
+    assert paged_tile(pages, P) == (H, 2 if kv == "int8" else 1)
     _lowers_for_tpu(
-        lambda q, k, v, t, p, m: flash_decode_paged(q, k, v, t, p, work=paged_work_list(p, m, PL, P), interpret=False),
+        lambda q, k, v, t, p, m: flash_decode_paged(q, k, v, t, p, work=paged_work_list(p, m, PL, P, span), interpret=False),
         q, pages, pages, _sds((B, P), jnp.int32), pos, _sds((B,), jnp.bool_),
+    )
+    # ... and at ZAYA1's shapes: 2 KV heads x 4 query heads of 128, 64 slots of 64 pages: a span of eight pages an item
+    zB, zP = 64, 64
+    zq, zpages = _sds((zB, 8, 1, 128), jnp.bfloat16), cache((1 + 2048, 2, PL, 128))
+    assert paged_tile(zpages, zP) == (2, 8)
+    _lowers_for_tpu(
+        lambda q, k, v, t, p, m: flash_decode_paged(q, k, v, t, p, work=paged_work_list(p, m, PL, zP, 8), interpret=False),
+        zq, zpages, zpages, _sds((zB, zP), jnp.int32), _sds((zB,), jnp.int32), _sds((zB,), jnp.bool_),
     )
     slots = cache((B, H, S, d))
     _lowers_for_tpu(lambda q, k, v, p: flash_decode(q, k, v, p, interpret=False), q, slots, slots, pos)
@@ -118,6 +129,30 @@ def v5e_chip():
     except Exception as e:  # no libtpu, or another process holds it
         pytest.skip(f"no v5e topology can be described here: {e}")
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("cell,slots,pages_per_slot,pool,heads,tile", [
+    ("gpt2_xl", 16, 8, (129, 25, 128, 64), 25, (25, 1)),          # 25 heads a program, (d, page_len) tiles
+    ("solar_open2", 160, 64, (2561, 8, 128, 128), 64, (8, 2)),    # 8 KV heads x 2 pages: 16 operands' worth a step
+    ("zaya1", 64, 64, (2049, 2, 128, 128), 8, (2, 8)),            # 2 KV heads x 8 pages, each page an operand of its own
+])
+def test_flash_decode_paged_compiles_for_v5e_under_the_tile_of_each_serve_cell(cell, slots, pages_per_slot, pool, heads, tile, v5e_chip):
+    """The paged decode kernel at the serve cells' own shapes, by the
+    chip's compiler without the chip: Mosaic takes the tile the pool's
+    shape gives (a mebibyte of K + V a grid step, twice over in VMEM)."""
+    from deepspeed_tpu.ops.kernels.flash_decode import flash_decode_paged, paged_tile, paged_work_list
+
+    on_chip = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    assert paged_tile(on_chip(pool), pages_per_slot) == tile
+
+    def call(q, k, v, t, p, m):
+        work = paged_work_list(p, m, pool[2], pages_per_slot, tile[1])
+        return flash_decode_paged(q, k, v, t, p, work=work, interpret=False)
+
+    compiled = jax.jit(call).lower(on_chip((slots, heads, 1, pool[3])), on_chip(pool), on_chip(pool),
+                                   on_chip((slots, pages_per_slot), jnp.int32), on_chip((slots,), jnp.int32),
+                                   on_chip((slots,), jnp.bool_)).compile()
+    assert chip_smoke.mosaic_kernels(compiled.as_text()) == {"flash_decode_paged": 1}
 
 
 @pytest.mark.parametrize("opt_name", ["adam", "lamb"])

@@ -1060,22 +1060,28 @@ def _work_case(name, P, page_len):
 WORK_CASES = ["ragged", "some_rows_not_live", "no_row_live", "every_page_filled", "pos_on_a_page_boundary"]
 
 
+WORK_FORMS = {"multi_head_d64": (2, 1, 64, False), "grouped_d128": (2, 4, 128, False), "int8_pool": (2, 1, 64, True),
+              # multi-head attention with every head of a page in one program, an odd count of them
+              "five_heads_a_program_d64": (5, 1, 64, False), "five_heads_a_program_d128": (5, 1, 128, False),
+              "five_heads_a_program_d64_int8": (5, 1, 64, True), "five_heads_a_program_d128_int8": (5, 1, 128, True)}
+
+
 @pytest.mark.parametrize("case", WORK_CASES)
-@pytest.mark.parametrize("form", ["multi_head_d64", "grouped_d128", "int8_pool"])
+@pytest.mark.parametrize("form", list(WORK_FORMS))
 def test_flash_decode_paged_walks_its_work_list(form, case):
     """The paged decode kernel over a work list against the gather + lax
-    form, for the three shapes the serve programs hand it — multi-head
-    with a head of half a lane row (``(d, page_len)`` tiles), grouped
-    queries at a whole one, the int8 code + scale pair — and the lists a
-    step can hold.  A row the list does not visit reads 0."""
+    form, for the shapes the serve programs hand it — multi-head with a
+    head of half a lane row (``(d, page_len)`` tiles), grouped queries
+    at a whole one, the int8 code + scale pair, multi-head with every
+    head of a page in one program at both tile forms and both pools —
+    and the lists a step can hold.  A row the list does not visit reads 0."""
     from deepspeed_tpu.ops.kernels import flash_decode as fd
     from deepspeed_tpu.ops.transformer import inference as inf
 
-    Hkv, group, d, quant = {"multi_head_d64": (2, 1, 64, False), "grouped_d128": (2, 4, 128, False),
-                            "int8_pool": (2, 1, 64, True)}[form]
+    Hkv, group, d, quant = WORK_FORMS[form]
     B, P, page_len, L = 3, 3, 128, 2
     H, num_pages = Hkv * group, 1 + B * P
-    rng = np.random.default_rng(WORK_CASES.index(case) + 7 * group + quant)
+    rng = np.random.default_rng(WORK_CASES.index(case) + 7 * group + quant + 11 * Hkv)
     kp, vp = inf.init_kv_cache(L, num_pages, Hkv, page_len, d, "int8" if quant else jnp.bfloat16)
     table = jnp.asarray(np.arange(1, num_pages, dtype=np.int32).reshape(B, P))
     rows = lambda: jnp.asarray(rng.standard_normal((B, Hkv, P * page_len, d)), jnp.bfloat16)  # noqa: E731
@@ -1086,6 +1092,7 @@ def test_flash_decode_paged_walks_its_work_list(form, case):
     pos = jnp.asarray(np.array(pos, np.int32))
     live = None if live is None else jnp.asarray(live)
     kc, vc, tab = inf.layer_pages(kp, vp, table, 1)
+    assert fd.paged_tile(kc, P) == (Hkv, 1)  # every KV head a program; three pages a slot: no span but 1
     work = fd.paged_work_list(pos, live, page_len, P)
     got = fd.flash_decode_paged(q, kc, vc, tab, pos, work=work)
     want = np.asarray(inf.paged_cache_attention(q, kc, vc, tab, pos, use_kernel=False), np.float32)
@@ -1117,8 +1124,14 @@ def test_engine_counts_the_pages_a_decode_step_walks_against_the_grid_of_every_p
     assert st["decode_pages_grid"] % (2 * 4) == 0 and new - 1 <= st["decode_pages_grid"] // (2 * 4) <= 2 * (new - 1)
     assert 0 < st["decode_pages_walked"] < st["decode_pages_grid"]
     assert "paged_decode_walk" not in st  # pages of 16 rows: the gather + lax form ran, and nothing walked a list
+    # the tile the kernel would read off this pool: every KV head a program, the slot's four pages one item
+    from deepspeed_tpu.ops.kernels.flash_decode import paged_tile
+
+    assert paged_tile(srv.pool.k, srv.pool.pages_per_slot) == (TINY.n_head, 4)
+    assert st["decode_grid_steps"] == len(lens) * (new - 1)       # one item a decoding row a step, one block of heads
+    assert st["decode_pages_read"] == 4 * st["decode_grid_steps"] >= st["decode_pages_walked"]  # the spans' masked tails included
     off = ServingEngine(eng, num_slots=2, prefill_chunk=8, max_len=64)
-    assert "decode_pages_walked" not in off.stats()
+    assert not {"decode_pages_walked", "decode_grid_steps", "decode_pages_read"} & set(off.stats())
 
 
 def test_paged_engine_decodes_through_the_work_list_kernel_as_through_the_gather_form(monkeypatch):
@@ -1143,8 +1156,13 @@ def test_paged_engine_decodes_through_the_work_list_kernel_as_through_the_gather
     want, st_off = served("0")
     for a, b in zip(got, want):
         np.testing.assert_array_equal(a, b)
-    assert st["paged_decode_walk"] == "work list" and "paged_decode_walk" not in st_off
+    # two pages a slot: both in one item, under both heads' one program
+    assert st["paged_decode_walk"] == f"work list, {cfg.n_head} heads x 2 pages" and "paged_decode_walk" not in st_off
     assert st["decode_pages_walked"] == st_off["decode_pages_walked"] > 0
+    for s_ in (st, st_off):  # host-side sums under the tile the pool's shape gives, whichever form ran
+        assert s_["decode_pages_read"] == 2 * s_["decode_grid_steps"] and s_["decode_grid_steps"] == st["decode_grid_steps"]
+    assert st["decode_pages_walked"] / st["decode_grid_steps"] > 1.0  # the row of 140 tokens: two pages a grid step
+    assert st["decode_pages_walked"] < st["decode_pages_read"]       # the rows of one page: the second is read and masked
 
 
 def test_paged_work_list_is_the_live_rows_filled_pages_in_slot_then_page_order():
@@ -1168,6 +1186,99 @@ def test_paged_work_list_is_the_live_rows_filled_pages_in_slot_then_page_order()
     # no row live: no item, and the padding names a block that exists
     slot, page, n, visited = (np.asarray(a) for a in paged_work_list(pos, jnp.zeros((5,), bool), page_len, P))
     assert int(n[0]) == 0 and not slot.any() and not page.any() and not visited.any()
+
+
+def test_paged_work_list_over_spans_is_the_live_rows_filled_spans_in_slot_then_span_order():
+    from deepspeed_tpu.ops.kernels.flash_decode import paged_work_list
+
+    page_len, P = 128, 8
+    pos = jnp.asarray(np.array([130, 5, 8 * 128 - 1, 4 * 128, 9000, 0], np.int32))
+    live = jnp.asarray([True, False, True, True, True, True])
+    filled = [2, 0, 8, 5, 8, 1]  # pages a live row holds (a position past the slot is held to its last page)
+    for span in (1, 2, 4, 8):
+        slot, at, n, visited = (np.asarray(a) for a in paged_work_list(pos, live, page_len, P, span))
+        want = [(b, s) for b, f in enumerate(filled) for s in range(-(-f // span))]
+        assert slot.shape == at.shape == (6 * P // span,) and slot.dtype == at.dtype == np.int32
+        assert int(n[0]) == len(want) and list(zip(slot[: len(want)].tolist(), at[: len(want)].tolist())) == want
+        assert set(zip(slot[len(want):].tolist(), at[len(want):].tolist())) <= {want[-1]}  # the padding repeats the last item
+        np.testing.assert_array_equal(visited, np.asarray(live))
+    # span 1 is the list of pages; no row live: no item; a span that does not tile the slot is refused
+    for a, b in zip(paged_work_list(pos, live, page_len, P), paged_work_list(pos, live, page_len, P, 1)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    slot, at, n, visited = (np.asarray(a) for a in paged_work_list(pos, jnp.zeros((6,), bool), page_len, P, 4))
+    assert int(n[0]) == 0 and not slot.any() and not at.any() and not visited.any()
+    with pytest.raises(ValueError, match="do not tile"):
+        paged_work_list(pos, live, page_len, 6, 4)
+
+
+@pytest.mark.parametrize("shape,dtype,pages_per_slot,tile", [
+    ((48, 129, 25, 128, 64), jnp.bfloat16, 8, (25, 1)),     # GPT-2 XL: 800 KB a page of 25 heads, one page an item
+    ((48, 129, 25, 128, 64), jnp.int8, 8, (25, 2)),         # its int8 codes: half the bytes, two pages
+    ((1, 2560, 8, 128, 128), jnp.bfloat16, 64, (8, 2)),     # Solar-Open2's GQA layer: 512 KB a page
+    ((20, 2049, 2, 128, 128), jnp.bfloat16, 64, (2, 8)),    # ZAYA1: 128 KB a page, eight of them
+    ((8, 4225, 4, 128, 128), jnp.bfloat16, 264, (4, 4)),    # Keye-like: 256 KB a page, four (as sparse_decode.span_of)
+    ((2, 64, 96, 128, 128), jnp.bfloat16, 16, (16, 1)),     # a many-headed multi-head pool: a proper divisor under the budget
+    ((2, 64, 7, 128, 128), jnp.float32, 9, (7, 1)),         # nine pages a slot: no span but 1 divides
+    ((2, 64, 2, 128, 16), jnp.float32, 6, (2, 2)),          # six: 2, not 4
+    ((2, 64, 3, 512, 256), jnp.float32, 8, (1, 1)),         # one head of one page is past the budget: one a program all the same
+], ids=["gpt2_xl", "gpt2_xl_int8", "solar_open2", "zaya1", "keye_like", "mha_96_heads", "nine_pages", "six_pages", "a_pair_past_the_budget"])
+def test_paged_tile_is_read_off_the_pool_shape(shape, dtype, pages_per_slot, tile):
+    from deepspeed_tpu.ops.kernels import flash_decode as fd
+
+    pool = jax.ShapeDtypeStruct(shape, dtype)
+    assert fd.paged_tile(pool, pages_per_slot) == tile
+    assert fd.paged_tile({"q": pool, "s": jax.ShapeDtypeStruct(shape[:-1] + (1,), jnp.float32)}, pages_per_slot) == tile
+    assert fd.paged_tile(jax.ShapeDtypeStruct(shape[1:], dtype), pages_per_slot) == tile  # a layer's pages: the same tile
+    heads, span = tile
+    pair = 2 * shape[-2] * shape[-1] * jnp.dtype(dtype).itemsize
+    assert shape[2] % heads == 0 and pages_per_slot % span == 0
+    assert heads * span * pair <= fd.TILE_BYTES or (heads, span) == (1, 1)
+
+
+SPANS = {1: 3, 2: 6, 4: 4, 8: 8}  # pages an item: pages a slot that give it (2 KV heads x 128 x 128 bf16: 128 KB a page)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("span", list(SPANS))
+def test_flash_decode_paged_walks_spans_of_pages(span, quant):
+    """An item of the work list is a span of 1, 2, 4 or 8 consecutive
+    pages of a row, each an operand of its own (the int8 pool's scales
+    follow their pages): against the gather + lax form with a row whose
+    last span reaches past its position — over pages that hold large
+    finite garbage —, a row that ends on a span's last position, a row
+    that does not decode, and a step in which none does."""
+    from deepspeed_tpu.ops.kernels import flash_decode as fd
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    P = SPANS[span]
+    B, Hkv, group, d, page_len, L = 4, 2, 4, 128, 128, 2
+    H, num_pages = Hkv * group, 1 + B * P
+    rng = np.random.default_rng(span + 10 * quant)
+    kp, vp = inf.init_kv_cache(L, num_pages, Hkv, page_len, d, "int8" if quant else jnp.bfloat16)
+    table = jnp.asarray(np.arange(1, num_pages, dtype=np.int32).reshape(B, P))
+    pos = np.array([page_len + 3, P * page_len - 1, 37, span * page_len - 1], np.int32)  # the last: a whole first span
+    live = np.array([True, True, False, True])
+    # every page of every slot written: past a row's position lies large finite garbage, not zeros
+    rows = rng.standard_normal((2, B, Hkv, P * page_len, d))
+    past = np.arange(P * page_len)[None, :] > pos[:, None]
+    rows = np.where(past[None, :, None, :, None], 3e4 * np.sign(rows), rows)
+    zero = jnp.zeros((B,), jnp.int32)
+    kp, vp = (inf.paged_cache_write_slices(c, 1, jnp.asarray(r, jnp.bfloat16), table, zero) for c, r in ((kp, rows[0]), (vp, rows[1])))
+    q = jnp.asarray(rng.standard_normal((B, H, 1, d)), jnp.bfloat16)
+    kc, vc, tab = inf.layer_pages(kp, vp, table, 1)
+    assert fd.paged_tile(kc, P) == (Hkv, span)
+    want = np.asarray(inf.paged_cache_attention(q, kc, vc, tab, jnp.asarray(pos), use_kernel=False), np.float32)
+    assert np.isfinite(want).all()
+    for mask in (live, np.zeros((B,), bool), None):
+        work = fd.paged_work_list(jnp.asarray(pos), None if mask is None else jnp.asarray(mask), page_len, P, span)
+        assert int(work[2][0]) == sum(int(p) // (page_len * span) + 1 for p, m in zip(pos, np.ones(B, bool) if mask is None else mask) if m)
+        got = np.asarray(fd.flash_decode_paged(q, kc, vc, tab, jnp.asarray(pos), work=work), np.float32)
+        np.testing.assert_allclose(got, want if mask is None else np.where(mask[:, None, None, None], want, 0.0), atol=2e-2, rtol=2e-2)
+    # the five-argument call builds the same list; a list built under another span is refused, not walked
+    np.testing.assert_array_equal(np.asarray(fd.flash_decode_paged(q, kc, vc, tab, jnp.asarray(pos)), np.float32), got)
+    if span > 1:
+        with pytest.raises(ValueError, match="not one over spans"):
+            fd.flash_decode_paged(q, kc, vc, tab, jnp.asarray(pos), work=fd.paged_work_list(jnp.asarray(pos), None, page_len, P))
 
 
 @pytest.mark.parametrize("d", [64, 128], ids=["pages_tiled_d_by_page_len", "pages_tiled_page_len_by_d"])
