@@ -21,6 +21,11 @@ weights made from a seed:
   ``dsa_index_scores_paged`` and ``dsa_sparse_decode`` in its decode
   program: attention over the positions the indexer selects)
   against ``benchmark/reference_keye.py``;
+* a sixth family — GigaChat3.5 at a small size on the hybrid cache over
+  **latent** pages (a latent buffer + per-slot recurrent state;
+  ``gdn_decode`` and ``mla_decode_paged`` in its decode program,
+  ``mla_prefill`` in its prefill program)
+  against ``benchmark/reference_gigachat35.py``;
 * server — ``deepspeed_tpu.init_inference("gpt2-xl")`` → ``ServingEngine``
   on the paged pool (8 slots, ``page_len`` 128), a bf16 then an int8 KV
   pool, eight seeded greedy requests each;
@@ -718,6 +723,81 @@ def serve_keye(s: Smoke, device) -> Dict[str, float]:
     return gaps
 
 
+GIGACHAT35_SMOKE = {
+    "vocab_size": 512, "max_position_embeddings": 4096, "hidden_size": 256, "intermediate_size": 512, "moe_intermediate_size": 128,
+    "num_hidden_layers": 3, "num_attention_heads": 8, "n_shared_experts": 1, "n_routed_experts": 16, "routed_scaling_factor": 2.5,
+    "kv_lora_rank": 128, "q_lora_rank": 96, "qk_rope_head_dim": 64, "v_head_dim": 128, "qk_nope_head_dim": 128, "n_group": 1,
+    "topk_group": 1, "num_experts_per_tok": 4, "first_k_dense_replace": 1, "norm_topk_prob": True, "rms_norm_eps": 1e-6,
+    "rope_theta": 100000, "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 8, "mscale": 1, "mscale_all_dim": 1,
+                                           "original_max_position_embeddings": 64, "type": "yarn"},
+    "norm_type": "ZeroCenteredGatedNorm", "layernorm_type": "pre_post", "layernorm_gating_weight": 2, "gated_attention": True,
+    "use_mla_scaling_factor": True, "linear_attention_type": "GigaChat35GatedDeltaNet", "full_attention_layers": [2],
+    "linear_key_head_dim": 128, "linear_value_head_dim": 128, "linear_conv_kernel_dim": 4, "linear_num_key_heads": 8,
+    "linear_num_value_heads": 16, "linear_gating_type": "gated_rmsnorm_sigmoid_zero_centered", "linear_sigmoid_gate_scale": 2,
+    "linear_attn_o_norm_eps": 1e-6, "swiglu_limit": 10, "tie_word_embeddings": False, "num_nextn_predict_layers": 0,
+    "experts_held": [8, 8], "vocab_held": 256,
+}
+# a bf16 program against the float32 reference: the mean as TOL_DSV2_TOKEN_GAP; the largest of 24 gaps is wider under four norms a
+# layer (0.19 at this size on the CPU mesh, 0.58-0.78 in the cell's runs: PERF.md section 2) and a wrong forward reads several units
+TOL_GIGACHAT35_TOKEN_GAP, TOL_GIGACHAT35_TOKEN_GAP_MAX = 0.05, 0.5
+
+
+def serve_gigachat35(s: Smoke, device) -> Dict[str, float]:
+    """``init_inference(model_config=GigaChat35Config)`` → the same
+    ``ServingEngine`` on the hybrid cache over **latent** pages: compiles
+    both programs, serves three requests over two slots (chunked prefill:
+    the chunked delta rule at a scalar decay and expanded latent
+    attention; decode through ``gdn_decode`` on the per-slot recurrent
+    state — 16 value heads on 8 query / key heads — and
+    ``mla_decode_paged`` on the latent pages; a slot reused) and holds
+    every emitted token against the plain reference's logits
+    (``benchmark/reference_gigachat35.py``)."""
+    from benchmark import weights_gigachat35 as weights
+    from benchmark.reference_gigachat35 import Reference
+    from benchmark.runners.serve_gigachat35 import served_gaps
+    from deepspeed_tpu.comm.mesh import make_mesh
+    from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.models import gigachat35
+    from deepspeed_tpu.serving import ServingEngine
+
+    dims = GIGACHAT35_SMOKE
+    mcfg = gigachat35.GigaChat35Config.from_hf(dims, experts_held=dims["experts_held"], vocab_held=dims["vocab_held"])
+    inf = deepspeed_tpu.init_inference(
+        model_config=mcfg, params=weights.program_params(s.seed, dims, jnp.bfloat16), dtype=jnp.bfloat16,
+        max_out_tokens=512, mesh=make_mesh(MeshConfig(), devices=[device]), donate_params=True,
+    )
+    srv = ServingEngine(inf, config={"num_slots": 2, "max_len": 512, "prefill_chunk": 128, "max_new_tokens": 16,
+                                     "overlap_chunks": True,  # as the cell serves it: programs dispatched ahead of the host's reads
+                                     "kvcache": {"enabled": True, "page_len": 128, "num_pages": 9}})
+    rng = np.random.default_rng(s.seed)
+    prompts = [rng.integers(1, dims["vocab_held"], n, dtype=np.int32) for n in (200, 131, 70)]
+    ids = [srv.submit(p, max_new_tokens=8) for p in prompts]
+    done = srv.drain()
+    check((srv.prefill_compiles, srv.decode_compiles) == (1, 1),
+          f"serve[gigachat35]: {srv.prefill_compiles} prefill / {srv.decode_compiles} decode executables")
+    stats = srv.stats()
+    moe, hybrid, kv = stats["moe"], stats["hybrid"], stats["kvcache"]
+    check(moe["dropped_assignments"] == 0 and moe["assignments_computed"] > 0, f"serve[gigachat35]: expert counters {moe}")
+    check(hybrid["state_resets_in_program"] == 3 and hybrid["state_bytes"] > 0, f"serve[gigachat35]: hybrid cache {hybrid}")
+    check(kv["page_kind"] == "LatentKV" and kv["page_leaves"] == {"k": 1 * 9 * 192 * 128 * 2} and srv.pool.v is None,
+          f"serve[gigachat35]: the pool's pages {kv.get('page_kind')} {kv.get('page_leaves')}")
+    gaps = served_gaps(Reference(dims, s.seed), [{"prompt": p, "generated": list(done[i].generated)}
+                                                 for p, i in zip(prompts, ids)], 256)
+    check(gaps["token_gap_mean"] <= TOL_GIGACHAT35_TOKEN_GAP and gaps["token_gap_max"] <= TOL_GIGACHAT35_TOKEN_GAP_MAX,
+          f"serve[gigachat35]: the emitted tokens lie {gaps['token_gap_mean']:.4f} on the mean, {gaps['token_gap_max']:.4f} at most "
+          f"under the reference's best logit (tolerances {TOL_GIGACHAT35_TOKEN_GAP}, {TOL_GIGACHAT35_TOKEN_GAP_MAX})")
+    expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
+                   ["gdn_decode", "mla_decode_paged", "moe_grouped_matmul"] if s.mosaic else [], "serve[gigachat35] decode")
+    expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()),
+                   ["mla_prefill", "moe_grouped_matmul"] if s.mosaic else [], "serve[gigachat35] prefill")
+    check(stats["gdn_decode_kernel"] is bool(s.mosaic) and stats["mla_decode_kernel"] is bool(s.mosaic),
+          f"serve[gigachat35]: stats() say of the decode program: gdn_decode_kernel {stats['gdn_decode_kernel']} "
+          f"({stats['gdn_decode_fallback']!r}), mla_decode_kernel {stats['mla_decode_kernel']} ({stats['mla_decode_fallback']!r})")
+    say(f"serve[gigachat35]: 3 requests x 8 tokens through the hybrid cache over latent pages, token gap mean "
+        f"{gaps['token_gap_mean']:.5f} max {gaps['token_gap_max']:.5f} over {gaps['tokens']} tokens")
+    return gaps
+
+
 def run(s: Smoke, devices: Sequence) -> None:
     """Every phase, in order; raises on the first check that fails."""
     if s.mosaic:
@@ -735,6 +815,7 @@ def run(s: Smoke, devices: Sequence) -> None:
     serve_deepseek_v2(s, devices[0])
     serve_solar_open2(s, devices[0])
     serve_keye(s, devices[0])
+    serve_gigachat35(s, devices[0])
 
 
 def main() -> int:

@@ -1359,6 +1359,12 @@ class ServingConfig:
     kv_cache_dtype: str = C.SERVING_KV_CACHE_DTYPE_DEFAULT
     prefill_chunk: int = C.SERVING_PREFILL_CHUNK_DEFAULT
     prefill_chunks_per_step: int = C.SERVING_PREFILL_CHUNKS_PER_STEP_DEFAULT
+    # hand a step's programs to the device ahead of the host's reads, the
+    # decode step first, and read a chunk that is not its prompt's last a
+    # step late: the device runs the chunk while the host turns the step
+    # (docs/serving.md §Scheduler policy).  Same programs, same tokens; a request
+    # decodes from the step after its last chunk, not in it
+    overlap_chunks: bool = C.SERVING_OVERLAP_CHUNKS_DEFAULT
     max_queue: int = C.SERVING_MAX_QUEUE_DEFAULT
     max_new_tokens: int = C.SERVING_MAX_NEW_TOKENS_DEFAULT
     deadline_seconds: float = C.SERVING_DEADLINE_SECONDS_DEFAULT
@@ -1423,6 +1429,7 @@ class ServingConfig:
             prefill_chunks_per_step=int(
                 _pop(d, "prefill_chunks_per_step", C.SERVING_PREFILL_CHUNKS_PER_STEP_DEFAULT)
             ),
+            overlap_chunks=bool(_pop(d, "overlap_chunks", C.SERVING_OVERLAP_CHUNKS_DEFAULT)),
             max_queue=int(_pop(d, "max_queue", C.SERVING_MAX_QUEUE_DEFAULT)),
             max_new_tokens=int(_pop(d, "max_new_tokens", C.SERVING_MAX_NEW_TOKENS_DEFAULT)),
             deadline_seconds=float(

@@ -221,10 +221,10 @@ def init_params(cfg: SolarOpen2Config, seed: int = 0):
 def cache_kind(cfg: SolarOpen2Config, dtype):
     """The family's cache kind for :class:`PagedKVPool`: K/V pages of the
     GQA layers, a per-slot state of the KDA layers."""
-    from deepspeed_tpu.serving.kvcache.pages import HybridKV
+    from deepspeed_tpu.serving.kvcache.pages import HybridKV, PerHeadKV
 
     n = len(cfg.kda_layers)
-    return HybridKV(len(cfg.gqa_layers), cfg.num_key_value_heads, cfg.head_dim, dtype, {
+    return HybridKV(len(cfg.gqa_layers), PerHeadKV(cfg.num_key_value_heads, cfg.head_dim, dtype), {
         "s": (n, (cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_head_dim), jnp.float32),
         "conv": (n, (cfg.kda_conv_size - 1, 3 * cfg.kda_width), dtype)})
 

@@ -208,11 +208,11 @@ def cache_kind(cfg: ZayaConfig, dtype):
     """The family's cache kind for :class:`PagedKVPool`: **every** layer
     has K/V pages and, per slot, its convolution tail."""
     from deepspeed_tpu.ops.transformer.compressed_attention import TAPS
-    from deepspeed_tpu.serving.kvcache.pages import HybridKV
+    from deepspeed_tpu.serving.kvcache.pages import HybridKV, PerHeadKV
 
     L, sz = cfg.num_hidden_layers, cfg.cca
-    return HybridKV(L, sz.kv_heads, sz.head_dim, dtype, {"conv": (L, (TAPS, sz.channels), dtype),
-                                                         "vshift": (L, (sz.shift_width,), dtype)})
+    return HybridKV(L, PerHeadKV(sz.kv_heads, sz.head_dim, dtype), {"conv": (L, (TAPS, sz.channels), dtype),
+                                                                    "vshift": (L, (sz.shift_width,), dtype)})
 
 
 # ---------------------------------------------------------------------------

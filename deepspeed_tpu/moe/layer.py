@@ -356,12 +356,24 @@ def note_grouped_form(trace_notes: dict, rows: int, why_not: str) -> None:
     trace_notes["moe_grouped_fallback"] = "; ".join(f"{r}: {off[r]}" for r in sorted(off, key=int))
 
 
+def swiglu_gate(g: jnp.ndarray, u: jnp.ndarray, limit: Optional[float] = None) -> jnp.ndarray:
+    """``silu(g) * u``, or — ``limit`` given, the **clamped** SwiGLU —
+    ``silu(min(g, limit)) * clip(u, -limit, limit)``: the gate bounded
+    above (``silu`` bounds it below by itself), the linear half on both
+    sides."""
+    if limit is not None:
+        g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
+    return jax.nn.silu(g) * u
+
+
 def dropless_held_experts(x: jnp.ndarray, idx: jnp.ndarray, weight: jnp.ndarray, w_gu: jnp.ndarray,
                           w_down: jnp.ndarray, held: Tuple[int, int], valid: Optional[jnp.ndarray] = None,
-                          trace_notes: Optional[dict] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                          trace_notes: Optional[dict] = None,
+                          swiglu_limit: Optional[float] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The part of a routed-expert layer that the experts **held here**
     give: ``sum_{e in chosen, first <= e < first + count} w_e E_e(x)``
-    with ``E_e(x) = (silu(x W_gate,e) * x W_up,e) W_down,e``.
+    with ``E_e(x) = (silu(x W_gate,e) * x W_up,e) W_down,e``
+    (``swiglu_limit``: the clamped form, :func:`swiglu_gate`).
 
     No capacity and no dropped assignment: every (token, expert) pair
     whose expert is held is computed.  Static shapes: the ``N * top_k``
@@ -411,7 +423,7 @@ def dropless_held_experts(x: jnp.ndarray, idx: jnp.ndarray, weight: jnp.ndarray,
         grouped = jax.lax.ragged_dot
     gu = grouped(xs, w_gu.astype(x.dtype), sizes)
     g, u = jnp.split(gu, 2, axis=-1)
-    ys = grouped(jax.nn.silu(g) * u, w_down.astype(x.dtype), sizes)[: N * K]
+    ys = grouped(swiglu_gate(g, u, swiglu_limit), w_down.astype(x.dtype), sizes)[: N * K]
     # rows past the held groups belong to absent experts: nothing was computed for them
     computed = jnp.arange(N * K) < jnp.sum(sizes)
     ws = jnp.take(jnp.where(is_held, weight, 0.0).reshape(N * K), order)

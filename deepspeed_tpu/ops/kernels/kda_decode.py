@@ -98,23 +98,19 @@ def _kda_decode_kernel(rows_ref, n_ref, cols_ref, v_ref, s_ref, o_ref, s_out_ref
             o_ref[0, pl.ds(j, 1), :] = jnp.sum(s * col(_Q), axis=0, keepdims=True)
 
 
-def kda_decode(state, layer: int, q, k, v, g, beta, write_mask, interpret: Optional[bool] = None):
-    """One recurrent step of the rows where ``write_mask`` holds.
-
-    ``state (layers, B, H, dk, dv)`` float32, updated in place at the
-    static ``layer``; ``q, k, g (B, H, dk)`` (``q`` already scaled, both
-    normalised, ``g`` the log-decay), ``v (B, H, dv)``, ``beta (B, H)``,
-    ``write_mask (B,)``.  Returns ``(o (B, H, dv) float32, state)``; the
-    rows that did not decode read 0 and their states are untouched."""
+def _delta_rule_call(name: str, state, layer: int, q, k, v, a, beta, write_mask, interpret: Optional[bool]):
+    """The one ``pallas_call`` under both names: ``q, k, a (B, H, dk)``
+    a state's query, key and decay columns (``a = exp(g)``), ``v (B, H,
+    dv)``, ``beta (B, H)``."""
     L, B, H, dk, dv = state.shape
     if not kda_decode_supported(H, dk, dv) or state.dtype != jnp.float32:
-        raise ValueError(f"kda_decode: unsupported call (state {state.shape} {state.dtype}); "
+        raise ValueError(f"{name}: unsupported call (state {state.shape} {state.dtype}); "
                          "callers must dispatch through kda_decode_supported()")
     if interpret is None:
         interpret = pallas_interpret_default()
     f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
-    q, k, v, g, beta = f32(q), f32(k), f32(v), f32(g), f32(beta)
-    cols = pack_columns(q, k, jnp.exp(g), beta[..., None] * k)
+    q, k, v, a, beta = f32(q), f32(k), f32(v), f32(a), f32(beta)
+    cols = pack_columns(q, k, a, beta[..., None] * k)
     rows, n = compact_rows(write_mask)
     nh = H // HEAD_BLOCK
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -139,13 +135,52 @@ def kda_decode(state, layer: int, q, k, v, g, beta, write_mask, interpret: Optio
         input_output_aliases={4: 1},
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name="kda_decode",
+        name=name,
     )(rows, n, cols, v, state)
     # rows no step visited hold whatever the output buffer held
     return jnp.where(write_mask.astype(bool)[:, None, None], o, 0.0), state
+
+
+def kda_decode(state, layer: int, q, k, v, g, beta, write_mask, interpret: Optional[bool] = None):
+    """One recurrent step of the rows where ``write_mask`` holds.
+
+    ``state (layers, B, H, dk, dv)`` float32, updated in place at the
+    static ``layer``; ``q, k, g (B, H, dk)`` (``q`` already scaled, both
+    normalised, ``g`` the log-decay), ``v (B, H, dv)``, ``beta (B, H)``,
+    ``write_mask (B,)``.  Returns ``(o (B, H, dv) float32, state)``; the
+    rows that did not decode read 0 and their states are untouched."""
+    return _delta_rule_call("kda_decode", state, layer, q, k, v, jnp.exp(g.astype(jnp.float32)), beta, write_mask, interpret)
+
+
+def gdn_decode(state, layer: int, q, k, v, g, beta, write_mask, interpret: Optional[bool] = None):
+    """The **gated delta rule**'s decode step (Gated DeltaNet,
+    arXiv:2412.06464): KDA's recurrence with the decay **one scalar a
+    head** (``diag(exp g)`` is ``exp(g) I``) and a query / key head
+    shared by ``H / Hk`` value heads.
+
+    ``state (layers, B, H, dk, dv)`` float32 as :func:`kda_decode`'s;
+    ``q, k (B, Hk, dk)`` with ``H % Hk == 0`` — value head ``h`` reads
+    query / key head ``h // (H / Hk)`` —, ``v (B, H, dv)``, ``g, beta (B,
+    H)``.  The body is :func:`kda_decode`'s under the program name
+    ``gdn_decode``: the columns tile is packed a **value** head, so a
+    shared query / key rides twice and the scalar decay as a column of
+    one number — 4 KB a head beside the 128 KB of state a head moves in
+    and out, 3 % the recurrence need not move
+    (``benchmark/kernels/gdn_decode.py`` counts the work without them)."""
+    H, dk = state.shape[2], state.shape[3]
+    rep = H // q.shape[1]
+    if rep * q.shape[1] != H:
+        raise ValueError(f"gdn_decode: {H} value heads are not whole groups of the {q.shape[1]} query / key heads")
+    share = lambda t: jnp.repeat(t, rep, axis=1) if rep > 1 else t  # noqa: E731
+    a = jnp.broadcast_to(jnp.exp(g.astype(jnp.float32))[..., None], g.shape + (dk,))
+    return _delta_rule_call("gdn_decode", state, layer, share(q), share(k), v, a, beta, write_mask, interpret)
 
 
 @register_op("kda_decode", "pallas", "KDA recurrent decode step, in place on the per-slot recurrent state")
 def _load_kda_decode():
     return kda_decode
 
+
+@register_op("gdn_decode", "pallas", "gated-delta-rule recurrent decode step (scalar decay a head, shared query / key heads), in place")
+def _load_gdn_decode():
+    return gdn_decode

@@ -9,6 +9,17 @@ Per head, with ``S (dk, dv)`` float32, normalised ``q, k (dk)``,
     S_t = (I - beta_t k_t k_t^T) diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
 
+The **gated delta rule** (Gated DeltaNet, arXiv:2412.06464) is the same
+recurrence with the decay **one scalar a head** — ``diag(exp g)`` is
+``exp(g) I`` — and all three forms below serve it with ``g`` given one
+number wide (``g (..., H, 1)``: it broadcasts over ``dk``);
+:func:`chunked` then takes the decay out of the key products (``k k^T``
+and ``q k^T`` are matrix products, scaled by ``exp(G_t - G_s)``
+afterwards) in place of a ``(C, C, dk)`` tensor a head.  Where fewer
+query / key heads feed more value heads (``Hk < H``: value head ``h``
+reads query / key head ``h // (H / Hk)``), the caller repeats them
+(:func:`share_heads`); :func:`decode_step` takes them as they are.
+
 Three forms of the one function:
 
 * :func:`recurrent_step` — the recurrence as written, in ``jnp``: the
@@ -68,20 +79,32 @@ def short_conv(x, w, state, n_valid=None):
     return jax.nn.silu(y), new.astype(state.dtype)
 
 
+def share_heads(t, H: int, axis: int):
+    """``t`` with ``Hk`` query / key heads along ``axis`` as ``H`` of
+    them: head ``h`` of the result is head ``h // (H / Hk)`` of ``t``."""
+    Hk = t.shape[axis]
+    if H % Hk:
+        raise ValueError(f"{H} value heads are not whole groups of the {Hk} query / key heads")
+    return t if Hk == H else jnp.repeat(t, H // Hk, axis=axis)
+
+
 def recurrent_step(S, q, k, v, g, beta):
-    """One token: ``S (..., H, dk, dv)``, ``q, k, g (..., H, dk)``,
-    ``v (..., H, dv)``, ``beta (..., H)``, all float32.  Returns
-    ``(o (..., H, dv), S)``."""
+    """One token: ``S (..., H, dk, dv)``, ``q, k (..., H, dk)``, ``g
+    (..., H, dk)`` or ``(..., H, 1)`` (a scalar decay a head), ``v (...,
+    H, dv)``, ``beta (..., H)``, all float32.  Returns ``(o (..., H,
+    dv), S)``."""
     S = S * jnp.exp(g)[..., None]
     u = beta[..., None] * (v - jnp.einsum("...hk,...hkv->...hv", k, S, precision=_HI))
     S = S + k[..., None] * u[..., None, :]
     return jnp.einsum("...hk,...hkv->...hv", q, S, precision=_HI), S
 
 
-def decode_form(use_kernel: Optional[bool], H: int, dk: int, dv: int, dtype=jnp.float32) -> Tuple[bool, str]:
+def decode_form(use_kernel: Optional[bool], H: int, dk: int, dv: int, dtype=jnp.float32,
+                kernel_name: str = "kda_decode") -> Tuple[bool, str]:
     """Which form a decode step on a state of these shapes and ``dtype``
     takes: ``(kernel, why_not)``; one line of the log for each distinct
-    answer."""
+    answer.  ``kernel_name`` is the program the kernel form would run
+    (``kda_decode``, or ``gdn_decode`` for a scalar decay a head)."""
     from deepspeed_tpu.ops import kernels as _kernels
     from deepspeed_tpu.ops.kernels.kda_decode import kda_decode_supported
     from deepspeed_tpu.ops.kernels.sharded import free_mesh_axes
@@ -99,9 +122,10 @@ def decode_form(use_kernel: Optional[bool], H: int, dk: int, dv: int, dtype=jnp.
         why_not = f"the state is {jnp.dtype(dtype).name}, the kernel's is float32"
     else:
         why_not = ""
-    _kernels.warn_once(("kda_decode", H, dk, dv, why_not),
-                       f"kernels: a KDA decode step (H {H}, state {dk} x {dv}) takes "
-                       + (f"the jnp recurrence: {why_not}" if why_not else "kda_decode"), level="info")
+    what = "KDA" if kernel_name == "kda_decode" else "gated-delta-rule"
+    _kernels.warn_once((kernel_name, H, dk, dv, why_not),
+                       f"kernels: a {what} decode step (H {H}, state {dk} x {dv}) takes "
+                       + (f"the jnp recurrence: {why_not}" if why_not else kernel_name), level="info")
     return not why_not, why_not
 
 
@@ -109,20 +133,26 @@ def decode_step(state, layer: int, q, k, v, g, beta, write_mask=None, use_kernel
                 trace_notes: Optional[dict] = None):
     """A decode step on the pool's state ``(layers, B, H, dk, dv)`` at
     the static ``layer``: ``q, k, g (B, H, dk)``, ``v (B, H, dv)``,
-    ``beta (B, H)``.  Returns ``(o (B, H, dv) float32, state)``; rows
-    with ``write_mask`` False read 0 and keep their state.
-    ``trace_notes`` is told which form (``kda_decode_kernel``,
-    ``kda_decode_fallback``)."""
+    ``beta (B, H)`` — or, the gated delta rule, ``g (B, H)`` **one
+    scalar a head** and ``q, k (B, Hk, dk)`` shared by ``H / Hk`` value
+    heads.  Returns ``(o (B, H, dv) float32, state)``; rows with
+    ``write_mask`` False read 0 and keep their state.  ``trace_notes`` is
+    told which form (``kda_decode_kernel``, ``kda_decode_fallback``;
+    ``gdn_*`` for the scalar decay)."""
     _, B, H, dk, dv = state.shape
+    scalar = g.ndim == 2
+    name = "gdn" if scalar else "kda"
     mask = jnp.ones((B,), bool) if write_mask is None else write_mask.astype(bool)
-    kernel, why_not = decode_form(use_kernel, H, dk, dv, state.dtype)
+    kernel, why_not = decode_form(use_kernel, H, dk, dv, state.dtype, f"{name}_decode")
     if trace_notes is not None:
-        trace_notes.update(kda_decode_kernel=kernel, kda_decode_fallback=why_not)
+        trace_notes.update({f"{name}_decode_kernel": kernel, f"{name}_decode_fallback": why_not})
     if kernel:
-        from deepspeed_tpu.ops.kernels.kda_decode import kda_decode
+        from deepspeed_tpu.ops.kernels.kda_decode import gdn_decode, kda_decode
 
-        return kda_decode(state, layer, q, k, v, g, beta, mask)
+        return (gdn_decode if scalar else kda_decode)(state, layer, q, k, v, g, beta, mask)
     f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+    if scalar:
+        q, k, g = share_heads(q, H, 1), share_heads(k, H, 1), g[..., None]
     o, S = recurrent_step(state[layer], f32(q), f32(k), f32(v), f32(g), f32(beta))
     S = jnp.where(mask[:, None, None, None], S, state[layer]).astype(state.dtype)
     return jnp.where(mask[:, None, None], o, 0.0), state.at[layer].set(S)
@@ -130,7 +160,8 @@ def decode_step(state, layer: int, q, k, v, g, beta, write_mask=None, use_kernel
 
 def chunked(S0, q, k, v, g, beta, chunk: int = CHUNK):
     """``T`` tokens from the carried state: ``S0 (B, H, dk, dv)``,
-    ``q, k, g (B, T, H, dk)``, ``v (B, T, H, dv)``, ``beta (B, T, H)``.
+    ``q, k (B, T, H, dk)``, ``g (B, T, H, dk)`` or ``(B, T, H, 1)`` (a
+    scalar decay a head), ``v (B, T, H, dv)``, ``beta (B, T, H)``.
     Returns ``(o (B, T, H, dv) float32, S_T)``.  ``T`` is cut into
     chunks of ``chunk`` (a shorter ``T`` is one chunk)."""
     B, T, H, dk = q.shape
@@ -150,11 +181,17 @@ def chunked(S0, q, k, v, g, beta, chunk: int = CHUNK):
 
     def one_chunk(S, xs):
         qc, kc, vc, gc, bc = xs
-        G = jnp.cumsum(gc, axis=-2)                                       # (B, H, C, dk), <= 0
-        diff = G[..., :, None, :] - G[..., None, :, :]                    # (B, H, C, C, dk): G_t - G_s
-        E = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf))          # 0 above the diagonal
-        kk = jnp.einsum("bhtc,bhtsc->bhts", kc, kc[..., None, :, :] * E, precision=_HI)
-        qk = jnp.einsum("bhtc,bhtsc->bhts", qc, kc[..., None, :, :] * E, precision=_HI)
+        G = jnp.cumsum(gc, axis=-2)                                       # (B, H, C, dk) or (B, H, C, 1), <= 0
+        if G.shape[-1] == 1 and dk > 1:
+            # a scalar decay a head leaves the channel sum: two matrix products, scaled afterwards
+            E = jnp.exp(jnp.where(lower, G[..., :, None, 0] - G[..., None, :, 0], -jnp.inf))  # (B, H, C, C)
+            kk = jnp.einsum("bhtc,bhsc->bhts", kc, kc, precision=_HI) * E
+            qk = jnp.einsum("bhtc,bhsc->bhts", qc, kc, precision=_HI) * E
+        else:
+            diff = G[..., :, None, :] - G[..., None, :, :]                # (B, H, C, C, dk): G_t - G_s
+            E = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf))      # 0 above the diagonal
+            kk = jnp.einsum("bhtc,bhtsc->bhts", kc, kc[..., None, :, :] * E, precision=_HI)
+            qk = jnp.einsum("bhtc,bhtsc->bhts", qc, kc[..., None, :, :] * E, precision=_HI)
         A = jnp.where(strict, bc[..., None] * kk, 0.0)
         expG = jnp.exp(G)
         rhs = jnp.concatenate([bc[..., None] * vc, bc[..., None] * kc * expG], axis=-1)  # (B, H, C, dv + dk)

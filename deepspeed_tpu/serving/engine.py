@@ -12,6 +12,10 @@ Sits on top of an :class:`~deepspeed_tpu.inference.engine.InferenceEngine`
   the whole slot pool;
 * ``drain()`` — run until every request finishes, return the results.
 
+``serving.overlap_chunks`` (off by default) hands a step's programs to
+the device ahead of the host's reads, so a chunk runs while the host
+turns the step (:meth:`ServingEngine._step_programs_overlapped`).
+
 Exactly **two** executables serve any churning live set: a prefill-chunk
 step (fixed ``(1, prefill_chunk)`` tokens, traced slot + position
 scalars) and a decode step (fixed ``(num_slots, 1)`` tokens, traced
@@ -167,6 +171,7 @@ class ServingEngine:
         self._aux_decode_steps = 0
         # what a cache kind with per-slot state is asked about (stats()["hybrid"])
         self._state_resets = 0
+        self._unread_chunks: list = []  # serving.overlap_chunks: (job, what _launch_prefill returned) of chunks not read back yet
         self._decode_rows = 0
         self._decode_steps = 0
         # how far the paged decode kernel's work list engages (stats()):
@@ -1066,13 +1071,16 @@ class ServingEngine:
                         self._tiers.prefetch_ahead))
         with tl.phase("sched"):
             plan = self.scheduler.tick(t0, self._step_count, admit=admit)
-        with tl.phase("prefill"):
-            for job in plan.prefill_jobs:
-                self._run_prefill(job)
-        with tl.phase("decode"):
-            decoding = self.scheduler.decoding()
-            if decoding:
-                self._run_decode(decoding)
+        if self.config.overlap_chunks:
+            decoding = self._step_programs_overlapped(plan)
+        else:
+            with tl.phase("prefill"):
+                for job in plan.prefill_jobs:
+                    self._run_prefill(job)
+            with tl.phase("decode"):
+                decoding = self.scheduler.decoding()
+                if decoding:
+                    self._run_decode(decoding)
         wall = time.monotonic() - t0
         # the step's books, under one span: gauges, service rate, journal
         with tl.phase("commit"):
@@ -1385,7 +1393,55 @@ class ServingEngine:
             self._kv_evt_seen[key] = int(st[key])
 
     # ------------------------------------------------------------------
+    def _step_programs_overlapped(self, plan) -> list:
+        """``serving.overlap_chunks``: the step's same two programs, all
+        handed to the device before the host reads any of them back, the
+        decode step first.  A chunk that is not its prompt's last is not
+        waited for: the scheduler is told of its progress at once, its
+        token (no request's) and counters are read a step later, when it
+        has long run.  So the device runs a chunk while the host turns
+        the step — the read-back, the notes, the commit, the caller's
+        loop, the next step's staging and dispatch — and finds the next
+        decode step queued behind it.  The device runs its programs in
+        the order they were dispatched, each on the pool the one before
+        left, so what they compute is what the serial step computes;
+        what changes is *when*: the decode set is taken before the
+        step's chunks land, so a request whose last chunk lands in step
+        N (awaited: its first token is the request's) decodes from step
+        N + 1, not N.  The host runs at most one chunk ahead.  Returns
+        the rows decoded."""
+        tl = self.timeline
+        with tl.phase("decode"):
+            decoding = self.scheduler.decoding()
+            launched = self._launch_decode(decoding) if decoding else None
+        with tl.phase("prefill"):
+            chunks = [(job, self._launch_prefill(job)) for job in plan.prefill_jobs]
+            self._land_unread()  # earlier steps' chunks: run by now, or running ahead of all that was launched above
+        if decoding:
+            with tl.phase("decode"):
+                self._land_decode(decoding, launched)
+        with tl.phase("prefill"):
+            for job, launched in chunks:
+                if job.final:
+                    self._land_prefill(job, launched)
+                else:
+                    self.scheduler.note_prefill(job, 0, now=time.monotonic(), step=self._step_count)
+                    self._unread_chunks.append((job, launched))
+        return decoding
+
+    def _land_unread(self) -> None:
+        """Read back what ``serving.overlap_chunks`` left on the device:
+        the counters of chunks whose progress the scheduler already has."""
+        unread, self._unread_chunks = self._unread_chunks, []
+        for job, launched in unread:
+            self._land_prefill(job, launched, noted=True)
+
     def _run_prefill(self, job: PrefillJob) -> None:
+        self._land_prefill(job, self._launch_prefill(job))
+
+    def _launch_prefill(self, job: PrefillJob):
+        """Stage and dispatch one chunk; returns what
+        :meth:`_land_prefill` reads back."""
         faults.check("serving.prefill")
         faults.check_latency("serving.prefill")
         san = self._sanitizer
@@ -1409,6 +1465,14 @@ class ServingEngine:
         if self._select_topk:
             self._dsa_chunks += 1
             self._dsa_chunk_attendable += job.length * job.start + job.length * (job.length + 1) // 2  # query i of the chunk: start + i + 1
+        return first, tracer, t0
+
+    def _land_prefill(self, job: PrefillJob, launched, noted: bool = False) -> None:
+        """Read a dispatched chunk back and hand it to the scheduler
+        (``noted``: the scheduler has its progress already)."""
+        tl = self.timeline
+        r = job.req
+        first, tracer, t0 = launched
         # explicit d2h read doubles as the fence that keeps prefill_ms
         # honest; the value is the first generated token on final chunks
         with tl.phase("prefill.wait"):
@@ -1417,7 +1481,7 @@ class ServingEngine:
         with tl.phase("prefill.note"):
             tok = int(tok if self._family_forward is None else self._note_aux(tok, decode=False))
             now = time.monotonic()
-            if self._paged and job.final:
+            if self._paged and job.final and not noted:
                 # the whole prompt's KV is paged in: learn it as a shared
                 # prefix (before note_prefill — a 1-token budget can retire
                 # the request, releasing the slot, inside that call)
@@ -1433,9 +1497,15 @@ class ServingEngine:
                           "len": job.length, "final": job.final},
                     tid_name=f"request {r.request_id}",
                 )
-            self.scheduler.note_prefill(job, tok, now=now, step=self._step_count)
+            if not noted:
+                self.scheduler.note_prefill(job, tok, now=now, step=self._step_count)
 
     def _run_decode(self, decoding) -> None:
+        self._land_decode(decoding, self._launch_decode(decoding))
+
+    def _launch_decode(self, decoding):
+        """Stage and dispatch the decode step over ``decoding``; returns
+        what :meth:`_land_decode` reads back."""
         faults.check("serving.decode")
         faults.check_latency("serving.decode")
         san = self._sanitizer
@@ -1465,6 +1535,10 @@ class ServingEngine:
             fills = [len(r.prompt) + len(r.generated) for r in decoding]  # positions 0 ... fill - 1, the query's own among them
             self._dsa_attendable += sum(fills)
             self._dsa_selected += sum(min(f, self._select_topk) for f in fills)
+        return nxt
+
+    def _land_decode(self, decoding, nxt) -> None:
+        tl = self.timeline
         with tl.phase("decode.wait"):
             out = jax.device_get(nxt)
         with tl.phase("decode.note"):
@@ -1566,6 +1640,7 @@ class ServingEngine:
         records.  Host-side deadline sweep included: an idle engine's
         over-deadline waiters expire the moment anyone looks, not only
         when a ``step()`` happens to run."""
+        self._land_unread()
         s = self.scheduler
         if s.sweep_expired(time.monotonic(), self._step_count):
             self._journal_commit()
@@ -1644,6 +1719,7 @@ class ServingEngine:
         # Solar-Open2: kda_decode_kernel / _fallback, kda_prefill_form, gqa_decode_kernel / _fallback, gqa_prefill_form;
         # ZAYA: cca_decode_kernel / _fallback, cca_prefill_form, moe_router_form;
         # Keye: dsa_index_form, dsa_select_form, dsa_decode_kernel, dsa_prefill_form, moe_router_form;
+        # GigaChat3.5: gdn_decode_kernel / _fallback, gdn_prefill_form, mla_decode_kernel / _fallback, mla_prefill_form, moe_router_form;
         # the paged per-head pool: kv_write_form, prefill_attend_form;
         # all three that decode through flash_decode_paged: paged_decode_walk)
         out.update(self._trace_notes)

@@ -153,11 +153,15 @@ class HybridKV:
     and values per position (docs/serving.md §Cache kinds).  Two
     geometries in one pool:
 
-    * **pages** — K and V of the ``paged_layers`` softmax-attention
-      layers, ``(paged_layers, pages, kv_heads, page_len, head_dim)``:
-      the :class:`PerHeadKV` layout, as deep as the family says (some of
-      the layers, or all of them);
-    * **state** — a third group with a **slot** axis where the others
+    * **pages** — what the ``paged_layers`` attention layers cache a
+      position, in the layout of the **page kind the family names**
+      (``pages``): :class:`PerHeadKV` (K and V, ``(paged_layers, pages,
+      kv_heads, page_len, head_dim)``) for softmax attention over
+      per-head keys and values, :class:`LatentKV` (one buffer
+      ``(paged_layers, pages, width, page_len)``, no V) for latent
+      attention — as deep as the family says (some of the layers, or all
+      of them);
+    * **state** — a further group with a **slot** axis where the others
       have pages (``pool.state``), its leaves **declared by the family**:
       ``state = {name: (layers, shape a slot, dtype)}`` makes a buffer
       ``(layers, slots) + shape`` a name.  A linear-attention layer keeps
@@ -170,24 +174,22 @@ class HybridKV:
 
     A page here does **not** hold everything its positions left behind
     — part of their trace is in the slot's state — so a page cannot
-    stand for a prefix: ``pages_hold_all`` is False and the pool turns
-    prefix hits, prefix learning, session rebinds, spill and tiers off,
-    explicitly (``stats()["reuse"]``).  Copy-on-write, which only ever
-    follows a shared page, never happens; the state is never copied.  A
-    fresh request needs no reset of its slot's state: the model's
-    prefill starts from zero where the chunk starts at position 0."""
+    stand for a prefix: ``pages_hold_all`` is False whatever the page
+    kind says of itself, and the pool turns prefix hits, prefix
+    learning, session rebinds, spill and tiers off, explicitly
+    (``stats()["reuse"]``).  Copy-on-write, which only ever follows a
+    shared page, never happens; the state is never copied.  A fresh
+    request needs no reset of its slot's state: the model's prefill
+    starts from zero where the chunk starts at position 0."""
 
     pages_hold_all = False
 
-    def __init__(self, paged_layers: int, kv_heads: int, head_dim: int, dtype: Any,
-                 state: Dict[str, Tuple[int, Tuple[int, ...], Any]]):
-        self.paged_layers, self.heads, self.head_dim, self.dtype = int(paged_layers), int(kv_heads), int(head_dim), dtype
+    def __init__(self, paged_layers: int, pages: Any, state: Dict[str, Tuple[int, Tuple[int, ...], Any]]):
+        self.paged_layers, self.pages, self.dtype = int(paged_layers), pages, pages.dtype
         self.state = {name: (int(layers), tuple(int(n) for n in shape), dt) for name, (layers, shape, dt) in state.items()}
 
     def buffers(self, n_layer: int, num_pages: int, page_len: int):
-        from deepspeed_tpu.ops.transformer.inference import init_kv_cache
-
-        return init_kv_cache(self.paged_layers, num_pages, self.heads, page_len, self.head_dim, self.dtype)
+        return self.pages.buffers(self.paged_layers, num_pages, page_len)
 
     def state_buffers(self, num_slots: int) -> Dict[str, Any]:
         return {name: jnp.zeros((layers, num_slots) + shape, dt) for name, (layers, shape, dt) in self.state.items()}
@@ -195,8 +197,9 @@ class HybridKV:
     def describe(self, n_layer: int, num_pages: int, page_len: int) -> str:
         leaves = " + ".join(f"{name}: {layers} layers x {' x '.join(str(n) for n in shape)} {np.dtype(dt).name}"
                             for name, (layers, shape, dt) in self.state.items())
-        return (f"pages 2 x ({self.paged_layers} of {n_layer} layers x {num_pages} pages x {self.heads} heads x "
-                f"{page_len} page_len x {self.head_dim} head_dim) + state per slot ({leaves})")
+        pages = self.pages.describe(self.paged_layers, num_pages, page_len).replace(
+            f"({self.paged_layers} layers", f"({self.paged_layers} of {n_layer} layers", 1)
+        return f"pages {pages} + state per slot ({leaves})"
 
 
 REUSE_OFF = ("off: this cache kind keeps part of a position's trace in per-slot state, so a page cannot stand for a "
@@ -1072,12 +1075,12 @@ class PagedKVPool:
             "session_drops": sess["drops"],
         }
         if self.state is not None:
-            out["kind"] = self.kind.describe(self.n_layer, self.num_pages, self.page_len)
             out["state_bytes"] = self.state_bytes()
             # the leaves the family declared, by name: bytes over all slots
             out["state_leaves"] = {name: int(buf.size * buf.dtype.itemsize) for name, buf in self.state.items()}
-        if getattr(self.kind, "names_page_leaves", False):
-            # a kind whose pages carry more than K and V: each leaf's bytes over the pool, by its last name
+            out["page_kind"] = type(self.kind.pages).__name__
+        if self.state is not None or getattr(self.kind, "names_page_leaves", False):
+            # a kind whose pages carry more than K and V, or stand beside a state: each leaf's bytes over the pool, by its last name
             out["kind"] = self.kind.describe(self.n_layer, self.num_pages, self.page_len)
             out["page_leaves"] = {name.rsplit(".", 1)[-1]: int(buf.size * buf.dtype.itemsize)
                                   for name, buf in _named_leaves(self.k, self.v).items()}

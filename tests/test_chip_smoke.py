@@ -323,6 +323,57 @@ def test_zaya_steps_compile_for_v5e_with_pages_and_tail_updated_in_place(which, 
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_gigachat35_steps_compile_for_v5e_with_latent_pages_and_state_updated_in_place(which, v5e_chip, monkeypatch):
+    """The cell's five layers of GigaChat3.5 at the published widths on
+    its hybrid cache over latent pages (96 slots of 280 pages, 8,193
+    latent pages of one layer, a 17 MB state a slot), by the chip's
+    compiler without the chip: a decode step holds ``gdn_decode`` once a
+    delta-rule layer, ``mla_decode_paged`` once and the experts' kernel
+    twice an expert layer; a chunk of 1,024 holds ``mla_prefill`` and the
+    experts' kernel; neither leaves a copy of the latent pool or of the
+    recurrent state in the program."""
+    from deepspeed_tpu.models import gigachat35 as gc
+    from deepspeed_tpu.ops.kernels import grouped_matmul, kda_decode, mla_decode, mla_prefill
+
+    monkeypatch.setenv("DS_KERNELS", "1")
+    for mod in (grouped_matmul, kda_decode, mla_decode, mla_prefill):
+        monkeypatch.setattr(mod, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
+    slots, pages, chunk, per_slot = 96, 8193, 1024, 280
+    cfg = gc.GigaChat35Config(num_hidden_layers=5, first_k_dense_replace=1, full_attention_layers=(4,), experts_held=(0, 16),
+                              vocab_held=16032)
+    on_chip = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    params = jax.tree.map(lambda sh: on_chip(sh, jnp.bfloat16), gc.param_shapes(cfg), is_leaf=lambda sh: isinstance(sh, tuple))
+    kind = gc.cache_kind(cfg, jnp.bfloat16)
+    pool = jax.eval_shape(lambda: kind.buffers(5, pages, 128)[0])
+    pool = on_chip(pool.shape, pool.dtype)
+    state = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(lambda: kind.state_buffers(slots)))
+    notes = {}
+    if which == "decode":
+        def step(p, t, pos, table, wm, k, st):
+            return gc.forward_with_cache(p, t[:, None], k, st, pos, cfg, table, write_mask=wm, row_valid=wm[:, None], trace_notes=notes)
+        args = (params, on_chip((slots,), jnp.int32), on_chip((slots,), jnp.int32), on_chip((slots, per_slot), jnp.int32),
+                on_chip((slots,), jnp.bool_), pool, state)
+    else:
+        def step(p, t, table, slot, pos, rv, k, st):
+            return gc.forward_with_cache(p, t, k, st, pos[None], cfg, table[None], slot=slot[None], row_valid=rv, trace_notes=notes)
+        args = (params, on_chip((1, chunk), jnp.int32), on_chip((per_slot,), jnp.int32), on_chip((), jnp.int32), on_chip((), jnp.int32),
+                on_chip((1, chunk), jnp.bool_), pool, state)
+    compiled = jax.jit(step, donate_argnums=(len(args) - 2, len(args) - 1)).lower(*args).compile()
+    found = chip_smoke.mosaic_kernels(compiled.as_text())
+    if which == "decode":
+        assert found == {"gdn_decode": 4, "mla_decode_paged": 1, "moe_grouped_matmul": 8}
+        assert notes["gdn_decode_kernel"] is True and notes["mla_decode_kernel"] is True and notes["moe_grouped_kernel"] == "768"
+    else:
+        assert found == {"mla_prefill": 1, "moe_grouped_matmul": 8}
+        assert notes["mla_prefill_kernel"] is True and notes["moe_grouped_kernel"] == "8192" and notes["gdn_prefill_form"].startswith("chunked")
+    assert notes["moe_grouped_fallback"] == ""
+    m = compiled.memory_analysis()
+    cache_bytes = int(np.prod(pool.shape)) * 2 + sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert cache_bytes > 2.8e9 and m.alias_size_in_bytes >= cache_bytes   # the latent pool and both state leaves come back in place
+    assert m.temp_size_in_bytes < int(np.prod(pool.shape)) * 2            # and no temporary is as large as the latent pool (1.2 GB)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_keye_steps_compile_for_v5e_with_all_three_leaves_updated_in_place(which, v5e_chip, monkeypatch):
     """Two layers of Keye-VL-2.0's language model at the published widths
     on the cell's three-leaf cache (16 slots of 264 pages, 3,329 pages: K,

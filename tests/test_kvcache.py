@@ -711,9 +711,9 @@ def test_fleet_session_stickiness_three_turns(eng, tmp_path):
 # ---------------------------------------------------------------------------
 
 def _hybrid_pool(**kw):
-    from deepspeed_tpu.serving.kvcache.pages import HybridKV
+    from deepspeed_tpu.serving.kvcache.pages import HybridKV, PerHeadKV
 
-    kind = HybridKV(paged_layers=2, kv_heads=2, head_dim=8, dtype=jnp.float32,
+    kind = HybridKV(paged_layers=2, pages=PerHeadKV(2, 8, jnp.float32),
                     state={"s": (6, (4, 8, 8), jnp.float32), "conv": (6, (3, 96), jnp.float32)})
     return PagedKVPool(8, 3, 0, 64, 0, jnp.float32, page_len=16, num_pages=13, prefill_chunk=16, kind=kind, **kw)
 
@@ -766,6 +766,83 @@ def test_hybrid_kind_refuses_prefix_hits_session_rebinds_and_spill_and_says_so(t
         pool.attach_tiers(object())
     with pytest.raises(SlotPoolError, match="prefix reuse is off"):
         pool.import_sessions(str(tmp_path))
+
+
+def _hybrid_latent_pool(**kw):
+    from deepspeed_tpu.serving.kvcache.pages import HybridKV, LatentKV
+
+    kind = HybridKV(paged_layers=1, pages=LatentKV(24, jnp.float32),
+                    state={"s": (4, (4, 8, 8), jnp.float32), "conv": (4, (3, 96), jnp.float32)})
+    return PagedKVPool(5, 3, 0, 64, 0, jnp.float32, page_len=16, num_pages=13, prefill_chunk=16, kind=kind, **kw)
+
+
+def test_hybrid_kind_over_latent_pages_has_one_latent_leaf_for_its_paged_layers_and_a_slot_axis_group():
+    pool = _hybrid_latent_pool()
+    assert pool.k.shape == (1, 13, 24, 16) and pool.v is None  # one of the five layers; positions along the lanes; no V
+    assert pool.state["s"].shape == (4, 3, 4, 8, 8) and pool.state["conv"].shape == (4, 3, 3, 96)
+    state_bytes = 4 * 3 * (4 * 8 * 8 + 3 * 96) * 4
+    page_bytes = 1 * 13 * 24 * 16 * 4
+    assert pool.state_bytes() == state_bytes and pool.cache_bytes() == page_bytes + state_bytes
+    assert "1 x (1 of 5 layers x 13 pages x 24 latent x 16 page_len)" in pool.shape_math()
+    st = pool.stats()
+    assert st["kind"] == pool.kind.describe(5, 13, 16) and st["page_kind"] == "LatentKV"
+    assert st["page_leaves"] == {"k": page_bytes} and st["state_leaves"] == {"s": 4 * 3 * 4 * 8 * 8 * 4, "conv": 4 * 3 * 3 * 96 * 4}
+    assert not pool.reuse and pool.kind.pages_hold_all is False  # whatever LatentKV says of itself
+    # the per-head hybrid says its own page kind and leaves the same way
+    st2 = _hybrid_pool().stats()
+    assert st2["page_kind"] == "PerHeadKV" and st2["page_leaves"] == {"k": 2 * 13 * 2 * 16 * 8 * 4, "v": 2 * 13 * 2 * 16 * 8 * 4}
+
+
+def test_hybrid_kind_over_latent_pages_allocates_frees_and_refuses_prefix_reuse_as_the_per_head_one(tmp_path):
+    from deepspeed_tpu.serving.kvcache.pages import REUSE_OFF
+
+    pool = _hybrid_latent_pool()
+    prompt = np.arange(1, 41, dtype=np.int32)
+    r1 = _KReq(1, prompt, max_new=4, sid="chat", generated=[5, 6, 7, 8], finish_reason="length")
+    r1.slot = pool.alloc_request(r1)
+    assert r1.prefill_pos == 0 and pool.pages_live == 3  # 44 positions: three pages of 16
+    assert (np.asarray(pool.table(r1.slot))[:3] > 0).all() and not np.asarray(pool.table(r1.slot))[3:].any()
+    pool.learn_prefix(r1)
+    assert len(pool.index) == 0
+    pool.retire(r1.slot, r1)
+    assert pool.sessions.peek("chat") is None and pool.pages_live == 0 and pool.free_slots == 3
+    r2 = _KReq(2, prompt, max_new=4, sid="chat")  # same prompt, same session: a miss, prefilled from position 0
+    r2.slot = pool.alloc_request(r2)
+    assert (r2.prefill_pos, r2.prefix_hint) == (0, 0) and pool.consume_cow(r2.slot) == (GARBAGE_PAGE, GARBAGE_PAGE)
+    st = pool.stats()
+    assert st["reuse"] == REUSE_OFF and st["sessions_unbound"] == 2 and (st["prefix_hits"], st["cow_copies"]) == (0, 0)
+    pool.retire(r2.slot, r2)
+    _assert_no_leaks(pool)
+    with pytest.raises(SlotPoolError, match="prefix reuse is off"):
+        _hybrid_latent_pool(spill_dir=str(tmp_path))
+    with pytest.raises(SlotPoolError, match="prefix reuse is off"):
+        _hybrid_latent_pool(pinned_prefixes=[[1, 2, 3]])
+
+
+@pytest.mark.parametrize("family", ["solar_open2", "zaya"])
+def test_the_per_head_hybrid_families_pools_are_what_they_were(family):
+    """Solar-Open2's and ZAYA1's pools, built through their ``cache_kind``
+    over the page kind they now name: the buffers' shapes, dtypes and the
+    kind's description as before the hybrid kind took a page kind."""
+    import importlib
+
+    mod = importlib.import_module(f"deepspeed_tpu.models.{family}")
+    cfg = mod.SOLAR_OPEN2_TINY if family == "solar_open2" else mod.ZAYA_TINY
+    kind = mod.cache_kind(cfg, jnp.bfloat16)
+    k, v = kind.buffers(cfg.n_layer, 9, 16)
+    state = kind.state_buffers(2)
+    if family == "solar_open2":
+        assert k.shape == v.shape == (2, 9, 2, 16, 16) and k.dtype == jnp.bfloat16
+        assert {n: (b.shape, str(b.dtype)) for n, b in state.items()} == {
+            "s": ((6, 2, 4, 16, 16), "float32"), "conv": ((6, 2, 3, 192), "bfloat16")}
+        assert kind.describe(8, 9, 16) == ("pages 2 x (2 of 8 layers x 9 pages x 2 heads x 16 page_len x 16 head_dim) + state per slot "
+                                           "(s: 6 layers x 4 x 16 x 16 float32 + conv: 6 layers x 3 x 192 bfloat16)")
+    else:
+        assert k.shape == v.shape and k.shape[0] == cfg.n_layer and k.shape[1:] == (9, cfg.cca.kv_heads, 16, cfg.cca.head_dim)
+        assert set(state) == {"conv", "vshift"} and state["conv"].shape[:2] == (cfg.n_layer, 2)
+        assert kind.describe(cfg.n_layer, 9, 16).startswith(f"pages 2 x ({cfg.n_layer} of {cfg.n_layer} layers x 9 pages x ")
+    assert not any(np.asarray(b, np.float32).any() for b in (k, v, *state.values()))
+    assert type(kind.pages).__name__ == "PerHeadKV" and kind.pages_hold_all is False
 
 
 @pytest.mark.parametrize("kind_name", ["per_head", "latent"])

@@ -116,6 +116,39 @@ def test_chunked_prefill_parity():
     np.testing.assert_array_equal(res[r_long].tokens(), _solo(eng, long_, 4))
 
 
+@pytest.mark.parametrize("pool", [{}, {"kvcache": {"enabled": True, "page_len": 8}},
+                                  {"prefill_chunks_per_step": 2, "kvcache": {"enabled": True, "page_len": 8}}],
+                         ids=["slots", "paged", "paged-two-chunks-a-step"])
+def test_overlap_chunks_serves_the_serial_steps_tokens(pool):
+    """``serving.overlap_chunks`` hands the same two programs to the
+    device in the same order on the same pool, ahead of the host's
+    reads: every request's tokens are the serial step's, a chunk that is
+    not its prompt's last is left unread for one step, and a request
+    decodes from the step after its last chunk."""
+    eng = _engine()
+    rng = np.random.default_rng(5)
+    reqs = [(rng.integers(1, TINY.vocab_size, n, dtype=np.int32), m) for n, m in ((20, 6), (37, 9), (5, 4), (50, 7), (16, 5), (33, 8), (3, 3))]
+
+    def serve(**kw):
+        srv = ServingEngine(eng, config={"num_slots": 3, "max_len": 64, "prefill_chunk": 8, **pool, **kw})
+        rids = [srv.submit(p, max_new_tokens=m) for p, m in reqs[:5]]
+        srv.step()
+        unread_after_first_step = len(srv._unread_chunks)
+        srv.step()
+        rids += [srv.submit(p, max_new_tokens=m) for p, m in reqs[5:]]  # late arrivals, while a chunk is in flight
+        res = srv.drain(max_steps=500)
+        return [list(res[r].generated) for r in rids], unread_after_first_step, srv
+
+    serial, none_unread, _ = serve()
+    overlapped, unread, srv = serve(overlap_chunks=True)
+    assert overlapped == serial and none_unread == 0
+    assert unread == pool.get("prefill_chunks_per_step", 1)  # the 20-token prompt's first chunks: progress noted, token unread
+    assert srv.scheduler.has_work() is False and srv.stats()["finished"] == 7 and not srv._unread_chunks
+    assert (srv.prefill_compiles, srv.decode_compiles) == (1, 1)
+    chunks = sum(-(-len(p) // 8) for p, _ in reqs)
+    assert srv.timeline.summary()["programs"] == chunks + srv._decode_steps  # every chunk ran once, read late or not
+
+
 def test_eos_retires_at_token_granularity():
     """Declaring a known generated token as EOS must retire the request
     the step that token appears, freeing its slot for the queue."""
