@@ -167,6 +167,7 @@ def gathered_float_shapes(hlo: str) -> List[Tuple[int, ...]]:
 
 
 _INSTR_RE = re.compile(r"^\s*(?:ROOT )?%?\S+ = (.*?)\s([a-z][a-z\-]*)\(", re.M)
+_LEAF_RE = re.compile(r"(bf16|f16|f32|s8)\[([\d,]+)\]")  # a leaf may be int8 codes
 # what may hold an array without moving it
 _NO_MOVE = ("parameter", "get-tuple-element", "bitcast", "tuple")
 
@@ -179,7 +180,7 @@ def leaf_sized_moves(hlo: str, elems: int) -> List[str]:
     was handed a view its buffers do not have)."""
     out = []
     for m in _INSTR_RE.finditer(hlo):
-        sizes = [math.prod(int(d) for d in dims.split(",")) for _, dims in _SHAPE_RE.findall(m.group(1))]
+        sizes = [math.prod(int(d) for d in dims.split(",")) for _, dims in _LEAF_RE.findall(m.group(1))]
         line = hlo[m.start():hlo.find("\n", m.end())]
         if elems in sizes and m.group(2) not in _NO_MOVE and "tpu_custom_call" not in line:
             out.append(m.group(2))
@@ -283,6 +284,47 @@ def check_flash_decode_paged(s: Smoke, mcfg, kv_dtype, kv_heads=None, head_dim=N
     # the output is cast to bf16 on both paths: one bf16 ulp on top of TOL_F32
     check(err < TOL_F32 + 2 ** -8, f"flash_decode_paged[{name}] off its reference by {err}")
     return err
+
+
+def check_paged_kv_write(s: Smoke, mcfg, kv_dtype) -> None:
+    """A decode step's K/V write into the stacked pool — on the chip the
+    one aliased ``paged_kv_write`` call a leaf, at the toy size the
+    slices — against the scatter ``paged_cache_write`` on the layer's
+    slice, **bit for bit on every page but the garbage page**: a layer
+    that is not the first, offsets 0 and ``page_len - 1``, a row at its
+    slot's last position, masked rows between writing ones, and the
+    other layers untouched.  The pool is donated and comes back."""
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    B, H, d, P, L, layer = s.slots, mcfg.n_head, mcfg.head_dim, s.max_len // s.page_len, 3, 1
+    rng = np.random.default_rng(s.seed + 2)
+    table = (1 + rng.permutation(B * P)).reshape(B, P).astype(np.int32)
+    pos = rng.integers(0, P * s.page_len, (B,)).astype(np.int32)
+    pos[:3] = (0, s.page_len - 1, P * s.page_len - 1)[:B]
+    mask = np.ones((B,), bool)
+    mask[1::3] = B < 3  # rows 1, 4, 7 of the chip's eight write nothing
+    k_pool, _ = inf.init_kv_cache(L, 1 + B * P, H, s.page_len, d, kv_dtype)
+    fill = lambda a, key: (jax.random.randint(key, a.shape, -127, 128, jnp.int32) if a.dtype == jnp.int8  # noqa: E731
+                           else jax.random.normal(key, a.shape, jnp.float32)).astype(a.dtype)
+    leaves, tree = jax.tree.flatten(k_pool)
+    k_pool = jax.tree.unflatten(tree, [fill(a, jax.random.PRNGKey(s.seed + 3 + i)) for i, a in enumerate(leaves)])
+    t = jax.random.normal(jax.random.PRNGKey(s.seed + 9), (B, H, 1, d), jnp.float32).astype(jnp.bfloat16)
+    args = (jnp.asarray(table), jnp.asarray(pos), jnp.asarray(mask))
+    want = jax.tree.map(lambda a, w: np.asarray(a.at[layer].set(w)), k_pool,
+                        inf.paged_cache_write(jax.tree.map(lambda a: a[layer], k_pool), t, *args))
+    takes_kernel = inf.decode_write_takes_kernel(k_pool, s.mosaic)
+    write = jax.jit(lambda c, t, table, pos, m: inf.paged_cache_write_slices(c, layer, t, table, pos, m, use_kernel=s.mosaic),
+                    donate_argnums=0)
+    write = write.lower(k_pool, t, *args).compile()
+    kernels = mosaic_kernels(write.as_text())
+    got = jax.tree.map(np.asarray, write(k_pool, t, *args))
+    name = "int8" if kv_dtype == "int8" else jnp.dtype(kv_dtype).name
+    check(kernels == ({"paged_kv_write": len(leaves)} if takes_kernel else {}), f"paged K/V write[{name}]: Mosaic calls {kernels}")
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        check(g.dtype == w.dtype and np.array_equal(g[:, 1:], w[:, 1:]),
+              f"paged K/V write[{name}]: {int((g[:, 1:] != w[:, 1:]).sum())} values of a {g.dtype} leaf differ from the scatter's")
+    say(f"paged K/V write[{name}] ({inf.KV_WRITE_FORMS[takes_kernel]}; B={B}, {int(mask.sum())} rows writing, "
+        f"pages of {H}x{s.page_len}x{d}): the scatter's, bit for bit")
 
 
 def check_fused_update(s: Smoke) -> Dict[str, float]:
@@ -452,6 +494,7 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
     compute the same tokens four times."""
     from deepspeed_tpu.comm.mesh import make_mesh
     from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.ops.transformer import inference
     from deepspeed_tpu.serving import ServingEngine
 
     t0 = time.perf_counter()
@@ -471,6 +514,7 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
             check_flash_decode_paged(s, mcfg, "int8" if kv == "int8" else inf.dtype)
             if kv == "model":  # grouped queries over one KV head of whole lane rows, eight pages a slot: a span of eight
                 check_flash_decode_paged(s, mcfg, inf.dtype, kv_heads=1, head_dim=128, pages=8)
+        check_paged_kv_write(s, mcfg, "int8" if kv == "int8" else inf.dtype)
         srv = ServingEngine(inf, config={
             "num_slots": s.slots, "max_len": s.max_len, "kv_cache_dtype": kv,
             "prefill_chunk": s.prefill_chunk,
@@ -493,10 +537,13 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
               f"serve[{kv}]: {srv.prefill_compiles} prefill / {srv.decode_compiles} decode executables for one pool")
         say(f"serve[{kv}]: {len(ids)} requests x {s.new_tokens} tokens done, smoke wall {wall:.1f}s "
             f"(compiles included), pool {srv.pool.cache_bytes() / 2**30:.2f} GiB")
-        # the paged prefill attends block by block in jnp (T > 1); only
-        # the decode step arms a kernel
+        # the paged prefill attends block by block in jnp and writes its
+        # chunk as slices (T > 1); only the decode step arms kernels
         decode = srv.compiled_step("decode")
-        expect_kernels(mosaic_kernels(decode.as_text()), ["flash_decode_paged"] if s.mosaic else [], f"serve[{kv}] decode")
+        decode_hlo = decode.as_text()
+        expect_kernels(mosaic_kernels(decode_hlo), ["flash_decode_paged", "paged_kv_write"] if s.mosaic else [], f"serve[{kv}] decode")
+        form = srv.stats()["kv_write_form"]
+        check(form == inference.KV_WRITE_FORMS[s.mosaic], f"serve[{kv}]: kv_write_form reads {form!r}")
         expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()), [], f"serve[{kv}] prefill")
         # the pool is written in place in the one layout the kernel
         # reads: the decode program hands all of it back aliased and
@@ -511,6 +558,11 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
         check(m.alias_size_in_bytes >= pool, f"serve[{kv}] decode: {m.alias_size_in_bytes} B aliased, the pool holds {pool}")
         check(m.temp_size_in_bytes < layer_kv + head + (32 << 20),
               f"serve[{kv}] decode: {m.temp_size_in_bytes} B of temporaries beside a layer slice of {layer_kv} B")
+        if s.mosaic:
+            # and nothing but the layer loop produces an array the size of a K or V leaf (int8: of its codes):
+            # the Mosaic calls write through bitcasts of the carry, no copy, relayout or slice update of it is left
+            moves = leaf_sized_moves(decode_hlo, max(a.size for a in jax.tree.leaves(srv.pool.k)))
+            check(set(moves) <= {"while"}, f"serve[{kv}] decode: pool-sized {sorted(set(moves) - {'while'})} beside the layer loop")
         out[kv] = [done[rid].generated for rid in ids]
         del srv, done
         gc.collect()

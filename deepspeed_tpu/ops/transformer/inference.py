@@ -182,8 +182,13 @@ def paged_cache_write(cache, t, page_table, pos, write_mask=None):
     return scat(cache, t)
 
 
-def page_target(page_table, b: int, at, page_len: int, span: int = 1, write_mask=None):
-    """``(page id, offset)`` of logical position ``at`` of row ``b``,
+# ``stats()["kv_write_form"]`` of a paged per-head pool, by :func:`decode_write_takes_kernel`
+KV_WRITE_FORMS = {False: "slices, in place", True: "paged_kv_write a decode step, slices a chunk, in place"}
+
+
+def page_target(page_table, b, at, page_len: int, span: int = 1, write_mask=None):
+    """``(page id, offset)`` of logical position ``at`` of row ``b`` (an
+    int, or every row's index beside every row's ``at``),
     clipped so that ``span`` positions from it fit the slot; a row whose
     ``write_mask`` is False goes to the garbage page (page 0, offset 0).
     The index arithmetic of every slice-wise cache write (here and
@@ -195,7 +200,35 @@ def page_target(page_table, b: int, at, page_len: int, span: int = 1, write_mask
     return pid, off
 
 
-def paged_cache_write_slices(pool, layer, t, page_table, pos, write_mask=None):
+def decode_write_takes_kernel(pool, use_kernel: Optional[bool] = None) -> bool:
+    """Whether a decode step's rows go into ``pool`` (a stacked pool, or
+    its int8 code+scale pair, which goes the way of its codes) through
+    ``paged_kv_write``: the kernel suite armed (``use_kernel`` None) and
+    a head narrower than the lanes in pages of whole lane rows — read
+    off the pool's shape, as ``flash_decode_paged`` reads its tile."""
+    from deepspeed_tpu.ops import kernels as _kernels
+    from deepspeed_tpu.ops.kernels.paged_kv_write import paged_kv_write_supported
+
+    armed = _kernels.flash_decode_armed() if use_kernel is None else use_kernel
+    return armed and paged_kv_write_supported(*(pool["q"] if isinstance(pool, dict) else pool).shape[3:])
+
+
+def decode_write_plan(pool, page_table, pos, write_mask=None, use_kernel: Optional[bool] = None):
+    """What a decode step's writes into ``pool`` share across its layers
+    — ``paged_kv_write``'s prefetched scalars: every row's
+    :func:`page_target` and the compacted list of the rows that write —
+    or ``None`` where the pool takes its rows as slices
+    (:func:`decode_write_takes_kernel`).  A layer loop builds it once,
+    outside its body, and hands it to :func:`paged_cache_write_slices`."""
+    if not decode_write_takes_kernel(pool, use_kernel):
+        return None
+    from deepspeed_tpu.ops.kernels.paged_kv_write import paged_write_plan
+
+    page_len = jax.tree.leaves(pool)[0].shape[3]
+    return paged_write_plan(*page_target(page_table, jnp.arange(page_table.shape[0]), pos, page_len, 1, write_mask), write_mask)
+
+
+def paged_cache_write_slices(pool, layer, t, page_table, pos, write_mask=None, use_kernel: Optional[bool] = None, plan=None):
     """:func:`paged_cache_write` into layer ``layer`` (a Python int or a
     traced scalar) of a stacked pool ``(layers, num_pages, H, page_len,
     d)`` — or of the int8 code+scale pair of such pools, the rows
@@ -206,7 +239,16 @@ def paged_cache_write_slices(pool, layer, t, page_table, pos, write_mask=None):
     TPU it copied both pools (2 x 1.3 GB at 5,121 pages of 8 x 128 x
     128) into that layout and back, every step.
 
-    One position a row (decode) is one update.  A chunk (``T > 1``) is
+    One position a row (decode) is one update where the pool is
+    row-major — ``d`` of whole lane rows — and **one aliased Mosaic call
+    for all rows** where a head is narrower than the lanes and the pool
+    so lies with ``page_len`` in them (:func:`decode_write_takes_kernel`;
+    ``ops/kernels/paged_kv_write.py``): a position is then a column of
+    ``H x d`` lane rows, ~5 us an update whatever it moves, and the call
+    rewrites the page's tile instead — the same bytes in the same place,
+    bit for bit; ``plan``, where the caller built it once for all its
+    layers (:func:`decode_write_plan`), saves building it here.  A chunk
+    (``T > 1``) is
     written **page by page, wherever it starts**: each of the
     ``ceil(T / page_len) + 1`` pages it can touch is read, the positions
     the chunk covers replaced, and written back — a start inside a page
@@ -215,13 +257,18 @@ def paged_cache_write_slices(pool, layer, t, page_table, pos, write_mask=None):
     page are dropped."""
     if isinstance(pool, dict):
         cq, cs = _kv_quant(t)
-        return {"q": paged_cache_write_slices(pool["q"], layer, cq, page_table, pos, write_mask),
-                "s": paged_cache_write_slices(pool["s"], layer, cs, page_table, pos, write_mask)}
+        use_kernel = decode_write_takes_kernel(pool, use_kernel)  # the scales follow the codes
+        return {"q": paged_cache_write_slices(pool["q"], layer, cq, page_table, pos, write_mask, use_kernel, plan),
+                "s": paged_cache_write_slices(pool["s"], layer, cs, page_table, pos, write_mask, use_kernel, plan)}
     page_len, P = pool.shape[3], page_table.shape[1]
     B, H, T, d = t.shape
     t = t.astype(pool.dtype)
     zero, layer = jnp.int32(0), jnp.asarray(layer, jnp.int32)
     if T == 1:
+        if decode_write_takes_kernel(pool, use_kernel):
+            from deepspeed_tpu.ops.kernels.paged_kv_write import paged_kv_write
+
+            return paged_kv_write(pool, layer, t, plan or decode_write_plan(pool, page_table, pos, write_mask, True))
         for b in range(B):
             pid, off = page_target(page_table, b, pos[b], page_len, 1, write_mask)
             pool = jax.lax.dynamic_update_slice(pool, t[b][None, None], (layer, pid, zero, off, zero))
@@ -514,6 +561,7 @@ def inference_block(
     layer=None,
     trace_notes: Optional[dict] = None,
     work=None,
+    write_plan=None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One transformer layer with cache update.
 
@@ -531,8 +579,9 @@ def inference_block(
     the pool as slices (``write_mask`` redirecting masked rows to the
     garbage page) and attention reads the layer's pages where they lie:
     one query through the paged decode kernel (or the gather + lax
-    form) — ``work`` its work list, built once for all layers
-    (``flash_decode.paged_work_list``) — a chunk block by block over the slot's pages
+    form) — ``work`` its work list and ``write_plan`` the writes'
+    targets, each built once for all layers
+    (``flash_decode.paged_work_list``, :func:`decode_write_plan`) — a chunk block by block over the slot's pages
     — requires a per-slot ``pos`` and no ``key_padding_mask``.  Returns
     (y, new_k_cache, new_v_cache).  Mirrors the reference's fused
     attention+MLP inference module (``transformer_inference.py``
@@ -552,11 +601,11 @@ def inference_block(
     if page_table is not None:
         if key_padding_mask is not None:
             raise ValueError("paged caches do not support key_padding_mask")
-        k_cache = paged_cache_write_slices(k_cache, layer, k, page_table, pos, write_mask)
-        v_cache = paged_cache_write_slices(v_cache, layer, v, page_table, pos, write_mask)
+        k_cache = paged_cache_write_slices(k_cache, layer, k, page_table, pos, write_mask, plan=write_plan)
+        v_cache = paged_cache_write_slices(v_cache, layer, v, page_table, pos, write_mask, plan=write_plan)
         kc, vc, table = layer_pages(k_cache, v_cache, page_table, layer)
         if trace_notes is not None:
-            trace_notes["kv_write_form"] = "slices, in place"
+            trace_notes["kv_write_form"] = KV_WRITE_FORMS[decode_write_takes_kernel(k_cache)]
         if T == 1:
             attn = paged_cache_attention(q, kc, vc, table, pos, work=work, trace_notes=trace_notes)
         else:
@@ -699,6 +748,7 @@ def forward_with_cache(
 
         P = page_table.shape[1]
         work = paged_work_list(pos, write_mask, jax.tree.leaves(k_cache)[0].shape[3], P, paged_tile(k_cache, P)[1]) if T == 1 else None
+        write_plan = decode_write_plan(k_cache, page_table, pos, write_mask) if T == 1 else None  # and so are its writes' targets
 
         def pin(cache):
             if pool_layout is None:
@@ -714,7 +764,7 @@ def forward_with_cache(
             lp, i = xs
             x, k, v = carry
             x, k, v = inference_block(cfg, lp, x, pin(k), pin(v), pos, page_table=page_table, write_mask=write_mask,
-                                      layer=i, trace_notes=trace_notes, work=work)
+                                      layer=i, trace_notes=trace_notes, work=work, write_plan=write_plan)
             return (x, pin(k), pin(v)), None
 
         (x, new_k, new_v), _ = jax.lax.scan(

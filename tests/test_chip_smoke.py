@@ -432,21 +432,25 @@ def test_gpt2_xl_paged_steps_compile_for_v5e_with_the_pool_read_and_written_wher
     serve cells' paged pool (16 slots, 80 pages of 128 x 64: a head of
     half a lane row, which the TPU stores with ``page_len`` in the
     lanes), by the chip's compiler without the chip: a decode step holds
-    ``flash_decode_paged`` in its layer loop, handed ``(d, page_len)``
-    tiles of the pool's own bytes; both steps hand the pool back aliased;
+    ``flash_decode_paged`` and the K and the V pool's ``paged_kv_write``
+    in its layer loop, each handed ``(d, page_len)`` tiles of the pool's
+    own bytes — no slice update of the pool is left in it (1,536 of them
+    were half the step: PERF.md, PR 49), and the writes' plan is built
+    outside the loop; both steps hand the pool back aliased;
     and neither holds an array the size of the pool or of a layer of it
-    other than the pool itself and its in-place slice updates (the
+    other than the pool itself and a chunk's in-place slice updates (the
     scatter, the scan over the pool and the relayout in front of the
     kernel made nine such operations, 3 s of a traced 5: PERF.md, PR 40).
     The carry is pinned to the layout the pool has on the chip, as the
     engine pins it from ``pool.k.format``."""
     from jax.experimental.layout import Layout
 
-    from deepspeed_tpu.ops.kernels import flash_decode
+    from deepspeed_tpu.ops.kernels import flash_decode, paged_kv_write
     from deepspeed_tpu.ops.transformer import inference as inf
 
     monkeypatch.setenv("DS_KERNELS", "1")
-    monkeypatch.setattr(flash_decode, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
+    for kernel in (flash_decode, paged_kv_write):
+        monkeypatch.setattr(kernel, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
     lanes_hold_page_len = Layout(major_to_minor=(0, 1, 2, 4, 3))  # what a v5e gives bf16[.., 128, 64]: the entry layout below
     mcfg = gpt2.PRESETS["gpt2-xl"]
     L, slots, pages, page_len, chunk = 4, 16, 80, 128, 64
@@ -473,12 +477,15 @@ def test_gpt2_xl_paged_steps_compile_for_v5e_with_the_pool_read_and_written_wher
                 on_chip((), jnp.int32), on_chip((), jnp.int32), pool, pool)
     compiled = jax.jit(step, donate_argnums=(len(args) - 2, len(args) - 1)).lower(*args).compile()
     hlo = compiled.as_text()
-    assert chip_smoke.mosaic_kernels(hlo) == ({"flash_decode_paged": 1} if which == "decode" else {})
+    assert chip_smoke.mosaic_kernels(hlo) == ({"flash_decode_paged": 1, "paged_kv_write": 2} if which == "decode" else {})
     assert "bf16[%d,%d,%d,%d,%d]{3,4,2,1,0" % (L, pages, H, page_len, d) in hlo.split("\n", 1)[0]  # the entry layout is the pinned one
     if which == "decode":
         assert chip_smoke.first_output_dims(hlo, "flash_decode_paged") == (slots, H, 1, d)
     pool_elems = L * pages * H * page_len * d
-    assert set(chip_smoke.leaf_sized_moves(hlo, pool_elems)) <= {"dynamic-update-slice", "fusion", "while"}  # slices, in place
+    # a chunk's slices, in place; a decode step's layer loop and nothing else, its sorts (the work list's, the write plan's) outside it
+    assert set(chip_smoke.leaf_sized_moves(hlo, pool_elems)) <= ({"while"} if which == "decode" else {"dynamic-update-slice", "fusion", "while"})
+    if which == "decode":
+        assert "dynamic-update-slice(" not in hlo and all("while/body" not in line for line in hlo.split("\n") if " sort(" in line)
     assert chip_smoke.leaf_sized_moves(hlo, pool_elems // L) == []                                 # nothing a layer's size
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 2 * pool_elems * 2

@@ -966,6 +966,169 @@ def test_slice_writes_split_a_chunk_at_the_page_edges_wherever_it_starts(start, 
 
 
 # ---------------------------------------------------------------------------
+# a decode step's rows through one aliased Mosaic call a leaf (ISSUE 49):
+# ``paged_kv_write`` in interpret mode against the scatter, and the rule
+# on the pool's shape that confines it
+# ---------------------------------------------------------------------------
+
+# name -> (layers, pages, H, page_len, d, kv dtype): the GPT-2 serve cells' page, a second narrow one, the int8 pair
+WRITE_POOLS = {
+    "bf16_25x128x64": (2, 11, 25, 128, 64, jnp.bfloat16),
+    "f32_3x256x32": (3, 11, 3, 256, 32, jnp.float32),
+    "int8_pair_4x128x64": (2, 11, 4, 128, 64, "int8"),
+}
+# name -> (pos of five rows over slots of two pages, write_mask or None); ``E`` is the slot's last position
+WRITE_ROWS = {
+    "offsets_0_and_last_and_the_slots_end": (("0", "L", "E", "7", "P"), None),
+    "masked_rows_between_live_ones": (("3", "0", "L", "E", "9"), (True, False, False, True, False)),
+    "one_row_writes": (("5", "6", "E", "8", "9"), (False, False, True, False, False)),
+    "all_rows_masked": (("1", "L", "E", "4", "5"), (False,) * 5),
+}
+
+
+def _write_case(pool_name, rows_name):
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    L, NP, H, page_len, d, dt = WRITE_POOLS[pool_name]
+    at, mask = WRITE_ROWS[rows_name]
+    rng = np.random.default_rng(len(pool_name) * 100 + len(rows_name))
+    if dt == "int8":
+        pool = {"q": jnp.asarray(rng.integers(-127, 128, (L, NP, H, page_len, d)), jnp.int8),
+                "s": jnp.asarray(rng.random((L, NP, H, page_len, 1)), jnp.float32)}
+    else:
+        pool = jnp.asarray(rng.standard_normal((L, NP, H, page_len, d)), dt)
+    B, P = len(at), 2
+    table = jnp.asarray(1 + rng.permutation(B * P).reshape(B, P), jnp.int32)  # page 0 is the garbage page
+    named = {"L": page_len - 1, "P": page_len, "E": P * page_len - 1}
+    pos = jnp.asarray([named.get(a) if a in named else int(a) for a in at], jnp.int32)
+    t = jnp.asarray(rng.standard_normal((B, H, 1, d)), jnp.bfloat16)
+    return inf, pool, t, table, pos, None if mask is None else jnp.asarray(mask)
+
+
+def _assert_layer_is_the_scatters(inf, got, pool, layer, t, table, pos, mask):
+    """Layer ``layer`` of ``got`` is the scatter's result on every page
+    but the garbage page, bit for bit, leaf by leaf."""
+    want = inf.paged_cache_write(jax.tree.map(lambda a: a[layer], pool), t, table, pos, mask)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g[layer, 1:]), np.asarray(w[1:]))
+
+
+@pytest.mark.parametrize("rows_name", list(WRITE_ROWS))
+@pytest.mark.parametrize("pool_name", list(WRITE_POOLS))
+def test_paged_kv_write_is_the_scatter_bit_for_bit_on_every_page_but_the_garbage_page(pool_name, rows_name):
+    """One position a row into a pool of narrow heads through
+    ``paged_kv_write`` (Pallas interpret mode), ``layer`` a Python int:
+    the layer reads what ``paged_cache_write`` leaves on every real
+    page, the other layers are untouched, and the real pages of rows
+    that do not write keep what they held."""
+    inf, pool, t, table, pos, mask = _write_case(pool_name, rows_name)
+    assert inf.decode_write_takes_kernel(pool, True) and not inf.decode_write_takes_kernel(pool, False)
+    got = inf.paged_cache_write_slices(pool, 1, t, table, pos, mask, use_kernel=True)
+    _assert_layer_is_the_scatters(inf, got, pool, 1, t, table, pos, mask)
+    for g, p in zip(jax.tree.leaves(got), jax.tree.leaves(pool)):
+        np.testing.assert_array_equal(np.asarray(g[0]), np.asarray(p[0]))
+        if mask is not None and not np.asarray(mask).any():  # nothing written: every real page as it was
+            np.testing.assert_array_equal(np.asarray(g[1, 1:]), np.asarray(p[1, 1:]))
+
+
+@pytest.mark.parametrize("rows_name", ["offsets_0_and_last_and_the_slots_end", "masked_rows_between_live_ones"])
+@pytest.mark.parametrize("pool_name", list(WRITE_POOLS))
+def test_paged_kv_write_takes_a_traced_layer_inside_a_loop_and_a_plan_built_once(pool_name, rows_name):
+    """``layer`` the counter of a ``lax.fori_loop`` and the write's plan
+    built once outside it, as a decode program's layer scan has them:
+    every layer holds its own rows (scaled by the layer, so that a write
+    into the wrong layer shows)."""
+    inf, pool, t, table, pos, mask = _write_case(pool_name, rows_name)
+    L = jax.tree.leaves(pool)[0].shape[0]
+    plan = inf.decode_write_plan(pool, table, pos, mask, use_kernel=True)
+    assert plan is not None and inf.decode_write_plan(pool, table, pos, mask, use_kernel=False) is None
+    rows = lambda l: (t.astype(jnp.float32) * (l + 1)).astype(t.dtype)  # noqa: E731
+    body = lambda l, c: inf.paged_cache_write_slices(c, l, rows(l), table, pos, mask, use_kernel=True, plan=plan)  # noqa: E731
+    got = jax.jit(lambda c: jax.lax.fori_loop(0, L, body, c))(pool)
+    for layer in range(L):
+        _assert_layer_is_the_scatters(inf, got, pool, layer, rows(layer), table, pos, mask)
+
+
+@pytest.mark.parametrize("pool_name", list(WRITE_POOLS))
+def test_paged_kv_write_under_jit_takes_the_donated_pool_and_hands_it_back(pool_name):
+    inf, pool, t, table, pos, mask = _write_case(pool_name, "masked_rows_between_live_ones")
+    kept = jax.tree.map(jnp.copy, pool)
+    write = jax.jit(lambda c: inf.paged_cache_write_slices(c, 0, t, table, pos, mask, use_kernel=True), donate_argnums=0)
+    got = write(pool)
+    assert all(a.is_deleted() for a in jax.tree.leaves(pool))  # donated, and taken
+    _assert_layer_is_the_scatters(inf, got, kept, 0, t, table, pos, mask)
+
+
+def _count_primitives(jaxpr, counts=None):
+    """Primitive name -> occurrences in a jaxpr and the jaxprs under its
+    equations, a ``pallas_call``'s own body left out."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _count_primitives(sub, counts)
+    return counts
+
+
+# name -> (pool (layers, pages, H, page_len, d), int8 pair?, T): whole-lane heads at one position and narrow ones over a chunk
+SLICED = {
+    "solar_open2_8x128x128": ((1, 9, 8, 128, 128), False, 1),
+    "zaya1_2x128x128": ((2, 9, 2, 128, 128), False, 1),
+    "keye_4x128x128": ((2, 9, 4, 128, 128), False, 1),
+    "int8_pair_of_whole_lane_heads": ((2, 9, 2, 128, 128), True, 1),
+    "narrow_heads_in_small_pages": ((2, 9, 4, 16, 8), False, 1),
+    "a_chunk_into_narrow_heads": ((2, 9, 25, 128, 64), False, 64),
+    "a_chunk_into_the_narrow_int8_pair": ((2, 9, 4, 128, 64), True, 200),
+    "a_chunk_into_whole_lane_heads": ((2, 9, 2, 128, 128), False, 130),
+}
+
+
+def _abstract_write(shape, quant, T, B=3):
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    sds = jax.ShapeDtypeStruct
+    pool = {"q": sds(shape, jnp.int8), "s": sds(shape[:-1] + (1,), jnp.float32)} if quant else sds(shape, jnp.bfloat16)
+    args = (pool, sds((B, shape[2], T, shape[4]), jnp.bfloat16), sds((B, 2), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.bool_))
+    return lambda use_kernel: jax.make_jaxpr(
+        lambda c, t, table, pos, m: inf.paged_cache_write_slices(c, 1, t, table, pos, m, use_kernel=use_kernel))(*args)
+
+
+@pytest.mark.parametrize("name", list(SLICED))
+def test_the_mosaic_write_is_confined_a_pool_of_whole_lane_heads_and_every_chunk_keep_their_slices(name, monkeypatch):
+    """Where ``d`` is a multiple of 128 (the Solar-Open2, ZAYA1 and Keye
+    pools), where pages are not whole lane rows, and for a chunk at any
+    ``d``, ``paged_cache_write_slices`` traces to the same jaxpr armed
+    and not: the ``dynamic_update_slice``s it held before ISSUE 49 — one
+    a row and leaf at one position, one a row, leaf and page a chunk can
+    touch — and no ``pallas_call``."""
+    shape, quant, T = SLICED[name]
+    B, leaves = 3, 2 if quant else 1
+    traced = _abstract_write(shape, quant, T, B)
+    monkeypatch.setenv("DS_KERNELS", "1")
+    armed, by_default_armed, not_armed = traced(True), traced(None), traced(False)
+    assert str(armed) == str(by_default_armed) == str(not_armed)
+    counts = _count_primitives(armed.jaxpr)
+    assert "pallas_call" not in counts
+    assert counts["dynamic_update_slice"] == leaves * B * (1 if T == 1 else -(-T // shape[3]) + 1)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8_pair"])
+def test_the_mosaic_write_takes_a_narrow_pools_one_position_whole(quant, monkeypatch):
+    """The narrow pool at one position, armed: one ``pallas_call`` a leaf
+    and no ``dynamic_update_slice``; the suite off (``DS_KERNELS=0``),
+    the slices as ever."""
+    traced = _abstract_write((2, 9, 25, 128, 64), quant, 1)
+    monkeypatch.setenv("DS_KERNELS", "1")
+    counts = _count_primitives(traced(None).jaxpr)
+    assert counts["pallas_call"] == (2 if quant else 1) and "dynamic_update_slice" not in counts and "scatter" not in counts
+    monkeypatch.setenv("DS_KERNELS", "0")
+    counts = _count_primitives(traced(None).jaxpr)
+    assert "pallas_call" not in counts and counts["dynamic_update_slice"] == 3 * (2 if quant else 1)
+
+
+# ---------------------------------------------------------------------------
 # the per-head pool written in place (ISSUE 40): what the old scatter,
 # gather and layer scan guaranteed, asked of the slices that replaced them
 # ---------------------------------------------------------------------------
@@ -1235,6 +1398,8 @@ def test_paged_engine_decodes_through_the_work_list_kernel_as_through_the_gather
         np.testing.assert_array_equal(a, b)
     # two pages a slot: both in one item, under both heads' one program
     assert st["paged_decode_walk"] == f"work list, {cfg.n_head} heads x 2 pages" and "paged_decode_walk" not in st_off
+    # ... and wrote its rows through ``paged_kv_write`` (heads of 16 in pages of 128), the other through slices
+    assert (st["kv_write_form"], st_off["kv_write_form"]) == ("paged_kv_write a decode step, slices a chunk, in place", "slices, in place")
     assert st["decode_pages_walked"] == st_off["decode_pages_walked"] > 0
     for s_ in (st, st_off):  # host-side sums under the tile the pool's shape gives, whichever form ran
         assert s_["decode_pages_read"] == 2 * s_["decode_grid_steps"] and s_["decode_grid_steps"] == st["decode_grid_steps"]
