@@ -564,6 +564,11 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
             moves = leaf_sized_moves(decode_hlo, max(a.size for a in jax.tree.leaves(srv.pool.k)))
             check(set(moves) <= {"while"}, f"serve[{kv}] decode: pool-sized {sorted(set(moves) - {'while'})} beside the layer loop")
         out[kv] = [done[rid].generated for rid in ids]
+        # the default order of a step: a chunk that is not its prompt's last is left unread a step, a last one waited for
+        st = srv.stats()
+        chunks = sum(-(-len(p) // s.prefill_chunk) for p in prompts)
+        check(srv.config.overlap_chunks and (st["chunks_awaited"], st["chunks_deferred"]) == (len(prompts), chunks - len(prompts)),
+              f"serve[{kv}]: {st['chunks_awaited']} chunks awaited, {st['chunks_deferred']} deferred of {chunks} in {len(prompts)} prompts")
         del srv, done
         gc.collect()
     return out

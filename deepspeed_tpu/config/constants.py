@@ -245,7 +245,7 @@ SERVING_KV_CACHE_DTYPE_DEFAULT = "model"  # model | int8
 SERVING_KV_CACHE_DTYPES = ["model", "int8"]
 SERVING_PREFILL_CHUNK_DEFAULT = 64  # prompt tokens per prefill chunk
 SERVING_PREFILL_CHUNKS_PER_STEP_DEFAULT = 1  # chunks interleaved per decode step
-SERVING_OVERLAP_CHUNKS_DEFAULT = False  # dispatch a step's programs ahead of its reads; leave a non-final chunk unread a step
+SERVING_OVERLAP_CHUNKS_DEFAULT = True  # dispatch a step's programs ahead of its reads; leave a non-final chunk unread a step
 SERVING_MAX_QUEUE_DEFAULT = 64  # waiting requests before submit() rejects
 SERVING_MAX_NEW_TOKENS_DEFAULT = 128  # per-request default generation budget
 SERVING_DEADLINE_SECONDS_DEFAULT = 0.0  # 0 = no queue-wait deadline

@@ -40,7 +40,9 @@ which ``other`` and ``wall`` derive (docs/telemetry.md).
 :meth:`StepTimeline.reset_window` — up to ``window`` of them, one row of
 floats each in a ring; what fell out of the ring is counted
 (``steps_dropped``) — and counts the window's *stalls*: steps whose
-wall is more than ``STALL_FACTOR`` times the window's median.
+wall is more than ``STALL_FACTOR`` times the window's median, or,
+where steps differ in what they wait for (``stall_among``), the median
+of the steps alike.
 """
 from __future__ import annotations
 
@@ -72,6 +74,11 @@ class StepTimeline:
     blocked on the device, so that :meth:`summary` can report the rest
     of each step (``host_ms_p50/p95``: time in which a serial engine has
     given the device nothing).
+    ``stall_among`` names a gauge by which steps are alike (serving:
+    ``reads``, the programs a step read back): a step is a stall against
+    the median wall of the steps that set it to the same value, not the
+    whole window's — a step that waits for three programs is no stall
+    for being three times one that waits for one.
     ``prefix`` (``train`` or ``serve``) names the engine in the
     profiler's trace: ``ds.<prefix>.<phase>``.  :meth:`set_gauge`
     records per-step levels (e.g. queue depth) that are averaged — not
@@ -85,7 +92,8 @@ class StepTimeline:
     (``steps_dropped``)."""
 
     def __init__(self, enabled: bool = True, window: int = 512, phases=None,
-                 sub_phases=(), blocked_on: Optional[str] = None, prefix: str = "train"):
+                 sub_phases=(), blocked_on: Optional[str] = None, prefix: str = "train",
+                 stall_among: Optional[str] = None):
         self.enabled = bool(enabled)
         self.window = max(1, int(window))
         self.phases = tuple(phases) if phases is not None else PHASES
@@ -93,6 +101,7 @@ class StepTimeline:
             self.phases = self.phases + ("other",)
         self.sub_phases = tuple(sub_phases)
         self.blocked_on = blocked_on
+        self.stall_among = stall_among
         self.prefix = str(prefix)
         # the ring: step n since reset_window() is row n % window.  "at"
         # (column 0) is the step's start, seconds since reset_window();
@@ -296,11 +305,17 @@ class StepTimeline:
         names = [c for c in self._cols if c != "at"]
         return [{c: float(held[c][k]) for c in names} for k in range(len(held["wall"]))]
 
-    @staticmethod
-    def _stalled(wall: np.ndarray):
-        """(median wall, mask of the steps that are stalls)."""
-        p50 = float(np.percentile(wall, 50)) if len(wall) else 0.0
-        return p50, (wall > STALL_FACTOR * p50) if p50 > 0 else np.zeros(len(wall), bool)
+    def _stalled(self, held: Dict[str, np.ndarray]):
+        """(each step's median wall — the window's, or that of the steps
+        alike in ``stall_among`` — and the mask of the steps that are
+        stalls)."""
+        wall = held["wall"]
+        p50 = np.zeros(len(wall))
+        alike = held.get(self.stall_among, np.zeros(len(wall)))
+        for value in np.unique(alike):
+            among = alike == value
+            p50[among] = np.percentile(wall[among], 50)
+        return p50, (wall > STALL_FACTOR * p50) & (p50 > 0)
 
     def summary(self, last_n: Optional[int] = None) -> Dict[str, float]:
         """Mean per-step milliseconds per phase over every step since
@@ -312,7 +327,8 @@ class StepTimeline:
         sub-phase, ``other`` and ``wall`` (and ``host``: wall minus the
         ``blocked_on`` phase), ``wall_ms_max`` (and the ``blocked_on``
         phase's), and the stalls: ``stall_steps`` whose wall is over
-        ``STALL_FACTOR`` medians, ``stall_ms`` their summed excess over
+        ``STALL_FACTOR`` medians (of the steps alike in ``stall_among``,
+        where that is set), ``stall_ms`` their summed excess over
         the median, ``stall_first_at_s`` seconds after
         :meth:`reset_window` (-1: none).  Scalars only; :meth:`stalls`
         names the steps."""
@@ -344,10 +360,10 @@ class StepTimeline:
         for p in ("wall", self.blocked_on):
             if p is not None:
                 out[f"{p}_ms_max"] = round(float(held[p].max()) * 1000.0, 3)
-        p50, over = self._stalled(held["wall"])
+        p50, over = self._stalled(held)
         if over.any():
             out["stall_steps"] = int(over.sum())
-            out["stall_ms"] = round(float((held["wall"][over] - p50).sum()) * 1000.0, 3)
+            out["stall_ms"] = round(float((held["wall"] - p50)[over].sum()) * 1000.0, 3)
             out["stall_first_at_s"] = round(float(held["at"][over][0]), 3)
         for g in sorted(self._gauge_names):
             out[g] = round(float(held[g].sum()) / n, 3) if g in held else 0.0
@@ -364,7 +380,7 @@ class StepTimeline:
         sub-phase's ``<name>_ms`` of that step."""
         held = self._held()
         wall = held["wall"]
-        _, over = self._stalled(wall)
+        _, over = self._stalled(held)
         idx = np.flatnonzero(over)
         idx = np.sort(idx[np.argsort(-wall[idx], kind="stable")[:STALLS_KEPT]])
         first = self.total_steps - len(wall) + 1

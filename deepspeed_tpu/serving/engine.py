@@ -12,9 +12,12 @@ Sits on top of an :class:`~deepspeed_tpu.inference.engine.InferenceEngine`
   the whole slot pool;
 * ``drain()`` — run until every request finishes, return the results.
 
-``serving.overlap_chunks`` (off by default) hands a step's programs to
-the device ahead of the host's reads, so a chunk runs while the host
-turns the step (:meth:`ServingEngine._step_programs_overlapped`).
+A step hands its programs to the device ahead of the host's reads, so
+a chunk that is not its prompt's last runs while the host turns the
+step (:meth:`ServingEngine._step_programs_overlapped`; the default since
+PR 50).  ``serving.overlap_chunks: false`` keeps the serial step — each
+program dispatched, then read back, the chunks before the decode step —
+which the equality tests compare against.
 
 Exactly **two** executables serve any churning live set: a prefill-chunk
 step (fixed ``(1, prefill_chunk)`` tokens, traced slot + position
@@ -172,6 +175,7 @@ class ServingEngine:
         # what a cache kind with per-slot state is asked about (stats()["hybrid"])
         self._state_resets = 0
         self._unread_chunks: list = []  # serving.overlap_chunks: (job, what _launch_prefill returned) of chunks not read back yet
+        self._programs_read = 0  # programs read back so far: a step that read none has measured no device time
         self._decode_rows = 0
         self._decode_steps = 0
         # how far the paged decode kernel's work list engages (stats()):
@@ -333,8 +337,15 @@ class ServingEngine:
         self.timeline = StepTimeline(
             enabled=True, window=TIMELINE_STEPS, phases=("sweep", "sched", "prefill", "decode", "commit"),
             sub_phases=("stage", "dispatch", "wait", "note"), blocked_on="wait", prefix="serve",
+            # a step that waits for the chunk before its own, the decode step and a prompt's last chunk is no stall for
+            # being three times one that waits for the decode step alone: a stall is told among the steps that read as many
+            stall_among="reads",
         )
         self._stall_logged = 0  # the last step stats() has logged as a stall
+        for name in ("chunks_awaited", "chunks_deferred"):
+            # on stats() from the first step: chunks waited for in their step (every chunk of the serial step, a
+            # prompt's last of the overlapped one) and chunks left unread a step; their sum is the chunks run
+            self.timeline.count(name, 0)
 
         # telemetry (docs/telemetry.md): attach to whatever plane the
         # process armed (the train engine's configure(), or an explicit
@@ -1055,6 +1066,7 @@ class ServingEngine:
     def _step_phases(self, admit: bool) -> bool:
         tl = self.timeline
         compiles0 = self.prefill_compiles + self.decode_compiles
+        read0 = self._programs_read
         t0 = time.monotonic()
         with tl.phase("sweep"):
             if self._paged:
@@ -1072,8 +1084,10 @@ class ServingEngine:
         with tl.phase("sched"):
             plan = self.scheduler.tick(t0, self._step_count, admit=admit)
         if self.config.overlap_chunks:
-            decoding = self._step_programs_overlapped(plan)
+            self._step_programs_overlapped(plan)
         else:
+            # the serial step, kept for the equality tests: each program
+            # read back before the next is staged, the chunks first
             with tl.phase("prefill"):
                 for job in plan.prefill_jobs:
                     self._run_prefill(job)
@@ -1086,11 +1100,16 @@ class ServingEngine:
         with tl.phase("commit"):
             tl.set_gauge("queue_depth", self.scheduler.queue_depth)
             tl.set_gauge("live_slots", self.pool.live_slots)
+            tl.set_gauge("reads", self._programs_read - read0)
             # measured service rate for the admission controller (EWMA over
-            # non-empty, non-compile steps — a jit trace in the wall would
-            # poison the TTFT estimate into shedding everything for minutes;
-            # the registry window supersedes the EWMA when armed)
-            if (plan.prefill_jobs or decoding) and (
+            # non-compile steps that read a program back — a jit trace in the
+            # wall would poison the TTFT estimate into shedding everything for
+            # minutes, and a step that left its only chunk unread has timed the
+            # host alone; the registry window supersedes the EWMA when armed).
+            # The wall ends at the step's last read: under the overlapped step
+            # it holds the chunk before the step's own, which in steady state
+            # is the same device time, so the estimate reads as the serial one
+            if self._programs_read > read0 and (
                 self.prefill_compiles + self.decode_compiles == compiles0
             ):
                 self._step_wall_ewma = (
@@ -1169,6 +1188,9 @@ class ServingEngine:
         try:
             while self.scheduler.live and wd.remaining() > 0:
                 self._step_once(admit=False)
+            # a chunk left unread by the last step taken (the deadline's cut,
+            # a cancel) is read before the sessions spill and the books close
+            self._land_unread()
         except BaseException as e:  # a dying drain must still certify honestly
             logger.error(f"serving: drain loop failed: {e!r}")
             drained_all = False
@@ -1393,41 +1415,62 @@ class ServingEngine:
             self._kv_evt_seen[key] = int(st[key])
 
     # ------------------------------------------------------------------
-    def _step_programs_overlapped(self, plan) -> list:
-        """``serving.overlap_chunks``: the step's same two programs, all
-        handed to the device before the host reads any of them back, the
-        decode step first.  A chunk that is not its prompt's last is not
-        waited for: the scheduler is told of its progress at once, its
-        token (no request's) and counters are read a step later, when it
-        has long run.  So the device runs a chunk while the host turns
-        the step — the read-back, the notes, the commit, the caller's
-        loop, the next step's staging and dispatch — and finds the next
-        decode step queued behind it.  The device runs its programs in
-        the order they were dispatched, each on the pool the one before
-        left, so what they compute is what the serial step computes;
-        what changes is *when*: the decode set is taken before the
-        step's chunks land, so a request whose last chunk lands in step
-        N (awaited: its first token is the request's) decodes from step
-        N + 1, not N.  The host runs at most one chunk ahead.  Returns
-        the rows decoded."""
+    def _step_programs_overlapped(self, plan) -> None:
+        """The step's two programs, all handed to the device before the
+        host reads any of them back, the decode step first
+        (``serving.overlap_chunks``, the default).  A chunk that is not
+        its prompt's last is not waited for: the scheduler is told of
+        its progress at once, its token (no request's) and counters are
+        read a step later, when it has long run.  So the device runs a
+        chunk while the host turns the step — the read-back, the notes,
+        the commit, the caller's loop, the next step's staging and
+        dispatch — and finds the next decode step queued behind it.  The
+        device runs its programs in the order they were dispatched, each
+        on the pool the one before left, so what they compute is what
+        the serial step computes, and whatever else touches the pool
+        (a tier's page moves, a session's spill) takes the newest
+        program's output and queues behind it; what changes is *when*:
+        the decode set is taken before the step's chunks land, so a
+        request whose last chunk lands in step N (awaited: its first
+        token is the request's, and its prompt is learned as a prefix
+        then) decodes from step N + 1, not N.  The host runs at most
+        one chunk ahead."""
         tl = self.timeline
-        with tl.phase("decode"):
-            decoding = self.scheduler.decoding()
-            launched = self._launch_decode(decoding) if decoding else None
-        with tl.phase("prefill"):
-            chunks = [(job, self._launch_prefill(job)) for job in plan.prefill_jobs]
-            self._land_unread()  # earlier steps' chunks: run by now, or running ahead of all that was launched above
-        if decoding:
+        decoding = self.scheduler.decoding()
+        launched, chunks = None, []
+        try:
+            if decoding:
+                with tl.phase("decode"):
+                    launched = self._launch_decode(decoding)
+            with tl.phase("prefill"):
+                for job in plan.prefill_jobs:
+                    chunks.append((job, self._launch_prefill(job)))
+                self._land_unread()  # earlier steps' chunks: run by now, or running ahead of all that was launched above
+        except Exception:
+            # a fault between two dispatches (an injected one, a refused
+            # launch): what was handed over runs all the same, and a
+            # cache kind with recurrent state cannot run it twice — the
+            # scheduler hears of it before the fault goes up, as it does
+            # in the serial step, which never has a program unread
+            self._land_launched(decoding, launched, chunks)
+            raise
+        self._land_launched(decoding, launched, chunks)
+
+    def _land_launched(self, decoding, launched, chunks) -> None:
+        """Read the step's decode program back, then its chunks: a
+        prompt's last is waited for, any other is noted and left."""
+        tl = self.timeline
+        if launched is not None:
             with tl.phase("decode"):
                 self._land_decode(decoding, launched)
         with tl.phase("prefill"):
-            for job, launched in chunks:
+            for job, sent in chunks:
                 if job.final:
-                    self._land_prefill(job, launched)
+                    self._land_prefill(job, sent)
                 else:
+                    tl.count("chunks_deferred")
                     self.scheduler.note_prefill(job, 0, now=time.monotonic(), step=self._step_count)
-                    self._unread_chunks.append((job, launched))
-        return decoding
+                    self._unread_chunks.append((job, sent))
 
     def _land_unread(self) -> None:
         """Read back what ``serving.overlap_chunks`` left on the device:
@@ -1479,6 +1522,9 @@ class ServingEngine:
             tok = jax.device_get(first)
         # the hand-back: from the read's return to the scheduler's note
         with tl.phase("prefill.note"):
+            self._programs_read += 1
+            if not noted:
+                tl.count("chunks_awaited")
             tok = int(tok if self._family_forward is None else self._note_aux(tok, decode=False))
             now = time.monotonic()
             if self._paged and job.final and not noted:
@@ -1542,6 +1588,7 @@ class ServingEngine:
         with tl.phase("decode.wait"):
             out = jax.device_get(nxt)
         with tl.phase("decode.note"):
+            self._programs_read += 1
             out = np.asarray(out if self._family_forward is None else self._note_aux(out, decode=True))
             now = time.monotonic()
             self.scheduler.note_decode(

@@ -32,6 +32,7 @@ chunk budget to 1 → shed queued low-priority requests.
 from __future__ import annotations
 
 import dataclasses
+import heapq
 import math
 import threading
 from collections import deque
@@ -188,9 +189,11 @@ class PrefillJob:
 @dataclasses.dataclass
 class StepPlan:
     """This tick's prefill chunks.  The decode set is NOT planned here:
-    the engine derives it from :meth:`ContinuousScheduler.decode_inputs`
-    *after* the chunks land, so a request whose final chunk completed
-    this very step decodes this step too."""
+    the engine takes it from :meth:`ContinuousScheduler.decoding` when it
+    stages the decode step — ahead of the step's chunks under the
+    default order of a step (a request decodes from the step after its
+    final chunk), after they land under the serial one (it decodes in
+    that very step)."""
 
     prefill_jobs: List[PrefillJob]
 
@@ -260,13 +263,23 @@ class AdmissionController:
     over *measured* time — ``step_seconds_fn`` returns the engine's
     recent mean serving-step wall (the telemetry registry's window when
     the plane is armed, a local EWMA otherwise); the backlog is counted
-    in steps:
+    in steps, by walking the waiters through the slots in queue order:
 
-    * prefill work ahead: every queued prompt's chunks (plus the
-      candidate's own) over the effective chunks-per-step budget;
-    * slot wait: with no free slot, the mean remaining decode budget of
-      the live set, times how many queue "generations" precede the
-      candidate (``ceil(queue_position / num_slots)``).
+    * a waiter starts in the step in which a slot is free for it: a free
+      slot now, else the soonest a request ahead of it lets one go — a
+      live request after its remaining chunks and tokens, a waiter
+      ahead after its *whole* hold (its chunks, then a decode step for
+      every token but the first, which its last chunk brings; a request
+      decodes from the step after its last chunk);
+    * its chunks queue behind every chunk ahead of it, at the effective
+      chunks-per-step budget; its first token is read in the step of
+      its last chunk;
+    * one step more on an engine with work, for the step in progress
+      when the request arrives.
+
+    That is what a request takes, to the step, under the default order
+    of a step (``tests/test_serving_resilience.py``); the serial order
+    lets a slot go a step sooner, and the estimate errs high there.
 
     It is an *estimate* feeding an SLO threshold, not a guarantee — the
     point is that shed decisions track the actually-measured service
@@ -310,24 +323,34 @@ class AdmissionController:
                 left = max(left - hint_fn(r.prompt, r.session_id), 1)
             return left
 
-        chunks = sum(math.ceil(_remaining(r) / chunk) for r in s._queue)
+        per_step = s.effective_chunks_per_step()
+        # the step each slot is next free in, and the chunks already
+        # spoken for: the live set's, in the order tick() runs them
+        free_at = [0] * s.pool.free_slots
+        chunks = 0
+        for r in sorted(s._active.values(), key=lambda r: r.request_id):
+            if r.status == PREFILL:
+                chunks += max(math.ceil((r.prompt_len - r.prefill_pos) / chunk), 1)
+                free_at.append(math.ceil(chunks / per_step) + r.max_new_tokens - 1)
+            else:
+                free_at.append(max(r.max_new_tokens - len(r.generated), 1))
+        heapq.heapify(free_at)
+        waiters = [(_remaining(r), r.max_new_tokens) for r in s._queue]
         if not in_queue:
             cand = int(prompt_len)
             if hint_fn is not None and prompt is not None and cand > 0:
                 cand = max(cand - hint_fn(prompt, session_id), 1)
-            chunks += math.ceil(cand / chunk)
-        steps = math.ceil(chunks / s.effective_chunks_per_step())
-        if not s.pool.free_slots:
-            live = [r for r in s._active.values()]
-            if live:
-                remaining = [
-                    max(r.max_new_tokens - len(r.generated), 1) for r in live
-                ]
-                mean_rem = sum(remaining) / len(remaining)
-                waiters = len(s._queue) + (0 if in_queue else 1)
-                generations = math.ceil(waiters / s.pool.num_slots)
-                steps += int(mean_rem * generations)
-        return steps * step_s
+            waiters.append((cand, 1))
+        first = 0  # steps from the next one to the one that reads the first token of the waiter walked last
+        for left, max_new in waiters:
+            start = heapq.heappop(free_at)
+            chunks = max(chunks, start * per_step) + max(math.ceil(left / chunk), 1)
+            first = math.ceil(chunks / per_step)
+            heapq.heappush(free_at, first + max_new - 1)
+        # ... and, on an engine with work, the step in progress when the
+        # request arrives: the threshold is one the admitted are to stay
+        # under, so err high
+        return (first + s.has_work()) * step_s
 
     def retry_after_seconds(self, est_s: Optional[float]) -> float:
         """How long until the backlog should have drained below the SLO

@@ -334,22 +334,57 @@ def test_served_tokens_are_the_greedy_tokens_of_a_lone_forward(served):
             assert int(jnp.argmax(logits[0])) == tok or np.sort(np.asarray(logits)[0])[-1] - np.asarray(logits)[0, tok] < 1e-4
 
 
-def test_overlap_chunks_serves_the_same_tokens_and_counts_every_chunk(served):
-    """``serving.overlap_chunks`` on the hybrid pool over latent pages:
-    the tokens, the experts' counters (every chunk's, the unread ones
-    read a step late) and the state's resets are the serial step's."""
+def _like_served(srv, **cfg):
     from deepspeed_tpu.serving import ServingEngine
 
+    return ServingEngine(srv.engine, config={"num_slots": 3, "max_len": 128, "prefill_chunk": 16,
+                                             "kvcache": {"enabled": True, "page_len": 16}, **cfg})
+
+
+def test_overlap_chunks_serves_the_same_tokens_and_counts_every_chunk(served):
+    """The default order of a step (``serving.overlap_chunks``) on the
+    hybrid pool over latent pages: the tokens, the experts' counters
+    (every chunk's, the unread ones read a step late) and the state's
+    resets are the serial step's."""
     srv, reqs, ids, done = served
-    over = ServingEngine(srv.engine, config={"num_slots": 3, "max_len": 128, "prefill_chunk": 16, "overlap_chunks": True,
-                                             "kvcache": {"enabled": True, "page_len": 16}})
-    mine = [over.submit(p, max_new_tokens=m, session_id="s1" if i == 2 else None) for i, (p, m) in enumerate(reqs)]
-    got = over.drain()
-    assert [got[i].generated for i in mine] == [done[i].generated for i in ids]
-    a, b = srv.stats(), over.stats()
+    assert srv.config.overlap_chunks is True
+    serial = _like_served(srv, overlap_chunks=False)
+    theirs = [serial.submit(p, max_new_tokens=m, session_id="s1" if i == 2 else None) for i, (p, m) in enumerate(reqs)]
+    got = serial.drain()
+    assert [got[i].generated for i in theirs] == [done[i].generated for i in ids]
+    a, b = serial.stats(), srv.stats()
     assert b["moe"]["tokens_per_expert"] == a["moe"]["tokens_per_expert"] and b["moe"]["dropped_assignments"] == 0
     assert b["hybrid"]["state_resets_in_program"] == a["hybrid"]["state_resets_in_program"] == 7
-    assert (b["prefill_compiles"], b["decode_compiles"]) == (1, 1) and not over._unread_chunks
+    assert (b["prefill_compiles"], b["decode_compiles"]) == (1, 1) and not srv._unread_chunks
+    chunks = sum(-(-len(p) // 16) for p, _ in reqs)
+    assert (a["chunks_awaited"], a["chunks_deferred"]) == (chunks, 0)
+    assert (b["chunks_awaited"], b["chunks_deferred"]) == (len(reqs), chunks - len(reqs))  # a prompt's last chunk is waited for
+
+
+@pytest.mark.parametrize("overlap", [True, False], ids=["default-order", "serial-order"])
+def test_a_fault_between_two_dispatches_runs_no_program_of_the_recurrent_state_twice(served, overlap):
+    """``faults.check("serving.prefill")`` fires after the default step
+    has handed its decode program over.  That program advanced the
+    rows' recurrent state, so it is read back and noted before the fault
+    goes up, and the engine serves on with the fault-free run's tokens
+    (the serial step has nothing in flight at that site)."""
+    from deepspeed_tpu.resilience import faults
+
+    srv, reqs, ids, done = served
+    again = _like_served(srv, overlap_chunks=overlap)
+    mine = [again.submit(p, max_new_tokens=m, session_id="s1" if i == 2 else None) for i, (p, m) in enumerate(reqs)]
+    raised = 0
+    for after in (3, 2):  # the 4th chunk's launch, then the 3rd after it: rows are decoding, prompts are mid-prefill
+        with faults.FaultInjector(seed=0).fail("serving.prefill", times=1, after=after):
+            while not raised or again.scheduler.has_work():
+                try:
+                    again.step()
+                except faults.InjectedFault:
+                    raised += 1
+                    break
+    got = again.drain(max_steps=500)
+    assert raised == 2 and not again.scheduler.has_work() and not again._unread_chunks
+    assert [got[i].generated for i in mine] == [done[i].generated for i in ids]
 
 
 def test_compiled_step_takes_the_pool_and_the_state_donated(served):

@@ -405,8 +405,8 @@ def test_shared_prefix_mix_halves_prefill_tokens_bit_identical(eng):
 
     def run(srv):
         computed, outputs = [], []
-        run_prefill = srv._run_prefill
-        srv._run_prefill = lambda job: (computed.append(job.length), run_prefill(job))[1]
+        launch_prefill = srv._launch_prefill  # every chunk handed to the device, whichever the order of the step
+        srv._launch_prefill = lambda job: (computed.append(job.length), launch_prefill(job))[1]
 
         def go(prompts, **kw):
             rids = [srv.submit(p, max_new_tokens=4, **dict(kw, **e)) for p, e in prompts]
@@ -434,6 +434,51 @@ def test_shared_prefix_mix_halves_prefill_tokens_bit_identical(eng):
     assert off_tokens - on_tokens == kv["tokens_saved"]
     assert kv["hit_rate"] >= 0.5, kv
     assert kv["session_rebinds"] >= 1, kv
+    _assert_no_leaks(srv.pool)
+
+
+@pytest.mark.parametrize("chunks_per_step", [1, 2])
+def test_prefix_hit_with_a_copy_on_write_pair_rides_a_chunk_left_unread(eng, chunks_per_step):
+    """Under the default order of a step a reader's first chunk carries
+    the copy-on-write pair of its prefix hit and is not waited for (it
+    is not the prompt's last): the page is copied on the device before
+    the chunk writes, behind whatever is in flight, the prompt is
+    learned as a prefix when its last chunk is read, and the tokens are
+    the serial step's and the solo run's."""
+    rng = np.random.default_rng(21)
+    tail = lambda n: rng.integers(1, TINY.vocab_size, n, dtype=np.int32)
+    system = tail(24)  # a page and a half: a hit on it shares the half-filled page
+    donor, readers = system, [np.concatenate([system, tail(n)]) for n in (27, 19)]
+    deeper = np.concatenate([readers[0], tail(6)])  # hits the first reader's whole prompt, learned from its awaited last chunk
+
+    def run(**kw):
+        srv = _srv(eng, num_slots=3, prefill_chunks_per_step=chunks_per_step, **kw)
+        r = srv.submit(donor, max_new_tokens=16)
+        for _ in range(-(-len(donor) // 8 // chunks_per_step)):
+            srv.step()  # the donor's prompt is learned, and it decodes on while the readers prefill
+        cow0 = srv.pool.cow_copies
+        rids = [srv.submit(p, max_new_tokens=4) for p in readers]
+        srv.step()
+        first_step = (srv.pool.cow_copies - cow0, len(srv._unread_chunks), [srv.result(i).prefill_pos for i in rids])
+        res = srv.drain(max_steps=300)
+        out = [res[i].tokens() for i in [r] + rids]
+        saved = srv.pool.tokens_saved
+        last = srv.submit(deeper, max_new_tokens=3)
+        out.append(srv.drain(max_steps=300)[last].tokens())
+        return out, first_step, srv.pool.tokens_saved - saved, srv
+
+    serial, first0, deep0, _ = run(overlap_chunks=False)
+    got, first, deep, srv = run()
+    for a, b, (p, n) in zip(got, serial, [(donor, 16), (readers[0], 4), (readers[1], 4), (deeper, 3)]):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a, _solo(eng, p, n))
+    # both readers were admitted onto the shared half page; their first chunks (from position 24, past the hit) carry the
+    # pairs, and those of this step were dispatched and left unread
+    assert first[0] == first0[0] == 2 and first[1] == chunks_per_step and first0[1] == 0
+    assert first[2] == first0[2] and min(first[2]) >= 24
+    assert deep == deep0 == 48  # 51 tokens of the first reader's prompt, floored to the chunk
+    st = srv.stats()
+    assert st["kvcache"]["cow_copies"] >= 2 and st["chunks_deferred"] > 0 and not srv._unread_chunks
     _assert_no_leaks(srv.pool)
 
 

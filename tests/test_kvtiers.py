@@ -130,6 +130,36 @@ def test_tiered_multiturn_bit_identical_vs_all_hbm(eng, tmp_path):
     srv._tiers.close()
 
 
+def test_a_tier_tick_moves_pages_in_a_step_that_finds_a_chunk_unread(eng, tmp_path):
+    """The default order of a step leaves a chunk that is not its
+    prompt's last on the device; the tiers' ``tick`` of the next step
+    gathers and scatters pages of the pool that chunk hands back, so its
+    traffic queues behind the chunk and the outputs are the all-HBM
+    engine's."""
+    ref = _turns(_tsrv(eng, tmp_path), n_sess=4)
+    srv = _tsrv(eng, tmp_path,
+                tiers={"host_pages": 8, "residency_window": 16,
+                       "demote_watermark": 0.25, "demote_batch": 8})
+    assert srv.config.overlap_chunks is True
+    tiers, tick, seen = srv._tiers, srv._tiers.tick, []
+
+    def spy(now, hints=()):
+        moved0, unread = tiers.demote_t0_t1 + tiers.promote_t1_t0 + tiers.promote_t2_t0, len(srv._unread_chunks)
+        tick(now, hints=hints)
+        seen.append((unread, tiers.demote_t0_t1 + tiers.promote_t1_t0 + tiers.promote_t2_t0 - moved0))
+
+    tiers.tick = spy
+    got = _turns(srv, n_sess=4)
+    assert sorted(got) == sorted(ref)
+    for key in ref:
+        np.testing.assert_array_equal(got[key], ref[key], err_msg=str(key))
+    assert any(unread and moved for unread, moved in seen), seen  # pages moved while a chunk was unread
+    st = srv.stats()
+    assert st["chunks_deferred"] > 0 and not srv._unread_chunks
+    assert st["kvcache"]["tiers"]["demote_t0_t1"] > 0 and srv.prefill_compiles == 1 and srv.decode_compiles == 1
+    srv._tiers.close()
+
+
 def test_sessions_at_4x_device_kv_capacity_no_queue_full(eng, tmp_path):
     """The capacity gate: eight 3-turn sessions whose parked KV is four
     times the device pool's usable pages are all served — no submit

@@ -196,6 +196,23 @@ class TestTheWholeWindow:
         tl.reset_window()
         assert tl.summary()["stall_steps"] == 0 and tl.stalls() == []
 
+    @pytest.mark.parametrize("among", [None, "reads"])
+    def test_a_step_is_a_stall_among_the_steps_that_read_as_many_programs(self, among):
+        """The default order of a serving step: one step in six waits for the chunk before its own, the decode step and
+        a prompt's last chunk (3.5 window medians), one in three for two programs — no stall among them but the one planted."""
+        tl = StepTimeline(stall_among=among, **ENGINE_PHASES)
+        for k in range(480):
+            reads = 3 if k % 6 == 0 else 2 if k % 3 == 0 else 1
+            tl.set_gauge("reads", reads)
+            _engine_step(tl, wait=0.500 if k == 300 else {1: 0.030, 2: 0.060, 3: 0.110}[reads])
+        s = tl.summary()
+        assert s["wall_ms_p50"] == pytest.approx(32.1, abs=1e-2) and s["reads"] == pytest.approx(1.5, abs=1e-2)
+        if among is None:  # the window's median alone: every step of three programs reads as a stall
+            assert s["stall_steps"] == 80
+            return
+        assert s["stall_steps"] == 1 and s["stall_ms"] == pytest.approx(502.1 - 112.1, abs=1e-2)
+        assert [x["step"] for x in tl.stalls()] == [301]
+
     def test_stalls_names_the_longest_sixteen_in_step_order(self):
         tl = StepTimeline(**ENGINE_PHASES)
         for k in range(400):
@@ -304,8 +321,13 @@ def _train_engine():
 
 
 class TestSpansInTheProfilersTrace:
-    def test_serving_vocabulary_nested_in_the_step_with_its_number(self, serving, tmp_path):
+    @pytest.mark.parametrize("serial", [False, True], ids=["default-order", "serial-order"])
+    def test_serving_vocabulary_nested_in_the_step_with_its_number(self, serving, tmp_path, serial):
         srv = serving
+        if serial:
+            srv = ServingEngine(serving.engine, config=dataclasses.replace(serving.config, overlap_chunks=False))
+            srv.submit(np.arange(1, 12, dtype=np.int32), max_new_tokens=3)  # learns the prompt as a prefix, outside the trace
+            srv.drain()
         before = srv._step_count
         with _Trace(tmp_path) as tr:
             rid = srv.submit(np.arange(1, 12, dtype=np.int32), max_new_tokens=3)
@@ -317,15 +339,23 @@ class TestSpansInTheProfilersTrace:
         assert all(s[3] is None for s in tr.spans if s[0] != "ds.serve.step")
         # every other span lies inside one step's span ...
         assert sum(len(tr.inside(s)) for s in steps) == len(tr.spans) - len(steps)
-        # ... and a program's four sub-phases inside its phase, in order (prefill: a chunk after a chunk)
+        # ... and a program's four sub-phases inside a phase of its own, in order
         for which in ("prefill", "decode"):
-            for outer in (s for s in tr.spans if s[0] == f"ds.serve.{which}"):
-                inner = [s[0] for s in tr.inside(outer)]
-                one = [f"ds.serve.{which}.{p}" for p in ("stage", "dispatch", "wait", "note")]
-                assert inner == one * (len(inner) // 4)
+            one = [f"ds.serve.{which}.{p}" for p in ("stage", "dispatch", "wait", "note")]
+            inner = [tr.inside(outer) for outer in tr.spans if outer[0] == f"ds.serve.{which}"]
+            assert sum(len(i) for i in inner) == sum(1 for s in tr.spans if s[0] in one)
+            if serial:  # each program read back before the next is staged (prefill: a chunk after a chunk)
+                assert all([s[0] for s in i] == one * (len(i) // 4) for i in inner)
+            # the n-th program staged is the n-th dispatched, waited for and noted, each after the other: whichever the
+            # order, and though the default one reads a chunk that is not its prompt's last a step late
+            runs = [[s for s in tr.spans if s[0] == name] for name in one]
+            assert len({len(r) for r in runs}) == 1 and len(runs[0]) >= 1
+            assert all(a[2] <= b[1] for earlier, later in zip(runs, runs[1:]) for a, b in zip(earlier, later))
         for step in steps:
             inside = tr.inside(step)
-            assert [s[0] for s in inside][:3] == ["ds.serve.sweep", "ds.serve.sched", "ds.serve.prefill"]
+            assert [s[0] for s in inside][:2] == ["ds.serve.sweep", "ds.serve.sched"]
+            # the serial step runs its chunks first; the default one hands the decode step over first where a row decodes
+            assert inside[2][0] == "ds.serve.prefill" if serial else inside[2][0] in ("ds.serve.decode", "ds.serve.prefill")
             assert inside[-1][0] == "ds.serve.commit"
             # the leaves follow one another and none overlaps the next: every instant under at most one
             leaves = [s for s in inside if s[0] in SERVE_LEAVES]

@@ -116,15 +116,18 @@ def test_chunked_prefill_parity():
     np.testing.assert_array_equal(res[r_long].tokens(), _solo(eng, long_, 4))
 
 
-@pytest.mark.parametrize("pool", [{}, {"kvcache": {"enabled": True, "page_len": 8}},
-                                  {"prefill_chunks_per_step": 2, "kvcache": {"enabled": True, "page_len": 8}}],
-                         ids=["slots", "paged", "paged-two-chunks-a-step"])
+POOLS = pytest.mark.parametrize("pool", [{}, {"kvcache": {"enabled": True, "page_len": 8}},
+                                         {"prefill_chunks_per_step": 2, "kvcache": {"enabled": True, "page_len": 8}}],
+                                 ids=["slots", "paged", "paged-two-chunks-a-step"])
+
+
+@POOLS
 def test_overlap_chunks_serves_the_serial_steps_tokens(pool):
-    """``serving.overlap_chunks`` hands the same two programs to the
-    device in the same order on the same pool, ahead of the host's
-    reads: every request's tokens are the serial step's, a chunk that is
-    not its prompt's last is left unread for one step, and a request
-    decodes from the step after its last chunk."""
+    """The default order of a step (``serving.overlap_chunks``) hands the
+    same two programs to the device in the same order on the same pool,
+    ahead of the host's reads: every request's tokens are the serial
+    step's, a chunk that is not its prompt's last is left unread for one
+    step, and a request decodes from the step after its last chunk."""
     eng = _engine()
     rng = np.random.default_rng(5)
     reqs = [(rng.integers(1, TINY.vocab_size, n, dtype=np.int32), m) for n, m in ((20, 6), (37, 9), (5, 4), (50, 7), (16, 5), (33, 8), (3, 3))]
@@ -139,14 +142,76 @@ def test_overlap_chunks_serves_the_serial_steps_tokens(pool):
         res = srv.drain(max_steps=500)
         return [list(res[r].generated) for r in rids], unread_after_first_step, srv
 
-    serial, none_unread, _ = serve()
-    overlapped, unread, srv = serve(overlap_chunks=True)
-    assert overlapped == serial and none_unread == 0
+    serial, none_unread, srv0 = serve(overlap_chunks=False)
+    overlapped, unread, srv = serve()
+    assert srv.config.overlap_chunks is True and overlapped == serial and none_unread == 0
     assert unread == pool.get("prefill_chunks_per_step", 1)  # the 20-token prompt's first chunks: progress noted, token unread
     assert srv.scheduler.has_work() is False and srv.stats()["finished"] == 7 and not srv._unread_chunks
     assert (srv.prefill_compiles, srv.decode_compiles) == (1, 1)
     chunks = sum(-(-len(p) // 8) for p, _ in reqs)
     assert srv.timeline.summary()["programs"] == chunks + srv._decode_steps  # every chunk ran once, read late or not
+    # a prompt's last chunk is waited for, every other left unread a step; the serial step waits for them all
+    st, st0 = srv.stats(), srv0.stats()
+    assert (st["chunks_awaited"], st["chunks_deferred"]) == (len(reqs), chunks - len(reqs))
+    assert (st0["chunks_awaited"], st0["chunks_deferred"]) == (chunks, 0)
+    assert ServingEngine(eng, num_slots=1, max_len=64, prefill_chunk=8).stats()["chunks_deferred"] == 0  # there from the start
+
+
+@POOLS
+def test_cancel_expiry_and_slot_reuse_with_a_chunk_unread(pool):
+    """The request whose chunk the default step left unread is cancelled:
+    its slot is free at once and the next occupant's chunks are
+    dispatched behind the one in flight, a queued request past its
+    deadline expires in that same step, and every other request's tokens
+    are its solo run's.  (Queue-wait deadlines expire queued requests
+    only, and this scheduler preempts no admitted request: a cancel is
+    the one way a request with a chunk in flight leaves early.)"""
+    eng = _engine()
+    rng = np.random.default_rng(11)
+    p_dec, p_long, p_next, p_late = (rng.integers(1, TINY.vocab_size, n, dtype=np.int32) for n in (6, 44, 21, 9))
+    srv = ServingEngine(eng, config={"num_slots": 2, "max_len": 64, "prefill_chunk": 8, **pool})
+    r_dec = srv.submit(p_dec, max_new_tokens=12)
+    srv.step()  # r_dec's only chunk: it decodes from the next step
+    r_long = srv.submit(p_long, max_new_tokens=4)
+    srv.step()
+    srv.step()
+    assert srv._unread_chunks and all(job.req.request_id == r_long for job, _ in srv._unread_chunks)
+    r_next = srv.submit(p_next, max_new_tokens=5)  # queued: both slots are taken
+    r_late = srv.submit(p_late, max_new_tokens=3, deadline_seconds=1e-9)
+    assert srv.cancel(r_long) is True and srv.cancel(r_long) is False
+    assert srv.pool.free_slots == 1 and srv._unread_chunks  # the chunk is still the device's
+    srv.step()  # reads it back, expires r_late, admits r_next into the freed slot
+    assert not any(job.req.request_id == r_long for job, _ in srv._unread_chunks)
+    assert srv.result(r_late).status == "expired" and srv.result(r_next).slot is not None
+    res = srv.drain(max_steps=300)
+    assert res[r_long].status == "cancelled" and res[r_long].generated == []
+    np.testing.assert_array_equal(res[r_dec].tokens(), _solo(eng, p_dec, 12))
+    np.testing.assert_array_equal(res[r_next].tokens(), _solo(eng, p_next, 5))
+    st = srv.stats()
+    assert not srv._unread_chunks and (st["cancelled"], st["expired"], st["finished"]) == (1, 1, 2)
+    # r_dec's one chunk, r_long's of two steps before the cancel, r_next's three: the two prompts' last were waited for
+    launched = 1 + 2 * pool.get("prefill_chunks_per_step", 1) + 3
+    assert st["chunks_awaited"] == 2 and st["chunks_awaited"] + st["chunks_deferred"] == st["programs"] - srv._decode_steps == launched
+    assert srv.pool.free_slots == 2
+
+
+def test_a_step_that_read_nothing_back_leaves_the_service_rate_alone():
+    """The admission controller's measured step (``_step_wall_ewma``)
+    averages the steps that waited for a program: under the default
+    order the step whose only program is a chunk left unread has timed
+    the host alone, and the next one, which reads that chunk, counts."""
+    eng = _engine()
+    for overlap in (True, False):
+        srv = ServingEngine(eng, num_slots=1, max_len=64, prefill_chunk=8, overlap_chunks=overlap)
+        srv.submit(_prompts(1, 12, 12, seed=3)[0], max_new_tokens=3)
+        srv.drain(max_steps=50)  # both programs compiled, a first reading taken
+        seeded = srv._step_wall_ewma
+        assert seeded is not None and seeded == srv.scheduler.step_seconds_fn()
+        srv.submit(_prompts(1, 30, 30, seed=4)[0], max_new_tokens=2)
+        srv.step()  # the default order: dispatched and noted, not waited for; the serial step waits for every chunk
+        assert len(srv._unread_chunks) == overlap and (srv._step_wall_ewma == seeded) is overlap
+        srv.step()
+        assert srv._step_wall_ewma != seeded  # this one read the chunk before its own
 
 
 def test_eos_retires_at_token_granularity():

@@ -198,6 +198,43 @@ def test_kill_mid_decode_restart_replays_bit_identical(eng, tmp_path):
     assert srv2.recover() == []
 
 
+@pytest.mark.parametrize("site, after", [("serving.prefill", 3), ("serving.decode", 2)], ids=["at-a-chunks-launch", "at-a-decode-launch"])
+def test_kill_with_a_chunk_in_flight_acknowledges_what_the_serial_step_does_and_replays(eng, tmp_path, site, after):
+    """A death while the default step has a chunk on the device unread:
+    the journal holds what the serial step's holds at the same launch —
+    the retirements committed at their step's boundary, the requests in
+    flight as incomplete — and a fresh engine replays those with the
+    uninterrupted run's tokens."""
+    prompts = [_prompts(1, n, n, seed=30 + n)[0] for n in (6, 30, 12)]  # one chunk, four, two; two slots
+    budgets = [2, 4, 3]
+
+    def killed(path, **kw):
+        srv = _srv(eng, tmp_path=path, **kw)
+        rids = [srv.submit(p, max_new_tokens=n) for p, n in zip(prompts, budgets)]
+        with pytest.raises(faults.InjectedKill):
+            with faults.FaultInjector(seed=0).kill(site, after=after):
+                srv.drain(max_steps=500)
+        return srv, rids
+
+    ref = _srv(eng)
+    ref_ids = [ref.submit(p, max_new_tokens=n) for p, n in zip(prompts, budgets)]
+    expect = [r.tokens() for r in map(ref.drain(max_steps=500).get, ref_ids)]
+
+    serial, ids0 = killed(tmp_path / "serial", overlap_chunks=False)
+    srv1, ids1 = killed(tmp_path / "default")
+    assert len(srv1._unread_chunks) == 1 and not serial._unread_chunks  # the chunk before the one being launched
+    acked = lambda srv, ids: [ids.index(i) for i in sorted(srv.scheduler._finished)]
+    assert acked(srv1, ids1) == acked(serial, ids0) == [0]  # the short request retired, and was committed, steps before
+    undone = lambda path, ids: [ids.index(e["id"]) for e in journal_mod.incomplete_requests(str(path / "journal"))]
+    assert undone(tmp_path / "default", ids1) == undone(tmp_path / "serial", ids0) == [1, 2]
+
+    srv2 = _srv(eng, tmp_path=tmp_path / "default")
+    assert srv2.recover() == ids1[1:]
+    res = srv2.drain(max_steps=500)
+    for rid, exp in zip(ids1[1:], expect[1:]):
+        np.testing.assert_array_equal(res[rid].tokens(), exp)
+
+
 def test_recover_without_journal_or_empty_is_noop(eng, tmp_path):
     assert _srv(eng).recover() == []
     srv = _srv(eng, tmp_path=tmp_path)
@@ -263,6 +300,7 @@ def test_sigterm_mid_prefill_drains_and_exits_43(eng, tmp_path):
     srv.install_watchdog(drain_deadline_seconds=60.0)
     try:
         srv.step()  # first chunk lands; prefill is mid-flight
+        assert len(srv._unread_chunks) == 1  # noted, not read back: the default order of a step
         os.kill(os.getpid(), signal.SIGTERM)
         with pytest.raises(ServingDraining) as exc:
             srv.submit(_prompts(1, 4, 4, seed=5)[0], max_new_tokens=2)
@@ -273,7 +311,8 @@ def test_sigterm_mid_prefill_drains_and_exits_43(eng, tmp_path):
     finally:
         srv._watchdog.uninstall()
     # the in-flight request drained; the queued one is durable undone work
-    assert srv.result(r_flight).finish_reason == "length"
+    assert srv.result(r_flight).finish_reason == "length" and not srv._unread_chunks
+    np.testing.assert_array_equal(srv.result(r_flight).tokens(), np.asarray(eng.generate(long_prompt[None, :], max_new_tokens=3))[0])
     inc = journal_mod.incomplete_requests(str(tmp_path / "journal"))
     assert [e["id"] for e in inc] == [r_queued]
     recs = journal_mod.read_records(str(tmp_path / "journal"))
@@ -286,6 +325,33 @@ def test_sigterm_mid_prefill_drains_and_exits_43(eng, tmp_path):
     assert res[r_queued].finish_reason == "length"
 
 
+def test_sigterm_past_the_drain_deadline_reads_the_unread_chunk_before_it_exits(eng, tmp_path):
+    """No drain budget: the loop takes no step, the chunk the last step
+    left on the device is read back before the books close, the request
+    it belonged to is durable undone work, and the restarted engine
+    serves it with the uninterrupted run's tokens."""
+    srv = _srv(eng, tmp_path=tmp_path, num_slots=1)
+    long_prompt = _prompts(1, 24, 24, seed=13)[0]  # 3 chunks of 8
+    r_flight = srv.submit(long_prompt, max_new_tokens=3)
+    srv.install_watchdog(drain_deadline_seconds=0.0)
+    try:
+        srv.step()
+        assert len(srv._unread_chunks) == 1
+        os.kill(os.getpid(), signal.SIGTERM)
+        with pytest.raises(SystemExit) as e:
+            srv.step()
+        assert e.value.code == 43  # the journal committed the undone set
+    finally:
+        srv._watchdog.uninstall()
+    assert not srv._unread_chunks and srv.stats()["chunks_deferred"] == 1
+    drains = [r for r in journal_mod.read_records(str(tmp_path / "journal")) if r["t"] == "drain"]
+    assert drains and drains[-1]["undone"] == [r_flight]
+    srv2 = _srv(eng, tmp_path=tmp_path, num_slots=1)
+    assert srv2.recover() == [r_flight]
+    res = srv2.drain(max_steps=300)
+    np.testing.assert_array_equal(res[r_flight].tokens(), np.asarray(eng.generate(long_prompt[None, :], max_new_tokens=3))[0])
+
+
 def test_sigterm_journal_commit_failure_exits_1(eng, tmp_path):
     """Exit 43 must CERTIFY the commit: an injected commit failure at
     drain time quarantines the journal and exits 1 (crash contract)."""
@@ -294,6 +360,7 @@ def test_sigterm_journal_commit_failure_exits_1(eng, tmp_path):
     srv.install_watchdog(drain_deadline_seconds=60.0)
     try:
         srv.step()
+        srv.step()  # served out under either order of a step: nothing is left for the drain loop to retire
         os.kill(os.getpid(), signal.SIGTERM)
         # the drain-record commit is the LAST commit; fail exactly there
         inj = faults.FaultInjector(seed=0).fail("serving.journal.commit", times=99)
@@ -337,21 +404,62 @@ def test_sigterm_without_journal_full_drain_is_43_undone_is_1(eng):
 # chaos: overload -> shed with retry_after + degradation ladder
 # ---------------------------------------------------------------------------
 
-def test_overload_sheds_with_retry_after_and_keeps_admitted_ttft(eng):
+ORDERS = pytest.mark.parametrize("overlap", [True, False], ids=["default-order", "serial-order"])
+
+
+@ORDERS
+@pytest.mark.parametrize("chunks_per_step", [1, 2])
+def test_ttft_estimate_walks_the_waiters_through_the_slots(eng, overlap, chunks_per_step):
+    """The admission estimate in steps (the measured step pinned to one
+    second) against the step that read each request's first token, at
+    arrivals that find slots free, mid-prefill, decoding and waited
+    for: what it took under the default order of a step and, on an
+    engine with work, one step more — the step in progress when a
+    request arrives, which a submit between two steps does not wait
+    for — and no fewer under the serial one, which lets a slot go a
+    step sooner."""
+    srv = _srv(eng, max_queue=64, prefill_chunks_per_step=chunks_per_step, overlap_chunks=overlap)
+    srv.scheduler.step_seconds_fn = lambda: 1.0
+    rng = np.random.default_rng(5)
+    prompts = _prompts(24, 3, 30, seed=15)
+    est, ids = {}, []
+    for i, p in enumerate(prompts):
+        priced = srv.scheduler.admission.estimate_ttft_seconds(len(p), prompt=p) - srv.scheduler.has_work()
+        ids.append(srv.submit(p, max_new_tokens=int(rng.integers(1, 10))))
+        est[ids[-1]] = (priced, srv._step_count)
+        for _ in range(int(rng.integers(0, 3))):  # bursts and lulls
+            srv.step()
+    res = srv.drain(max_steps=2000)
+    took = {r: res[r].first_token_step - est[r][1] for r in ids}
+    over = [est[r][0] - took[r] for r in ids]
+    assert min(took.values()) < 5 < 20 < max(took.values())  # a slot free at arrival, and a long queue
+    if overlap:
+        assert not any(over), (over, took)
+    else:
+        assert min(over) == 0 and all(0 <= o <= t // 2 for o, t in zip(over, took.values())), (over, took)
+
+
+@ORDERS
+def test_overload_sheds_with_retry_after_and_keeps_admitted_ttft(eng, overlap):
     """Offered load far past capacity (every step costs an injected
-    20ms, submits arrive back-to-back — well beyond 4x the measured
-    service rate): the estimated-TTFT shedder rejects with a positive
-    ``retry_after`` and the ADMITTED requests' p99 TTFT stays within
-    the configured SLO."""
-    slo_ms = 400.0
-    srv = _srv(eng, slo_ttft_ms=slo_ms, max_queue=256)
+    80ms, submits arrive back-to-back — twice what two slots of
+    4-token requests serve): the estimated-TTFT shedder rejects with a
+    positive ``retry_after`` and the ADMITTED requests' p99 TTFT stays
+    within the configured SLO, under both orders of a step.  The
+    estimate is one step over what a request takes under the default
+    order; the injected cost is most of a step, so that this host's own
+    time, 3 ms a step alone and several times that under the suite's
+    workers, is little of it, and the SLO is six and a half such steps,
+    of which the estimate admits five."""
+    slo_ms = 540.0
+    srv = _srv(eng, slo_ttft_ms=slo_ms, max_queue=256, overlap_chunks=overlap)
     prompts = _prompts(40, 6, 8, seed=10)
-    inj = faults.FaultInjector(seed=0).latency("serving.decode", seconds=0.02)
+    inj = faults.FaultInjector(seed=0).latency("serving.decode", seconds=0.08)
     with inj:
         # warm: the EWMA must see the slow decode before the blast
         # (HIGH priority: an armed process-wide telemetry plane may hold
         # stale step walls from other engines, and warm-up must admit)
-        srv.submit(prompts[0], max_new_tokens=3, priority=PRIORITY_HIGH)
+        srv.submit(prompts[0], max_new_tokens=12, priority=PRIORITY_HIGH)
         srv.drain(max_steps=50)
         admitted, sheds = [], []
         for p in prompts[1:]:
