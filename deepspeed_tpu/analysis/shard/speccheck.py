@@ -268,7 +268,7 @@ def _eqn_source_line(eqn) -> Tuple[Optional[str], int]:
     try:
         from jax._src import source_info_util as siu
 
-        frame = siu.user_frame(eqn.source_info)
+        frame = siu.user_frame(eqn.source_info.traceback)  # the installed JAX's signature: a traceback, not the SourceInfo
         if frame is not None:
             return frame.file_name, int(frame.start_line)
     except (ImportError, AttributeError, TypeError):

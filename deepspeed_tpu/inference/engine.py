@@ -192,22 +192,21 @@ class InferenceEngine:
 
         # the family seam (docs/serving.md §Model families): the config's
         # class names its model module, which supplies the parameter
-        # tree, the partition rules and — for a family with a cache kind
-        # of its own — the serving forward.  Duck-typed configs outside
-        # the built-in MROs keep falling back to BERT's encoder layout.
+        # tree, names its partition-rule table and — a causal family —
+        # supplies its cache kind and its serving forward.  Duck-typed
+        # configs outside the built-in MROs keep falling back to BERT's
+        # encoder layout and rule table, here and nowhere else.
         from deepspeed_tpu.models import family_of
 
-        self._family = family_of(self.model_config) or bert_mod
+        family = family_of(self.model_config)
+        self._family = family or bert_mod
         self._is_gpt = self._family is gpt2_mod  # the GPT-2 parameter layout (generate(), the slot cache)
         self._causal = bool(getattr(self._family, "CAUSAL_LM", False))
         # partition-rule engine: the family table every param layout
         # resolves through (sharding/rules.py; packed-int8 aware)
         from deepspeed_tpu.sharding.rules import rules_for_config, rules_for_family
 
-        try:
-            self._rules = rules_for_config(self.model_config)
-        except ValueError:
-            self._rules = rules_for_family("gpt2" if self._is_gpt else "bert")
+        self._rules = rules_for_config(self.model_config) if family is not None else rules_for_family("bert")
         # disable remat for inference (no backward to save memory for)
         if getattr(self.model_config, "remat", False):
             self.model_config = dataclasses.replace(self.model_config, remat=False)
@@ -479,17 +478,7 @@ class InferenceEngine:
         """The fused-block config for a cache of capacity ``max_len``."""
         from deepspeed_tpu.ops.transformer.inference import DeepSpeedInferenceConfig
 
-        cfg = self.model_config
-        return DeepSpeedInferenceConfig(
-            hidden_size=cfg.n_embd,
-            heads=cfg.n_head,
-            layer_norm_eps=cfg.layer_norm_epsilon,
-            mp_size=self.mp_world_size,
-            dtype=self.dtype,
-            max_out_tokens=int(max_len),
-            use_flash_attention=cfg.use_flash_attention,
-            moe_top_k=getattr(cfg, "moe_top_k", 2),
-        )
+        return DeepSpeedInferenceConfig.for_model(self.model_config, self.dtype, self.mp_world_size, max_len)
 
     def init_cache(self, batch: int, max_len: int):
         """Externally-owned KV cache ``(layers, batch, heads, max_len,

@@ -1,9 +1,12 @@
 """Model families.  A family is a module of this package: it owns its
-config class, its parameter tree (``init_params`` /
-``init_params_device``) and its partition rules, says whether it is a
-causal LM (``CAUSAL_LM``), and — when its cache is not the per-head
-K/V pair — supplies ``cache_kind`` and ``serving_forward`` for the
-serving engine (docs/serving.md §Model families)."""
+config class and its parameter tree (``init_params`` /
+``init_params_device``), names its partition-rule table
+(``PARTITION_RULES``, a table of ``sharding/rules.py``), says whether it
+is a causal LM (``CAUSAL_LM``), and — a causal one — supplies
+``cache_kind`` and ``serving_forward`` for the serving engine
+(docs/serving.md §Model families).  ``_CONFIG_FAMILIES`` below is the one
+table from a config class to its family: the engines and the rule
+engine resolve through :func:`family_of`."""
 from __future__ import annotations
 
 import importlib

@@ -23,6 +23,7 @@ from deepspeed_tpu.models.gpt2 import _dropout, _layer_norm
 
 
 CAUSAL_LM = False  # models/__init__.py: what the engines ask of a family
+PARTITION_RULES = "bert"  # the family's table in sharding/rules.py
 
 
 @dataclasses.dataclass(frozen=True)

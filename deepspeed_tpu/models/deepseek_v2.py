@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 CAUSAL_LM = True
+PARTITION_RULES = "deepseek_v2"  # the family's table in sharding/rules.py
 
 
 @dataclasses.dataclass(frozen=True)

@@ -61,6 +61,9 @@ import numpy as np
 from deepspeed_tpu.models.deepseek_v2 import apply_rope, rms_norm, rope_cos_sin, seeded_tree
 
 CAUSAL_LM = True
+# DeepSeek-V2's deployment and its leaves' names: ``experts_gu`` / ``experts_down`` (held, ...) over ``expert``,
+# ``embed`` and ``head`` (vocabulary, hidden) over the vocabulary, both mixers, shared experts, router and norms replicated
+PARTITION_RULES = "deepseek_v2"
 
 
 @dataclasses.dataclass(frozen=True)

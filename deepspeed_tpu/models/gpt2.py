@@ -33,9 +33,13 @@ from deepspeed_tpu.ops.attention.flash_attention import flash_attention, mha_ref
 # single shared implementation (ops/normalize.py); aliased because
 # models/bert.py imports these names from here
 from deepspeed_tpu.ops.normalize import dropout as _dropout, layer_norm as _layer_norm, token_nll
+# the family seam of ``ServingEngine`` (docs/serving.md §Model families): the fused inference blocks' step on the
+# paged pool, and on the slot-contiguous one (``serving.kvcache.enabled: false``); ``cache_kind`` is below
+from deepspeed_tpu.ops.transformer.inference import serving_forward, slot_serving_forward  # noqa: F401
 
 
 CAUSAL_LM = True  # models/__init__.py: what the engines ask of a family
+PARTITION_RULES = "gpt2"  # the family's table in sharding/rules.py
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,6 +231,14 @@ def tp_spec_fn(path: str, shape) -> Optional[P]:
     from deepspeed_tpu.sharding.rules import rules_for_family
 
     return rules_for_family("gpt2").spec(path, shape)
+
+
+def cache_kind(cfg: GPT2Config, dtype):
+    """The family's cache kind for the serving pools: K and V a head, a
+    jnp dtype or ``"int8"`` (the code+scale pair)."""
+    from deepspeed_tpu.serving.kvcache.pages import PerHeadKV
+
+    return PerHeadKV(cfg.n_head, cfg.head_dim, dtype)
 
 
 # per-(config-values, seq) layout cache: layouts are static numpy, built once

@@ -34,6 +34,7 @@ import numpy as np
 from deepspeed_tpu.models.deepseek_v2 import rms_norm, seeded_tree
 
 CAUSAL_LM = True
+PARTITION_RULES = "keye"  # the family's table in sharding/rules.py: its head is (hidden, vocabulary)
 
 
 @dataclasses.dataclass(frozen=True)

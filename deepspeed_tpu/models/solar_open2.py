@@ -47,6 +47,9 @@ import numpy as np
 from deepspeed_tpu.models.deepseek_v2 import _swiglu, rms_norm, seeded_tree
 
 CAUSAL_LM = True
+# one deployment, one table: held experts over ``expert``, embedding and head over the vocabulary, every kind of
+# attention, shared experts, router and norms replicated
+PARTITION_RULES = "deepseek_v2"
 
 
 @dataclasses.dataclass(frozen=True)

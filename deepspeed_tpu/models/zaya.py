@@ -35,6 +35,7 @@ import numpy as np
 from deepspeed_tpu.models.deepseek_v2 import rms_norm, seeded_tree
 
 CAUSAL_LM = True
+PARTITION_RULES = "zaya"  # the family's table in sharding/rules.py
 
 
 @dataclasses.dataclass(frozen=True)

@@ -65,6 +65,12 @@ class DeepSpeedInferenceConfig:
     def head_dim(self) -> int:
         return self.hidden_size // self.heads
 
+    @classmethod
+    def for_model(cls, mcfg, dtype, mp_size: int, max_len: int) -> "DeepSpeedInferenceConfig":
+        """The fused-block config of a GPT-2-layout model config for a cache of capacity ``max_len``."""
+        return cls(hidden_size=mcfg.n_embd, heads=mcfg.n_head, layer_norm_eps=mcfg.layer_norm_epsilon, mp_size=mp_size, dtype=dtype,
+                   max_out_tokens=int(max_len), use_flash_attention=mcfg.use_flash_attention, moe_top_k=getattr(mcfg, "moe_top_k", 2))
+
 
 def _wmm(h: jnp.ndarray, w) -> jnp.ndarray:
     """Weight matmul that understands int8-packed weights
@@ -813,6 +819,83 @@ def forward_with_cache(
     x = _ln(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
     logits = x @ params["wte"].T.astype(x.dtype)
     return logits.astype(jnp.float32), new_k, new_v
+
+
+# the serving engine's seam (docs/serving.md §Model families), reached through models/gpt2.py: this layout's step on the
+# paged per-head pool, and on the slot-contiguous one
+
+def _logit_rows(logits, take):
+    """Row ``take[b]`` of each row's ``(T, V)`` logits — the last where
+    ``take`` is None (a decode step) — as slices: ``(B, V)``."""
+    if take is None:
+        return logits[:, -1]
+    return jnp.stack([logits[b, take[b]] for b in range(logits.shape[0])])
+
+
+def serving_forward(mcfg):
+    """``fwd(params, tokens, k, v, pos, page_table=, write_mask=, row_valid=, take=, state=, slot=) -> (logits (B, V), k, v,
+    state, None)`` on the paged :class:`PerHeadKV` pool (bf16/f32 or the int8 pair): :func:`forward_with_cache` with the pools
+    pinned to the layout they were allocated with, the chunk's position ids clipped (its default under a per-row ``pos``),
+    and row ``take`` of the logits.  No per-slot state and no counters: ``state`` passes through, ``aux`` is None.
+    ``fwd.bind(dtype=, mp_size=, pool=)`` is handed what a model config does not hold — the engine's compute dtype and the
+    pool as allocated — before anything is traced; ``fwd.trace_notes`` holds ``kv_write_form``, ``prefill_attend_form`` and
+    ``paged_decode_walk``."""
+    notes: Dict[str, Any] = {}
+    bound: Dict[str, Any] = {}
+
+    def bind(dtype, mp_size: int, pool) -> None:
+        from jax.experimental.layout import Layout
+
+        bound["cfg"] = DeepSpeedInferenceConfig.for_model(mcfg, dtype, mp_size, pool.max_len)
+        # the on-device layout the pool's K buffers were allocated with (V's is the same), leaf by leaf: both programs pin
+        # the pool to it while they carry it through the layers, so that it is one layout from allocation to the kernel and
+        # back (on the TPU a head narrower than the lanes lies with ``page_len`` in them; docs/serving.md §Paged KV)
+        bound["layout"] = jax.tree.map(lambda a: Layout(major_to_minor=a.format.layout.major_to_minor), pool.k)
+
+    def fwd(params, tokens, k, v, pos, page_table, write_mask=None, row_valid=None, take=None, state=None, slot=None):
+        logits, k, v = forward_with_cache(params, tokens, k, v, pos, bound["cfg"], page_table=page_table,
+                                          write_mask=write_mask, trace_notes=notes, pool_layout=bound["layout"])
+        return _logit_rows(logits, take), k, v, state, None
+
+    fwd.bind = bind
+    fwd.trace_notes = notes
+    return fwd
+
+
+def _take_slot(c, slot):
+    return jax.tree.map(lambda a: jax.lax.dynamic_slice(a, (0, slot, 0, 0, 0), (a.shape[0], 1) + a.shape[2:]), c)
+
+
+def _put_slot(c, cs, slot):
+    return jax.tree.map(lambda a, b: jax.lax.dynamic_update_slice(a, b, (0, slot, 0, 0, 0)), c, cs)
+
+
+def slot_serving_forward(mcfg):
+    """The same seam on the slot-contiguous pool (``serving/pool.py``),
+    whose slot axis is the batch axis: a decode step's rows are the
+    slots, each at its own ``pos``; a chunk (``slot`` given) takes its
+    slot's rows out, runs them as a batch of one at the scalar ``pos``
+    and puts them back.  No page table, no write mask."""
+    bound: Dict[str, Any] = {}
+
+    def bind(dtype, mp_size: int, pool) -> None:
+        bound["cfg"] = DeepSpeedInferenceConfig.for_model(mcfg, dtype, mp_size, pool.max_len)
+
+    def fwd(params, tokens, k, v, pos, page_table=None, write_mask=None, row_valid=None, take=None, state=None, slot=None):
+        if slot is None:
+            # per-slot pos: slot-indexed cache write + position mask, auto-clipped position ids
+            logits, k, v = forward_with_cache(params, tokens, k, v, pos, bound["cfg"])
+            return _logit_rows(logits, take), k, v, state, None
+        slot, pos = slot[0], pos[0]
+        ks, vs = _take_slot(k, slot), _take_slot(v, slot)
+        # explicit clipped position ids: the zero-padded chunk tail must
+        # not clamp the wpe slice and shift real rows
+        position_ids = jnp.clip(pos + jnp.arange(tokens.shape[1], dtype=jnp.int32), 0, mcfg.n_positions - 1)[None, :]
+        logits, ks, vs = forward_with_cache(params, tokens, ks, vs, pos, bound["cfg"], position_ids=position_ids)
+        return _logit_rows(logits, take), _put_slot(k, ks, slot), _put_slot(v, vs, slot), state, None
+
+    fwd.bind = bind
+    return fwd
 
 
 @register_op("transformer_inference", "xla", "KV-cache prefill/decode transformer (inference kernel analog)")

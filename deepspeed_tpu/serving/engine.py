@@ -49,7 +49,7 @@ from deepspeed_tpu.analysis.shard import hooks as shard_hooks
 from deepspeed_tpu.config.config import DeepSpeedConfigError, ServingConfig
 from deepspeed_tpu.resilience import faults
 from deepspeed_tpu.serving.journal import JournalError, RequestJournal
-from deepspeed_tpu.serving.kvcache import PagedKVPool
+from deepspeed_tpu.serving.kvcache import PagedKVPool, PerHeadKV
 from deepspeed_tpu.serving.pool import SlotKVPool
 from deepspeed_tpu.serving.scheduler import (
     PRIORITY_NORMAL,
@@ -154,18 +154,13 @@ class ServingEngine:
         self._replicated = replicated_sharding(engine.mesh)
         kvc = config.kvcache
         self._paged = bool(kvc.enabled)
-        # the family seam (docs/serving.md §Model families): a family
-        # whose cache is not the per-head K/V pair supplies the kind of
-        # its pool and its own step on it; the engine keeps scheduler,
-        # staging, sampling, program names and timeline
+        # the family seam (docs/serving.md §Model families): the family
+        # supplies the kind of its pool and its own step on it; the
+        # engine keeps scheduler, staging, sampling, program names and
+        # timeline
         family = engine._family
-        cache_kind = getattr(family, "cache_kind", None)
-        make_forward = getattr(family, "serving_forward", None)
-        self._family_forward = make_forward(mcfg) if make_forward is not None else None
-        # the forms the two programs took, said while they were traced
-        # (stats()): the family's own dict, or the paged per-head pool's
-        self._trace_notes: Dict[str, Any] = getattr(self._family_forward, "trace_notes", {})
-        if self._family_forward is not None and kv_dtype == "int8":
+        kind = family.cache_kind(mcfg, kv_dtype)
+        if kv_dtype == "int8" and not isinstance(kind, PerHeadKV):
             raise ValueError(f"{type(mcfg).__name__}: no int8 form of its cache kind (kv_cache_dtype must be 'model')")
         # per-step counters a family's step returns beside the tokens
         # (DeepSeek-V2: tokens per held expert; stats()["moe"])
@@ -196,13 +191,9 @@ class ServingEngine:
         self._dsa_chunks = 0
         self._dsa_chunk_attendable = 0
         self._dsa_steps0 = 0  # the decode steps before the counters were last started afresh
-        # what the newest decode step of a family that says ``decode_keeps`` left on the device (docs/serving.md §Model families)
-        self.decode_keeps = bool(getattr(self._family_forward, "decode_keeps", False))
-        self.decode_kept: Optional[Dict[str, Any]] = None
         if self._paged:
             import math
 
-            kind = cache_kind(mcfg, kv_dtype) if cache_kind is not None else None  # None: the pool's PerHeadKV
             if config.max_len:
                 if max_len % kvc.page_len:
                     raise ValueError(
@@ -216,7 +207,7 @@ class ServingEngine:
                     # drop those positions may have it (a clipped write lands on the slot's last valid page)
                     raise DeepSpeedConfigError(
                         f"'serving.max_len' ({max_len}) must be a multiple of prefill_chunk "
-                        f"({config.prefill_chunk}) for the cache kind {type(kind).__name__ if kind is not None else 'PerHeadKV'}"
+                        f"({config.prefill_chunk}) for the cache kind {type(kind).__name__}"
                     )
             else:
                 # re-floor the derived capacity to a (chunk, page_len)
@@ -254,16 +245,29 @@ class ServingEngine:
 
                 heads, span = paged_tile(k, self.pool.pages_per_slot)
                 self._decode_tile = (jax.tree.leaves(k)[0].shape[2] // heads, span)
-        elif self._family_forward is not None:
+            make_forward = family.serving_forward
+        elif not isinstance(kind, PerHeadKV):
             raise ValueError(
                 f"{type(mcfg).__name__} is served on its own cache kind, which lives in the "
                 "paged pool only: set serving.kvcache.enabled"
             )
         else:
             self.pool = SlotKVPool(
-                mcfg.n_layer, config.num_slots, mcfg.n_head, max_len, mcfg.head_dim,
+                mcfg.n_layer, config.num_slots, kind.heads, max_len, kind.head_dim,
                 kv_dtype, sharding=self._replicated,
             )
+            make_forward = family.slot_serving_forward
+        # the model's step on the pool that was built: fwd(params, tokens, k, v, pos, page_table=, write_mask=,
+        # row_valid=, take=, state=, slot=) -> (logits (B, V), k, v, state, aux)
+        self._family_forward = make_forward(mcfg)
+        bind = getattr(self._family_forward, "bind", None)
+        if bind is not None:  # a forward that wants what a model config does not hold
+            bind(dtype=engine.dtype, mp_size=engine.mp_world_size, pool=self.pool)
+        # the forms the two programs took, said while they were traced (stats())
+        self._trace_notes: Dict[str, Any] = getattr(self._family_forward, "trace_notes", {})
+        # what the newest decode step of a family that says ``decode_keeps`` left on the device (docs/serving.md §Model families)
+        self.decode_keeps = bool(getattr(self._family_forward, "decode_keeps", False))
+        self.decode_kept: Optional[Dict[str, Any]] = None
         self.scheduler = ContinuousScheduler(
             self.pool,
             prefill_chunk=config.prefill_chunk,
@@ -434,140 +438,53 @@ class ServingEngine:
             return san.recompile.wrap(fn, site=site, owner=id(self))
         return fn
 
-    def _pool_layout(self):
-        """The on-device layout the paged pool's K buffers were allocated
-        with (V's is the same), leaf by leaf: what both programs pin the
-        pool to while they carry it through the layers, so that it is
-        one layout from allocation to the kernel and back (on the TPU a
-        head narrower than the lanes lies with ``page_len`` in them;
-        docs/serving.md §Paged KV)."""
-        from jax.experimental.layout import Layout
-
-        return jax.tree.map(lambda a: Layout(major_to_minor=a.format.layout.major_to_minor), self.pool.k)
-
     def _get_prefill(self):
         if self._prefill_fn is None:
             from deepspeed_tpu.inference.engine import sample_logits_pooled
-            from deepspeed_tpu.ops.transformer.inference import forward_with_cache, page_copy
 
-            icfg = self.engine.inference_config(self.pool.max_len) if self._family_forward is None else None
-            notes = self._trace_notes
-            n_pos = self.engine.model_config.n_positions
+            fwd = self._family_forward
+            kind = getattr(self.pool, "kind", None)  # the paged pool's cache kind: its copy-on-write
             chunk = self.config.prefill_chunk
             max_top_k = self.config.max_top_k
             unpack = self._prefill_layout.unpack
 
-            def _take_slot(c, slot):
-                return jax.tree.map(
-                    lambda a: jax.lax.dynamic_slice(
-                        a, (0, slot, 0, 0, 0), (a.shape[0], 1) + a.shape[2:]
-                    ),
-                    c,
-                )
-
-            def _put_slot(c, cs, slot):
-                return jax.tree.map(
-                    lambda a, b: jax.lax.dynamic_update_slice(a, b, (0, slot, 0, 0, 0)),
-                    c, cs,
-                )
-
-            if self._family_forward is not None:
-                fwd = self._family_forward
-
-                def serve_prefill(params, packed, k_pool, v_pool, state_pool):
-                    f = unpack(packed)
-                    toks, table, pos, take_idx = f["tokens"], f["table"], f["pos"], f["take_idx"]
-                    cow_src, cow_dst, slot = f["cow_src"], f["cow_dst"], f.get("slot")
-                    # the paged step below with the family's own forward
-                    # on its own cache kind; the chunk's padded tail is
-                    # computed and left out of the family's counters.
-                    # ``state_pool`` is the kind's slot-axis group: the
-                    # chunk's ``slot`` says which rows of it are this
-                    # request's; copy-on-write is a matter of pages and
-                    # never touches it.  A kind that is pages and nothing
-                    # else has no ``slot`` among its fields and hands None
-                    # for the group (an empty pytree: nothing is donated)
-                    cow = lambda b: b.at[:, cow_dst].set(b[:, cow_src])  # noqa: E731
-                    k_pool = jax.tree.map(cow, k_pool)
-                    v_pool = jax.tree.map(cow, v_pool)
-                    logits, k_pool, v_pool, state_pool, aux = fwd(
-                        params, toks, k_pool, v_pool, pos[None], page_table=table[None, :],
-                        row_valid=(jnp.arange(chunk, dtype=jnp.int32) <= take_idx)[None, :],
-                        take=take_idx[None], state=state_pool, slot=None if slot is None else slot[None],
-                    )
-                    key = jax.random.fold_in(jax.random.PRNGKey(f["seed"]), pos + take_idx)
-                    first = sample_logits_pooled(
-                        logits.astype(jnp.float32), key[None], f["do_sample"][None], f["temperature"][None],
-                        f["top_k"][None], max_top_k,
-                    )[0]
-                    return (first, aux), k_pool, v_pool, state_pool
-
-                donate = (2, 3, 4)
-            elif self._paged:
-                pool_layout = self._pool_layout()
-
-                def serve_prefill(params, packed, k_pool, v_pool):
-                    f = unpack(packed)
-                    toks, table, pos, take_idx = f["tokens"], f["table"], f["pos"], f["take_idx"]
+            def serve_prefill(params, packed, k_pool, v_pool, state_pool):
+                f = unpack(packed)
+                pos, take_idx, table, slot = f["pos"], f["take_idx"], f.get("table"), f.get("slot")
+                if "cow_src" in f:
                     # the slot's pending copy-on-write lands BEFORE this
                     # chunk's writes: a traced (src, dst) page pair rides
                     # the request's first chunk ((0, 0) — garbage page
                     # onto itself — is the identity when nothing pends).
-                    # One page read and written as slices: a scatter over
-                    # the page axis relays the whole pool out
-                    k_pool = page_copy(k_pool, f["cow_src"], f["cow_dst"])
-                    v_pool = page_copy(v_pool, f["cow_src"], f["cow_dst"])
-                    position_ids = jnp.clip(
-                        pos + jnp.arange(chunk, dtype=jnp.int32), 0, n_pos - 1
-                    )[None, :]
-                    logits, k_pool, v_pool = forward_with_cache(
-                        params, toks, k_pool, v_pool, pos[None], icfg,
-                        position_ids=position_ids, page_table=table[None, :],
-                        trace_notes=notes, pool_layout=pool_layout,
-                    )
-                    key = jax.random.fold_in(
-                        jax.random.PRNGKey(f["seed"]), pos + take_idx
-                    )
-                    first = sample_logits_pooled(
-                        logits[0, take_idx].astype(jnp.float32)[None, :],
-                        key[None], f["do_sample"][None], f["temperature"][None], f["top_k"][None],
-                        max_top_k,
-                    )[0]
-                    return first, k_pool, v_pool
-
-                donate = (2, 3)
-            else:
-                def serve_prefill(params, packed, k_pool, v_pool):
-                    f = unpack(packed)
-                    toks, slot, pos, take_idx = f["tokens"], f["slot"], f["pos"], f["take_idx"]
-                    ks, vs = _take_slot(k_pool, slot), _take_slot(v_pool, slot)
-                    # explicit clipped position ids: the zero-padded chunk
-                    # tail must not clamp the wpe slice and shift real rows
-                    position_ids = jnp.clip(
-                        pos + jnp.arange(chunk, dtype=jnp.int32), 0, n_pos - 1
-                    )[None, :]
-                    logits, ks, vs = forward_with_cache(
-                        params, toks, ks, vs, pos, icfg, position_ids=position_ids
-                    )
-                    # the first generated token samples with the request's
-                    # params (the same key schedule as decode: key = seed
-                    # folded with the fed token's cache position)
-                    key = jax.random.fold_in(jax.random.PRNGKey(f["seed"]), pos + take_idx)
-                    first = sample_logits_pooled(
-                        logits[0, take_idx].astype(jnp.float32)[None, :],
-                        key[None],
-                        f["do_sample"][None],
-                        f["temperature"][None],
-                        f["top_k"][None],
-                        max_top_k,
-                    )[0]
-                    return first, _put_slot(k_pool, ks, slot), _put_slot(v_pool, vs, slot)
-
-                donate = (2, 3)
+                    # The form is the cache kind's.  A matter of pages:
+                    # it never touches ``state_pool``
+                    k_pool = kind.copy_page(k_pool, f["cow_src"], f["cow_dst"])
+                    v_pool = kind.copy_page(v_pool, f["cow_src"], f["cow_dst"])
+                # one chunk of one request, a batch of one: ``table`` its
+                # pages, ``slot`` (a field where the cache keeps something
+                # by slot) its rows of the slot axis.  The chunk's padded
+                # tail is computed and left out of the family's counters.
+                # A cache without a slot-axis group hands None for
+                # ``state_pool`` (an empty pytree: nothing is donated)
+                logits, k_pool, v_pool, state_pool, aux = fwd(
+                    params, f["tokens"], k_pool, v_pool, pos[None], page_table=None if table is None else table[None, :],
+                    row_valid=(jnp.arange(chunk, dtype=jnp.int32) <= take_idx)[None, :],
+                    take=take_idx[None], state=state_pool, slot=None if slot is None else slot[None],
+                )
+                # the first generated token samples with the request's
+                # params (the same key schedule as decode: key = seed
+                # folded with the fed token's cache position)
+                key = jax.random.fold_in(jax.random.PRNGKey(f["seed"]), pos + take_idx)
+                first = sample_logits_pooled(
+                    logits.astype(jnp.float32), key[None], f["do_sample"][None], f["temperature"][None],
+                    f["top_k"][None], max_top_k,
+                )[0]
+                # what the model's step counted rides beside the token (``_note_aux``)
+                return (first if aux is None else (first, aux)), k_pool, v_pool, state_pool
 
             # the function's name is the program's in the profiler's
             # trace: jit_serve_prefill on the device's "XLA Modules" line
-            self._prefill_jit = jax.jit(self.engine._scoped(serve_prefill), donate_argnums=donate)
+            self._prefill_jit = jax.jit(self.engine._scoped(serve_prefill), donate_argnums=(2, 3, 4))
             self._prefill_fn = self._wrap(self._prefill_jit, "serving.prefill")
             self.prefill_compiles += 1
             # ds_shard Pass 2 feed (no-op unless the audit armed it)
@@ -580,85 +497,44 @@ class ServingEngine:
     def _get_decode(self):
         if self._decode_fn is None:
             from deepspeed_tpu.inference.engine import sample_logits_pooled
-            from deepspeed_tpu.ops.transformer.inference import forward_with_cache
 
-            icfg = self.engine.inference_config(self.pool.max_len) if self._family_forward is None else None
-            notes = self._trace_notes
+            fwd = self._family_forward
             max_top_k = self.config.max_top_k
             unpack = self._decode_layout.unpack
+            # a family may ask that arrays of its decode step stay on the device until the next one
+            # (``decode_kept``: never fetched here; Keye's selection, which a check of the served program reads)
+            keeps = self.decode_keeps
 
-            if self._family_forward is not None:
-                fwd = self._family_forward
-                # a family may ask that arrays of its decode step stay on the device until the next one
-                # (``decode_kept``: never fetched here; Keye's selection, which a check of the served program reads)
-                keeps = self.decode_keeps
+            def serve_decode(params, packed, k_pool, v_pool, state_pool):
+                f = unpack(packed)
+                toks, pos, write_mask = f["toks"], f["pos"], f.get("write_mask")
+                kept = {} if keeps else None
+                # row b is slot b, at its own ``pos``: the rows of
+                # ``state_pool`` are the batch's.  Per-slot page tables
+                # are traced values of the one fixed signature;
+                # ``write_mask`` redirects non-decoding slots' writes to
+                # the garbage page (pages.py).  The slot-contiguous pool
+                # has neither
+                logits, k_pool, v_pool, state_pool, aux = fwd(
+                    params, toks[:, None], k_pool, v_pool, pos, page_table=f.get("tables"), write_mask=write_mask,
+                    row_valid=None if write_mask is None else write_mask[:, None], state=state_pool,
+                    **({"kept": kept} if keeps else {}),
+                )
+                # per-(request seed, position) keys: reproducible per
+                # request regardless of slot assignment or pool churn
+                keys = jax.vmap(
+                    lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
+                )(f["seeds"], pos)
+                nxt = sample_logits_pooled(
+                    logits.astype(jnp.float32), keys, f["flags"], f["temps"], f["topks"], max_top_k,
+                )
+                if keeps:
+                    nxt = (nxt, aux, kept)
+                elif aux is not None:  # what the model's step counted rides beside the tokens (``_note_aux``)
+                    nxt = (nxt, aux)
+                return nxt, k_pool, v_pool, state_pool
 
-                def serve_decode(params, packed, k_pool, v_pool, state_pool):
-                    f = unpack(packed)
-                    toks, pos, page_table, write_mask = f["toks"], f["pos"], f["tables"], f["write_mask"]
-                    kept = {} if keeps else None
-                    # row b is slot b: the rows of ``state_pool`` are the batch's
-                    logits, k_pool, v_pool, state_pool, aux = fwd(
-                        params, toks[:, None], k_pool, v_pool, pos, page_table=page_table,
-                        write_mask=write_mask, row_valid=write_mask[:, None], state=state_pool,
-                        **({"kept": kept} if keeps else {}),
-                    )
-                    keys = jax.vmap(
-                        lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
-                    )(f["seeds"], pos)
-                    nxt = sample_logits_pooled(
-                        logits.astype(jnp.float32), keys, f["flags"], f["temps"], f["topks"], max_top_k,
-                    )
-                    return ((nxt, aux, kept) if keeps else (nxt, aux)), k_pool, v_pool, state_pool
-
-                donate = (2, 3, 4)
-            elif self._paged:
-                pool_layout = self._pool_layout()
-
-                def serve_decode(params, packed, k_pool, v_pool):
-                    f = unpack(packed)
-                    toks, pos, page_table, write_mask = f["toks"], f["pos"], f["tables"], f["write_mask"]
-                    # per-slot page tables are traced values of the one
-                    # fixed signature; write_mask redirects non-decoding
-                    # slots' writes to the garbage page (pages.py)
-                    logits, k_pool, v_pool = forward_with_cache(
-                        params, toks[:, None], k_pool, v_pool, pos, icfg,
-                        page_table=page_table, write_mask=write_mask, trace_notes=notes,
-                        pool_layout=pool_layout,
-                    )
-                    keys = jax.vmap(
-                        lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
-                    )(f["seeds"], pos)
-                    nxt = sample_logits_pooled(
-                        logits[:, -1].astype(jnp.float32), keys, f["flags"], f["temps"],
-                        f["topks"], max_top_k,
-                    )
-                    return nxt, k_pool, v_pool
-
-                donate = (2, 3)
-            else:
-                def serve_decode(params, packed, k_pool, v_pool):
-                    f = unpack(packed)
-                    toks, pos = f["toks"], f["pos"]
-                    # per-slot pos: slot-indexed cache write + position mask
-                    # (ops/transformer/inference.py), auto-clipped position ids
-                    logits, k_pool, v_pool = forward_with_cache(
-                        params, toks[:, None], k_pool, v_pool, pos, icfg
-                    )
-                    # per-(request seed, position) keys: reproducible per
-                    # request regardless of slot assignment or pool churn
-                    keys = jax.vmap(
-                        lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
-                    )(f["seeds"], pos)
-                    nxt = sample_logits_pooled(
-                        logits[:, -1].astype(jnp.float32), keys, f["flags"], f["temps"], f["topks"],
-                        max_top_k,
-                    )
-                    return nxt, k_pool, v_pool
-
-                donate = (2, 3)
-
-            self._decode_jit = jax.jit(self.engine._scoped(serve_decode), donate_argnums=donate)
+            self._decode_jit = jax.jit(self.engine._scoped(serve_decode), donate_argnums=(2, 3, 4))
             self._decode_fn = self._wrap(self._decode_jit, "serving.decode")
             self.decode_compiles += 1
             # ds_shard Pass 2 feed (no-op unless the audit armed it)
@@ -1503,8 +1379,8 @@ class ServingEngine:
             tl.count("programs")
             first, *pools = fn(self.engine.params, staged, *self._pool_args())
         self.pool.swap(*pools)
-        if self._family_forward is not None and job.start == 0:
-            self._state_resets += 1  # taken inside the program: the chunk at position 0 starts from zero
+        if job.start == 0:
+            self._state_resets += 1  # a cache kind with per-slot state: taken inside the program, the chunk at position 0 starts from zero
         if self._select_topk:
             self._dsa_chunks += 1
             self._dsa_chunk_attendable += job.length * job.start + job.length * (job.length + 1) // 2  # query i of the chunk: start + i + 1
@@ -1525,7 +1401,7 @@ class ServingEngine:
             self._programs_read += 1
             if not noted:
                 tl.count("chunks_awaited")
-            tok = int(tok if self._family_forward is None else self._note_aux(tok, decode=False))
+            tok = int(self._note_aux(tok, decode=False))
             now = time.monotonic()
             if self._paged and job.final and not noted:
                 # the whole prompt's KV is paged in: learn it as a shared
@@ -1589,7 +1465,7 @@ class ServingEngine:
             out = jax.device_get(nxt)
         with tl.phase("decode.note"):
             self._programs_read += 1
-            out = np.asarray(out if self._family_forward is None else self._note_aux(out, decode=True))
+            out = np.asarray(self._note_aux(out, decode=True))
             now = time.monotonic()
             self.scheduler.note_decode(
                 {r.slot: int(out[r.slot]) for r in decoding}, now, self._step_count
@@ -1636,20 +1512,22 @@ class ServingEngine:
         return jax.device_put(host, self._replicated)
 
     def _pool_args(self) -> tuple:
-        """The donated cache arguments of a serving step: K and V, and —
-        for a family's step — the kind's slot-axis group (None for a kind
-        that is pages and nothing else)."""
-        if self._family_forward is not None:
-            return self.pool.k, self.pool.v, self.pool.state
-        return self.pool.k, self.pool.v
+        """The donated cache arguments of a serving step: K, V and the
+        kind's slot-axis group (None, an empty pytree, for a cache that
+        has none)."""
+        return self.pool.k, self.pool.v, self.pool.state
 
     def _note_aux(self, got, decode: bool):
-        """A family's step returns ``(tokens, aux)``: add the step's
-        counters (host side, a few hundred integers) and hand the tokens
-        on.  DeepSeek-V2's ``aux (moe layers, held + 1)``: tokens
-        computed per held expert, and last the assignments routed to
-        held experts."""
+        """A step whose model counted something returns ``(tokens,
+        aux)``: add the step's counters (host side, a few hundred
+        integers) and hand the tokens on.  DeepSeek-V2's ``aux (moe
+        layers, held + 1)``: tokens computed per held expert, and last
+        the assignments routed to held experts."""
+        if not isinstance(got, (tuple, list)):
+            return got
         tokens, aux = got
+        if aux is None:
+            return tokens
         aux = np.asarray(aux, np.int64)
         self._aux_total = aux if self._aux_total is None else self._aux_total + aux
         if decode:

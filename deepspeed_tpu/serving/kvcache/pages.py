@@ -74,12 +74,25 @@ def _locked(fn):
     return wrapper
 
 
+def _scatter_page(pool, src, dst):
+    """Page ``src`` of every layer onto page ``dst`` of every leaf, as a gather and a scatter over the page axis (``src == dst``: the identity)."""
+    return jax.tree.map(lambda b: b.at[:, dst].set(b[:, src]), pool)
+
+
 class PerHeadKV:
     """The default cache kind: a K and a V buffer of ``(layers, pages,
     heads, page_len, head_dim)`` (or the int8 code+scale pair)."""
 
     def __init__(self, heads: int, head_dim: int, dtype: Any):
         self.heads, self.head_dim, self.dtype = int(heads), int(head_dim), dtype
+
+    @staticmethod
+    def copy_page(pool, src, dst):
+        """The kind's copy-on-write, each kind the form its cell read faster on the chip (``PERF.md`` §6, PRs 40, 51): here a page as
+        slices — a scatter relays a pool of heads narrower than the lanes out whole; the other kinds scatter (ZAYA1's chunk: 3.4 %)."""
+        from deepspeed_tpu.ops.transformer.inference import page_copy
+
+        return page_copy(pool, src, dst)
 
     def buffers(self, n_layer: int, num_pages: int, page_len: int):
         from deepspeed_tpu.ops.transformer.inference import init_kv_cache
@@ -99,6 +112,7 @@ class LatentKV:
     (``pool.v`` is None, an empty pytree to ``jit``)."""
 
     pages_hold_all = True
+    copy_page = staticmethod(_scatter_page)
 
     def __init__(self, width: int, dtype: Any):
         self.width, self.dtype = int(width), dtype
@@ -132,6 +146,7 @@ class IndexedKV:
     # (``paged_cache_write_slices``, ``sparse_attention.index_cache_write``), so a slot need not hold whole chunks;
     # a kind that does not say so keeps the chunk-multiple rule (``latent_cache_write`` clips onto the last page)
     chunk_writes_drop_past_slot = True
+    copy_page = staticmethod(_scatter_page)
 
     def __init__(self, kv_heads: int, head_dim: int, index_dim: int, dtype: Any):
         self.heads, self.head_dim, self.index_dim, self.dtype = int(kv_heads), int(head_dim), int(index_dim), dtype
@@ -183,6 +198,7 @@ class HybridKV:
     starts from zero where the chunk starts at position 0."""
 
     pages_hold_all = False
+    copy_page = staticmethod(_scatter_page)  # never follows a shared page here; the form the programs always held
 
     def __init__(self, paged_layers: int, pages: Any, state: Dict[str, Tuple[int, Tuple[int, ...], Any]]):
         self.paged_layers, self.pages, self.dtype = int(paged_layers), pages, pages.dtype

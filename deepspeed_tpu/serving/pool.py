@@ -60,6 +60,7 @@ class SlotKVPool:
             # compiled step reshards the pool implicitly (a transfer the
             # ds_san guard rightly flags)
             self.k, self.v = jax.device_put((self.k, self.v), sharding)
+        self.state = None  # no slot-axis group beside K and V (PagedKVPool's HybridKV has one)
         self._free: Deque[int] = deque(range(num_slots))
         self._owner: Dict[int, Any] = {}  # slot -> request id
 
@@ -104,9 +105,10 @@ class SlotKVPool:
         self._free.append(slot)
 
     # -- device buffers ---------------------------------------------------
-    def swap(self, k, v) -> None:
+    def swap(self, k, v, state=None) -> None:
         """Rebind the cache buffers after a donated compiled step (the
-        old arrays were consumed by the donation)."""
+        old arrays were consumed by the donation); ``state`` is what a
+        step hands back for the group this pool does not have."""
         self.k, self.v = k, v
 
     def cache_bytes(self) -> int:

@@ -318,9 +318,17 @@ def _zaya_rules(layout: SpecLayout) -> Tuple[Rule, ...]:
     return tuple(r for r in _deepseek_v2_rules(layout) if "head" not in r[0])
 
 
+def _keye_rules(layout: SpecLayout) -> Tuple[Rule, ...]:
+    """Keye (models/keye.py): the same deployment with an untied head
+    that lies ``(hidden, vocabulary)`` — vocabulary-parallel is its
+    second dim."""
+    return _zaya_rules(layout) + ((r"(^|/)head$", layout.column_parallel()),)
+
+
 register_family("gpt2", _gpt2_rules)
 register_family("deepseek_v2", _deepseek_v2_rules)
 register_family("zaya", _zaya_rules)
+register_family("keye", _keye_rules)
 register_family("bert", _bert_rules)
 register_family("neo", _neo_rules)
 register_family("moe", _moe_family_rules)
@@ -340,22 +348,19 @@ def rules_for_family(name: str, layout: SpecLayout = DEFAULT_LAYOUT) -> Partitio
 
 
 def rules_for_config(model_config: Any, layout: SpecLayout = DEFAULT_LAYOUT) -> PartitionRules:
-    """Family rules for a model config object (GPT2Config → gpt2,
-    BertConfig → bert) — how the inference/serving engines resolve."""
-    for klass in type(model_config).__mro__:
-        if klass.__name__ == "GPT2Config":
-            return rules_for_family("gpt2", layout)
-        if klass.__name__ == "BertConfig":
-            return rules_for_family("bert", layout)
-        if klass.__name__ in ("DeepseekV2Config", "SolarOpen2Config"):
-            # one deployment, one table: held experts over ``expert``, embedding and head over the
-            # vocabulary, every kind of attention, shared experts, router and norms replicated
-            return rules_for_family("deepseek_v2", layout)
-        if klass.__name__ == "ZayaConfig":
-            return rules_for_family("zaya", layout)
-    raise ValueError(
-        f"no built-in partition rules for model config {type(model_config).__name__}"
-    )
+    """The rules of a model config object — how the inference/serving
+    engines resolve: its class names its family (``models.family_of``,
+    the one table from a config class to a family) and the family's
+    module names its table (``PARTITION_RULES``)."""
+    from deepspeed_tpu.models import family_of
+
+    family = family_of(model_config)
+    if family is None:
+        raise ValueError(f"no built-in partition rules for model config {type(model_config).__name__}")
+    table = getattr(family, "PARTITION_RULES", None)
+    if table is None:
+        raise ValueError(f"{family.__name__} names no partition-rule table (PARTITION_RULES)")
+    return rules_for_family(table, layout)
 
 
 def family_catalog() -> Dict[str, int]:
