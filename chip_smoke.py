@@ -26,6 +26,12 @@ weights made from a seed:
   ``gdn_decode`` and ``mla_decode_paged`` in its decode program,
   ``mla_prefill`` in its prefill program)
   against ``benchmark/reference_gigachat35.py``;
+* a seventh family — Laguna at a small size on two page groups in one
+  pool (pages by length for the full-attention layers, a ring of pages a
+  slot for the window layers; ``flash_decode_paged`` and, for the window
+  layers, ``swa_decode_paged`` in its decode program), the window kernel
+  first held against its ``jnp`` form on a lapped ring at 72 / 8 heads,
+  against ``benchmark/reference_laguna.py``;
 * server — ``deepspeed_tpu.init_inference("gpt2-xl")`` → ``ServingEngine``
   on the paged pool (8 slots, ``page_len`` 128), a bf16 then an int8 KV
   pool, eight seeded greedy requests each;
@@ -855,11 +861,114 @@ def serve_gigachat35(s: Smoke, device) -> Dict[str, float]:
     return gaps
 
 
+LAGUNA_SMOKE = {
+    "model_type": "laguna", "vocab_size": 512, "hidden_size": 256, "intermediate_size": 512, "num_hidden_layers": 5,
+    "num_attention_heads": 12, "num_key_value_heads": 2, "head_dim": 128, "max_position_embeddings": 4096, "attention_bias": False,
+    "rms_norm_eps": 1e-6, "num_experts": 16, "num_experts_per_tok": 4, "moe_intermediate_size": 128,
+    "shared_expert_intermediate_size": 128, "norm_topk_prob": True, "decoder_sparse_step": 1, "mlp_only_layers": [0],
+    "tie_word_embeddings": False, "gating": "per-head", "sliding_window": 160,
+    "rope_parameters": {"full_attention": {"rope_theta": 500000, "rope_type": "yarn", "factor": 8, "original_max_position_embeddings": 128,
+                                           "beta_slow": 1, "beta_fast": 32, "attention_factor": 1.2, "partial_rotary_factor": 0.5},
+                        "sliding_attention": {"rope_type": "default", "rope_theta": 10000, "partial_rotary_factor": 1}},
+    "layer_types": ["full_attention", "sliding_attention", "sliding_attention", "sliding_attention", "full_attention"],
+    "mlp_layer_types": ["dense", "sparse", "sparse", "sparse", "sparse"], "gating_types": ["per_head"] * 5,
+    "num_attention_heads_per_layer": [12, 18, 18, 18, 12],  # 6 and 9 query heads a KV head: the published groups
+    "moe_routed_scaling_factor": 2.5, "moe_apply_router_weight_on_input": False, "moe_router_logit_softcapping": 0,
+    "experts_held": [8, 8], "vocab_held": 256,
+}
+TOL_LAGUNA_TOKEN_GAP = 0.05  # as TOL_DSV2_TOKEN_GAP: a bf16 program against the float32 reference
+
+
+def check_swa_decode_paged(s: Smoke) -> float:
+    """``swa_decode_paged`` — the paged decode kernel under its window
+    form — against its ``jnp`` form on a **lapped ring** at the cell's
+    heads (72 query heads on 8 KV heads of 128, 9 a KV head), pages of
+    128, window 512: rows before a window is full, rows whose ring has
+    lapped several times, a row that does not decode."""
+    from deepspeed_tpu.ops.kernels.flash_decode import paged_tile, paged_work_list
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    B, H, Hkv, d, page_len, window, P = 6, 72, 8, 128, 128, 512, 64
+    R = inf.ring_pages_for(window, page_len)
+    rng = np.random.default_rng(s.seed)
+    wk, wv = (jnp.asarray(rng.standard_normal((1 + B * R, Hkv, page_len, d)), jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((B, H, 1, d)), jnp.bfloat16)
+    ring = inf.ring_table(jnp.arange(B), R, P)
+    pos = jnp.asarray([7, 511, 512, 3000, 8191, 1234], jnp.int32)
+    live = jnp.asarray([True, True, True, True, True, False])
+    work = paged_work_list(pos, live, page_len, P, paged_tile(wk, P)[1], window)
+    got = jax.jit(lambda *a: inf.window_cache_attention(*a, window, use_kernel=True, work=work))(q, wk, wv, ring, pos)
+    want = jax.jit(lambda *a: inf.window_cache_attention(*a, window, use_kernel=False))(q, wk, wv, ring, pos)
+    err = _max_err(got[:5], want[:5])
+    check(err <= TOL_BF16, f"swa_decode_paged on a lapped ring of {R} pages, {H} / {Hkv} heads: max error {err:.4f} against the jnp form (tolerance {TOL_BF16})")
+    check(float(jnp.abs(got[5].astype(jnp.float32)).max()) == 0.0, "swa_decode_paged: the row that does not decode reads 0")
+    check(int(work[2][0]) == sum(int(p) // 256 - max(int(p) - 511, 0) // 256 + 1 for p in np.asarray(pos)[:5]),
+          f"swa_decode_paged: the work list holds {int(work[2][0])} items, the window's spans of the five live rows")
+    say(f"kernel swa_decode_paged: max error {err:.4f}, {int(work[2][0])} items for 5 live rows")
+    return err
+
+
+def serve_laguna(s: Smoke, device) -> Dict[str, float]:
+    """``init_inference(model_config=LagunaConfig)`` → the same
+    ``ServingEngine`` on **two page groups in one pool**: compiles both
+    programs, serves three requests over two slots (chunked prefill:
+    block by block over pages by length in the full layers, a banded walk
+    and the ring's write in the window layers — window 160 on pages of
+    128 is a ring of 3 pages, so a prompt of 600 laps it; decode through
+    ``flash_decode_paged`` at 6 query heads a KV head and
+    ``swa_decode_paged`` at 9; a slot reused) and holds every emitted
+    token against the plain reference's logits
+    (``benchmark/reference_laguna.py``)."""
+    from benchmark import weights_laguna as weights
+    from benchmark.reference_laguna import Reference
+    from benchmark.runners.serve_laguna import served_gaps
+    from deepspeed_tpu.comm.mesh import make_mesh
+    from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.models import laguna
+    from deepspeed_tpu.serving import ServingEngine
+
+    dims = LAGUNA_SMOKE
+    mcfg = laguna.LagunaConfig.from_hf(dims, experts_held=dims["experts_held"], vocab_held=dims["vocab_held"])
+    inf = deepspeed_tpu.init_inference(
+        model_config=mcfg, params=weights.program_params(s.seed, dims, jnp.bfloat16), dtype=jnp.bfloat16,
+        max_out_tokens=1024, mesh=make_mesh(MeshConfig(), devices=[device]), donate_params=True,
+    )
+    srv = ServingEngine(inf, config={"num_slots": 2, "max_len": 1024, "prefill_chunk": 256, "max_new_tokens": 16,
+                                     "kvcache": {"enabled": True, "page_len": 128, "num_pages": 17}})
+    rng = np.random.default_rng(s.seed)
+    prompts = [rng.integers(1, dims["vocab_held"], n, dtype=np.int32) for n in (600, 131, 300)]
+    ids = [srv.submit(p, max_new_tokens=8) for p in prompts]
+    done = srv.drain()
+    check((srv.prefill_compiles, srv.decode_compiles) == (1, 1),
+          f"serve[laguna]: {srv.prefill_compiles} prefill / {srv.decode_compiles} decode executables")
+    stats = srv.stats()
+    moe, groups = stats["moe"], stats["kvcache"]["groups"]
+    check(moe["dropped_assignments"] == 0 and moe["assignments_computed"] > 0, f"serve[laguna]: expert counters {moe}")
+    check((groups["full"]["layers"], groups["window"]["layers"], groups["window"]["pages_per_slot"], groups["window"]["positions_per_slot"]) == (2, 3, 3, 384)
+          and groups["window"]["bytes"] == 2 * 3 * (1 + 2 * 3) * 2 * 128 * 128 * 2 and stats["swa_ring_positions"] == 384,
+          f"serve[laguna]: the pool's two groups {groups}")
+    check(stats["kvcache"]["reuse"].startswith("off:"), "serve[laguna]: prefix reuse is off and says so")
+    gaps = served_gaps(Reference(dims, s.seed), [{"prompt": p, "generated": list(done[i].generated)}
+                                                 for p, i in zip(prompts, ids)], 256)
+    check(gaps["token_gap_max"] <= TOL_LAGUNA_TOKEN_GAP,
+          f"serve[laguna]: an emitted token lies {gaps['token_gap_max']:.4f} under the reference's best logit (tolerance {TOL_LAGUNA_TOKEN_GAP})")
+    expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
+                   ["flash_decode_paged", "swa_decode_paged", "moe_grouped_matmul"] if s.mosaic else [], "serve[laguna] decode")
+    expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()),
+                   ["moe_grouped_matmul"] if s.mosaic else [], "serve[laguna] prefill")
+    check(stats["swa_decode_form"].startswith("swa_decode_paged" if s.mosaic else "jnp over the ring") and stats["swa_chunk_form"].startswith("banded jnp"),
+          f"serve[laguna]: stats() say of the window layers: decode {stats['swa_decode_form']!r}, chunk {stats['swa_chunk_form']!r}")
+    say(f"serve[laguna]: 3 requests x 8 tokens through both page groups, token gap mean {gaps['token_gap_mean']:.5f} "
+        f"max {gaps['token_gap_max']:.5f} over {gaps['tokens']} tokens")
+    return gaps
+
+
 def run(s: Smoke, devices: Sequence) -> None:
     """Every phase, in order; raises on the first check that fails."""
     if s.mosaic:
         check_flash_attention(s)
         check_fused_update(s)
+        check_swa_decode_paged(s)
     result = train(s, devices[:1])
     if len(devices) > 1:
         # the same 16 sequences on one chip (above) and on all of them
@@ -873,6 +982,7 @@ def run(s: Smoke, devices: Sequence) -> None:
     serve_solar_open2(s, devices[0])
     serve_keye(s, devices[0])
     serve_gigachat35(s, devices[0])
+    serve_laguna(s, devices[0])
 
 
 def main() -> int:

@@ -130,9 +130,15 @@ class HFGPTNEOLayerPolicy(DSPolicy):
 
     GPT-Neo uses separate (out, in) Linear q/k/v without biases for q/k/v
     weights' layout, so weights are transposed and q|k|v concatenated.
-    Local-attention layers attend over a window; this policy maps them to
-    full attention (valid superset for short sequences — documented
-    deviation, window masking lands with the sparse-attention kernels).
+    Local-attention layers attend over a window of ``window_size``
+    positions, which the GPT-2 layout this policy converts to does not
+    compute: a model with a ``local`` layer whose window is shorter than
+    its context is **refused by name** (full attention there would be
+    another model's mathematics under this one's name).  A window that
+    covers ``max_position_embeddings`` is full attention and converts.
+    The band mask itself exists (``ops/transformer/inference.py``'s
+    window forms, the Laguna family's); carrying a per-layer window
+    through the GPT-2 block is ROADMAP R2's, not done.
     """
 
     architectures = ("GPTNeoForCausalLM", "GPTNeoModel")
@@ -141,8 +147,14 @@ class HFGPTNEOLayerPolicy(DSPolicy):
     def convert(cls, model, hf_config=None):
         from deepspeed_tpu.models.gpt2 import GPT2Config
 
-        sd = _state_dict_of(model)
         hf = hf_config if hf_config is not None else model.config
+        local = [i for i, kind in enumerate(getattr(hf, "attention_layers", None) or ()) if kind == "local"]
+        if local and int(getattr(hf, "window_size", 0) or 0) < int(hf.max_position_embeddings):
+            raise NotImplementedError(
+                f"HFGPTNEOLayerPolicy: layers {local} attend over a local window of {hf.window_size} positions "
+                f"(context {hf.max_position_embeddings}); the GPT-2 layout computes full attention, which is not this model"
+            )
+        sd = _state_dict_of(model)
         prefix = "transformer." if any(k.startswith("transformer.") for k in sd) else ""
         n_layer = hf.num_layers
         d = hf.hidden_size

@@ -301,12 +301,15 @@ def paged_tile(cache, pages_per_slot: int):
     return heads, span
 
 
-def paged_work_list(pos, live, page_len: int, pages_per_slot: int, span: int = 1):
+def paged_work_list(pos, live, page_len: int, pages_per_slot: int, span: int = 1, window: Optional[int] = None):
     """The work of one paged decode step as a list of (slot, span) items,
     a span being ``span`` consecutive logical pages of a row
     (:func:`paged_tile`; 1: an item is a page): for every row with
     ``live[b]`` (``None``: every row) the spans ``0 ... pos[b] //
-    (page_len * span)``, in slot order, then span order.  Returns
+    (page_len * span)``, in slot order, then span order — or, ``window``
+    given (a layer that attends over its last ``window`` positions, the
+    query's own among them), only the spans those positions lie in:
+    from ``max(pos[b] - window + 1, 0) // (page_len * span)`` on.  Returns
     ``(slot, span_index, n, live)``: two int32 arrays of the static
     capacity ``B * pages_per_slot // span``, the count ``n (1,)`` — the
     items past ``n`` repeat the last one and are not walked
@@ -322,6 +325,8 @@ def paged_work_list(pos, live, page_len: int, pages_per_slot: int, span: int = 1
     live = jnp.ones(pos.shape, bool) if live is None else live.astype(bool)
     spans = jnp.where(live, jnp.clip(pos // (page_len * span), 0, S - 1) + 1, 0)  # (B,) filled spans a row
     filled = jnp.arange(S, dtype=jnp.int32)[None, :] < spans[:, None]  # (B, S), row-major: slot order, then span order
+    if window is not None:
+        filled &= jnp.arange(S, dtype=jnp.int32)[None, :] >= (jnp.maximum(pos - (window - 1), 0) // (page_len * span))[:, None]
     items, n = compact_rows(filled.reshape(-1))
     return items // S, items % S, n, live
 
@@ -343,6 +348,7 @@ def _flash_decode_paged_kernel(
     group: int,
     span: int,
     lanes_hold_rows: bool,
+    window: Optional[int] = None,
 ):
     k_refs, v_refs, *scales = (rest[g * span: (g + 1) * span] for g in range(4 if quant else 2))
     ks_refs, vs_refs = scales or (None, None)
@@ -352,6 +358,8 @@ def _flash_decode_paged_kernel(
     b, s_idx = slot_ref[i], span_ref[i]
     heads = [(h, pl.dslice(h * group, group)) for h in range(block_heads)]   # a KV head and the rows of its query heads
     cols = [pl.dslice(j * page_len, page_len) for j in range(span)]          # a page's positions in the item's score rows
+    # the row's first item: span 0, or — a window layer — the span the window's first position lies in
+    first = 0 if window is None else jnp.maximum(pos_ref[b] - (window - 1), 0) // (page_len * span)
 
     @pl.when(n_ref[0] == 0)
     def _nothing_decodes():
@@ -360,7 +368,7 @@ def _flash_decode_paged_kernel(
 
     @pl.when(n_ref[0] > 0)
     def _item():
-        @pl.when(s_idx == 0)
+        @pl.when(s_idx == first)
         def _init():
             m_ref[:] = jnp.full_like(m_ref, NEG_INF)
             l_ref[:] = jnp.zeros_like(l_ref)
@@ -390,7 +398,10 @@ def _flash_decode_paged_kernel(
         # position-space; a row's last span may reach past its position:
         # those pages were fetched, and every position of theirs is masked
         key_idx = s_idx * (span * page_len) + jax.lax.broadcasted_iota(jnp.int32, (1, span * page_len), 1)
-        scores = jnp.where(key_idx <= pos_ref[b], s_ref[:] * sm_scale, NEG_INF)
+        seen = key_idx <= pos_ref[b]
+        if window is not None:  # the lower bound: a span's head may lie before the window, and a ring's page may hold a later lap
+            seen &= key_idx > pos_ref[b] - window
+        scores = jnp.where(seen, s_ref[:] * sm_scale, NEG_INF)
         m_prev = m_ref[:]                                            # (rows, 1)
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
         p = jnp.exp(scores - m_new)
@@ -426,6 +437,7 @@ def flash_decode_paged(
     sm_scale: Optional[float] = None,
     work=None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Single-query attention against a PAGED pool (docs/serving.md
     §Paged KV & prefix caching): caches are ``(num_pages, Hkv, page_len,
@@ -459,6 +471,16 @@ def flash_decode_paged(
     decode program builds it once and hands it to every layer's call
     (the layers differ in the *table*, not in the items).  Rows no item
     visits read 0.
+
+    **A window layer** (``window`` given: the query attends over its last
+    ``window`` positions, its own among them) is the same body under the
+    kernel name ``swa_decode_paged``: the work list holds only the spans
+    those positions lie in (``paged_work_list(..., window=)``), a row's
+    softmax starts at the first of them, and the mask has the lower bound
+    ``key > pos - window`` beside ``key <= pos``.  Its pages may be a
+    **ring**: the table maps *logical* pages (``inference.ring_table``),
+    so a page that holds a later lap of the ring than the span it is read
+    for is masked like any position outside the window.
 
     The page table rides the grid as a **prefetched scalar** too
     (``PrefetchScalarGridSpec``), so each program's K/V pages stream
@@ -511,7 +533,7 @@ def flash_decode_paged(
     # a position is inside the slot: the page it lies in is an item of the list
     pos_vec = jnp.clip(jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,)), 0, P * page_len - 1)
     if work is None:
-        work = paged_work_list(pos_vec, None, page_len, P, span)
+        work = paged_work_list(pos_vec, None, page_len, P, span, window)
     slot, span_idx, n, live = work
     if slot.shape[0] != B * (P // span):
         raise ValueError(f"flash_decode_paged: a work list of {slot.shape[0]} items is not one over spans of {span} pages "
@@ -540,6 +562,7 @@ def flash_decode_paged(
         group=group,
         span=span,
         lanes_hold_rows=lanes_hold_rows,
+        **({} if window is None else {"window": int(window)}),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
@@ -562,7 +585,7 @@ def flash_decode_paged(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_decode_paged",
+        name="flash_decode_paged" if window is None else "swa_decode_paged",
     )(table, pos_vec, slot, span_idx, n, *args)
     # rows no item visited hold whatever the output buffer held
     return jnp.where(live[:, None, None, None], out, 0).reshape(B, H, 1, d)

@@ -475,6 +475,139 @@ def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Option
     return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).reshape(B, H, T, d).astype(q.dtype)
 
 
+# ---------------------------------------------------------------------------
+# window layers: a per-slot ring of pages (docs/serving.md §Cache kinds, ``WindowedKV``)
+# ---------------------------------------------------------------------------
+
+def ring_pages_for(window: int, page_len: int) -> int:
+    """Pages a slot's ring holds for a layer that attends over its last
+    ``window`` positions (the query's own among them): the page the
+    newest position lies in and the ``ceil((window - 1) / page_len)``
+    before it — whatever the request's length."""
+    return -(-(int(window) - 1) // int(page_len)) + 1
+
+
+def ring_table(slot, ring_pages: int, pages_per_slot: int):
+    """The window group's page table, made in the program from nothing
+    but the rows' slots: **logical** page ``lp`` of slot ``s`` is page
+    ``1 + s * ring_pages + lp % ring_pages`` of the group (page 0 is its
+    garbage page), ``(B, pages_per_slot)`` int32.  Under it the paged
+    writes and the paged decode kernel address a ring as they address
+    pages by length; what a page holds of an earlier lap is outside the
+    window and behind the band mask."""
+    lap = jnp.arange(pages_per_slot, dtype=jnp.int32) % ring_pages
+    return 1 + jnp.asarray(slot, jnp.int32)[:, None] * ring_pages + lap[None, :]
+
+
+def ring_chunk_write(pool, layer, t, ring, pos, n_valid, ring_pages: int):
+    """A prefill chunk's rows ``t (B, Hkv, T, d)`` at **page-aligned**
+    ``pos (B,)`` into layer ``layer`` of the window group ``pool
+    (layers, pages, Hkv, page_len, d)``: of the chunk's ``T / page_len``
+    pages, those the ring keeps — the last ``ring_pages`` up to the page
+    of the row's last real token (``n_valid (B,)`` real tokens; the
+    padded tail's pages would lap over what a decode step still needs) —
+    go to their ring pages as whole-page slices, the others to the
+    group's garbage page."""
+    page_len = pool.shape[3]
+    B, H, T, d = t.shape
+    t = t.astype(pool.dtype)
+    zero, layer = jnp.int32(0), jnp.asarray(layer, jnp.int32)
+    for b in range(B):
+        first = pos[b] // page_len
+        last = (pos[b] + n_valid[b] - 1) // page_len
+        for i in range(T // page_len):
+            lp = first + i
+            keep = (lp <= last) & (lp > last - ring_pages)
+            pid = jnp.where(keep, ring[b, jnp.clip(lp, 0, ring.shape[1] - 1)], 0)
+            pool = jax.lax.dynamic_update_slice(pool, t[b, :, i * page_len:(i + 1) * page_len][None, None], (layer, pid, zero, zero, zero))
+    return pool
+
+
+def window_chunk_attention(q, k, v, k_cache, v_cache, ring, pos, window: int, sm_scale: Optional[float] = None,
+                           query_block: int = 256):
+    """A prefill chunk of a **window layer**: ``q (B, H, T, d)`` at
+    positions ``pos[b] + t`` (``pos`` page-aligned) over the band ``p -
+    window < j <= p``.  The chunk's own keys and values ``k, v (B, Hkv,
+    T, d)`` are attended **where they are** (they are not in the ring
+    yet: a ring shorter than the chunk could not hold them), in front of
+    them the ``ceil((window - 1) / page_len)`` pages before the chunk's
+    first, gathered once from the layer's ring ``k_cache, v_cache
+    (pages, Hkv, page_len, d)`` under ``ring (B, pages_per_slot)``.  The
+    walk is **banded**: query block ``i`` of ``query_block`` queries
+    meets the ``earlier + query_block`` keys from its window's first
+    page to its own last query — a static slice — under the band mask,
+    and no block outside the band is computed, whatever the context.
+    Grouped queries as :func:`paged_chunk_attention`.  Returns ``(B, H,
+    T, d)`` in ``q``'s dtype."""
+    B, H, T, d = q.shape
+    _, Hkv, page_len, _ = k_cache.shape
+    G, n_prev = H // Hkv, ring_pages_for(window, page_len) - 1
+    if sm_scale is None:
+        sm_scale = 1.0 / (d ** 0.5)
+    E = n_prev * page_len  # earlier positions in front of the chunk's own
+    with jax.named_scope("swa.chunk"):
+        lp = pos[:, None] // page_len - n_prev + jnp.arange(n_prev, dtype=jnp.int32)[None, :]  # (B, n_prev), negative before the sequence
+        pages = jnp.take_along_axis(ring, jnp.clip(lp, 0, ring.shape[1] - 1), axis=1)
+
+        # (B, Hkv, E + T, d): the window's earlier pages out of the ring, then the chunk's own rows
+        keys = jnp.concatenate([paged_gather(k_cache, pages).astype(q.dtype), k.astype(q.dtype)], axis=2)
+        vals = jnp.concatenate([paged_gather(v_cache, pages).astype(q.dtype), v.astype(q.dtype)], axis=2)
+        Tq = min(T, query_block)
+        while T % Tq:
+            Tq -= 1
+        qg = q.reshape(B, Hkv, G, T, d)
+        out = []
+        for i in range(T // Tq):
+            # the block's queries at chunk offsets i*Tq ... ; its keys at offsets i*Tq - E ... i*Tq + Tq - 1 (index i*Tq of ``keys``)
+            ks, vs = keys[:, :, i * Tq: i * Tq + E + Tq], vals[:, :, i * Tq: i * Tq + E + Tq]
+            s = jnp.einsum("bhgtd,bhsd->bhgts", qg[:, :, :, i * Tq:(i + 1) * Tq], ks, preferred_element_type=jnp.float32) * sm_scale
+            q_off = i * Tq + jnp.arange(Tq, dtype=jnp.int32)[:, None]        # relative to the chunk's first position
+            k_off = i * Tq - E + jnp.arange(E + Tq, dtype=jnp.int32)[None, :]
+            band = (k_off <= q_off) & (k_off > q_off - window)               # (Tq, E + Tq)
+            ok = band[None] & (pos[:, None, None] + k_off[None] >= 0)        # nothing lies before the sequence
+            p = jax.nn.softmax(jnp.where(ok[:, None, None], s, -1e30), axis=-1).astype(q.dtype)
+            out.append(jnp.einsum("bhgts,bhsd->bhgtd", p, vs, preferred_element_type=jnp.float32))
+        return jnp.concatenate(out, axis=3).reshape(B, H, T, d).astype(q.dtype)
+
+
+def window_cache_attention(q, k_cache, v_cache, ring, pos, window: int, sm_scale: Optional[float] = None,
+                           use_kernel: Optional[bool] = None, work=None, trace_notes: Optional[dict] = None):
+    """One query a row against a window layer's ring: ``q (B, H, 1, d)``
+    at ``pos (B,)`` (its own key already written) over positions ``pos -
+    window < j <= pos``.  The paged decode kernel under its window form
+    (``swa_decode_paged``: ``flash_decode_paged(..., window=)``, ``work``
+    its list of the window's spans) where the suite is armed and the
+    page geometry qualifies; else the ring's pages gathered and attended
+    in ``jnp`` — the numerics ground truth."""
+    B, H, _, d = q.shape
+    _, Hkv, page_len, _ = k_cache.shape
+    if use_kernel is None:
+        from deepspeed_tpu.ops import kernels as _kernels
+
+        use_kernel = _kernels.flash_decode_armed()
+    if use_kernel:
+        from deepspeed_tpu.ops.kernels.flash_decode import decode_paged_supported, flash_decode_paged, paged_tile
+
+        if decode_paged_supported(B, H, ring.shape[1], page_len, d):
+            if trace_notes is not None:
+                heads, span = paged_tile(k_cache, ring.shape[1])
+                trace_notes["swa_decode_form"] = (f"swa_decode_paged: work list of the window's spans, {heads} heads x {span} "
+                                                  f"page{'s' if span > 1 else ''}, {H // Hkv} query heads a KV head")
+            return flash_decode_paged(q, k_cache, v_cache, ring, pos, sm_scale=sm_scale, work=work, window=window)
+    if trace_notes is not None:
+        trace_notes["swa_decode_form"] = "jnp over the ring's pages (gather)"
+    R = ring_pages_for(window, page_len)
+    lp = pos[:, None] // page_len - (R - 1) + jnp.arange(R, dtype=jnp.int32)[None, :]  # (B, R): the pages the window lies in
+    pages = jnp.take_along_axis(ring, jnp.clip(lp, 0, ring.shape[1] - 1), axis=1)
+
+    k_pos = (lp[:, :, None] * page_len + jnp.arange(page_len, dtype=jnp.int32)[None, None, :]).reshape(B, R * page_len)
+    ok = (k_pos <= pos[:, None]) & (k_pos > pos[:, None] - window) & (k_pos >= 0)
+    keys, vals = (paged_gather(c, pages).astype(jnp.float32) for c in (k_cache, v_cache))  # (B, Hkv, R * page_len, d)
+    s = jnp.einsum("bhgd,bhsd->bhgs", q.reshape(B, Hkv, H // Hkv, d).astype(jnp.float32), keys) * (sm_scale or 1.0 / (d ** 0.5))
+    p = jax.nn.softmax(jnp.where(ok[:, None, None], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhgs,bhsd->bhgd", p, vals).reshape(B, H, 1, d).astype(q.dtype)
+
+
 def cache_attention(q, k_cache, v_cache, pos, sm_scale: Optional[float] = None,
                     key_padding_mask=None, use_kernel: Optional[bool] = None):
     """Attend queries (B,H,T,d) against a static cache (B,H,S,d).

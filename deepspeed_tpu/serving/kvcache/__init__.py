@@ -2,7 +2,7 @@
 copy-on-write pages, durable session KV, and hierarchical HBM → host →
 disk page tiering (docs/serving.md §Paged KV & prefix caching, §KV
 tiering)."""
-from deepspeed_tpu.serving.kvcache.pages import GARBAGE_PAGE, HybridKV, IndexedKV, LatentKV, PagedKVPool, PerHeadKV
+from deepspeed_tpu.serving.kvcache.pages import GARBAGE_PAGE, HybridKV, IndexedKV, LatentKV, PagedKVPool, PerHeadKV, WindowedKV
 from deepspeed_tpu.serving.kvcache.prefix import PrefixEntry, PrefixIndex
 from deepspeed_tpu.serving.kvcache.sessions import Session, SessionStore
 from deepspeed_tpu.serving.kvcache.tiers import PageTierManager, TierEntry
@@ -20,4 +20,5 @@ __all__ = [
     "Session",
     "SessionStore",
     "TierEntry",
+    "WindowedKV",
 ]

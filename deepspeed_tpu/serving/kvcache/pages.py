@@ -207,19 +207,93 @@ class HybridKV:
     def buffers(self, n_layer: int, num_pages: int, page_len: int):
         return self.pages.buffers(self.paged_layers, num_pages, page_len)
 
-    def state_buffers(self, num_slots: int) -> Dict[str, Any]:
+    def state_buffers(self, num_slots: int, page_len: int = 0, prefill_chunk: int = 0) -> Dict[str, Any]:
+        """The slot-axis group for a pool of ``num_slots`` (the pool's page geometry is a :class:`WindowedKV`'s to read)."""
         return {name: jnp.zeros((layers, num_slots) + shape, dt) for name, (layers, shape, dt) in self.state.items()}
+
+    def _describe_pages(self, n_layer: int, num_pages: int, page_len: int) -> str:
+        """The page kind's own description, its layers said as the paged ones of the model's."""
+        return self.pages.describe(self.paged_layers, num_pages, page_len).replace(
+            f"({self.paged_layers} layers", f"({self.paged_layers} of {n_layer} layers", 1)
 
     def describe(self, n_layer: int, num_pages: int, page_len: int) -> str:
         leaves = " + ".join(f"{name}: {layers} layers x {' x '.join(str(n) for n in shape)} {np.dtype(dt).name}"
                             for name, (layers, shape, dt) in self.state.items())
-        pages = self.pages.describe(self.paged_layers, num_pages, page_len).replace(
-            f"({self.paged_layers} layers", f"({self.paged_layers} of {n_layer} layers", 1)
-        return f"pages {pages} + state per slot ({leaves})"
+        return f"pages {self._describe_pages(n_layer, num_pages, page_len)} + state per slot ({leaves})"
 
 
-REUSE_OFF = ("off: this cache kind keeps part of a position's trace in per-slot state, so a page cannot stand for a "
-             "prefix (no prefix hits, prefix learning, session rebinds, spill or tiers)")
+class WindowedKV(HybridKV):
+    """Cache kind of a model that mixes **full-attention** layers with
+    layers that attend over a **window** of their last ``window``
+    positions (docs/serving.md §Cache kinds).  Two page groups in one
+    pool, K and V in the :class:`PerHeadKV` layout in both:
+
+    * **full** — ``pool.k`` / ``pool.v``, ``(full_layers, num_pages,
+      kv_heads, page_len, head_dim)``: pages **by length** under the
+      pool's page table, as every paged kind has them;
+    * **window** — ``pool.state["wk"]`` / ``["wv"]``, ``(window_layers, 1
+      + slots * ring_pages, kv_heads, page_len, head_dim)``: **a ring of
+      ``ring_pages`` pages a slot**, ``ring_pages = ceil((window - 1) /
+      page_len) + 1`` — the page the newest position lies in and those
+      the window reaches back over — **whatever the request's length and
+      whatever ``max_len``**.  Page 0 is the group's garbage page; slot
+      ``s`` owns pages ``1 + s * ring_pages ...`` for as long as it owns
+      the slot, so the group's table is arithmetic on the slot
+      (``ops/transformer/inference.py::ring_table``: logical page ``lp``
+      lies in ring page ``lp % ring_pages``) and no program input.
+
+    The window group rides the slot-axis group (``pool.state``): it is
+    claimed with the slot and returned with it, it is donated through
+    both programs beside the pages, and the engine, the scheduler and
+    the staging never learn that it is there.  It is partitioned by slot
+    rather than allocated because every live slot needs exactly
+    ``ring_pages`` and there are exactly ``num_slots`` of them: an
+    allocator would have nothing to decide.  What :meth:`alloc_request`
+    charges a request is therefore the full group's pages by its length
+    (it waits for those alone) and, with its slot, the ring — ``stats()
+    ["groups"]`` tells the two apart.
+
+    A full layer's page does **not** hold everything its positions left
+    behind: the window layers' trace of a prefix is gone once the ring
+    has lapped over it.  ``pages_hold_all`` is False and prefix reuse is
+    off, said so (:data:`REUSE_OFF`)."""
+
+    def __init__(self, full_layers: int, window_layers: int, kv_heads: int, head_dim: int, window: int, dtype: Any):
+        super().__init__(full_layers, PerHeadKV(kv_heads, head_dim, dtype), {})
+        self.window_layers, self.window = int(window_layers), int(window)
+
+    def ring_pages(self, page_len: int) -> int:
+        from deepspeed_tpu.ops.transformer.inference import ring_pages_for
+
+        return ring_pages_for(self.window, page_len)
+
+    def state_buffers(self, num_slots: int, page_len: int = 0, prefill_chunk: int = 0) -> Dict[str, Any]:
+        from deepspeed_tpu.ops.transformer.inference import init_kv_cache
+
+        if prefill_chunk > 1 and prefill_chunk % page_len:
+            raise SlotPoolError(f"WindowedKV: prefill_chunk={prefill_chunk} must be whole pages of {page_len} (a chunk's rows go into the ring page by page)")
+        wk, wv = init_kv_cache(self.window_layers, 1 + num_slots * self.ring_pages(page_len), self.pages.heads, page_len, self.pages.head_dim, self.dtype)
+        return {"wk": wk, "wv": wv}
+
+    def groups(self, pool) -> Dict[str, Dict[str, Any]]:
+        """Each group's layers, what a slot holds of it and its bytes over the pool (``pool.stats()["groups"]``)."""
+        ring = self.ring_pages(pool.page_len)
+        return {
+            "full": {"layers": self.paged_layers, "pages_per_slot": "by length, up to %d" % pool.pages_per_slot,
+                     "positions_per_slot": "by length, up to %d" % pool.max_len, "bytes": pool.cache_bytes() - pool.state_bytes()},
+            "window": {"layers": self.window_layers, "window": self.window, "pages_per_slot": ring,
+                       "positions_per_slot": ring * pool.page_len, "slots_live": pool.live_slots, "bytes": pool.state_bytes()},
+        }
+
+    def describe(self, n_layer: int, num_pages: int, page_len: int) -> str:
+        return (f"full-attention pages by length {self._describe_pages(n_layer, num_pages, page_len)} + window ring per slot "
+                f"({self.window_layers} layers x {self.ring_pages(page_len)} pages x {self.pages.heads} heads x {page_len} page_len x "
+                f"{self.pages.head_dim} head_dim, K and V, window {self.window})")
+
+
+REUSE_OFF = ("off: this cache kind keeps part of a position's trace in per-slot state (a recurrent state, or a ring of "
+             "window layers' pages that laps over a prefix), so a page cannot stand for a prefix (no prefix hits, prefix "
+             "learning, session rebinds, spill or tiers)")
 
 
 def _named_leaves(k, v) -> Dict[str, Any]:
@@ -297,7 +371,7 @@ class PagedKVPool:
         self.k, self.v = self.kind.buffers(n_layer, self.num_pages, self.page_len)
         # the slot-axis group (HybridKV): None for the kinds that are pages and nothing else
         make_state = getattr(self.kind, "state_buffers", None)
-        self.state = make_state(self.num_slots) if make_state is not None else None
+        self.state = make_state(self.num_slots, self.page_len, self.prefill_chunk) if make_state is not None else None
         # whether a page may stand for a prefix (shared, learned, parked, spilled, tiered)
         self.reuse = bool(getattr(self.kind, "pages_hold_all", True))
         if not self.reuse and (spill_dir or len(list(pinned_prefixes))):
@@ -1095,6 +1169,8 @@ class PagedKVPool:
             # the leaves the family declared, by name: bytes over all slots
             out["state_leaves"] = {name: int(buf.size * buf.dtype.itemsize) for name, buf in self.state.items()}
             out["page_kind"] = type(self.kind.pages).__name__
+        if hasattr(self.kind, "groups"):  # two page groups in one pool: each one's layers, pages or positions a slot, bytes
+            out["groups"] = self.kind.groups(self)
         if self.state is not None or getattr(self.kind, "names_page_leaves", False):
             # a kind whose pages carry more than K and V, or stand beside a state: each leaf's bytes over the pool, by its last name
             out["kind"] = self.kind.describe(self.n_layer, self.num_pages, self.page_len)

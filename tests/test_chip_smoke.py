@@ -135,6 +135,7 @@ def v5e_chip():
     ("gpt2_xl", 16, 8, (129, 25, 128, 64), 25, (25, 1)),          # 25 heads a program, (d, page_len) tiles
     ("solar_open2", 160, 64, (2561, 8, 128, 128), 64, (8, 2)),    # 8 KV heads x 2 pages: 16 operands' worth a step
     ("zaya1", 64, 64, (2049, 2, 128, 128), 8, (2, 8)),            # 2 KV heads x 8 pages, each page an operand of its own
+    ("laguna_full", 24, 168, (2305, 8, 128, 128), 48, (8, 2)),    # 6 query heads a KV head: a 48-row tile
 ])
 def test_flash_decode_paged_compiles_for_v5e_under_the_tile_of_each_serve_cell(cell, slots, pages_per_slot, pool, heads, tile, v5e_chip):
     """The paged decode kernel at the serve cells' own shapes, by the
@@ -371,6 +372,59 @@ def test_gigachat35_steps_compile_for_v5e_with_latent_pages_and_state_updated_in
     cache_bytes = int(np.prod(pool.shape)) * 2 + sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(state))
     assert cache_bytes > 2.8e9 and m.alias_size_in_bytes >= cache_bytes   # the latent pool and both state leaves come back in place
     assert m.temp_size_in_bytes < int(np.prod(pool.shape)) * 2            # and no temporary is as large as the latent pool (1.2 GB)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_laguna_steps_compile_for_v5e_with_both_page_groups_updated_in_place(which, v5e_chip, monkeypatch):
+    """The cell's twelve layers of Laguna-S-2.1 at the published widths on
+    its two page groups (24 slots of 168 pages: 2,305 pages of 3 full
+    layers, a ring of 5 pages a slot of 9 window layers), by the chip's
+    compiler without the chip: a decode step holds ``flash_decode_paged``
+    once a full layer (6 query heads a KV head), ``swa_decode_paged`` once
+    a window layer (9 a KV head: Mosaic takes the 72-row tile as it is,
+    neither padded nor re-laid) and the experts' kernel twice a sparse
+    layer; a chunk of 1,024 holds the experts' kernel; neither leaves a
+    copy of a group in the program."""
+    from deepspeed_tpu.models import laguna as lg
+    from deepspeed_tpu.ops.kernels import flash_decode, grouped_matmul
+
+    monkeypatch.setenv("DS_KERNELS", "1")
+    for mod in (flash_decode, grouped_matmul):
+        monkeypatch.setattr(mod, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
+    slots, pages, chunk, per_slot = 24, 2305, 1024, 168
+    cfg = lg.LagunaConfig.from_hf({}, num_hidden_layers=12, experts_held=(0, 32), vocab_held=12544)
+    on_chip = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    params = jax.tree.map(lambda sh: on_chip(sh, jnp.bfloat16), lg.param_shapes(cfg), is_leaf=lambda sh: isinstance(sh, tuple))
+    kind = lg.cache_kind(cfg, jnp.bfloat16)
+    k, v = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(lambda: kind.buffers(12, pages, 128)))
+    state = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(lambda: kind.state_buffers(slots, 128, chunk)))
+    assert k.shape == (3, pages, 8, 128, 128) and state["wk"].shape == (9, 1 + slots * 5, 8, 128, 128)  # 640 positions a slot, whatever max_len
+    notes = {}
+    if which == "decode":
+        def step(p, t, pos, table, wm, k, v, st):
+            return lg.forward_with_cache(p, t[:, None], k, v, st, pos, cfg, table, write_mask=wm, row_valid=wm[:, None], trace_notes=notes)
+        args = (params, on_chip((slots,), jnp.int32), on_chip((slots,), jnp.int32), on_chip((slots, per_slot), jnp.int32),
+                on_chip((slots,), jnp.bool_), k, v, state)
+    else:
+        def step(p, t, table, slot, pos, rv, k, v, st):
+            return lg.forward_with_cache(p, t, k, v, st, pos[None], cfg, table[None], slot=slot[None], row_valid=rv, trace_notes=notes)
+        args = (params, on_chip((1, chunk), jnp.int32), on_chip((per_slot,), jnp.int32), on_chip((), jnp.int32), on_chip((), jnp.int32),
+                on_chip((1, chunk), jnp.bool_), k, v, state)
+    compiled = jax.jit(step, donate_argnums=(len(args) - 3, len(args) - 2, len(args) - 1)).lower(*args).compile()
+    found = chip_smoke.mosaic_kernels(compiled.as_text())
+    if which == "decode":
+        assert found == {"flash_decode_paged": 3, "swa_decode_paged": 9, "moe_grouped_matmul": 22}
+        assert notes["swa_decode_form"].startswith("swa_decode_paged") and "9 query heads a KV head" in notes["swa_decode_form"]
+        assert notes["paged_decode_walk"] == "work list, 8 heads x 2 pages" and notes["moe_grouped_kernel"] == "240"
+    else:
+        assert found == {"moe_grouped_matmul": 22} and notes["moe_grouped_kernel"] == "10240"
+        assert notes["swa_chunk_form"].startswith("banded jnp") and notes["gqa_prefill_form"].startswith("blockwise jnp")
+    assert notes["moe_grouped_fallback"] == "" and notes["swa_ring_positions"] == 640
+    m = compiled.memory_analysis()
+    cache_bytes = sum(int(np.prod(a.shape)) * 2 for a in (k, v, *jax.tree.leaves(state)))
+    assert 4.1e9 < cache_bytes < 4.3e9 and m.alias_size_in_bytes >= cache_bytes  # both groups come back in place
+    assert m.temp_size_in_bytes < int(np.prod(state["wk"].shape)) * 2 * 2         # and no temporary is as large as the window group (0.57 GB)
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14.0e9               # weights 8.65 GB + caches 4.2 GB + the step's own
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])

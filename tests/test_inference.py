@@ -434,3 +434,18 @@ def test_int8_kv_cache_bytes_halved():
     b_bf = kb.size * kb.dtype.itemsize
     b_q = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(kq))
     assert b_q < 0.6 * b_bf, (b_q, b_bf)
+
+
+@pytest.mark.parametrize("layers,window,refused", [(["global", "local"], 16, True), (["global", "local"], 64, False), (["global", "global"], 16, False)])
+def test_gptneo_policy_refuses_a_local_layer_whose_window_is_shorter_than_the_context(layers, window, refused):
+    """A local-attention layer is not full attention: the policy raises by
+    name instead of computing another model's mathematics (ROADMAP D18);
+    a window that covers the context is full attention and goes on (here
+    to the state dict it was not given)."""
+    import types
+
+    from deepspeed_tpu.inference.injection import HFGPTNEOLayerPolicy
+
+    hf = types.SimpleNamespace(attention_layers=layers, window_size=window, max_position_embeddings=64)
+    with pytest.raises(NotImplementedError if refused else (AttributeError, TypeError, KeyError), match="local window of 16" if refused else None):
+        HFGPTNEOLayerPolicy.convert(None, hf_config=hf)

@@ -1661,3 +1661,88 @@ def test_indexed_kind_third_leaf_is_written_shared_copied_on_write_freed_and_reu
     assert dst in pool._slot_pages[r2.slot] or pool.pages_free < free0 + 2  # freed pages go out again
     pool.retire(r2.slot, r2)
     _assert_no_leaks(pool)
+
+
+# ---------------------------------------------------------------------------
+# two page groups in one pool: pages by length + a ring of pages a slot (WindowedKV)
+# ---------------------------------------------------------------------------
+
+def _windowed_pool(num_pages=41, max_len=256, **kw):
+    from deepspeed_tpu.serving.kvcache.pages import WindowedKV
+
+    kind = WindowedKV(full_layers=2, window_layers=3, kv_heads=2, head_dim=8, window=40, dtype=jnp.float32)
+    return PagedKVPool(5, 3, 0, max_len, 0, jnp.float32, page_len=16, num_pages=num_pages, prefill_chunk=32, kind=kind, **kw)
+
+
+def test_windowed_kind_has_pages_by_length_for_its_full_layers_and_a_ring_a_slot_for_its_window_layers():
+    pool = _windowed_pool()
+    assert pool.k.shape == pool.v.shape == (2, 41, 2, 16, 8)  # the two full layers of the five
+    # window 40 on pages of 16: the page of the newest position and the three the window reaches back over, + the garbage page
+    assert pool.kind.ring_pages(16) == 4 and pool.state["wk"].shape == pool.state["wv"].shape == (3, 1 + 3 * 4, 2, 16, 8)
+    full_bytes, window_bytes = 2 * 2 * 41 * 2 * 16 * 8 * 4, 2 * 3 * 13 * 2 * 16 * 8 * 4
+    assert pool.state_bytes() == window_bytes and pool.cache_bytes() == full_bytes + window_bytes
+    st = pool.stats()
+    g = st["groups"]
+    assert g["full"] == {"layers": 2, "pages_per_slot": "by length, up to 16", "positions_per_slot": "by length, up to 256", "bytes": full_bytes}
+    assert g["window"] == {"layers": 3, "window": 40, "pages_per_slot": 4, "positions_per_slot": 64, "slots_live": 0, "bytes": window_bytes}
+    assert st["page_kind"] == "PerHeadKV" and st["state_leaves"] == {"wk": window_bytes // 2, "wv": window_bytes // 2}
+    math = pool.shape_math()
+    assert "full-attention pages by length 2 x (2 of 5 layers x 41 pages" in math and "window ring per slot (3 layers x 4 pages" in math
+    assert st["kind"] == pool.kind.describe(5, 41, 16) and "window 40" in st["kind"]
+    assert not pool.reuse and pool.kind.pages_hold_all is False
+
+
+@pytest.mark.parametrize("max_len", [64, 256, 4096])
+def test_a_window_layers_cache_a_slot_does_not_grow_with_the_request_or_with_max_len(max_len):
+    pool = _windowed_pool(num_pages=1 + 3 * (max_len // 16), max_len=max_len)
+    want = pool.stats()["groups"]["window"]
+    assert (want["pages_per_slot"], want["positions_per_slot"]) == (4, 64)  # sliding_window and page_len alone
+    for n in (3, 40, max_len - 8):
+        r = _KReq(n, np.arange(1, n + 1, dtype=np.int32), max_new=8)
+        r.slot = pool.alloc_request(r)
+        g = pool.stats()["groups"]
+        assert pool.pages_live == -(-(n + 8) // 16)  # the full group: pages by the request's length
+        assert {k: v for k, v in g["window"].items() if k != "slots_live"} == {k: v for k, v in want.items() if k != "slots_live"}
+        assert g["window"]["slots_live"] == 1 and pool.state["wk"].shape[1] == 1 + 3 * 4
+        pool.retire(r.slot, r)  # both groups go back: the pages to the free list, the ring with the slot
+        assert pool.pages_live == 0 and pool.free_slots == 3 and pool.stats()["groups"]["window"]["slots_live"] == 0
+    _assert_no_leaks(pool)
+
+
+def test_a_long_request_waits_for_full_pages_only_and_reuse_is_off_and_says_why(tmp_path):
+    from deepspeed_tpu.serving.kvcache.pages import REUSE_OFF
+
+    pool = _windowed_pool(num_pages=21)  # 20 usable pages: one slot's 16 and a few
+    long = _KReq(1, np.arange(1, 241, dtype=np.int32), max_new=8, sid="agent", generated=[5, 6, 7, 8], finish_reason="length")
+    long.slot = pool.alloc_request(long)
+    assert pool.pages_live == 16 and pool.free_slots == 2
+    waits = _KReq(2, np.arange(1, 101, dtype=np.int32), max_new=8)  # 7 pages of the 4 that are left: it waits, slots and rings to spare
+    assert pool.alloc_request(waits) is None and pool.stats()["alloc_waits"] == 1 and pool.free_slots == 2
+    short = _KReq(3, np.arange(1, 41, dtype=np.int32), max_new=8)  # 3 pages: it fits beside the long one
+    short.slot = pool.alloc_request(short)
+    assert short.slot is not None and pool.stats()["groups"]["window"]["slots_live"] == 2
+    pool.learn_prefix(long)
+    assert len(pool.index) == 0  # nothing is learned: a full layer's page does not hold what the window layers' rings lapped over
+    pool.retire(long.slot, long)
+    assert pool.sessions.peek("agent") is None  # nothing is parked
+    waits.slot = pool.alloc_request(waits)
+    assert waits.slot is not None and waits.prefill_pos == 0
+    st = pool.stats()
+    assert st["reuse"] == REUSE_OFF and "ring" in REUSE_OFF and (st["prefix_hits"], st["session_rebinds"], st["cow_copies"]) == (0, 0, 0)
+    for r in (short, waits):
+        pool.retire(r.slot, r)
+    _assert_no_leaks(pool)
+    with pytest.raises(SlotPoolError, match="prefix reuse is off"):
+        _windowed_pool(spill_dir=str(tmp_path))
+    with pytest.raises(SlotPoolError, match="prefix reuse is off"):
+        _windowed_pool(pinned_prefixes=[[1, 2, 3]])
+    with pytest.raises(SlotPoolError, match="prefix reuse is off"):
+        pool.attach_tiers(object())
+
+
+def test_a_chunk_that_is_not_whole_pages_is_refused_by_the_windowed_kind():
+    from deepspeed_tpu.serving.kvcache.pages import WindowedKV
+
+    kind = WindowedKV(1, 1, 2, 8, 40, jnp.float32)
+    with pytest.raises(SlotPoolError, match="whole pages"):
+        PagedKVPool(2, 2, 0, 96, 0, jnp.float32, page_len=16, num_pages=13, prefill_chunk=24, kind=kind)

@@ -533,3 +533,61 @@ def test_sampling_validation():
         srv.submit(p, max_new_tokens=2, do_sample=True, temperature=0.0)
     with pytest.raises(DeepSpeedConfigError, match="max_top_k"):
         ServingEngine(eng, num_slots=1, prefill_chunk=8, max_len=32, max_top_k=0)
+
+
+# ---------------------------------------------------------------------------
+# a family on two page groups (window + full attention): Laguna behind the one seam
+# ---------------------------------------------------------------------------
+
+def _laguna_served(**kw):
+    import deepspeed_tpu
+    from deepspeed_tpu.models import laguna
+
+    inf = deepspeed_tpu.init_inference(model_config=laguna.LAGUNA_TINY, dtype=jnp.float32, max_out_tokens=96)
+    srv = ServingEngine(inf, config={"num_slots": 3, "max_len": 96, "prefill_chunk": 16, "kvcache": {"enabled": True, "page_len": 4}, **kw})
+    rng = np.random.default_rng(0)
+    rids = [srv.submit(rng.integers(1, 256, n, dtype=np.int32), max_new_tokens=m) for n, m in ((37, 9), (5, 12), (50, 6), (20, 20), (33, 5))]
+    done = srv.drain(max_steps=500)
+    return [list(done[r].generated) for r in rids], srv
+
+
+def test_the_overlapped_step_and_the_serial_step_give_the_same_tokens_on_two_page_groups():
+    serial, _ = _laguna_served(overlap_chunks=False)
+    overlapped, srv = _laguna_served()
+    assert overlapped == serial and (srv.prefill_compiles, srv.decode_compiles) == (1, 1)
+    st = srv.stats()
+    # the forms the two programs took, and the ring: window 8 on pages of 4 is 3 pages = 12 positions a slot, under a chunk of 16
+    assert st["swa_ring_positions"] == 12 and st["swa_decode_form"].startswith("jnp over the ring") and st["swa_chunk_form"].startswith("banded jnp")
+    g = st["kvcache"]["groups"]
+    assert (g["full"]["layers"], g["window"]["layers"], g["window"]["pages_per_slot"], g["window"]["slots_live"]) == (2, 3, 3, 0)
+    assert g["full"]["bytes"] + g["window"]["bytes"] == srv.pool.cache_bytes() == st["pool_bytes"]
+    assert st["kvcache"]["reuse"].startswith("off:") and st["hybrid"]["state_resets_in_program"] == 5
+    assert st["moe"]["dropped_assignments"] == 0 and st["chunks_deferred"] > 0
+    # what the newest decode step's routers chose stays on the device for a check of the served program
+    kept = srv.decode_kept
+    assert kept["experts"].shape == kept["router_logits"].shape == (4, 3, 4) and kept["router_logits"].dtype == jnp.float32
+
+
+def test_the_windowed_kind_lives_in_the_paged_pool_only_and_has_no_int8_form():
+    import deepspeed_tpu
+    from deepspeed_tpu.models import laguna
+
+    inf = deepspeed_tpu.init_inference(model_config=laguna.LAGUNA_TINY, dtype=jnp.float32, max_out_tokens=96)
+    with pytest.raises(ValueError, match="paged pool only"):
+        ServingEngine(inf, config={"num_slots": 2, "max_len": 96, "prefill_chunk": 16})
+    with pytest.raises(ValueError, match="no int8 form"):
+        ServingEngine(inf, config={"num_slots": 2, "max_len": 96, "prefill_chunk": 16, "kv_cache_dtype": "int8",
+                                   "kvcache": {"enabled": True, "page_len": 4}})
+    with pytest.raises(Exception, match="whole pages"):
+        ServingEngine(inf, config={"num_slots": 2, "max_len": 96, "prefill_chunk": 6, "kvcache": {"enabled": True, "page_len": 4}})
+
+
+def test_the_engine_the_scheduler_and_the_staging_do_not_know_the_new_family_or_its_cache_kind():
+    import os
+
+    import deepspeed_tpu.serving as serving
+
+    root = os.path.dirname(serving.__file__)
+    for name in ("engine.py", "scheduler.py", "staging.py"):
+        text = open(os.path.join(root, name)).read().lower()
+        assert "laguna" not in text and "windowedkv" not in text and "ring_table" not in text, name
