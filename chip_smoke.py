@@ -36,7 +36,12 @@ weights made from a seed:
   on the paged pool (8 slots, ``page_len`` 128), a bf16 then an int8 KV
   pool, eight seeded greedy requests each;
 * every Pallas kernel those paths arm, once, against the repo's own
-  lax/XLA ground truth at the same shapes.
+  lax/XLA ground truth at the same shapes — ``flash_chunk_paged``, a
+  prefill chunk's attention over its pages, at Keye's chunk (32 / 4
+  heads, 2,048 queries, under a top-2,048 selection and without) and
+  Laguna's (48 / 8, 1,024); it must stand in the prefill programs of the
+  Solar-Open2, Keye and Laguna cases and be absent from GPT-2's, whose
+  heads are narrower than the lanes (``stats()`` say why).
 
 It fails (non-zero exit, no result line) when JAX finds no TPU, when a
 phase raises, or when a check does not hold; no phase is wrapped in
@@ -548,9 +553,14 @@ def serve(s: Smoke, device) -> Dict[str, Any]:
         decode = srv.compiled_step("decode")
         decode_hlo = decode.as_text()
         expect_kernels(mosaic_kernels(decode_hlo), ["flash_decode_paged", "paged_kv_write"] if s.mosaic else [], f"serve[{kv}] decode")
-        form = srv.stats()["kv_write_form"]
+        stats = srv.stats()
+        form = stats["kv_write_form"]
         check(form == inference.KV_WRITE_FORMS[s.mosaic], f"serve[{kv}]: kv_write_form reads {form!r}")
+        # a head of 64 lies with its positions in the lanes: flash_chunk_paged leaves GPT-2's chunk to the jnp form, and says so
         expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()), [], f"serve[{kv}] prefill")
+        check(stats["chunk_attention_kernel"] is False
+              and stats["chunk_attention_fallback"].startswith(("int8 pool" if kv == "int8" else "head dim") if s.mosaic else "kernel suite not armed"),
+              f"serve[{kv}]: stats() say of the chunk's attention: kernel {stats['chunk_attention_kernel']}, {stats['chunk_attention_fallback']!r}")
         # the pool is written in place in the one layout the kernel
         # reads: the decode program hands all of it back aliased and
         # keeps less than one layer's K+V (and the tied head's weights,
@@ -711,7 +721,9 @@ def serve_solar_open2(s: Smoke, device) -> Dict[str, float]:
     expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
                    ["kda_decode", "flash_decode_paged", "moe_grouped_matmul"] if s.mosaic else [], "serve[solar2] decode")
     expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()),
-                   ["moe_grouped_matmul"] if s.mosaic else [], "serve[solar2] prefill")
+                   ["flash_chunk_paged", "moe_grouped_matmul"] if s.mosaic else [], "serve[solar2] prefill")
+    check(stats["chunk_attention_kernel"] is bool(s.mosaic) and stats["gqa_prefill_form"].startswith("flash_chunk_paged" if s.mosaic else "blockwise jnp"),
+          f"serve[solar2]: stats() say of the chunk's attention: {stats['gqa_prefill_form']!r}")
     check(stats["kda_decode_kernel"] is bool(s.mosaic) and stats["gqa_decode_kernel"] is bool(s.mosaic),
           f"serve[solar2]: stats() say of the decode program: kda_decode_kernel {stats['kda_decode_kernel']} "
           f"({stats['kda_decode_fallback']!r}), gqa_decode_kernel {stats['gqa_decode_kernel']} ({stats['gqa_decode_fallback']!r})")
@@ -777,7 +789,9 @@ def serve_keye(s: Smoke, device) -> Dict[str, float]:
     expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
                    ["dsa_index_scores_paged", "dsa_select_threshold", "dsa_sparse_decode", "moe_grouped_matmul"] if s.mosaic else [], "serve[keye] decode")
     expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()),
-                   ["dsa_select_threshold", "moe_grouped_matmul"] if s.mosaic else [], "serve[keye] prefill")
+                   ["dsa_select_threshold", "flash_chunk_paged", "moe_grouped_matmul"] if s.mosaic else [], "serve[keye] prefill")
+    check(stats["chunk_attention_kernel"] is bool(s.mosaic) and ("flash_chunk_paged" in stats["dsa_prefill_form"]) is bool(s.mosaic),
+          f"serve[keye]: stats() say of the chunk's attention: {stats['dsa_prefill_form']!r}")
     check(stats["dsa_decode_kernel"].startswith("dsa_sparse_decode" if s.mosaic else "lax"),
           f"serve[keye]: stats() say of the decode program: dsa_decode_kernel {stats['dsa_decode_kernel']!r}, "
           f"dsa_index_form {stats['dsa_index_form']!r}")
@@ -908,6 +922,37 @@ def check_swa_decode_paged(s: Smoke) -> float:
     return err
 
 
+def check_flash_chunk_paged(s: Smoke) -> Dict[str, float]:
+    """``flash_chunk_paged`` against the ``jnp`` form of
+    ``paged_chunk_attention`` at the two cells it leads: Keye's chunk (32
+    query heads on 4 KV heads of 128, 2,048 queries ending a context of
+    16,384 under a top-2,048 selection, and without it) and Laguna's full
+    layers' (48 on 8, 1,024 queries ending 6,144), pages of 128 in
+    shuffled order."""
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    errs = {}
+    for name, H, Hkv, T, P, context, topk in (("keye", 32, 4, 2048, 264, 16384, 2048), ("keye, no selection", 32, 4, 2048, 264, 16384, 0),
+                                              ("laguna", 48, 8, 1024, 168, 6144, 0)):
+        rng = np.random.default_rng(s.seed)
+        k, v = (jnp.asarray(rng.standard_normal((1 + P, Hkv, 128, 128)), jnp.bfloat16) for _ in range(2))
+        q = jnp.asarray(rng.standard_normal((1, H, T, 128)), jnp.bfloat16)
+        table = jnp.asarray(1 + rng.permutation(P)[None], jnp.int32)
+        pos = jnp.asarray([context - T], jnp.int32)
+        mask = None
+        if topk:  # each query's top-k of random scores over what it reaches, as the indexer's selection leaves it
+            reach = jnp.arange(P * 128)[None, None, :] <= (pos[:, None] + jnp.arange(T)[None, :])[:, :, None]
+            scores = jnp.where(reach, jnp.asarray(rng.standard_normal((1, T, P * 128)), jnp.float32), -jnp.inf)
+            mask = (scores >= jax.lax.top_k(scores, topk)[0][..., -1:]) & reach
+        got, want = (jax.jit(lambda *a, use=use: inf.paged_chunk_attention(*a, extra_mask=mask, use_kernel=use))(q, k, v, table, pos)
+                     for use in (True, False))
+        errs[name] = _max_err(got, want)
+        check(errs[name] <= TOL_BF16, f"flash_chunk_paged at {H} / {Hkv} heads, {T} queries ending {context}"
+              f"{f', top-{topk} selected' if topk else ''}: max error {errs[name]:.4f} against the jnp form (tolerance {TOL_BF16})")
+    say("kernel flash_chunk_paged: max error " + ", ".join(f"{e:.4f} ({name})" for name, e in errs.items()))
+    return errs
+
+
 def serve_laguna(s: Smoke, device) -> Dict[str, float]:
     """``init_inference(model_config=LagunaConfig)`` → the same
     ``ServingEngine`` on **two page groups in one pool**: compiles both
@@ -955,7 +1000,9 @@ def serve_laguna(s: Smoke, device) -> Dict[str, float]:
     expect_kernels(mosaic_kernels(srv.compiled_step("decode").as_text()),
                    ["flash_decode_paged", "swa_decode_paged", "moe_grouped_matmul"] if s.mosaic else [], "serve[laguna] decode")
     expect_kernels(mosaic_kernels(srv.compiled_step("prefill").as_text()),
-                   ["moe_grouped_matmul"] if s.mosaic else [], "serve[laguna] prefill")
+                   ["flash_chunk_paged", "moe_grouped_matmul"] if s.mosaic else [], "serve[laguna] prefill")
+    check(stats["chunk_attention_kernel"] is bool(s.mosaic) and stats["gqa_prefill_form"].startswith("flash_chunk_paged" if s.mosaic else "blockwise jnp"),
+          f"serve[laguna]: stats() say of the full layers' chunk: {stats['gqa_prefill_form']!r}")
     check(stats["swa_decode_form"].startswith("swa_decode_paged" if s.mosaic else "jnp over the ring") and stats["swa_chunk_form"].startswith("banded jnp"),
           f"serve[laguna]: stats() say of the window layers: decode {stats['swa_decode_form']!r}, chunk {stats['swa_chunk_form']!r}")
     say(f"serve[laguna]: 3 requests x 8 tokens through both page groups, token gap mean {gaps['token_gap_mean']:.5f} "
@@ -969,6 +1016,7 @@ def run(s: Smoke, devices: Sequence) -> None:
         check_flash_attention(s)
         check_fused_update(s)
         check_swa_decode_paged(s)
+        check_flash_chunk_paged(s)
     result = train(s, devices[:1])
     if len(devices) > 1:
         # the same 16 sequences on one chip (above) and on all of them

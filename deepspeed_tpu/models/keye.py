@@ -259,7 +259,8 @@ def serving_forward(cfg: KeyeConfig):
     kind has no per-slot state (``state`` passes through, None).
     ``fwd.trace_notes`` holds the forms the two programs compiled:
     ``dsa_index_form``, ``dsa_prefill_index_form``, ``dsa_select_form``,
-    ``dsa_decode_kernel``, ``dsa_prefill_form``, ``moe_router_form``,
+    ``dsa_decode_kernel``, ``dsa_prefill_form`` (from
+    ``chunk_attention_kernel`` / ``_fallback``), ``moe_router_form``,
     ``moe_grouped_kernel`` / ``_fallback``.
 
     ``fwd.decode_keeps``: the decode program hands back, beside its

@@ -340,9 +340,9 @@ def attention_block(cfg: LagunaConfig, lp: Dict[str, Any], x, layer: int, caches
         elif T == 1:
             attn = inf.paged_cache_attention(q, kc, vc, table, pos, use_kernel=armed, work=work, trace_notes=trace_notes)
         else:
+            attn = inf.paged_chunk_attention(q, kc, vc, table, pos, use_kernel=armed, trace_notes=trace_notes)
             if trace_notes is not None:
-                trace_notes["gqa_prefill_form"] = "blockwise jnp (paged_chunk_attention)"
-            attn = inf.paged_chunk_attention(q, kc, vc, table, pos)
+                trace_notes["gqa_prefill_form"] = inf.chunk_attention_note(trace_notes)
     gate = jax.nn.sigmoid(h @ lp["gate"])  # (B, T, H): one scalar a head and position
     attn = attn.transpose(0, 2, 1, 3) * gate[..., None].astype(attn.dtype)
     return x + attn.reshape(B, T, H * hd) @ lp["o"], k_pool, v_pool
@@ -434,7 +434,8 @@ def serving_forward(cfg: LagunaConfig):
     rows are the slots); ``state`` is the window group.
     ``fwd.trace_notes`` holds the forms the two programs compiled:
     ``swa_decode_form``, ``swa_chunk_form``, ``swa_ring_positions``,
-    ``paged_decode_walk``, ``gqa_prefill_form``, ``moe_router_form``,
+    ``paged_decode_walk``, ``gqa_prefill_form`` (from
+    ``chunk_attention_kernel`` / ``_fallback``), ``moe_router_form``,
     ``moe_grouped_kernel`` / ``_fallback``.
 
     ``fwd.decode_keeps``: the decode program hands back, beside its

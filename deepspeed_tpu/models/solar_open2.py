@@ -265,9 +265,9 @@ def gqa_block(cfg: SolarOpen2Config, lp: Dict[str, Any], x, k_pool, v_pool, page
             trace_notes.update(gqa_decode_kernel=not why_not, gqa_decode_fallback=why_not)
         attn = inf.paged_cache_attention(q, kc, vc, page_table, pos, use_kernel=armed, work=work, trace_notes=trace_notes)
     else:
+        attn = inf.paged_chunk_attention(q, kc, vc, page_table, pos, use_kernel=use_kernel, trace_notes=trace_notes)
         if trace_notes is not None:
-            trace_notes["gqa_prefill_form"] = "blockwise jnp (paged_chunk_attention)"
-        attn = inf.paged_chunk_attention(q, kc, vc, page_table, pos)
+            trace_notes["gqa_prefill_form"] = inf.chunk_attention_note(trace_notes)
     attn = attn.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
     return x + (jax.nn.sigmoid(h @ lp["gate"]) * attn) @ lp["o"], k_pool, v_pool
 
@@ -391,7 +391,8 @@ def serving_forward(cfg: SolarOpen2Config):
     rows are the slots).  ``fwd.trace_notes`` holds the forms the two
     programs compiled: ``kda_decode_kernel`` / ``_fallback``,
     ``kda_prefill_form``, ``gqa_decode_kernel`` / ``_fallback``,
-    ``paged_decode_walk``, ``gqa_prefill_form``, ``moe_grouped_kernel``
+    ``paged_decode_walk``, ``gqa_prefill_form`` (from
+    ``chunk_attention_kernel`` / ``_fallback``), ``moe_grouped_kernel``
     / ``_fallback``."""
     notes: Dict[str, Any] = {}
 

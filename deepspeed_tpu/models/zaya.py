@@ -287,7 +287,8 @@ def serving_forward(cfg: ZayaConfig):
     ``slot`` is the prefill chunk's slot (a decode step passes None: its
     rows are the slots).  ``fwd.trace_notes`` holds the forms the two
     programs compiled: ``cca_decode_kernel`` / ``_fallback``,
-    ``paged_decode_walk``, ``cca_prefill_form``, ``moe_router_form``,
+    ``paged_decode_walk``, ``cca_prefill_form`` (from
+    ``chunk_attention_kernel`` / ``_fallback``), ``moe_router_form``,
     ``moe_grouped_kernel`` / ``_fallback``."""
     notes: Dict[str, Any] = {}
 

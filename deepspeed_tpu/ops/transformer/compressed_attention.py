@@ -210,7 +210,7 @@ def attention(sz: Sizes, w_qkv, w_conv0, w_conv1, tau, h, k_pool, v_pool, state:
                 trace_notes.update(cca_decode_kernel=not why_not, cca_decode_fallback=why_not)
             o = inf.paged_cache_attention(q, kc, vc, table, pos, use_kernel=armed, work=work, trace_notes=trace_notes)
         else:
+            o = inf.paged_chunk_attention(q, kc, vc, table, pos, use_kernel=use_kernel, trace_notes=trace_notes)
             if trace_notes is not None:
-                trace_notes["cca_prefill_form"] = "blockwise jnp (paged_chunk_attention)"
-            o = inf.paged_chunk_attention(q, kc, vc, table, pos)
+                trace_notes["cca_prefill_form"] = inf.chunk_attention_note(trace_notes)
     return o.transpose(0, 2, 1, 3).reshape(B, T, H * d), k_pool, v_pool, state

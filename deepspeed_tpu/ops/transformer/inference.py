@@ -382,8 +382,36 @@ def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
     return cache_attention(q, gk, gv, pos, sm_scale=sm_scale, use_kernel=False)
 
 
+def chunk_attention_form(use_kernel: Optional[bool], quant: bool, H: int, Hkv: int, T: int, d: int, page_len: int):
+    """Which form a prefill chunk of these shapes takes over its pages:
+    ``(kernel, why_not)`` — ``flash_chunk_paged`` when the suite is armed
+    (or ``use_kernel`` says so) and the kernel serves what the input
+    shows, else the ``jnp`` walk and the reason; one line of the log for
+    each distinct answer."""
+    from deepspeed_tpu.ops import kernels as _kernels
+    from deepspeed_tpu.ops.kernels.flash_chunk import flash_chunk_unsupported
+
+    if use_kernel is None:
+        use_kernel = _kernels.flash_decode_armed()
+    why_not = flash_chunk_unsupported(T, d, page_len, quant) if use_kernel else "kernel suite not armed"
+    _kernels.warn_once(("flash_chunk_paged", H, Hkv, T, d, why_not),
+                       f"kernels: a prefill chunk's attention over its pages ({H} / {Hkv} heads of {d}, T {T}) takes "
+                       + (f"the jnp form of paged_chunk_attention: {why_not}" if why_not else "flash_chunk_paged"), level="info")
+    return not why_not, why_not
+
+
+def chunk_attention_note(trace_notes: dict, jnp_form: str = "blockwise jnp (paged_chunk_attention)") -> str:
+    """A family's form note (``gqa_prefill_form`` …) from what
+    :func:`paged_chunk_attention` told ``trace_notes``: the kernel, or
+    the ``jnp`` form and why."""
+    if trace_notes["chunk_attention_kernel"]:
+        return "flash_chunk_paged (the slot's pages where they lie, scores in VMEM)"
+    return f"{jnp_form}: {trace_notes['chunk_attention_fallback']}"
+
+
 def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Optional[float] = None,
-                          block_pages: int = 4, extra_mask=None):
+                          block_pages: int = 4, extra_mask=None, use_kernel: Optional[bool] = None,
+                          trace_notes: Optional[dict] = None):
     """A prefill chunk against a paged cache, **block by block over its
     context** under an online softmax: ``q (B, H, T, d)`` at positions
     ``pos[b] + t`` (the chunk's own keys already written), caches
@@ -399,11 +427,28 @@ def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Option
     ``extra_mask (B, T, P * page_len)`` bool, where given, is a per-query
     selection of the context applied beside the causal mask (learned
     sparse attention: the same dense walk, fewer keys let through).
-    Returns ``(B, H, T, d)`` in ``q``'s dtype."""
+    Returns ``(B, H, T, d)`` in ``q``'s dtype.
+
+    The walk is the Mosaic kernel
+    :func:`deepspeed_tpu.ops.kernels.flash_chunk.flash_chunk_paged` — the
+    score tile in VMEM, the pages read through the table — where
+    :func:`chunk_attention_form` says so, by what the input shows, and
+    the ``jnp`` lines below otherwise (a head narrower than the lanes,
+    the int8 pool, small shapes, the CPU; the kernel's reference).
+    ``trace_notes``, a dict, is told which (``chunk_attention_kernel``,
+    ``chunk_attention_fallback``: ``ServingEngine.stats()``), while
+    tracing."""
     quant = isinstance(k_cache, dict)
     B, H, T, d = q.shape
     _, Hkv, page_len, _ = (k_cache["q"] if quant else k_cache).shape
     P, G = page_table.shape[1], H // Hkv
+    kernel, why_not = chunk_attention_form(use_kernel, quant, H, Hkv, T, d, page_len)
+    if trace_notes is not None:
+        trace_notes.update(chunk_attention_kernel=kernel, chunk_attention_fallback=why_not)
+    if kernel:
+        from deepspeed_tpu.ops.kernels.flash_chunk import flash_chunk_paged
+
+        return flash_chunk_paged(q, k_cache, v_cache, page_table, pos, sm_scale=sm_scale, extra_mask=extra_mask)
     while P % block_pages:
         block_pages -= 1
     S = block_pages * page_len
@@ -748,9 +793,9 @@ def inference_block(
         if T == 1:
             attn = paged_cache_attention(q, kc, vc, table, pos, work=work, trace_notes=trace_notes)
         else:
+            attn = paged_chunk_attention(q, kc, vc, table, pos, trace_notes=trace_notes)
             if trace_notes is not None:
-                trace_notes["prefill_attend_form"] = "blockwise (paged_chunk_attention)"
-            attn = paged_chunk_attention(q, kc, vc, table, pos)
+                trace_notes["prefill_attend_form"] = chunk_attention_note(trace_notes, "blockwise (paged_chunk_attention)")
         attn = attn.transpose(0, 2, 1, 3).reshape(B, T, D)
         attn = _wmm(attn, lp["proj_w"]) + lp["proj_b"].astype(attn.dtype)
         return _block_mlp(cfg, lp, x + attn), k_cache, v_cache
@@ -971,8 +1016,8 @@ def serving_forward(mcfg):
     pinned to the layout they were allocated with, the chunk's position ids clipped (its default under a per-row ``pos``),
     and row ``take`` of the logits.  No per-slot state and no counters: ``state`` passes through, ``aux`` is None.
     ``fwd.bind(dtype=, mp_size=, pool=)`` is handed what a model config does not hold — the engine's compute dtype and the
-    pool as allocated — before anything is traced; ``fwd.trace_notes`` holds ``kv_write_form``, ``prefill_attend_form`` and
-    ``paged_decode_walk``."""
+    pool as allocated — before anything is traced; ``fwd.trace_notes`` holds ``kv_write_form``, ``prefill_attend_form``
+    (from ``chunk_attention_kernel`` / ``_fallback``) and ``paged_decode_walk``."""
     notes: Dict[str, Any] = {}
     bound: Dict[str, Any] = {}
 

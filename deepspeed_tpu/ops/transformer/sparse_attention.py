@@ -314,7 +314,9 @@ def attention(sz: Sizes, lp: Dict[str, Any], u, k_pool, v_pool, layer: int, pos,
     them: the mask the attention below reads and the float32 score it was
     cut at.  Returns ``(o (B, T, H * d), k_pool, v_pool)``."""
     from deepspeed_tpu.ops.kernels import sparse_decode as kern
-    from deepspeed_tpu.ops.transformer.inference import layer_pages, paged_cache_write_slices, paged_chunk_attention
+    from deepspeed_tpu.ops.transformer.inference import (
+        chunk_attention_note, layer_pages, paged_cache_write_slices, paged_chunk_attention,
+    )
 
     B, T, _ = u.shape
     H, Hkv, d = sz.heads, sz.kv_heads, sz.head_dim
@@ -361,7 +363,7 @@ def attention(sz: Sizes, lp: Dict[str, Any], u, k_pool, v_pool, layer: int, pos,
             else:
                 o = selected_decode_reference(q, kc, vc, table, mask[:, 0], d ** -0.5)
         else:
-            o = paged_chunk_attention(q, kc, vc, table, pos, extra_mask=mask)
+            o = paged_chunk_attention(q, kc, vc, table, pos, extra_mask=mask, use_kernel=use_kernel, trace_notes=trace_notes)
     if trace_notes is not None:
         trace_notes["dsa_index_form" if T == 1 else "dsa_prefill_index_form"] = form
         in_vmem = kern.select_supported(B * T, min(S, SELECT_BUCKET), use_kernel)
@@ -372,5 +374,6 @@ def attention(sz: Sizes, lp: Dict[str, Any], u, k_pool, v_pool, layer: int, pos,
             trace_notes["dsa_decode_kernel"] = ("dsa_sparse_decode (the rows' filled pages under the selection mask)"
                                                 if decode_kernel else "lax: gathered rows under the selection mask")
         else:
-            trace_notes["dsa_prefill_form"] = "paged_chunk_attention, blockwise dense under the selection mask"
+            trace_notes["dsa_prefill_form"] = ("paged_chunk_attention, dense under the selection mask: "
+                                               + chunk_attention_note(trace_notes, "blockwise jnp"))
     return o.transpose(0, 2, 1, 3).reshape(B, T, H * d), k_pool, v_pool

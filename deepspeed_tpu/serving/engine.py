@@ -1646,7 +1646,8 @@ class ServingEngine:
         # Keye: dsa_index_form, dsa_select_form, dsa_decode_kernel, dsa_prefill_form, moe_router_form;
         # GigaChat3.5: gdn_decode_kernel / _fallback, gdn_prefill_form, mla_decode_kernel / _fallback, mla_prefill_form, moe_router_form;
         # the paged per-head pool: kv_write_form, prefill_attend_form;
-        # all three that decode through flash_decode_paged: paged_decode_walk)
+        # all three that decode through flash_decode_paged: paged_decode_walk;
+        # every family whose chunk attends over per-head pages: chunk_attention_kernel / chunk_attention_fallback)
         out.update(self._trace_notes)
         out.update(self.timeline.summary())
         for stall in self.timeline.stalls() if out["stall_steps"] else ():

@@ -156,6 +156,31 @@ def test_flash_decode_paged_compiles_for_v5e_under_the_tile_of_each_serve_cell(c
     assert chip_smoke.mosaic_kernels(compiled.as_text()) == {"flash_decode_paged": 1}
 
 
+@pytest.mark.parametrize("cell,heads,kv_heads,chunk,pages_per_slot,masked,tile", [
+    ("keye", 32, 4, 2048, 264, True, (256, 8)),          # 8 query heads a KV head stacked: 2,048 rows against 1,024 keys, under the selection
+    ("laguna_full", 48, 8, 1024, 168, False, (256, 8)),  # 6 a KV head: 1,536 rows
+    ("zaya1", 8, 2, 1024, 64, False, (512, 8)),          # 4 a KV head
+    ("solar_open2", 64, 8, 512, 64, False, (256, 8)),
+    ("a_slot_its_blocks_do_not_divide", 8, 2, 256, 21, True, (256, 8)),  # the last block reaches 3 pages past the slot, the mask's with it
+])
+def test_flash_chunk_paged_compiles_for_v5e_under_the_tile_of_each_serve_cell(cell, heads, kv_heads, chunk, pages_per_slot, masked, tile, v5e_chip):
+    """The chunk's attention kernel at the serve cells' own shapes, by the
+    chip's compiler without the chip: Mosaic takes the tile the shapes
+    give, and nothing the size of a block's float32 scores is left in
+    the program beside it."""
+    from deepspeed_tpu.ops.kernels.flash_chunk import chunk_tile, flash_chunk_paged
+
+    on_chip = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    assert chunk_tile(heads // kv_heads, chunk, pages_per_slot, 128) == tile
+    pool = (1 + 2 * pages_per_slot, kv_heads, 128, 128)
+    args = [on_chip((1, heads, chunk, 128)), on_chip(pool), on_chip(pool), on_chip((1, pages_per_slot), jnp.int32), on_chip((1,), jnp.int32)]
+    if masked:
+        args.append(on_chip((1, chunk, pages_per_slot * 128), jnp.bool_))
+    compiled = jax.jit(lambda q, k, v, t, p, m=None: flash_chunk_paged(q, k, v, t, p, extra_mask=m, interpret=False)).lower(*args).compile()
+    assert chip_smoke.mosaic_kernels(compiled.as_text()) == {"flash_chunk_paged": 1}
+    assert compiled.memory_analysis().temp_size_in_bytes < heads * chunk * tile[1] * 128 * 4
+
+
 @pytest.mark.parametrize("opt_name", ["adam", "lamb"])
 def test_fused_update_compiles_for_v5e_with_no_pass_beside_the_kernels(opt_name, v5e_chip):
     """The check chip_smoke makes on the chip, made by the chip's
@@ -239,10 +264,10 @@ def test_solar_open2_steps_compile_for_v5e_with_both_caches_updated_in_place(whi
     state in the program (an XLA scatter for the K/V write copied both
     pools every step: PERF.md, PR 32)."""
     from deepspeed_tpu.models import solar_open2 as so
-    from deepspeed_tpu.ops.kernels import flash_decode, grouped_matmul, kda_decode
+    from deepspeed_tpu.ops.kernels import flash_chunk, flash_decode, grouped_matmul, kda_decode
 
     monkeypatch.setenv("DS_KERNELS", "1")
-    for mod in (flash_decode, grouped_matmul, kda_decode):
+    for mod in (flash_chunk, flash_decode, grouped_matmul, kda_decode):
         monkeypatch.setattr(mod, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
     slots, pages, chunk = 16, 2049, 512
     cfg = so.SolarOpen2Config(num_hidden_layers=4, gqa_layers=(0,), experts_held=(0, 40), vocab_held=24576)
@@ -266,7 +291,7 @@ def test_solar_open2_steps_compile_for_v5e_with_both_caches_updated_in_place(whi
     if which == "decode":
         assert found == {"kda_decode": 3, "flash_decode_paged": 1, "moe_grouped_matmul": 8}
     else:
-        assert found == {"moe_grouped_matmul": 8}
+        assert found == {"flash_chunk_paged": 1, "moe_grouped_matmul": 8}  # the GQA layer's chunk walks its pages in the kernel
     m = compiled.memory_analysis()
     cache_bytes = 2 * int(np.prod(k.shape)) * 2 + sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(state))
     assert m.alias_size_in_bytes >= cache_bytes               # all three groups come back in place
@@ -284,10 +309,10 @@ def test_zaya_steps_compile_for_v5e_with_pages_and_tail_updated_in_place(which, 
     its 64 top-1 rows (padded to one MXU window); a chunk of 1,024 holds
     the experts' kernel; neither leaves a copy of the pools or the tail."""
     from deepspeed_tpu.models import zaya
-    from deepspeed_tpu.ops.kernels import flash_decode, grouped_matmul
+    from deepspeed_tpu.ops.kernels import flash_chunk, flash_decode, grouped_matmul
 
     monkeypatch.setenv("DS_KERNELS", "1")
-    for mod in (flash_decode, grouped_matmul):
+    for mod in (flash_chunk, flash_decode, grouped_matmul):
         monkeypatch.setattr(mod, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
     layers, slots, pages, chunk = 2, 64, 2433, 1024
     cfg = zaya.ZayaConfig(num_hidden_layers=layers, experts_held=(0, 8), vocab_held=131136)
@@ -315,7 +340,8 @@ def test_zaya_steps_compile_for_v5e_with_pages_and_tail_updated_in_place(which, 
         assert found == {"flash_decode_paged": layers, "moe_grouped_matmul": 2 * layers}
         assert notes["cca_decode_kernel"] is True and notes["moe_grouped_kernel"] == "64"
     else:
-        assert found == {"moe_grouped_matmul": 2 * layers} and notes["moe_grouped_kernel"] == "1024"
+        assert found == {"flash_chunk_paged": layers, "moe_grouped_matmul": 2 * layers} and notes["moe_grouped_kernel"] == "1024"
+        assert notes["chunk_attention_kernel"] is True and notes["cca_prefill_form"].startswith("flash_chunk_paged")
     assert notes["moe_grouped_fallback"] == ""
     m = compiled.memory_analysis()
     cache_bytes = 2 * int(np.prod(k.shape)) * 2 + sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(state))
@@ -386,10 +412,10 @@ def test_laguna_steps_compile_for_v5e_with_both_page_groups_updated_in_place(whi
     layer; a chunk of 1,024 holds the experts' kernel; neither leaves a
     copy of a group in the program."""
     from deepspeed_tpu.models import laguna as lg
-    from deepspeed_tpu.ops.kernels import flash_decode, grouped_matmul
+    from deepspeed_tpu.ops.kernels import flash_chunk, flash_decode, grouped_matmul
 
     monkeypatch.setenv("DS_KERNELS", "1")
-    for mod in (flash_decode, grouped_matmul):
+    for mod in (flash_chunk, flash_decode, grouped_matmul):
         monkeypatch.setattr(mod, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
     slots, pages, chunk, per_slot = 24, 2305, 1024, 168
     cfg = lg.LagunaConfig.from_hf({}, num_hidden_layers=12, experts_held=(0, 32), vocab_held=12544)
@@ -417,8 +443,9 @@ def test_laguna_steps_compile_for_v5e_with_both_page_groups_updated_in_place(whi
         assert notes["swa_decode_form"].startswith("swa_decode_paged") and "9 query heads a KV head" in notes["swa_decode_form"]
         assert notes["paged_decode_walk"] == "work list, 8 heads x 2 pages" and notes["moe_grouped_kernel"] == "240"
     else:
-        assert found == {"moe_grouped_matmul": 22} and notes["moe_grouped_kernel"] == "10240"
-        assert notes["swa_chunk_form"].startswith("banded jnp") and notes["gqa_prefill_form"].startswith("blockwise jnp")
+        assert found == {"flash_chunk_paged": 3, "moe_grouped_matmul": 22} and notes["moe_grouped_kernel"] == "10240"
+        assert notes["swa_chunk_form"].startswith("banded jnp") and notes["gqa_prefill_form"].startswith("flash_chunk_paged")
+        assert notes["chunk_attention_kernel"] is True and notes["chunk_attention_fallback"] == ""
     assert notes["moe_grouped_fallback"] == "" and notes["swa_ring_positions"] == 640
     m = compiled.memory_analysis()
     cache_bytes = sum(int(np.prod(a.shape)) * 2 for a in (k, v, *jax.tree.leaves(state)))
@@ -438,10 +465,10 @@ def test_keye_steps_compile_for_v5e_with_all_three_leaves_updated_in_place(which
     at 2048 / 768 for its 16 x 8 assignment rows; a chunk of 2,048 holds
     the experts' kernel; neither leaves a copy of a leaf."""
     from deepspeed_tpu.models import keye
-    from deepspeed_tpu.ops.kernels import grouped_matmul, sparse_decode
+    from deepspeed_tpu.ops.kernels import flash_chunk, grouped_matmul, sparse_decode
 
     monkeypatch.setenv("DS_KERNELS", "1")
-    for mod in (sparse_decode, grouped_matmul):
+    for mod in (flash_chunk, sparse_decode, grouped_matmul):
         monkeypatch.setattr(mod, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
     layers, slots, pages, per_slot, chunk = 2, 16, 3329, 264, 2048
     cfg = keye.KeyeConfig(num_hidden_layers=layers, experts_held=(0, 16), vocab_held=18992)
@@ -469,8 +496,9 @@ def test_keye_steps_compile_for_v5e_with_all_three_leaves_updated_in_place(which
         assert notes["dsa_decode_kernel"].startswith("dsa_sparse_decode") and notes["dsa_index_form"].startswith("dsa_index_scores_paged")
         assert notes["moe_grouped_kernel"] == "128" and "dsa_select_threshold" in notes["dsa_select_form"]
     else:
-        assert found == {"moe_grouped_matmul": 2 * layers} and notes["moe_grouped_kernel"] == "16384"
+        assert found == {"flash_chunk_paged": layers, "moe_grouped_matmul": 2 * layers} and notes["moe_grouped_kernel"] == "16384"
         assert notes["dsa_prefill_form"].startswith("paged_chunk_attention") and "dsa_select_threshold" in notes["dsa_prefill_select_form"]
+        assert notes["chunk_attention_kernel"] is True and "flash_chunk_paged" in notes["dsa_prefill_form"]
     assert notes["moe_grouped_fallback"] == ""
     m = compiled.memory_analysis()
     cache_bytes = sum(int(np.prod(a.shape)) * 2 for a in jax.tree.leaves((k, v)))
