@@ -19,7 +19,9 @@ weights made from a seed:
 * a fifth family — Keye's language model at a small size on the cache
   kind with a third leaf (K, V and an indexer key a position;
   ``dsa_index_scores_paged`` and ``dsa_sparse_decode`` in its decode
-  program: attention over the positions the indexer selects)
+  program: attention over the positions the indexer selects), the
+  masked decode kernel first held against the lax form at Keye's tile
+  (32 / 4 heads, four pages a grid step),
   against ``benchmark/reference_keye.py``;
 * a sixth family — GigaChat3.5 at a small size on the hybrid cache over
   **latent** pages (a latent buffer + per-slot recurrent state;
@@ -922,6 +924,42 @@ def check_swa_decode_paged(s: Smoke) -> float:
     return err
 
 
+def check_dsa_sparse_decode(s: Smoke) -> float:
+    """``dsa_sparse_decode`` — the paged decode kernel under a selection —
+    against the gathered rows under the mask (the lax form) at Keye's tile
+    (32 query heads on 4 KV heads of 128, pages of 128: 4 heads x 4 pages
+    a grid step): a row whose first item holds nothing selected, a row
+    that selects nothing, a row that does not decode, a row whose last
+    span reaches past its position, a full one."""
+    from deepspeed_tpu.ops.kernels import sparse_decode as kern
+    from deepspeed_tpu.ops.transformer.sparse_attention import selected_decode_reference
+
+    B, H, Hkv, d, page_len, P = 6, 32, 4, 128, 128, 16
+    rng = np.random.default_rng(s.seed)
+    kc, vc = (jnp.asarray(rng.standard_normal((1 + B * P, Hkv, page_len, d)), jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((B, H, 1, d)), jnp.bfloat16)
+    table = jnp.asarray((1 + rng.permutation(B * P)).reshape(B, P).astype(np.int32))
+    pos = np.asarray([5, 600, 2047, 1500, 700, 1234], np.int32)
+    live = np.asarray([True, True, True, True, True, False])
+    mask = (np.arange(P * page_len)[None, :] <= pos[:, None]) & (rng.random((B, P * page_len)) < 0.115)
+    mask[0, 2] = True
+    mask[1, :512], mask[1, 513] = False, True   # the first item (4 pages) holds nothing selected
+    mask[3] = False                             # a row that selects nothing
+    span = kern.span_of(kc, P)
+    work = kern.work_list(jnp.asarray(pos), jnp.asarray(live), kc, P)
+    got = jax.jit(lambda *a: kern.dsa_sparse_decode(*a, None, work))(q, kc, vc, table, jnp.asarray(pos), jnp.asarray(mask))
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda *a: selected_decode_reference(*a, d ** -0.5))(q, kc, vc, table, jnp.asarray(mask & live[:, None]))
+    err = _max_err(got, want)
+    check(span == 4, f"dsa_sparse_decode: {span} pages a grid step at Keye's pages, not 4")
+    check(err <= TOL_BF16, f"dsa_sparse_decode at {H} / {Hkv} heads, spans of {span} pages: max error {err:.4f} against the lax form (tolerance {TOL_BF16})")
+    quiet = np.abs(np.asarray(got, np.float32)).reshape(B, -1).max(1)
+    check(quiet[3] == 0.0 and quiet[5] == 0.0 and quiet[[0, 1, 2, 4]].min() > 0.0,
+          f"dsa_sparse_decode: the row that selects nothing and the row that does not decode read 0, the others do not ({quiet})")
+    say(f"kernel dsa_sparse_decode: max error {err:.4f}, {int(work[2][0])} items for 5 live rows")
+    return err
+
+
 def check_flash_chunk_paged(s: Smoke) -> Dict[str, float]:
     """``flash_chunk_paged`` against the ``jnp`` form of
     ``paged_chunk_attention`` at the two cells it leads: Keye's chunk (32
@@ -1016,6 +1054,7 @@ def run(s: Smoke, devices: Sequence) -> None:
         check_flash_attention(s)
         check_fused_update(s)
         check_swa_decode_paged(s)
+        check_dsa_sparse_decode(s)
         check_flash_chunk_paged(s)
     result = train(s, devices[:1])
     if len(devices) > 1:

@@ -226,7 +226,7 @@ def forward_with_cache(params: Dict[str, Any], tokens, k_pool, v_pool, pos, cfg:
     x = jnp.take(params["embed"], tokens, axis=0)
     if positions3 is None:
         positions3 = jnp.broadcast_to(pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :], (3, B, T))
-    work = work_list(pos, write_mask, v_pool.shape[3], page_table.shape[1]) if T == 1 else None  # once, for every layer
+    work = work_list(pos, write_mask, k_pool["k"], page_table.shape[1]) if T == 1 else None  # once, for every layer
     valid = None if row_valid is None else row_valid.reshape(B * T)
     aux = []
     for layer, lp in enumerate(params["layers"]):

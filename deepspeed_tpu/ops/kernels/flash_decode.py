@@ -340,7 +340,8 @@ def _flash_decode_paged_kernel(
     q_ref,            # (1, block_heads, group, d): the query heads of block_heads KV heads
     *rest,            # ``span`` K pages, ``span`` V pages, each (1, block_heads, page_len, d) — THE page pt[slot, span * s + j],
                       # codes or bf16/f32, (1, block_heads, d, page_len) when ``lanes_hold_rows``;
-                      # [``span`` K scales, ``span`` V scales (1, block_heads, 1, page_len)]; o_ref; scratch m, l, acc, scores
+                      # [``span`` K scales, ``span`` V scales (1, block_heads, 1, page_len)];
+                      # [the item's strip of the row's selection (1, 1, 1, span * page_len) int8]; o_ref; scratch m, l, acc, scores
     sm_scale: float,
     page_len: int,
     quant: bool,
@@ -352,7 +353,8 @@ def _flash_decode_paged_kernel(
 ):
     k_refs, v_refs, *scales = (rest[g * span: (g + 1) * span] for g in range(4 if quant else 2))
     ks_refs, vs_refs = scales or (None, None)
-    o_ref, m_ref, l_ref, acc_ref, s_ref = rest[(4 if quant else 2) * span:]
+    *selection, o_ref, m_ref, l_ref, acc_ref, s_ref = rest[(4 if quant else 2) * span:]
+    chosen_ref = selection[0] if selection else None  # the call was given a selection: Python's branch, nothing of it is traced without one
 
     i = pl.program_id(1)
     b, s_idx = slot_ref[i], span_ref[i]
@@ -401,10 +403,14 @@ def _flash_decode_paged_kernel(
         seen = key_idx <= pos_ref[b]
         if window is not None:  # the lower bound: a span's head may lie before the window, and a ring's page may hold a later lap
             seen &= key_idx > pos_ref[b] - window
+        if chosen_ref is not None:  # the row's selection: nothing else is attended
+            seen &= chosen_ref[0, 0].astype(jnp.int32) != 0
         scores = jnp.where(seen, s_ref[:] * sm_scale, NEG_INF)
         m_prev = m_ref[:]                                            # (rows, 1)
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
         p = jnp.exp(scores - m_new)
+        if chosen_ref is not None:  # an item may hold nothing selected, a row's first too: with the maximum still at NEG_INF exp(0) is 1 a position
+            p = jnp.where(seen, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
         m_ref[:] = m_new
@@ -438,6 +444,7 @@ def flash_decode_paged(
     work=None,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
+    mask=None,
 ) -> jnp.ndarray:
     """Single-query attention against a PAGED pool (docs/serving.md
     §Paged KV & prefix caching): caches are ``(num_pages, Hkv, page_len,
@@ -481,6 +488,18 @@ def flash_decode_paged(
     **ring**: the table maps *logical* pages (``inference.ring_table``),
     so a page that holds a later lap of the ring than the span it is read
     for is masked like any position outside the window.
+
+    **A selection** (``mask (B, P * page_len)`` bool given: learned sparse
+    attention, the row attends the positions it selects and no other) is
+    the same body again, under the kernel name ``dsa_sparse_decode``: the
+    mask rides as one more operand, int8, an item's ``(1, span *
+    page_len)`` strip under the index map ``(slot, span)``, and joins the
+    position mask of pass (2).  The walk still reads every filled page —
+    2,048 selected positions scattered over a long row touch nearly every
+    one — so the selection saves arithmetic, not bytes.  An item with
+    nothing selected adds nothing, and a row that selects nothing reads 0.
+    The branch is Python's: with ``mask=None`` the call traces what it
+    traced without the operand.
 
     The page table rides the grid as a **prefetched scalar** too
     (``PrefetchScalarGridSpec``), so each program's K/V pages stream
@@ -552,6 +571,9 @@ def flash_decode_paged(
         vs = v_cache["s"].reshape(NP, Hkv, 1, page_len)
         in_specs += [pl.BlockSpec((1, bh, 1, page_len), kv_page(j)) for j in range(span)] * 2
         args += [ks] * span + [vs] * span
+    if mask is not None:
+        in_specs.append(pl.BlockSpec((1, 1, 1, span * page_len), lambda h, i, pt, pv, sl, sp, n: (sl[i], sp[i], 0, 0)))
+        args.append(mask.astype(jnp.int8).reshape(B, P // span, 1, span * page_len))
 
     kern = functools.partial(
         _flash_decode_paged_kernel,
@@ -585,7 +607,7 @@ def flash_decode_paged(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_decode_paged" if window is None else "swa_decode_paged",
+        name="dsa_sparse_decode" if mask is not None else "flash_decode_paged" if window is None else "swa_decode_paged",
     )(table, pos_vec, slot, span_idx, n, *args)
     # rows no item visited hold whatever the output buffer held
     return jnp.where(live[:, None, None, None], out, 0).reshape(B, H, 1, d)

@@ -8,12 +8,11 @@ The two of the decode step walk the step's **work list** exactly as
 ``flash_decode_paged`` does — the list and the page table ride as
 prefetched scalars, the list's length is the traced bound of the grid,
 and a row that does not decode costs no grid step.  An item is a **span**
-of :func:`span_of` = 4 consecutive pages of a row (``work_list``:
-``flash_decode.paged_work_list`` over spans), each page an operand of its
-own found through the page table, so a grid step's fixed cost is paid once
-for four pages (the last span of a row may reach past its position: those
-pages are read and masked) — the form ``flash_decode_paged`` has too since
-PR 46, where ``flash_decode.paged_tile`` sizes the span.
+of :func:`span_of` consecutive pages of a row (``flash_decode.paged_tile``
+sizes it off the K pages: 4 at Keye's pool, about a mebibyte of K + V a
+grid step), each page an operand of its own found through the page table,
+so a grid step's fixed cost is paid once for the span (the last span of a
+row may reach past its position: those pages are read and masked).
 
 * :func:`dsa_index_scores_paged` — an item is one page of one row's
   indexer keys, ``(index_dim, page_len)``: the row's ``index_heads``
@@ -21,13 +20,13 @@ PR 46, where ``flash_decode.paged_tile`` sizes the span.
   under the row's weights: one ``(1, page_len)`` strip of scores.  Strips
   no item visits hold whatever the buffer held; the selection masks them
   (they lie past the row's position).
-* :func:`dsa_sparse_decode` — ``flash_decode_paged`` for grouped queries
-  with one more operand, the row's selection as an additive bias strip a
-  page (0 where selected, ``NEG_INF`` elsewhere).  It **walks every filled
-  page** and masks: 2,048 selected positions scattered over a long row
-  touch nearly every page, so the walk reads the row's K and V whole —
-  the selection saves arithmetic, not bytes, and the kernel's share of a
-  roofline that counts the selected rows only reads low
+* :func:`dsa_sparse_decode` — ``flash_decode_paged`` itself, handed the
+  row's selection as its ``mask`` operand (the kernel's name in a trace
+  stays ``dsa_sparse_decode``).  It **walks every filled page** and
+  masks: 2,048 selected positions scattered over a long row touch nearly
+  every page, so the walk reads the row's K and V whole — the selection
+  saves arithmetic, not bytes, and the kernel's share of a roofline that
+  counts the selected rows only reads low
   (``benchmark/kernels/dsa_sparse_decode.py``, docs/kernels.md).
 
 Layout demands: ``page_len`` a multiple of 128 and ``head_dim`` a
@@ -47,10 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.kernels.flash_decode import paged_work_list
+from deepspeed_tpu.ops.kernels.flash_decode import flash_decode_paged, paged_tile, paged_work_list
 from deepspeed_tpu.utils.device import pallas_interpret_default
-
-NEG_INF = -1e30
 
 
 def supported(B: int, H: int, Hkv: int, P: int, page_len: int, d: int, use_kernel: Optional[bool] = None) -> bool:
@@ -123,20 +120,17 @@ def dsa_select_threshold(keys, k: int, interpret: Optional[bool] = None):
     return t[:, 0], p[:, 0]
 
 
-PAGES_A_STEP = 4  # pages of one row a grid step takes (a *span*): a step's fixed cost (~0.4 us) is paid once for them
+def span_of(k_pages, pages_per_slot: int) -> int:
+    """Pages a grid step of both kernels takes (a *span*): ``flash_decode.paged_tile``'s, read off the K pages' shape
+    ``(..., Hkv, page_len, d)`` — the one span rule of every paged decode kernel."""
+    return paged_tile(k_pages, pages_per_slot)[1]
 
 
-def span_of(pages_per_slot: int) -> int:
-    """Pages a grid step takes: the largest of 4, 2, 1 that divides the slot's pages."""
-    return next(c for c in (PAGES_A_STEP, 2, 1) if c <= PAGES_A_STEP and pages_per_slot % c == 0)
-
-
-def work_list(pos, live, page_len: int, pages_per_slot: int):
+def work_list(pos, live, k_pages, pages_per_slot: int):
     """The step's work list for both kernels, built once for every layer:
-    ``flash_decode.paged_work_list`` over **spans** of :func:`span_of`
-    pages — the filled spans of the rows that decode, slot by slot."""
-    c = span_of(pages_per_slot)
-    return paged_work_list(pos, live, page_len * c, pages_per_slot // c)
+    ``flash_decode.paged_work_list`` over spans of :func:`span_of` pages —
+    the filled spans of the rows that decode, slot by slot."""
+    return paged_work_list(pos, live, k_pages.shape[-2], pages_per_slot, span_of(k_pages, pages_per_slot))
 
 
 def _index_scores_kernel(pt_ref, slot_ref, span_ref, n_ref, q_ref, w_ref, *rest, span: int, page_len: int):
@@ -157,17 +151,18 @@ def dsa_index_scores_paged(qi, w, idx_pool, layer: int, page_table, pos, work=No
     single query ``qi (B, Hi, di)`` with head weights ``w (B, Hi)`` (both
     float32, scales folded into ``w``) against its own pages of layer
     ``layer`` of ``idx_pool (layers, pages, di, page_len)``.  ``work`` is
-    :func:`work_list`'s (None: every row's filled spans).  Positions on
-    spans the work list does not visit are undefined."""
+    :func:`work_list`'s, whose length says the span it was built under
+    (None: every row's filled pages, one an item).  Positions on spans the
+    work list does not visit are undefined."""
     B, Hi, di = qi.shape
     L, NP, _, page_len = idx_pool.shape
     P = page_table.shape[1]
-    C = span_of(P)
     if interpret is None:
         interpret = pallas_interpret_default()
     if work is None:
-        work = work_list(jnp.clip(jnp.asarray(pos, jnp.int32).reshape(-1), 0, P * page_len - 1), None, page_len, P)
+        work = paged_work_list(jnp.clip(jnp.asarray(pos, jnp.int32).reshape(-1), 0, P * page_len - 1), None, page_len, P)
     slot, span, n, _ = work
+    C = B * P // slot.shape[0]
     table = jnp.asarray(page_table, jnp.int32) + jnp.int32(layer * NP)  # the layer's pages where they lie (layers and pages merged)
     row = lambda i, pt, sl, sp, n: (sl[i], 0, 0)  # noqa: E731
     page = lambda c: (lambda i, pt, sl, sp, n: (pt[sl[i], sp[i] * C + c], 0, 0))  # noqa: E731
@@ -188,94 +183,15 @@ def dsa_index_scores_paged(qi, w, idx_pool, layer: int, page_table, pos, work=No
     return out.reshape(B, P * page_len)
 
 
-def _decode_kernel(pt_ref, pos_ref, slot_ref, span_ref, n_ref, q_ref, *rest, sm_scale: float, page_len: int, heads: int, group: int,
-                   span: int):
-    k_refs, v_refs = rest[:span], rest[span: 2 * span]
-    bias_ref, o_ref, m_ref, l_ref, acc_ref = rest[2 * span:]
-    i = pl.program_id(0)
-    b, s_idx = slot_ref[i], span_ref[i]
-
-    @pl.when(n_ref[0] == 0)
-    def _nothing_decodes():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(n_ref[0] > 0)
-    def _item():
-        @pl.when(s_idx == 0)
-        def _init():
-            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[:] = jnp.zeros_like(l_ref)
-            acc_ref[:] = jnp.zeros_like(acc_ref)
-
-        for c in range(span):
-            bias = bias_ref[0, 0, :, c * page_len: (c + 1) * page_len]         # (1, page_len): 0 selected, NEG_INF not
-            chosen = bias > 0.5 * NEG_INF
-            for h in range(heads):  # a KV head's ``group`` query heads share its page: one fetch, ``group`` rows
-                rows = pl.dslice(h * group, group)
-                q = q_ref[0, h].astype(jnp.float32)                            # (group, d)
-                k = k_refs[c][0, h].astype(jnp.float32)                        # (page_len, d)
-                s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * sm_scale + bias
-                m_prev, l_prev = m_ref[rows], l_ref[rows]
-                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-                p = jnp.where(chosen, jnp.exp(s - m_new), 0.0)                 # a page with nothing selected adds nothing
-                alpha = jnp.exp(m_prev - m_new)
-                l_ref[rows] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-                m_ref[rows] = m_new
-                acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
-                    p, v_refs[c][0, h].astype(jnp.float32), (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-        @pl.when(s_idx == pos_ref[b] // (page_len * span))  # the row's last item: the span its position lies in
-        def _emit():
-            l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-            o_ref[:] = (acc_ref[:] / l).reshape(o_ref.shape).astype(o_ref.dtype)
-
-
 def dsa_sparse_decode(q, k_cache, v_cache, page_table, pos, mask, sm_scale: Optional[float] = None, work=None,
-                     interpret: Optional[bool] = None):
+                      interpret: Optional[bool] = None):
     """Single-query grouped attention of each row over **the positions
     ``mask`` selects**: ``q (B, H, 1, d)``, caches ``(num_pages, Hkv,
     page_len, d)``, ``page_table (B, P)``, ``pos (B,)`` the rows' query
     positions, ``mask (B, P * page_len)`` bool (nothing past ``pos``).
-    Walks ``work`` (:func:`work_list`; None: every row's filled spans),
-    :func:`span_of` pages a grid step with every KV head's pages in one
-    program.  Rows no item visits, and rows that select nothing, read 0.
-    Returns ``(B, H, 1, d)``."""
-    B, H, T, d = q.shape
-    NP, Hkv, page_len, _ = k_cache.shape
-    P = page_table.shape[1]
-    C = span_of(P)
-    if T != 1:
-        raise ValueError(f"dsa_sparse_decode serves exactly one query per slot, got T={T}")
-    if H % Hkv or page_len % 128 or d % 128:
-        raise ValueError(f"dsa_sparse_decode cannot serve (H={H}, Hkv={Hkv}, page_len={page_len}, d={d}); "
-                         "callers dispatch through supported()")
-    if sm_scale is None:
-        sm_scale = d ** -0.5
-    if interpret is None:
-        interpret = pallas_interpret_default()
-    group = H // Hkv
-    pos_vec = jnp.clip(jnp.asarray(pos, jnp.int32).reshape(-1), 0, P * page_len - 1)
-    if work is None:
-        work = work_list(pos_vec, None, page_len, P)
-    slot, span, n, live = work
-    bias = jnp.where(mask, 0.0, NEG_INF).astype(jnp.float32).reshape(B, P // C, 1, C * page_len)
-
-    row = lambda i, pt, pv, sl, sp, n: (sl[i], 0, 0, 0)  # noqa: E731
-    page = lambda c: (lambda i, pt, pv, sl, sp, n: (pt[sl[i], sp[i] * C + c], 0, 0, 0))  # noqa: E731
-    kv_specs = [pl.BlockSpec((1, Hkv, page_len, d), page(c)) for c in range(C)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(jnp.maximum(n[0], 1),),
-        in_specs=[pl.BlockSpec((1, Hkv, group, d), row)] + kv_specs + kv_specs
-                 + [pl.BlockSpec((1, 1, 1, C * page_len), lambda i, pt, pv, sl, sp, n: (sl[i], sp[i], 0, 0))],
-        out_specs=pl.BlockSpec((1, Hkv, group, d), row),
-        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32), pltpu.VMEM((H, 1), jnp.float32), pltpu.VMEM((H, d), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, sm_scale=float(sm_scale), page_len=page_len, heads=Hkv, group=group, span=C),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret, name="dsa_sparse_decode",
-    )(jnp.asarray(page_table, jnp.int32), pos_vec, slot, span, n, q.reshape(B, Hkv, group, d), *([k_cache] * C), *([v_cache] * C), bias)
-    return jnp.where(live[:, None, None, None], out, 0).reshape(B, H, 1, d)
+    It is ``flash_decode_paged`` with the selection as its ``mask``
+    operand — the same body, an item's products in three passes — under
+    the kernel name ``dsa_sparse_decode``, walking ``work``
+    (:func:`work_list`; None: every row's filled spans).  Rows no item
+    visits, and rows that select nothing, read 0.  Returns ``(B, H, 1, d)``."""
+    return flash_decode_paged(q, k_cache, v_cache, page_table, pos, sm_scale, work, interpret, mask=mask)

@@ -29,8 +29,9 @@ Two forms of the attention over ``S_t``, the same mathematics:
   per-query gather would move ``T x topk`` rows where the block walk reads
   each context row once;
 * a **decode step** walks the rows' filled pages with the mask applied —
-  the Mosaic kernel ``ops/kernels/sparse_decode.py::dsa_sparse_decode`` on
-  the chip, the gathered lax form below elsewhere (its reference).
+  ``ops/kernels/sparse_decode.py::dsa_sparse_decode`` on the chip (the
+  paged decode kernel ``flash_decode_paged`` under the selection as its
+  ``mask`` operand), the gathered lax form below elsewhere (its reference).
 
 Rotary is **three-stream** (``mrope``): the frequency pairs of a head are
 split ``sections`` = 16 / 24 / 24 over a temporal, a height and a width

@@ -240,6 +240,8 @@ class ServingEngine:
                 kind=kind,
             )
             k = self.pool.k
+            if isinstance(k, dict) and "k" in k:  # K pages beside another leaf (the indexer's keys): the walk is over the K pages
+                k = k["k"]
             if self.pool.v is not None and (not isinstance(k, dict) or "q" in k):  # K/V pages a KV head: flash_decode_paged's
                 from deepspeed_tpu.ops.kernels.flash_decode import paged_tile
 

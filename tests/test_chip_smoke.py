@@ -136,24 +136,28 @@ def v5e_chip():
     ("solar_open2", 160, 64, (2561, 8, 128, 128), 64, (8, 2)),    # 8 KV heads x 2 pages: 16 operands' worth a step
     ("zaya1", 64, 64, (2049, 2, 128, 128), 8, (2, 8)),            # 2 KV heads x 8 pages, each page an operand of its own
     ("laguna_full", 24, 168, (2305, 8, 128, 128), 48, (8, 2)),    # 6 query heads a KV head: a 48-row tile
+    ("keye_selected", 16, 264, (8 * 4225, 4, 128, 128), 32, (4, 4)),  # under the rows' selection: an int8 strip of 512 positions an item
 ])
 def test_flash_decode_paged_compiles_for_v5e_under_the_tile_of_each_serve_cell(cell, slots, pages_per_slot, pool, heads, tile, v5e_chip):
     """The paged decode kernel at the serve cells' own shapes, by the
     chip's compiler without the chip: Mosaic takes the tile the pool's
-    shape gives (a mebibyte of K + V a grid step, twice over in VMEM)."""
+    shape gives (a mebibyte of K + V a grid step, twice over in VMEM) —
+    Keye's under a selection, where the call's name is ``dsa_sparse_decode``."""
     from deepspeed_tpu.ops.kernels.flash_decode import flash_decode_paged, paged_tile, paged_work_list
 
     on_chip = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
     assert paged_tile(on_chip(pool), pages_per_slot) == tile
+    selected = cell == "keye_selected"
 
-    def call(q, k, v, t, p, m):
+    def call(q, k, v, t, p, m, chosen=None):
         work = paged_work_list(p, m, pool[2], pages_per_slot, tile[1])
-        return flash_decode_paged(q, k, v, t, p, work=work, interpret=False)
+        return flash_decode_paged(q, k, v, t, p, work=work, interpret=False, mask=chosen)
 
     compiled = jax.jit(call).lower(on_chip((slots, heads, 1, pool[3])), on_chip(pool), on_chip(pool),
                                    on_chip((slots, pages_per_slot), jnp.int32), on_chip((slots,), jnp.int32),
-                                   on_chip((slots,), jnp.bool_)).compile()
-    assert chip_smoke.mosaic_kernels(compiled.as_text()) == {"flash_decode_paged": 1}
+                                   on_chip((slots,), jnp.bool_),
+                                   *([on_chip((slots, pages_per_slot * pool[2]), jnp.bool_)] if selected else [])).compile()
+    assert chip_smoke.mosaic_kernels(compiled.as_text()) == {"dsa_sparse_decode" if selected else "flash_decode_paged": 1}
 
 
 @pytest.mark.parametrize("cell,heads,kv_heads,chunk,pages_per_slot,masked,tile", [
@@ -465,10 +469,10 @@ def test_keye_steps_compile_for_v5e_with_all_three_leaves_updated_in_place(which
     at 2048 / 768 for its 16 x 8 assignment rows; a chunk of 2,048 holds
     the experts' kernel; neither leaves a copy of a leaf."""
     from deepspeed_tpu.models import keye
-    from deepspeed_tpu.ops.kernels import flash_chunk, grouped_matmul, sparse_decode
+    from deepspeed_tpu.ops.kernels import flash_chunk, flash_decode, grouped_matmul, sparse_decode
 
     monkeypatch.setenv("DS_KERNELS", "1")
-    for mod in (flash_chunk, sparse_decode, grouped_matmul):
+    for mod in (flash_chunk, flash_decode, sparse_decode, grouped_matmul):
         monkeypatch.setattr(mod, "pallas_interpret_default", lambda: False)  # this process's platform is the CPU
     layers, slots, pages, per_slot, chunk = 2, 16, 3329, 264, 2048
     cfg = keye.KeyeConfig(num_hidden_layers=layers, experts_held=(0, 16), vocab_held=18992)

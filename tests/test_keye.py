@@ -214,6 +214,10 @@ def test_init_inference_serves_the_family_on_the_normal_path(served):
     assert "indexer keys (2 layers" in kv["kind"] and "reuse" not in kv  # prefix reuse stays on for this kind
     assert kv["prefix_hits"] >= 1 and kv["tokens_saved"] >= 32 and st["kv_dtype"] == "float32"
     assert st["pool_bytes"] == srv.pool.cache_bytes() == sum(kv["page_leaves"].values())
+    # the walk's counters are read off the K leaf of the three: one block of heads, the slot's eight pages one item
+    steps = sum(m - 1 for _, m in reqs)
+    assert st["decode_grid_steps"] == steps and st["decode_pages_read"] == 8 * steps >= st["decode_pages_walked"] == sum(
+        (f - 1) // 16 + 1 for f in fills)
     assert srv.pool.shape_math().count("float32") == 1
 
 

@@ -132,46 +132,141 @@ def _paged(rng, B, P, page_len, NP):
     return jnp.asarray(table)
 
 
-@pytest.mark.parametrize("P", [3, 4, 6])  # spans of 1, 4 and 2 pages a grid step
+def _k_pages(NP, Hkv=2, page_len=128, d=128):
+    """The K pages both kernels' span is read off: 2 KV heads x 128 x 128 bf16 = 128 KB of K + V a page."""
+    return jax.ShapeDtypeStruct((NP, Hkv, page_len, d), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("shape,dtype,P,span", [
+    ((8, 4225, 4, 128, 128), jnp.bfloat16, 264, 4),   # Keye's pool: 256 KB a page, four an item, a mebibyte a grid step
+    ((20, 2, 128, 128), jnp.bfloat16, 3, 1),          # three pages a slot: no span but 1 divides
+    ((20, 2, 128, 128), jnp.bfloat16, 6, 2),
+    ((20, 2, 128, 128), jnp.bfloat16, 4, 4),
+    ((20, 2, 128, 128), jnp.bfloat16, 16, 8),         # 128 KB a page: eight fit the budget
+    ((20, 8, 128, 128), jnp.float32, 16, 1),          # a page of a mebibyte: one
+], ids=["keye", "three_pages", "six_pages", "four_pages", "small_pages", "large_pages"])
+def test_the_span_of_both_kernels_is_the_paged_tiles(shape, dtype, P, span):
+    """One span rule: ``span_of`` is ``flash_decode.paged_tile``'s, and
+    ``work_list`` is ``paged_work_list`` under it — what the indexer's
+    scores and the attention both walk."""
+    from deepspeed_tpu.ops.kernels import flash_decode as fd
+
+    pages = jax.ShapeDtypeStruct(shape, dtype)
+    assert kern.span_of(pages, P) == fd.paged_tile(pages, P)[1] == span
+    pos, live = jnp.asarray([5, 130, P * 128 - 1], jnp.int32), jnp.asarray([True, False, True])
+    got, want = kern.work_list(pos, live, pages, P), fd.paged_work_list(pos, live, 128, P, span)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert got[0].shape == (3 * P // span,) and int(got[2][0]) == 1 + P // span
+
+
+@pytest.mark.parametrize("P", [3, 4, 6, 8])  # spans of 1, 4, 2 and 8 pages a grid step
 @pytest.mark.parametrize("live", [None, [True, False, True]])
 def test_dsa_index_scores_paged_in_interpret_mode_against_the_jnp_form(live, P):
     rng = np.random.default_rng(5)
     B, Hi, di, L, NP, page_len, layer = 3, 4, 64, 2, 20, 128, 1
-    assert kern.span_of(P) == {3: 1, 4: 4, 6: 2}[P]
+    assert kern.span_of(_k_pages(NP), P) == {3: 1, 4: 4, 6: 2, 8: 8}[P]
     pool = jnp.asarray(rng.standard_normal((L, NP, di, page_len)), jnp.bfloat16)
     qi, w = jnp.asarray(rng.standard_normal((B, Hi, di)), jnp.float32), jnp.asarray(rng.standard_normal((B, Hi)), jnp.float32)
     table, pos = _paged(rng, B, P, page_len, NP), jnp.asarray([5, 130, 383], jnp.int32)
-    work = kern.work_list(pos, None if live is None else jnp.asarray(live), page_len, P)
+    work = kern.work_list(pos, None if live is None else jnp.asarray(live), _k_pages(NP), P)
     got = np.asarray(kern.dsa_index_scores_paged(qi, w, pool, layer, table, pos, work, interpret=True))
     want = np.asarray(dsa.index_scores(qi[:, None], w[:, None], dsa.index_context(pool, layer, table))[:, 0])
     for b in range(B):
         if live is None or live[b]:
             n = (int(pos[b]) // page_len + 1) * page_len  # the filled pages: what the list visits
             np.testing.assert_allclose(got[b, :n], want[b, :n], rtol=1e-5, atol=1e-5)
+    if live is None:  # no list given: the filled pages, one an item
+        alone = np.asarray(kern.dsa_index_scores_paged(qi, w, pool, layer, table, pos, interpret=True))
+        for b in range(B):
+            n = (int(pos[b]) // page_len + 1) * page_len
+            np.testing.assert_array_equal(alone[b, :n], got[b, :n])
 
 
-@pytest.mark.parametrize("P", [3, 4, 6])
-@pytest.mark.parametrize("live", [None, [True, False, True]])
-def test_dsa_sparse_decode_in_interpret_mode_against_the_jnp_form(live, P):
-    rng = np.random.default_rng(6)
-    B, H, Hkv, d, NP, page_len = 3, 8, 2, 128, 20, 128
+SPANS = {1: 3, 2: 6, 4: 12, 8: 16}  # pages an item: pages a slot that give it at 128 KB a page, two items a slot at the least
+
+
+def _selection_case(rng, span, group, Hkv=2, d=128, page_len=128):
+    """Four rows on the merged body: one whose last span reaches past its
+    position, one whose **first item holds nothing selected**, one that
+    selects nothing, one filled to the slot's last position but 77."""
+    P = SPANS[span]
+    B, H, NP = 4, Hkv * group, 1 + 4 * P
     kc = jnp.asarray(rng.standard_normal((NP, Hkv, page_len, d)), jnp.bfloat16)
     vc = jnp.asarray(rng.standard_normal((NP, Hkv, page_len, d)), jnp.bfloat16)
     q = jnp.asarray(rng.standard_normal((B, H, 1, d)), jnp.bfloat16)
-    table, pos = _paged(rng, B, P, page_len, NP), jnp.asarray([5, 130, 383], jnp.int32)
-    reach = np.arange(P * page_len)[None, :] <= np.asarray(pos)[:, None]
-    mask = reach & (rng.random((B, P * page_len)) < 0.3)
-    mask[1, :128] = False  # a whole page with nothing selected, in front of one with something
-    mask[1, 129] = True
+    item = span * page_len
+    pos = np.asarray([5, item + 2, P * page_len - 1, P * page_len - 78], np.int32)
+    mask = (np.arange(P * page_len)[None, :] <= pos[:, None]) & (rng.random((B, P * page_len)) < 0.3)
+    mask[0, 3] = True
+    mask[1, :item] = False   # the row's first item adds nothing: its running maximum is still NEG_INF when the second starts
+    mask[1, item + 1] = True
+    mask[2] = False          # a row that selects nothing reads 0
+    return q, kc, vc, _paged(rng, B, P, page_len, NP), jnp.asarray(pos), mask, P
+
+
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("span", list(SPANS))
+@pytest.mark.parametrize("live", [None, [True, True, True, False]])
+def test_dsa_sparse_decode_in_interpret_mode_against_the_jnp_form(live, span, group):
+    """``flash_decode_paged`` under a selection (the body
+    ``dsa_sparse_decode`` hands over to) against the gathered rows under
+    the mask, at every span the tile rule gives and at multi-head and
+    grouped queries."""
+    q, kc, vc, table, pos, mask, P = _selection_case(np.random.default_rng(6 + span + group), span, group)
+    assert kern.span_of(kc, P) == span
     lv = None if live is None else jnp.asarray(live)
-    work = kern.work_list(pos, lv, page_len, P)
+    work = kern.work_list(pos, lv, kc, P)
     got = np.asarray(kern.dsa_sparse_decode(q, kc, vc, table, pos, jnp.asarray(mask), None, work, interpret=True), np.float32)
     if live is not None:
         mask = mask & np.asarray(live)[:, None]
-    want = np.asarray(dsa.selected_decode_reference(q, kc, vc, table, jnp.asarray(mask), d ** -0.5), np.float32)
+    want = np.asarray(dsa.selected_decode_reference(q, kc, vc, table, jnp.asarray(mask), 128 ** -0.5), np.float32)
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+    assert got[0].any() and got[1].any() and not got[2].any()  # a row that selects nothing reads 0
     if live is not None:
-        assert not got[1].any()  # a row that does not decode reads 0
+        assert not got[3].any()  # a row that does not decode reads 0
+    else:  # no list given: the rows' filled spans, every row live
+        alone = np.asarray(kern.dsa_sparse_decode(q, kc, vc, table, pos, jnp.asarray(mask), interpret=True), np.float32)
+        np.testing.assert_array_equal(alone, got)
+
+
+@pytest.mark.parametrize("form", ["float32_strip", "int8_strip"])
+def test_the_selection_may_come_as_a_strip_of_ones_where_selected(form):
+    """The operand is the mask as int8, whatever it came as: a strip that is
+    1 where a position is selected and 0 elsewhere, float32 or int8, reads
+    as the bool mask does, bit for bit."""
+    q, kc, vc, table, pos, mask, P = _selection_case(np.random.default_rng(11), 4, 8)
+    want = np.asarray(kern.dsa_sparse_decode(q, kc, vc, table, pos, jnp.asarray(mask), interpret=True), np.float32)
+    strip = jnp.asarray(mask, {"float32_strip": jnp.float32, "int8_strip": jnp.int8}[form])
+    got = np.asarray(kern.dsa_sparse_decode(q, kc, vc, table, pos, strip, interpret=True), np.float32)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("window", [None, 200])
+def test_flash_decode_paged_without_a_mask_has_no_selection_operand(window):
+    """What keeps the other families' decode programs what they were: the
+    selection is a branch of Python, so a call without one traces the
+    Mosaic call without the operand, its mask and its name."""
+    from deepspeed_tpu.ops.kernels import flash_decode as fd
+
+    q, kc, vc, table, pos, mask, P = _selection_case(np.random.default_rng(12), 4, 8)
+    span = fd.paged_tile(kc, P)[1]
+
+    def call_of(**kw):
+        jaxpr = jax.make_jaxpr(lambda *a: fd.flash_decode_paged(*a, interpret=True, **kw))(q, kc, vc, table, pos)
+        (eqn,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+        return eqn, str(eqn)
+
+    bare, text = call_of(window=window)
+    # the grid's traced bound, the five prefetched scalars, q, a span of K pages and one of V pages
+    assert len(bare.invars) == 1 + 5 + 1 + 2 * span and not any(v.aval.dtype == jnp.int8 for v in bare.invars)
+    assert ("swa_decode_paged" if window else "flash_decode_paged") in text and "dsa_sparse_decode" not in text
+    under, text = call_of(window=window, mask=jnp.asarray(mask))
+    assert len(under.invars) == len(bare.invars) + 1 and "dsa_sparse_decode" in text
+    strip = under.invars[-1].aval
+    assert strip.dtype == jnp.int8 and strip.shape == (4, P // span, 1, span * 128)
+    # and the unmasked trace does not depend on a masked one having been traced before it
+    assert str(call_of(window=window)[0].params["jaxpr"]) == str(bare.params["jaxpr"])
 
 
 def test_chunk_attention_under_a_selection_mask_is_masked_softmax_attention():
