@@ -924,6 +924,47 @@ def check_swa_decode_paged(s: Smoke) -> float:
     return err
 
 
+def check_mimo_decode_paged(s: Smoke) -> Dict[str, float]:
+    """The paged decode kernel at MiMo-V2-Flash's two geometries — keys
+    192 wide (positions in the lanes), values 128 (row-major), in one
+    call — against the ``jnp`` forms: ``swa_decode_paged`` with **a sink
+    a head** on a lapped ring of 2 pages (64 query heads on 8 KV heads,
+    window 128: rows before the window is full, rows whose ring has
+    lapped many times, a row at a page's last position, one that does not
+    decode), and ``flash_decode_paged`` at 64 / 4 heads over pages by
+    length."""
+    from deepspeed_tpu.ops.kernels.flash_decode import paged_tile, paged_work_list
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    B, H, d, dv, page_len, window, P = 6, 64, 192, 128, 128, 128, 64
+    R = inf.ring_pages_for(window, page_len)
+    rng = np.random.default_rng(s.seed + 1)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)  # noqa: E731
+    wk, wv, q = draw(1 + B * R, 8, page_len, d), draw(1 + B * R, 8, page_len, dv), draw(B, H, 1, d)
+    sink = jnp.asarray(rng.standard_normal((H,)) + 4.0, jnp.float32)
+    ring = inf.ring_table(jnp.arange(B), R, P)
+    pos = jnp.asarray([7, 127, 128, 3000, 8191, 1234], jnp.int32)
+    live = jnp.asarray([True, True, True, True, True, False])
+    work = paged_work_list(pos, live, page_len, P, paged_tile(wk, P, wv)[1], window)
+    got = jax.jit(lambda *a: inf.window_cache_attention(*a[:5], window, use_kernel=True, work=work, sink=a[5]))(q, wk, wv, ring, pos, sink)
+    want = jax.jit(lambda *a: inf.window_cache_attention(*a[:5], window, use_kernel=False, sink=a[5]))(q, wk, wv, ring, pos, sink)
+    bare = jax.jit(lambda *a: inf.window_cache_attention(*a, window, use_kernel=False))(q, wk, wv, ring, pos)
+    swa = _max_err(got[:5], want[:5])
+    check(got.shape == (B, H, 1, dv) and swa <= TOL_BF16,
+          f"swa_decode_paged with sinks on a lapped ring of {R} pages, {H} / 8 heads, keys {d} / values {dv}: max error {swa:.4f} "
+          f"against the jnp form (tolerance {TOL_BF16})")
+    check(float(jnp.abs(got[5].astype(jnp.float32)).max()) == 0.0, "swa_decode_paged with sinks: the row that does not decode reads 0")
+    check(_max_err(bare[:5], want[:5]) > 10 * TOL_BF16, "swa_decode_paged: the sink column takes mass (without it the rows read differently)")
+    k, v, q4 = draw(1 + 3 * 8, 4, page_len, d), draw(1 + 3 * 8, 4, page_len, dv), draw(3, H, 1, d)
+    table = jnp.asarray(1 + np.arange(24, dtype=np.int32).reshape(3, 8))
+    at = jnp.asarray([5, 600, 1023], jnp.int32)
+    full = _max_err(jax.jit(lambda *a: inf.paged_cache_attention(*a, use_kernel=True))(q4, k, v, table, at),
+                    jax.jit(lambda *a: inf.paged_cache_attention(*a, use_kernel=False))(q4, k, v, table, at))
+    check(full <= TOL_BF16, f"flash_decode_paged at {H} / 4 heads, keys {d} / values {dv}: max error {full:.4f} against the lax form (tolerance {TOL_BF16})")
+    say(f"kernel swa_decode_paged (sinks, {d} / {dv}): max error {swa:.4f}; flash_decode_paged ({d} / {dv}): {full:.4f}")
+    return {"swa": swa, "full": full}
+
+
 def check_dsa_sparse_decode(s: Smoke) -> float:
     """``dsa_sparse_decode`` — the paged decode kernel under a selection —
     against the gathered rows under the mask (the lax form) at Keye's tile
@@ -1054,6 +1095,7 @@ def run(s: Smoke, devices: Sequence) -> None:
         check_flash_attention(s)
         check_fused_update(s)
         check_swa_decode_paged(s)
+        check_mimo_decode_paged(s)
         check_dsa_sparse_decode(s)
         check_flash_chunk_paged(s)
     result = train(s, devices[:1])

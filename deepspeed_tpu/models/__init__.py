@@ -15,7 +15,7 @@ from typing import Any, Optional
 # config class name (anywhere in the MRO) -> module of this package
 _CONFIG_FAMILIES = {"GPT2Config": "gpt2", "BertConfig": "bert", "DeepseekV2Config": "deepseek_v2",
                     "SolarOpen2Config": "solar_open2", "ZayaConfig": "zaya", "KeyeConfig": "keye",
-                    "GigaChat35Config": "gigachat35", "LagunaConfig": "laguna"}
+                    "GigaChat35Config": "gigachat35", "LagunaConfig": "laguna", "MiMoV2Config": "mimo_v2"}
 
 
 def family_of(model_config: Any) -> Optional[Any]:

@@ -33,8 +33,16 @@ in skips the causal mask.  Arithmetic as the ``jnp`` form and as
 query selected nothing adds nothing, a row nothing reached (``l == 0``)
 reads 0.  Inference only.
 
-Left to the ``jnp`` form (:func:`flash_chunk_unsupported`): a head
-narrower than the 128 lanes (its pool lies with the positions in the
+**Values narrower than keys** (``d_v != d``: the V pool ``(pages, Hkv,
+page_len, d_v)``): the score product contracts over ``d``, the value
+product and the accumulator are ``d_v`` wide.  Keys whose width is not
+whole lanes (192) lie in the pool with their positions in the lanes
+(``flash_decode_paged``'s rule, an operand at a time): their tile is
+handed over as ``(d, page_len)`` — the pool's own bytes — and the score
+product contracts over its rows.
+
+Left to the ``jnp`` form (:func:`flash_chunk_unsupported`): values
+narrower than the 128 lanes (their pool lies with the positions in the
 lanes: GPT-2), the int8 code + scale pool, chunks and pages that are not
 whole 128-row tiles.
 """
@@ -57,21 +65,23 @@ SCORE_BYTES = 8 << 20  # the float32 score tile (G * tq, ts) of a grid step
 VMEM_LIMIT = 64 << 20  # of a v5e's 128 MiB: the score tile, its probabilities in float32 and in the value's dtype, the operands twice
 
 
-def flash_chunk_unsupported(T: int, d: int, page_len: int, quant: bool) -> str:
+def flash_chunk_unsupported(T: int, d: int, page_len: int, quant: bool, d_v: Optional[int] = None) -> str:
     """Why the kernel does not serve a chunk of these shapes, ``""``
-    where it does: whole 128-row query and key tiles, a head that fills
-    the lanes, an unquantised pool."""
+    where it does: whole 128-row query and key tiles, values that fill
+    the lanes (``d_v``; the keys' ``d`` where not given), an unquantised
+    pool."""
     if quant:
         return "int8 pool (codes + scales)"
-    if d % 128:
-        return f"head dim {d} is narrower than the 128 lanes (the pool lies with its positions in the lanes)"
+    d_v = d if d_v is None else d_v
+    if d_v % 128:
+        return f"head dim {d_v} is narrower than the 128 lanes (the pool lies with its positions in the lanes)"
     if T % 128 or page_len % 128:
         return f"chunk of {T} on pages of {page_len}: not whole 128-row tiles"
     return ""
 
 
-def flash_chunk_supported(T: int, d: int, page_len: int, quant: bool = False) -> bool:
-    return not flash_chunk_unsupported(T, d, page_len, quant)
+def flash_chunk_supported(T: int, d: int, page_len: int, quant: bool = False, d_v: Optional[int] = None) -> bool:
+    return not flash_chunk_unsupported(T, d, page_len, quant, d_v)
 
 
 def chunk_tile(G: int, T: int, P: int, page_len: int):
@@ -91,13 +101,14 @@ def chunk_tile(G: int, T: int, P: int, page_len: int):
     return tq, span
 
 
-def _flash_chunk_kernel(pt_ref, pos_ref, nb_ref, q_ref, *rest, sm_scale: float, span: int, masked: bool):
+def _flash_chunk_kernel(pt_ref, pos_ref, nb_ref, q_ref, *rest, sm_scale: float, span: int, masked: bool,
+                        lanes_hold_rows: bool = False):
     del pt_ref  # the index maps' own
     k_refs, v_refs = rest[:span], rest[span: 2 * span]
     mask_ref = rest[2 * span] if masked else None
     o_ref, m_ref, l_ref, acc_ref = rest[-4:]
     G, tq, d = q_ref.shape[2:]
-    ts = span * k_refs[0].shape[2]
+    ts = span * v_refs[0].shape[2]
     b, qi, si = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(si == 0)
@@ -111,8 +122,11 @@ def _flash_chunk_kernel(pt_ref, pos_ref, nb_ref, q_ref, *rest, sm_scale: float, 
     k_first = si * ts
 
     def fold(causal: bool):
-        k, v = (jnp.concatenate([r[0, 0] for r in refs], axis=0) for refs in (k_refs, v_refs))    # (ts, d): the block's pages, end to end
-        s = jax.lax.dot_general(q_ref[0, 0].reshape(G * tq, d), k, (((1,), (1,)), ((), ())),
+        if lanes_hold_rows:  # keys with their positions in the lanes, (d, ts): the contraction moves
+            k, v = jnp.concatenate([r[0, 0] for r in k_refs], axis=1), jnp.concatenate([r[0, 0] for r in v_refs], axis=0)
+        else:
+            k, v = (jnp.concatenate([r[0, 0] for r in refs], axis=0) for refs in (k_refs, v_refs))    # (ts, d): the block's pages, end to end
+        s = jax.lax.dot_general(q_ref[0, 0].reshape(G * tq, d), k, (((1,), (0 if lanes_hold_rows else 1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale           # (G * tq, ts)
         ok = None
         if causal:
@@ -147,7 +161,7 @@ def _flash_chunk_kernel(pt_ref, pos_ref, nb_ref, q_ref, *rest, sm_scale: float, 
     @pl.when(si == nb_ref[0] - 1)
     def _emit():
         l = l_ref[...]
-        o_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).reshape(G, tq, d).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).reshape(o_ref.shape[2:]).astype(o_ref.dtype)
 
 
 def flash_chunk_paged(q, k_cache, v_cache, page_table, pos, sm_scale: Optional[float] = None, extra_mask=None,
@@ -158,15 +172,17 @@ def flash_chunk_paged(q, k_cache, v_cache, page_table, pos, sm_scale: Optional[f
     ``i`` attends KV head ``i // (H / Hkv)``); ``extra_mask (B, T, P *
     page_len)`` bool, where given, a per-query selection applied beside
     the causal mask.  ``tile = (tq, span)`` overrides :func:`chunk_tile`
-    (a sweep, a test).  Returns ``(B, H, T, d)`` in ``q``'s dtype —
+    (a sweep, a test).  Returns ``(B, H, T, d_v)`` in ``q``'s dtype (``d_v``
+    the V pool's width) —
     :func:`inference.paged_chunk_attention`'s contract, which dispatches
     here; shapes outside :func:`flash_chunk_supported` are its ``jnp``
     form's."""
     quant = isinstance(k_cache, dict)
     B, H, T, d = q.shape
     _, Hkv, page_len, _ = (k_cache["q"] if quant else k_cache).shape
+    d_v = (v_cache["q"] if quant else v_cache).shape[-1]
     P, G = page_table.shape[1], H // Hkv
-    why_not = flash_chunk_unsupported(T, d, page_len, quant)
+    why_not = flash_chunk_unsupported(T, d, page_len, quant, d_v)
     if why_not or H % Hkv:
         raise ValueError(f"flash_chunk_paged cannot serve (H {H} / {Hkv}, T {T}, d {d}, page_len {page_len}): {why_not or 'heads are not whole groups'}; "
                          "callers dispatch through flash_chunk_supported()")
@@ -187,31 +203,39 @@ def flash_chunk_paged(q, k_cache, v_cache, page_table, pos, sm_scale: Optional[f
     q_map = lambda b, h, qi, si, pt, pv, nb: (b, h, 0, qi, 0)  # noqa: E731
     page = lambda j: (lambda b, h, qi, si, pt, pv, nb: (pt[b, jnp.minimum(key_block(b, qi, si, pv) * span + j, P - 1)], h, 0, 0))  # noqa: E731
     pages = [pl.BlockSpec((1, 1, page_len, d), page(j)) for j in range(span)]
-    in_specs = [pl.BlockSpec((1, 1, G, tq, d), q_map)] + pages + pages
+    v_pages = pages
+    lanes_hold_rows = d % 128 != 0  # keys whose width is not whole lanes lie with their positions in the lanes: the tile is (d, page_len)
+    if lanes_hold_rows:
+        k_cache = jnp.swapaxes(k_cache, 2, 3)
+        pages = [pl.BlockSpec((1, 1, d, page_len), page(j)) for j in range(span)]
+    if d_v != d:
+        v_pages = [pl.BlockSpec((1, 1, page_len, d_v), page(j)) for j in range(span)]
+    in_specs = [pl.BlockSpec((1, 1, G, tq, d), q_map)] + pages + v_pages
     args = [q.reshape(B, Hkv, G, T, d)] + [k_cache] * span + [v_cache] * span
     if extra_mask is not None:
         in_specs.append(pl.BlockSpec((1, tq, ts), lambda b, h, qi, si, pt, pv, nb: (b, qi, key_block(b, qi, si, pv))))
         args.append(extra_mask.astype(jnp.int8))  # a byte a position, as the bool it was: the cast fuses into its producer
     out = pl.pallas_call(
-        functools.partial(_flash_chunk_kernel, sm_scale=float(sm_scale), span=span, masked=extra_mask is not None),
+        functools.partial(_flash_chunk_kernel, sm_scale=float(sm_scale), span=span, masked=extra_mask is not None,
+                          lanes_hold_rows=lanes_hold_rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, Hkv, T // tq, nb[0]),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, G, tq, d), q_map),
+            out_specs=pl.BlockSpec((1, 1, G, tq, d_v), q_map),
             scratch_shapes=[
                 pltpu.VMEM((G * tq, 1), jnp.float32),   # m
                 pltpu.VMEM((G * tq, 1), jnp.float32),   # l
-                pltpu.VMEM((G * tq, d), jnp.float32),   # acc
+                pltpu.VMEM((G * tq, d_v), jnp.float32),   # acc
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, T, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, T, d_v), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
                                              vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="flash_chunk_paged",
     )(jnp.asarray(page_table, jnp.int32), pos_vec, nb, *args)
-    return out.reshape(B, H, T, d)
+    return out.reshape(B, H, T, d_v)
 
 
 @register_op("flash_chunk_paged", "pallas", "a prefill chunk's attention over its slot's pages: online softmax, scores in VMEM")

@@ -285,17 +285,19 @@ def decode_paged_supported(B: int, H: int, P: int, page_len: int, d: int) -> boo
 TILE_BYTES = 1 << 20
 
 
-def paged_tile(cache, pages_per_slot: int):
+def paged_tile(cache, pages_per_slot: int, v_cache=None):
     """``(KV heads a program, pages an item)`` of a paged decode call:
     the tile a grid step holds, read off the pool's shape and nothing
     else.  Every KV head of a page whose K + V fit :data:`TILE_BYTES`
     (the largest divisor of ``Hkv`` that does), then a **span** of the
     largest of 8, 4, 2, 1 consecutive pages of a row that divides
     ``pages_per_slot`` and still fits.  ``cache`` is the K pool, its
-    int8 pair, or a ``ShapeDtypeStruct``: ``(..., Hkv, page_len, d)``."""
+    int8 pair, or a ``ShapeDtypeStruct``: ``(..., Hkv, page_len, d)``;
+    ``v_cache`` the V pool where its rows are not as wide as K's."""
     leaf = cache["q"] if isinstance(cache, dict) else cache
     Hkv, page_len, d = leaf.shape[-3:]
-    pair = 2 * page_len * d * jnp.dtype(leaf.dtype).itemsize  # K + V bytes of one KV head of one page
+    d_v = d if v_cache is None else (v_cache["q"] if isinstance(v_cache, dict) else v_cache).shape[-1]
+    pair = page_len * (d + d_v) * jnp.dtype(leaf.dtype).itemsize  # K + V bytes of one KV head of one page
     heads = max(h for h in range(1, Hkv + 1) if Hkv % h == 0 and (h == 1 or h * pair <= TILE_BYTES))
     span = next(c for c in (8, 4, 2, 1) if pages_per_slot % c == 0 and (c == 1 or c * heads * pair <= TILE_BYTES))
     return heads, span
@@ -339,9 +341,11 @@ def _flash_decode_paged_kernel(
     n_ref,            # SMEM (1,) int32 — items to walk: the traced bound of the grid's last axis
     q_ref,            # (1, block_heads, group, d): the query heads of block_heads KV heads
     *rest,            # ``span`` K pages, ``span`` V pages, each (1, block_heads, page_len, d) — THE page pt[slot, span * s + j],
-                      # codes or bf16/f32, (1, block_heads, d, page_len) when ``lanes_hold_rows``;
+                      # codes or bf16/f32, (1, block_heads, d, page_len) when ``lanes_hold_rows`` (an operand whose own width is not
+                      # whole lanes: ``v_lanes_hold_rows`` where V's differs from K's);
                       # [``span`` K scales, ``span`` V scales (1, block_heads, 1, page_len)];
-                      # [the item's strip of the row's selection (1, 1, 1, span * page_len) int8]; o_ref; scratch m, l, acc, scores
+                      # [the item's strip of the row's selection (1, 1, 1, span * page_len) int8];
+                      # [the heads' sink logits (block_heads * group, 1) float32]; o_ref; scratch m, l, acc, scores
     sm_scale: float,
     page_len: int,
     quant: bool,
@@ -350,11 +354,16 @@ def _flash_decode_paged_kernel(
     span: int,
     lanes_hold_rows: bool,
     window: Optional[int] = None,
+    v_lanes_hold_rows: bool = False,
+    selected: bool = False,
+    sunk: bool = False,
 ):
     k_refs, v_refs, *scales = (rest[g * span: (g + 1) * span] for g in range(4 if quant else 2))
     ks_refs, vs_refs = scales or (None, None)
-    *selection, o_ref, m_ref, l_ref, acc_ref, s_ref = rest[(4 if quant else 2) * span:]
-    chosen_ref = selection[0] if selection else None  # the call was given a selection: Python's branch, nothing of it is traced without one
+    *extra, o_ref, m_ref, l_ref, acc_ref, s_ref = rest[(4 if quant else 2) * span:]
+    # the call was given a selection, a sink: Python's branches, nothing of either is traced without one
+    chosen_ref = extra.pop(0) if selected else None
+    sink_ref = extra.pop(0) if sunk else None
 
     i = pl.program_id(1)
     b, s_idx = slot_ref[i], span_ref[i]
@@ -372,8 +381,14 @@ def _flash_decode_paged_kernel(
     def _item():
         @pl.when(s_idx == first)
         def _init():
-            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[:] = jnp.zeros_like(l_ref)
+            if sink_ref is None:
+                m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+                l_ref[:] = jnp.zeros_like(l_ref)
+            else:
+                # the sink: one more column of the row's softmax, a logit a head that takes mass and carries no value — it
+                # enters pass (2)'s running maximum and denominator once, here: exp(sink - m) is 1 with the maximum at the sink
+                m_ref[:] = sink_ref[:]
+                l_ref[:] = jnp.ones_like(l_ref)
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
         # Three passes over the item, so that no product waits for another
@@ -423,7 +438,7 @@ def _flash_decode_paged_kernel(
                 jax.lax.dot_general(
                     s_ref[rows, cols[j]] * vs_refs[j][0, h] if quant else s_ref[rows, cols[j]],
                     v_refs[j][0, h].astype(jnp.float32),
-                    dimension_numbers=(((1,), (1 if lanes_hold_rows else 0,)), ((), ())),
+                    dimension_numbers=(((1,), (1 if v_lanes_hold_rows else 0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 ) for j in range(span))
 
@@ -445,6 +460,7 @@ def flash_decode_paged(
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
     mask=None,
+    sink=None,
 ) -> jnp.ndarray:
     """Single-query attention against a PAGED pool (docs/serving.md
     §Paged KV & prefix caching): caches are ``(num_pages, Hkv, page_len,
@@ -501,6 +517,21 @@ def flash_decode_paged(
     The branch is Python's: with ``mask=None`` the call traces what it
     traced without the operand.
 
+    **Values narrower than keys** (the V cache ``(num_pages, Hkv,
+    page_len, d_v)`` with ``d_v != d``): the score pass contracts over
+    ``d``, the value pass over pages ``d_v`` wide, and the output is ``(B,
+    H, 1, d_v)``.  Each operand's tile takes the form its **own** width
+    says (``lanes_hold_rows`` below): keys 192 wide lie with their
+    positions in the lanes, values 128 wide row-major, in one call.
+
+    **A sink** (``sink (H,)`` float32 given: a learned logit a head that
+    stands as one more column of the row's softmax, taking mass and
+    carrying no value) rides as one more operand, ``(H, 1)``, and enters
+    pass (2)'s running maximum and denominator once a row — at the row's
+    first item the maximum starts at the sink and the denominator at 1 —
+    under either kernel name.  Python's branch again: with ``sink=None``
+    and ``d_v == d`` the call traces what it traced before.
+
     The page table rides the grid as a **prefetched scalar** too
     (``PrefetchScalarGridSpec``), so each program's K/V pages stream
     HBM→VMEM directly — the gather the lax path materializes never
@@ -520,6 +551,7 @@ def flash_decode_paged(
     v_op = v_cache["q"] if quant else v_cache
     B, H, T, d = q.shape
     NP, Hkv, page_len, _ = k_op.shape
+    d_v = v_op.shape[-1]
     P = page_table.shape[1]
     if T != 1:
         raise ValueError(f"flash_decode_paged serves exactly one query per slot, got T={T}")
@@ -536,17 +568,21 @@ def flash_decode_paged(
     if interpret is None:
         interpret = pallas_interpret_default()
     group = H // Hkv
-    bh, span = paged_tile(k_op, P)
+    bh, span = paged_tile(k_op, P, v_op)
     # A head narrower than the 128 lanes: the TPU stores ``(..., page_len,
     # d)`` with ``page_len`` in the lanes (``d`` there would pad every
     # row to 128), so the tile Mosaic is handed is ``(d, page_len)`` —
     # the pool's own bytes under another name, where a ``(page_len, d)``
     # tile is a relayout of the whole pool in front of every call
-    # (docs/kernels.md).  A rule on the shape, for every caller.
-    lanes_hold_rows = d % 128 != 0
+    # (docs/kernels.md).  A rule on the shape, for every caller — and on
+    # each operand's own width where K's and V's differ.
+    lanes_hold_rows, v_lanes_hold_rows = d % 128 != 0, d_v % 128 != 0
     if lanes_hold_rows:
-        k_op, v_op = jnp.swapaxes(k_op, 2, 3), jnp.swapaxes(v_op, 2, 3)
+        k_op = jnp.swapaxes(k_op, 2, 3)
+    if v_lanes_hold_rows:
+        v_op = jnp.swapaxes(v_op, 2, 3)
     page_block = (1, bh, d, page_len) if lanes_hold_rows else (1, bh, page_len, d)
+    v_block = (1, bh, d_v, page_len) if v_lanes_hold_rows else (1, bh, page_len, d_v)
 
     table = jnp.asarray(page_table, jnp.int32)
     # a position is inside the slot: the page it lies in is an item of the list
@@ -562,7 +598,8 @@ def flash_decode_paged(
     row = lambda h, i, pt, pv, sl, sp, n: (sl[i], h, 0, 0)  # noqa: E731
     kv_page = lambda j: (lambda h, i, pt, pv, sl, sp, n: (pt[sl[i], sp[i] * span + j], h, 0, 0))  # noqa: E731
     pages = [pl.BlockSpec(page_block, kv_page(j)) for j in range(span)]
-    in_specs = [pl.BlockSpec((1, bh, group, d), row)] + pages + pages
+    v_pages = pages if v_block == page_block else [pl.BlockSpec(v_block, kv_page(j)) for j in range(span)]
+    in_specs = [pl.BlockSpec((1, bh, group, d), row)] + pages + v_pages
     args = [q.reshape(B, Hkv, group, d)] + [k_op] * span + [v_op] * span
     if quant:
         # (NP, H, page_len, 1) scales -> (NP, H, 1, page_len) row
@@ -574,6 +611,9 @@ def flash_decode_paged(
     if mask is not None:
         in_specs.append(pl.BlockSpec((1, 1, 1, span * page_len), lambda h, i, pt, pv, sl, sp, n: (sl[i], sp[i], 0, 0)))
         args.append(mask.astype(jnp.int8).reshape(B, P // span, 1, span * page_len))
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((bh * group, 1), lambda h, i, pt, pv, sl, sp, n: (h, 0)))
+        args.append(jnp.asarray(sink, jnp.float32).reshape(H, 1))
 
     kern = functools.partial(
         _flash_decode_paged_kernel,
@@ -585,24 +625,27 @@ def flash_decode_paged(
         span=span,
         lanes_hold_rows=lanes_hold_rows,
         **({} if window is None else {"window": int(window)}),
+        v_lanes_hold_rows=v_lanes_hold_rows,
+        selected=mask is not None,
+        sunk=sink is not None,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         # one step at least: a grid bound of zero is nothing a compiled program needs to meet
         grid=(Hkv // bh, jnp.maximum(n[0], 1)),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bh, group, d), row),
+        out_specs=pl.BlockSpec((1, bh, group, d_v), row),
         scratch_shapes=[
             pltpu.VMEM((bh * group, 1), jnp.float32),   # m
             pltpu.VMEM((bh * group, 1), jnp.float32),   # l
-            pltpu.VMEM((bh * group, d), jnp.float32),   # acc
+            pltpu.VMEM((bh * group, d_v), jnp.float32),   # acc
             pltpu.VMEM((bh * group, span * page_len), jnp.float32),   # an item's scores, then its probabilities
         ],
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, d_v), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
@@ -610,7 +653,7 @@ def flash_decode_paged(
         name="dsa_sparse_decode" if mask is not None else "flash_decode_paged" if window is None else "swa_decode_paged",
     )(table, pos_vec, slot, span_idx, n, *args)
     # rows no item visited hold whatever the output buffer held
-    return jnp.where(live[:, None, None, None], out, 0).reshape(B, H, 1, d)
+    return jnp.where(live[:, None, None, None], out, 0).reshape(B, H, 1, d_v)
 
 
 def flash_decode_reference(q, k_cache, v_cache, pos, sm_scale=None, key_padding_mask=None):

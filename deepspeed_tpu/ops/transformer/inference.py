@@ -83,7 +83,8 @@ def _wmm(h: jnp.ndarray, w) -> jnp.ndarray:
     return h @ w.astype(h.dtype)
 
 
-def init_kv_cache(n_layer: int, batch: int, heads: int, max_len: int, head_dim: int, dtype=jnp.bfloat16):
+def init_kv_cache(n_layer: int, batch: int, heads: int, max_len: int, head_dim: int, dtype=jnp.bfloat16,
+                  v_dim: Optional[int] = None):
     """Static-capacity KV cache, stacked on a leading layer dim so it scans
     with the stacked blocks (the reference grows ``layer_past`` tensors
     per step; static shapes are the XLA-friendly equivalent).
@@ -91,15 +92,16 @@ def init_kv_cache(n_layer: int, batch: int, heads: int, max_len: int, head_dim: 
     ``dtype="int8"``: each cache is a ``{"q": int8, "s": f32}`` pair —
     per-(b,h,pos) absmax row quantization over head_dim.  ~2× less HBM
     traffic per decoded token than bf16 for the cache read (the term
-    that grows with context length)."""
+    that grows with context length).
+
+    ``v_dim``: the value's width where it is not the key's (the V cache's
+    last dim; each as wide as it is, neither padded to the other)."""
     shape = (n_layer, batch, heads, max_len, head_dim)
+    v_shape = shape if v_dim is None else shape[:-1] + (int(v_dim),)
     if dtype == "int8" or dtype == jnp.int8:
-        c = {
-            "q": jnp.zeros(shape, jnp.int8),
-            "s": jnp.zeros(shape[:-1] + (1,), jnp.float32),
-        }
-        return c, {k: jnp.zeros_like(v) for k, v in c.items()}
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+        pair = lambda sh: {"q": jnp.zeros(sh, jnp.int8), "s": jnp.zeros(sh[:-1] + (1,), jnp.float32)}  # noqa: E731
+        return pair(shape), pair(v_shape)
+    return jnp.zeros(shape, dtype), jnp.zeros(v_shape, dtype)
 
 
 def _kv_quant(t: jnp.ndarray):
@@ -369,7 +371,7 @@ def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
         page_len = (k_cache["q"] if quant else k_cache).shape[2]
         if decode_paged_supported(B, H, page_table.shape[1], page_len, d):
             if trace_notes is not None:
-                heads, span = paged_tile(k_cache, page_table.shape[1])
+                heads, span = paged_tile(k_cache, page_table.shape[1], v_cache)
                 trace_notes["paged_decode_walk"] = f"work list, {heads} heads x {span} page{'s' if span > 1 else ''}"
             return flash_decode_paged(
                 q, k_cache, v_cache, page_table, pos, sm_scale=sm_scale, work=work
@@ -382,7 +384,8 @@ def paged_cache_attention(q, k_cache, v_cache, page_table, pos,
     return cache_attention(q, gk, gv, pos, sm_scale=sm_scale, use_kernel=False)
 
 
-def chunk_attention_form(use_kernel: Optional[bool], quant: bool, H: int, Hkv: int, T: int, d: int, page_len: int):
+def chunk_attention_form(use_kernel: Optional[bool], quant: bool, H: int, Hkv: int, T: int, d: int, page_len: int,
+                         d_v: Optional[int] = None):
     """Which form a prefill chunk of these shapes takes over its pages:
     ``(kernel, why_not)`` — ``flash_chunk_paged`` when the suite is armed
     (or ``use_kernel`` says so) and the kernel serves what the input
@@ -393,9 +396,10 @@ def chunk_attention_form(use_kernel: Optional[bool], quant: bool, H: int, Hkv: i
 
     if use_kernel is None:
         use_kernel = _kernels.flash_decode_armed()
-    why_not = flash_chunk_unsupported(T, d, page_len, quant) if use_kernel else "kernel suite not armed"
-    _kernels.warn_once(("flash_chunk_paged", H, Hkv, T, d, why_not),
-                       f"kernels: a prefill chunk's attention over its pages ({H} / {Hkv} heads of {d}, T {T}) takes "
+    why_not = flash_chunk_unsupported(T, d, page_len, quant, d_v) if use_kernel else "kernel suite not armed"
+    widths = d if d_v in (None, d) else f"{d} / {d_v}"
+    _kernels.warn_once(("flash_chunk_paged", H, Hkv, T, widths, why_not),
+                       f"kernels: a prefill chunk's attention over its pages ({H} / {Hkv} heads of {widths}, T {T}) takes "
                        + (f"the jnp form of paged_chunk_attention: {why_not}" if why_not else "flash_chunk_paged"), level="info")
     return not why_not, why_not
 
@@ -427,7 +431,9 @@ def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Option
     ``extra_mask (B, T, P * page_len)`` bool, where given, is a per-query
     selection of the context applied beside the causal mask (learned
     sparse attention: the same dense walk, fewer keys let through).
-    Returns ``(B, H, T, d)`` in ``q``'s dtype.
+    Returns ``(B, H, T, d_v)`` in ``q``'s dtype, ``d_v`` the V cache's
+    width (the key's ``d`` everywhere but where a family's values are
+    narrower than its keys).
 
     The walk is the Mosaic kernel
     :func:`deepspeed_tpu.ops.kernels.flash_chunk.flash_chunk_paged` — the
@@ -441,8 +447,9 @@ def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Option
     quant = isinstance(k_cache, dict)
     B, H, T, d = q.shape
     _, Hkv, page_len, _ = (k_cache["q"] if quant else k_cache).shape
+    dv = (v_cache["q"] if quant else v_cache).shape[-1]
     P, G = page_table.shape[1], H // Hkv
-    kernel, why_not = chunk_attention_form(use_kernel, quant, H, Hkv, T, d, page_len)
+    kernel, why_not = chunk_attention_form(use_kernel, quant, H, Hkv, T, d, page_len, dv)
     if trace_notes is not None:
         trace_notes.update(chunk_attention_kernel=kernel, chunk_attention_fallback=why_not)
     if kernel:
@@ -458,7 +465,7 @@ def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Option
     qg = q.reshape(B, Hkv, G, T, d)
     q_pos = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (B, T)
 
-    if d % 128:
+    if d % 128 or dv % 128:
         # A head narrower than the 128 lanes lies with its positions in
         # the lanes (flash_decode_paged).  A gather wants its operand
         # row-major, and a loop body that slices the pool picks a layout
@@ -515,9 +522,9 @@ def paged_chunk_attention(q, k_cache, v_cache, page_table, pos, sm_scale: Option
         return m_new, l, acc
 
     stat = (B, Hkv, G, T)
-    init = (jnp.full(stat, -1e30, jnp.float32), jnp.zeros(stat, jnp.float32), jnp.zeros(stat + (d,), jnp.float32))
+    init = (jnp.full(stat, -1e30, jnp.float32), jnp.zeros(stat, jnp.float32), jnp.zeros(stat + (dv,), jnp.float32))
     _, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
-    return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).reshape(B, H, T, d).astype(q.dtype)
+    return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).reshape(B, H, T, dv).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -568,8 +575,19 @@ def ring_chunk_write(pool, layer, t, ring, pos, n_valid, ring_pages: int):
     return pool
 
 
+def sink_softmax(s, sink):
+    """Softmax over the last axis of float32 scores ``s`` with **one more
+    column** of logit ``sink`` (broadcast against ``s[..., :1]``) in the
+    maximum and the denominator: the column takes its share of the mass
+    and is not returned — the probabilities sum to less than one."""
+    sink = sink.astype(jnp.float32)
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), sink)
+    e = jnp.exp(s - m)
+    return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
 def window_chunk_attention(q, k, v, k_cache, v_cache, ring, pos, window: int, sm_scale: Optional[float] = None,
-                           query_block: int = 256):
+                           query_block: int = 256, sink=None):
     """A prefill chunk of a **window layer**: ``q (B, H, T, d)`` at
     positions ``pos[b] + t`` (``pos`` page-aligned) over the band ``p -
     window < j <= p``.  The chunk's own keys and values ``k, v (B, Hkv,
@@ -582,10 +600,15 @@ def window_chunk_attention(q, k, v, k_cache, v_cache, ring, pos, window: int, sm
     meets the ``earlier + query_block`` keys from its window's first
     page to its own last query — a static slice — under the band mask,
     and no block outside the band is computed, whatever the context.
-    Grouped queries as :func:`paged_chunk_attention`.  Returns ``(B, H,
-    T, d)`` in ``q``'s dtype."""
+    Grouped queries as :func:`paged_chunk_attention`.  ``sink (H,)``
+    float32, where given, is a learned logit a head that stands as **one
+    more column of every query's softmax** — it takes mass and carries no
+    value (attention sinks) — entering the row's maximum and denominator
+    and nothing else.  Returns ``(B, H, T, d_v)`` in ``q``'s dtype (``d_v``
+    the values' width, the keys' unless a family's differ)."""
     B, H, T, d = q.shape
     _, Hkv, page_len, _ = k_cache.shape
+    dv = v.shape[-1]
     G, n_prev = H // Hkv, ring_pages_for(window, page_len) - 1
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
@@ -610,22 +633,30 @@ def window_chunk_attention(q, k, v, k_cache, v_cache, ring, pos, window: int, sm
             k_off = i * Tq - E + jnp.arange(E + Tq, dtype=jnp.int32)[None, :]
             band = (k_off <= q_off) & (k_off > q_off - window)               # (Tq, E + Tq)
             ok = band[None] & (pos[:, None, None] + k_off[None] >= 0)        # nothing lies before the sequence
-            p = jax.nn.softmax(jnp.where(ok[:, None, None], s, -1e30), axis=-1).astype(q.dtype)
+            s = jnp.where(ok[:, None, None], s, -1e30)
+            if sink is None:
+                p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            else:
+                p = sink_softmax(s, sink.reshape(1, Hkv, G, 1, 1)).astype(q.dtype)
             out.append(jnp.einsum("bhgts,bhsd->bhgtd", p, vs, preferred_element_type=jnp.float32))
-        return jnp.concatenate(out, axis=3).reshape(B, H, T, d).astype(q.dtype)
+        return jnp.concatenate(out, axis=3).reshape(B, H, T, dv).astype(q.dtype)
 
 
 def window_cache_attention(q, k_cache, v_cache, ring, pos, window: int, sm_scale: Optional[float] = None,
-                           use_kernel: Optional[bool] = None, work=None, trace_notes: Optional[dict] = None):
+                           use_kernel: Optional[bool] = None, work=None, trace_notes: Optional[dict] = None, sink=None):
     """One query a row against a window layer's ring: ``q (B, H, 1, d)``
     at ``pos (B,)`` (its own key already written) over positions ``pos -
     window < j <= pos``.  The paged decode kernel under its window form
     (``swa_decode_paged``: ``flash_decode_paged(..., window=)``, ``work``
     its list of the window's spans) where the suite is armed and the
     page geometry qualifies; else the ring's pages gathered and attended
-    in ``jnp`` — the numerics ground truth."""
+    in ``jnp`` — the numerics ground truth.  ``sink (H,)`` float32 as
+    :func:`window_chunk_attention` takes it; the output is as wide as the
+    V ring's rows."""
     B, H, _, d = q.shape
     _, Hkv, page_len, _ = k_cache.shape
+    dv = v_cache.shape[-1]
+    said = "" if sink is None and dv == d else f"; keys {d} / values {dv} wide" + ("" if sink is None else ", a sink column a head")
     if use_kernel is None:
         from deepspeed_tpu.ops import kernels as _kernels
 
@@ -635,12 +666,12 @@ def window_cache_attention(q, k_cache, v_cache, ring, pos, window: int, sm_scale
 
         if decode_paged_supported(B, H, ring.shape[1], page_len, d):
             if trace_notes is not None:
-                heads, span = paged_tile(k_cache, ring.shape[1])
+                heads, span = paged_tile(k_cache, ring.shape[1], v_cache)
                 trace_notes["swa_decode_form"] = (f"swa_decode_paged: work list of the window's spans, {heads} heads x {span} "
-                                                  f"page{'s' if span > 1 else ''}, {H // Hkv} query heads a KV head")
-            return flash_decode_paged(q, k_cache, v_cache, ring, pos, sm_scale=sm_scale, work=work, window=window)
+                                                  f"page{'s' if span > 1 else ''}, {H // Hkv} query heads a KV head" + said)
+            return flash_decode_paged(q, k_cache, v_cache, ring, pos, sm_scale=sm_scale, work=work, window=window, sink=sink)
     if trace_notes is not None:
-        trace_notes["swa_decode_form"] = "jnp over the ring's pages (gather)"
+        trace_notes["swa_decode_form"] = "jnp over the ring's pages (gather)" + said
     R = ring_pages_for(window, page_len)
     lp = pos[:, None] // page_len - (R - 1) + jnp.arange(R, dtype=jnp.int32)[None, :]  # (B, R): the pages the window lies in
     pages = jnp.take_along_axis(ring, jnp.clip(lp, 0, ring.shape[1] - 1), axis=1)
@@ -649,8 +680,9 @@ def window_cache_attention(q, k_cache, v_cache, ring, pos, window: int, sm_scale
     ok = (k_pos <= pos[:, None]) & (k_pos > pos[:, None] - window) & (k_pos >= 0)
     keys, vals = (paged_gather(c, pages).astype(jnp.float32) for c in (k_cache, v_cache))  # (B, Hkv, R * page_len, d)
     s = jnp.einsum("bhgd,bhsd->bhgs", q.reshape(B, Hkv, H // Hkv, d).astype(jnp.float32), keys) * (sm_scale or 1.0 / (d ** 0.5))
-    p = jax.nn.softmax(jnp.where(ok[:, None, None], s, NEG_INF), axis=-1)
-    return jnp.einsum("bhgs,bhsd->bhgd", p, vals).reshape(B, H, 1, d).astype(q.dtype)
+    s = jnp.where(ok[:, None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1) if sink is None else sink_softmax(s, sink.reshape(1, Hkv, H // Hkv, 1))
+    return jnp.einsum("bhgs,bhsd->bhgd", p, vals).reshape(B, H, 1, dv).astype(q.dtype)
 
 
 def cache_attention(q, k_cache, v_cache, pos, sm_scale: Optional[float] = None,

@@ -245,7 +245,7 @@ class ServingEngine:
             if self.pool.v is not None and (not isinstance(k, dict) or "q" in k):  # K/V pages a KV head: flash_decode_paged's
                 from deepspeed_tpu.ops.kernels.flash_decode import paged_tile
 
-                heads, span = paged_tile(k, self.pool.pages_per_slot)
+                heads, span = paged_tile(k, self.pool.pages_per_slot, self.pool.v)
                 self._decode_tile = (jax.tree.leaves(k)[0].shape[2] // heads, span)
             make_forward = family.serving_forward
         elif not isinstance(kind, PerHeadKV):
@@ -1617,6 +1617,9 @@ class ServingEngine:
         }
         if self._paged:
             out["kvcache"] = self.pool.stats()
+            if "groups" in out["kvcache"]:  # two page groups in one pool: each one's geometry and bytes, beside the counters
+                out["kv_groups"] = {name: {key: g[key] for key in ("kv_heads", "k_dim", "v_dim", "bytes")}
+                                    for name, g in out["kvcache"]["groups"].items()}
             self._publish_kvcache()
             out["decode_pages_walked"] = self._decode_pages_walked
             # what a grid of every page of every slot walks

@@ -81,10 +81,14 @@ def _scatter_page(pool, src, dst):
 
 class PerHeadKV:
     """The default cache kind: a K and a V buffer of ``(layers, pages,
-    heads, page_len, head_dim)`` (or the int8 code+scale pair)."""
+    heads, page_len, head_dim)`` (or the int8 code+scale pair).  ``v_dim``
+    is the value's width where it is not the key's (a V buffer ``(...,
+    page_len, v_dim)``): each leaf is stored as wide as it is, neither
+    padded to the other."""
 
-    def __init__(self, heads: int, head_dim: int, dtype: Any):
+    def __init__(self, heads: int, head_dim: int, dtype: Any, v_dim: Optional[int] = None):
         self.heads, self.head_dim, self.dtype = int(heads), int(head_dim), dtype
+        self.v_dim = self.head_dim if v_dim is None else int(v_dim)
 
     @staticmethod
     def copy_page(pool, src, dst):
@@ -97,9 +101,19 @@ class PerHeadKV:
     def buffers(self, n_layer: int, num_pages: int, page_len: int):
         from deepspeed_tpu.ops.transformer.inference import init_kv_cache
 
-        return init_kv_cache(n_layer, num_pages, self.heads, page_len, self.head_dim, self.dtype)
+        return init_kv_cache(n_layer, num_pages, self.heads, page_len, self.head_dim, self.dtype, self.v_dim)
+
+    def position_bytes(self) -> int:
+        """Bytes one position of one layer costs: K and V of every KV head, as wide as each is stored (the int8
+        pair: a byte a code and a float32 scale a row)."""
+        if self.dtype == "int8" or self.dtype == jnp.int8:
+            return self.heads * (self.head_dim + self.v_dim + 2 * 4)
+        return self.heads * (self.head_dim + self.v_dim) * np.dtype(self.dtype).itemsize
 
     def describe(self, n_layer: int, num_pages: int, page_len: int) -> str:
+        if self.v_dim != self.head_dim:
+            return (f"({n_layer} layers x {num_pages} pages x {self.heads} heads x {page_len} page_len) x "
+                    f"(K {self.head_dim} + V {self.v_dim})")
         return (f"2 x ({n_layer} layers x {num_pages} pages x {self.heads} heads x "
                 f"{page_len} page_len x {self.head_dim} head_dim)")
 
@@ -226,7 +240,9 @@ class WindowedKV(HybridKV):
     """Cache kind of a model that mixes **full-attention** layers with
     layers that attend over a **window** of their last ``window``
     positions (docs/serving.md §Cache kinds).  Two page groups in one
-    pool, K and V in the :class:`PerHeadKV` layout in both:
+    pool, K and V in the :class:`PerHeadKV` layout in both, **each group
+    of its own geometry** (``window_pages``: the window group's KV heads,
+    key and value widths; default: the full group's):
 
     * **full** — ``pool.k`` / ``pool.v``, ``(full_layers, num_pages,
       kv_heads, page_len, head_dim)``: pages **by length** under the
@@ -258,9 +274,11 @@ class WindowedKV(HybridKV):
     has lapped over it.  ``pages_hold_all`` is False and prefix reuse is
     off, said so (:data:`REUSE_OFF`)."""
 
-    def __init__(self, full_layers: int, window_layers: int, kv_heads: int, head_dim: int, window: int, dtype: Any):
-        super().__init__(full_layers, PerHeadKV(kv_heads, head_dim, dtype), {})
+    def __init__(self, full_layers: int, window_layers: int, kv_heads: int, head_dim: int, window: int, dtype: Any,
+                 v_dim: Optional[int] = None, window_pages: Optional[PerHeadKV] = None):
+        super().__init__(full_layers, PerHeadKV(kv_heads, head_dim, dtype, v_dim), {})
         self.window_layers, self.window = int(window_layers), int(window)
+        self.window_pages = self.pages if window_pages is None else window_pages
 
     def ring_pages(self, page_len: int) -> int:
         from deepspeed_tpu.ops.transformer.inference import ring_pages_for
@@ -268,27 +286,32 @@ class WindowedKV(HybridKV):
         return ring_pages_for(self.window, page_len)
 
     def state_buffers(self, num_slots: int, page_len: int = 0, prefill_chunk: int = 0) -> Dict[str, Any]:
-        from deepspeed_tpu.ops.transformer.inference import init_kv_cache
-
         if prefill_chunk > 1 and prefill_chunk % page_len:
             raise SlotPoolError(f"WindowedKV: prefill_chunk={prefill_chunk} must be whole pages of {page_len} (a chunk's rows go into the ring page by page)")
-        wk, wv = init_kv_cache(self.window_layers, 1 + num_slots * self.ring_pages(page_len), self.pages.heads, page_len, self.pages.head_dim, self.dtype)
+        wk, wv = self.window_pages.buffers(self.window_layers, 1 + num_slots * self.ring_pages(page_len), page_len)
         return {"wk": wk, "wv": wv}
 
     def groups(self, pool) -> Dict[str, Dict[str, Any]]:
-        """Each group's layers, what a slot holds of it and its bytes over the pool (``pool.stats()["groups"]``)."""
+        """Each group's layers, its geometry (KV heads, key and value widths, the bytes a position costs over its
+        layers), what a slot holds of it and its bytes over the pool (``pool.stats()["groups"]``)."""
         ring = self.ring_pages(pool.page_len)
+        geometry = lambda kind, layers: {"kv_heads": kind.heads, "k_dim": kind.head_dim, "v_dim": kind.v_dim,  # noqa: E731
+                                         "position_bytes": layers * kind.position_bytes()}
         return {
-            "full": {"layers": self.paged_layers, "pages_per_slot": "by length, up to %d" % pool.pages_per_slot,
+            "full": {"layers": self.paged_layers, **geometry(self.pages, self.paged_layers),
+                     "pages_per_slot": "by length, up to %d" % pool.pages_per_slot,
                      "positions_per_slot": "by length, up to %d" % pool.max_len, "bytes": pool.cache_bytes() - pool.state_bytes()},
-            "window": {"layers": self.window_layers, "window": self.window, "pages_per_slot": ring,
-                       "positions_per_slot": ring * pool.page_len, "slots_live": pool.live_slots, "bytes": pool.state_bytes()},
+            "window": {"layers": self.window_layers, **geometry(self.window_pages, self.window_layers), "window": self.window,
+                       "pages_per_slot": ring, "positions_per_slot": ring * pool.page_len, "slots_live": pool.live_slots,
+                       "bytes": pool.state_bytes()},
         }
 
     def describe(self, n_layer: int, num_pages: int, page_len: int) -> str:
+        w = self.window_pages
+        widths = f"{w.head_dim} head_dim, K and V" if w.v_dim == w.head_dim else f"(K {w.head_dim} + V {w.v_dim})"
         return (f"full-attention pages by length {self._describe_pages(n_layer, num_pages, page_len)} + window ring per slot "
-                f"({self.window_layers} layers x {self.ring_pages(page_len)} pages x {self.pages.heads} heads x {page_len} page_len x "
-                f"{self.pages.head_dim} head_dim, K and V, window {self.window})")
+                f"({self.window_layers} layers x {self.ring_pages(page_len)} pages x {w.heads} heads x {page_len} page_len x "
+                f"{widths}, window {self.window})")
 
 
 REUSE_OFF = ("off: this cache kind keeps part of a position's trace in per-slot state (a recurrent state, or a ring of "

@@ -160,6 +160,32 @@ def test_flash_decode_paged_compiles_for_v5e_under_the_tile_of_each_serve_cell(c
     assert chip_smoke.mosaic_kernels(compiled.as_text()) == {"dsa_sparse_decode" if selected else "flash_decode_paged": 1}
 
 
+@pytest.mark.parametrize("form,kv_heads,slots,pool_pages,tile", [("swa_decode_paged", 8, 32, 65, (8, 1)), ("flash_decode_paged", 4, 32, 7169, (4, 2))])
+def test_the_mimo_decode_kernels_compile_for_v5e_with_keys_in_the_lanes_form_and_values_row_major(form, kv_heads, slots, pool_pages, tile, v5e_chip):
+    """``chip_smoke.check_mimo_decode_paged``'s shadow: the paged decode kernel at MiMo-V2-Flash's two geometries (64 query heads
+    on 8 / 4 KV heads, keys 192 and values 128 wide, slots of 544 pages), by the chip's compiler without the chip — the window
+    form with its sink operand on the ring of 2 pages, the full form over pages by length; the K pool lies with its positions
+    in the lanes, unpadded, and no copy of a pool stands in front of the call."""
+    from deepspeed_tpu.ops.kernels.flash_decode import flash_decode_paged, paged_tile, paged_work_list
+    from deepspeed_tpu.ops.transformer import inference as inf
+
+    on_chip = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    k, v = on_chip((pool_pages, kv_heads, 128, 192)), on_chip((pool_pages, kv_heads, 128, 128))
+    assert paged_tile(k, 544, v) == tile
+    window = 128 if form == "swa_decode_paged" else None
+
+    def call(q, k, v, t, p, m, sink):
+        table = inf.ring_table(jnp.arange(slots), 2, 544) if window else t
+        work = paged_work_list(p, m, 128, 544, tile[1], window)
+        return flash_decode_paged(q, k, v, table, p, work=work, interpret=False, window=window, sink=sink if window else None)
+
+    compiled = jax.jit(call).lower(on_chip((slots, 64, 1, 192)), k, v, on_chip((slots, 544), jnp.int32), on_chip((slots,), jnp.int32),
+                                   on_chip((slots,), jnp.bool_), on_chip((64,), jnp.float32)).compile()
+    assert chip_smoke.mosaic_kernels(compiled.as_text()) == {form: 1}
+    assert "bf16[%d,%d,128,192]{2,3,1,0" % (pool_pages, kv_heads) in compiled.as_text()  # page_len in the lanes: 192 is not padded to 256
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
 @pytest.mark.parametrize("cell,heads,kv_heads,chunk,pages_per_slot,masked,tile", [
     ("keye", 32, 4, 2048, 264, True, (256, 8)),          # 8 query heads a KV head stacked: 2,048 rows against 1,024 keys, under the selection
     ("laguna_full", 48, 8, 1024, 168, False, (256, 8)),  # 6 a KV head: 1,536 rows

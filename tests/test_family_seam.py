@@ -11,12 +11,12 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu import models
-from deepspeed_tpu.models import bert, deepseek_v2, gigachat35, gpt2, keye, laguna, solar_open2, zaya
+from deepspeed_tpu.models import bert, deepseek_v2, gigachat35, gpt2, keye, laguna, mimo_v2, solar_open2, zaya
 from deepspeed_tpu.sharding.rules import rules_for_config
 
 TINY = {"GPT2Config": gpt2.GPT2_TINY, "BertConfig": bert.BERT_TINY, "DeepseekV2Config": deepseek_v2.DEEPSEEK_V2_TINY,
         "SolarOpen2Config": solar_open2.SOLAR_OPEN2_TINY, "ZayaConfig": zaya.ZAYA_TINY, "KeyeConfig": keye.KEYE_TINY,
-        "GigaChat35Config": gigachat35.GIGACHAT35_TINY, "LagunaConfig": laguna.LAGUNA_TINY}
+        "GigaChat35Config": gigachat35.GIGACHAT35_TINY, "LagunaConfig": laguna.LAGUNA_TINY, "MiMoV2Config": mimo_v2.MIMO_V2_TINY}
 
 
 def test_every_built_in_config_class_has_a_tiny_configuration_here():
@@ -33,7 +33,7 @@ def test_a_config_class_resolves_to_the_table_its_family_names_and_to_berts_only
     assert (rules.name == "bert") == (family is bert)
 
 
-@pytest.mark.parametrize("family", [keye, gigachat35, laguna], ids=["keye", "gigachat35", "laguna"])
+@pytest.mark.parametrize("family", [keye, gigachat35, laguna, mimo_v2], ids=["keye", "gigachat35", "laguna", "mimo_v2"])
 def test_the_two_newest_families_shard_held_experts_over_expert_and_embedding_and_head_over_the_vocabulary(family):
     cfg = TINY[[k for k, v in models._CONFIG_FAMILIES.items() if family.__name__.endswith("." + v)][0]]
     rules, shapes = rules_for_config(cfg), family.param_shapes(cfg)

@@ -1683,13 +1683,48 @@ def test_windowed_kind_has_pages_by_length_for_its_full_layers_and_a_ring_a_slot
     assert pool.state_bytes() == window_bytes and pool.cache_bytes() == full_bytes + window_bytes
     st = pool.stats()
     g = st["groups"]
-    assert g["full"] == {"layers": 2, "pages_per_slot": "by length, up to 16", "positions_per_slot": "by length, up to 256", "bytes": full_bytes}
-    assert g["window"] == {"layers": 3, "window": 40, "pages_per_slot": 4, "positions_per_slot": 64, "slots_live": 0, "bytes": window_bytes}
+    geometry = lambda layers: {"kv_heads": 2, "k_dim": 8, "v_dim": 8, "position_bytes": layers * 2 * 16 * 4}  # noqa: E731  one geometry for both groups
+    assert g["full"] == {"layers": 2, **geometry(2), "pages_per_slot": "by length, up to 16", "positions_per_slot": "by length, up to 256",
+                         "bytes": full_bytes}
+    assert g["window"] == {"layers": 3, **geometry(3), "window": 40, "pages_per_slot": 4, "positions_per_slot": 64, "slots_live": 0,
+                           "bytes": window_bytes}
     assert st["page_kind"] == "PerHeadKV" and st["state_leaves"] == {"wk": window_bytes // 2, "wv": window_bytes // 2}
     math = pool.shape_math()
     assert "full-attention pages by length 2 x (2 of 5 layers x 41 pages" in math and "window ring per slot (3 layers x 4 pages" in math
     assert st["kind"] == pool.kind.describe(5, 41, 16) and "window 40" in st["kind"]
     assert not pool.reuse and pool.kind.pages_hold_all is False
+
+
+def test_windowed_kind_takes_a_geometry_a_group_and_a_value_width_and_stores_each_leaf_as_wide_as_it_is():
+    """MiMo-V2's pool at a small size: 2 KV heads on the full group's pages, 4 on the window group's rings, keys 24 wide beside
+    values 16 wide in both — four leaves of four shapes under one allocator, none padded to another."""
+    from deepspeed_tpu.serving.kvcache.pages import PerHeadKV, WindowedKV
+
+    kind = WindowedKV(full_layers=2, window_layers=3, kv_heads=2, head_dim=24, window=6, dtype=jnp.bfloat16, v_dim=16,
+                      window_pages=PerHeadKV(4, 24, jnp.bfloat16, 16))
+    pool = PagedKVPool(5, 3, 0, 128, 0, jnp.bfloat16, page_len=8, num_pages=33, prefill_chunk=16, kind=kind)
+    assert pool.k.shape == (2, 33, 2, 8, 24) and pool.v.shape == (2, 33, 2, 8, 16)
+    assert kind.ring_pages(8) == 2 and pool.state["wk"].shape == (3, 1 + 3 * 2, 4, 8, 24) and pool.state["wv"].shape == (3, 7, 4, 8, 16)
+    full_bytes, window_bytes = 2 * 33 * 8 * 2 * (24 + 16) * 2, 3 * 7 * 8 * 4 * (24 + 16) * 2
+    assert pool.state_bytes() == window_bytes and pool.cache_bytes() == full_bytes + window_bytes
+    g = pool.stats()["groups"]
+    assert {k: g["full"][k] for k in ("kv_heads", "k_dim", "v_dim", "position_bytes", "bytes")} == \
+        {"kv_heads": 2, "k_dim": 24, "v_dim": 16, "position_bytes": 2 * 2 * 40 * 2, "bytes": full_bytes}
+    assert {k: g["window"][k] for k in ("kv_heads", "k_dim", "v_dim", "position_bytes", "bytes", "pages_per_slot")} == \
+        {"kv_heads": 4, "k_dim": 24, "v_dim": 16, "position_bytes": 3 * 4 * 40 * 2, "bytes": window_bytes, "pages_per_slot": 2}
+    assert pool.stats()["state_leaves"] == {"wk": window_bytes * 24 // 40, "wv": window_bytes * 16 // 40}
+    math = pool.shape_math()
+    assert "(K 24 + V 16)" in math and "4 heads" in math and "window 6" in math
+    # the allocator never looks inside: a request takes pages by its length and its slot's ring, and gives both back
+    r = _KReq(1, np.arange(1, 41, dtype=np.int32), max_new=8)
+    r.slot = pool.alloc_request(r)
+    assert pool.pages_live == 6 and pool.stats()["groups"]["window"]["slots_live"] == 1
+    pool.retire(r.slot, r)
+    _assert_no_leaks(pool)
+    # a value width on the default kind too: the int8 pair follows it
+    k8, v8 = PerHeadKV(2, 24, "int8", 16).buffers(1, 3, 8)
+    assert k8["q"].shape == (1, 3, 2, 8, 24) and v8["q"].shape == (1, 3, 2, 8, 16) and v8["s"].shape == (1, 3, 2, 8, 1)
+    assert PerHeadKV(2, 24, "int8", 16).position_bytes() == 2 * 48 and PerHeadKV(2, 8, jnp.float32).v_dim == 8
 
 
 @pytest.mark.parametrize("max_len", [64, 256, 4096])

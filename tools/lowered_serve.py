@@ -9,10 +9,10 @@ import jax  # noqa: E402
 
 BUILDERS = {"gpt2-xl-serve-paged": "build", "deepseek-v2-serve-ep4share": "build_deepseek_v2", "solar-open2-serve-ep8share": "build_solar_open2",
             "zaya1-8b-serve-ep2share": "build_zaya1", "keye-vl2-30b-serve-ep8share": "build_keye", "gigachat35-serve-ep16share": "build_gigachat35",
-            "laguna-s21-serve-ep8stage": "build_laguna"}
+            "laguna-s21-serve-ep8stage": "build_laguna", "mimo-v2-flash-serve-ep16stage": "build_mimo"}
 PAGED = {"kvcache": {"enabled": True, "page_len": 16}}
 TINY = {"gpt2": ("gpt2", PAGED), "gpt2-int8": ("gpt2", {**PAGED, "kv_cache_dtype": "int8"}), "gpt2-slot": ("gpt2", {}),
-        **{family: (family, PAGED) for family in ("deepseek_v2", "solar_open2", "zaya", "keye", "gigachat35", "laguna")}}
+        **{family: (family, PAGED) for family in ("deepseek_v2", "solar_open2", "zaya", "keye", "gigachat35", "laguna", "mimo_v2")}}
 
 
 def normalised(text: str) -> str:
